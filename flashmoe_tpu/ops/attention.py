@@ -108,14 +108,49 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         ).astype(o_ref.dtype)
 
 
+def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
+                    block_q: int = 128, block_k: int = 128,
+                    interpret: bool = False):
+    """Blockwise attention. q/k/v: [B, N, T, D] with T % block == 0.
+
+    Differentiable: the forward is the Pallas kernel; the backward
+    recomputes through :func:`attention_xla` (the kernel writes no
+    residuals, and ``pallas_call`` itself has no transpose rule — on the
+    chip ``jax.grad`` through the bare kernel dies in its JVP rule, which
+    is how the trainer first met it)."""
+    return _flash_ad(q, k, v, causal, scale, block_q, block_k, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_ad(q, k, v, causal, scale, block_q, block_k, interpret):
+    return _flash_forward(q, k, v, causal=causal, scale=scale,
+                          block_q=block_q, block_k=block_k,
+                          interpret=interpret)
+
+
+def _flash_ad_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+    out = _flash_forward(q, k, v, causal=causal, scale=scale,
+                         block_q=block_q, block_k=block_k,
+                         interpret=interpret)
+    return out, (q, k, v)
+
+
+def _flash_ad_bwd(causal, scale, block_q, block_k, interpret, res, dy):
+    _, vjp = jax.vjp(
+        lambda q, k, v: attention_xla(q, k, v, causal=causal, scale=scale),
+        *res)
+    return vjp(dy)
+
+
+_flash_ad.defvjp(_flash_ad_fwd, _flash_ad_bwd)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "block_q", "block_k", "interpret", "scale"),
 )
-def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool = False):
-    """Blockwise attention. q/k/v: [B, N, T, D] with T % block == 0."""
+def _flash_forward(q, k, v, *, causal: bool, scale: float | None,
+                   block_q: int, block_k: int, interpret: bool):
     b, n, tq, d = q.shape
     tk = k.shape[2]
     scale = scale if scale is not None else d ** -0.5

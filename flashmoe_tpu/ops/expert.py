@@ -38,6 +38,63 @@ from flashmoe_tpu.models.reference import activation_fn
 # working-set sizing); call sites share this instead of bare literals
 DEFAULT_BLOCK_I = 512
 
+# Mosaic scopes a kernel to 16 MiB of VMEM unless told otherwise; a v5e
+# core has 128 MiB.  Each kernel here counts what its double-buffered
+# blocks and scratch take (``need``), asks for that much, and shrinks its
+# chunk only when even the ceiling would not hold it.  block_m is the
+# caller's (``tile_gid`` is laid out by it), so only chunks shrink.
+_VMEM_DEFAULT = 16 << 20
+_VMEM_CEILING = 64 << 20
+
+
+def _fit_chunk(dim: int, block: int, need) -> int:
+    """Largest chunk of ``dim`` no larger than ``block`` whose working set
+    ``need(chunk)`` (bytes) fits :data:`_VMEM_CEILING`."""
+    while need(block) > _VMEM_CEILING and block > 8:
+        block = _auto_block(dim, block - 1)
+    return block
+
+
+def _vmem_params(need: int) -> pltpu.CompilerParams:
+    """Scoped-VMEM request for a working set of ``need`` bytes, with a
+    quarter on top for Mosaic's own temporaries."""
+    limit = need + need // 4 + (2 << 20)
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(max(limit, _VMEM_DEFAULT), _VMEM_CEILING))
+
+
+def _ffn_vmem(block_m: int, h: int, bi: int, gated: bool, x_isz: int,
+              w_isz: int, res_outs: int = 0) -> int:
+    """Bytes one grouped-FFN grid step keeps in VMEM: two input and two
+    output row tiles, the f32 accumulator, two buffers each of the up
+    (and gate) and down weight chunks, and ``res_outs`` double-buffered
+    [block_m, bi] residual tiles."""
+    rows = 4 * block_m * h * x_isz + block_m * h * 4
+    weights = 2 * h * bi * ((2 if gated else 1) + 1) * w_isz
+    return rows + weights + res_outs * 2 * block_m * bi * x_isz
+
+
+def _ffn_chunks(x, w_up, w_gate, block_m: int, block_i: int, gated: bool,
+                res_outs: int = 0):
+    """How one grouped-FFN launch walks the intermediate axis: the chunk
+    ``bi`` (the largest divisor of I under ``block_i`` whose working set
+    fits VMEM), the up-projection weights as the kernel streams them —
+    for a gated FFN [E, H, 2*I] with each I-chunk laid out
+    [gate_chunk | up_chunk], so one block DMA brings both halves — and
+    the compiler params that ask for the VMEM the launch needs."""
+    if gated and w_gate is None:
+        raise ValueError("gated_ffn requires w_gate")
+    e, h, i = w_up.shape
+    need = lambda b: _ffn_vmem(block_m, h, b, gated, x.dtype.itemsize,
+                               w_up.dtype.itemsize, res_outs)
+    bi = _fit_chunk(i, _auto_block(i, block_i), need)
+    if gated:
+        nj = i // bi
+        w_up = jnp.concatenate(
+            [w_gate.reshape(e, h, nj, bi), w_up.reshape(e, h, nj, bi)],
+            axis=-1).reshape(e, h, nj * 2 * bi)
+    return bi, w_up, _vmem_params(need(bi))
+
 
 # ----------------------------------------------------------------------
 # XLA path: batched over the capacity buffer
@@ -132,22 +189,11 @@ def grouped_ffn(x, tile_gid, w_up, b_up, w_down, b_down, w_gate=None, *,
     e, _, i = w_up.shape
     if t % block_m:
         raise ValueError(f"rows {t} must be a multiple of block_m={block_m}")
-    bi = _auto_block(i, block_i)
+    bi, w_up_eff, vmem = _ffn_chunks(x, w_up, w_gate, block_m, block_i,
+                                     gated)
     nt, nj = t // block_m, i // bi
-
-    if gated:
-        if w_gate is None:
-            raise ValueError("gated_ffn requires w_gate")
-        # interleave per-chunk: [E, H, 2*I] as chunk-major [gate_chunk|up_chunk]
-        wg = w_gate.reshape(e, h, nj, bi)
-        wu = w_up.reshape(e, h, nj, bi)
-        w_up_eff = jnp.concatenate([wg, wu], axis=-1).reshape(e, h, nj * 2 * bi)
-        up_block = (1, h, 2 * bi)
-        up_map = lambda ti, j, gid: (gid[ti], 0, j)
-    else:
-        w_up_eff = w_up
-        up_block = (1, h, bi)
-        up_map = lambda ti, j, gid: (gid[ti], 0, j)
+    up_block = (1, h, 2 * bi if gated else bi)
+    up_map = lambda ti, j, gid: (gid[ti], 0, j)
 
     # biases are lifted to [E, 1, dim] so their (1, dim) trailing block shape
     # satisfies the TPU (8, 128) tiling rule via the equal-dimension escape
@@ -184,6 +230,7 @@ def grouped_ffn(x, tile_gid, w_up, b_up, w_down, b_down, w_gate=None, *,
             + w_down.size * w_down.dtype.itemsize,
             transcendentals=t * i,
         ),
+        compiler_params=vmem,
         interpret=interpret,
     )(tile_gid, x, w_up_eff, b_up3, w_down, b_down3)
 
@@ -297,19 +344,10 @@ def grouped_ffn_tokens(x, src_tok, tile_gid, w_up, b_up, w_down, b_down,
     e, _, i = w_up.shape
     if t % block_m:
         raise ValueError(f"slab rows {t} must be a multiple of {block_m}")
-    bi = _auto_block(i, block_i)
+    bi, w_up_eff, vmem = _ffn_chunks(x, w_up, w_gate, block_m, block_i,
+                                     gated)
     nt, nj = t // block_m, i // bi
-
-    if gated:
-        if w_gate is None:
-            raise ValueError("gated_ffn requires w_gate")
-        wg = w_gate.reshape(e, h, nj, bi)
-        wu = w_up.reshape(e, h, nj, bi)
-        w_up_eff = jnp.concatenate([wg, wu], axis=-1).reshape(e, h, nj * 2 * bi)
-        up_block = (1, h, 2 * bi)
-    else:
-        w_up_eff = w_up
-        up_block = (1, h, bi)
+    up_block = (1, h, 2 * bi if gated else bi)
     b_up3 = b_up.reshape(e, 1, i)
     b_down3 = b_down.reshape(e, 1, h)
 
@@ -348,6 +386,7 @@ def grouped_ffn_tokens(x, src_tok, tile_gid, w_up, b_up, w_down, b_down,
             + w_down.size * w_down.dtype.itemsize,
             transcendentals=t * i,
         ),
+        compiler_params=vmem,
         interpret=interpret,
     )(tile_gid, src_tok, x, w_up_eff, b_up3, w_down, b_down3)
 
@@ -468,7 +507,7 @@ def capacity_ffn_gather(x, plan, cfg: MoEConfig, capacity: int, params, *,
         x, src_tok.reshape(-1), tile_gid,
         params["w_up"].astype(x.dtype), params["b_up"],
         params["w_down"].astype(x.dtype), params["b_down"],
-        params.get("w_gate", None) if cfg.gated_ffn else None,
+        params["w_gate"].astype(x.dtype) if cfg.gated_ffn else None,
         cfg.hidden_act, cfg.gated_ffn, bm, block_i, interpret,
     )
     return y.reshape(e, cp, h), cp
@@ -488,12 +527,12 @@ def _auto_block(dim: int, cap: int) -> int:
     raise ValueError(f"dimension {dim} not a multiple of 8")
 
 def _gmm_kernel(gid_ref, x_ref, w_ref, out_ref, acc_ref, *, transpose_w):
-    """One (row-tile, K-chunk) grid step of out = x @ w[gid] (or @ w[gid]^T
-    when ``transpose_w`` — the weight block is then [N, bk] and the
-    contraction runs over its last dim, so no transposed weight copy is
+    """One (row-tile, N-chunk, K-chunk) grid step of out = x @ w[gid] (or
+    @ w[gid]^T when ``transpose_w`` — the weight block is then [bn, bk] and
+    the contraction runs over its last dim, so no transposed weight copy is
     ever materialized in HBM)."""
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
+    j = pl.program_id(2)
+    nj = pl.num_programs(2)
 
     @pl.when(j == 0)
     def _():
@@ -526,6 +565,9 @@ def grouped_matmul(x, tile_gid, w, *, transpose_w: bool = False,
 
     The grouped-GEMM primitive of the backward pass: dA and dX are grouped
     matmuls against the *forward* weight layouts with ``transpose_w=True``.
+    N stays whole unless the [block_m, N] output tile and the [N, bk]
+    weight chunk would outgrow VMEM (Mixtral's I=14336), then it is
+    chunked too.
     """
     t, k = x.shape
     if transpose_w:
@@ -536,37 +578,46 @@ def grouped_matmul(x, tile_gid, w, *, transpose_w: bool = False,
         raise ValueError(f"contraction mismatch: x K={k}, w K={kw}")
     if t % block_m:
         raise ValueError(f"rows {t} must be a multiple of {block_m}")
+    out_dtype = jnp.dtype(out_dtype or x.dtype)
     bk = _auto_block(k, block_k)
-    nt, nk = t // block_m, k // bk
+    need = lambda b: (2 * block_m * bk * x.dtype.itemsize
+                      + 2 * bk * b * w.dtype.itemsize
+                      + block_m * b * (2 * out_dtype.itemsize + 4))
+    bn = _fit_chunk(n, n, need)
+    nt, nn, nk = t // block_m, n // bn, k // bk
 
     if transpose_w:
-        w_spec = pl.BlockSpec((1, n, bk), lambda ti, j, gid: (gid[ti], 0, j),
+        w_spec = pl.BlockSpec((1, bn, bk),
+                              lambda ti, jn, j, gid: (gid[ti], jn, j),
                               memory_space=pltpu.VMEM)
     else:
-        w_spec = pl.BlockSpec((1, bk, n), lambda ti, j, gid: (gid[ti], j, 0),
+        w_spec = pl.BlockSpec((1, bk, bn),
+                              lambda ti, jn, j, gid: (gid[ti], j, jn),
                               memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(nt, nk),
+        grid=(nt, nn, nk),
         in_specs=[
-            pl.BlockSpec((block_m, bk), lambda ti, j, gid: (ti, j),
+            pl.BlockSpec((block_m, bk), lambda ti, jn, j, gid: (ti, j),
                          memory_space=pltpu.VMEM),
             w_spec,
         ],
-        out_specs=pl.BlockSpec((block_m, n), lambda ti, j, gid: (ti, 0),
+        out_specs=pl.BlockSpec((block_m, bn),
+                               lambda ti, jn, j, gid: (ti, jn),
                                memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((block_m, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_m, bn), jnp.float32)],
     )
     return pl.pallas_call(
         functools.partial(_gmm_kernel, transpose_w=transpose_w),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, n), out_dtype or x.dtype),
+        out_shape=jax.ShapeDtypeStruct((t, n), out_dtype),
         cost_estimate=pl.CostEstimate(
             flops=2 * t * k * n,
             bytes_accessed=x.size * x.dtype.itemsize
             + w.size * w.dtype.itemsize,
             transcendentals=0,
         ),
+        compiler_params=_vmem_params(need(bn)),
         interpret=interpret,
     )(tile_gid, x, w)
 
@@ -711,18 +762,10 @@ def _grouped_ffn_res(x, tile_gid, w_up, b_up, w_down, b_down, w_gate, *,
     e, _, i = w_up.shape
     if t % block_m:
         raise ValueError(f"rows {t} must be a multiple of block_m={block_m}")
-    bi = _auto_block(i, block_i)
+    bi, w_up_eff, vmem = _ffn_chunks(x, w_up, w_gate, block_m, block_i,
+                                     gated, res_outs=2)
     nt, nj = t // block_m, i // bi
-
-    if gated:
-        wg = w_gate.reshape(e, h, nj, bi)
-        wu = w_up.reshape(e, h, nj, bi)
-        w_up_eff = jnp.concatenate([wg, wu], axis=-1).reshape(
-            e, h, nj * 2 * bi)
-        up_block = (1, h, 2 * bi)
-    else:
-        w_up_eff = w_up
-        up_block = (1, h, bi)
+    up_block = (1, h, 2 * bi if gated else bi)
     b_up3 = b_up.reshape(e, 1, i)
     b_down3 = b_down.reshape(e, 1, h)
 
@@ -768,6 +811,7 @@ def _grouped_ffn_res(x, tile_gid, w_up, b_up, w_down, b_down, w_gate, *,
             jax.ShapeDtypeStruct((t, i) if gated else (block_m, bi),
                                  x.dtype),
         ],
+        compiler_params=vmem,
         interpret=interpret,
     )(tile_gid, x, w_up_eff, b_up3, w_down, b_down3)
     return y, u, (g if gated else None)
@@ -888,7 +932,7 @@ def capacity_buffer_ffn_ad(xs, params, cfg: MoEConfig,
     out = grouped_ffn_ad(
         x, tile_gid, params["w_up"].astype(x.dtype), params["b_up"],
         params["w_down"].astype(x.dtype), params["b_down"],
-        params.get("w_gate", None) if cfg.gated_ffn else None,
+        params["w_gate"].astype(x.dtype) if cfg.gated_ffn else None,
         cfg.hidden_act, cfg.gated_ffn, bm, block_i, interpret,
     )
     return out.reshape(e, cp, h)[:, :c, :]
@@ -915,7 +959,7 @@ def capacity_buffer_ffn_pallas(xs, params, cfg: MoEConfig, *,
     out = grouped_ffn(
         x, tile_gid, params["w_up"].astype(x.dtype),
         params["b_up"], params["w_down"].astype(x.dtype), params["b_down"],
-        params.get("w_gate", None) if cfg.gated_ffn else None,
+        params["w_gate"].astype(x.dtype) if cfg.gated_ffn else None,
         act_name=cfg.hidden_act, gated=cfg.gated_ffn, block_m=bm,
         block_i=block_i, interpret=interpret,
     )

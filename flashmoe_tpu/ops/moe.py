@@ -115,7 +115,7 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
         ffn_tail = (
             params["w_up"].astype(cfg.dtype), params["b_up"],
             params["w_down"].astype(cfg.dtype), params["b_down"],
-            params.get("w_gate", None) if cfg.gated_ffn else None,
+            params["w_gate"].astype(cfg.dtype) if cfg.gated_ffn else None,
             cfg.hidden_act, cfg.gated_ffn, bm, exp.DEFAULT_BLOCK_I,
             interpret,
         )

@@ -60,7 +60,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flashmoe_tpu.config import MoEConfig
-from flashmoe_tpu.utils.compat import axis_size, shard_map
 from flashmoe_tpu.models.reference import shared_expert_ffn
 from flashmoe_tpu.ops import dispatch as dsp
 from flashmoe_tpu.ops import expert as exp
@@ -213,7 +212,7 @@ def _ep_moe_shard(params, x, cfg: MoEConfig, *, axis: str, use_pallas: bool,
     efficiency measurement (:mod:`flashmoe_tpu.parallel.overlap`); the
     result is numerically meaningless (tokens meet the wrong experts).
     """
-    d = axis_size(axis)
+    d = jax.lax.axis_size(axis)
     s_loc, h = x.shape
     e, nlx = cfg.num_experts, cfg.num_experts // d
     cap = local_capacity(cfg, s_loc)
@@ -264,7 +263,7 @@ def _ep_moe_shard(params, x, cfg: MoEConfig, *, axis: str, use_pallas: bool,
     if tp_axis is not None:
         # row-parallel down bias: each tp rank contributes 1/tp of it so
         # the psum reconstructs it exactly once
-        tp = axis_size(tp_axis)
+        tp = jax.lax.axis_size(tp_axis)
         ffn_params = dict(params, b_down=params["b_down"] / tp)
 
     def ffn(buf, p):
@@ -513,7 +512,7 @@ def ep_moe_layer(params, x, cfg: MoEConfig, mesh: Mesh, *,
     )
     stats_specs = (st.MoEStats(*([P()] * len(st.MoEStats._fields)))
                    if cfg.collect_stats else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, P(token_axes, None)),
         out_specs=MoEOutput(P(token_axes, None), P(), P(), P(),

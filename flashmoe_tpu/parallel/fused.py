@@ -101,7 +101,6 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 from flashmoe_tpu.config import MoEConfig
-from flashmoe_tpu.utils.compat import axis_size, shard_map
 from flashmoe_tpu.models.reference import activation_fn, shared_expert_ffn
 from flashmoe_tpu.ops import dispatch as dsp
 from flashmoe_tpu.ops import stats as st
@@ -181,8 +180,6 @@ def _fused_kernel(
     # ---- phase 0/1 (first step only): barrier, then start every send ----
     @pl.when(s == 0)
     def _():
-        barrier = pltpu.get_barrier_semaphore()
-
         def signal_peer(d, c):
             @pl.when(d != my)
             def _():
@@ -192,8 +189,13 @@ def _fused_kernel(
                 )
             return c
 
-        jax.lax.fori_loop(0, d_world, signal_peer, 0)
-        pltpu.semaphore_wait(barrier, d_world - 1)
+        # a world of one has no peer to meet: waiting for zero signals
+        # on a semaphore nobody signalled is refused by the interpreter
+        # of jax 0.9.0 (an assert in shared_memory.wait)
+        if x_send.shape[0] > 1:
+            barrier = pltpu.get_barrier_semaphore()
+            jax.lax.fori_loop(0, d_world, signal_peer, 0)
+            pltpu.semaphore_wait(barrier, d_world - 1)
 
         def send(step, c):
             dst = jax.lax.rem(my + step + 1, d_world)
@@ -285,11 +287,14 @@ def _fused_kernel(
 
     def expert_body(e, _):
         # stream this expert's biases once
+        # biases arrive lifted to [nLx, 1, dim]: ``.at[e]`` is then a
+        # whole (1, dim) slab — a one-row ``pl.ds(e, 1)`` slice of a
+        # [nLx, dim] ref is off the (8, 128) tiling and Mosaic refuses it
         bup_dma = pltpu.make_async_copy(
-            b_up.at[pl.ds(e, 1), :], bup_vmem, copy_sems.at[0]
+            b_up.at[e], bup_vmem, copy_sems.at[0]
         )
         bdn_dma = pltpu.make_async_copy(
-            b_down.at[pl.ds(e, 1), :], bdn_vmem, copy_sems.at[1]
+            b_down.at[e], bdn_vmem, copy_sems.at[1]
         )
         bup_dma.start(); bdn_dma.start()
         bup_dma.wait(); bdn_dma.wait()
@@ -1567,7 +1572,7 @@ def _fused_shard(send_cnt, recv_cnt, src_order, x_send, w_up, b_up, w_down,
             (d_world, nlx, cap, h), jnp.float32))
         out_specs.append(any_spec)
     in_specs += [any_spec] * 5
-    inputs += [x_send, w_up, b_up, w_down, b_down]
+    inputs += [x_send, w_up, b_up[:, None, :], w_down, b_down[:, None, :]]
     if quant:
         # per-output-channel f32 scales: tiny ([nLx, I(+I)] + [nLx, H])
         # and read every window, so they live whole in VMEM
@@ -1666,6 +1671,9 @@ def _fused_shard(send_cnt, recv_cnt, src_order, x_send, w_up, b_up, w_down,
         # wait instead of on io_callback threads — slower-arrival
         # semantics, but immune to the interpreter's eager-thread
         # deadlocks (see fused_ep_moe_layer's interpret note).
+        from flashmoe_tpu.utils.compat import cure_interpret_device_barrier
+
+        cure_interpret_device_barrier()
         interp = pltpu.InterpretParams(
             dma_execution_mode=os.environ.get("FLASHMOE_INTERPRET_DMA",
                                               "eager"),
@@ -2032,7 +2040,7 @@ def fused_ep_moe_layer(params, x, cfg: MoEConfig, mesh: Mesh, *,
         src_order = jnp.asarray(src_order, jnp.int32)
 
     def body(params, x, src_order):
-        d = axis_size("ep")
+        d = jax.lax.axis_size("ep")
         s_loc, h = x.shape
         nlx = cfg.num_experts // d
         cap = local_capacity(cfg, s_loc)
@@ -2224,7 +2232,7 @@ def fused_ep_moe_layer(params, x, cfg: MoEConfig, mesh: Mesh, *,
               else P() for k in params}
     stats_specs = (st.MoEStats(*([P()] * len(st.MoEStats._fields)))
                    if cfg.collect_stats else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, P(token_axes, None), P()),
         out_specs=MoEOutput(P(token_axes, None), P(), P(), P(),

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from flashmoe_tpu.config import MoEConfig
-from flashmoe_tpu.utils.compat import axis_size, shard_map
 from flashmoe_tpu.models import transformer as tfm
 from flashmoe_tpu.ops.moe import moe_layer
 from flashmoe_tpu.parallel.ep import _ep_moe_shard
@@ -181,7 +180,7 @@ def pipeline_loss(params, batch, cfg: MoEConfig, mesh: Mesh, *,
         # in_specs P("pp") leaves a leading singleton stage dim per rank
         stage_layers = jax.tree_util.tree_map(lambda a: a[0], stage_layers)
         s = jax.lax.axis_index("pp")
-        p = axis_size("pp")
+        p = jax.lax.axis_size("pp")
         m = num_microbatches
         b, t1 = tokens.shape
         bm = b // m
@@ -265,7 +264,7 @@ def pipeline_loss(params, batch, cfg: MoEConfig, mesh: Mesh, *,
         return ce + aux, ce, aux
 
     tok_spec = P(("dp", "ep"), None) if use_ep else P("dp", None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(stage_specs, P(), tok_spec),
         out_specs=(P(), P(), P()),

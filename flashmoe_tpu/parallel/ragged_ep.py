@@ -40,7 +40,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from flashmoe_tpu.config import BLOCK_M, MoEConfig
-from flashmoe_tpu.utils.compat import axis_size, shard_map
 from flashmoe_tpu.ops import expert as exp
 from flashmoe_tpu.ops import ragged as rag
 from flashmoe_tpu.ops import stats as st
@@ -216,7 +215,7 @@ def _grouped_ffn(x_grp, tile_gid, weights, cfg: MoEConfig, *,
             x_grp, tile_gid,
             w_up.astype(cfg.dtype), b_up,
             w_down.astype(cfg.dtype), b_down,
-            w_gate,
+            None if w_gate is None else w_gate.astype(cfg.dtype),
             cfg.hidden_act, cfg.gated_ffn, block_m,
             exp.DEFAULT_BLOCK_I, interpret,
         )
@@ -367,7 +366,7 @@ def _ragged_ep_shard(params, x, cfg: MoEConfig, *, axis: str,
                      use_pallas: bool, interpret: bool, exchange: str,
                      block_m: int, reduce_axes,
                      skip_exchange: bool = False):
-    d = axis_size(axis)
+    d = jax.lax.axis_size(axis)
     s_loc, h = x.shape
     e = cfg.num_experts
     nlx = e // d
@@ -606,7 +605,7 @@ def ragged_ep_moe_layer(params, x, cfg: MoEConfig, mesh: Mesh, *,
     pspecs = {k: P("ep") if k != "gate_w" else P() for k in params}
     stats_specs = (st.MoEStats(*([P()] * len(st.MoEStats._fields)))
                    if cfg.collect_stats else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, P(token_axes, None)),
         out_specs=MoEOutput(P(token_axes, None), P(), P(), P(),

@@ -25,7 +25,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from flashmoe_tpu.utils.compat import axis_size, shard_map
 
 from flashmoe_tpu.ops.attention import NEG_INF
 
@@ -55,7 +54,7 @@ def _block_attn(q, k, v, q_off, kv_off, scale, causal):
 
 def _ring_shard(q, k, v, *, axis, scale, causal):
     """Per-rank body. q/k/v: [B, N, T_loc, D] local shards."""
-    d_world = axis_size(axis)
+    d_world = jax.lax.axis_size(axis)
     my = jax.lax.axis_index(axis)
     t_loc = q.shape[2]
     q_off = my * t_loc
@@ -101,7 +100,7 @@ def ring_attention(q, k, v, mesh: Mesh, *, axis: str = "sp",
     scale = scale if scale is not None else dd ** -0.5
     body = functools.partial(_ring_shard, axis=axis, scale=scale,
                              causal=causal)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None, axis, None),) * 3,
         out_specs=P(None, None, axis, None),

@@ -148,9 +148,9 @@ def _dtype_peak(gen: str, cfg: MoEConfig) -> tuple[float, float]:
 
 def _ici_link(gen: str) -> tuple[float, float]:
     """(alpha_ms, one-way B/ms per link)."""
-    from flashmoe_tpu.parallel.topology import _ICI_SPECS
+    from flashmoe_tpu.parallel.topology import ici_spec
 
-    lat_us, gbps = _ICI_SPECS.get(gen, _ICI_SPECS["default"])
+    lat_us, gbps = ici_spec(gen)
     return lat_us / 1e3, gbps * 1e6
 
 
@@ -231,7 +231,7 @@ def dp_allreduce_ms(cfg: MoEConfig, dp: int, gen: str, *,
     if dp <= 1 or not cfg.is_training:
         return 0.0
     from flashmoe_tpu.parallel.decider import ring_allreduce_ms
-    from flashmoe_tpu.parallel.topology import _DCN_SPEC, _ICI_SPECS
+    from flashmoe_tpu.parallel.topology import _DCN_SPEC, ici_spec
 
     grad_mb = (cfg.param_count
                * jnp.dtype(cfg.param_dtype).itemsize) / 1e6
@@ -239,7 +239,7 @@ def dp_allreduce_ms(cfg: MoEConfig, dp: int, gen: str, *,
         lat_us, gbps = _DCN_SPEC
         beta = 1e3 / (gbps * 1e3)                       # ms per MB
     else:
-        lat_us, gbps = _ICI_SPECS.get(gen, _ICI_SPECS["default"])
+        lat_us, gbps = ici_spec(gen)
         beta = 1e3 / (gbps * 1e3 * max(links, 1))
     return ring_allreduce_ms(grad_mb, dp, beta, lat_us / 1e3)
 

@@ -156,4 +156,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from flashmoe_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

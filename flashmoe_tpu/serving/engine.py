@@ -504,8 +504,6 @@ def _ep_decode_fn(mesh, cfg: MoEConfig, params):
     import jax.tree_util as jtu
     from jax.sharding import PartitionSpec as P
 
-    from flashmoe_tpu.utils.compat import shard_map
-
     key = (mesh, cfg, jtu.tree_structure(params))
     cached = _EP_DECODE_CACHE.get(key)
     if cached is not None:
@@ -580,7 +578,7 @@ def _ep_decode_fn(mesh, cfg: MoEConfig, params):
 
         return lm_logits(params, cfg, x), k_pages, v_pages
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, P(None, "ep"), P(None, "ep"), P("ep"),
                   P("ep", None), P("ep")),
@@ -600,8 +598,6 @@ def _ep_verify_fn(mesh, cfg: MoEConfig, params):
     Cached like :func:`_ep_decode_fn`."""
     import jax.tree_util as jtu
     from jax.sharding import PartitionSpec as P
-
-    from flashmoe_tpu.utils.compat import shard_map
 
     key = (mesh, cfg, jtu.tree_structure(params))
     cached = _EP_VERIFY_CACHE.get(key)
@@ -680,7 +676,7 @@ def _ep_verify_fn(mesh, cfg: MoEConfig, params):
 
         return lm_logits_span(params, cfg, x), k_pages, v_pages
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, P(None, "ep"), P(None, "ep"), P("ep", None),
                   P("ep", None), P("ep")),

@@ -27,10 +27,7 @@ import sys
 
 def _ensure_virtual_mesh():
     """The tracing engines need >= 8 devices.  Mirror tests/conftest.py:
-    force the virtual CPU backend unless the caller explicitly asked for
-    real hardware — static analysis never needs silicon."""
-    if os.environ.get("FLASHMOE_TEST_TPU") == "1":
-        return
+    force the virtual CPU backend — static analysis never needs silicon."""
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

@@ -1,70 +1,27 @@
-"""JAX version-compatibility shims.
+"""What this code needs from inside jax 0.9.0 (``jax._src``).
 
-The codebase targets current jax, where ``jax.shard_map`` is a public
-top-level API and the replication check is spelled ``check_vma``.  Pinned
-container images may carry an older release where shard_map still lives
-in ``jax.experimental.shard_map`` and the same knob is ``check_rep`` —
-without this shim every shard_map-based layer (ep / fused / ragged /
-pipeline / ring attention / DCN probe) dies on AttributeError before it
-can trace.  One resolution point keeps the seven call sites identical on
-both versions.
+``jax.shard_map`` and ``jax.lax.axis_size`` are public and the call sites
+use them directly.  The three helpers here have no public spelling; each
+was checked against the installed jax 0.9.0 (``pyproject.toml`` pins it).
 """
 
 from __future__ import annotations
 
-import jax
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` where available, else the experimental API with
-    ``check_vma`` mapped onto its older ``check_rep`` spelling."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` where available; on older releases the
-    constant-folded ``psum(1, axis)`` idiom yields the same static int
-    inside shard_map bodies."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 #: trace classes that build a jaxpr instead of executing — values under
 #: them are abstract, so host-clock instants taken there are TRACE time
-_ABSTRACT_TRACE_NAMES = frozenset(
-    {"DynamicJaxprTrace", "JaxprTrace", "DynamicJaxprTrace2"})
+_ABSTRACT_TRACE_NAMES = frozenset({"DynamicJaxprTrace", "JaxprTrace"})
 
 
 def under_abstract_trace() -> bool:
     """True when an abstract (jaxpr-building) trace is active on this
     thread — i.e. the code is being TRACED by ``jit``/``make_jaxpr``,
-    not executed.  ``jax.core.trace_state_clean()`` alone cannot answer
-    this: an *eager* ``shard_map`` body also runs under a trace
-    (ShardMapTrace, plus a RewriteTrace for the replication check), but
-    its values are concrete per-device arrays and its wall clock is
-    real execution time.  Walks the ``parent_trace`` chain looking for
-    a jaxpr-building trace; unknown machinery (no chain to walk while
-    a trace is active) is conservatively reported abstract."""
-    import jax.core as jax_core
+    not executed.  An *eager* ``shard_map`` body also runs under a trace
+    (ShardMapTrace), but its values are concrete per-device arrays and
+    its wall clock is real execution time, so the ``parent_trace`` chain
+    is walked for a jaxpr-building trace instead."""
+    from jax._src.core import trace_ctx
 
-    try:
-        if jax_core.trace_state_clean():
-            return False
-    except Exception:  # pragma: no cover - ancient jax
-        return False
-    try:
-        from jax._src.core import trace_ctx
-
-        trace = trace_ctx.trace
-    except Exception:  # pragma: no cover - trace machinery moved again
-        return True
+    trace = trace_ctx.trace
     hops = 0
     while trace is not None and hops < 16:
         if type(trace).__name__ in _ABSTRACT_TRACE_NAMES:
@@ -77,10 +34,9 @@ def under_abstract_trace() -> bool:
 def concrete_leaf(leaf):
     """The concrete array under ``leaf``, or ``None`` if it is abstract.
 
-    Eager shard_map values arrive as tracer onions —
-    ``RewriteTracer(ShardMapTracer(ArrayImpl))`` — whose ``.val`` chain
-    bottoms out at a blockable concrete array; under an abstract trace
-    the chain ends at a valueless tracer instead."""
+    Eager shard_map values arrive as ``ShardMapTracer(ArrayImpl)`` whose
+    ``.val`` chain bottoms out at a blockable concrete array; under an
+    abstract trace the chain ends at a valueless tracer instead."""
     v = leaf
     hops = 0
     while v is not None and hops < 16:
@@ -92,3 +48,28 @@ def concrete_leaf(leaf):
             return None
         hops += 1
     return None
+
+
+def cure_interpret_device_barrier() -> None:
+    """Make the TPU interpreter's per-device barrier take a host int.
+
+    jax 0.9.0 hands ``SharedMemory.update_clocks_for_device_barrier`` the
+    ``device_id`` as a ``jax.Array`` and multiplies it there, which
+    dispatches a JAX computation from inside an ``io_callback`` thread.
+    With three or more ranks doing that at once every thread waits on the
+    others for ever (the 4-rank fused kernel; near-zero CPU, all threads
+    at ``shared_memory.py:589``).  Every other callback of the interpreter
+    casts with ``int(device_id)`` first; this does the same.  Idempotent;
+    interpret mode only — nothing here is on a compiled path."""
+    from jax._src.pallas.mosaic.interpret import shared_memory
+
+    cls = shared_memory.SharedMemory
+    orig = cls.update_clocks_for_device_barrier
+    if getattr(orig, "_flashmoe_cured", False):
+        return
+
+    def update_clocks_for_device_barrier(self, device_id):
+        return orig(self, int(device_id))
+
+    update_clocks_for_device_barrier._flashmoe_cured = True
+    cls.update_clocks_for_device_barrier = update_clocks_for_device_barrier

@@ -113,7 +113,8 @@ def test_calibration_is_deterministic_and_never_worse():
     assert r1.output_rel_err == r2.output_rel_err
     # absmax (p100) is always a candidate, so the winner can never be
     # worse than uncalibrated on the sample it measured
-    assert r1.output_rel_err <= r1.report["p100"] + 1e-12
+    # (the report rounds to 8 places)
+    assert r1.output_rel_err <= r1.report["p100"] + 1e-8
     qs = qt.quantize_state(params, "int8", calibration=r1)
     assert qt.is_quantized(qs.params)
 

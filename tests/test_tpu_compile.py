@@ -1,0 +1,237 @@
+"""The chip's own compiler on the main path at real widths — no chip needed.
+
+libtpu compiles for a v5e that is described, not attached (topology
+``v5e:2x2``, device kind "TPU v5 lite"), and refuses what the chip would
+refuse: a slice off the (8, 128) tiling, a kernel over its VMEM limit, a
+program over 16 GB.  Interpret mode shows none of that.  Nothing runs, so
+these say nothing about results or times — ``chip_smoke.py`` does, on the
+chip.
+
+All of these live in this ONE file, and the topology is described inside a
+module-scoped fixture: only the worker that is handed this file loads the
+TPU's library (one process at a time may hold it).  Shapes come from
+``jax.eval_shape``; the persistent compile cache is off around them (an
+entry written without a chip cannot be read back and would only warn).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+import flashmoe_tpu as fm
+from flashmoe_tpu.config import BENCH_CONFIGS
+from flashmoe_tpu.models.reference import init_moe_params
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here: nothing to ask
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _layer_shapes(cfg, params_sharding, x_sharding):
+    """(params, x) of one MoE layer as shapes: ``params_sharding`` maps a
+    parameter's name to its sharding."""
+    p = jax.eval_shape(lambda: init_moe_params(jax.random.PRNGKey(0), cfg))
+    p = {k: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                 sharding=params_sharding(k))
+         for k, a in p.items()}
+    x = jax.ShapeDtypeStruct((cfg.tokens, cfg.hidden_size), cfg.dtype,
+                             sharding=x_sharding)
+    return p, x
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled, compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel", ["router_pallas", "router_pallas_tiled"])
+@pytest.mark.parametrize("name", ["reference", "deepseek"])
+def test_gate_kernels_compile(one_chip, name, kernel):
+    from flashmoe_tpu.ops import gate
+
+    cfg = BENCH_CONFIGS[name].replace(ep=1)
+    x = jax.ShapeDtypeStruct((cfg.tokens, cfg.hidden_size), cfg.dtype,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((cfg.hidden_size, cfg.num_experts),
+                             cfg.param_dtype, sharding=one_chip)
+    _, text = _compile(
+        lambda x, w: getattr(gate, kernel)(x, w, cfg).combine_weights, x, w)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize(
+    "name", ["reference", "deepseek", "mixtral", "token_scaling"])
+def test_moe_layer_forward_compiles(one_chip, name):
+    """deepseek, mixtral and token_scaling are the widths whose grouped
+    FFN asked for more than Mosaic's 16 MiB of scoped VMEM before PR 22."""
+    cfg = BENCH_CONFIGS[name].replace(ep=1)
+    p, x = _layer_shapes(cfg, lambda k: one_chip, one_chip)
+    _, text = _compile(
+        lambda p, x: fm.moe_layer(p, x, cfg, use_pallas=True).out, p, x)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("name", ["reference", "mixtral"])
+def test_moe_layer_forward_and_grad_compiles(one_chip, name):
+    """The Pallas backward kernels (``grouped_matmul`` / ``tgmm``);
+    Mixtral's I=14336 is the width that forces ``grouped_matmul`` to chunk
+    its N axis."""
+    cfg = BENCH_CONFIGS[name].replace(ep=1, is_training=True)
+    p, x = _layer_shapes(cfg, lambda k: one_chip, one_chip)
+
+    def loss(p, x):
+        o = fm.moe_layer(p, x, cfg, use_pallas=True)
+        return (o.out.astype(jnp.float32) ** 2).mean() + o.aux_loss
+
+    _, text = _compile(jax.grad(loss), p, x)
+    assert text.count("tpu_custom_call") >= 4  # forward, dX, dW up and down
+
+
+def test_flash_attention_compiles_forward_and_grad(one_chip):
+    from flashmoe_tpu.ops.attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((1, 16, 4096, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    _, text = _compile(lambda q, k, v: flash_attention(q, k, v), q, q, q)
+    assert "tpu_custom_call" in text
+    # the trainer differentiates through it; the backward recomputes in
+    # XLA, so all that is asked here is that the gradient compiles at all
+    # (before PR 22 pallas_call's JVP rule raised an AssertionError)
+    _compile(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), q, q, q)
+
+
+@pytest.fixture(scope="module")
+def ep4(topo):
+    """reference config over a Mesh of the four described chips."""
+    from flashmoe_tpu.parallel.mesh import make_mesh
+
+    cfg = BENCH_CONFIGS["reference"].replace(ep=4)
+    mesh = make_mesh(cfg, dp=1, devices=topo.devices)
+    p, x = _layer_shapes(
+        cfg,
+        lambda k: NamedSharding(mesh, P() if k == "gate_w" else P("ep")),
+        NamedSharding(mesh, P("ep", None)))
+    return cfg, mesh, p, x
+
+
+def test_ep_moe_layer_compiles_on_four_chips(ep4):
+    from flashmoe_tpu.parallel.ep import ep_moe_layer
+
+    cfg, mesh, p, x = ep4
+    compiled, text = _compile(
+        lambda p, x: ep_moe_layer(p, x, cfg, mesh, use_pallas=True).out,
+        p, x)
+    assert "tpu_custom_call" in text
+    assert text.count("all-to-all(") == 2  # dispatch and combine
+    # each chip holds its 16 experts' weights, not all 64
+    per_chip = compiled.memory_analysis().argument_size_in_bytes
+    whole = sum(np.prod(a.shape) * a.dtype.itemsize for a in p.values())
+    assert per_chip < whole / 3
+
+
+def test_fused_ep_moe_layer_compiles_on_four_chips(ep4):
+    """The in-kernel RDMA path (the paper's kernel).  Before PR 22 Mosaic
+    refused its one-row bias DMA (``pl.ds(e, 1)`` of a [16, 2048] ref)."""
+    from flashmoe_tpu.parallel.fused import fused_ep_moe_layer
+
+    cfg, mesh, p, x = ep4
+    _, text = _compile(
+        lambda p, x: fused_ep_moe_layer(p, x, cfg, mesh, interpret=False,
+                                        use_pallas_gate=True).out, p, x)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.xfail(strict=True, raises=Exception, reason=(
+    "Mosaic refuses the gather-fused FFN's one-row DMAs "
+    "(ops/expert.py _ffn_gather_kernel, x_ref.at[pl.ds(tok, 1), :]): "
+    "'Slice shape along dimension 0 must be aligned to tiling (8), but "
+    "is 1.'  Both ends of the copy are tiled; a repair needs another "
+    "layout for the token rows, not a one-line change (ROADMAP S6)."))
+def test_gather_fused_ffn_is_still_refused(one_chip):
+    cfg = BENCH_CONFIGS["tiny"].replace(gather_fused=True)
+    p, x = _layer_shapes(cfg, lambda k: one_chip, one_chip)
+    _compile(lambda p, x: fm.moe_layer(p, x, cfg, use_pallas=True).out, p, x)
+
+
+@pytest.mark.xfail(strict=True, raises=ValueError, reason=(
+    "fused_ep_moe_layer at deepseek widths: 'intermediate 1408 not "
+    "divisible by 512' (parallel/fused.py _resolve_tiles takes "
+    "min(bi_cap, I), not a divisor of I) — raised before any lowering; "
+    "the planner's golden tables price that same geometry (ROADMAP S6)."))
+def test_fused_ep_moe_layer_deepseek_is_still_refused(topo):
+    from flashmoe_tpu.parallel.fused import fused_ep_moe_layer
+    from flashmoe_tpu.parallel.mesh import make_mesh
+
+    cfg = BENCH_CONFIGS["deepseek"].replace(ep=4)
+    mesh = make_mesh(cfg, dp=1, devices=topo.devices)
+    p, x = _layer_shapes(
+        cfg,
+        lambda k: NamedSharding(
+            mesh, P() if k == "gate_w" or k.startswith("shared")
+            else P("ep")),
+        NamedSharding(mesh, P("ep", None)))
+    _compile(lambda p, x: fused_ep_moe_layer(
+        p, x, cfg, mesh, interpret=False, use_pallas_gate=True).out, p, x)
+
+
+def test_train_step_compiles_at_chip_smoke_size(one_chip, topo):
+    """The step ``chip_smoke.py``'s train phase runs: flashmoe-reference
+    widths, batch 2 x 4096, f32 state with Adam moments — inside the 16 GB
+    the chip's compiler counts, with the Pallas kernels in it.  Steered to
+    the chip's branches here in the test: ``jax.default_backend()`` still
+    says "cpu" while compiling for a described device."""
+    import chip_smoke
+    from flashmoe_tpu.models.presets import PRESETS
+    from flashmoe_tpu.parallel.mesh import make_mesh
+    from flashmoe_tpu.runtime.trainer import (
+        init_state, make_optimizer, make_train_step,
+    )
+
+    cfg = PRESETS["flashmoe-reference"](
+        sequence_len=chip_smoke.TRAIN_SEQ, is_training=True)
+    mesh = make_mesh(cfg, devices=[topo.devices[0]])
+    opt = make_optimizer(cfg, total_steps=3)
+    state = jax.eval_shape(
+        lambda: init_state(jax.random.PRNGKey(0), cfg, opt))
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        state)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (chip_smoke.TRAIN_BATCH, cfg.sequence_len + 1), jnp.int32,
+        sharding=NamedSharding(mesh, P("dp", None)))}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = make_train_step(cfg, mesh, opt).lower(
+            state, batch).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert total < 15.75 * 2**30
+    assert compiled.as_text().count("tpu_custom_call") >= 4
