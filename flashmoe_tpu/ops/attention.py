@@ -168,6 +168,7 @@ def _flash_forward(q, k, v, *, causal: bool, scale: float | None,
             _flash_kernel, scale=scale, causal=causal,
             block_q=bq, block_k=bk,
         ),
+        name="fm_flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0),

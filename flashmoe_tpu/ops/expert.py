@@ -221,6 +221,7 @@ def grouped_ffn(x, tile_gid, w_up, b_up, w_down, b_down, w_gate=None, *,
     flops = 2 * t * h * i * (3 if gated else 2)
     return pl.pallas_call(
         functools.partial(_ffn_kernel, act_name=act_name, gated=gated),
+        name="fm_ffn_fwd",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, h), x.dtype),
         cost_estimate=pl.CostEstimate(
@@ -377,6 +378,7 @@ def grouped_ffn_tokens(x, src_tok, tile_gid, w_up, b_up, w_down, b_down,
     return pl.pallas_call(
         functools.partial(_ffn_gather_kernel, act_name=act_name, gated=gated,
                           block_m=block_m),
+        name="fm_ffn_fwd_gather",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, h), x.dtype),
         cost_estimate=pl.CostEstimate(
@@ -609,6 +611,7 @@ def grouped_matmul(x, tile_gid, w, *, transpose_w: bool = False,
     )
     return pl.pallas_call(
         functools.partial(_gmm_kernel, transpose_w=transpose_w),
+        name="fm_gmm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, n), out_dtype),
         cost_estimate=pl.CostEstimate(
@@ -684,6 +687,7 @@ def tgmm(x, dy, tile_gid, num_experts: int, *, block_m: int = BLOCK_M,
     )
     out = pl.pallas_call(
         _tgmm_kernel,
+        name="fm_tgmm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_experts, k, n), jnp.float32),
         cost_estimate=pl.CostEstimate(
@@ -804,6 +808,7 @@ def _grouped_ffn_res(x, tile_gid, w_up, b_up, w_down, b_down, w_gate, *,
     )
     y, u, g = pl.pallas_call(
         functools.partial(_ffn_res_kernel, act_name=act_name, gated=gated),
+        name="fm_ffn_fwd_res",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((t, h), x.dtype),

@@ -174,6 +174,7 @@ def router_pallas(x, gate_w, cfg: MoEConfig, interpret: bool = False
     grid = (s // bm,)
     top_p, top_i, stats = pl.pallas_call(
         functools.partial(_gate_kernel, k=k, e=e, px=px),
+        name="fm_router",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, h), lambda i: (i, 0), memory_space=pltpu.VMEM),
@@ -390,6 +391,7 @@ def _router_pallas_tiled_jit(x, gate_w, cfg: MoEConfig, interpret: bool,
     res = pl.pallas_call(
         functools.partial(_gate_pass1_kernel, k=k, e=e, et=et,
                           spill=need_stats),
+        name="fm_router_pass1",
         grid=(nt, nj),
         in_specs=[
             pl.BlockSpec((bm, h), lambda i, j: (i, 0),
@@ -419,6 +421,7 @@ def _router_pallas_tiled_jit(x, gate_w, cfg: MoEConfig, interpret: bool,
     if need_stats:
         stats = pl.pallas_call(
             functools.partial(_gate_pass2_kernel, k=k, e=e, et=et),
+            name="fm_router_pass2",
             grid=(nj, nt),
             in_specs=[
                 pl.BlockSpec((bm, et), lambda j, i: (i, j),

@@ -30,6 +30,7 @@ from flashmoe_tpu.ops import dispatch as dsp
 from flashmoe_tpu.ops import expert as exp
 from flashmoe_tpu.ops import ragged as rag
 from flashmoe_tpu.ops.gate import router
+from flashmoe_tpu.utils.telemetry import trace_span
 
 
 def _gather_fused(cfg: MoEConfig) -> bool:
@@ -87,8 +88,11 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
             if cfg.expert_quant is not None and cfg.collect_stats
             else None)
     params = qt.ffn_compute_params(params, cfg)
-    r = router(x, params["gate_w"], cfg, use_pallas=use_pallas,
-               interpret=interpret)
+    # the paper's four stages under the names the mesh paths use
+    # (parallel/ep.py): trace-time scopes, in every operation's op_name
+    with trace_span("moe.gate"):
+        r = router(x, params["gate_w"], cfg, use_pallas=use_pallas,
+                   interpret=interpret)
     s, h = x.shape
     dropless = use_pallas and not cfg.drop_tokens and capacity is None
     stats = None
@@ -109,7 +113,8 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
         # dropless: ragged expert-sorted grouping + block-sparse grouped FFN
         # (S*K + E*block rows instead of the capacity path's E*S)
         bm = BLOCK_M if s >= BLOCK_M else max(8, ((s + 7) // 8) * 8)
-        plan = rag.make_ragged_plan(r.expert_idx, cfg, bm)
+        with trace_span("moe.dispatch"):
+            plan = rag.make_ragged_plan(r.expert_idx, cfg, bm)
         # identical weight/config tail for both kernel entries, so the
         # training and inference arms cannot drift numerically
         ffn_tail = (
@@ -122,11 +127,16 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
         if not cfg.is_training and _gather_fused(cfg):
             # inference: gather fused into the kernel via the plan's
             # inverse map — no [T_pad, H] grouped buffer in HBM
-            ybuf = exp.grouped_ffn_tokens_ad(
-                x.astype(cfg.dtype), plan.src_tok, plan.tile_gid, *ffn_tail)
+            with trace_span("moe.expert"):
+                ybuf = exp.grouped_ffn_tokens_ad(
+                    x.astype(cfg.dtype), plan.src_tok, plan.tile_gid,
+                    *ffn_tail)
         else:
-            xbuf = rag.ragged_dispatch(x.astype(cfg.dtype), plan, cfg, bm)
-            ybuf = exp.grouped_ffn_ad(xbuf, plan.tile_gid, *ffn_tail)
+            with trace_span("moe.dispatch"):
+                xbuf = rag.ragged_dispatch(x.astype(cfg.dtype), plan, cfg,
+                                           bm)
+            with trace_span("moe.expert"):
+                ybuf = exp.grouped_ffn_ad(xbuf, plan.tile_gid, *ffn_tail)
         if degrade:
             # tier-0 (ops/health.py): ragged_combine does not
             # renormalize, so the mask renormalizes survivors itself
@@ -134,26 +144,31 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
                                               cfg.num_experts, bm)
             ybuf, combine_w = hlt.degrade_outputs(
                 ybuf, combine_w, r.expert_idx, healthy, renormalize=True)
-        out = rag.ragged_combine(ybuf, plan, combine_w, cfg)
+        with trace_span("moe.combine"):
+            out = rag.ragged_combine(ybuf, plan, combine_w, cfg)
     else:
         # capacity from the ACTUAL token count of this call, not the config's
         # nominal sequence length (callers pass batched shards of any size)
         cap = capacity if capacity is not None else cfg.capacity_for(s)
-        plan = dsp.make_plan(r.expert_idx, cfg, cap)
+        with trace_span("moe.dispatch"):
+            plan = dsp.make_plan(r.expert_idx, cfg, cap)
         if use_pallas and not cfg.is_training and _gather_fused(cfg):
             # inference: gather fused into the kernel — the [E, C, H]
             # dispatch buffer never hits HBM (training keeps the explicit
             # dispatch so the fused backward has its residuals)
-            ybuf, cap_p = exp.capacity_ffn_gather(
-                x.astype(cfg.dtype), plan, cfg, cap, params,
-                interpret=interpret)
+            with trace_span("moe.expert"):
+                ybuf, cap_p = exp.capacity_ffn_gather(
+                    x.astype(cfg.dtype), plan, cfg, cap, params,
+                    interpret=interpret)
         else:
-            xbuf = dsp.dispatch(x.astype(cfg.dtype), plan, cfg, cap)
-            if use_pallas:
-                ybuf = exp.capacity_buffer_ffn_ad(xbuf, params, cfg,
-                                                  interpret=interpret)
-            else:
-                ybuf = exp.expert_ffn_dense(xbuf, params, cfg)
+            with trace_span("moe.dispatch"):
+                xbuf = dsp.dispatch(x.astype(cfg.dtype), plan, cfg, cap)
+            with trace_span("moe.expert"):
+                if use_pallas:
+                    ybuf = exp.capacity_buffer_ffn_ad(xbuf, params, cfg,
+                                                      interpret=interpret)
+                else:
+                    ybuf = exp.expert_ffn_dense(xbuf, params, cfg)
             cap_p = cap
         from flashmoe_tpu.chaos import inject as chaos_inject
 
@@ -165,7 +180,8 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
             healthy = hlt.expert_health_capacity(ybuf)
             ybuf, combine_w = hlt.degrade_outputs(ybuf, combine_w,
                                                   r.expert_idx, healthy)
-        out = dsp.combine(ybuf, plan, combine_w, cfg, cap_p)
+        with trace_span("moe.combine"):
+            out = dsp.combine(ybuf, plan, combine_w, cfg, cap_p)
     if degrade and stats is not None:
         stats = hlt.attach_degradation(stats, healthy, r.expert_idx)
     if qerr is not None and stats is not None:
@@ -173,9 +189,9 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
 
         stats = with_quant_error(stats, qerr)
     if cfg.num_shared_experts:
-        out = out + shared_expert_ffn(x.astype(cfg.dtype), params, cfg).astype(
-            out.dtype
-        )
+        with trace_span("moe.shared"):
+            out = out + shared_expert_ffn(
+                x.astype(cfg.dtype), params, cfg).astype(out.dtype)
     return MoEOutput(
         out.astype(cfg.dtype),
         r.aux_loss * cfg.aux_loss_coef,

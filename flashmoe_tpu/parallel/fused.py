@@ -1681,6 +1681,7 @@ def _fused_shard(send_cnt, recv_cnt, src_order, x_send, w_up, b_up, w_down,
         )
     results = pl.pallas_call(
         kernel,
+        name="fm_fused_ep",
         grid=(d_world,),
         in_specs=in_specs,
         out_specs=out_specs,

@@ -19,6 +19,7 @@ import argparse
 import itertools
 import json
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -162,12 +163,27 @@ def main(argv=None) -> int:
                                   progress=progress,
                                   metrics_obj=metrics)
         history = []
+        # a step is timed from the completion of the one before to its
+        # own, with the next one already dispatched: the call itself
+        # returns at once (the first time holds the compile)
+        ahead = None
+        t_done = time.perf_counter()
+
+        def completed(waited_for):
+            nonlocal t_done
+            jax.block_until_ready(waited_for)
+            now = time.perf_counter()
+            metrics.times["step"].append(now - t_done)
+            t_done = now
+
         try:
             for i in range(args.steps):
                 if server is not None:
                     progress["step"] = i
-                with metrics.timer("step"):
-                    state, m = step(state, next(data))
+                state, m = step(state, next(data))
+                if ahead is not None:
+                    completed(ahead)
+                ahead = m["loss"]
                 if i % args.log_every == 0 or i == args.steps - 1:
                     # scalar-safe: array-valued metrics (per-expert
                     # stats when collect_stats is on) must not crash
@@ -176,6 +192,8 @@ def main(argv=None) -> int:
                     history.append(rec)
                     print(json.dumps({"step": i, **rec}),
                           file=sys.stderr)
+            if ahead is not None:
+                completed(ahead)
         finally:
             if server is not None:
                 server.stop()

@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from flashmoe_tpu.config import MoEConfig
 from flashmoe_tpu.models import transformer
 from flashmoe_tpu.parallel.mesh import transformer_param_specs
+from flashmoe_tpu.utils.telemetry import trace_span
 
 
 class TrainState(NamedTuple):
@@ -154,9 +155,14 @@ def make_train_step(cfg: MoEConfig, mesh: Mesh, optimizer,
         cfg = cfg.replace(is_training=True)
 
     def step_fn(state: TrainState, batch):
-        (loss, metrics), grads = jax.value_and_grad(
-            transformer.loss_fn, has_aux=True
-        )(state.params, batch, cfg, mesh, use_pallas)
+        with trace_span("train.forward_backward"):
+            (loss, metrics), grads = jax.value_and_grad(
+                transformer.loss_fn, has_aux=True
+            )(state.params, batch, cfg, mesh, use_pallas)
+        with trace_span("train.optimizer"):
+            return _apply(state, loss, metrics, grads)
+
+    def _apply(state: TrainState, loss, metrics, grads):
         from flashmoe_tpu.chaos import inject as chaos_inject
 
         if (chaos_inject.is_armed("nan_grad")
@@ -296,7 +302,9 @@ def train(cfg: MoEConfig, mesh: Mesh, data_iter, num_steps: int,
     import time
 
     from flashmoe_tpu.profiler import spans as prof
-    from flashmoe_tpu.utils.telemetry import FlightRecorder, metrics as tm
+    from flashmoe_tpu.utils.telemetry import (
+        FlightRecorder, compile_totals, metrics as tm, watch_compiles,
+    )
 
     key = key if key is not None else jax.random.PRNGKey(0)
     optimizer = make_optimizer(cfg, total_steps=num_steps)
@@ -309,6 +317,7 @@ def train(cfg: MoEConfig, mesh: Mesh, data_iter, num_steps: int,
     if flight_path is not None and recorder is None:
         recorder = FlightRecorder()
     watchdog = _as_watchdog(slo)
+    watch_compiles()
     history = []
     flushed = 0  # offset-aware export cursor (absolute record index)
     progress = {"step": 0}
@@ -333,6 +342,7 @@ def train(cfg: MoEConfig, mesh: Mesh, data_iter, num_steps: int,
                 # With a recorder every step is timed exactly; log-only runs
                 # time the logged step plus whatever backlog drained with it.
                 t0 = time.perf_counter()
+                compiles0, compile_s0 = compile_totals()
                 if tl is not None:
                     # an armed timeline gets per-step records; any phases
                     # measured inside (eager fenced runs — under jit the
@@ -367,6 +377,10 @@ def train(cfg: MoEConfig, mesh: Mesh, data_iter, num_steps: int,
                     rec = host_metrics(metrics,
                                        moe_layers=cfg.moe_layer_indices)
                     rec["step_ms"] = step_ms
+                    # which step compiled (a new shape, a rebuilt step)
+                    compiles1, compile_s1 = compile_totals()
+                    rec["compiles"] = int(compiles1 - compiles0)
+                    rec["compile_ms"] = (compile_s1 - compile_s0) * 1e3
                     if rec.get("grad_ok", 1.0) == 0.0:
                         # tier-1 guard fired: the skipped update is a
                         # structured decision so a postmortem can answer

@@ -68,7 +68,9 @@ from flashmoe_tpu.serving.speculate import (
     DraftState, SpecConfig, spec_stats_fields,
 )
 from flashmoe_tpu.utils.telemetry import metrics as _global_metrics
-from flashmoe_tpu.utils.telemetry import trace_span
+from flashmoe_tpu.utils.telemetry import (
+    compile_totals, trace_span, watch_compiles,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -204,6 +206,13 @@ class _QueueEntry:
                                    # not accrue synthetic queue wait
     first_token_s: float | None    # survives eviction: the client
                                    # already holds the first token
+    # ---- the request's account, carried across evictions -------------
+    queued_s: float | None = None  # when THIS wait began (None: the
+                                   # first wait, which began at arrival)
+    waited_ms: float = 0.0         # queue waits already served
+    prefill_ms: float | None = None
+    last_token_s: float | None = None
+    gap_max_ms: float = 0.0
 
 
 @dataclasses.dataclass
@@ -227,6 +236,14 @@ class _Slot:
                                     # survives eviction and migration
     spec_drafted: int = 0           # drafts proposed this incarnation
     spec_accepted: int = 0          # ... and accepted (= canonical)
+    # ---- the request's account (engine clock; all but admit_s survive
+    # eviction through the queue entry) --------------------------------
+    admit_s: float = 0.0            # this incarnation's admission
+    queue_wait_ms: float = 0.0      # arrival to admission, summed over
+                                    # re-admissions
+    prefill_ms: float | None = None  # admission to first token
+    last_token_s: float | None = None
+    gap_max_ms: float = 0.0         # widest gap between two tokens
 
 
 # ----------------------------------------------------------------------
@@ -778,6 +795,13 @@ class ServingEngine:
         self._vclock = (clock if hasattr(clock, "complete_step")
                         else None)
         self._heartbeat = heartbeat_fn
+        # ---- the step's own account (see _phase) ---------------------
+        self._phase_open = None     # (span, name, opened at)
+        self._phase_ms: dict = {}   # this step's phases, by name
+        self._delivered_now: dict = {}   # rid -> tokens this step
+        self._ctx_pages = (0, 0.0, 0)   # this step's decode program:
+                                        # pages gathered, idle, slots
+        watch_compiles()
         # ---- live telemetry plane (default off = zero threads, no
         # behavior change; outputs are bit-identical either way) ------
         self.tracer = None
@@ -1141,6 +1165,18 @@ class ServingEngine:
                 break                      # head-of-line: deterministic
             pages = self._alloc_pages(slot, n_pages)
             self.queue.popleft()
+            # the request's account: this wait ends here (a later one in
+            # the same step also waited through its neighbour's prefill)
+            admit_s = self._clock()
+            wait_ms = max(0.0, admit_s - (
+                entry.queued_s if entry.queued_s is not None
+                else entry.arrival_s)) * 1e3
+            self.metrics.sketch("serve.queue_wait_ms", wait_ms)
+            account = dict(
+                admit_s=admit_s, queue_wait_ms=entry.waited_ms + wait_ms,
+                prefill_ms=entry.prefill_ms,
+                last_token_s=entry.last_token_s,
+                gap_max_ms=entry.gap_max_ms)
             if self.tracer is not None:
                 # closes the queued span and arms prefill attribution
                 # for the trace_span below
@@ -1158,7 +1194,7 @@ class ServingEngine:
                     emitted=[], admit_step=self.step_idx,
                     arrival_s=entry.arrival_s,
                     first_token_s=entry.first_token_s,
-                    prefill_pos=0, prefill_toks=toks)
+                    prefill_pos=0, prefill_toks=toks, **account)
                 self.stats["prefill_buckets"].add(chunk)
             else:
                 prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
@@ -1186,7 +1222,7 @@ class ServingEngine:
                     req=req, orig=orig, pages=list(pages), length=t0,
                     emitted=[], admit_step=self.step_idx,
                     arrival_s=entry.arrival_s,
-                    first_token_s=entry.first_token_s)
+                    first_token_s=entry.first_token_s, **account)
                 self.stats["prefill_buckets"].add(t_pad)
             self._rates["admits"].add()
             self.metrics.decision(
@@ -1294,7 +1330,9 @@ class ServingEngine:
         # holds the delivered tokens — TTFT/TPOT must not restart)
         self.queue.appendleft(_QueueEntry(
             self.step_idx, resumed, s.orig, s.arrival_s,
-            s.first_token_s))
+            s.first_token_s, queued_s=self._clock(),
+            waited_ms=s.queue_wait_ms, prefill_ms=s.prefill_ms,
+            last_token_s=s.last_token_s, gap_max_ms=s.gap_max_ms))
         self.slots[victim] = None
         self.stats["evictions"] += 1
         self._rates["evictions"].add()
@@ -1365,36 +1403,39 @@ class ServingEngine:
         k = spec.draft_tokens
         # ---- draft (host-only: per-slot suffix-match tables) ---------
         drafts: dict[int, list] = {}
-        with trace_span("serve.draft"):
-            for i in active:
-                s = self.slots[i]
-                hist = list(s.req.prompt) + s.emitted
-                if s.draft is None:
-                    # deterministic rebuild from prompt + emitted: the
-                    # same history the eviction / migration resume
-                    # carries, so speculation survives both for free
-                    s.draft = DraftState(spec, hist)
-                else:
-                    s.draft.sync(hist)
-                dr = s.draft.draft(k)
-                # truncate to the remaining token budget and the
-                # context ceiling: every ACCEPTED draft's KV row must
-                # land in a real page
-                dr = dr[:max(0, s.orig.max_new_tokens
-                             - self._delivered(s))]
-                dr = dr[:max(0, sv.max_context - 1 - s.length)]
-                if dr:
-                    drafts[i] = [int(t) for t in dr]
+        self._phase("serve.draft")
+        for i in active:
+            s = self.slots[i]
+            hist = list(s.req.prompt) + s.emitted
+            if s.draft is None:
+                # deterministic rebuild from prompt + emitted: the
+                # same history the eviction / migration resume
+                # carries, so speculation survives both for free
+                s.draft = DraftState(spec, hist)
+            else:
+                s.draft.sync(hist)
+            dr = s.draft.draft(k)
+            # truncate to the remaining token budget and the
+            # context ceiling: every ACCEPTED draft's KV row must
+            # land in a real page
+            dr = dr[:max(0, s.orig.max_new_tokens
+                         - self._delivered(s))]
+            dr = dr[:max(0, sv.max_context - 1 - s.length)]
+            if dr:
+                drafts[i] = [int(t) for t in dr]
         if not drafts:
             return None
 
         # pre-cover the span's write positions (may evict — re-fetch)
+        self._phase("serve.grow")
         self._grow_pages(span=k)
         active = self._decoding()
         if not active:
             return 0
 
         # ---- verify: score k+1 positions per slot in one forward ----
+        # (the drafted positions' sampling keys are built with the feed)
+        self._phase("serve.decode_feed")
         t_span = k + 1
         feed = np.full((sv.max_batch, t_span), sv.pad_token, np.int32)
         positions = np.zeros((sv.max_batch,), np.int32)
@@ -1405,6 +1446,7 @@ class ServingEngine:
         tps = np.ones((sv.max_batch, k), np.float32)
         keys = np.zeros((sv.max_batch, k, 2), np.uint32)
         longest = 1
+        own_pages = 0
         for i in active:
             s = self.slots[i]
             feed[i, 0] = s.emitted[-1]
@@ -1413,6 +1455,7 @@ class ServingEngine:
             positions[i] = s.length
             tables[i, :len(s.pages)] = s.pages
             longest = max(longest, s.length + t_span)
+            own_pages += (s.length + t_span - 1) // sv.page_size + 1
             r = s.req
             temps[i] = r.temperature
             tks[i] = r.top_k
@@ -1426,25 +1469,27 @@ class ServingEngine:
                                  sv.ctx_bucket_pages,
                                  sv.max_pages_per_slot)
         self.stats["decode_buckets"].add(n_ctx)
-        with trace_span("serve.verify"):
-            if self._ep_fn is not None:
-                if self._ep_verify is None:
-                    self._ep_verify = _ep_verify_fn(
-                        self.mesh, self.cfg, self.params)
-                span_logits, kp, vp = self._ep_verify(
-                    self.params, self.cache.k_pages,
-                    self.cache.v_pages, jnp.asarray(feed),
-                    jnp.asarray(tables[:, :n_ctx]),
-                    jnp.asarray(positions))
-            else:
-                span_logits, kp, vp = _paged_verify_step(
-                    self.params, self.cfg, self.cache.k_pages,
-                    self.cache.v_pages, jnp.asarray(feed),
-                    jnp.asarray(tables[:, :n_ctx]),
-                    jnp.asarray(positions))
+        self._note_ctx(n_ctx, own_pages, len(active))
+        self._phase("serve.verify")
+        if self._ep_fn is not None:
+            if self._ep_verify is None:
+                self._ep_verify = _ep_verify_fn(
+                    self.mesh, self.cfg, self.params)
+            span_logits, kp, vp = self._ep_verify(
+                self.params, self.cache.k_pages,
+                self.cache.v_pages, jnp.asarray(feed),
+                jnp.asarray(tables[:, :n_ctx]),
+                jnp.asarray(positions))
+        else:
+            span_logits, kp, vp = _paged_verify_step(
+                self.params, self.cfg, self.cache.k_pages,
+                self.cache.v_pages, jnp.asarray(feed),
+                jnp.asarray(tables[:, :n_ctx]),
+                jnp.asarray(positions))
         self.cache = self.cache._replace(k_pages=kp, v_pages=vp)
         self._spec_steps += 1
 
+        self._phase("serve.sample")
         # canonical samples for every drafted position: column t-1
         # logits, position-(base+t-1) key, the same sampler numerics
         cand = np.asarray(_sample_dynamic(
@@ -1455,6 +1500,7 @@ class ServingEngine:
             jnp.asarray(tps.reshape(-1)))).reshape(sv.max_batch, k)
 
         # ---- accept the agreeing prefix; roll back the rest ----------
+        self._phase("serve.deliver")
         n_extra = 0
         accepted_cols = np.zeros((sv.max_batch,), np.int32)
         for i in active:
@@ -1478,6 +1524,10 @@ class ServingEngine:
             self._spec_accepted += a
             s.spec_accepted += a
             accepted_cols[i] = a
+            if a and self.recorder is not None:
+                rid = s.orig.rid
+                self._delivered_now[rid] = \
+                    self._delivered_now.get(rid, 0) + a
             s.length += 1 + a
             # roll back the block table past the accepted frontier:
             # rejected-draft rows free their surplus pages (LIFO, so
@@ -1566,17 +1616,23 @@ class ServingEngine:
                 "accept_rate": (round(s.spec_accepted / s.spec_drafted,
                                       6) if s.spec_drafted else None),
             }
+        account = {
+            "queue_wait_ms": round(s.queue_wait_ms, 3),
+            "prefill_ms": (round(s.prefill_ms, 3)
+                           if s.prefill_ms is not None else None),
+            "gap_max_ms": round(s.gap_max_ms, 3),
+        }
         self.metrics.decision(
             "serve.retire", rid=s.orig.rid, step=self.step_idx,
             slot=slot, tokens=n_tok,
             ttft_ms=round(ttft_ms, 3) if ttft_ms is not None else None,
             tpot_ms=round(tpot_ms, 3) if tpot_ms is not None else None,
-            **spec_kw)
+            **account, **spec_kw)
         if self.recorder is not None:
             self.recorder.record(
                 kind="serve_request", step=self.step_idx,
                 rid=s.orig.rid, tokens=n_tok, ttft_ms=ttft_ms,
-                tpot_ms=tpot_ms, **spec_kw)
+                tpot_ms=tpot_ms, **account, **spec_kw)
         if self.watchdog is not None:
             dominant = None
             if self.tracer is not None:
@@ -1596,12 +1652,54 @@ class ServingEngine:
 
     # ---- the engine step ---------------------------------------------
 
+    def _phase(self, name, beat=None) -> float:
+        """A phase boundary of :meth:`step`.  ONE read of the engine's
+        clock closes the running phase (its time is added to this
+        step's ``phase_ms`` under its name) and opens ``name`` (``None``:
+        nothing) as a :func:`trace_span`, so the phase is on the
+        profiler's clock too; ``beat`` goes to the heartbeat seam.
+        Returns the boundary's time."""
+        now = self._clock()
+        if self._phase_open is not None:
+            span, was, opened = self._phase_open
+            span.__exit__(None, None, None)
+            self._phase_ms[was] = (self._phase_ms.get(was, 0.0)
+                                   + (now - opened) * 1e3)
+            self._phase_open = None
+        if name is not None:
+            span = trace_span(name)  # staticcheck: ok the lint checks the literal at every _phase(...) call
+            span.__enter__()
+            self._phase_open = (span, name, now)
+        if beat is not None and self._heartbeat is not None:
+            self._heartbeat(beat)
+        return now
+
+    def _note_ctx(self, n_ctx: int, own_pages: int, slots: int) -> None:
+        """What this step's decode or verify program gathers: ``n_ctx``
+        pages a slot, of which the ``slots`` decoding slots' own
+        contexts fill ``own_pages`` in all (a span drafted up to the
+        context ceiling counts pages past it: never under 0 idle)."""
+        self._ctx_pages = (n_ctx, max(0.0, n_ctx - own_pages / slots),
+                           slots)
+
     def step(self) -> dict:
         """One engine iteration: admit -> sample/retire -> decode.
         Returns the step's flight record (also appended to the
         recorder when one is attached)."""
-        t0_s = self._clock()
+        with trace_span("serve.step"):
+            try:
+                return self._step()
+            finally:
+                if self._phase_open is not None:    # the step raised
+                    self._phase(None)
+
+    def _step(self) -> dict:
         sv = self.serve
+        compiles0, compile_s0 = compile_totals()
+        self._phase_ms = {}
+        self._delivered_now = {}
+        self._ctx_pages = (0, 0.0, 0)
+        t0_s = self._phase("serve.admit")
         if self.tracer is not None:
             # open the step window BEFORE admissions: everything in
             # this step (a neighbour's prefill compile included) rides
@@ -1611,14 +1709,12 @@ class ServingEngine:
                 [self.slots[i].orig.rid for i in self._active()])
         self._mark_arrivals()
         self._admit()
-        if self._heartbeat is not None:
-            self._heartbeat("admit")
+        self._phase("serve.prefill_advance", beat="admit")
         self._advance_prefill()
-        if self._heartbeat is not None:
-            self._heartbeat("prefill")
 
         # sample each decoding slot's next token from its pending
         # logits (slots mid-chunked-prefill have none yet)
+        self._phase("serve.sample_keys", beat="prefill")
         emitted_now = 0
         active = self._decoding()
         if active:
@@ -1634,10 +1730,11 @@ class ServingEngine:
                 keys[i] = np.asarray(jax.random.fold_in(
                     jax.random.PRNGKey(r.seed),
                     self._delivered(self.slots[i])))
+            self._phase("serve.sample")
             toks = np.asarray(_sample_dynamic(
                 self._logits, jnp.asarray(keys),
                 jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps)))
-            now = self._clock()
+            now = self._phase("serve.deliver")
             for i in active:
                 s = self.slots[i]
                 tok = int(toks[i])
@@ -1645,17 +1742,24 @@ class ServingEngine:
                 emitted_now += 1
                 if s.first_token_s is None:
                     s.first_token_s = now
+                    s.prefill_ms = (now - s.admit_s) * 1e3
+                elif s.last_token_s is not None:
+                    gap_ms = (now - s.last_token_s) * 1e3
+                    if gap_ms > s.gap_max_ms:
+                        s.gap_max_ms = gap_ms
+                s.last_token_s = now
+                if self.recorder is not None:
+                    self._delivered_now[s.orig.rid] = 1
                 done = (tok in s.req.stop_tokens
                         or self._delivered(s) >= s.orig.max_new_tokens)
                 if done:
                     self._retire(i, s)
         self.stats["tokens"] += emitted_now
-        if self._heartbeat is not None:
-            self._heartbeat("sample")
 
         # feed the survivors one decode step — speculative (draft +
         # span verify, possibly emitting extra tokens) when armed and
         # anything drafted, else the plain one-token step
+        self._phase("serve.grow", beat="sample")
         active = self._decoding()
         if active:
             self._grow_pages()
@@ -1667,42 +1771,45 @@ class ServingEngine:
                 emitted_now += n_extra
                 self.stats["tokens"] += n_extra
         if active and n_extra is None:
+            self._phase("serve.decode_feed")
             feed = np.full((sv.max_batch,), sv.pad_token, np.int32)
             positions = np.zeros((sv.max_batch,), np.int32)
             tables = np.full((sv.max_batch, sv.max_pages_per_slot),
                              SCRATCH_PAGE, np.int32)
             longest = 1
+            own_pages = 0
             for i in active:
                 s = self.slots[i]
                 feed[i] = s.emitted[-1]
                 positions[i] = s.length
                 tables[i, :len(s.pages)] = s.pages
                 longest = max(longest, s.length + 1)
+                own_pages += s.length // sv.page_size + 1
             n_ctx = ctx_pages_bucket(longest, sv.page_size,
                                      sv.ctx_bucket_pages,
                                      sv.max_pages_per_slot)
             self.stats["decode_buckets"].add(n_ctx)
-            with trace_span("serve.decode"):
-                if self._ep_fn is not None:
-                    logits, kp, vp = self._ep_fn(
-                        self.params, self.cache.k_pages,
-                        self.cache.v_pages, jnp.asarray(feed),
-                        jnp.asarray(tables[:, :n_ctx]),
-                        jnp.asarray(positions))
-                else:
-                    logits, kp, vp = _paged_decode_step(
-                        self.params, self.cfg, self.cache.k_pages,
-                        self.cache.v_pages, jnp.asarray(feed),
-                        jnp.asarray(tables[:, :n_ctx]),
-                        jnp.asarray(positions))
+            self._note_ctx(n_ctx, own_pages, len(active))
+            self._phase("serve.decode")
+            if self._ep_fn is not None:
+                logits, kp, vp = self._ep_fn(
+                    self.params, self.cache.k_pages,
+                    self.cache.v_pages, jnp.asarray(feed),
+                    jnp.asarray(tables[:, :n_ctx]),
+                    jnp.asarray(positions))
+            else:
+                logits, kp, vp = _paged_decode_step(
+                    self.params, self.cfg, self.cache.k_pages,
+                    self.cache.v_pages, jnp.asarray(feed),
+                    jnp.asarray(tables[:, :n_ctx]),
+                    jnp.asarray(positions))
             self._logits = logits
             self.cache = self.cache._replace(k_pages=kp, v_pages=vp)
             for i in active:
                 self.slots[i].length += 1
-        if self._heartbeat is not None:
-            self._heartbeat("decode")
 
         # telemetry
+        self._phase("serve.account", beat="decode")
         if self._vclock is not None:
             # charge the decode tick INSIDE the step window (before
             # end_step closes it): virtual step duration becomes
@@ -1711,7 +1818,6 @@ class ServingEngine:
             self._vclock.complete_step()
         if self.tracer is not None:
             self.tracer.end_step()
-        step_ms = (self._clock() - t0_s) * 1e3
         n_active = len(self._active())
         qd = len(self.queue)
         occ = self.pool.occupancy
@@ -1725,7 +1831,6 @@ class ServingEngine:
         self.metrics.gauge("serve.active_requests", n_active)
         self.metrics.gauge("serve.cache_occupancy", occ)
         # rolling distributions + windowed rates for the live scrape
-        self.metrics.sketch("serve.step_ms", step_ms)
         self.metrics.sketch("serve.queue_depth_dist", qd)
         self.metrics.gauge("serve.tokens_per_s",
                            self._rates["tokens"].add(emitted_now))
@@ -1733,6 +1838,18 @@ class ServingEngine:
                            self._rates["admits"].rate())
         self.metrics.gauge("serve.evictions_per_s",
                            self._rates["evictions"].rate())
+        for name, ms in self._phase_ms.items():
+            self.metrics.sketch(f"serve.phase.{name[6:]}_ms", ms)
+        # the step ends HERE on the engine's clock: its phases add up to
+        # step_ms, and what follows (two sketches, the record) is the
+        # only part of step() that no phase holds
+        t1_s = self._phase(None)
+        step_ms = (t1_s - t0_s) * 1e3
+        self.metrics.sketch("serve.step_ms", step_ms)
+        self.metrics.sketch("serve.phase.account_ms",
+                            self._phase_ms["serve.account"])
+        compiles1, compile_s1 = compile_totals()
+        ctx_pages, ctx_idle, n_decoding = self._ctx_pages
         rec = {
             "kind": "serve_step", "step": self.step_idx,
             "active": n_active, "queue_depth": qd,
@@ -1741,12 +1858,30 @@ class ServingEngine:
             "tokens": emitted_now,
             "completed": self.stats["completed"],
             "step_ms": round(step_ms, 3),
+            "t0_s": t0_s, "t1_s": t1_s,
+            "phase_ms": {k: round(v, 3)
+                         for k, v in self._phase_ms.items()},
+            "compiles": int(compiles1 - compiles0),
+            "compile_ms": round((compile_s1 - compile_s0) * 1e3, 3),
+            "ctx_pages": ctx_pages,
+            "ctx_pages_idle": round(ctx_idle, 3),
         }
         if self.serve.speculate is not None:
             rec["spec_tokens"] = int(n_extra or 0)
             rec["spec_on"] = self._spec is not None
         if self.recorder is not None:
+            # every token of the step has the time t1_s: the per-token
+            # gaps are a reduction of these records
+            rec["delivered"] = [[rid, n] for rid, n
+                                in self._delivered_now.items()]
             self.recorder.record(**rec)
+            if ctx_pages:
+                # the decode program's shape, one record per step that
+                # ran it: a mean over these is a mean over decode steps
+                self.recorder.record(
+                    kind="serve_decode", step=self.step_idx,
+                    slots=n_decoding, ctx_pages=ctx_pages,
+                    ctx_pages_idle=rec["ctx_pages_idle"])
         if self.watchdog is not None:
             self.watchdog.observe_step(self.step_idx, step_ms)
         self.step_idx += 1

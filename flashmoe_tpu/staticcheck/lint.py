@@ -317,7 +317,9 @@ def check_span_names(files=None) -> list[Violation]:
             f = node.func
             attr = f.attr if isinstance(f, ast.Attribute) else (
                 f.id if isinstance(f, ast.Name) else None)
-            if attr not in ("trace_span", "section"):
+            # _phase: the serving engine's phase boundary, which opens
+            # a trace_span under the name it is handed
+            if attr not in ("trace_span", "section", "_phase"):
                 continue
             line = lines[node.lineno - 1] if node.lineno <= len(
                 lines) else ""
@@ -344,7 +346,10 @@ def check_span_names(files=None) -> list[Violation]:
                         "base name followed by '.' (chunk-suffix "
                         "convention) — the registry cannot vouch for "
                         "a computed prefix"))
-            elif attr == "trace_span":
+            elif attr == "trace_span" or (
+                    attr == "_phase" and not (
+                        isinstance(arg, ast.Constant)
+                        and arg.value is None)):
                 out.append(Violation(
                     "lint", "span-name", f"{rel}:{node.lineno}",
                     "non-literal span name: the registry cannot vouch "

@@ -25,7 +25,8 @@ producing a contiguous per-request list of child spans:
 * ``serve.decode`` — one span per decode step the request participated
   in, attributed through the same hook;
 * ``serve.step`` — the full engine-step window every active request
-  rode (begin_step → end_step): it covers the host work BETWEEN the
+  rode (begin_step → end_step; for a request that also rode the step
+  before, from that window's close): it covers the host work BETWEEN the
   jitted spans (sampling, page growth, first-call compiles), which is
   what makes a retired request's track contiguous rather than a comb
   of device slices with unexplained holes.
@@ -91,6 +92,10 @@ class RequestTracer:
         self._step: int | None = None
         self._step_t0: float | None = None
         self._joined_at: dict[int, float] = {}
+        #: rid -> when the last step window it rode closed; its next
+        #: window opens there, so the time BETWEEN two steps (the
+        #: caller's own work, a scrape) is on the track as waiting
+        self._rode_until: dict[int, float] = {}
         self._pending_retires: list = []
 
     # ---- clock --------------------------------------------------------
@@ -204,6 +209,7 @@ class RequestTracer:
             self._active_rids = tuple(r for r in self._active_rids
                                       if r != rid)
             self._joined_at.pop(rid, None)
+        self._rode_until.pop(rid, None)
         st.open_queued = now
 
     def begin_step(self, step: int, active_rids) -> None:
@@ -214,16 +220,21 @@ class RequestTracer:
         gets a ``serve.step`` window span when the step closes.  The
         window opening before ``_admit`` is what keeps a neighbour's
         prefill (or its first-call compile) from punching a hole in
-        every other active request's track."""
+        every other active request's track.  A request that also rode
+        the step before waited between the two: its window opens where
+        that one closed."""
         self._step = int(step)
         self._active_rids = tuple(int(r) for r in active_rids)
         self._prefill_rid = None
         self._step_t0 = self._now_ms()
-        self._joined_at: dict[int, float] = {}
+        self._joined_at = {}
         for rid in self._active_rids:
             st = self.requests.get(rid)
             if st is not None:
                 st.steps += 1
+            rode = self._rode_until.pop(rid, None)
+            if rode is not None:
+                self._joined_at[rid] = rode
 
     def end_step(self) -> None:
         """Close the engine-step window: every request that rode this
@@ -240,6 +251,9 @@ class RequestTracer:
         for rid in self._active_rids:
             t0 = self._joined_at.get(rid, self._step_t0)
             self._span(rid, "serve.step", t0, now)
+            st = self.requests.get(rid)
+            if st is not None and not st.retired:
+                self._rode_until[rid] = now
         self._step_t0 = None
         self._active_rids = ()
         self._joined_at = {}
@@ -254,6 +268,7 @@ class RequestTracer:
             return
         st.retired = True
         st.t_last = self._now_ms()
+        self._rode_until.pop(rid, None)
         fields = {"tokens": tokens, "ttft_ms": ttft_ms,
                   "tpot_ms": tpot_ms}
         if self._step_t0 is not None:

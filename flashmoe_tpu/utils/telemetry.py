@@ -232,6 +232,7 @@ SPAN_NAMES: dict[str, str] = {
         "return all-to-all (``.k`` suffix = pipeline chunk k)",
     "moe.combine": "weighted gather back to token order",
     "moe.fused_kernel": "fused RDMA kernel (dispatch+FFN in one launch)",
+    "moe.shared": "shared experts: the dense FFN every token takes",
     "serve.prefill":
         "serving engine: single-pass prompt prefill into cache pages",
     "serve.prefill_chunk":
@@ -256,10 +257,39 @@ SPAN_NAMES: dict[str, str] = {
         "request trace: the parent span of one request's whole "
         "lifecycle (trace_id minted at serve.admit)",
     "serve.step":
-        "request trace: the full engine-step window a request rode "
-        "(covers host sampling/compile between the jitted spans)",
+        "one whole engine step, the parent of the step's phases; on a "
+        "request's trace, the step window the request rode (it opens "
+        "where the request's last window closed, so the time between "
+        "two steps is on the track too)",
+    "serve.admit":
+        "step phase: arrivals stamped and queued requests admitted "
+        "(``serve.prefill`` runs inside it)",
+    "serve.prefill_advance":
+        "step phase: one chunk of every prompt mid chunked prefill",
+    "serve.sample_keys":
+        "step phase: per-slot temperature, top-k/top-p and sampling "
+        "key on the host (the key's read-back waits for the decode "
+        "step dispatched before)",
+    "serve.sample":
+        "step phase: the sampler program through the tokens on the host",
+    "serve.deliver":
+        "step phase: tokens appended, first-token stamps, retirements",
+    "serve.grow":
+        "step phase: next KV page for every slot at a page edge "
+        "(evictions happen here)",
+    "serve.decode_feed":
+        "step phase: the decode or verify step's feed, positions and "
+        "block tables built on the host",
+    "serve.account":
+        "step phase: gauges, sketches and the step's flight record",
     "train.data_pull": "host wait on the data iterator",
     "train.step": "one train step: dispatch + device execution",
+    "train.forward_backward":
+        "in the compiled step: loss and gradients (jax marks the "
+        "backward's operations ``transpose(...)`` inside it)",
+    "train.optimizer":
+        "in the compiled step: gradient norm, optimizer update, new "
+        "parameters",
     "train.checkpoint": "checkpoint save on the step loop",
     "train.drain": "graceful preemption drain (final save + cursor)",
 }
@@ -334,6 +364,37 @@ def trace_span(name: str):
     finally:
         if lst is not None:
             lst.span_exit(name, tok)
+
+
+#: jax.monitoring's name for one backend compile request (a load from
+#: the persistent cache counts: it is a program the call did not have)
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listening: list = [False]
+
+
+def watch_compiles() -> None:
+    """Count every backend compile of the process into the global
+    :data:`metrics` (``compile.count``, ``compile.seconds``).  Installs
+    ONE ``jax.monitoring`` listener, however often it is called; the
+    serving engine and the train loops call it when they are built and
+    report each step's share (:func:`compile_totals` before and after)
+    as ``compiles`` / ``compile_ms`` in their step records."""
+    if _compile_listening[0]:
+        return
+    _compile_listening[0] = True
+
+    def on_duration(event, duration_secs, **_):
+        if event == _COMPILE_EVENT:
+            metrics.counters["compile.count"] += 1
+            metrics.counters["compile.seconds"] += duration_secs
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
+def compile_totals() -> tuple[float, float]:
+    """(compiles, seconds spent in them) since :func:`watch_compiles`."""
+    c = metrics.counters
+    return c.get("compile.count", 0.0), c.get("compile.seconds", 0.0)
 
 
 def start_trace(log_dir: str):

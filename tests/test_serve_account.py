@@ -1,0 +1,409 @@
+"""The engine's own account of a step and of a request, and the stage
+scopes of the one-chip layer — all on the ``clock=`` seam with a stepped
+fake clock: nothing here sleeps or reads the wall clock."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from flashmoe_tpu.config import MoEConfig
+from flashmoe_tpu.models.transformer import init_params
+from flashmoe_tpu.serving.engine import (
+    Request, ServeConfig, ServingEngine,
+)
+from flashmoe_tpu.serving.loadgen import tiny_config
+from flashmoe_tpu.utils import telemetry
+from flashmoe_tpu.utils.telemetry import (
+    SPAN_NAMES, FlightRecorder, Metrics,
+)
+
+CFG = tiny_config()
+SERVE = ServeConfig(max_batch=4, page_size=8, num_pages=32,
+                    max_pages_per_slot=4, ctx_bucket_pages=1,
+                    prompt_bucket=8)
+
+
+class Ticking:
+    """Every read is ``tick`` seconds after the one before."""
+
+    def __init__(self, tick=0.001):
+        self.t, self.tick = 0.0, tick
+
+    def __call__(self):
+        self.t += self.tick
+        return self.t
+
+
+class Stepped:
+    """Stands still until the test moves it."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(jax.random.PRNGKey(0), CFG)
+
+
+def _prompt(rid, n=8):
+    return tuple(int(t) for t in jax.random.randint(
+        jax.random.PRNGKey(100 + rid), (n,), 0, CFG.vocab_size))
+
+
+def _req(rid, n=8, max_new=4, **kw):
+    return Request(rid=rid, prompt=_prompt(rid, n), max_new_tokens=max_new,
+                   **kw)
+
+
+def _drive(engine, clock=None, dt=1.0, before_step=None):
+    """Step to completion, moving a :class:`Stepped` clock by ``dt``
+    after every step; returns the step records."""
+    recs = []
+    while engine.pending():
+        if before_step is not None:
+            before_step(engine.step_idx)
+        recs.append(engine.step())
+        if clock is not None:
+            clock.t += dt
+    return recs
+
+
+class _SpanLog:
+    """A span listener that writes down enters and exits in order."""
+
+    def __init__(self):
+        self.events = []
+
+    def span_enter(self, name):
+        self.events.append(("enter", name))
+        return name
+
+    def span_exit(self, name, tok):
+        self.events.append(("exit", name))
+
+
+def test_phases_are_registered_sum_to_step_and_nest(params):
+    mx, log = Metrics(), _SpanLog()
+    engine = ServingEngine(params, CFG, SERVE, metrics_obj=mx,
+                           clock=Ticking(), recorder=FlightRecorder())
+    telemetry.set_span_listener(log)
+    try:
+        for rid in range(3):
+            engine.submit(_req(rid))
+        recs = _drive(engine)
+    finally:
+        telemetry.set_span_listener(None)
+    assert len(recs) >= 4
+    seen = set()
+    for rec in recs:
+        phases = rec["phase_ms"]
+        assert set(phases) <= set(SPAN_NAMES), set(phases) - set(SPAN_NAMES)
+        assert all(k.startswith("serve.") for k in phases)
+        assert rec["t1_s"] > rec["t0_s"]
+        assert rec["step_ms"] == pytest.approx(
+            (rec["t1_s"] - rec["t0_s"]) * 1e3, abs=2e-3)
+        assert sum(phases.values()) == pytest.approx(rec["step_ms"],
+                                                     rel=0.01)
+        seen |= set(phases)
+    # a step that decodes walks every phase of the plain path
+    assert seen == {"serve.admit", "serve.prefill_advance",
+                    "serve.sample_keys", "serve.sample", "serve.deliver",
+                    "serve.grow", "serve.decode_feed", "serve.decode",
+                    "serve.account"}
+    # each phase feeds the scrape's sketch, once a step it ran in
+    for name in seen:
+        sk = mx.sketches[f"serve.phase.{name[6:]}_ms"]
+        assert sk.n == sum(name in r["phase_ms"] for r in recs)
+    assert "flashmoe_serve_phase_sample_keys_ms" in mx.prometheus_text()
+
+    # nesting: one serve.step around everything, the phases one after
+    # another inside it, serve.prefill inside serve.admit
+    # (the layer's own scopes show up too, while a program is traced
+    # for its first call: not the engine's, left out here)
+    open_names, steps = [], 0
+    for kind, name in log.events:
+        if not name.startswith("serve."):
+            continue
+        if kind == "enter":
+            if name == "serve.step":
+                assert not open_names
+                steps += 1
+            elif name == "serve.prefill":
+                assert open_names == ["serve.step", "serve.admit"]
+            else:
+                assert open_names == ["serve.step"], (name, open_names)
+            open_names.append(name)
+        else:
+            assert open_names.pop() == name
+    assert not open_names and steps == len(recs)
+
+
+def test_phase_names_under_speculation(params):
+    from flashmoe_tpu.serving.speculate import SpecConfig
+
+    serve = ServeConfig(max_batch=2, page_size=8, num_pages=32,
+                        max_pages_per_slot=4, ctx_bucket_pages=1,
+                        prompt_bucket=8,
+                        speculate=SpecConfig(draft_tokens=2))
+    engine = ServingEngine(params, CFG, serve, metrics_obj=Metrics(),
+                           clock=Ticking(), recorder=FlightRecorder())
+    motif = (5, 9)
+    engine.submit(Request(rid=0, prompt=motif * 4, max_new_tokens=8))
+    recs = _drive(engine)
+    seen = set().union(*(r["phase_ms"] for r in recs))
+    assert seen <= set(SPAN_NAMES)
+    assert "serve.draft" in seen
+    for rec in recs:
+        assert sum(rec["phase_ms"].values()) == pytest.approx(
+            rec["step_ms"], rel=0.01)
+        if "serve.verify" in rec["phase_ms"]:
+            assert rec["ctx_pages"] >= 1
+    total = sum(n for r in recs for _, n in r["delivered"])
+    assert total == len(engine.outputs[0]) - 8
+
+
+def test_queue_wait_prefill_and_widest_gap_follow_the_clock(params):
+    """One slot: the second request waits until the first has retired.
+    The clock moves 1 s after every step, and 5 s more before step 2."""
+    clock, mx, rec = Stepped(), Metrics(), FlightRecorder()
+    serve = ServeConfig(max_batch=1, page_size=8, num_pages=32,
+                        max_pages_per_slot=4, ctx_bucket_pages=1,
+                        prompt_bucket=8)
+    engine = ServingEngine(params, CFG, serve, metrics_obj=mx,
+                           recorder=rec, clock=clock)
+    engine.submit(_req(0, max_new=4))
+    engine.submit(_req(1, max_new=3))
+
+    def hold(step):
+        if step == 2:
+            clock.t += 5.0
+
+    _drive(engine, clock, before_step=hold)
+    reqs = {r["rid"]: r for r in rec.records
+            if r.get("kind") == "serve_request"}
+    # request 0: admitted in step 0 at t=0, tokens at t = 0, 1, 7, 8
+    assert reqs[0]["queue_wait_ms"] == 0.0
+    assert reqs[0]["prefill_ms"] == 0.0
+    assert reqs[0]["gap_max_ms"] == 6000.0
+    # request 1 arrived at t=0 and was admitted in step 4 (t = 9), the
+    # step after request 0's last token; its tokens came 1 s apart
+    assert reqs[1]["queue_wait_ms"] == 9000.0
+    assert reqs[1]["gap_max_ms"] == 1000.0
+    retire = {d["rid"]: d for d in mx.decisions
+              if d["decision"] == "serve.retire"}
+    for rid in (0, 1):
+        for k in ("queue_wait_ms", "prefill_ms", "gap_max_ms"):
+            assert retire[rid][k] == reqs[rid][k]
+    # the sketch is fed once per admission
+    assert mx.sketches["serve.queue_wait_ms"].n == 2
+    assert mx.sketches["serve.queue_wait_ms"].max == 9000.0
+
+
+def test_evicted_request_reports_the_sum_of_its_waits(params):
+    """Page pressure evicts; the evictee's queue wait is its first wait
+    plus every wait between an eviction and the admission after it, as
+    the decision stream and the clock (1 s a step) dictate."""
+    clock, mx, rec = Stepped(), Metrics(), FlightRecorder()
+    serve = ServeConfig(max_batch=8, page_size=8, num_pages=20,
+                        max_pages_per_slot=4, ctx_bucket_pages=1,
+                        prompt_bucket=8)
+    engine = ServingEngine(params, CFG, serve, metrics_obj=mx,
+                           recorder=rec, clock=clock)
+    arrivals = [0, 0, 0, 0, 1, 1, 2, 3]
+    for rid, arr in enumerate(arrivals):
+        engine.submit(_req(rid, max_new=10), arr)
+    _drive(engine, clock)
+    assert engine.stats["evictions"] > 0
+    want = {}
+    queued_at = {rid: float(arr) for rid, arr in enumerate(arrivals)}
+    for d in mx.decisions:
+        if d["decision"] == "serve.evict":
+            queued_at[d["rid"]] = float(d["step"])
+        elif d["decision"] == "serve.admit":
+            want[d["rid"]] = want.get(d["rid"], 0.0) + 1e3 * (
+                d["step"] - queued_at.pop(d["rid"]))
+    reqs = {r["rid"]: r for r in rec.records
+            if r.get("kind") == "serve_request"}
+    assert len(reqs) == 8
+    evicted = {d["rid"] for d in mx.decisions
+               if d["decision"] == "serve.evict"}
+    for rid, r in reqs.items():
+        assert r["queue_wait_ms"] == want[rid], rid
+        # an evictee's tokens stand: the gap across its eviction is on
+        # its account, so it is wider than one step
+        assert (r["gap_max_ms"] > 1000.0) == (rid in evicted), rid
+    assert any(reqs[rid]["queue_wait_ms"] > 0 for rid in evicted)
+
+
+def test_delivered_alone_rebuilds_counts_and_first_token_order(params):
+    rec = FlightRecorder()
+    engine = ServingEngine(params, CFG, SERVE, metrics_obj=Metrics(),
+                           recorder=rec, clock=Ticking())
+    for rid, (arr, new) in enumerate([(0, 5), (0, 2), (1, 4), (3, 3),
+                                      (3, 6), (4, 2)]):
+        engine.submit(_req(rid, max_new=new), arr)
+    # the old way, for comparison: read the engine's slots after a step
+    first_seen = []
+    while engine.pending():
+        engine.step()
+        for s in engine.slots:
+            if s is not None and s.emitted \
+                    and s.orig.rid not in first_seen:
+                first_seen.append(s.orig.rid)
+        for rid in engine.outputs:
+            if rid not in first_seen:
+                first_seen.append(rid)
+    counts, order, t_prev = {}, [], 0.0
+    for r in rec.records:
+        if r.get("kind") != "serve_step":
+            continue
+        assert r["t1_s"] > t_prev          # every token has a time
+        t_prev = r["t1_s"]
+        assert sum(n for _, n in r["delivered"]) == r["tokens"]
+        for rid, n in r["delivered"]:
+            counts[rid] = counts.get(rid, 0) + n
+            if rid not in order:
+                order.append(rid)
+    assert counts == {rid: len(out) - 8
+                      for rid, out in engine.outputs.items()}
+    assert sorted(order) == sorted(first_seen)
+    # same step, same slot order: the two views agree on who came first
+    assert [sorted(g) for g in _by_step(order, rec)] == \
+        [sorted(g) for g in _by_step(first_seen, rec)]
+
+
+def _by_step(order, rec):
+    """Group rids by the step their first token came in."""
+    first_step = {}
+    for r in rec.records:
+        if r.get("kind") == "serve_step":
+            for rid, _ in r["delivered"]:
+                first_step.setdefault(rid, r["step"])
+    groups = {}
+    for rid in order:
+        groups.setdefault(first_step[rid], []).append(rid)
+    return [groups[k] for k in sorted(groups)]
+
+
+def test_step_that_meets_a_new_shape_reports_its_compiles(params):
+    """max_batch 3 and a 5-token prompt are shapes no other test uses:
+    the first request compiles (pad, prefill, sampler, decode), a repeat
+    of it compiles nothing."""
+    serve = ServeConfig(max_batch=3, page_size=8, num_pages=16,
+                        max_pages_per_slot=2, ctx_bucket_pages=1,
+                        prompt_bucket=8)
+    engine = ServingEngine(params, CFG, serve, metrics_obj=Metrics(),
+                           clock=Ticking())
+    engine.submit(_req(0, n=5, max_new=3))
+    first = _drive(engine)
+    assert first[0]["compiles"] >= 1 and first[0]["compile_ms"] > 0.0
+    engine.submit(_req(1, n=5, max_new=3))
+    again = _drive(engine)
+    assert [r["compiles"] for r in again] == [0] * len(again)
+    assert all(r["compile_ms"] == 0.0 for r in again)
+    count, seconds = telemetry.compile_totals()
+    assert count >= first[0]["compiles"] and seconds > 0.0
+
+
+def test_token_streams_do_not_depend_on_the_recorder(params):
+    outs = []
+    for recorder in (None, FlightRecorder()):
+        engine = ServingEngine(params, CFG, SERVE, metrics_obj=Metrics(),
+                               recorder=recorder, clock=Ticking())
+        outs.append(engine.run(
+            [_req(rid, max_new=6, temperature=0.7 * (rid % 2), seed=rid)
+             for rid in range(6)], [0, 0, 1, 1, 2, 5]))
+    assert outs[0] == outs[1]
+
+
+def test_ctx_pages_by_hand_on_a_two_slot_batch(params):
+    """Pages of 4 tokens, buckets of 2 pages.  Prompts of 4 and 12
+    tokens: the first decode writes positions 4 and 12, so the longest
+    context is 13 tokens = 4 pages, a whole bucket count already; the
+    slots' own contexts fill 2 and 4 pages, mean 3: one page a slot is
+    gathered and masked."""
+    rec = FlightRecorder()
+    serve = ServeConfig(max_batch=2, page_size=4, num_pages=32,
+                        max_pages_per_slot=8, ctx_bucket_pages=2,
+                        prompt_bucket=4)
+    engine = ServingEngine(params, CFG, serve, metrics_obj=Metrics(),
+                           recorder=rec, clock=Ticking())
+    engine.submit(_req(0, n=4, max_new=6))
+    engine.submit(_req(1, n=12, max_new=2))
+    recs = _drive(engine)
+    assert (recs[0]["ctx_pages"], recs[0]["ctx_pages_idle"]) == (4, 1.0)
+    # step 1: request 1 retires with its second token before the decode;
+    # request 0 alone writes position 5: 2 pages, none idle
+    assert (recs[1]["ctx_pages"], recs[1]["ctx_pages_idle"]) == (2, 0.0)
+    # step 4: request 0 writes position 8, its third page: bucket of 4
+    assert (recs[4]["ctx_pages"], recs[4]["ctx_pages_idle"]) == (4, 1.0)
+    # the last step samples the last token and runs no decode
+    assert (recs[-1]["ctx_pages"], recs[-1]["ctx_pages_idle"]) == (0, 0.0)
+    assert all("ctx_pages" in r and "ctx_pages_idle" in r for r in recs)
+    decodes = [r for r in rec.records if r.get("kind") == "serve_decode"]
+    assert [(d["step"], d["slots"], d["ctx_pages"], d["ctx_pages_idle"])
+            for d in decodes] == [
+        (r["step"], 2 if r["step"] == 0 else 1, r["ctx_pages"],
+         r["ctx_pages_idle"]) for r in recs if r["ctx_pages"]]
+
+
+def test_one_chip_layer_carries_the_stage_scopes():
+    """The lowered one-chip ``moe_layer`` (XLA path) names the paper's
+    four stages, and the shared experts, in its operations' op_name."""
+    from flashmoe_tpu.models.reference import init_moe_params
+    from flashmoe_tpu.ops.moe import moe_layer
+
+    cfg = MoEConfig(num_experts=4, expert_top_k=2, hidden_size=64,
+                    intermediate_size=128, sequence_len=32,
+                    num_shared_experts=1, drop_tokens=False,
+                    dtype=jnp.float32, param_dtype=jnp.float32)
+    p = init_moe_params(jax.random.PRNGKey(0), cfg)
+    x = jnp.ones((32, 64), jnp.float32)
+    text = jax.jit(lambda p, x: moe_layer(
+        p, x, cfg, use_pallas=False).out).lower(p, x).compile().as_text()
+    for stage in ("moe.gate", "moe.dispatch", "moe.expert", "moe.combine",
+                  "moe.shared"):
+        assert f"/{stage}/" in text, stage
+
+
+def test_train_step_scopes_and_records_say_which_step_compiled():
+    from flashmoe_tpu.parallel.mesh import make_mesh
+    from flashmoe_tpu.runtime.trainer import (
+        init_state, make_optimizer, make_train_step, train,
+    )
+
+    cfg = MoEConfig(num_experts=4, expert_top_k=2, hidden_size=64,
+                    intermediate_size=128, sequence_len=24, num_layers=1,
+                    moe_frequency=1, vocab_size=256, num_heads=2,
+                    drop_tokens=False, is_training=True,
+                    dtype=jnp.float32, param_dtype=jnp.float32)
+    mesh = make_mesh(cfg, dp=1, devices=jax.devices()[:1])
+
+    def batches():
+        i = 0
+        while True:
+            yield {"tokens": jax.random.randint(
+                jax.random.PRNGKey(i), (1, 25), 0, 256)}
+            i += 1
+
+    rec = FlightRecorder()
+    train(cfg, mesh, batches(), num_steps=3, recorder=rec)
+    steps = rec.records
+    assert steps[0]["compiles"] >= 1 and steps[0]["compile_ms"] > 0.0
+    assert [s["compiles"] for s in steps[1:]] == [0, 0]
+
+    opt = make_optimizer(cfg, total_steps=3)
+    state = init_state(jax.random.PRNGKey(0), cfg, opt)
+    text = make_train_step(cfg, mesh, opt, use_pallas=False).lower(
+        state, next(batches())).compile().as_text()
+    assert "train.forward_backward" in text
+    assert "train.optimizer" in text
+    # the layer's stages sit inside the trainer's scope, forward and
+    # backward (jax marks the backward transpose(...))
+    assert "train.forward_backward/jvp(" in text
+    assert "train.forward_backward/transpose(" in text
+    assert "moe.expert" in text

@@ -201,12 +201,13 @@ def test_fused_ep_moe_layer_deepseek_is_still_refused(topo):
         p, x, cfg, mesh, interpret=False, use_pallas_gate=True).out, p, x)
 
 
-def test_train_step_compiles_at_chip_smoke_size(one_chip, topo):
-    """The step ``chip_smoke.py``'s train phase runs: flashmoe-reference
-    widths, batch 2 x 4096, f32 state with Adam moments — inside the 16 GB
-    the chip's compiler counts, with the Pallas kernels in it.  Steered to
-    the chip's branches here in the test: ``jax.default_backend()`` still
-    says "cpu" while compiling for a described device."""
+@pytest.fixture(scope="module")
+def train_step_compiled(one_chip, topo):
+    """The step ``chip_smoke.py``'s train phase runs, compiled ONCE for
+    the tests below: flashmoe-reference widths, batch 2 x 4096, f32 state
+    with Adam moments.  Steered to the chip's branches here in the test:
+    ``jax.default_backend()`` still says "cpu" while compiling for a
+    described device."""
     import chip_smoke
     from flashmoe_tpu.models.presets import PRESETS
     from flashmoe_tpu.parallel.mesh import make_mesh
@@ -228,10 +229,71 @@ def test_train_step_compiles_at_chip_smoke_size(one_chip, topo):
         sharding=NamedSharding(mesh, P("dp", None)))}
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
-        compiled = make_train_step(cfg, mesh, opt).lower(
+        return make_train_step(cfg, mesh, opt).lower(
             state, batch).compile()
-    m = compiled.memory_analysis()
+
+
+def test_train_step_compiles_at_chip_smoke_size(train_step_compiled):
+    """Inside the 16 GB the chip's compiler counts, with the Pallas
+    kernels in it."""
+    m = train_step_compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert total < 15.75 * 2**30
-    assert compiled.as_text().count("tpu_custom_call") >= 4
+    assert train_step_compiled.as_text().count("tpu_custom_call") >= 4
+
+
+def _custom_call_names(text):
+    """(instruction name, op_name) of every Pallas kernel in a compiled
+    program: the instruction name is what the chip's trace shows on its
+    ``XLA Ops`` line (``%fm_tgmm.3 = ...``)."""
+    import re
+
+    out = []
+    for line in text.splitlines():
+        if "tpu_custom_call" not in line:
+            continue
+        name = re.search(r"%([\w.\-]+) = ", line)
+        op = re.search(r'op_name="([^"]*)"', line)
+        out.append((name.group(1) if name else "",
+                    op.group(1) if op else ""))
+    return out
+
+
+def test_train_step_names_its_kernels(train_step_compiled):
+    """Every Pallas kernel of the train step runs under its own
+    ``fm_<kernel>`` instruction name — under ``jvp``, ``remat`` and
+    ``custom_vjp`` alike — and inside the stage scope of its layer, so a
+    reader over the trace finds it by a pattern that survives refactors
+    (``benchmark/layer_metrics/expert_*_roofline.train.json``)."""
+    calls = _custom_call_names(train_step_compiled.as_text())
+    assert calls
+    stray = [c for c in calls if not c[0].startswith("fm_")]
+    assert not stray, stray
+    families = {n.split(".")[0] for n, _ in calls}
+    assert families == {"fm_ffn_fwd_res", "fm_gmm", "fm_tgmm",
+                        "fm_flash_fwd", "fm_router"}, families
+    for name, op in calls:
+        assert "train.forward_backward" in op, (name, op)
+        stage = ("moe.gate" if name.startswith("fm_router") else
+                 None if name.startswith("fm_flash") else "moe.expert")
+        assert stage is None or stage in op, (name, op)
+
+
+@pytest.mark.parametrize("name", ["reference"])
+def test_bare_layer_grad_names_its_kernels(one_chip, name):
+    """The same holds for the layer differentiated on its own, with no
+    trainer scope around it: the stage scopes inside ``moe_layer`` are
+    what keeps a transform's name (``jvp(...)``) off the kernel's."""
+    cfg = BENCH_CONFIGS[name].replace(ep=1, is_training=True)
+    p, x = _layer_shapes(cfg, lambda k: one_chip, one_chip)
+
+    def loss(p, x):
+        o = fm.moe_layer(p, x, cfg, use_pallas=True)
+        return (o.out.astype(jnp.float32) ** 2).mean() + o.aux_loss
+
+    _, text = _compile(jax.grad(loss), p, x)
+    calls = _custom_call_names(text)
+    assert {n.split(".")[0] for n, _ in calls} >= {
+        "fm_ffn_fwd_res", "fm_gmm", "fm_tgmm"}, calls
+    assert all(n.startswith("fm_") for n, _ in calls), calls
