@@ -703,8 +703,17 @@ def _ep_verify_fn(mesh, cfg: MoEConfig, params):
     return fn
 
 
-@jax.jit
-def _sample_dynamic(logits, keys, temps, top_ks, top_ps):
+def _token_keys(seeds, index):
+    """The sampler's key for each row: ``fold_in(PRNGKey(seed),
+    token_index)`` as ``[B, 2]`` uint32 key data, traced.  ``seeds`` is
+    ``Request.seed & 0xFFFFFFFF`` as uint32 (what the host's
+    ``PRNGKey(seed)`` keeps of any Python int with x64 off), ``index``
+    the token's position in the request's output."""
+    return jax.vmap(lambda s, n: jax.random.fold_in(
+        jax.random.PRNGKey(s), n))(seeds, index)
+
+
+def _sample_with_keys(logits, keys, temps, top_ks, top_ps):
     """Per-slot sampling with DYNAMIC per-request knobs (the engine's
     batch mixes requests): temperature <= 0 rows take the exact argmax
     (bit-equal to ``sample_tokens``' greedy arm); sampled rows apply
@@ -729,6 +738,35 @@ def _sample_dynamic(logits, keys, temps, top_ks, top_ps):
     sampled = jax.vmap(
         lambda kk, ll: jax.random.categorical(kk, ll))(keys, scaled)
     return jnp.where(temps <= 0.0, greedy, sampled.astype(jnp.int32))
+
+
+@jax.jit
+def _sample_dynamic(logits, seeds, index, temps, top_ks, top_ps):
+    """:func:`_sample_with_keys` on the keys :func:`_token_keys` derives
+    in the same program: the host uploads five small numpy arrays and
+    reads back the tokens, nothing else."""
+    return _sample_with_keys(logits, _token_keys(seeds, index), temps,
+                             top_ks, top_ps)
+
+
+def _sampler_rows(rows):
+    """``(request, token_index)`` per row, ``None`` for an idle row ->
+    the numpy arrays :func:`_sample_dynamic` takes after the logits."""
+    n = len(rows)
+    seeds = np.zeros((n,), np.uint32)
+    index = np.zeros((n,), np.int32)
+    temps = np.zeros((n,), np.float32)
+    top_ks = np.zeros((n,), np.int32)
+    top_ps = np.ones((n,), np.float32)
+    for j, row in enumerate(rows):
+        if row is None:
+            continue
+        r, index[j] = row
+        seeds[j] = r.seed & 0xFFFFFFFF
+        temps[j] = r.temperature
+        top_ks[j] = r.top_k
+        top_ps[j] = r.top_p
+    return seeds, index, temps, top_ks, top_ps
 
 
 def _as_watchdog(slo):
@@ -1434,17 +1472,14 @@ class ServingEngine:
             return 0
 
         # ---- verify: score k+1 positions per slot in one forward ----
-        # (the drafted positions' sampling keys are built with the feed)
+        # (the drafted positions' sampler rows are built with the feed)
         self._phase("serve.decode_feed")
         t_span = k + 1
         feed = np.full((sv.max_batch, t_span), sv.pad_token, np.int32)
         positions = np.zeros((sv.max_batch,), np.int32)
         tables = np.full((sv.max_batch, sv.max_pages_per_slot),
                          SCRATCH_PAGE, np.int32)
-        temps = np.zeros((sv.max_batch, k), np.float32)
-        tks = np.zeros((sv.max_batch, k), np.int32)
-        tps = np.ones((sv.max_batch, k), np.float32)
-        keys = np.zeros((sv.max_batch, k, 2), np.uint32)
+        rows = [None] * (sv.max_batch * k)
         longest = 1
         own_pages = 0
         for i in active:
@@ -1456,15 +1491,9 @@ class ServingEngine:
             tables[i, :len(s.pages)] = s.pages
             longest = max(longest, s.length + t_span)
             own_pages += (s.length + t_span - 1) // sv.page_size + 1
-            r = s.req
-            temps[i] = r.temperature
-            tks[i] = r.top_k
-            tps[i] = r.top_p
             base = self._delivered(s)   # emitted already holds tok_0
-            root = jax.random.PRNGKey(r.seed)
-            for t in range(k):
-                keys[i, t] = np.asarray(
-                    jax.random.fold_in(root, base + t))
+            rows[i * k:(i + 1) * k] = [(s.req, base + t)
+                                       for t in range(k)]
         n_ctx = ctx_pages_bucket(longest, sv.page_size,
                                  sv.ctx_bucket_pages,
                                  sv.max_pages_per_slot)
@@ -1494,10 +1523,7 @@ class ServingEngine:
         # logits, position-(base+t-1) key, the same sampler numerics
         cand = np.asarray(_sample_dynamic(
             span_logits[:, :k, :].reshape(sv.max_batch * k, -1),
-            jnp.asarray(keys.reshape(sv.max_batch * k, 2)),
-            jnp.asarray(temps.reshape(-1)),
-            jnp.asarray(tks.reshape(-1)),
-            jnp.asarray(tps.reshape(-1)))).reshape(sv.max_batch, k)
+            *_sampler_rows(rows))).reshape(sv.max_batch, k)
 
         # ---- accept the agreeing prefix; roll back the rest ----------
         self._phase("serve.deliver")
@@ -1718,22 +1744,15 @@ class ServingEngine:
         emitted_now = 0
         active = self._decoding()
         if active:
-            temps = np.zeros((sv.max_batch,), np.float32)
-            tks = np.zeros((sv.max_batch,), np.int32)
-            tps = np.ones((sv.max_batch,), np.float32)
-            keys = np.zeros((sv.max_batch, 2), np.uint32)
+            rows = [None] * sv.max_batch
             for i in active:
-                r = self.slots[i].req
-                temps[i] = r.temperature
-                tks[i] = r.top_k
-                tps[i] = r.top_p
-                keys[i] = np.asarray(jax.random.fold_in(
-                    jax.random.PRNGKey(r.seed),
-                    self._delivered(self.slots[i])))
+                s = self.slots[i]
+                rows[i] = (s.req, self._delivered(s))
+            knobs = _sampler_rows(rows)
             self._phase("serve.sample")
-            toks = np.asarray(_sample_dynamic(
-                self._logits, jnp.asarray(keys),
-                jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps)))
+            # the step's one read-back: it waits for the decode program
+            # the step before dispatched, then for the sampler's
+            toks = np.asarray(_sample_dynamic(self._logits, *knobs))
             now = self._phase("serve.deliver")
             for i in active:
                 s = self.slots[i]

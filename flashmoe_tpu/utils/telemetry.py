@@ -267,11 +267,13 @@ SPAN_NAMES: dict[str, str] = {
     "serve.prefill_advance":
         "step phase: one chunk of every prompt mid chunked prefill",
     "serve.sample_keys":
-        "step phase: per-slot temperature, top-k/top-p and sampling "
-        "key on the host (the key's read-back waits for the decode "
-        "step dispatched before)",
+        "step phase: per-slot temperature, top-k/top-p, seed and token "
+        "index filled into numpy arrays on the host (the sampler "
+        "program derives the keys from them; nothing is read back)",
     "serve.sample":
-        "step phase: the sampler program through the tokens on the host",
+        "step phase: the sampler program through the tokens on the "
+        "host, the step's one read-back (it waits for the decode step "
+        "dispatched before, then for the sampler)",
     "serve.deliver":
         "step phase: tokens appended, first-token stamps, retirements",
     "serve.grow":

@@ -18,6 +18,7 @@ import pytest
 from flashmoe_tpu.config import BENCH_CONFIGS, MoEConfig
 from flashmoe_tpu.models.generate import generate
 from flashmoe_tpu.models.transformer import init_params
+from flashmoe_tpu.serving import engine as eng
 from flashmoe_tpu.serving.engine import (
     Request, ServeConfig, ServingEngine,
 )
@@ -255,6 +256,153 @@ def test_sampled_requests_deterministic(params, prompts):
                                       np.asarray(b[i]))
         toks = a[i][8:]
         assert all(0 <= t < CFG.vocab_size for t in toks)
+
+
+# ----------------------------------------------------------------------
+# The sampler derives its keys in its own program (ISSUE 26)
+# ----------------------------------------------------------------------
+
+KEY_SEEDS = (0, 1, 11, 2**31 - 1, 2**31, 2**32 - 1, 2**32 + 5, -1)
+KEY_INDICES = (0, 1, 511, 100000)
+
+
+def _host_key(seed, n):
+    """The key formula as the host loop had it, one row at a time."""
+    return np.asarray(jax.random.fold_in(jax.random.PRNGKey(seed), n))
+
+
+@pytest.mark.parametrize("n", KEY_INDICES)
+@pytest.mark.parametrize("seed", KEY_SEEDS)
+def test_token_keys_bit_equal_to_host_formula(seed, n):
+    """Every seed ``Request`` accepts: the traced derivation, fed what
+    ``_sampler_rows`` uploads, gives the host statement's key data."""
+    seeds, index, *_ = eng._sampler_rows(
+        [(Request(rid=0, prompt=(1,), seed=seed), n)])
+    assert seeds.dtype == np.uint32 and index.dtype == np.int32
+    got = np.asarray(jax.jit(eng._token_keys)(seeds, index))
+    assert got.dtype == np.uint32
+    np.testing.assert_array_equal(got, _host_key(seed, n)[None])
+
+
+@pytest.mark.parametrize("draw", [0, 1, 2])
+def test_sampler_entry_equals_numerics_on_host_keys(draw):
+    """Greedy, temperature, top-k and top-p rows mixed in one batch,
+    with an idle row: the jitted entry returns the tokens the
+    key-taking numerics return on host-derived keys."""
+    rng = np.random.default_rng(draw)
+    knobs = [dict(), dict(temperature=0.7), dict(temperature=1.3, top_k=5),
+             dict(temperature=0.9, top_p=0.6), None,
+             dict(temperature=1.0, top_k=9, top_p=0.8), dict(top_k=3),
+             dict(temperature=2.0)]
+    rows = [None if kw is None else
+            (Request(rid=j, prompt=(1,), seed=KEY_SEEDS[j], **kw),
+             int(rng.integers(0, 100001)))
+            for j, kw in enumerate(knobs)]
+    logits = jnp.asarray(rng.normal(size=(len(rows), 96)) * 3,
+                         jnp.float32)
+    seeds, index, temps, top_ks, top_ps = eng._sampler_rows(rows)
+    keys = np.stack([_host_key(0, 0) if row is None
+                     else _host_key(row[0].seed, row[1]) for row in rows])
+    want = np.asarray(jax.jit(eng._sample_with_keys)(
+        logits, keys, temps, top_ks, top_ps))
+    got = np.asarray(eng._sample_dynamic(
+        logits, seeds, index, temps, top_ks, top_ps))
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        got[[0, 4, 6]], np.argmax(np.asarray(logits), -1)[[0, 4, 6]])
+    assert (got != np.argmax(np.asarray(logits), -1)).any(), \
+        "no sampled row left the argmax: the keys were not exercised"
+
+
+def _watch_host_traffic(monkeypatch) -> list:
+    """While the patches hold, an eager ``jax.random`` call raises, and
+    every read-back of a device array is appended to the returned list
+    as ``(shape, dtype)``: ``np.asarray`` / ``np.array`` (on the CPU
+    they read a device array through the buffer protocol, so the numpy
+    entry is where it shows) and ``ArrayImpl._value`` (``int()``,
+    ``float()``, ``bool()``, ``.tolist()``, ``.item()``,
+    ``jax.device_get``)."""
+    from jax._src.array import ArrayImpl
+
+    reads = []
+
+    def traced_only(name):
+        real = getattr(jax.random, name)
+
+        def guarded(*args, **kw):
+            if not any(isinstance(a, jax.core.Tracer)
+                       for a in list(args) + list(kw.values())):
+                raise AssertionError(
+                    f"eager jax.random.{name} inside engine.step()")
+            return real(*args, **kw)
+        return guarded
+
+    for name in ("PRNGKey", "key", "fold_in", "split", "key_data",
+                 "wrap_key_data", "categorical"):
+        monkeypatch.setattr(jax.random, name, traced_only(name))
+
+    def noting(real):
+        def spy(a, *args, **kw):
+            if isinstance(a, jax.Array):
+                reads.append((tuple(a.shape), str(a.dtype)))
+            return real(a, *args, **kw)
+        return spy
+
+    monkeypatch.setattr(np, "asarray", noting(np.asarray))
+    monkeypatch.setattr(np, "array", noting(np.array))
+    real_value = ArrayImpl._value
+
+    def value(arr):
+        reads.append((tuple(arr.shape), str(arr.dtype)))
+        return real_value.fget(arr)
+
+    monkeypatch.setattr(ArrayImpl, "_value", property(value))
+    return reads
+
+
+@pytest.mark.parametrize("speculate", [None, 3],
+                         ids=["plain", "speculative"])
+def test_step_reads_back_only_the_sampled_tokens(params, prompts,
+                                                 spec_prompts, speculate):
+    """No ``jax.random`` call outside a jit and one device value back
+    per sampler call — the tokens — on the plain and on the
+    speculative arm, and the streams are those of an unwatched run."""
+    serve = _spec_serve(speculate=speculate)
+    src = spec_prompts if speculate else prompts
+    # two greedy requests (on the repetitive prompts they draft, so the
+    # verify program runs) beside two sampled ones (they use the keys)
+    reqs = (_requests(src, 2, max_new=8)
+            + _requests(src, 4, max_new=8, temperature=0.8, top_k=20,
+                        top_p=0.9, seed=21)[2:])
+    want = ServingEngine(params, CFG, serve).run(
+        reqs, arrivals=[0, 0, 1, 2])
+
+    engine = ServingEngine(params, CFG, serve)
+    for req, at in zip(reqs, [0, 0, 1, 2]):
+        engine.submit(req, at)
+    token_reads = {((serve.max_batch,), "int32")}
+    if speculate:
+        token_reads.add(((serve.max_batch * speculate,), "int32"))
+    with pytest.MonkeyPatch.context() as mp:
+        reads = _watch_host_traffic(mp)
+        with pytest.raises(AssertionError, match="eager jax.random"):
+            jax.random.fold_in(jax.random.PRNGKey(0), 1)
+        np.asarray(jnp.zeros((3,)))
+        assert reads == [((3,), "float32")]   # the spies work
+        sampled_steps = 0
+        while engine.pending():
+            del reads[:]
+            rec = engine.step()
+            assert set(reads) <= token_reads, reads
+            assert len(reads) <= (2 if speculate else 1)
+            sampled_steps += bool(reads)
+            assert bool(reads) == bool(rec["tokens"])
+    assert sampled_steps >= 8
+    if speculate:
+        assert engine.spec_snapshot()["spec_drafted"] > 0, "never verified"
+    for i in range(4):
+        np.testing.assert_array_equal(np.asarray(engine.outputs[i]),
+                                      np.asarray(want[i]))
 
 
 def test_stop_token_retires_early(params, prompts):

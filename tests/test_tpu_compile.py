@@ -297,3 +297,23 @@ def test_bare_layer_grad_names_its_kernels(one_chip, name):
     assert {n.split(".")[0] for n, _ in calls} >= {
         "fm_ffn_fwd_res", "fm_gmm", "fm_tgmm"}, calls
     assert all(n.startswith("fm_") for n, _ in calls), calls
+
+
+def test_sampler_program_derives_its_keys_on_the_chip(one_chip):
+    """The serving sampler at the backlog cell's size (32 slots, the
+    deepseek vocabulary): the chip's compiler takes the key derivation
+    (a ``vmap`` of ``PRNGKey`` + ``fold_in`` over uint32 seeds) in the
+    sampler's own program and returns 32 tokens."""
+    from flashmoe_tpu.serving import engine as eng
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, v = 32, 102400
+    compiled = eng._sample_dynamic.lower(
+        arg((b, v), jnp.float32), arg((b,), jnp.uint32),
+        arg((b,), jnp.int32), arg((b,), jnp.float32),
+        arg((b,), jnp.int32), arg((b,), jnp.float32)).compile()
+    (out,) = jax.tree.leaves(compiled.out_info)
+    assert out.shape == (b,) and out.dtype == jnp.int32
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
