@@ -391,7 +391,7 @@ def phase_serve(seed):
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)
     t0 = time.perf_counter()
     mem = eng._paged_decode_step.lower(
-        params, cfg, cache.k_pages, cache.v_pages, i32(serve.max_batch),
+        params, cfg, cache, i32(serve.max_batch),
         i32(serve.max_batch, serve.max_pages_per_slot),
         i32(serve.max_batch)).compile().memory_analysis()
     compile_s = time.perf_counter() - t0

@@ -104,6 +104,38 @@ class MoEConfig:
     router_z_loss_coef: float = 0.0
     rope_theta: float = 10000.0
 
+    # --- architecture keys a published config.json states and the block
+    # above cannot (DeepSeek-V3 family: JoyAI-LLM-Flash).  Every default
+    # is the value all earlier presets run at, so their traced graphs do
+    # not change.
+    # attention: "mha" (q/k/v projections, GQA by num_kv_heads) or "mla"
+    # (multi-head latent attention: queries through a q_lora_rank
+    # bottleneck, keys and values decompressed from one kv_lora_rank
+    # latent a token, a qk_rope_head_dim rotary key shared by all heads;
+    # RoPE rotates ADJACENT pairs).  The five sizes are MLA's and must be
+    # 0 under "mha".
+    attention_kind: str = "mha"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # router: scores are softmax(logits) or sigmoid(logits); with
+    # router_bias the layer holds a per-expert `gate_bias` that is added
+    # to the scores for the top-k SELECTION only (the combine weights are
+    # the scores themselves: `e_score_correction_bias`, "noaux_tc");
+    # norm_topk_prob divides the chosen weights by their sum;
+    # routed_scaling_factor multiplies them afterwards.
+    router_score: str = "softmax"
+    router_bias: bool = False
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # the first first_k_dense layers carry a dense FFN of width
+    # dense_intermediate_size (0: intermediate_size) whatever
+    # moe_frequency says
+    first_k_dense: int = 0
+    dense_intermediate_size: int = 0
+
     # --- numerics ---
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -312,6 +344,41 @@ class MoEConfig:
             raise ValueError("num_experts must divide evenly over ep")
         if self.capacity_factor <= 0:
             raise ValueError("capacity_factor must be > 0")
+        mla_sizes = (self.q_lora_rank, self.kv_lora_rank,
+                     self.qk_nope_head_dim, self.qk_rope_head_dim,
+                     self.v_head_dim)
+        if self.attention_kind == "mla":
+            if min(mla_sizes) < 1 or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "attention_kind='mla' needs q_lora_rank, kv_lora_rank, "
+                    "qk_nope_head_dim, qk_rope_head_dim (even) and "
+                    f"v_head_dim >= 1, got {mla_sizes}")
+            if self.num_kv_heads not in (0, self.num_heads) or self.head_dim:
+                raise ValueError(
+                    "attention_kind='mla' has no kv-head grouping and no "
+                    "single head_dim: leave num_kv_heads and head_dim 0")
+            if self.kv_wire_dtype is not None:
+                raise NotImplementedError(
+                    "kv_wire_dtype with attention_kind='mla': the page "
+                    "codec (fabric/handoff.py) packs (layer, page) blocks "
+                    "of a K/V pair; a codec for latent rows is missing")
+        elif self.attention_kind == "mha":
+            if any(mla_sizes):
+                raise ValueError(
+                    f"MLA sizes {mla_sizes} need attention_kind='mla'")
+        else:
+            raise ValueError(f"attention_kind {self.attention_kind!r} not "
+                             f"in ('mha', 'mla')")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score {self.router_score!r} not in "
+                             f"('softmax', 'sigmoid')")
+        if self.routed_scaling_factor <= 0:
+            raise ValueError("routed_scaling_factor must be > 0")
+        if not 0 <= self.first_k_dense <= self.num_layers:
+            raise ValueError("first_k_dense must be in [0, num_layers]")
+        if self.dense_intermediate_size % 64:
+            raise ValueError(
+                "dense_intermediate_size must be a multiple of 64")
         if self.moe_backend not in ("collective", "fused", "ragged",
                                     "auto"):
             raise ValueError(
@@ -512,7 +579,33 @@ class MoEConfig:
         if self.num_experts <= 1:
             return ()
         f = max(1, self.moe_frequency)
-        return tuple(i for i in range(self.num_layers) if (i + 1) % f == 0)
+        return tuple(i for i in range(self.first_k_dense, self.num_layers)
+                     if (i + 1) % f == 0)
+
+    def ffn_config(self, li: int) -> "MoEConfig":
+        """The config layer ``li``'s feed-forward runs under: this one
+        for a mixture layer, else one dense expert (no router, no shared
+        experts) of the dense width."""
+        if li in self.moe_layer_indices:
+            return self
+        return self.replace(
+            num_experts=1, expert_top_k=1, num_shared_experts=0,
+            intermediate_size=(self.dense_intermediate_size
+                               or self.intermediate_size))
+
+    @property
+    def kv_token_elems(self) -> int:
+        """Elements ONE layer's cache holds for one token: K and V of
+        every kv head, or MLA's latent beside its shared rotary key."""
+        if self.attention_kind == "mla":
+            return self.kv_lora_rank + self.qk_rope_head_dim
+        return 2 * self.resolved_num_kv_heads * self.resolved_head_dim
+
+    @property
+    def kv_token_bytes(self) -> int:
+        """Bytes one cached token costs over all layers."""
+        return (self.num_layers * self.kv_token_elems
+                * jnp.dtype(self.dtype).itemsize)
 
     @property
     def param_count(self) -> int:

@@ -129,6 +129,11 @@ class KVHandoff:
                  wire=None, metrics_obj=None,
                  decode_step_ms: float | None = None, vclock=None,
                  transport=None):
+        if cfg.attention_kind == "mla":
+            raise NotImplementedError(
+                "KV handoff of an attention_kind='mla' model: the payload "
+                "(encode_kv_run / decode_kv_run) is a K/V pair of "
+                "[L, N_kv, T, D] runs; a latent-row payload is missing")
         self.params = params
         self.cfg = cfg
         self.page_size = int(page_size)

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from flashmoe_tpu.config import MoEConfig
 from flashmoe_tpu.models.transformer import _rope, rms_norm
-from flashmoe_tpu.ops.attention import attention_xla
+from flashmoe_tpu.ops.attention import attention_xla, mla_paged_attention
 from flashmoe_tpu.ops.moe import moe_layer
 
 
@@ -41,20 +41,77 @@ class KVCache(NamedTuple):
     v: jax.Array
 
 
-def init_cache(cfg: MoEConfig, batch: int, max_len: int) -> KVCache:
+class LatentCache(NamedTuple):
+    """The dense cache of an MLA model: one latent row a token a layer,
+    ``[L, B, T_max * (kv_lora_rank + qk_rope_head_dim)]``: a latent pool
+    (``serving/kvcache.LatentPagedCache``'s layout) of one ``T_max``-row
+    page a batch row, which is how :func:`mla_span_forward` takes it."""
+
+    c: jax.Array
+
+
+def init_cache(cfg: MoEConfig, batch: int, max_len: int):
+    if cfg.attention_kind == "mla":
+        return LatentCache(jnp.zeros(
+            (cfg.num_layers, batch, max_len * cfg.kv_token_elems),
+            cfg.dtype))
     nkv, dh = cfg.resolved_num_kv_heads, cfg.resolved_head_dim
     shape = (cfg.num_layers, batch, nkv, max_len, dh)
     return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 
 
-def _layer_cfg(cfg: MoEConfig, li: int) -> MoEConfig:
-    return cfg if li in cfg.moe_layer_indices else cfg.replace(
-        num_experts=1, expert_top_k=1, num_shared_experts=0
-    )
+def mla_span_forward(params, cfg: MoEConfig, x, pool, pos, write,
+                     block_tables, *, absorbed: bool):
+    """An MLA model's layers over a span of T tokens a slot: the ONE
+    layer loop of every cached MLA path (the serving engine's prefill,
+    chunked prefill, decode and verify programs, and :func:`generate`).
+
+    x: [B, T, H] embedded tokens; pool: the latent pool
+    ``[L, P, page * C]`` or None (a whole prompt at once: nothing cached
+    yet); pos: [B, T] absolute positions; write / block_tables: see
+    :func:`~flashmoe_tpu.ops.attention.mla_paged_attention`.  Returns
+    (x pre-final-norm [B, T, H], the pool, the span's latent rows
+    [L, B, T, C])."""
+    b, t, _ = x.shape
+    latents = []
+    for li, layer in enumerate(params["layers"]):
+        a, pool, latent = mla_paged_attention(
+            layer, rms_norm(x, layer["attn_norm"]), cfg, pool, li, pos,
+            write, block_tables, absorbed=absorbed)
+        latents.append(latent)
+        x = x + a
+        f_in = rms_norm(x, layer["ffn_norm"])
+        # the experts over the S x K routed rows: the capacity arm's
+        # E x S rows cost 32 x the routed work at 256 experts top-8 and
+        # do not fit beside the weights past a 512-token span
+        o = moe_layer(layer["moe"], f_in.reshape(b * t, -1),
+                      cfg.ffn_config(li), use_pallas=False,
+                      routed_rows=True)
+        x = x + o.out.reshape(b, t, -1).astype(x.dtype)
+    return x, pool, jnp.stack(latents)
+
+
+def _mla_dense_step(params, cfg: MoEConfig, x, cache: LatentCache, pos,
+                    absorbed: bool):
+    """:func:`mla_span_forward` over the dense cache: batch row b owns
+    page b, and a span starting at ``pos`` writes rows pos..pos+T-1."""
+    b, t, _ = x.shape
+    positions = jnp.broadcast_to(
+        pos + jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
+    rows_b = jnp.broadcast_to(jnp.arange(b, dtype=jnp.int32)[:, None],
+                              (b, t))
+    x, pool, _ = mla_span_forward(
+        params, cfg, x, cache.c, positions, (rows_b, positions),
+        rows_b[:, :1], absorbed=absorbed)
+    return x, LatentCache(pool)
 
 
 def _decode_step(params, cfg: MoEConfig, x, cache: KVCache, pos):
     """One token through all layers. x: [B, 1, H]; pos: [] current index."""
+    if cfg.attention_kind == "mla":
+        x, cache = _mla_dense_step(params, cfg, x, cache, pos,
+                                   absorbed=True)
+        return lm_logits(params, cfg, x), cache
     b = x.shape[0]
     nh, nkv, dh = cfg.num_heads, cfg.resolved_num_kv_heads, cfg.resolved_head_dim
     new_k, new_v = [], []
@@ -95,7 +152,7 @@ def _decode_step(params, cfg: MoEConfig, x, cache: KVCache, pos):
 
         f_in = rms_norm(x, layer["ffn_norm"])
         o = moe_layer(
-            layer["moe"], f_in.reshape(b, -1), _layer_cfg(cfg, li),
+            layer["moe"], f_in.reshape(b, -1), cfg.ffn_config(li),
             use_pallas=False
         )
         x = x + o.out.reshape(b, 1, -1).astype(x.dtype)
@@ -124,8 +181,11 @@ def prefill_forward(params, cfg: MoEConfig, prompt, cache: KVCache):
     dynamic true-length index, not the last row.
     """
     b, t0 = prompt.shape
-    nh, nkv, dh = cfg.num_heads, cfg.resolved_num_kv_heads, cfg.resolved_head_dim
     x = params["embed"].astype(cfg.dtype)[prompt]  # [B, T0, H]
+    if cfg.attention_kind == "mla":
+        return _mla_dense_step(params, cfg, x, cache, jnp.int32(0),
+                               absorbed=False)
+    nh, nkv, dh = cfg.num_heads, cfg.resolved_num_kv_heads, cfg.resolved_head_dim
     positions = jnp.broadcast_to(jnp.arange(t0)[None, :], (b, t0))
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
@@ -165,7 +225,7 @@ def prefill_forward(params, cfg: MoEConfig, prompt, cache: KVCache):
 
         f_in = rms_norm(x, layer["ffn_norm"])
         o = moe_layer(
-            layer["moe"], f_in.reshape(b * t0, -1), _layer_cfg(cfg, li),
+            layer["moe"], f_in.reshape(b * t0, -1), cfg.ffn_config(li),
             use_pallas=False
         )
         x = x + o.out.reshape(b, t0, -1).astype(x.dtype)

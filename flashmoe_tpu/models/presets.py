@@ -65,9 +65,34 @@ def flashmoe_reference(**overrides) -> MoEConfig:
     return MoEConfig(**base)
 
 
+def joyai_llm_flash(**overrides) -> MoEConfig:
+    """JoyAI-LLM-Flash (48B-A2.7B; huggingface.co/jdopensource/
+    JoyAI-LLM-Flash ``config.json``, ``model_type`` joyai_llm_flash): 40
+    layers of multi-head latent attention (32 heads), the first a dense
+    SwiGLU of width 7168, the rest 256 routed experts top-8 + 1 shared
+    of width 768 behind a sigmoid router with a selection bias
+    (``noaux_tc``), normalised top-k weights times 2.5; one group, so no
+    group-limited selection.  Its multi-token-prediction layer is not
+    part of the model this preset builds."""
+    base = dict(
+        num_experts=256, expert_top_k=8, num_shared_experts=1,
+        hidden_size=2048, intermediate_size=768, num_layers=40,
+        moe_frequency=1, first_k_dense=1, dense_intermediate_size=7168,
+        vocab_size=129280, num_heads=32, attention_kind="mla",
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, rope_theta=3.2e7,
+        router_score="sigmoid", router_bias=True, norm_topk_prob=True,
+        routed_scaling_factor=2.5, sequence_len=4096, gated_ffn=True,
+        hidden_act=Activation.SILU, drop_tokens=False, dtype=jnp.bfloat16,
+    )
+    base.update(overrides)
+    return MoEConfig(**base)
+
+
 PRESETS = {
     "mixtral-8x7b": mixtral_8x7b,
     "deepseek-moe-16b": deepseek_moe_16b,
     "switch-base": switch_base,
     "flashmoe-reference": flashmoe_reference,
+    "joyai-llm-flash": joyai_llm_flash,
 }

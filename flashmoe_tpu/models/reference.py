@@ -48,6 +48,10 @@ def init_moe_params(key, cfg: MoEConfig) -> dict:
         p["w_gate"] = (
             jax.random.normal(ks[3], (e, h, i), cfg.param_dtype) / jnp.sqrt(h)
         )
+    if cfg.router_bias and e > 1:
+        # the selection bias is a buffer the balancing rule moves, not a
+        # trained weight: float32, zero until a checkpoint sets it
+        p["gate_bias"] = jnp.zeros((e,), jnp.float32)
     if cfg.num_shared_experts:
         si = i * cfg.num_shared_experts
         p["shared_w_up"] = (
@@ -63,8 +67,10 @@ def init_moe_params(key, cfg: MoEConfig) -> dict:
     return p
 
 
-def reference_gate(x, gate_w, cfg: MoEConfig):
-    """Gate: logits -> softmax over experts -> top-k.
+def reference_gate(x, gate_w, cfg: MoEConfig, gate_bias=None):
+    """Gate: logits -> softmax over experts -> top-k (or what the
+    config's router keys say: sigmoid scores, a selection bias that only
+    steers the choice, ``norm_topk_prob``, ``routed_scaling_factor``).
 
     Returns (combine_weights [S, E], top_idx [S, K], router_probs [S, E],
     aux_loss).  ``combine_weights`` is the softmax prob masked to the top-k
@@ -78,11 +84,18 @@ def reference_gate(x, gate_w, cfg: MoEConfig):
         gate_w.astype(cfg.accum_dtype),
         preferred_element_type=cfg.accum_dtype,
     )
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_idx = jax.lax.top_k(probs, cfg.expert_top_k)
+    if cfg.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    else:
+        scores = probs = jax.nn.softmax(logits, axis=-1)
+    select = scores if gate_bias is None else scores + gate_bias[None, :]
+    _, top_idx = jax.lax.top_k(select, cfg.expert_top_k)
+    top_p = jnp.take_along_axis(scores, top_idx, axis=-1)
     # mask to top-k, renormalize over the selected set
     denom = jnp.sum(top_p, axis=-1, keepdims=True)
-    norm_top = top_p / jnp.maximum(denom, 1e-20)
+    norm_top = (top_p / jnp.maximum(denom, 1e-20) if cfg.norm_topk_prob
+                else top_p) * cfg.routed_scaling_factor
     one_hot = jax.nn.one_hot(top_idx, cfg.num_experts, dtype=probs.dtype)
     combine_weights = jnp.einsum("sk,ske->se", norm_top, one_hot)
 
@@ -140,7 +153,8 @@ def reference_moe(params, x, cfg: MoEConfig):
     Note: with drop_tokens capacity limits, optimized paths may drop tokens
     the oracle keeps; tests account for that explicitly.
     """
-    combine_weights, _, _, aux = reference_gate(x, params["gate_w"], cfg)
+    combine_weights, _, _, aux = reference_gate(
+        x, params["gate_w"], cfg, params.get("gate_bias"))
     xs = x.astype(cfg.dtype)
     all_out = jnp.stack(
         [expert_ffn(xs, params, cfg, e) for e in range(cfg.num_experts)], axis=0

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from flashmoe_tpu.config import MoEConfig
 from flashmoe_tpu.models.reference import init_moe_params
+from flashmoe_tpu.ops.attention import rms_norm  # noqa: F401  (re-exported)
 from flashmoe_tpu.ops.moe import dense_ffn, moe_layer
 from flashmoe_tpu.parallel.ep import ep_moe_layer
 
@@ -56,24 +57,32 @@ def init_params(key, cfg: MoEConfig) -> dict:
         "lm_head": dense(keys[1], (h, cfg.vocab_size), h),
         "layers": [],
     }
-    moe_set = set(cfg.moe_layer_indices)
     for li in range(cfg.num_layers):
         lk = jax.random.split(keys[2 + li], 6)
         layer = {
             "attn_norm": jnp.ones((h,), cfg.param_dtype),
             "ffn_norm": jnp.ones((h,), cfg.param_dtype),
-            "wq": dense(lk[0], (h, nh * dh), h),
-            "wk": dense(lk[1], (h, nkv * dh), h),
-            "wv": dense(lk[2], (h, nkv * dh), h),
-            "wo": dense(lk[3], (nh * dh, h), nh * dh),
         }
-        if li in moe_set:
-            layer["moe"] = init_moe_params(lk[4], cfg)
+        if cfg.attention_kind == "mla":
+            ak = jax.random.split(lk[0], 4)
+            rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+            dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+            layer.update(
+                wq_a=dense(ak[0], (h, rq), h),
+                q_a_norm=jnp.ones((rq,), cfg.param_dtype),
+                wq_b=dense(ak[1], (rq, nh * (dn + dr)), rq),
+                wkv_a=dense(ak[2], (h, rkv + dr), h),
+                kv_a_norm=jnp.ones((rkv,), cfg.param_dtype),
+                wkv_b=dense(ak[3], (rkv, nh * (dn + dv)), rkv),
+                wo=dense(lk[3], (nh * dv, h), nh * dv))
         else:
-            layer["moe"] = init_moe_params(
-                lk[4], cfg.replace(num_experts=1, expert_top_k=1,
-                                   num_shared_experts=0)
-            )
+            layer.update(
+                wq=dense(lk[0], (h, nh * dh), h),
+                wk=dense(lk[1], (h, nkv * dh), h),
+                wv=dense(lk[2], (h, nkv * dh), h),
+                wo=dense(lk[3], (nh * dh, h), nh * dh))
+        layer["moe"] = init_moe_params(lk[4], cfg.ffn_config(li))
         params["layers"].append(layer)
     return params
 
@@ -81,13 +90,6 @@ def init_params(key, cfg: MoEConfig) -> dict:
 # ----------------------------------------------------------------------
 # Blocks
 # ----------------------------------------------------------------------
-
-def rms_norm(x, w, eps=1e-6):
-    dt = x.dtype
-    x = x.astype(jnp.float32)
-    x = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
-    return (x * w.astype(jnp.float32)).astype(dt)
-
 
 def _rope(q, k, positions, theta):
     """Rotary position embeddings. q/k: [B, T, N, D]."""
@@ -116,13 +118,24 @@ def attention(layer, x, cfg: MoEConfig, positions=None, mesh=None,
     sequence-parallel configs, the flash Pallas kernel on TPU, plain XLA
     otherwise.
     """
-    from flashmoe_tpu.ops.attention import attention_xla, flash_attention
+    from flashmoe_tpu.ops.attention import (
+        attention_xla, flash_attention, mla_paged_attention,
+    )
     from flashmoe_tpu.parallel.ringattn import ring_attention
 
     b, t, h = x.shape
-    nh, nkv, dh = cfg.num_heads, cfg.resolved_num_kv_heads, cfg.resolved_head_dim
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
+    if cfg.attention_kind == "mla":
+        # plain XLA, the first (decompressing) form: flash_attention
+        # assumes equal q/k/v head sizes, ring attention K/V shards
+        if mesh is not None and cfg.sp > 1:
+            raise NotImplementedError(
+                "attention_kind='mla' under sp > 1: ring attention "
+                "passes K/V shards; a latent-row ring is missing")
+        return mla_paged_attention(layer, x, cfg, None, 0, positions,
+                                   None, None, absorbed=False)[0]
+    nh, nkv, dh = cfg.num_heads, cfg.resolved_num_kv_heads, cfg.resolved_head_dim
 
     q = (x @ layer["wq"].astype(x.dtype)).reshape(b, t, nh, dh)
     k = (x @ layer["wk"].astype(x.dtype)).reshape(b, t, nkv, dh)
@@ -167,9 +180,7 @@ def _ffn(layer, x, cfg: MoEConfig, li: int, mesh, use_pallas):
     """FFN sub-block: MoE (possibly expert-parallel) or dense."""
     b, t, h = x.shape
     flat = x.reshape(b * t, h)
-    layer_cfg = cfg if li in cfg.moe_layer_indices else cfg.replace(
-        num_experts=1, expert_top_k=1, num_shared_experts=0
-    )
+    layer_cfg = cfg.ffn_config(li)
     if mesh is not None and layer_cfg.num_experts > 1 and cfg.ep > 1:
         axes = ("dp", "ep") + (("sp",) if cfg.sp > 1 else ())
         backend, chunks = _resolved_plan(cfg, mesh)

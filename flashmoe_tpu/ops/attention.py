@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from flashmoe_tpu.utils.telemetry import trace_span
+
 NEG_INF = -1e30
 
 
@@ -45,6 +47,175 @@ def attention_xla(q, k, v, *, causal: bool = True, q_offset: int | jax.Array = 0
         "bnts,bnsd->bntd", probs.astype(v.dtype), v,
         preferred_element_type=jnp.float32,
     ).astype(q.dtype)
+
+
+# ----------------------------------------------------------------------
+# Multi-head latent attention (MLA: DeepSeek-V2/V3 family), plain XLA
+# ----------------------------------------------------------------------
+#
+# x is the normed input of a block, h indexes the heads:
+#   c_q = RMSNorm(x W_qa);  [q_nope_h | q_rope_h] = c_q W_qb;  RoPE(q_rope_h)
+#   [c | k_r] = x W_kva;  c_kv = RMSNorm(c);  k_rope = RoPE(k_r)  (one key
+#   for all heads);  [k_nope_h | v_h] = c_kv W_kvb
+#   score_h(t, s) = (q_nope_h(t).k_nope_h(s) + q_rope_h(t).k_rope(s))
+#                   / sqrt(d_nope + d_rope),  causal softmax,  o_h = sum p v_h
+# What a cache keeps of a token is the LATENT row [c_kv | k_rope]
+# (kv_lora_rank + qk_rope_head_dim elements, nothing per head).  The
+# ABSORBED form gives the same numbers in another order and never
+# decompresses the context: with W_kvb split per head into W_uk_h and
+# W_uv_h,  q~_h = W_uk_h q_nope_h,  score_h = [q~_h | q_rope_h] . latent(s),
+# o~_h = sum p latent(s),  o_h = o~_h[:rank] W_uv_h.
+
+def rms_norm(x, w, eps=1e-6):
+    dt = x.dtype
+    x = x.astype(jnp.float32)
+    x = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return (x * w.astype(jnp.float32)).astype(dt)
+
+
+def rope_adjacent(x, positions, theta):
+    """Rotary embedding over ADJACENT pairs (2i, 2i+1) of the last axis
+    (the published ``rope_interleave``).  x: [B, T, D] or [B, T, N, D];
+    positions: [B, T]."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    ang = positions[..., None].astype(jnp.float32) * freq     # [B, T, half]
+    if x.ndim == 4:
+        ang = ang[:, :, None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+    x1, x2 = xf[..., 0], xf[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def mla_project(layer, x, cfg, positions):
+    """x: [B, T, H] normed -> (q_nope [B, T, N, d_nope], q_rope
+    [B, T, N, d_rope] roped, latent [B, T, rank + d_rope]: the normed
+    ``c_kv`` beside the roped shared key, the row a cache keeps)."""
+    b, t, _ = x.shape
+    nh, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    dc = cfg.kv_lora_rank
+    c_q = rms_norm(x @ layer["wq_a"].astype(x.dtype), layer["q_a_norm"])
+    q = (c_q @ layer["wq_b"].astype(x.dtype)).reshape(b, t, nh, dn + dr)
+    q_rope = rope_adjacent(q[..., dn:], positions, cfg.rope_theta)
+    kv = x @ layer["wkv_a"].astype(x.dtype)                   # [B, T, dc+dr]
+    c_kv = rms_norm(kv[..., :dc], layer["kv_a_norm"])
+    k_rope = rope_adjacent(kv[..., dc:], positions, cfg.rope_theta)
+    return q[..., :dn], q_rope, jnp.concatenate([c_kv, k_rope], axis=-1)
+
+
+def mla_attend(layer, q_nope, q_rope, latent_ctx, cfg, q_pos, *,
+               absorbed: bool):
+    """Causal MLA of T queries a row over a context of latent rows.
+
+    q_nope / q_rope: [B, T, N, .]; latent_ctx: [B, S, rank + d_rope], row
+    s the latent of position s; q_pos: [B, T] the queries' positions (a
+    query sees s <= its position: rows past it may hold anything).
+    ``absorbed`` picks the order of the products (see above): the plain
+    form decompresses K and V of all S rows, the absorbed form touches
+    the latent rows only.  Returns the block's attention output
+    [B, T, H]."""
+    b, t, nh, dn = q_nope.shape
+    dc, dv = cfg.kv_lora_rank, cfg.v_head_dim
+    dt = q_nope.dtype
+    w_kvb = layer["wkv_b"].astype(dt).reshape(dc, nh, dn + dv)
+    w_uk, w_uv = w_kvb[..., :dn], w_kvb[..., dn:]
+    scale = (dn + cfg.qk_rope_head_dim) ** -0.5
+    mask = (jnp.arange(latent_ctx.shape[1])[None, None, None, :]
+            <= q_pos[:, None, :, None])
+    f32 = dict(preferred_element_type=jnp.float32)
+    if absorbed:
+        with trace_span("attn.mla_decode"):
+            q_lat = jnp.einsum("btnd,cnd->btnc", q_nope, w_uk, **f32)
+            q_cat = jnp.concatenate([q_lat.astype(dt), q_rope], axis=-1)
+            logits = jnp.einsum("btnc,bsc->bnts", q_cat, latent_ctx,
+                                **f32) * scale
+            probs = jax.nn.softmax(jnp.where(mask, logits, NEG_INF),
+                                   axis=-1).astype(dt)
+            # heads and span rows as ONE row axis of a plain batched
+            # product over the latent rows
+            o_lat = jnp.einsum(
+                "bms,bsc->bmc", probs.reshape(b, nh * t, -1), latent_ctx,
+                **f32).reshape(b, nh, t, -1).transpose(0, 2, 1, 3)[..., :dc]
+            ctx = jnp.einsum("btnc,cnd->btnd", o_lat.astype(dt), w_uv,
+                             **f32)
+    else:
+        with trace_span("attn.mla_prefill"):
+            c_kv, k_rope = latent_ctx[..., :dc], latent_ctx[..., dc:]
+            k_nope = jnp.einsum("bsc,cnd->bsnd", c_kv, w_uk,
+                                **f32).astype(dt)
+            v = jnp.einsum("bsc,cnd->bsnd", c_kv, w_uv, **f32).astype(dt)
+            logits = (jnp.einsum("btnd,bsnd->bnts", q_nope, k_nope, **f32)
+                      + jnp.einsum("btnr,bsr->bnts", q_rope, k_rope,
+                                   **f32)) * scale
+            probs = jax.nn.softmax(jnp.where(mask, logits, NEG_INF),
+                                   axis=-1).astype(dt)
+            ctx = jnp.einsum("bnts,bsnd->btnd", probs, v, **f32)
+    ctx = ctx.reshape(b, t, nh * dv).astype(dt)
+    return ctx @ layer["wo"].astype(dt)
+
+
+def store_latent(pool, li: int, rows_kv, page_ids, rows):
+    """Scatter a span's latent rows into layer ``li`` of the latent pool.
+    pool: [L, P, page * C] (a page's rows side by side: see
+    ``serving/kvcache.LatentPagedCache``); rows_kv: [B, T, C]; page_ids /
+    rows: [B, T].  One window of C elements a token, at column
+    ``row * C`` of its page.  ``rows`` None: the span fills WHOLE pages
+    (a prefill chunk); page_ids is then [B, T // page], one id a page,
+    and a page is one window (the chip walks a scatter window by window:
+    1024 token windows a layer were a fifth of a chunk's time).  The
+    layer is an index of the scatter, not a slice taken out and put back:
+    that copied the layer's 300 MB twice in every program."""
+    c = rows_kv.shape[-1]
+    lcol = jnp.full(page_ids.shape, li, page_ids.dtype)
+    if rows is None:
+        idx = jnp.stack([lcol, page_ids], axis=-1).reshape(-1, 2)
+        upd = rows_kv.reshape(idx.shape[0], -1)
+        dnums = jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1,), inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1))
+    else:
+        idx = jnp.stack([lcol, page_ids, rows * c], axis=-1).reshape(-1, 3)
+        upd = rows_kv.reshape(-1, c)
+        dnums = jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1,), inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1, 2))
+    return jax.lax.scatter(
+        pool, idx, upd.astype(pool.dtype), dnums,
+        mode=jax.lax.GatherScatterMode.CLIP)
+
+
+def gather_latent(pool, li: int, block_tables, c: int):
+    """Each slot's context window from layer ``li`` of the latent pool.
+    pool: [L, P, page * C]; block_tables: [B, n] -> [B, n * page, C]; rows
+    past a slot's length are scratch and are masked by the caller."""
+    return pool[li, block_tables].reshape(block_tables.shape[0], -1, c)
+
+
+def mla_paged_attention(layer, x, cfg, pool, li, pos, write, block_tables,
+                        *, absorbed: bool):
+    """THE multi-head latent attention of every cached path: project a
+    span of T tokens a slot, write its latent rows to layer ``li``'s
+    pages, gather the context, attend.  Prefill (whole and chunked),
+    decode and verify all call this and nothing else.
+
+    x: [B, T, H] normed; pool: the latent pool [L, P, page * C], or None
+    for a whole prompt at once (nothing is cached yet, the context is the
+    span itself); pos: [B, T] absolute positions; write:
+    ``(page_ids, rows)``, each [B, T], where the span's rows go (or
+    ``(page_ids [B, T // page], None)`` for a span of whole pages);
+    block_tables: [B, n] pages to gather as context.  Returns (attention
+    output [B, T, H], the pool, the span's latent rows [B, T, C])."""
+    q_nope, q_rope, latent = mla_project(layer, x, cfg, pos)
+    if pool is None:
+        ctx = latent
+    else:
+        pool = store_latent(pool, li, latent, *write)
+        ctx = gather_latent(pool, li, block_tables, latent.shape[-1])
+    out = mla_attend(layer, q_nope, q_rope, ctx, cfg, pos,
+                     absorbed=absorbed)
+    return out, pool, latent
 
 
 # ----------------------------------------------------------------------

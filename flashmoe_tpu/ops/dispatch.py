@@ -127,6 +127,22 @@ def dispatch(x, plan: DispatchPlan, cfg: MoEConfig, capacity: int):
     return buf.astype(x.dtype)
 
 
+def _surviving_weights(plan: DispatchPlan, combine_weights, cfg: MoEConfig):
+    """[S, K] f32 combine weights of the slots that were not dropped,
+    renormalized so that a token keeps across its remaining experts the
+    weight its choices summed to: one for a router whose weights sum to
+    one (matches reference 1/sum(w) scaling), else the router's own sum
+    (``norm_topk_prob`` off or a ``routed_scaling_factor``: the layer's
+    output scale is part of the published model)."""
+    w = jnp.where(plan.valid, combine_weights, 0.0).astype(jnp.float32)
+    denom = jnp.sum(w, axis=-1, keepdims=True)
+    if cfg.norm_topk_prob and cfg.routed_scaling_factor == 1.0:
+        return w / jnp.maximum(denom, 1e-20)
+    total = jnp.sum(combine_weights.astype(jnp.float32), axis=-1,
+                    keepdims=True)
+    return w * (total / jnp.maximum(denom, 1e-20))
+
+
 def sorted_return_maps(plan: DispatchPlan, combine_weights, cfg: MoEConfig,
                        capacity: int, rows_pad: int):
     """Token-sorted return placement for the in-kernel (fused) combine.
@@ -154,9 +170,7 @@ def sorted_return_maps(plan: DispatchPlan, combine_weights, cfg: MoEConfig,
     """
     s, k = plan.expert_idx.shape
     e = cfg.num_experts
-    w = jnp.where(plan.valid, combine_weights, 0.0).astype(jnp.float32)
-    denom = jnp.sum(w, axis=-1, keepdims=True)
-    w = w / jnp.maximum(denom, 1e-20)
+    w = _surviving_weights(plan, combine_weights, cfg)
     # sorted-buffer row of each (token, j) assignment
     pos = (jnp.arange(s, dtype=jnp.int32)[:, None] * k
            + jnp.arange(k, dtype=jnp.int32)[None, :])      # [S, K]
@@ -196,11 +210,7 @@ def combine(expert_out, plan: DispatchPlan, combine_weights, cfg: MoEConfig,
     # (the count-aware fused kernel skips empty tiles entirely) — zero the
     # values, not just the weights, or NaN garbage * 0.0 = NaN propagates
     gathered = jnp.where(plan.valid[..., None], gathered, 0)
-    w = jnp.where(plan.valid, combine_weights, 0.0).astype(jnp.float32)
-    # renormalize over surviving slots so dropped tokens keep unit weight
-    # across their remaining experts (matches reference 1/sum(w) scaling).
-    denom = jnp.sum(w, axis=-1, keepdims=True)
-    w = w / jnp.maximum(denom, 1e-20)
+    w = _surviving_weights(plan, combine_weights, cfg)
     out = jnp.einsum(
         "skh,sk->sh", gathered.astype(jnp.float32), w,
         preferred_element_type=jnp.float32,

@@ -52,9 +52,15 @@ class RouterOutput(NamedTuple):
 
 
 def _finish(cfg: MoEConfig, top_p, top_idx, probs_sum, counts, zsum, s_tokens):
-    """Shared epilogue: normalize top-k weights, form aux/z losses."""
-    denom = jnp.sum(top_p, axis=-1, keepdims=True)
-    combine_weights = (top_p / jnp.maximum(denom, 1e-20)).astype(cfg.accum_dtype)
+    """Shared epilogue: normalize top-k weights (unless the config says
+    the published model does not: ``norm_topk_prob``), scale them by
+    ``routed_scaling_factor``, form aux/z losses."""
+    if cfg.norm_topk_prob:
+        denom = jnp.sum(top_p, axis=-1, keepdims=True)
+        top_p = top_p / jnp.maximum(denom, 1e-20)
+    if cfg.routed_scaling_factor != 1.0:
+        top_p = top_p * cfg.routed_scaling_factor
+    combine_weights = top_p.astype(cfg.accum_dtype)
     probs_mean = probs_sum / s_tokens
     density = counts.astype(cfg.accum_dtype) / (s_tokens * cfg.expert_top_k)
     # Switch-transformer load-balance loss: E * sum(density * mean_prob).
@@ -74,8 +80,15 @@ def _finish(cfg: MoEConfig, top_p, top_idx, probs_sum, counts, zsum, s_tokens):
 # XLA reference path
 # ----------------------------------------------------------------------
 
-def router_xla(x, gate_w, cfg: MoEConfig) -> RouterOutput:
-    """Router in plain XLA ops. x: [S, H], gate_w: [H, E]."""
+def router_xla(x, gate_w, cfg: MoEConfig, gate_bias=None) -> RouterOutput:
+    """Router in plain XLA ops. x: [S, H], gate_w: [H, E].
+
+    ``cfg.router_score='sigmoid'``: the scores are sigmoid(logits); the
+    top-k is taken over ``scores + gate_bias`` (``gate_bias`` [E]: the
+    published ``e_score_correction_bias``, which steers the SELECTION and
+    nothing else) and the combine weights are the chosen experts' own
+    scores, normalised and scaled in :func:`_finish`.  The load-balance
+    statistics read the scores normalised over the experts."""
     s = x.shape[0]
     logits = jnp.dot(
         x.astype(cfg.accum_dtype),
@@ -86,8 +99,18 @@ def router_xla(x, gate_w, cfg: MoEConfig) -> RouterOutput:
 
     if chaos_inject.is_armed("skewed_routing"):  # trace-time check only
         logits = chaos_inject.poison_logits(logits)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_idx = jax.lax.top_k(probs, cfg.expert_top_k)
+    if cfg.router_score == "sigmoid" or gate_bias is not None:
+        scores = (jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
+        select = scores if gate_bias is None else (
+            scores + gate_bias.astype(scores.dtype)[None, :])
+        _, top_idx = jax.lax.top_k(select, cfg.expert_top_k)
+        top_p = jnp.take_along_axis(scores, top_idx, axis=-1)
+        probs = scores / jnp.maximum(
+            jnp.sum(scores, axis=-1, keepdims=True), 1e-20)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_idx = jax.lax.top_k(probs, cfg.expert_top_k)
     counts = jnp.sum(
         jax.nn.one_hot(top_idx, cfg.num_experts, dtype=jnp.int32), axis=(0, 1)
     )
@@ -533,13 +556,27 @@ def apply_replicas(out: RouterOutput, cfg: MoEConfig) -> RouterOutput:
 
 
 def router(x, gate_w, cfg: MoEConfig, use_pallas: bool = True,
-           interpret: bool = False) -> RouterOutput:
+           interpret: bool = False, gate_bias=None) -> RouterOutput:
     """Dispatch to a fused kernel on TPU, XLA fallback elsewhere.
     Differentiable on all paths.  Large-E configs beyond the single-tile
     kernel's VMEM budget (:func:`gate_vmem_bytes`) use the two-pass
-    expert-tiled kernel."""
+    expert-tiled kernel.
+
+    The Pallas kernels are SOFTMAX kernels with no selection bias: a
+    config with ``router_score='sigmoid'`` or ``router_bias`` takes the
+    XLA arm (:func:`router_xla`) on every path, whatever ``use_pallas``
+    says.  A ``router_bias`` config must be handed its ``gate_bias``: a
+    caller that has none to pass (the mesh paths) is refused here rather
+    than routed without it."""
     from flashmoe_tpu.chaos import inject as chaos_inject
 
+    if cfg.router_bias and gate_bias is None:
+        raise NotImplementedError(
+            "this config routes with a selection bias (router_bias) and "
+            "the caller passed no gate_bias: only ops/moe.py:moe_layer "
+            "carries it (the expert-parallel layers do not yet)")
+    if cfg.router_score != "softmax" or gate_bias is not None:
+        return apply_replicas(router_xla(x, gate_w, cfg, gate_bias), cfg)
     if chaos_inject.is_armed("skewed_routing") and use_pallas:
         # the skew fault biases router LOGITS (router_xla hook); the
         # fused gate kernels compute logits in-kernel, so chaos drills

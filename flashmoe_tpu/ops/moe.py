@@ -73,8 +73,54 @@ def dense_ffn(params, x, cfg: MoEConfig):
     return down.astype(x.dtype)
 
 
+def routed_rows_ffn(params, x, r, cfg: MoEConfig):
+    """Dropless expert FFN over exactly the ROUTED rows, in plain XLA.
+
+    The capacity arm computes ``E x capacity`` rows, and a dropless
+    config's capacity is the token count: ``E x S`` rows where ``S x K``
+    are routed (32 x too many at 256 experts top-8, and [E, S, .] buffers
+    beside the weights).  Here the ``S x K`` (token, choice) rows are
+    sorted by expert and the three products are ``jax.lax.ragged_dot``
+    (on a TPU XLA's own grouped matmul, which reads an expert's weights
+    only if rows reached it; elsewhere a masked dense product).  No row is
+    padded, none is dropped.  x: [S, H]; r: the router's output.  Returns
+    [S, H] float32, the weighted sum of every token's K expert outputs."""
+    s, h = x.shape
+    k = cfg.expert_top_k
+    act = activation_fn(cfg.hidden_act)
+    f32 = dict(preferred_element_type=cfg.accum_dtype)
+    with trace_span("moe.dispatch"):
+        flat_e = r.expert_idx.reshape(-1)              # row t*K + j
+        order = jnp.argsort(flat_e, stable=True)
+        sorted_e = flat_e[order]
+        sizes = r.expert_counts                        # rows an expert
+        xs = x.astype(cfg.dtype)[order // k]           # [S*K, H]
+    with trace_span("moe.expert"):
+        up = jax.lax.ragged_dot(xs, params["w_up"].astype(xs.dtype), sizes,
+                                **f32)
+        up = up + params["b_up"].astype(cfg.accum_dtype)[sorted_e]
+        if cfg.gated_ffn:
+            g = jax.lax.ragged_dot(
+                xs, params["w_gate"].astype(xs.dtype), sizes, **f32)
+            hidden = act(g) * up
+        else:
+            hidden = act(up)
+        y = jax.lax.ragged_dot(
+            hidden.astype(xs.dtype), params["w_down"].astype(xs.dtype),
+            sizes, **f32)
+        y = (y + params["b_down"].astype(cfg.accum_dtype)[sorted_e]
+             ).astype(xs.dtype)
+    with trace_span("moe.combine"):
+        back = jnp.argsort(order)                      # row t*K + j again
+        return jnp.einsum(
+            "skh,sk->sh", y[back].reshape(s, k, h).astype(jnp.float32),
+            r.combine_weights.astype(jnp.float32),
+            preferred_element_type=jnp.float32)
+
+
 def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
-                    capacity: int | None, interpret: bool) -> MoEOutput:
+                    capacity: int | None, interpret: bool,
+                    routed_rows: bool = False) -> MoEOutput:
     # quantized expert storage (flashmoe_tpu/quant/): resolve the FFN
     # weights to their dequant-in-compute form — payloads dequantize,
     # full-precision params fake-quant in-graph.  Called
@@ -92,9 +138,19 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
     # (parallel/ep.py): trace-time scopes, in every operation's op_name
     with trace_span("moe.gate"):
         r = router(x, params["gate_w"], cfg, use_pallas=use_pallas,
-                   interpret=interpret)
+                   interpret=interpret,
+                   gate_bias=params["gate_bias"] if cfg.router_bias
+                   else None)
     s, h = x.shape
-    dropless = use_pallas and not cfg.drop_tokens and capacity is None
+    if routed_rows and (use_pallas or cfg.drop_tokens
+                        or capacity is not None
+                        or cfg.degrade_unhealthy_experts):
+        raise ValueError(
+            "routed_rows is the dropless plain-XLA arm: it takes no "
+            "use_pallas, no drop_tokens config, no capacity and no "
+            "degrade_unhealthy_experts")
+    dropless = routed_rows or (
+        use_pallas and not cfg.drop_tokens and capacity is None)
     stats = None
     if cfg.collect_stats:
         # in-graph routing health (ops/stats.py): pure function of the
@@ -109,7 +165,9 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
     combine_w = r.combine_weights
     if degrade:
         from flashmoe_tpu.ops import health as hlt
-    if dropless:
+    if routed_rows:
+        out = routed_rows_ffn(params, x, r, cfg)
+    elif dropless:
         # dropless: ragged expert-sorted grouping + block-sparse grouped FFN
         # (S*K + E*block rows instead of the capacity path's E*S)
         bm = BLOCK_M if s >= BLOCK_M else max(8, ((s + 7) // 8) * 8)
@@ -202,8 +260,8 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
 
 
 def moe_layer(params, x, cfg: MoEConfig, *, use_pallas: bool | None = None,
-              capacity: int | None = None,
-              interpret: bool = False) -> MoEOutput:
+              capacity: int | None = None, interpret: bool = False,
+              routed_rows: bool = False) -> MoEOutput:
     """One MoE layer over a token shard x: [S, H].
 
     ``use_pallas`` selects the fused Pallas gate + grouped-FFN kernels;
@@ -214,6 +272,13 @@ def moe_layer(params, x, cfg: MoEConfig, *, use_pallas: bool | None = None,
     (``grouped_matmul``/``tgmm`` with residuals saved in the forward,
     :mod:`flashmoe_tpu.ops.expert`), while the cheap gate/dispatch/combine
     stages differentiate through XLA.
+
+    ``routed_rows`` (with ``use_pallas=False``, a dropless config and no
+    ``capacity``) computes the experts over exactly the ``S x K`` routed
+    rows (:func:`routed_rows_ffn`) where the capacity arm computes
+    ``E x S``: the serving path of the latent-attention models asks for
+    it (``models/generate.mla_span_forward``); every other caller keeps
+    the arm it had.
     """
     if use_pallas is None:
         use_pallas = interpret or jax.default_backend() == "tpu"
@@ -222,4 +287,5 @@ def moe_layer(params, x, cfg: MoEConfig, *, use_pallas: bool | None = None,
     if cfg.num_experts == 1:
         out = dense_ffn(params, x, cfg)
         return MoEOutput(out, zero, zero, jnp.full((1,), s, jnp.int32))
-    return _moe_layer_impl(params, x, cfg, use_pallas, capacity, interpret)
+    return _moe_layer_impl(params, x, cfg, use_pallas, capacity, interpret,
+                           routed_rows)

@@ -55,14 +55,15 @@ import numpy as np
 
 from flashmoe_tpu.config import MoEConfig
 from flashmoe_tpu.models.generate import (
-    init_cache, lm_logits, lm_logits_span, prefill_forward,
+    init_cache, lm_logits, lm_logits_span, mla_span_forward,
+    prefill_forward,
 )
 from flashmoe_tpu.models.transformer import rms_norm, _rope
 from flashmoe_tpu.ops.moe import moe_layer
 from flashmoe_tpu.serving.kvcache import (
-    SCRATCH_PAGE, PagePool, ShardedPagePool, ctx_pages_bucket,
-    gather_ctx, init_paged_cache, prompt_pad, store_prefill,
-    store_token, store_tokens,
+    SCRATCH_PAGE, LatentPagedCache, PagedKVCache, PagePool,
+    ShardedPagePool, ctx_pages_bucket, gather_ctx, init_paged_cache,
+    page_size_of, prompt_pad, store_prefill, store_token, store_tokens,
 )
 from flashmoe_tpu.serving.speculate import (
     DraftState, SpecConfig, spec_stats_fields,
@@ -255,8 +256,18 @@ def _prefill_padded(params, cfg: MoEConfig, prompt_padded, true_len):
     """Prefill one padded prompt: [1, T_pad] int32 -> (logits [V] at
     the true last position, k_seq/v_seq [L, N_kv, T_pad, D]).  Pad
     positions compute garbage no causal query before them ever sees;
-    their K/V rows land in pages the length mask never exposes."""
+    their K/V rows land in pages the length mask never exposes.  An MLA
+    config returns (logits, latent rows [L, T_pad, C]): what its one
+    pool keeps."""
     t_pad = prompt_padded.shape[1]
+    if cfg.attention_kind == "mla":
+        x, _, latents = mla_span_forward(
+            params, cfg, params["embed"].astype(cfg.dtype)[prompt_padded],
+            None, jnp.arange(t_pad, dtype=jnp.int32)[None, :], None, None,
+            absorbed=False)
+        h = jax.lax.dynamic_slice(
+            x, (0, true_len - 1, 0), (1, 1, x.shape[-1]))
+        return lm_logits(params, cfg, h)[0], latents[:, 0]
     cache = init_cache(cfg, 1, t_pad)
     x, cache = prefill_forward(params, cfg, prompt_padded, cache)
     h = jax.lax.dynamic_slice(
@@ -266,7 +277,7 @@ def _prefill_padded(params, cfg: MoEConfig, prompt_padded, true_len):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
-def _prefill_chunk(params, cfg: MoEConfig, k_pages, v_pages, chunk_toks,
+def _prefill_chunk(params, cfg: MoEConfig, pools, chunk_toks,
                    block_table, chunk_page_ids, start_pos, rel_last):
     """Prefill ONE fixed-size chunk of a long prompt directly into the
     paged cache.
@@ -277,7 +288,8 @@ def _prefill_chunk(params, cfg: MoEConfig, k_pages, v_pages, chunk_toks,
     THIS chunk writes; start_pos: absolute position of the chunk's
     first token; rel_last: in-chunk index of the prompt's true last
     token (clipped — only the chunk containing it keeps the logits).
-    Returns (logits [V], k_pages, v_pages).
+    ``pools`` is the engine's cache (a K/V pair or one latent pool).
+    Returns (logits [V], pools).
 
     Per-layer math mirrors :func:`_prefill_padded`'s single-shot path
     at chunk granularity: the chunk's K/V land in their pages BEFORE
@@ -286,13 +298,22 @@ def _prefill_chunk(params, cfg: MoEConfig, k_pages, v_pages, chunk_toks,
     write garbage rows that decode overwrites before any causal query
     exposes them — the whole-prefill invariant, per chunk."""
     c = chunk_toks.shape[1]
-    nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
-                   cfg.resolved_head_dim)
-    page = k_pages.shape[3]
-    n_ctx = block_table.shape[0] * page
-    n_c = c // page
     positions = start_pos + jnp.arange(c, dtype=jnp.int32)   # [C]
     x = params["embed"].astype(cfg.dtype)[chunk_toks]        # [1, C, H]
+    if cfg.attention_kind == "mla":
+        write = (chunk_page_ids[None, :], None)      # whole pages
+        x, pages, _ = mla_span_forward(
+            params, cfg, x, pools.pages, positions[None, :], write,
+            block_table[None, :], absorbed=False)
+        h = jax.lax.dynamic_slice(
+            x, (0, rel_last, 0), (1, 1, x.shape[-1]))
+        return lm_logits(params, cfg, h)[0], LatentPagedCache(pages)
+    k_pages, v_pages = pools
+    nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
+                   cfg.resolved_head_dim)
+    page = pools.page_size
+    n_ctx = block_table.shape[0] * page
+    n_c = c // page
     for li, layer in enumerate(params["layers"]):
         h_in = rms_norm(x, layer["attn_norm"])
         q = (h_in @ layer["wq"].astype(x.dtype)).reshape(1, c, nh, dh)
@@ -334,29 +355,37 @@ def _prefill_chunk(params, cfg: MoEConfig, k_pages, v_pages, chunk_toks,
         x = x + o.out.reshape(1, c, -1).astype(x.dtype)
 
     h = jax.lax.dynamic_slice(x, (0, rel_last, 0), (1, 1, x.shape[-1]))
-    return lm_logits(params, cfg, h)[0], k_pages, v_pages
+    return lm_logits(params, cfg, h)[0], PagedKVCache(k_pages, v_pages)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
-def _paged_decode_step(params, cfg: MoEConfig, k_pages, v_pages, toks,
+def _paged_decode_step(params, cfg: MoEConfig, pools, toks,
                        block_tables, positions):
     """One decode step for the whole slot grid.
 
     toks: [B] int32 tokens to feed; block_tables: [B, n] page ids
     (bucketed); positions: [B] write positions (= each slot's current
     length; inactive slots pass 0 with an all-scratch table).  Returns
-    (logits [B, V] f32, k_pages, v_pages).  Mirrors
+    (logits [B, V] f32, pools).  Mirrors
     ``generate._decode_step``'s per-layer arithmetic with per-slot
-    positions and paged K/V."""
+    positions and paged K/V; an MLA config runs the absorbed form over
+    its latent pool."""
     b = toks.shape[0]
-    nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
-                   cfg.resolved_head_dim)
-    page = k_pages.shape[3]
-    n_ctx = block_tables.shape[1] * page
+    page = page_size_of(pools, cfg)
     x = params["embed"].astype(cfg.dtype)[toks][:, None, :]  # [B, 1, H]
     page_ids = jnp.take_along_axis(
         block_tables, (positions // page)[:, None], axis=1)[:, 0]
     rows = positions % page
+    if cfg.attention_kind == "mla":
+        x, pages, _ = mla_span_forward(
+            params, cfg, x, pools.pages, positions[:, None],
+            (page_ids[:, None], rows[:, None]), block_tables,
+            absorbed=True)
+        return lm_logits(params, cfg, x), LatentPagedCache(pages)
+    k_pages, v_pages = pools
+    nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
+                   cfg.resolved_head_dim)
+    n_ctx = block_tables.shape[1] * page
     for li, layer in enumerate(params["layers"]):
         h_in = rms_norm(x, layer["attn_norm"])
         q = (h_in @ layer["wq"].astype(x.dtype)).reshape(b, 1, nh, dh)
@@ -395,11 +424,11 @@ def _paged_decode_step(params, cfg: MoEConfig, k_pages, v_pages, toks,
                       use_pallas=False)
         x = x + o.out.reshape(b, 1, -1).astype(x.dtype)
 
-    return lm_logits(params, cfg, x), k_pages, v_pages
+    return lm_logits(params, cfg, x), PagedKVCache(k_pages, v_pages)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
-def _paged_verify_step(params, cfg: MoEConfig, k_pages, v_pages, toks,
+def _paged_verify_step(params, cfg: MoEConfig, pools, toks,
                        block_tables, positions):
     """Speculative verify: score a ``T = draft_tokens + 1`` position
     SPAN per slot in one forward (ISSUE 20).
@@ -407,8 +436,8 @@ def _paged_verify_step(params, cfg: MoEConfig, k_pages, v_pages, toks,
     toks: [B, T] int32 — column 0 is the canonical last-sampled token,
     columns 1..k the drafted continuation (pad past the real drafts);
     positions: [B] base write positions (column t lands at
-    ``positions + t``).  Returns (logits [B, T, V] f32, k_pages,
-    v_pages): logits[:, t] is the next-token distribution after feeding
+    ``positions + t``).  Returns (logits [B, T, V] f32, pools):
+    logits[:, t] is the next-token distribution after feeding
     column t — column 0 is bit-equal to what :func:`_paged_decode_step`
     returns for the same token, columns 1..k are what it WOULD return
     after each draft, all for one weight pass (the planner's decode
@@ -423,9 +452,7 @@ def _paged_verify_step(params, cfg: MoEConfig, k_pages, v_pages, toks,
     state, and the next step's span overwrites those exact rows before
     any causal mask exposes them (the prefill pad-row invariant)."""
     b, t_span = toks.shape
-    nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
-                   cfg.resolved_head_dim)
-    page = k_pages.shape[3]
+    page = page_size_of(pools, cfg)
     ntab = block_tables.shape[1]
     n_ctx = ntab * page
     x = params["embed"].astype(cfg.dtype)[toks]              # [B, T, H]
@@ -437,6 +464,15 @@ def _paged_verify_step(params, cfg: MoEConfig, k_pages, v_pages, toks,
         valid, jnp.take_along_axis(block_tables, pidx, axis=1),
         jnp.int32(SCRATCH_PAGE))
     rows = jnp.where(valid, pos % page, 0)
+    if cfg.attention_kind == "mla":
+        # a short span over a long context: the absorbed form, as decode
+        x, pages, _ = mla_span_forward(
+            params, cfg, x, pools.pages, pos, (page_ids, rows),
+            block_tables, absorbed=True)
+        return lm_logits_span(params, cfg, x), LatentPagedCache(pages)
+    k_pages, v_pages = pools
+    nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
+                   cfg.resolved_head_dim)
     for li, layer in enumerate(params["layers"]):
         h_in = rms_norm(x, layer["attn_norm"])
         q = (h_in @ layer["wq"].astype(x.dtype)).reshape(b, t_span, nh,
@@ -479,7 +515,19 @@ def _paged_verify_step(params, cfg: MoEConfig, k_pages, v_pages, toks,
                       layer_cfg, use_pallas=False)
         x = x + o.out.reshape(b, t_span, -1).astype(x.dtype)
 
-    return lm_logits_span(params, cfg, x), k_pages, v_pages
+    return lm_logits_span(params, cfg, x), PagedKVCache(k_pages, v_pages)
+
+
+# The same three programs with the cache DONATED: the pool is updated in
+# place and the caller's arrays die with the call.  An MLA model is served
+# through these: beside 11 GB of weights the chip has no room for the
+# input pool, the output pool and the pool of a prefill chunk dispatched
+# while the decode step still runs.  (The K/V programs above keep their
+# second copy: ROADMAP S4.)
+_INPLACE = {
+    fn.__name__: jax.jit(fn.__wrapped__, static_argnames=("cfg",),
+                         donate_argnames=("pools",))
+    for fn in (_prefill_chunk, _paged_decode_step, _paged_verify_step)}
 
 
 # ----------------------------------------------------------------------
@@ -808,6 +856,20 @@ class ServingEngine:
         a replica that hangs mid-step stops beating mid-step, so the
         watchdog catches it without waiting for the step boundary.
         None (the default) makes zero calls — byte-identical."""
+        mla = cfg.attention_kind == "mla"
+        sv = serve if serve is not None else ServeConfig()
+        if mla and sv.ep_shards > 1:
+            raise NotImplementedError(
+                "attention_kind='mla' with ep_shards > 1: _ep_decode_fn "
+                "and _ep_verify_fn shard a K/V page pair over the mesh "
+                "and attend per kv head; a latent pool has neither, and "
+                "their bodies have no latent arm yet")
+        if mla and prefill_fn is not None:
+            raise NotImplementedError(
+                "attention_kind='mla' with a prefill_fn (the fabric's KV "
+                "handoff): the seam's contract is (logits, k_seq, v_seq) "
+                "and fabric/handoff.py encodes K/V pages; a latent-row "
+                "payload is missing")
         if cfg.drop_tokens:
             raise ValueError(
                 "the serving engine requires a dropless config "
@@ -937,9 +999,8 @@ class ServingEngine:
         self.pool = (ShardedPagePool(self.serve.num_pages, d) if d > 1
                      else PagePool(self.serve.num_pages))
         if self.quant_info is not None:
-            page_bytes = (self.cache.k_pages.nbytes
-                          + self.cache.v_pages.nbytes
-                          ) / self.serve.num_pages
+            page_bytes = (sum(p.nbytes for p in self.cache)
+                          / self.serve.num_pages)
             extra = int(self.quant_info["freed_bytes"] // page_bytes)
             self.quant_info.update(
                 page_bytes=int(page_bytes), extra_kv_pages=extra)
@@ -952,6 +1013,9 @@ class ServingEngine:
                 num_pages=self.serve.num_pages)
             self.metrics.gauge("serve.quant_freed_mb",
                                self.quant_info["freed_bytes"] / 2**20)
+        # bytes one cached token costs over all layers (a gauge, and on
+        # every serve_step record): what the pool's pages are made of
+        self.metrics.gauge("serve.kv_token_bytes", cfg.kv_token_bytes)
         self.queue: deque = deque()       # (arrival_step, _Slot-seed)
         self.slots: list[_Slot | None] = [None] * self.serve.max_batch
         self._logits = jnp.zeros(
@@ -1241,20 +1305,19 @@ class ServingEngine:
                         prompt, ((0, 0), (0, t_pad - t0)),
                         constant_values=sv.pad_token)
                 with trace_span("serve.prefill"):
+                    # (logits, one dense run per pool of the cache)
                     if self._prefill_fn is not None:
-                        logits, k_seq, v_seq = self._prefill_fn(
+                        logits, *seqs = self._prefill_fn(
                             prompt, t0, rid=orig.rid)
                     else:
-                        logits, k_seq, v_seq = _prefill_padded(
+                        logits, *seqs = _prefill_padded(
                             self.params, self.cfg, prompt,
                             jnp.int32(t0))
                     page_ids = jnp.asarray(
                         self._global_pages(slot, pages), jnp.int32)
-                    self.cache = self.cache._replace(
-                        k_pages=store_prefill(self.cache.k_pages,
-                                              k_seq, page_ids),
-                        v_pages=store_prefill(self.cache.v_pages,
-                                              v_seq, page_ids))
+                    self.cache = type(self.cache)(*(
+                        store_prefill(pool, seq, page_ids)
+                        for pool, seq in zip(self.cache, seqs)))
                 self._logits = self._logits.at[slot].set(logits)
                 self.slots[slot] = _Slot(
                     req=req, orig=orig, pages=list(pages), length=t0,
@@ -1320,14 +1383,12 @@ class ServingEngine:
                 # the span lands on THIS slot's request track
                 self.tracer.on_prefill_chunk(s.orig.rid)
             with trace_span("serve.prefill_chunk"):
-                logits, kp, vp = _prefill_chunk(
-                    self.params, self.cfg,
-                    self.cache.k_pages, self.cache.v_pages,
+                logits, self.cache = self._paged("_prefill_chunk")(
+                    self.params, self.cfg, self.cache,
                     jnp.asarray(toks)[None, :],
                     jnp.asarray(table),
                     jnp.asarray(chunk_ids, jnp.int32),
                     jnp.int32(pos), jnp.int32(rel_last))
-            self.cache = self.cache._replace(k_pages=kp, v_pages=vp)
             s.prefill_pos = pos + chunk
             if pos <= t0 - 1 < pos + chunk:
                 # prefill complete — arm the sampler, join decode
@@ -1382,6 +1443,13 @@ class ServingEngine:
             slot=victim, freed_pages=len(s.pages),
             emitted=delivered)
         return True
+
+    def _paged(self, name: str):
+        """The paged program ``name`` of this module (looked up at call
+        time), or for an MLA model its in-place twin."""
+        if self.cfg.attention_kind == "mla":
+            return _INPLACE[name]
+        return globals()[name]
 
     def _delivered(self, s: _Slot) -> int:
         """Tokens delivered across incarnations (an evicted request's
@@ -1509,13 +1577,12 @@ class ServingEngine:
                 self.cache.v_pages, jnp.asarray(feed),
                 jnp.asarray(tables[:, :n_ctx]),
                 jnp.asarray(positions))
+            self.cache = PagedKVCache(kp, vp)
         else:
-            span_logits, kp, vp = _paged_verify_step(
-                self.params, self.cfg, self.cache.k_pages,
-                self.cache.v_pages, jnp.asarray(feed),
+            span_logits, self.cache = self._paged("_paged_verify_step")(
+                self.params, self.cfg, self.cache, jnp.asarray(feed),
                 jnp.asarray(tables[:, :n_ctx]),
                 jnp.asarray(positions))
-        self.cache = self.cache._replace(k_pages=kp, v_pages=vp)
         self._spec_steps += 1
 
         self._phase("serve.sample")
@@ -1816,14 +1883,13 @@ class ServingEngine:
                     self.cache.v_pages, jnp.asarray(feed),
                     jnp.asarray(tables[:, :n_ctx]),
                     jnp.asarray(positions))
+                self.cache = PagedKVCache(kp, vp)
             else:
-                logits, kp, vp = _paged_decode_step(
-                    self.params, self.cfg, self.cache.k_pages,
-                    self.cache.v_pages, jnp.asarray(feed),
+                logits, self.cache = self._paged("_paged_decode_step")(
+                    self.params, self.cfg, self.cache, jnp.asarray(feed),
                     jnp.asarray(tables[:, :n_ctx]),
                     jnp.asarray(positions))
             self._logits = logits
-            self.cache = self.cache._replace(k_pages=kp, v_pages=vp)
             for i in active:
                 self.slots[i].length += 1
 
@@ -1884,6 +1950,7 @@ class ServingEngine:
             "compile_ms": round((compile_s1 - compile_s0) * 1e3, 3),
             "ctx_pages": ctx_pages,
             "ctx_pages_idle": round(ctx_idle, 3),
+            "kv_token_bytes": self.cfg.kv_token_bytes,
         }
         if self.serve.speculate is not None:
             rec["spec_tokens"] = int(n_extra or 0)

@@ -63,18 +63,49 @@ class PagedKVCache(NamedTuple):
         return self.k_pages.shape[3]
 
 
-def init_paged_cache(cfg: MoEConfig, num_pages: int,
-                     page_size: int) -> PagedKVCache:
-    """Allocate the pool.  ``num_pages`` includes the scratch page."""
+class LatentPagedCache(NamedTuple):
+    """The page pool of an MLA model (``cfg.attention_kind == 'mla'``):
+    ONE array ``[L, P, page * C]``, C = kv_lora_rank + qk_rope_head_dim,
+    holding a token a layer the normed latent beside the roped shared
+    key, and nothing per head.  A page's ``page`` rows lie side by side
+    in the last axis (row r at columns r*C .. r*C+C-1): as
+    ``[L, P, page, C]`` the chip would keep the array pages-minor (C =
+    576 is no multiple of its 128 lanes) and every program would copy
+    the whole pool into gather order and back.  Pages, block tables, the
+    scratch page and the allocators below are :class:`PagedKVCache`'s."""
+
+    pages: jax.Array
+
+    @property
+    def num_pages(self) -> int:
+        return self.pages.shape[1]
+
+
+def init_paged_cache(cfg: MoEConfig, num_pages: int, page_size: int):
+    """Allocate the pool the config's attention kind reads: a K/V pair
+    (:class:`PagedKVCache`) or one latent pool
+    (:class:`LatentPagedCache`).  ``num_pages`` includes the scratch
+    page."""
     if num_pages < 2:
         raise ValueError(f"num_pages={num_pages} must be >= 2 (page 0 "
                          f"is the reserved scratch page)")
     if page_size < 1:
         raise ValueError(f"page_size={page_size} must be >= 1")
+    if cfg.attention_kind == "mla":
+        return LatentPagedCache(jnp.zeros(
+            (cfg.num_layers, num_pages, page_size * cfg.kv_token_elems),
+            cfg.dtype))
     nkv, dh = cfg.resolved_num_kv_heads, cfg.resolved_head_dim
     shape = (cfg.num_layers, num_pages, nkv, page_size, dh)
     return PagedKVCache(jnp.zeros(shape, cfg.dtype),
                         jnp.zeros(shape, cfg.dtype))
+
+
+def page_size_of(pools, cfg: MoEConfig) -> int:
+    """Tokens a page of ``pools`` (either cache class) holds."""
+    if isinstance(pools, LatentPagedCache):
+        return pools.pages.shape[2] // cfg.kv_token_elems
+    return pools.page_size
 
 
 # ----------------------------------------------------------------------
@@ -129,8 +160,15 @@ def store_prefill(pages, seq_kv, page_ids):
     with ``T_pad = len(page_ids) * page``; page_ids: ``[n]`` int32.
     Positions past the true prompt length write garbage rows the
     length mask never exposes."""
-    l, nkv, t_pad, d = seq_kv.shape
     n = page_ids.shape[0]
+    if pages.ndim == 3:
+        # a latent pool [L, P, page * C] takes rows [L, T_pad, C]
+        l, t_pad, c = seq_kv.shape
+        if t_pad * c != n * pages.shape[2]:
+            raise ValueError(f"prefill run of {t_pad} rows does not fill "
+                             f"{n} pages of {pages.shape[2] // c}")
+        return pages.at[:, page_ids].set(seq_kv.reshape(l, n, -1))
+    l, nkv, t_pad, d = seq_kv.shape
     page = pages.shape[3]
     if t_pad != n * page:
         raise ValueError(f"prefill run of {t_pad} rows does not fill "

@@ -358,6 +358,12 @@ STRUCTURAL_FIELDS = frozenset({
     "num_shared_experts", "num_heads", "num_kv_heads", "head_dim",
     "gated_ffn", "router_jitter", "aux_loss_coef", "router_z_loss_coef",
     "rope_theta",
+    # the published architecture's own keys: which attention and router
+    # the model IS (softmax / mha defaults are every earlier preset's)
+    "attention_kind", "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+    "qk_rope_head_dim", "v_head_dim",
+    "router_score", "router_bias", "norm_topk_prob",
+    "routed_scaling_factor", "first_k_dense", "dense_intermediate_size",
     "dtype", "param_dtype", "accum_dtype",
     "dp", "ep", "tp", "sp", "pp",
 })

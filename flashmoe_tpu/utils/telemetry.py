@@ -233,6 +233,13 @@ SPAN_NAMES: dict[str, str] = {
     "moe.combine": "weighted gather back to token order",
     "moe.fused_kernel": "fused RDMA kernel (dispatch+FFN in one launch)",
     "moe.shared": "shared experts: the dense FFN every token takes",
+    "attn.mla_prefill":
+        "latent attention, first form: K and V of the whole context "
+        "decompressed from the latent rows (prefill, chunked prefill, "
+        "training forward)",
+    "attn.mla_decode":
+        "latent attention, absorbed form: scores and output taken over "
+        "the latent rows themselves (decode and verify steps)",
     "serve.prefill":
         "serving engine: single-pass prompt prefill into cache pages",
     "serve.prefill_chunk":

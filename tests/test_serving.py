@@ -898,14 +898,14 @@ def test_spec_off_graph_and_config_identity(params, prompts):
 
     def decode_jaxpr():
         sv = _spec_serve()
-        k, v = init_paged_cache(CFG, sv.num_pages, sv.page_size)
+        cache = init_paged_cache(CFG, sv.num_pages, sv.page_size)
         toks = jnp.zeros((sv.max_batch,), jnp.int32)
         pos = jnp.zeros((sv.max_batch,), jnp.int32)
         tables = jnp.zeros((sv.max_batch, sv.ctx_bucket_pages),
                            jnp.int32)
         closed = jax.make_jaxpr(
             lambda *a: _paged_decode_step.__wrapped__(params, CFG, *a))
-        return jaxpr_text(closed(k, v, toks, tables, pos).jaxpr)
+        return jaxpr_text(closed(cache, toks, tables, pos).jaxpr)
 
     before = decode_jaxpr()
     engine = ServingEngine(params, CFG, _spec_serve(speculate=2))

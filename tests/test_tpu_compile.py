@@ -317,3 +317,81 @@ def test_sampler_program_derives_its_keys_on_the_chip(one_chip):
     (out,) = jax.tree.leaves(compiled.out_info)
     assert out.shape == (b,) and out.dtype == jnp.int32
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+@pytest.fixture(scope="module")
+def mla_programs(one_chip):
+    """The benchmark cell's largest decode and prefill-chunk programs
+    (``joyai_flash``: the leading dense layer + 4 mixture layers in bf16,
+    32 slots, a 16384 x 16-token latent pool, tables at their 448 pages,
+    a 1024-token chunk), lowered as the engine runs them: the pool
+    donated."""
+    from flashmoe_tpu.models.presets import PRESETS
+    from flashmoe_tpu.models.transformer import init_params
+    from flashmoe_tpu.serving import engine as eng
+    from flashmoe_tpu.serving.kvcache import init_paged_cache
+
+    cfg = PRESETS["joyai-llm-flash"](num_layers=5, param_dtype=jnp.bfloat16)
+    on = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = on(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on(jax.eval_shape(lambda: init_paged_cache(cfg, 16384, 16)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32, sharding=one_chip)
+    return {
+        "decode": eng._INPLACE["_paged_decode_step"].lower(
+            params, cfg, cache, i32(32), i32(32, 448), i32(32)),
+        "chunk": eng._INPLACE["_prefill_chunk"].lower(
+            params, cfg, cache, i32(1, 1024), i32(448), i32(64), i32(),
+            i32())}
+
+
+@pytest.fixture(scope="module")
+def mla_decode_compiled(mla_programs):
+    compiled = mla_programs["decode"].compile()
+    return compiled, compiled.as_text()
+
+
+def test_mla_prefill_chunk_fits_and_computes_the_routed_rows(mla_programs):
+    """A 1024-token chunk at the widest context: the experts are XLA's
+    grouped matmul over the 8192 routed rows (three a mixture layer), no
+    [256, 1024, .] capacity buffer, and under 14.5 GB (13.63 as compiled;
+    the E x S arm took 16.16)."""
+    compiled = mla_programs["chunk"].compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 12.6e9 < total < 14.5e9
+    text = compiled.as_text()
+    assert text.count("ragged-dot-metadata = ") >= 1
+    assert "[8192,768]" in text and "[256,1024," not in text
+
+
+def test_mla_decode_step_fits_the_chip_in_place(mla_decode_compiled):
+    compiled, text = mla_decode_compiled
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    # 11.12 GB of weights + the 1.51 GB pool, once (donated: aliased to
+    # the output), + under 1.5 GB of temporaries: 13.83 GB as compiled
+    assert m.alias_size_in_bytes >= 5 * 16384 * 16 * 576 * 2
+    assert 12.6e9 < total < 14.5e9
+    # the pool arrives and leaves in gather order: no whole-pool copy
+    assert "bf16[5,16384,9216]{2,1,0" in text
+    import re
+    assert not re.search(r"bf16\[5,16384,9216\]\S* copy\(", text)
+
+
+def test_mla_decode_step_reads_latent_rows_only(mla_decode_compiled):
+    """No K or V of the whole context ([.., 32 heads, 7168, 128] in any
+    order) in the program the chip runs: the absorbed form."""
+    import re
+    _, text = mla_decode_compiled
+    shapes = set(re.findall(r"(?:bf16|f32)\[([0-9,]+)\]", text))
+    big = [s for s in shapes
+           if {"7168", "128"} <= set(s.split(","))
+           and s.split(",").count("32") >= 2]
+    assert big == []
+    assert "bf16[32,7168,576]" in text          # the gathered latent rows
+    assert "attn.mla_decode" in text and "moe.gate" in text
