@@ -18,8 +18,9 @@ per-step by construction.
 Sampling supports greedy, temperature, top-k and nucleus (top-p)
 truncation, plus per-request stop tokens — the retirement primitive the
 continuous-batching engine (:mod:`flashmoe_tpu.serving.engine`) builds
-on.  :func:`sample_tokens` is shared with that engine so the two
-samplers cannot drift.
+on.  That engine's batch mixes requests, so it has a sampler of its own
+with the knobs as vectors; tests/test_serving.py holds its rows to
+:func:`sample_tokens`' tokens.
 """
 
 from __future__ import annotations
@@ -290,9 +291,11 @@ def sample_tokens(logits, key, *, temperature: float = 0.0,
     truncates to the k highest logits; ``top_p < 1`` applies nucleus
     truncation (smallest prefix of the sorted distribution whose mass
     reaches ``top_p`` — the top token always survives).  Truncations
-    compose (top-k first, then top-p over the survivors).  Shared by
-    :func:`generate` and the serving engine's per-request sampler, so
-    the two can never drift."""
+    compose (top-k first, then top-p over the survivors).  The knobs
+    are static here, one set for the batch.  The serving engine mixes
+    requests, so its sampler takes them as vectors
+    (``serving.engine._sample_dynamic``: the same arithmetic row by row,
+    which ``tests/test_serving.py`` holds token-equal to this one)."""
     if temperature == 0.0:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     if not 0 < top_p <= 1.0:

@@ -761,40 +761,65 @@ def _token_keys(seeds, index):
         jax.random.PRNGKey(s), n))(seeds, index)
 
 
+def _rows_ask(temps, top_ks, top_ps, v):
+    """What each sampler row asks for, from its knobs: a draw, a top-k
+    cut, a nucleus cut.  ONE rule for the program (traced) and for the
+    host's count of what the program did (numpy).  ``top_p == 1`` asks
+    for no nucleus and gets none, whatever the rounding of a running
+    sum and whatever the batch's other rows ask for."""
+    return temps > 0.0, (top_ks > 0) & (top_ks < v), top_ps < 1.0
+
+
 def _sample_with_keys(logits, keys, temps, top_ks, top_ps):
     """Per-slot sampling with DYNAMIC per-request knobs (the engine's
     batch mixes requests): temperature <= 0 rows take the exact argmax
     (bit-equal to ``sample_tokens``' greedy arm); sampled rows apply
-    top-k then nucleus truncation, keyed per request."""
+    top-k then nucleus truncation, keyed per request.  The vocabulary
+    is sorted, once, only where a sampled row of this batch truncates
+    (a ``lax.cond`` on the knob vectors: nothing is read back)."""
     v = logits.shape[-1]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     neg = jnp.asarray(-1e30, jnp.float32)
     scaled = logits.astype(jnp.float32) / jnp.maximum(
         temps, 1e-6)[:, None]
-    sort_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-    kth = jnp.take_along_axis(
-        sort_desc, jnp.clip(top_ks - 1, 0, v - 1)[:, None], axis=1)
-    use_k = (top_ks > 0) & (top_ks < v)
-    scaled = jnp.where(use_k[:, None] & (scaled < kth), neg, scaled)
-    sort_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(sort_desc, axis=-1)
-    csum = jnp.cumsum(probs, axis=-1)
-    keep = (csum - probs) < top_ps[:, None]
-    thresh = jnp.min(
-        jnp.where(keep, sort_desc, jnp.inf), axis=-1, keepdims=True)
-    scaled = jnp.where(scaled < thresh, neg, scaled)
+    drawn, use_k, use_p = _rows_ask(temps, top_ks, top_ps, v)
+
+    def truncated():
+        sort_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+        kth = jnp.take_along_axis(
+            sort_desc, jnp.clip(top_ks - 1, 0, v - 1)[:, None], axis=1)
+        below_k = lambda x: use_k[:, None] & (x < kth)
+        # the top-k survivors in descending order, with no second sort:
+        # what falls under the k-th value already lies at the tail
+        sort_desc = jnp.where(below_k(sort_desc), neg, sort_desc)
+        probs = jax.nn.softmax(sort_desc, axis=-1)
+        csum = jnp.cumsum(probs, axis=-1)
+        keep = ((csum - probs) < top_ps[:, None]) | ~use_p[:, None]
+        thresh = jnp.min(
+            jnp.where(keep, sort_desc, jnp.inf), axis=-1, keepdims=True)
+        kept = jnp.where(below_k(scaled), neg, scaled)
+        return jnp.where(kept < thresh, neg, kept)
+
+    scaled = jax.lax.cond(
+        jnp.any(drawn & (use_k | use_p)), truncated, lambda: scaled)
     sampled = jax.vmap(
         lambda kk, ll: jax.random.categorical(kk, ll))(keys, scaled)
-    return jnp.where(temps <= 0.0, greedy, sampled.astype(jnp.int32))
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return jnp.where(drawn, sampled.astype(jnp.int32), greedy)
 
 
 @jax.jit
 def _sample_dynamic(logits, seeds, index, temps, top_ks, top_ps):
-    """:func:`_sample_with_keys` on the keys :func:`_token_keys` derives
-    in the same program: the host uploads five small numpy arrays and
-    reads back the tokens, nothing else."""
-    return _sample_with_keys(logits, _token_keys(seeds, index), temps,
-                             top_ks, top_ps)
+    """The step's tokens from its logits and the rows' knobs; the host
+    uploads five small numpy arrays and reads back the tokens, nothing
+    else.  The program does what the rows of this batch ask for: with
+    no sampled row it is an ``argmax``; the keys (:func:`_token_keys`,
+    derived here) and :func:`_sample_with_keys` run only in the other
+    arm of a ``lax.cond`` on ``temps``."""
+    return jax.lax.cond(
+        jnp.any(temps > 0.0),
+        lambda: _sample_with_keys(logits, _token_keys(seeds, index),
+                                  temps, top_ks, top_ps),
+        lambda: jnp.argmax(logits, axis=-1).astype(jnp.int32))
 
 
 def _sampler_rows(rows):
@@ -901,6 +926,8 @@ class ServingEngine:
         self._delivered_now: dict = {}   # rid -> tokens this step
         self._ctx_pages = (0, 0.0, 0)   # this step's decode program:
                                         # pages gathered, idle, slots
+        # this step's sampler rows: not idle, drawn, truncating
+        self._sampled = np.zeros((3,), np.int64)
         watch_compiles()
         # ---- live telemetry plane (default off = zero threads, no
         # behavior change; outputs are bit-identical either way) ------
@@ -1588,9 +1615,10 @@ class ServingEngine:
         self._phase("serve.sample")
         # canonical samples for every drafted position: column t-1
         # logits, position-(base+t-1) key, the same sampler numerics
-        cand = np.asarray(_sample_dynamic(
+        cand = self._sample(
             span_logits[:, :k, :].reshape(sv.max_batch * k, -1),
-            *_sampler_rows(rows))).reshape(sv.max_batch, k)
+            _sampler_rows(rows), len(active) * k
+        ).reshape(sv.max_batch, k)
 
         # ---- accept the agreeing prefix; roll back the rest ----------
         self._phase("serve.deliver")
@@ -1775,6 +1803,19 @@ class ServingEngine:
         self._ctx_pages = (n_ctx, max(0.0, n_ctx - own_pages / slots),
                            slots)
 
+    def _sample(self, logits, knobs, n_rows: int) -> np.ndarray:
+        """The tokens :func:`_sample_dynamic` gives ``logits`` on
+        ``knobs`` (:func:`_sampler_rows`; ``n_rows`` of its rows are
+        not idle), read back.  What the rows ask of the program is
+        counted from the host's arrays by the program's own rule
+        (:func:`_rows_ask`): rows with a temperature are drawn, and
+        those of them that truncate make it sort (0 of them: it sorted
+        nothing)."""
+        drawn, use_k, use_p = _rows_ask(*knobs[2:], logits.shape[-1])
+        self._sampled += np.array(
+            [n_rows, drawn.sum(), (drawn & (use_k | use_p)).sum()])
+        return np.asarray(_sample_dynamic(logits, *knobs))
+
     def step(self) -> dict:
         """One engine iteration: admit -> sample/retire -> decode.
         Returns the step's flight record (also appended to the
@@ -1792,6 +1833,7 @@ class ServingEngine:
         self._phase_ms = {}
         self._delivered_now = {}
         self._ctx_pages = (0, 0.0, 0)
+        self._sampled = np.zeros((3,), np.int64)
         t0_s = self._phase("serve.admit")
         if self.tracer is not None:
             # open the step window BEFORE admissions: everything in
@@ -1819,7 +1861,7 @@ class ServingEngine:
             self._phase("serve.sample")
             # the step's one read-back: it waits for the decode program
             # the step before dispatched, then for the sampler's
-            toks = np.asarray(_sample_dynamic(self._logits, *knobs))
+            toks = self._sample(self._logits, knobs, len(active))
             now = self._phase("serve.deliver")
             for i in active:
                 s = self.slots[i]
@@ -1935,6 +1977,11 @@ class ServingEngine:
                             self._phase_ms["serve.account"])
         compiles1, compile_s1 = compile_totals()
         ctx_pages, ctx_idle, n_decoding = self._ctx_pages
+        sample_rows, sample_drawn, sample_sorted = map(int, self._sampled)
+        if sample_rows:
+            self.metrics.count("serve.sample_steps")
+            self.metrics.count("serve.sample_sort_steps",
+                               float(sample_sorted > 0))
         rec = {
             "kind": "serve_step", "step": self.step_idx,
             "active": n_active, "queue_depth": qd,
@@ -1951,6 +1998,8 @@ class ServingEngine:
             "ctx_pages": ctx_pages,
             "ctx_pages_idle": round(ctx_idle, 3),
             "kv_token_bytes": self.cfg.kv_token_bytes,
+            "sample_rows": sample_rows, "sample_drawn": sample_drawn,
+            "sample_sorted": sample_sorted,
         }
         if self.serve.speculate is not None:
             rec["spec_tokens"] = int(n_extra or 0)

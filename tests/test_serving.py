@@ -314,6 +314,252 @@ def test_sampler_entry_equals_numerics_on_host_keys(draw):
         "no sampled row left the argmax: the keys were not exercised"
 
 
+# ----------------------------------------------------------------------
+# The sampler does only what its rows ask for (ISSUE 28)
+# ----------------------------------------------------------------------
+
+def _two_sort_sampler(logits, keys, temps, top_ks, top_ps):
+    """The parent's ``_sample_with_keys``, verbatim: two sorts of the
+    whole vocabulary, a softmax, a running sum and a draw for every
+    row, thrown away where ``temperature <= 0``.  The reference."""
+    v = logits.shape[-1]
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    neg = jnp.asarray(-1e30, jnp.float32)
+    scaled = logits.astype(jnp.float32) / jnp.maximum(
+        temps, 1e-6)[:, None]
+    sort_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    kth = jnp.take_along_axis(
+        sort_desc, jnp.clip(top_ks - 1, 0, v - 1)[:, None], axis=1)
+    use_k = (top_ks > 0) & (top_ks < v)
+    scaled = jnp.where(use_k[:, None] & (scaled < kth), neg, scaled)
+    sort_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    probs = jax.nn.softmax(sort_desc, axis=-1)
+    csum = jnp.cumsum(probs, axis=-1)
+    keep = (csum - probs) < top_ps[:, None]
+    thresh = jnp.min(
+        jnp.where(keep, sort_desc, jnp.inf), axis=-1, keepdims=True)
+    scaled = jnp.where(scaled < thresh, neg, scaled)
+    sampled = jax.vmap(
+        lambda kk, ll: jax.random.categorical(kk, ll))(keys, scaled)
+    return jnp.where(temps <= 0.0, greedy, sampled.astype(jnp.int32))
+
+
+@jax.jit
+def _two_sort_dynamic(logits, seeds, index, temps, top_ks, top_ps):
+    return _two_sort_sampler(logits, eng._token_keys(seeds, index),
+                             temps, top_ks, top_ps)
+
+
+SAMPLER_V = 96
+# what a batch's rows ask of the sampler: ``None`` is an idle row
+SAMPLER_BATCHES = {
+    "greedy": [dict(), dict(top_k=3), dict(top_p=0.5), dict(),
+               dict(top_k=7, top_p=0.2), dict()],
+    "greedy+idle": [dict(), None, dict(top_k=3), None],
+    "drawn": [dict(temperature=0.7), dict(temperature=1.3),
+              dict(temperature=2.0, top_k=SAMPLER_V),
+              dict(temperature=0.2, top_k=SAMPLER_V + 9),
+              dict(temperature=1.0, top_p=1.0)],
+    "drawn+greedy+idle": [dict(temperature=0.7), dict(), None,
+                          dict(temperature=1.1, top_k=0), dict(top_k=2)],
+    "top_k": [dict(temperature=0.7, top_k=1),
+              dict(temperature=1.3, top_k=5),
+              dict(temperature=0.9, top_k=SAMPLER_V - 1),
+              dict(temperature=2.0, top_k=40)],
+    "top_p": [dict(temperature=0.9, top_p=0.6),
+              dict(temperature=1.5, top_p=0.05),
+              dict(temperature=0.6, top_p=0.99)],
+    "top_k+top_p": [dict(temperature=1.0, top_k=9, top_p=0.8),
+                    dict(temperature=0.8, top_k=20, top_p=0.9),
+                    dict(temperature=1.7, top_k=3, top_p=0.3)],
+    "one_truncates": [dict(temperature=0.7), dict(), None,
+                      dict(temperature=0.9, top_p=0.6),
+                      dict(temperature=1.3), dict(top_p=0.1)],
+    "mixed": [dict(), dict(temperature=0.7),
+              dict(temperature=1.3, top_k=5),
+              dict(temperature=0.9, top_p=0.6), None,
+              dict(temperature=1.0, top_k=9, top_p=0.8), dict(top_k=3),
+              dict(temperature=2.0)],
+}
+
+
+def _sampler_batch(name, draw, ties=False):
+    """``(logits, *knobs)`` for one of :data:`SAMPLER_BATCHES`; with
+    ``ties`` the logits take few distinct values, so the k-th value and
+    the nucleus threshold are shared by several entries."""
+    rng = np.random.default_rng(draw)
+    rows = [None if kw is None else
+            (Request(rid=j, prompt=(1,), seed=KEY_SEEDS[j], **kw),
+             int(rng.integers(0, 100001)))
+            for j, kw in enumerate(SAMPLER_BATCHES[name])]
+    logits = rng.normal(size=(len(rows), SAMPLER_V)) * 3
+    if ties:
+        logits = np.round(logits)
+    return (jnp.asarray(logits, jnp.float32),) + eng._sampler_rows(rows)
+
+
+@pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
+@pytest.mark.parametrize("draw", [0, 1])
+@pytest.mark.parametrize("batch", list(SAMPLER_BATCHES))
+def test_sampler_tokens_equal_the_two_sort_sampler(batch, draw, ties):
+    """Whatever the batch's rows ask for, the program that skips what
+    they do not ask for returns the two-sort sampler's tokens, bit for
+    bit: the greedy arm, the draw with no sort, and the one sort whose
+    top-k mask is applied to the sorted row."""
+    args = _sampler_batch(batch, draw, ties)
+    got = np.asarray(eng._sample_dynamic(*args))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, np.asarray(_two_sort_dynamic(*args)))
+    temps = args[3]
+    np.testing.assert_array_equal(
+        got[temps <= 0], np.argmax(np.asarray(args[0]), -1)[temps <= 0])
+
+
+@pytest.mark.parametrize("batch", ["drawn", "top_k", "top_p",
+                                   "top_k+top_p", "mixed"])
+def test_sampler_rows_equal_sample_tokens(batch):
+    """Row by row, the engine's sampler returns what ``generate()``'s
+    ``sample_tokens`` returns for that row alone, given the row's key
+    and its knobs as static values."""
+    from flashmoe_tpu.models.generate import sample_tokens
+
+    logits, seeds, index, temps, top_ks, top_ps = _sampler_batch(batch, 2)
+    got = np.asarray(eng._sample_dynamic(
+        logits, seeds, index, temps, top_ks, top_ps))
+    keys = np.asarray(jax.jit(eng._token_keys)(seeds, index))
+    for j in range(len(got)):
+        want = sample_tokens(
+            logits[j:j + 1], keys[j], temperature=float(temps[j]),
+            top_k=int(top_ks[j]), top_p=float(top_ps[j]))
+        assert got[j] == int(want[0]), (j, SAMPLER_BATCHES[batch][j])
+
+
+def test_a_row_without_nucleus_keeps_its_whole_distribution():
+    """``top_p == 1`` asks for no truncation and gets none, in either
+    arm: beside a truncating row, every logit of a drawn row reaches
+    its draw.  (The two-sort sampler let the rounding of the running
+    sum drop a tail of such a row, which the no-sort arm never does:
+    a row's tokens must not depend on what its neighbours ask for.)"""
+    rng = np.random.default_rng(0)
+    v = 4096
+    logits = jnp.asarray(rng.normal(size=(2, v)) * 3, jnp.float32)
+    args = (logits, np.zeros((2, 2), np.uint32),
+            np.array([0.7, 0.7], np.float32), np.zeros((2,), np.int32),
+            np.array([1.0, 0.5], np.float32))
+    with pytest.MonkeyPatch.context() as mp:
+        # the "draw" reports how many logits reached it
+        mp.setattr(jax.random, "categorical",
+                   lambda key, row: jnp.sum(row > -1e29))
+        kept = np.asarray(jax.jit(
+            lambda *a: eng._sample_with_keys(*a))(*args))
+        was = np.asarray(jax.jit(
+            lambda *a: _two_sort_sampler(*a))(*args))
+    assert kept[0] == v and 0 < kept[1] < v // 2, kept
+    assert was[0] < v and was[1] == kept[1], was
+
+
+def _cond_eqns(jaxpr):
+    """The ``cond`` equations of ``jaxpr``, looking through ``jit``
+    calls but not into a ``cond``'s branches."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            yield eqn
+        elif eqn.primitive.name in ("jit", "pjit"):
+            yield from _cond_eqns(eqn.params["jaxpr"].jaxpr)
+
+
+def _prims_outside_conds(jaxpr):
+    """Primitive names of ``jaxpr`` that no ``cond`` guards."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("jit", "pjit"):
+            yield from _prims_outside_conds(eqn.params["jaxpr"].jaxpr)
+        elif eqn.primitive.name != "cond":
+            yield eqn.primitive.name
+
+
+@pytest.fixture(scope="module")
+def sampler_jaxpr():
+    return jax.make_jaxpr(eng._sample_dynamic)(
+        *_sampler_batch("mixed", 0)).jaxpr
+
+
+def test_sampler_sorts_only_inside_a_cond(sampler_jaxpr):
+    from flashmoe_tpu.staticcheck.graph import prim_counts
+
+    outside = set(_prims_outside_conds(sampler_jaxpr))
+    assert not outside & {"sort", "cumsum", "random_bits", "argmax"}, outside
+    assert prim_counts(sampler_jaxpr)["sort"] == 1
+
+
+def test_sampler_greedy_arm_is_an_argmax(sampler_jaxpr):
+    from flashmoe_tpu.staticcheck.graph import prim_counts
+
+    (cond,) = _cond_eqns(sampler_jaxpr)
+    greedy, drawn = (prim_counts(br.jaxpr) for br in cond.params["branches"])
+    assert greedy["argmax"] == 1
+    assert not [p for p in greedy
+                if p in ("sort", "cumsum", "cond") or "random" in p
+                or "threefry" in p], greedy
+    assert drawn["random_bits"] == 1 and drawn["sort"] == 1
+
+
+def test_sampler_draws_without_a_sort_where_no_row_truncates(
+        sampler_jaxpr):
+    """Inside the drawn arm a second ``cond`` guards the sort: its
+    other arm passes the scaled logits on untouched."""
+    from flashmoe_tpu.staticcheck.graph import prim_counts
+
+    (outer,) = _cond_eqns(sampler_jaxpr)
+    drawn = outer.params["branches"][1].jaxpr
+    assert "sort" not in set(_prims_outside_conds(drawn))
+    (inner,) = _cond_eqns(drawn)
+    plain, truncated = (prim_counts(br.jaxpr)
+                        for br in inner.params["branches"])
+    assert not plain, plain
+    assert truncated["sort"] == 1 and truncated["cumsum"] == 1
+
+
+SAMPLED_KNOBS = {
+    "greedy": dict(),
+    "drawn": dict(temperature=0.8, seed=5),
+    "top_p": dict(temperature=0.8, top_p=0.9, seed=5),
+    "top_k": dict(temperature=0.8, top_k=20, seed=5),
+    "top_k_of_all": dict(temperature=0.8, top_k=CFG.vocab_size, seed=5),
+}
+
+
+@pytest.mark.parametrize("third", list(SAMPLED_KNOBS))
+def test_step_records_count_what_the_sampler_was_asked(params, prompts,
+                                                       third):
+    """Two greedy requests and a third with the knobs named: every
+    ``serve_step`` record says how many rows decoded, how many were
+    drawn and how many made the program sort, and the counter
+    ``serve.sample_sort_steps`` is the number of steps that sorted
+    (0, and present, on traffic that never truncates)."""
+    mx, recorder = Metrics(), FlightRecorder()
+    engine = ServingEngine(params, CFG, _spec_serve(), metrics_obj=mx,
+                           recorder=recorder)
+    reqs = _requests(prompts, 2, max_new=5) + [
+        Request(rid=2, prompt=tuple(int(t) for t in prompts[2]),
+                max_new_tokens=3, **SAMPLED_KNOBS[third])]
+    engine.run(reqs, arrivals=[0, 0, 1])
+    steps = [r for r in recorder.records if r.get("kind") == "serve_step"]
+    sampling = [r for r in steps if r["tokens"]]
+    assert [r["sample_rows"] for r in steps] == [r["tokens"] for r in steps]
+    assert sum(r["sample_rows"] for r in steps) == 5 + 5 + 3
+    drawn = 0 if third == "greedy" else 3
+    sorted_ = 3 if third in ("top_p", "top_k") else 0
+    assert [r["sample_drawn"] for r in steps].count(1) == drawn
+    assert [r["sample_sorted"] for r in steps].count(1) == sorted_
+    assert sum(r["sample_drawn"] for r in steps) == drawn
+    assert sum(r["sample_sorted"] for r in steps) == sorted_
+    assert all(r["sample_sorted"] <= r["sample_drawn"] <= r["sample_rows"]
+               for r in steps)
+    assert mx.counters["serve.sample_steps"] == len(sampling)
+    assert "serve.sample_sort_steps" in mx.counters
+    assert mx.counters["serve.sample_sort_steps"] == sorted_
+
+
 def _watch_host_traffic(monkeypatch) -> list:
     """While the patches hold, an eager ``jax.random`` call raises, and
     every read-back of a device array is appended to the returned list
@@ -389,7 +635,7 @@ def test_step_reads_back_only_the_sampled_tokens(params, prompts,
             jax.random.fold_in(jax.random.PRNGKey(0), 1)
         np.asarray(jnp.zeros((3,)))
         assert reads == [((3,), "float32")]   # the spies work
-        sampled_steps = 0
+        sampled_steps = sorted_rows = 0
         while engine.pending():
             del reads[:]
             rec = engine.step()
@@ -397,7 +643,10 @@ def test_step_reads_back_only_the_sampled_tokens(params, prompts,
             assert len(reads) <= (2 if speculate else 1)
             sampled_steps += bool(reads)
             assert bool(reads) == bool(rec["tokens"])
-    assert sampled_steps >= 8
+            # counted from the host's arrays: no read-back more
+            assert bool(reads) == bool(rec["sample_rows"])
+            sorted_rows += rec["sample_sorted"]
+    assert sampled_steps >= 8 and sorted_rows >= 8
     if speculate:
         assert engine.spec_snapshot()["spec_drafted"] > 0, "never verified"
     for i in range(4):
