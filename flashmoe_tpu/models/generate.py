@@ -32,13 +32,18 @@ import jax
 import jax.numpy as jnp
 
 from flashmoe_tpu.config import MoEConfig
-from flashmoe_tpu.models.transformer import _rope, rms_norm
-from flashmoe_tpu.ops.attention import attention_xla, mla_paged_attention
+from flashmoe_tpu.models.transformer import rms_norm
+from flashmoe_tpu.ops.attention import paged_attention
 from flashmoe_tpu.ops.moe import moe_layer
 
 
 class KVCache(NamedTuple):
-    k: jax.Array  # [L, B, N_kv, T_max, D]
+    """The dense cache of a K/V model, ``[L, B, N_kv, T_max, D]`` each:
+    a K/V page pool (``serving/kvcache.PagedKVCache``'s layout) of one
+    ``T_max``-row page a batch row, which is how :func:`span_forward`
+    takes it."""
+
+    k: jax.Array
     v: jax.Array
 
 
@@ -46,7 +51,7 @@ class LatentCache(NamedTuple):
     """The dense cache of an MLA model: one latent row a token a layer,
     ``[L, B, T_max * (kv_lora_rank + qk_rope_head_dim)]``: a latent pool
     (``serving/kvcache.LatentPagedCache``'s layout) of one ``T_max``-row
-    page a batch row, which is how :func:`mla_span_forward` takes it."""
+    page a batch row."""
 
     c: jax.Array
 
@@ -61,177 +66,80 @@ def init_cache(cfg: MoEConfig, batch: int, max_len: int):
     return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 
 
-def mla_span_forward(params, cfg: MoEConfig, x, pool, pos, write,
-                     block_tables, *, absorbed: bool):
-    """An MLA model's layers over a span of T tokens a slot: the ONE
-    layer loop of every cached MLA path (the serving engine's prefill,
-    chunked prefill, decode and verify programs, and :func:`generate`).
+def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
+                 block_tables, *, absorbed: bool, mixture=None):
+    """The model's layers over a span of T tokens a slot: the ONE layer
+    loop of every cached path of either attention kind (the serving
+    engine's prefill, chunked prefill, decode and verify programs, their
+    EP-sharded twins, and :func:`generate`).
 
-    x: [B, T, H] embedded tokens; pool: the latent pool
-    ``[L, P, page * C]`` or None (a whole prompt at once: nothing cached
-    yet); pos: [B, T] absolute positions; write / block_tables: see
-    :func:`~flashmoe_tpu.ops.attention.mla_paged_attention`.  Returns
-    (x pre-final-norm [B, T, H], the pool, the span's latent rows
-    [L, B, T, C])."""
+    x: [B, T, H] embedded tokens; cache: the pools as a NamedTuple of
+    arrays (a paged cache of ``serving/kvcache`` or a dense one of this
+    module), or None (a whole prompt at once: nothing cached yet); pos:
+    [B, T] absolute positions; write / block_tables / absorbed: see
+    :func:`~flashmoe_tpu.ops.attention.kv_paged_attention` and
+    ``mla_paged_attention``.  ``mixture(moe_params, rows, cfg)`` runs the
+    experts of the mixture layers in place of :func:`moe_layer` (the EP
+    programs' exchange).  Returns (x pre-final-norm [B, T, H], the cache,
+    the span's rows, one array ``[L, B, ...]`` for each pool)."""
     b, t, _ = x.shape
-    latents = []
+    # the experts over the S x K routed rows for an MLA config (the
+    # capacity arm's E x S rows cost 32 x the routed work at 256 experts
+    # top-8 and do not fit beside the weights past a 512-token span), over
+    # E x S rows for a K/V config: ROADMAP R7 races the two on the chip
+    local = functools.partial(moe_layer, use_pallas=False,
+                              routed_rows=cfg.attention_kind == "mla")
+    pools = None if cache is None else tuple(cache)
+    rows = []
     for li, layer in enumerate(params["layers"]):
-        a, pool, latent = mla_paged_attention(
-            layer, rms_norm(x, layer["attn_norm"]), cfg, pool, li, pos,
+        a, pools, span = paged_attention(
+            layer, rms_norm(x, layer["attn_norm"]), cfg, pools, li, pos,
             write, block_tables, absorbed=absorbed)
-        latents.append(latent)
+        rows.append(span)
         x = x + a
-        f_in = rms_norm(x, layer["ffn_norm"])
-        # the experts over the S x K routed rows: the capacity arm's
-        # E x S rows cost 32 x the routed work at 256 experts top-8 and
-        # do not fit beside the weights past a 512-token span
-        o = moe_layer(layer["moe"], f_in.reshape(b * t, -1),
-                      cfg.ffn_config(li), use_pallas=False,
-                      routed_rows=True)
+        f_in = rms_norm(x, layer["ffn_norm"]).reshape(b * t, -1)
+        layer_cfg = cfg.ffn_config(li)
+        experts = mixture if (mixture is not None
+                              and li in cfg.moe_layer_indices) else local
+        o = experts(layer["moe"], f_in, layer_cfg)
         x = x + o.out.reshape(b, t, -1).astype(x.dtype)
-    return x, pool, jnp.stack(latents)
+    if cache is not None:
+        cache = type(cache)(*pools)
+    return x, cache, tuple(jnp.stack(r) for r in zip(*rows))
 
 
-def _mla_dense_step(params, cfg: MoEConfig, x, cache: LatentCache, pos,
-                    absorbed: bool):
-    """:func:`mla_span_forward` over the dense cache: batch row b owns
-    page b, and a span starting at ``pos`` writes rows pos..pos+T-1."""
+def _dense_span(params, cfg: MoEConfig, x, cache, pos, absorbed: bool):
+    """:func:`span_forward` over the dense cache: batch row b owns page
+    b, and a span starting at ``pos`` writes rows pos..pos+T-1."""
     b, t, _ = x.shape
     positions = jnp.broadcast_to(
         pos + jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
     rows_b = jnp.broadcast_to(jnp.arange(b, dtype=jnp.int32)[:, None],
                               (b, t))
-    x, pool, _ = mla_span_forward(
-        params, cfg, x, cache.c, positions, (rows_b, positions),
+    x, cache, _ = span_forward(
+        params, cfg, x, cache, positions, (rows_b, positions),
         rows_b[:, :1], absorbed=absorbed)
-    return x, LatentCache(pool)
+    return x, cache
 
 
-def _decode_step(params, cfg: MoEConfig, x, cache: KVCache, pos):
+def _decode_step(params, cfg: MoEConfig, x, cache, pos):
     """One token through all layers. x: [B, 1, H]; pos: [] current index."""
-    if cfg.attention_kind == "mla":
-        x, cache = _mla_dense_step(params, cfg, x, cache, pos,
-                                   absorbed=True)
-        return lm_logits(params, cfg, x), cache
-    b = x.shape[0]
-    nh, nkv, dh = cfg.num_heads, cfg.resolved_num_kv_heads, cfg.resolved_head_dim
-    new_k, new_v = [], []
-    for li, layer in enumerate(params["layers"]):
-        h_in = rms_norm(x, layer["attn_norm"])
-        q = (h_in @ layer["wq"].astype(x.dtype)).reshape(b, 1, nh, dh)
-        k = (h_in @ layer["wk"].astype(x.dtype)).reshape(b, 1, nkv, dh)
-        v = (h_in @ layer["wv"].astype(x.dtype)).reshape(b, 1, nkv, dh)
-        positions = jnp.broadcast_to(pos[None, None], (b, 1))
-        q, k = _rope(q, k, positions, cfg.rope_theta)
-
-        ck = jax.lax.dynamic_update_slice(
-            cache.k[li], k.transpose(0, 2, 1, 3), (0, 0, pos, 0)
-        )
-        cv = jax.lax.dynamic_update_slice(
-            cache.v[li], v.transpose(0, 2, 1, 3), (0, 0, pos, 0)
-        )
-        new_k.append(ck)
-        new_v.append(cv)
-
-        kk, vv = ck, cv
-        if nkv != nh:
-            rep = nh // nkv
-            kk = jnp.repeat(kk, rep, axis=1)
-            vv = jnp.repeat(vv, rep, axis=1)
-        qh = q.transpose(0, 2, 1, 3)  # [B, N, 1, D]
-        t_max = kk.shape[2]
-        logits = jnp.einsum(
-            "bntd,bnsd->bnts", qh, kk, preferred_element_type=jnp.float32
-        ) * (dh ** -0.5)
-        mask = (jnp.arange(t_max) <= pos)[None, None, None, :]
-        logits = jnp.where(mask, logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-        ctx = jnp.einsum(
-            "bnts,bnsd->bntd", probs, vv, preferred_element_type=jnp.float32
-        ).transpose(0, 2, 1, 3).reshape(b, 1, nh * dh).astype(x.dtype)
-        x = x + ctx @ layer["wo"].astype(x.dtype)
-
-        f_in = rms_norm(x, layer["ffn_norm"])
-        o = moe_layer(
-            layer["moe"], f_in.reshape(b, -1), cfg.ffn_config(li),
-            use_pallas=False
-        )
-        x = x + o.out.reshape(b, 1, -1).astype(x.dtype)
-
-    cache = KVCache(jnp.stack(new_k), jnp.stack(new_v))
-    h = rms_norm(x, params["final_norm"])
-    logits = jnp.dot(
-        h.astype(cfg.dtype), params["lm_head"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )[:, 0]  # [B, V]
-    return logits, cache
+    x, cache = _dense_span(params, cfg, x, cache, pos, absorbed=True)
+    return lm_logits(params, cfg, x), cache
 
 
-def prefill_forward(params, cfg: MoEConfig, prompt, cache: KVCache):
+def prefill_forward(params, cfg: MoEConfig, prompt, cache):
     """Single-pass prefill core: the full prompt through every layer at
-    once, causal-masked, writing the KV cache in one shot.
+    once, causal-masked, writing the cache in one shot.
 
     prompt: [B, T0] int32.  Returns (x [B, T0, H] pre-final-norm hidden
-    states, cache with positions [0, T0) filled).  Mirrors
-    :func:`_decode_step`'s per-layer arithmetic with T0 query positions
-    so the two prefill arms stay logits-equal on dropless configs
-    (capacity configs compete for slots per call, so their drop
-    pattern is step-count-dependent — use the loop arm there).
-    Exposed separately from :func:`prefill_batched` because the serving
-    engine prefills PADDED prompts and needs the hidden state at a
-    dynamic true-length index, not the last row.
-    """
-    b, t0 = prompt.shape
+    states, cache with positions [0, T0) filled).  The same span path as
+    :func:`_decode_step` with T0 query positions, so the two prefill arms
+    stay logits-equal on dropless configs (capacity configs compete for
+    slots per call, so their drop pattern is step-count-dependent — use
+    the loop arm there)."""
     x = params["embed"].astype(cfg.dtype)[prompt]  # [B, T0, H]
-    if cfg.attention_kind == "mla":
-        return _mla_dense_step(params, cfg, x, cache, jnp.int32(0),
-                               absorbed=False)
-    nh, nkv, dh = cfg.num_heads, cfg.resolved_num_kv_heads, cfg.resolved_head_dim
-    positions = jnp.broadcast_to(jnp.arange(t0)[None, :], (b, t0))
-    new_k, new_v = [], []
-    for li, layer in enumerate(params["layers"]):
-        h_in = rms_norm(x, layer["attn_norm"])
-        q = (h_in @ layer["wq"].astype(x.dtype)).reshape(b, t0, nh, dh)
-        k = (h_in @ layer["wk"].astype(x.dtype)).reshape(b, t0, nkv, dh)
-        v = (h_in @ layer["wv"].astype(x.dtype)).reshape(b, t0, nkv, dh)
-        q, k = _rope(q, k, positions, cfg.rope_theta)
-
-        ck = jax.lax.dynamic_update_slice(
-            cache.k[li], k.transpose(0, 2, 1, 3), (0, 0, 0, 0)
-        )
-        cv = jax.lax.dynamic_update_slice(
-            cache.v[li], v.transpose(0, 2, 1, 3), (0, 0, 0, 0)
-        )
-        new_k.append(ck)
-        new_v.append(cv)
-
-        kk, vv = ck, cv
-        if nkv != nh:
-            rep = nh // nkv
-            kk = jnp.repeat(kk, rep, axis=1)
-            vv = jnp.repeat(vv, rep, axis=1)
-        qh = q.transpose(0, 2, 1, 3)  # [B, N, T0, D]
-        t_max = kk.shape[2]
-        logits = jnp.einsum(
-            "bntd,bnsd->bnts", qh, kk, preferred_element_type=jnp.float32
-        ) * (dh ** -0.5)
-        mask = (jnp.arange(t_max)[None, None, None, :]
-                <= positions[:, None, :, None])
-        logits = jnp.where(mask, logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-        ctx = jnp.einsum(
-            "bnts,bnsd->bntd", probs, vv, preferred_element_type=jnp.float32
-        ).transpose(0, 2, 1, 3).reshape(b, t0, nh * dh).astype(x.dtype)
-        x = x + ctx @ layer["wo"].astype(x.dtype)
-
-        f_in = rms_norm(x, layer["ffn_norm"])
-        o = moe_layer(
-            layer["moe"], f_in.reshape(b * t0, -1), cfg.ffn_config(li),
-            use_pallas=False
-        )
-        x = x + o.out.reshape(b, t0, -1).astype(x.dtype)
-
-    return x, KVCache(jnp.stack(new_k), jnp.stack(new_v))
+    return _dense_span(params, cfg, x, cache, jnp.int32(0), absorbed=False)
 
 
 def lm_logits(params, cfg: MoEConfig, h):

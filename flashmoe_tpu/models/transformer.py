@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from flashmoe_tpu.config import MoEConfig
 from flashmoe_tpu.models.reference import init_moe_params
 from flashmoe_tpu.ops.attention import rms_norm  # noqa: F401  (re-exported)
+from flashmoe_tpu.ops.attention import rope_halves as _rope
 from flashmoe_tpu.ops.moe import dense_ffn, moe_layer
 from flashmoe_tpu.parallel.ep import ep_moe_layer
 
@@ -90,25 +91,6 @@ def init_params(key, cfg: MoEConfig) -> dict:
 # ----------------------------------------------------------------------
 # Blocks
 # ----------------------------------------------------------------------
-
-def _rope(q, k, positions, theta):
-    """Rotary position embeddings. q/k: [B, T, N, D]."""
-    d = q.shape[-1]
-    half = d // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions[..., None].astype(jnp.float32) * freq  # [B, T, half]
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
-
-    def rot(x):
-        x1, x2 = x[..., :half], x[..., half:]
-        xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
-        return jnp.concatenate(
-            [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1
-        ).astype(x.dtype)
-
-    return rot(q), rot(k)
-
 
 def attention(layer, x, cfg: MoEConfig, positions=None, mesh=None,
               use_pallas=None):

@@ -219,6 +219,155 @@ def mla_paged_attention(layer, x, cfg, pool, li, pos, write, block_tables,
 
 
 # ----------------------------------------------------------------------
+# K/V attention over a paged cache (MHA or GQA, RoPE over halves), plain XLA
+# ----------------------------------------------------------------------
+#
+# The twin of the MLA trio above for ``attention_kind == 'mha'``: a cache
+# keeps of a token its roped K and its V per kv head, in a pair of pools
+# ``[L, P, N_kv, page, D]`` (``serving/kvcache.PagedKVCache``; generate's
+# dense ``KVCache`` is the same layout with one ``T_max``-row page a batch
+# row).
+
+def rope_halves(q, k, positions, theta):
+    """Rotary position embeddings over the two HALVES (i, i + D/2) of the
+    last axis.  q/k: [B, T, N, D]; positions: [B, T]."""
+    d = q.shape[-1]
+    half = d // 2
+    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    angles = positions[..., None].astype(jnp.float32) * freq  # [B, T, half]
+    cos = jnp.cos(angles)[:, :, None, :]
+    sin = jnp.sin(angles)[:, :, None, :]
+
+    def rot(x):
+        x1, x2 = x[..., :half], x[..., half:]
+        xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
+        return jnp.concatenate(
+            [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1
+        ).astype(x.dtype)
+
+    return rot(q), rot(k)
+
+
+def kv_project(layer, x, cfg, positions):
+    """x: [B, T, H] normed -> (q [B, T, N, D], k and v [B, T, N_kv, D]),
+    q and k roped at ``positions`` [B, T]."""
+    b, t, _ = x.shape
+    nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
+                   cfg.resolved_head_dim)
+    q = (x @ layer["wq"].astype(x.dtype)).reshape(b, t, nh, dh)
+    k = (x @ layer["wk"].astype(x.dtype)).reshape(b, t, nkv, dh)
+    v = (x @ layer["wv"].astype(x.dtype)).reshape(b, t, nkv, dh)
+    q, k = rope_halves(q, k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def kv_attend(layer, q, k_ctx, v_ctx, q_pos):
+    """Causal attention of T queries a row over a context of K/V rows.
+
+    q: [B, T, N, D]; k_ctx / v_ctx: [B, N_kv, S, D], row s the K/V of
+    position s; q_pos: [B, T] the queries' positions (a query sees
+    s <= its position: rows past it may hold anything).  Returns the
+    block's attention output [B, T, H]."""
+    b, t, nh, dh = q.shape
+    dt = q.dtype
+    if k_ctx.shape[1] != nh:  # GQA: repeat kv heads
+        rep = nh // k_ctx.shape[1]
+        k_ctx = jnp.repeat(k_ctx, rep, axis=1)
+        v_ctx = jnp.repeat(v_ctx, rep, axis=1)
+    logits = jnp.einsum(
+        "bntd,bnsd->bnts", q.transpose(0, 2, 1, 3), k_ctx,
+        preferred_element_type=jnp.float32) * (dh ** -0.5)
+    mask = (jnp.arange(k_ctx.shape[2])[None, None, None, :]
+            <= q_pos[:, None, :, None])
+    probs = jax.nn.softmax(jnp.where(mask, logits, NEG_INF),
+                           axis=-1).astype(dt)
+    ctx = jnp.einsum(
+        "bnts,bnsd->bntd", probs, v_ctx, preferred_element_type=jnp.float32
+    ).transpose(0, 2, 1, 3).reshape(b, t, nh * dh).astype(dt)
+    return ctx @ layer["wo"].astype(dt)
+
+
+def store_kv(pages, li: int, span_kv, page_ids, rows):
+    """Scatter a span's K (or V) rows into layer ``li`` of its pool.
+    pages: [L, P, N_kv, page, D]; span_kv: [B, T, N_kv, D]; page_ids /
+    rows: [B, T] int32 (inactive slots and positions past a slot's
+    context pass the scratch page: duplicate scratch writes race, but
+    scratch rows are never read back with non-zero weight; a rejected
+    draft's rows are overwritten before any causal mask exposes them).
+    ``rows`` None: the span fills WHOLE pages (a prefill chunk); page_ids
+    is then [B, T // page], one id a page."""
+    if rows is None:
+        _, _, nkv, d = span_kv.shape
+        whole = span_kv.reshape(-1, pages.shape[3], nkv, d)
+        return pages.at[li, page_ids.reshape(-1)].set(
+            whole.transpose(0, 2, 1, 3).astype(pages.dtype))
+    if span_kv.shape[1] == 1:
+        # a decode step, one row a slot: [B] indices, for which the
+        # chip's compiler builds one index fusion less than for [B, 1]
+        # (until ROADMAP S2's kernel writes its rows itself)
+        page_ids, rows, span_kv = page_ids[:, 0], rows[:, 0], span_kv[:, 0]
+    # the advanced indices at axes 0 and 2 are split by the head-axis
+    # slice, so numpy semantics front their broadcast dims: the result
+    # aligns with span_kv exactly
+    return pages.at[li].set(
+        pages[li].at[page_ids, :, rows, :].set(span_kv))
+
+
+def gather_ctx(pages, block_tables):
+    """Gather each slot's context window from its pages.
+
+    pages: ``[P, N_kv, page, D]`` (one layer's pool); block_tables:
+    ``[B, n]`` page ids (already sliced to the bucketed page count).
+    Returns ``[B, N_kv, n * page, D]`` — rows past a request's length are
+    scratch/garbage and MUST be masked by the caller's length mask."""
+    b, n = block_tables.shape
+    g = pages[block_tables]                    # [B, n, N_kv, page, D]
+    _, _, nkv, page, d = g.shape
+    return g.transpose(0, 2, 1, 3, 4).reshape(b, nkv, n * page, d)
+
+
+def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
+    """THE K/V attention of every cached path, with
+    :func:`mla_paged_attention`'s contract: project a span of T tokens a
+    slot, write its K and V rows to layer ``li``'s pages, gather the
+    context, attend.
+
+    x: [B, T, H] normed; pools: the ``(k_pages, v_pages)`` pair, each
+    [L, P, N_kv, page, D], or None for a whole prompt at once (the
+    context is the span itself); pos: [B, T] absolute positions; write:
+    ``(page_ids, rows)``, each [B, T], or ``(page_ids [B, T // page],
+    None)`` for a span of whole pages; block_tables: [B, n].  Returns
+    (attention output [B, T, H], the pools, the span's ``(k, v)`` rows
+    laid out as a context, each [B, N_kv, T, D])."""
+    q, k, v = kv_project(layer, x, cfg, pos)
+    span = (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+    if pools is None:
+        k_ctx, v_ctx = span
+    else:
+        pools = (store_kv(pools[0], li, k, *write),
+                 store_kv(pools[1], li, v, *write))
+        k_ctx = gather_ctx(pools[0][li], block_tables)
+        v_ctx = gather_ctx(pools[1][li], block_tables)
+    return kv_attend(layer, q, k_ctx, v_ctx, pos), pools, span
+
+
+def paged_attention(layer, x, cfg, pools, li, pos, write, block_tables, *,
+                    absorbed: bool):
+    """Layer ``li``'s attention over a cache, by ``cfg.attention_kind``:
+    :func:`kv_paged_attention` or :func:`mla_paged_attention` (which alone
+    reads ``absorbed``).  pools: the cache's arrays as a tuple, a K/V pair
+    or the one latent pool, or None.  Returns (attention output, the
+    pools, the span's rows: one array for each pool of the cache)."""
+    if cfg.attention_kind != "mla":
+        return kv_paged_attention(layer, x, cfg, pools, li, pos, write,
+                                  block_tables)
+    out, pool, latent = mla_paged_attention(
+        layer, x, cfg, None if pools is None else pools[0], li, pos, write,
+        block_tables, absorbed=absorbed)
+    return out, None if pools is None else (pool,), (latent,)
+
+
+# ----------------------------------------------------------------------
 # Flash attention kernel
 # ----------------------------------------------------------------------
 

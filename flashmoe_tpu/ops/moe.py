@@ -277,7 +277,7 @@ def moe_layer(params, x, cfg: MoEConfig, *, use_pallas: bool | None = None,
     ``capacity``) computes the experts over exactly the ``S x K`` routed
     rows (:func:`routed_rows_ffn`) where the capacity arm computes
     ``E x S``: the serving path of the latent-attention models asks for
-    it (``models/generate.mla_span_forward``); every other caller keeps
+    it (``models/generate.span_forward``); every other caller keeps
     the arm it had.
     """
     if use_pallas is None:

@@ -6,8 +6,8 @@ slowest member:
 
 * **admission** — queued requests whose (seeded-trace) arrival step has
   passed take a free slot when the page pool can hold their prompt:
-  single-pass batched prefill (:func:`flashmoe_tpu.models.generate.
-  prefill_forward`) writes their pages in one shot, ``serve.admit``;
+  single-pass batched prefill (:func:`_prefill_padded`) writes their
+  pages in one shot, ``serve.admit``;
 * **decode** — one jitted step advances every active slot: sample from
   each slot's pending logits (greedy / temperature / top-k / top-p,
   per-request), feed the sampled tokens, paged attention over each
@@ -55,15 +55,12 @@ import numpy as np
 
 from flashmoe_tpu.config import MoEConfig
 from flashmoe_tpu.models.generate import (
-    init_cache, lm_logits, lm_logits_span, mla_span_forward,
-    prefill_forward,
+    lm_logits, lm_logits_span, span_forward,
 )
-from flashmoe_tpu.models.transformer import rms_norm, _rope
-from flashmoe_tpu.ops.moe import moe_layer
 from flashmoe_tpu.serving.kvcache import (
-    SCRATCH_PAGE, LatentPagedCache, PagedKVCache, PagePool,
-    ShardedPagePool, ctx_pages_bucket, gather_ctx, init_paged_cache,
-    page_size_of, prompt_pad, store_prefill, store_token, store_tokens,
+    SCRATCH_PAGE, PagedKVCache, PagePool, ShardedPagePool,
+    ctx_pages_bucket, init_paged_cache, page_size_of, prompt_pad,
+    store_prefill,
 )
 from flashmoe_tpu.serving.speculate import (
     DraftState, SpecConfig, spec_stats_fields,
@@ -248,32 +245,28 @@ class _Slot:
 
 
 # ----------------------------------------------------------------------
-# Jitted kernels (module-level so every engine instance shares caches)
+# Jitted kernels (module-level so every engine instance shares caches).
+# Each is: embed, say where the span's rows go (``pos`` / ``write`` from
+# the block tables), the one layer loop (``generate.span_forward``, for
+# either cache class), the head.
 # ----------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _prefill_padded(params, cfg: MoEConfig, prompt_padded, true_len):
     """Prefill one padded prompt: [1, T_pad] int32 -> (logits [V] at
-    the true last position, k_seq/v_seq [L, N_kv, T_pad, D]).  Pad
-    positions compute garbage no causal query before them ever sees;
-    their K/V rows land in pages the length mask never exposes.  An MLA
-    config returns (logits, latent rows [L, T_pad, C]): what its one
-    pool keeps."""
+    the true last position, then one dense run for each pool of the
+    cache, as ``store_prefill`` takes it: k_seq/v_seq
+    [L, N_kv, T_pad, D], or an MLA config's latent rows [L, T_pad, C]).
+    Pad positions compute garbage no causal query before them ever
+    sees; their rows land in pages the length mask never exposes."""
     t_pad = prompt_padded.shape[1]
-    if cfg.attention_kind == "mla":
-        x, _, latents = mla_span_forward(
-            params, cfg, params["embed"].astype(cfg.dtype)[prompt_padded],
-            None, jnp.arange(t_pad, dtype=jnp.int32)[None, :], None, None,
-            absorbed=False)
-        h = jax.lax.dynamic_slice(
-            x, (0, true_len - 1, 0), (1, 1, x.shape[-1]))
-        return lm_logits(params, cfg, h)[0], latents[:, 0]
-    cache = init_cache(cfg, 1, t_pad)
-    x, cache = prefill_forward(params, cfg, prompt_padded, cache)
+    x, _, runs = span_forward(
+        params, cfg, params["embed"].astype(cfg.dtype)[prompt_padded],
+        None, jnp.arange(t_pad, dtype=jnp.int32)[None, :], None, None,
+        absorbed=False)
     h = jax.lax.dynamic_slice(
         x, (0, true_len - 1, 0), (1, 1, x.shape[-1]))
-    logits = lm_logits(params, cfg, h)[0]                    # [V]
-    return logits, cache.k[:, 0], cache.v[:, 0]
+    return (lm_logits(params, cfg, h)[0], *(run[:, 0] for run in runs))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -291,140 +284,61 @@ def _prefill_chunk(params, cfg: MoEConfig, pools, chunk_toks,
     ``pools`` is the engine's cache (a K/V pair or one latent pool).
     Returns (logits [V], pools).
 
-    Per-layer math mirrors :func:`_prefill_padded`'s single-shot path
-    at chunk granularity: the chunk's K/V land in their pages BEFORE
-    the gather, so in-chunk causal attention sees them through the
-    same paged read decode uses.  Positions past the true prompt end
-    write garbage rows that decode overwrites before any causal query
-    exposes them — the whole-prefill invariant, per chunk."""
+    The chunk's rows land in their pages BEFORE the gather, so in-chunk
+    causal attention sees them through the same paged read decode uses.
+    Positions past the true prompt end write garbage rows that decode
+    overwrites before any causal query exposes them — the whole-prefill
+    invariant, per chunk."""
     c = chunk_toks.shape[1]
     positions = start_pos + jnp.arange(c, dtype=jnp.int32)   # [C]
-    x = params["embed"].astype(cfg.dtype)[chunk_toks]        # [1, C, H]
-    if cfg.attention_kind == "mla":
-        write = (chunk_page_ids[None, :], None)      # whole pages
-        x, pages, _ = mla_span_forward(
-            params, cfg, x, pools.pages, positions[None, :], write,
-            block_table[None, :], absorbed=False)
-        h = jax.lax.dynamic_slice(
-            x, (0, rel_last, 0), (1, 1, x.shape[-1]))
-        return lm_logits(params, cfg, h)[0], LatentPagedCache(pages)
-    k_pages, v_pages = pools
-    nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
-                   cfg.resolved_head_dim)
-    page = pools.page_size
-    n_ctx = block_table.shape[0] * page
-    n_c = c // page
-    for li, layer in enumerate(params["layers"]):
-        h_in = rms_norm(x, layer["attn_norm"])
-        q = (h_in @ layer["wq"].astype(x.dtype)).reshape(1, c, nh, dh)
-        k = (h_in @ layer["wk"].astype(x.dtype)).reshape(1, c, nkv, dh)
-        v = (h_in @ layer["wv"].astype(x.dtype)).reshape(1, c, nkv, dh)
-        q, k = _rope(q, k, positions[None, :], cfg.rope_theta)
-
-        kc = k[0].reshape(n_c, page, nkv, dh).transpose(0, 2, 1, 3)
-        vc = v[0].reshape(n_c, page, nkv, dh).transpose(0, 2, 1, 3)
-        k_pages = k_pages.at[li, chunk_page_ids].set(
-            kc.astype(k_pages.dtype))
-        v_pages = v_pages.at[li, chunk_page_ids].set(
-            vc.astype(v_pages.dtype))
-
-        kk = gather_ctx(k_pages[li], block_table[None, :])
-        vv = gather_ctx(v_pages[li], block_table[None, :])
-        if nkv != nh:
-            rep = nh // nkv
-            kk = jnp.repeat(kk, rep, axis=1)
-            vv = jnp.repeat(vv, rep, axis=1)
-        qh = q.transpose(0, 2, 1, 3)                # [1, N, C, D]
-        logits = jnp.einsum(
-            "bntd,bnsd->bnts", qh, kk, preferred_element_type=jnp.float32
-        ) * (dh ** -0.5)
-        mask = (jnp.arange(n_ctx, dtype=jnp.int32)[None, :]
-                <= positions[:, None])[None, None, :, :]
-        logits = jnp.where(mask, logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-        ctx = jnp.einsum(
-            "bnts,bnsd->bntd", probs, vv, preferred_element_type=jnp.float32
-        ).transpose(0, 2, 1, 3).reshape(1, c, nh * dh).astype(x.dtype)
-        x = x + ctx @ layer["wo"].astype(x.dtype)
-
-        f_in = rms_norm(x, layer["ffn_norm"])
-        layer_cfg = cfg if li in cfg.moe_layer_indices else cfg.replace(
-            num_experts=1, expert_top_k=1, num_shared_experts=0)
-        o = moe_layer(layer["moe"], f_in.reshape(c, -1), layer_cfg,
-                      use_pallas=False)
-        x = x + o.out.reshape(1, c, -1).astype(x.dtype)
-
+    x, pools, _ = span_forward(
+        params, cfg, params["embed"].astype(cfg.dtype)[chunk_toks], pools,
+        positions[None, :], (chunk_page_ids[None, :], None),  # whole pages
+        block_table[None, :], absorbed=False)
     h = jax.lax.dynamic_slice(x, (0, rel_last, 0), (1, 1, x.shape[-1]))
-    return lm_logits(params, cfg, h)[0], PagedKVCache(k_pages, v_pages)
+    return lm_logits(params, cfg, h)[0], pools
+
+
+def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
+               positions, mixture=None):
+    """A span of T tokens a slot through the layers, over the paged
+    cache: the body of the decode (T = 1) and verify programs and of
+    their EP-sharded twins (which pass ``mixture``).  toks: [B, T];
+    column t lands at ``positions + t``.  Returns (x [B, T, H], pools).
+
+    Span positions past the gathered context (a slot drafted into its
+    context ceiling) route their writes to the scratch page and produce
+    garbage columns the host never reads — the host truncates drafts to
+    fit, this is the in-graph belt-and-suspenders."""
+    page = page_size_of(pools, cfg)
+    ntab = block_tables.shape[1]
+    pos = (positions[:, None]
+           + jnp.arange(toks.shape[1], dtype=jnp.int32)[None, :])  # [B, T]
+    valid = pos < ntab * page
+    page_ids = jnp.where(
+        valid, jnp.take_along_axis(
+            block_tables, jnp.clip(pos // page, 0, ntab - 1), axis=1),
+        jnp.int32(SCRATCH_PAGE))
+    rows = jnp.where(valid, pos % page, 0)
+    # a short span over a long context: MLA's absorbed form
+    x, pools, _ = span_forward(
+        params, cfg, params["embed"].astype(cfg.dtype)[toks], pools, pos,
+        (page_ids, rows), block_tables, absorbed=True, mixture=mixture)
+    return x, pools
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _paged_decode_step(params, cfg: MoEConfig, pools, toks,
                        block_tables, positions):
-    """One decode step for the whole slot grid.
+    """One decode step for the whole slot grid: the span path at T = 1.
 
     toks: [B] int32 tokens to feed; block_tables: [B, n] page ids
     (bucketed); positions: [B] write positions (= each slot's current
     length; inactive slots pass 0 with an all-scratch table).  Returns
-    (logits [B, V] f32, pools).  Mirrors
-    ``generate._decode_step``'s per-layer arithmetic with per-slot
-    positions and paged K/V; an MLA config runs the absorbed form over
-    its latent pool."""
-    b = toks.shape[0]
-    page = page_size_of(pools, cfg)
-    x = params["embed"].astype(cfg.dtype)[toks][:, None, :]  # [B, 1, H]
-    page_ids = jnp.take_along_axis(
-        block_tables, (positions // page)[:, None], axis=1)[:, 0]
-    rows = positions % page
-    if cfg.attention_kind == "mla":
-        x, pages, _ = mla_span_forward(
-            params, cfg, x, pools.pages, positions[:, None],
-            (page_ids[:, None], rows[:, None]), block_tables,
-            absorbed=True)
-        return lm_logits(params, cfg, x), LatentPagedCache(pages)
-    k_pages, v_pages = pools
-    nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
-                   cfg.resolved_head_dim)
-    n_ctx = block_tables.shape[1] * page
-    for li, layer in enumerate(params["layers"]):
-        h_in = rms_norm(x, layer["attn_norm"])
-        q = (h_in @ layer["wq"].astype(x.dtype)).reshape(b, 1, nh, dh)
-        k = (h_in @ layer["wk"].astype(x.dtype)).reshape(b, 1, nkv, dh)
-        v = (h_in @ layer["wv"].astype(x.dtype)).reshape(b, 1, nkv, dh)
-        q, k = _rope(q, k, positions[:, None], cfg.rope_theta)
-
-        k_pages = k_pages.at[li].set(
-            store_token(k_pages[li], k[:, 0], page_ids, rows))
-        v_pages = v_pages.at[li].set(
-            store_token(v_pages[li], v[:, 0], page_ids, rows))
-
-        kk = gather_ctx(k_pages[li], block_tables)  # [B, nkv, ctx, D]
-        vv = gather_ctx(v_pages[li], block_tables)
-        if nkv != nh:
-            rep = nh // nkv
-            kk = jnp.repeat(kk, rep, axis=1)
-            vv = jnp.repeat(vv, rep, axis=1)
-        qh = q.transpose(0, 2, 1, 3)                # [B, N, 1, D]
-        logits = jnp.einsum(
-            "bntd,bnsd->bnts", qh, kk, preferred_element_type=jnp.float32
-        ) * (dh ** -0.5)
-        mask = (jnp.arange(n_ctx)[None, :]
-                <= positions[:, None])[:, None, None, :]
-        logits = jnp.where(mask, logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-        ctx = jnp.einsum(
-            "bnts,bnsd->bntd", probs, vv, preferred_element_type=jnp.float32
-        ).transpose(0, 2, 1, 3).reshape(b, 1, nh * dh).astype(x.dtype)
-        x = x + ctx @ layer["wo"].astype(x.dtype)
-
-        f_in = rms_norm(x, layer["ffn_norm"])
-        layer_cfg = cfg if li in cfg.moe_layer_indices else cfg.replace(
-            num_experts=1, expert_top_k=1, num_shared_experts=0)
-        o = moe_layer(layer["moe"], f_in.reshape(b, -1), layer_cfg,
-                      use_pallas=False)
-        x = x + o.out.reshape(b, 1, -1).astype(x.dtype)
-
-    return lm_logits(params, cfg, x), PagedKVCache(k_pages, v_pages)
+    (logits [B, V] f32, pools)."""
+    x, pools = _span_step(params, cfg, pools, toks[:, None], block_tables,
+                          positions)
+    return lm_logits(params, cfg, x), pools
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -444,78 +358,12 @@ def _paged_verify_step(params, cfg: MoEConfig, pools, toks,
     mode prices the step as wire/HBM-bound, so the extra columns ride
     nearly free).
 
-    Span positions past the gathered context (a slot drafted into its
-    context ceiling) route their KV writes to the scratch page and
-    produce garbage columns the host never reads — the host truncates
-    drafts to fit, this is the in-graph belt-and-suspenders.  Rejected
-    columns DO write rows: the host rolls back the block-table/length
-    state, and the next step's span overwrites those exact rows before
-    any causal mask exposes them (the prefill pad-row invariant)."""
-    b, t_span = toks.shape
-    page = page_size_of(pools, cfg)
-    ntab = block_tables.shape[1]
-    n_ctx = ntab * page
-    x = params["embed"].astype(cfg.dtype)[toks]              # [B, T, H]
-    pos = (positions[:, None]
-           + jnp.arange(t_span, dtype=jnp.int32)[None, :])   # [B, T]
-    valid = pos < n_ctx
-    pidx = jnp.clip(pos // page, 0, ntab - 1)
-    page_ids = jnp.where(
-        valid, jnp.take_along_axis(block_tables, pidx, axis=1),
-        jnp.int32(SCRATCH_PAGE))
-    rows = jnp.where(valid, pos % page, 0)
-    if cfg.attention_kind == "mla":
-        # a short span over a long context: the absorbed form, as decode
-        x, pages, _ = mla_span_forward(
-            params, cfg, x, pools.pages, pos, (page_ids, rows),
-            block_tables, absorbed=True)
-        return lm_logits_span(params, cfg, x), LatentPagedCache(pages)
-    k_pages, v_pages = pools
-    nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
-                   cfg.resolved_head_dim)
-    for li, layer in enumerate(params["layers"]):
-        h_in = rms_norm(x, layer["attn_norm"])
-        q = (h_in @ layer["wq"].astype(x.dtype)).reshape(b, t_span, nh,
-                                                         dh)
-        k = (h_in @ layer["wk"].astype(x.dtype)).reshape(b, t_span, nkv,
-                                                         dh)
-        v = (h_in @ layer["wv"].astype(x.dtype)).reshape(b, t_span, nkv,
-                                                         dh)
-        q, k = _rope(q, k, pos, cfg.rope_theta)
-
-        k_pages = k_pages.at[li].set(
-            store_tokens(k_pages[li], k, page_ids, rows))
-        v_pages = v_pages.at[li].set(
-            store_tokens(v_pages[li], v, page_ids, rows))
-
-        kk = gather_ctx(k_pages[li], block_tables)  # [B, nkv, ctx, D]
-        vv = gather_ctx(v_pages[li], block_tables)
-        if nkv != nh:
-            rep = nh // nkv
-            kk = jnp.repeat(kk, rep, axis=1)
-            vv = jnp.repeat(vv, rep, axis=1)
-        qh = q.transpose(0, 2, 1, 3)                # [B, N, T, D]
-        logits = jnp.einsum(
-            "bntd,bnsd->bnts", qh, kk, preferred_element_type=jnp.float32
-        ) * (dh ** -0.5)
-        mask = (jnp.arange(n_ctx)[None, None, None, :]
-                <= pos[:, None, :, None])
-        logits = jnp.where(mask, logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-        ctx = jnp.einsum(
-            "bnts,bnsd->bntd", probs, vv, preferred_element_type=jnp.float32
-        ).transpose(0, 2, 1, 3).reshape(b, t_span, nh * dh).astype(
-            x.dtype)
-        x = x + ctx @ layer["wo"].astype(x.dtype)
-
-        f_in = rms_norm(x, layer["ffn_norm"])
-        layer_cfg = cfg if li in cfg.moe_layer_indices else cfg.replace(
-            num_experts=1, expert_top_k=1, num_shared_experts=0)
-        o = moe_layer(layer["moe"], f_in.reshape(b * t_span, -1),
-                      layer_cfg, use_pallas=False)
-        x = x + o.out.reshape(b, t_span, -1).astype(x.dtype)
-
-    return lm_logits_span(params, cfg, x), PagedKVCache(k_pages, v_pages)
+    Rejected columns DO write rows: the host rolls back the
+    block-table/length state, and the next step's span overwrites those
+    exact rows before any causal mask exposes them (the prefill pad-row
+    invariant)."""
+    x, pools = _span_step(params, cfg, pools, toks, block_tables, positions)
+    return lm_logits_span(params, cfg, x), pools
 
 
 # The same three programs with the cache DONATED: the pool is updated in
@@ -523,7 +371,9 @@ def _paged_verify_step(params, cfg: MoEConfig, pools, toks,
 # through these: beside 11 GB of weights the chip has no room for the
 # input pool, the output pool and the pool of a prefill chunk dispatched
 # while the decode step still runs.  (The K/V programs above keep their
-# second copy: ROADMAP S4.)
+# second copy: ROADMAP S4 races donation on the backlog cell, then one
+# set of programs stays.  ``ServingEngine._paged`` is the one place that
+# picks.)
 _INPLACE = {
     fn.__name__: jax.jit(fn.__wrapped__, static_argnames=("cfg",),
                          donate_argnames=("pools",))
@@ -534,7 +384,7 @@ _INPLACE = {
 # EP-sharded decode (the fabric's decode-pool execution path)
 # ----------------------------------------------------------------------
 
-_EP_DECODE_CACHE: dict = {}
+_EP_CACHE: dict = {}
 
 
 def _ep_param_specs(params, cfg: MoEConfig):
@@ -557,197 +407,45 @@ def _ep_param_specs(params, cfg: MoEConfig):
     return tree_map_with_path(spec, params)
 
 
-def _ep_decode_fn(mesh, cfg: MoEConfig, params):
-    """Build (and cache per (mesh, cfg, param-structure)) the
-    EP-sharded twin of :func:`_paged_decode_step`: one jitted
-    ``shard_map`` whose body runs the same per-layer arithmetic on the
-    LOCAL slot rows and the LOCAL slab of the paged KV cache, with MoE
-    layers dispatched through the decode-priced ragged EP path
+def _ep_decode_fn(mesh, cfg: MoEConfig, params, *, span: bool = False):
+    """Build (and cache per (mesh, cfg, param-structure, span)) the
+    EP-sharded twin of :func:`_paged_decode_step` or, with ``span``, of
+    :func:`_paged_verify_step`: one jitted ``shard_map`` whose body is
+    the same :func:`_span_step` on the LOCAL slot rows and the LOCAL
+    slab of the paged K/V cache, with the mixture layers' experts
+    dispatched through the decode-priced ragged EP path
     (:func:`flashmoe_tpu.parallel.ragged_ep.decode_moe_rows`) — the
     plan ``serve.plan`` resolves in decode mode is what actually
-    executes here.  Block tables carry per-SHARD-local page ids."""
+    executes here.  Block tables carry per-SHARD-local page ids.
+    Called as ``fn(params, pools, toks, block_tables, positions)``."""
     import jax.tree_util as jtu
     from jax.sharding import PartitionSpec as P
 
-    key = (mesh, cfg, jtu.tree_structure(params))
-    cached = _EP_DECODE_CACHE.get(key)
+    key = (mesh, cfg, jtu.tree_structure(params), span)
+    cached = _EP_CACHE.get(key)
     if cached is not None:
         return cached
 
     from flashmoe_tpu.parallel import ragged_ep
 
-    pspecs = _ep_param_specs(params, cfg)
-    exchange = "ragged" if jax.default_backend() == "tpu" else "dense"
+    head = lm_logits_span if span else lm_logits
 
-    def body(params, k_pages, v_pages, toks, block_tables, positions):
-        # LOCAL view: max_batch/d slot rows, num_pages/d slab pages.
-        # Attention mirrors _paged_decode_step (kept duplicated so the
-        # unsharded path stays byte-identical to its pre-fabric form);
-        # only the MoE FFN differs.
-        b = toks.shape[0]
-        nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
-                       cfg.resolved_head_dim)
-        page = k_pages.shape[3]
-        n_ctx = block_tables.shape[1] * page
-        x = params["embed"].astype(cfg.dtype)[toks][:, None, :]
-        page_ids = jnp.take_along_axis(
-            block_tables, (positions // page)[:, None], axis=1)[:, 0]
-        rows = positions % page
-        for li, layer in enumerate(params["layers"]):
-            h_in = rms_norm(x, layer["attn_norm"])
-            q = (h_in @ layer["wq"].astype(x.dtype)).reshape(b, 1, nh,
-                                                             dh)
-            k = (h_in @ layer["wk"].astype(x.dtype)).reshape(b, 1, nkv,
-                                                             dh)
-            v = (h_in @ layer["wv"].astype(x.dtype)).reshape(b, 1, nkv,
-                                                             dh)
-            q, k = _rope(q, k, positions[:, None], cfg.rope_theta)
+    def body(params, pools, toks, block_tables, positions):
+        x, pools = _span_step(params, cfg, pools,
+                              toks if span else toks[:, None],
+                              block_tables, positions,
+                              mixture=ragged_ep.decode_moe_rows)
+        return head(params, cfg, x), pools
 
-            k_pages = k_pages.at[li].set(
-                store_token(k_pages[li], k[:, 0], page_ids, rows))
-            v_pages = v_pages.at[li].set(
-                store_token(v_pages[li], v[:, 0], page_ids, rows))
-
-            kk = gather_ctx(k_pages[li], block_tables)
-            vv = gather_ctx(v_pages[li], block_tables)
-            if nkv != nh:
-                rep = nh // nkv
-                kk = jnp.repeat(kk, rep, axis=1)
-                vv = jnp.repeat(vv, rep, axis=1)
-            qh = q.transpose(0, 2, 1, 3)
-            logits = jnp.einsum(
-                "bntd,bnsd->bnts", qh, kk,
-                preferred_element_type=jnp.float32) * (dh ** -0.5)
-            mask = (jnp.arange(n_ctx)[None, :]
-                    <= positions[:, None])[:, None, None, :]
-            logits = jnp.where(mask, logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-            ctx = jnp.einsum(
-                "bnts,bnsd->bntd", probs, vv,
-                preferred_element_type=jnp.float32
-            ).transpose(0, 2, 1, 3).reshape(b, 1, nh * dh).astype(
-                x.dtype)
-            x = x + ctx @ layer["wo"].astype(x.dtype)
-
-            f_in = rms_norm(x, layer["ffn_norm"])
-            if li in cfg.moe_layer_indices:
-                o_out = ragged_ep.decode_moe_rows(
-                    layer["moe"], f_in.reshape(b, -1), cfg,
-                    axis="ep", exchange=exchange).out
-            else:
-                dense_cfg = cfg.replace(num_experts=1, expert_top_k=1,
-                                        num_shared_experts=0)
-                o_out = moe_layer(layer["moe"], f_in.reshape(b, -1),
-                                  dense_cfg, use_pallas=False).out
-            x = x + o_out.reshape(b, 1, -1).astype(x.dtype)
-
-        return lm_logits(params, cfg, x), k_pages, v_pages
-
+    slab = PagedKVCache(P(None, "ep"), P(None, "ep"))
     fn = jax.jit(jax.shard_map(
         body, mesh=mesh,
-        in_specs=(pspecs, P(None, "ep"), P(None, "ep"), P("ep"),
-                  P("ep", None), P("ep")),
-        out_specs=(P("ep"), P(None, "ep"), P(None, "ep")),
+        in_specs=(_ep_param_specs(params, cfg), slab,
+                  P("ep", None) if span else P("ep"), P("ep", None),
+                  P("ep")),
+        out_specs=(P("ep"), slab),
         check_vma=False))
-    _EP_DECODE_CACHE[key] = fn
-    return fn
-
-
-_EP_VERIFY_CACHE: dict = {}
-
-
-def _ep_verify_fn(mesh, cfg: MoEConfig, params):
-    """The EP-sharded twin of :func:`_paged_verify_step`: the same
-    span-scoring body over the LOCAL slot rows and cache slab, MoE
-    through the decode-priced ragged EP path on ``b_local * T`` rows.
-    Cached like :func:`_ep_decode_fn`."""
-    import jax.tree_util as jtu
-    from jax.sharding import PartitionSpec as P
-
-    key = (mesh, cfg, jtu.tree_structure(params))
-    cached = _EP_VERIFY_CACHE.get(key)
-    if cached is not None:
-        return cached
-
-    from flashmoe_tpu.parallel import ragged_ep
-
-    pspecs = _ep_param_specs(params, cfg)
-    exchange = "ragged" if jax.default_backend() == "tpu" else "dense"
-
-    def body(params, k_pages, v_pages, toks, block_tables, positions):
-        b, t_span = toks.shape
-        nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
-                       cfg.resolved_head_dim)
-        page = k_pages.shape[3]
-        ntab = block_tables.shape[1]
-        n_ctx = ntab * page
-        x = params["embed"].astype(cfg.dtype)[toks]
-        pos = (positions[:, None]
-               + jnp.arange(t_span, dtype=jnp.int32)[None, :])
-        valid = pos < n_ctx
-        pidx = jnp.clip(pos // page, 0, ntab - 1)
-        page_ids = jnp.where(
-            valid, jnp.take_along_axis(block_tables, pidx, axis=1),
-            jnp.int32(SCRATCH_PAGE))
-        rows = jnp.where(valid, pos % page, 0)
-        for li, layer in enumerate(params["layers"]):
-            h_in = rms_norm(x, layer["attn_norm"])
-            q = (h_in @ layer["wq"].astype(x.dtype)).reshape(
-                b, t_span, nh, dh)
-            k = (h_in @ layer["wk"].astype(x.dtype)).reshape(
-                b, t_span, nkv, dh)
-            v = (h_in @ layer["wv"].astype(x.dtype)).reshape(
-                b, t_span, nkv, dh)
-            q, k = _rope(q, k, pos, cfg.rope_theta)
-
-            k_pages = k_pages.at[li].set(
-                store_tokens(k_pages[li], k, page_ids, rows))
-            v_pages = v_pages.at[li].set(
-                store_tokens(v_pages[li], v, page_ids, rows))
-
-            kk = gather_ctx(k_pages[li], block_tables)
-            vv = gather_ctx(v_pages[li], block_tables)
-            if nkv != nh:
-                rep = nh // nkv
-                kk = jnp.repeat(kk, rep, axis=1)
-                vv = jnp.repeat(vv, rep, axis=1)
-            qh = q.transpose(0, 2, 1, 3)
-            logits = jnp.einsum(
-                "bntd,bnsd->bnts", qh, kk,
-                preferred_element_type=jnp.float32) * (dh ** -0.5)
-            mask = (jnp.arange(n_ctx)[None, None, None, :]
-                    <= pos[:, None, :, None])
-            logits = jnp.where(mask, logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-            ctx = jnp.einsum(
-                "bnts,bnsd->bntd", probs, vv,
-                preferred_element_type=jnp.float32
-            ).transpose(0, 2, 1, 3).reshape(b, t_span, nh * dh).astype(
-                x.dtype)
-            x = x + ctx @ layer["wo"].astype(x.dtype)
-
-            f_in = rms_norm(x, layer["ffn_norm"])
-            if li in cfg.moe_layer_indices:
-                o_out = ragged_ep.decode_moe_rows(
-                    layer["moe"], f_in.reshape(b * t_span, -1), cfg,
-                    axis="ep", exchange=exchange).out
-            else:
-                dense_cfg = cfg.replace(num_experts=1, expert_top_k=1,
-                                        num_shared_experts=0)
-                o_out = moe_layer(layer["moe"],
-                                  f_in.reshape(b * t_span, -1),
-                                  dense_cfg, use_pallas=False).out
-            x = x + o_out.reshape(b, t_span, -1).astype(x.dtype)
-
-        return lm_logits_span(params, cfg, x), k_pages, v_pages
-
-    fn = jax.jit(jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(pspecs, P(None, "ep"), P(None, "ep"), P("ep", None),
-                  P("ep", None), P("ep")),
-        out_specs=(P("ep"), P(None, "ep"), P(None, "ep")),
-        check_vma=False))
-    _EP_VERIFY_CACHE[key] = fn
+    _EP_CACHE[key] = fn
     return fn
 
 
@@ -886,9 +584,9 @@ class ServingEngine:
         if mla and sv.ep_shards > 1:
             raise NotImplementedError(
                 "attention_kind='mla' with ep_shards > 1: _ep_decode_fn "
-                "and _ep_verify_fn shard a K/V page pair over the mesh "
-                "and attend per kv head; a latent pool has neither, and "
-                "their bodies have no latent arm yet")
+                "shards a K/V page pair over the mesh, and the mesh's "
+                "expert exchange has no selection bias; a latent slab "
+                "and the bias through ragged_ep are missing (ROADMAP R4)")
         if mla and prefill_fn is not None:
             raise NotImplementedError(
                 "attention_kind='mla' with a prefill_fn (the fabric's KV "
@@ -1473,7 +1171,9 @@ class ServingEngine:
 
     def _paged(self, name: str):
         """The paged program ``name`` of this module (looked up at call
-        time), or for an MLA model its in-place twin."""
+        time), or for an MLA model its in-place twin: the ONE place
+        that decides donation, by attention kind until ROADMAP S4 has
+        raced it on the K/V cell."""
         if self.cfg.attention_kind == "mla":
             return _INPLACE[name]
         return globals()[name]
@@ -1597,14 +1297,12 @@ class ServingEngine:
         self._phase("serve.verify")
         if self._ep_fn is not None:
             if self._ep_verify is None:
-                self._ep_verify = _ep_verify_fn(
-                    self.mesh, self.cfg, self.params)
-            span_logits, kp, vp = self._ep_verify(
-                self.params, self.cache.k_pages,
-                self.cache.v_pages, jnp.asarray(feed),
+                self._ep_verify = _ep_decode_fn(
+                    self.mesh, self.cfg, self.params, span=True)
+            span_logits, self.cache = self._ep_verify(
+                self.params, self.cache, jnp.asarray(feed),
                 jnp.asarray(tables[:, :n_ctx]),
                 jnp.asarray(positions))
-            self.cache = PagedKVCache(kp, vp)
         else:
             span_logits, self.cache = self._paged("_paged_verify_step")(
                 self.params, self.cfg, self.cache, jnp.asarray(feed),
@@ -1920,12 +1618,10 @@ class ServingEngine:
             self._note_ctx(n_ctx, own_pages, len(active))
             self._phase("serve.decode")
             if self._ep_fn is not None:
-                logits, kp, vp = self._ep_fn(
-                    self.params, self.cache.k_pages,
-                    self.cache.v_pages, jnp.asarray(feed),
+                logits, self.cache = self._ep_fn(
+                    self.params, self.cache, jnp.asarray(feed),
                     jnp.asarray(tables[:, :n_ctx]),
                     jnp.asarray(positions))
-                self.cache = PagedKVCache(kp, vp)
             else:
                 logits, self.cache = self._paged("_paged_decode_step")(
                     self.params, self.cfg, self.cache, jnp.asarray(feed),

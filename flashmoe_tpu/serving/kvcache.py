@@ -109,48 +109,9 @@ def page_size_of(pools, cfg: MoEConfig) -> int:
 
 
 # ----------------------------------------------------------------------
-# In-graph page ops (called inside the engine's jitted step)
+# Whole-run page write (the engine's admission; the per-span store and the
+# gather of the jitted steps are ops/attention.py's store_kv / gather_ctx)
 # ----------------------------------------------------------------------
-
-def store_token(pages, token_kv, page_ids, rows):
-    """Scatter one decode step's per-slot K (or V) into its pages.
-
-    pages: ``[P, N_kv, page, D]`` (one layer's pool); token_kv:
-    ``[B, N_kv, D]``; page_ids/rows: ``[B]`` int32 (inactive slots pass
-    ``SCRATCH_PAGE`` / 0 — duplicate scratch writes race, but scratch
-    content is never read back with non-zero weight)."""
-    return pages.at[page_ids, :, rows, :].set(token_kv)
-
-
-def store_tokens(pages, span_kv, page_ids, rows):
-    """Scatter a verify step's drafted SPAN into its pages — the
-    multi-position twin of :func:`store_token` (ISSUE 20 speculative
-    decode).
-
-    pages: ``[P, N_kv, page, D]`` (one layer's pool); span_kv:
-    ``[B, T, N_kv, D]`` for a ``T = draft_tokens + 1`` wide span;
-    page_ids/rows: ``[B, T]`` int32.  The advanced indices at axes 0
-    and 2 are split by the head-axis slice, so numpy semantics front
-    the broadcast ``[B, T]`` dims — the result aligns with ``span_kv``
-    exactly.  Out-of-span and inactive positions pass ``SCRATCH_PAGE``;
-    rejected-draft rows land in pages the engine rolls back (or rows a
-    later step overwrites before any causal mask exposes them — the
-    same invariant prefill pad rows rely on)."""
-    return pages.at[page_ids, :, rows, :].set(span_kv)
-
-
-def gather_ctx(pages, block_tables):
-    """Gather each slot's context window from its pages.
-
-    pages: ``[P, N_kv, page, D]``; block_tables: ``[B, n]`` page ids
-    (already sliced to the bucketed page count).  Returns
-    ``[B, N_kv, n * page, D]`` — rows past a request's length are
-    scratch/garbage and MUST be masked by the caller's length mask."""
-    b, n = block_tables.shape
-    g = pages[block_tables]                    # [B, n, N_kv, page, D]
-    _, _, nkv, page, d = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, nkv, n * page, d)
-
 
 def store_prefill(pages, seq_kv, page_ids):
     """Scatter a prefilled dense K (or V) run into freshly-allocated

@@ -7,6 +7,7 @@ mid-flight whose outputs are token-bit-equal to the same prompts
 decoded one at a time through ``generate()``.
 """
 
+import functools
 import json
 import os
 
@@ -22,9 +23,10 @@ from flashmoe_tpu.serving import engine as eng
 from flashmoe_tpu.serving.engine import (
     Request, ServeConfig, ServingEngine,
 )
+from flashmoe_tpu.ops.attention import gather_ctx, store_kv
 from flashmoe_tpu.serving.kvcache import (
-    SCRATCH_PAGE, PagePool, ctx_pages_bucket, gather_ctx,
-    init_paged_cache, prompt_pad, store_prefill, store_token,
+    SCRATCH_PAGE, PagePool, ctx_pages_bucket, init_paged_cache,
+    prompt_pad, store_prefill,
 )
 from flashmoe_tpu.serving.loadgen import (
     build_requests, serve_load_sweep, tiny_config,
@@ -88,7 +90,7 @@ def test_ctx_bucketing():
 
 
 def test_paged_store_gather_roundtrip():
-    """store_prefill + store_token + gather_ctx reproduce a dense K/V
+    """store_prefill + store_kv + gather_ctx reproduce a dense K/V
     run exactly (the block-table indirection is pure reindexing)."""
     cache = init_paged_cache(CFG, num_pages=8, page_size=4)
     nkv, dh = CFG.resolved_num_kv_heads, CFG.resolved_head_dim
@@ -100,8 +102,8 @@ def test_paged_store_gather_roundtrip():
     # one decode token at position 8 goes into a third page
     tok = jax.random.normal(jax.random.PRNGKey(3), (1, nkv, dh),
                             CFG.dtype)
-    kp = kp.at[0].set(store_token(kp[0], tok, jnp.asarray([6]),
-                                  jnp.asarray([0])))
+    kp = store_kv(kp, 0, tok[:, None], jnp.asarray([[6]]),
+                  jnp.asarray([[0]]))
     bt = jnp.asarray([[3, 5, 6]], jnp.int32)        # this slot's table
     got = gather_ctx(kp[0], bt)                     # [1, nkv, 12, dh]
     np.testing.assert_array_equal(np.asarray(got[0, :, :8]),
@@ -1229,3 +1231,239 @@ def test_draft_state_ngram_index():
     f = spec_stats_fields(4, 3, 2)
     assert f["accept_rate"] == 0.75
     assert f["spec_tokens_per_step"] == 2.5
+
+
+# ----------------------------------------------------------------------
+# One layer body for every cached path (ISSUE 29)
+# ----------------------------------------------------------------------
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _parent_span_step(params, cfg, pools, toks, block_tables, positions,
+                      row=None):
+    """THE REFERENCE: the K/V arm of ``_paged_verify_step`` as it stood
+    before the seven hand-copied bodies became ``generate.span_forward``
+    (commit a402b38), verbatim, with the two page ops it called
+    (``kvcache.store_tokens``, ``kvcache.gather_ctx``) written out.  A
+    ``[B, T]`` span over pages is the most general of the seven: decode
+    is T = 1, a prefill chunk one slot writing whole pages, a whole
+    prompt one slot over fresh pages.  ``row`` is the one addition: the
+    prefill programs' head (the lm head on ONE hidden row of slot 0, as
+    they apply it: a product over one row and over T rows may round
+    differently)."""
+    from flashmoe_tpu.models.generate import lm_logits, lm_logits_span
+    from flashmoe_tpu.models.transformer import _rope, rms_norm
+    from flashmoe_tpu.ops.moe import moe_layer
+    from flashmoe_tpu.serving.kvcache import PagedKVCache, page_size_of
+
+    def store_tokens(pages, span_kv, page_ids, rows):
+        return pages.at[page_ids, :, rows, :].set(span_kv)
+
+    def gather_ctx(pages, block_tables):
+        b, n = block_tables.shape
+        g = pages[block_tables]                    # [B, n, N_kv, page, D]
+        _, _, nkv, page, d = g.shape
+        return g.transpose(0, 2, 1, 3, 4).reshape(b, nkv, n * page, d)
+
+    b, t_span = toks.shape
+    page = page_size_of(pools, cfg)
+    ntab = block_tables.shape[1]
+    n_ctx = ntab * page
+    x = params["embed"].astype(cfg.dtype)[toks]              # [B, T, H]
+    pos = (positions[:, None]
+           + jnp.arange(t_span, dtype=jnp.int32)[None, :])   # [B, T]
+    valid = pos < n_ctx
+    pidx = jnp.clip(pos // page, 0, ntab - 1)
+    page_ids = jnp.where(
+        valid, jnp.take_along_axis(block_tables, pidx, axis=1),
+        jnp.int32(SCRATCH_PAGE))
+    rows = jnp.where(valid, pos % page, 0)
+    k_pages, v_pages = pools
+    nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
+                   cfg.resolved_head_dim)
+    for li, layer in enumerate(params["layers"]):
+        h_in = rms_norm(x, layer["attn_norm"])
+        q = (h_in @ layer["wq"].astype(x.dtype)).reshape(b, t_span, nh,
+                                                         dh)
+        k = (h_in @ layer["wk"].astype(x.dtype)).reshape(b, t_span, nkv,
+                                                         dh)
+        v = (h_in @ layer["wv"].astype(x.dtype)).reshape(b, t_span, nkv,
+                                                         dh)
+        q, k = _rope(q, k, pos, cfg.rope_theta)
+
+        k_pages = k_pages.at[li].set(
+            store_tokens(k_pages[li], k, page_ids, rows))
+        v_pages = v_pages.at[li].set(
+            store_tokens(v_pages[li], v, page_ids, rows))
+
+        kk = gather_ctx(k_pages[li], block_tables)  # [B, nkv, ctx, D]
+        vv = gather_ctx(v_pages[li], block_tables)
+        if nkv != nh:
+            rep = nh // nkv
+            kk = jnp.repeat(kk, rep, axis=1)
+            vv = jnp.repeat(vv, rep, axis=1)
+        qh = q.transpose(0, 2, 1, 3)                # [B, N, T, D]
+        logits = jnp.einsum(
+            "bntd,bnsd->bnts", qh, kk, preferred_element_type=jnp.float32
+        ) * (dh ** -0.5)
+        mask = (jnp.arange(n_ctx)[None, None, None, :]
+                <= pos[:, None, :, None])
+        logits = jnp.where(mask, logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
+        ctx = jnp.einsum(
+            "bnts,bnsd->bntd", probs, vv, preferred_element_type=jnp.float32
+        ).transpose(0, 2, 1, 3).reshape(b, t_span, nh * dh).astype(
+            x.dtype)
+        x = x + ctx @ layer["wo"].astype(x.dtype)
+
+        f_in = rms_norm(x, layer["ffn_norm"])
+        layer_cfg = cfg if li in cfg.moe_layer_indices else cfg.replace(
+            num_experts=1, expert_top_k=1, num_shared_experts=0)
+        o = moe_layer(layer["moe"], f_in.reshape(b * t_span, -1),
+                      layer_cfg, use_pallas=False)
+        x = x + o.out.reshape(b, t_span, -1).astype(x.dtype)
+
+    if row is not None:
+        h = jax.lax.dynamic_slice(x, (0, row, 0), (1, 1, x.shape[-1]))
+        return lm_logits(params, cfg, h)[0], PagedKVCache(k_pages, v_pages)
+    return lm_logits_span(params, cfg, x), PagedKVCache(k_pages, v_pages)
+
+
+#: MHA with an interleaved dense layer (the drill's model); GQA, every
+#: layer a mixture with a shared expert; one kv head under four query
+#: heads, three layers of which one is a mixture, in bf16
+_BODY_CFGS = {
+    "mha_dense": CFG,
+    "gqa": CFG.replace(num_heads=4, num_kv_heads=2, moe_frequency=1,
+                       num_shared_experts=1),
+    "mqa_dense_bf16": CFG.replace(num_heads=4, num_kv_heads=1,
+                                  num_layers=3, dtype=jnp.bfloat16),
+}
+_PAGE = 8
+
+
+@pytest.fixture(scope="module", params=sorted(_BODY_CFGS))
+def body_state(request, prompts):
+    """A config, its weights and a 32-page pool holding the eight drill
+    prompts (slot i in page 1 + i, its second page 9 + i), written by
+    the REFERENCE body."""
+    cfg = _BODY_CFGS[request.param]
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tables = np.stack([1 + np.arange(8), 9 + np.arange(8)],
+                      axis=1).astype(np.int32)
+    _, pools = _parent_span_step(
+        params, cfg, init_paged_cache(cfg, 32, _PAGE), prompts,
+        jnp.asarray(tables), jnp.zeros((8,), jnp.int32))
+    tables[7] = SCRATCH_PAGE                       # an idle slot
+    return cfg, params, pools, jnp.asarray(tables)
+
+
+def _same_bits(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+
+
+@pytest.mark.parametrize("program", ["decode", "verify", "chunk",
+                                     "whole_prompt"])
+def test_every_paged_program_is_the_parents_span_body(body_state, prompts,
+                                                      program):
+    """The four jitted programs, each now ``span_forward`` under an
+    embed and a head, hold to the kept copy of the parent's body BIT
+    for bit, logits and pools: any drift of arithmetic order fails."""
+    cfg, params, pools, tables = body_state
+    for li in set(range(cfg.num_layers)) - set(cfg.moe_layer_indices):
+        # the dense layer's config is the one the parent built inline:
+        # an EQUAL frozen config, so the same jit cache entry
+        inline = cfg.replace(num_experts=1, expert_top_k=1,
+                             num_shared_experts=0)
+        assert cfg.ffn_config(li) == inline
+        assert hash(cfg.ffn_config(li)) == hash(inline)
+    if program == "decode":
+        pos = jnp.asarray([8, 8, 7, 15, 8, 6, 8, 0], jnp.int32)
+        toks = prompts[:, 0]
+        got, got_pools = eng._paged_decode_step(params, cfg, pools, toks,
+                                                tables, pos)
+        want, want_pools = _parent_span_step(params, cfg, pools,
+                                             toks[:, None], tables, pos)
+        want = want[:, 0]
+    elif program == "verify":
+        # slot 3 drafts into its context ceiling (2 pages = 16 rows,
+        # span 14..17): columns 2 and 3 go to the scratch page
+        pos = jnp.asarray([8, 9, 7, 14, 8, 6, 8, 0], jnp.int32)
+        got, got_pools = eng._paged_verify_step(
+            params, cfg, pools, prompts[:, :4], tables, pos)
+        want, want_pools = _parent_span_step(
+            params, cfg, pools, prompts[:, :4], tables, pos)
+    elif program == "chunk":
+        # 16 tokens at positions 8..23 of slot 2, into pages 20 and 21
+        toks = prompts[4:6].reshape(1, 16)
+        table = jnp.asarray([3, 20, 21], jnp.int32)
+        got, got_pools = eng._prefill_chunk(
+            params, cfg, pools, toks, table, table[1:], jnp.int32(8),
+            jnp.int32(13))
+        want, want_pools = _parent_span_step(
+            params, cfg, pools, toks, table[None, :],
+            jnp.asarray([8], jnp.int32), row=jnp.int32(13))
+    else:
+        # a 13-token prompt padded to 16, stored into pages 25 and 17
+        toks = prompts[6:8].reshape(1, 16)
+        page_ids = jnp.asarray([25, 17], jnp.int32)
+        got, *runs = eng._prefill_padded(params, cfg, toks, jnp.int32(13))
+        got_pools = type(pools)(*(store_prefill(pool, run, page_ids)
+                                  for pool, run in zip(pools, runs)))
+        want, want_pools = _parent_span_step(
+            params, cfg, pools, toks, page_ids[None, :],
+            jnp.zeros((1,), jnp.int32), row=jnp.int32(12))
+    assert type(got_pools) is type(want_pools)
+    if program == "whole_prompt" and cfg.resolved_num_kv_heads == cfg.num_heads:
+        # the one case the CPU cannot hold to the bit: with no GQA repeat
+        # between them, XLA's CPU backend reads the scores' K operand
+        # straight out of the projection's transpose here and out of the
+        # page gather in the reference, and rounds the two products
+        # differently (8e-7 on the logits; so did the parent's program:
+        # parent and change ARE bit-equal here, run side by side for
+        # ISSUE 29).  The first layer's rows, written before any
+        # attention, are held to the bit; the rest to float32's grain.
+        for got_pool, want_pool in zip(got_pools, want_pools):
+            _same_bits(got_pool[0], want_pool[0])
+            np.testing.assert_allclose(got_pool, want_pool, rtol=2e-5,
+                                       atol=2e-6)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+        return
+    _same_bits(got, want)
+    for got_pool, want_pool in zip(got_pools, want_pools):
+        _same_bits(got_pool, want_pool)
+
+
+@pytest.mark.parametrize("speculate", [None, 3], ids=["plain", "speculate"])
+def test_ep_sharded_decode_token_equal_to_one_device(params, prompts,
+                                                     spec_prompts,
+                                                     speculate):
+    """``ServeConfig(ep_shards=2)`` on two of the CPU's virtual devices
+    (the slot grid, the page slab and the experts split over ``"ep"``,
+    the mixture layers through ``ragged_ep.decode_moe_rows``): token
+    streams equal to the one-device engine's, through the decode twin
+    and, with speculation on, through the verify twin — the one
+    ``_span_step`` body under ``shard_map``."""
+    from flashmoe_tpu.serving.speculate import SpecConfig
+
+    src = spec_prompts if speculate else prompts
+
+    def run(ep_shards):
+        engine = ServingEngine(params, CFG, ServeConfig(
+            max_batch=4, page_size=8, num_pages=16, max_pages_per_slot=4,
+            ctx_bucket_pages=1, prompt_bucket=8, ep_shards=ep_shards,
+            speculate=SpecConfig(draft_tokens=speculate)
+            if speculate else None))
+        return engine, engine.run(_requests(src, 6, max_new=8),
+                                  arrivals=[0, 0, 0, 1, 1, 2])
+
+    _, want = run(1)
+    engine, got = run(2)
+    assert engine._ep_fn is not None and engine.summary()["completed"] == 6
+    if speculate:
+        assert engine._ep_verify is not None
+        assert engine.spec_snapshot()["spec_accepted"] > 0
+    for i in range(6):
+        np.testing.assert_array_equal(np.asarray(got[i]),
+                                      np.asarray(want[i]))

@@ -416,3 +416,67 @@ def test_mla_decode_step_reads_latent_rows_only(mla_decode_compiled):
     assert big == []
     assert "bf16[32,7168,576]" in text          # the gathered latent rows
     assert "attn.mla_decode" in text and "moe.gate" in text
+
+
+def _program_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+@pytest.fixture(scope="module")
+def backlog_programs(one_chip):
+    """The backlog cell's widest decode program and its largest
+    whole-prompt prefill (``dsmoe16b``: 6 layers in bf16, 32 slots, a
+    2048 x 16-token K/V pool, tables at their 160 pages, a 2048-token
+    pad), lowered as the engine runs them: nothing donated."""
+    from flashmoe_tpu.models.presets import PRESETS
+    from flashmoe_tpu.models.transformer import init_params
+    from flashmoe_tpu.serving import engine as eng
+    from flashmoe_tpu.serving.kvcache import init_paged_cache
+
+    cfg = PRESETS["deepseek-moe-16b"](num_layers=6,
+                                      param_dtype=jnp.bfloat16)
+    on = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = on(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on(jax.eval_shape(lambda: init_paged_cache(cfg, 2048, 16)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32, sharding=one_chip)
+    return {
+        "decode": eng._paged_decode_step.lower(
+            params, cfg, cache, i32(32), i32(32, 160), i32(32)),
+        "prefill": eng._prefill_padded.lower(
+            params, cfg, i32(1, 2048), i32())}
+
+
+def test_backlog_decode_step_is_the_program_the_ledger_measured(
+        backlog_programs):
+    """What the K/V decode step compiles to since before ISSUE 29 merged
+    the seven layer bodies, pinned so that ROADMAP S2 / S4 move it on
+    purpose: 13.07 GB (7.90 of weights, the 1.61 GB pool TWICE, the
+    gathered contexts) and FOUR copies of a whole pool in gather
+    order."""
+    compiled = backlog_programs["decode"].compile()
+    assert abs(_program_bytes(compiled) / 13.0654e9 - 1) < 0.01
+    assert compiled.memory_analysis().alias_size_in_bytes == 0
+    text = compiled.as_text()
+    pool_copies = re.findall(
+        r"^.*= bf16\[6,2048,16,16,128\]\S* copy\(.*$", text, re.M)
+    assert len(pool_copies) == 4, pool_copies
+    assert "moe.gate" in text and "moe.expert" in text
+
+
+def test_backlog_whole_prompt_prefill_fits_beside_the_pool(
+        backlog_programs):
+    """A 2048-token prompt at once: 9.76 GB as compiled (the weights,
+    f32 scores of 16 heads over 2048 x 2048, the experts over E x S
+    rows), which leaves the engine's pool its 1.61 GB; the program holds
+    no pool and hands back one K and one V run for ``store_prefill``."""
+    compiled = backlog_programs["prefill"].compile()
+    assert abs(_program_bytes(compiled) / 9.7633e9 - 1) < 0.01
+    logits, k_run, v_run = jax.tree.leaves(compiled.out_info)
+    assert logits.shape == (102400,) and logits.dtype == jnp.float32
+    assert k_run.shape == v_run.shape == (6, 16, 2048, 128)
+    assert "[6,2048,16,16,128]" not in compiled.as_text()
