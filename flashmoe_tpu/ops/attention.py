@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from flashmoe_tpu.config import LANE
 from flashmoe_tpu.utils.telemetry import trace_span
 
 NEG_INF = -1e30
@@ -226,7 +227,9 @@ def mla_paged_attention(layer, x, cfg, pool, li, pos, write, block_tables,
 # keeps of a token its roped K and its V per kv head, in a pair of pools
 # ``[L, P, N_kv, page, D]`` (``serving/kvcache.PagedKVCache``; generate's
 # dense ``KVCache`` is the same layout with one ``T_max``-row page a batch
-# row).
+# row).  The functions of this section are the plain form; a short span
+# over a paged pool on a TPU takes the kernel further down instead
+# (``kv_attention_arm``).
 
 def rope_halves(q, k, positions, theta):
     """Rotary position embeddings over the two HALVES (i, i + D/2) of the
@@ -301,11 +304,6 @@ def store_kv(pages, li: int, span_kv, page_ids, rows):
         whole = span_kv.reshape(-1, pages.shape[3], nkv, d)
         return pages.at[li, page_ids.reshape(-1)].set(
             whole.transpose(0, 2, 1, 3).astype(pages.dtype))
-    if span_kv.shape[1] == 1:
-        # a decode step, one row a slot: [B] indices, for which the
-        # chip's compiler builds one index fusion less than for [B, 1]
-        # (until ROADMAP S2's kernel writes its rows itself)
-        page_ids, rows, span_kv = page_ids[:, 0], rows[:, 0], span_kv[:, 0]
     # the advanced indices at axes 0 and 2 are split by the head-axis
     # slice, so numpy semantics front their broadcast dims: the result
     # aligns with span_kv exactly
@@ -329,25 +327,37 @@ def gather_ctx(pages, block_tables):
 def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
     """THE K/V attention of every cached path, with
     :func:`mla_paged_attention`'s contract: project a span of T tokens a
-    slot, write its K and V rows to layer ``li``'s pages, gather the
-    context, attend.
+    slot, write its K and V rows to layer ``li``'s pages, attend over the
+    context.  Two arms by what the shapes and the backend say
+    (:func:`kv_attention_arm`): a short span over a paged pool on a TPU
+    reads each slot's own pages in place (:func:`paged_decode_attention`);
+    everything else stores, gathers the context and attends in plain XLA
+    (:func:`store_kv`, :func:`gather_ctx`, :func:`kv_attend`: the form
+    the kernel is held against).
 
     x: [B, T, H] normed; pools: the ``(k_pages, v_pages)`` pair, each
     [L, P, N_kv, page, D], or None for a whole prompt at once (the
-    context is the span itself); pos: [B, T] absolute positions; write:
-    ``(page_ids, rows)``, each [B, T], or ``(page_ids [B, T // page],
-    None)`` for a span of whole pages; block_tables: [B, n].  Returns
-    (attention output [B, T, H], the pools, the span's ``(k, v)`` rows
-    laid out as a context, each [B, N_kv, T, D])."""
+    context is the span itself); pos: [B, T] absolute positions,
+    consecutive along T; write: ``(page_ids, rows)``, each [B, T], or
+    ``(page_ids [B, T // page], None)`` for a span of whole pages;
+    block_tables: [B, n].  Returns (attention output [B, T, H], the
+    pools, the span's ``(k, v)`` rows laid out as a context, each
+    [B, N_kv, T, D])."""
     q, k, v = kv_project(layer, x, cfg, pos)
     span = (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
     if pools is None:
-        k_ctx, v_ctx = span
-    else:
-        pools = (store_kv(pools[0], li, k, *write),
-                 store_kv(pools[1], li, v, *write))
-        k_ctx = gather_ctx(pools[0][li], block_tables)
-        v_ctx = gather_ctx(pools[1][li], block_tables)
+        return kv_attend(layer, q, *span, pos), pools, span
+    n_kv, page, d = pools[0].shape[2:]
+    if (write[1] is not None and kv_attention_arm(
+            q.shape[1], page, n_kv, d, pools[0].dtype) == "paged_kernel"):
+        ctx, pools = paged_decode_attention(
+            q, k, v, pools, li, block_tables, pos[:, 0], write,
+            interpret=jax.default_backend() != "tpu")
+        return ctx @ layer["wo"].astype(q.dtype), pools, span
+    pools = (store_kv(pools[0], li, k, *write),
+             store_kv(pools[1], li, v, *write))
+    k_ctx = gather_ctx(pools[0][li], block_tables)
+    v_ctx = gather_ctx(pools[1][li], block_tables)
     return kv_attend(layer, q, k_ctx, v_ctx, pos), pools, span
 
 
@@ -365,6 +375,262 @@ def paged_attention(layer, x, cfg, pools, li, pos, write, block_tables, *,
         layer, x, cfg, None if pools is None else pools[0], li, pos, write,
         block_tables, absorbed=absorbed)
     return out, None if pools is None else (pool,), (latent,)
+
+
+# ----------------------------------------------------------------------
+# Paged decode attention kernel: a short span over each slot's OWN pages
+# ----------------------------------------------------------------------
+#
+# ``gather_ctx`` + ``kv_attend`` materialise every slot's context at the
+# batch's bucket ([B, N_kv, n * page, D], K and V, a layer), and with a
+# query or five a head the chip's compiler turns the scores into an f32
+# copy of all of it.  This kernel reads the pool where it lies: slot b's
+# block table and position arrive in SMEM before the body runs, the pages
+# that hold positions [0, pos_b) come into VMEM in blocks of
+# ``_KV_BLOCK`` positions, one [N_kv, page, D] DMA a page (all heads of a
+# page are contiguous in ``[L, P, N_kv, page, D]``), double-buffered, and
+# an online softmax runs over them.  The span's own rows are operands:
+# they are attended to from VMEM and written into the slot's page by the
+# kernel itself (the page is read, its rows replaced, and written back:
+# the pools are aliased to the outputs and no XLA instruction touches
+# them, so they keep their layout and are never copied).
+
+#: context positions a block of the paged decode kernel holds
+_KV_BLOCK = LANE
+
+#: VMEM the kernel's two double-buffered context blocks (K and V) may take
+_KV_VMEM_BUDGET = 8 * 1024 * 1024
+
+
+def kv_attention_arm(t: int, page: int, n_kv: int, d: int, dtype) -> str:
+    """The arm :func:`kv_paged_attention` takes for a span of ``t`` rows a
+    slot over a pool of pages ``[n_kv, page, d]``: ``"paged_kernel"``
+    (:func:`paged_decode_attention`) for a span shorter than a page over
+    pages that tile a VMEM block (whole packed tiles of ``dtype``, a
+    divisor of the block, full lanes, four blocks within the budget) on a
+    TPU; ``"gather"`` (:func:`gather_ctx` + :func:`kv_attend`) for
+    everything else: a chunk, the dense cache's one ``T_max``-row page,
+    any other backend.  The engine's records ask the same function."""
+    itemsize = jnp.dtype(dtype).itemsize
+    rows = 32 // itemsize                       # of a packed (rows, 128) tile
+    fits = (t < page and _KV_BLOCK % page == 0 and page % rows == 0
+            and d % LANE == 0
+            and 4 * n_kv * _KV_BLOCK * d * itemsize <= _KV_VMEM_BUDGET)
+    return ("paged_kernel" if fits and jax.default_backend() == "tpu"
+            else "gather")
+
+
+def paged_decode_block_pages(page: int, n_tab: int) -> int:
+    """Pages a block of the kernel reads, under tables ``n_tab`` pages
+    wide (a slot's context is read rounded up to this)."""
+    return max(1, min(_KV_BLOCK // page, n_tab))
+
+
+def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
+                         q_ref, ks_ref, vs_ref, k_hbm, v_hbm,
+                         o_ref, k_out, v_out,
+                         kbuf, vbuf, wk, wv, sems, wsems, *,
+                         t, rep, page, bp, n_tab, scale):
+    """Grid: (B,), one slot a step.  li_ref: [1] the layer; tab_ref /
+    pos_ref / wpage_ref / wrow_ref: the block tables, positions and write
+    targets, flat.  q_ref / o_ref: [1, N_kv, R, D], row
+    ``t * rep + g`` the query of span column t and head ``h * rep + g``;
+    ks_ref / vs_ref: [1, N_kv, Tp, D] the span's rows; k_hbm / v_hbm: the
+    pools in HBM, read through these and written through their aliases
+    k_out / v_out.  kbuf / vbuf:
+    [2, N_kv, bp * page, D]; wk / wv: [2, N_kv, page, D], the one or two
+    pages the span's rows fall into."""
+    b = pl.program_id(0)
+    li, pos = li_ref[0], pos_ref[b]
+    nkv, r_pad, d = q_ref.shape[1:]
+    s_blk = bp * page
+    n_blocks = (pos + s_blk - 1) // s_blk
+
+    def block_dmas(blk, slot):
+        for j in range(bp):
+            pid = tab_ref[b * n_tab + jnp.minimum(blk * bp + j, n_tab - 1)]
+            rows = pl.ds(j * page, page)
+            yield pltpu.make_async_copy(
+                k_hbm.at[li, pid], kbuf.at[slot, :, rows, :],
+                sems.at[0, slot])
+            yield pltpu.make_async_copy(
+                v_hbm.at[li, pid], vbuf.at[slot, :, rows, :],
+                sems.at[1, slot])
+
+    # the page the span starts in and, where the span crosses a page
+    # edge, the next: rows the span does not write are kept
+    page_a = wpage_ref[b * t]
+    page_b = wpage_ref[b * t + t - 1]
+    two = page_b != page_a
+
+    def page_dmas(page_id, i, *, store):
+        for j, (hbm, buf) in enumerate(((k_out, wk), (v_out, wv))):
+            ends = (buf.at[i], hbm.at[li, page_id])
+            yield pltpu.make_async_copy(*(ends if store else ends[::-1]),
+                                        wsems.at[i, j])
+
+    for dma in page_dmas(page_a, 0, store=False):
+        dma.start()
+
+    @pl.when(two)
+    def _():
+        for dma in page_dmas(page_b, 1, store=False):
+            dma.start()
+
+    @pl.when(n_blocks > 0)
+    def _():
+        for dma in block_dmas(0, 0):
+            dma.start()
+
+    q = q_ref[0]                                            # [N_kv, R, D]
+    # products of bf16 operands are exact in f32 as they are; Mosaic
+    # refuses them an ambient ``default_matmul_precision("highest")``
+    f32 = dict(preferred_element_type=jnp.float32,
+               precision=None if q.dtype == jnp.float32
+               else jax.lax.Precision.DEFAULT)
+
+    def attend(carry, s, v_rows):
+        """One online-softmax step: masked scores s [h, r, c] over the
+        values v_rows [h, c, D]."""
+        m_prev, l_prev, acc = carry
+        m = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m)
+        alpha = jnp.exp(m_prev - m)
+        return (m, l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                acc * alpha + jnp.einsum(
+                    "hrc,hcd->hrd", p.astype(v_rows.dtype), v_rows, **f32))
+
+    def body(blk, carry):
+        slot = jax.lax.rem(blk, 2)
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            for dma in block_dmas(blk + 1, 1 - slot):
+                dma.start()
+
+        for dma in block_dmas(blk, slot):
+            dma.wait()
+        s = jnp.einsum("hrd,hcd->hrc", q, kbuf[slot], **f32) * scale
+        col = blk * s_blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        return attend(carry, jnp.where(col < pos, s, NEG_INF), vbuf[slot])
+
+    carry = jax.lax.fori_loop(
+        0, n_blocks, body,
+        (jnp.full((nkv, r_pad, 1), NEG_INF, jnp.float32),
+         jnp.zeros((nkv, r_pad, 1), jnp.float32),
+         jnp.zeros((nkv, r_pad, d), jnp.float32)))
+
+    # the span's own rows, causal among themselves: query row r (column
+    # r // rep of the span) sees span row c iff c * rep <= r
+    ks, vs = ks_ref[0], vs_ref[0]                           # [N_kv, Tp, D]
+    s = jnp.einsum("hrd,hcd->hrc", q, ks, **f32) * scale
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    _, l, acc = attend(
+        carry, jnp.where((col * rep <= row) & (col < t), s, NEG_INF), vs)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+    # the span's rows into their pages
+    for dma in page_dmas(page_a, 0, store=False):
+        dma.wait()
+
+    @pl.when(two)
+    def _():
+        for dma in page_dmas(page_b, 1, store=False):
+            dma.wait()
+
+    at = jax.lax.broadcasted_iota(jnp.int32, wk.shape[1:], 1)
+    for c in range(t):
+        which = (wpage_ref[b * t + c] != page_a).astype(jnp.int32)
+        here = at == wrow_ref[b * t + c]
+        for buf, new in ((wk, ks), (wv, vs)):
+            buf[which] = jnp.where(
+                here, new[:, c:c + 1, :].astype(jnp.float32),
+                buf[which].astype(jnp.float32)).astype(buf.dtype)
+    for dma in page_dmas(page_a, 0, store=True):
+        dma.start()
+
+    @pl.when(two)
+    def _():
+        for dma in page_dmas(page_b, 1, store=True):
+            dma.start()
+        for dma in page_dmas(page_b, 1, store=True):
+            dma.wait()
+
+    for dma in page_dmas(page_a, 0, store=True):
+        dma.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("block_pages", "interpret"))
+def paged_decode_attention(q, k, v, pools, li, block_tables, pos, write, *,
+                           block_pages: int | None = None,
+                           interpret: bool = False):
+    """Causal attention of a short span a slot over the slot's own pages,
+    read in place, and the span's rows written into them.  Jitted with
+    the layer ``li`` as an operand: the layers of a program share ONE
+    traced and lowered kernel (lowered once a layer, the kernels were a
+    second of every decode program's set-up, cache or no cache).
+
+    q: [B, T, N, D]; k / v: [B, T, N_kv, D] the span's rows (T < page);
+    pools: ``(k_pages, v_pages)``, each [L, P, N_kv, page, D]; block_tables:
+    [B, n]; pos: [B] the position of each slot's first span row, the pool
+    holding positions before it; write: ``(page_ids, rows)``, each [B, T],
+    where the span's rows go (consecutive rows: at most two pages a slot).
+    Returns (the heads' outputs [B, T, N * D], the pools).  What
+    :func:`store_kv`, :func:`gather_ctx` and the softmax of
+    :func:`kv_attend` give, with f32 scores, statistics and accumulator,
+    the probabilities rounded to the pool's dtype before the PV product."""
+    b, t, nh, d = q.shape
+    nkv, page = k.shape[2], pools[0].shape[3]
+    rep = nh // nkv
+    n_tab = block_tables.shape[1]
+    bp = block_pages or paged_decode_block_pages(page, n_tab)
+    tile = 32 // q.dtype.itemsize
+    r_pad = -(-t * rep // tile) * tile
+    t_pad = -(-t // tile) * tile
+    # [B, T, N_kv, rep, D] -> [B, N_kv, T * rep, D], whole tiles of rows
+    qh = q.reshape(b, t, nkv, rep, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, nkv, t * rep, d)
+    qh = jnp.pad(qh, ((0, 0), (0, 0), (0, r_pad - t * rep), (0, 0)))
+    span = [jnp.pad(x.transpose(0, 2, 1, 3),
+                    ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
+            for x in (k, v)]
+    slot_block = lambda rows: pl.BlockSpec(
+        (1, nkv, rows, d), lambda i, *_: (i, 0, 0, 0),
+        memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    dt = pools[0].dtype
+    out, k_pages, v_pages = pl.pallas_call(
+        functools.partial(
+            _paged_decode_kernel, t=t, rep=rep, page=page, bp=bp,
+            n_tab=n_tab, scale=d ** -0.5),
+        name="fm_paged_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(b,),
+            in_specs=[slot_block(r_pad), slot_block(t_pad),
+                      slot_block(t_pad), hbm, hbm],
+            out_specs=[slot_block(r_pad), hbm, hbm],
+            scratch_shapes=[
+                pltpu.VMEM((2, nkv, bp * page, d), dt),
+                pltpu.VMEM((2, nkv, bp * page, d), dt),
+                pltpu.VMEM((2, nkv, page, d), dt),
+                pltpu.VMEM((2, nkv, page, d), dt),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct(qh.shape, q.dtype),
+                   jax.ShapeDtypeStruct(pools[0].shape, dt),
+                   jax.ShapeDtypeStruct(pools[1].shape, dt)],
+        # operands: 5 scalar vectors, q, k, v, then the pools
+        input_output_aliases={8: 1, 9: 2},
+        interpret=interpret,
+    )(jnp.asarray(li, jnp.int32).reshape(1), block_tables.reshape(-1), pos,
+      write[0].reshape(-1), write[1].reshape(-1), qh, span[0].astype(dt),
+      span[1].astype(dt), *pools)
+    out = out[:, :, :t * rep].reshape(b, nkv, t, rep, d).transpose(
+        0, 2, 1, 3, 4).reshape(b, t, nh * d)
+    return out, (k_pages, v_pages)
 
 
 # ----------------------------------------------------------------------

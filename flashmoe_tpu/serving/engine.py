@@ -57,6 +57,7 @@ from flashmoe_tpu.config import MoEConfig
 from flashmoe_tpu.models.generate import (
     lm_logits, lm_logits_span, span_forward,
 )
+from flashmoe_tpu.ops import attention
 from flashmoe_tpu.serving.kvcache import (
     SCRATCH_PAGE, PagedKVCache, PagePool, ShardedPagePool,
     ctx_pages_bucket, init_paged_cache, page_size_of, prompt_pad,
@@ -367,17 +368,25 @@ def _paged_verify_step(params, cfg: MoEConfig, pools, toks,
 
 
 # The same three programs with the cache DONATED: the pool is updated in
-# place and the caller's arrays die with the call.  An MLA model is served
-# through these: beside 11 GB of weights the chip has no room for the
-# input pool, the output pool and the pool of a prefill chunk dispatched
-# while the decode step still runs.  (The K/V programs above keep their
-# second copy: ROADMAP S4 races donation on the backlog cell, then one
-# set of programs stays.  ``ServingEngine._paged`` is the one place that
-# picks.)
+# place and the caller's arrays die with the call.  These are the programs
+# the engine runs, for either cache kind: beside the weights the chip has
+# no room for the input pool, the output pool and the pool of a prefill
+# chunk dispatched while the decode step still runs (an MLA model's 11 GB
+# leave none at all; a K/V model's second pool was 1.61 GB of the backlog
+# cell's 13.07 and the reason for two whole-pool copies a step).  The
+# undonated programs above are what keeps its inputs: tests and
+# ``lower()`` hold them against each other over one pool.
 _INPLACE = {
     fn.__name__: jax.jit(fn.__wrapped__, static_argnames=("cfg",),
                          donate_argnames=("pools",))
     for fn in (_prefill_chunk, _paged_decode_step, _paged_verify_step)}
+
+#: ``store_prefill`` with the pool donated, as ONE program: an admission
+#: writes a prompt's pages into the pool where it lies (called eagerly, the
+#: scatter copied the whole pool first: 2 x 2.4 ms an admission on the
+#: backlog cell, a twentieth of the device's time once the decode step
+#: stopped copying it)
+_store_prefill = jax.jit(store_prefill, donate_argnums=(0,))
 
 
 # ----------------------------------------------------------------------
@@ -622,8 +631,8 @@ class ServingEngine:
         self._phase_open = None     # (span, name, opened at)
         self._phase_ms: dict = {}   # this step's phases, by name
         self._delivered_now: dict = {}   # rid -> tokens this step
-        self._ctx_pages = (0, 0.0, 0)   # this step's decode program:
-                                        # pages gathered, idle, slots
+        # this step's decode program: pages read, idle, slots, arm
+        self._ctx_pages = (0, 0.0, 0, None)
         # this step's sampler rows: not idle, drawn, truncating
         self._sampled = np.zeros((3,), np.int64)
         watch_compiles()
@@ -1041,7 +1050,7 @@ class ServingEngine:
                     page_ids = jnp.asarray(
                         self._global_pages(slot, pages), jnp.int32)
                     self.cache = type(self.cache)(*(
-                        store_prefill(pool, seq, page_ids)
+                        _store_prefill(pool, seq, page_ids)
                         for pool, seq in zip(self.cache, seqs)))
                 self._logits = self._logits.at[slot].set(logits)
                 self.slots[slot] = _Slot(
@@ -1108,7 +1117,7 @@ class ServingEngine:
                 # the span lands on THIS slot's request track
                 self.tracer.on_prefill_chunk(s.orig.rid)
             with trace_span("serve.prefill_chunk"):
-                logits, self.cache = self._paged("_prefill_chunk")(
+                logits, self.cache = _INPLACE["_prefill_chunk"](
                     self.params, self.cfg, self.cache,
                     jnp.asarray(toks)[None, :],
                     jnp.asarray(table),
@@ -1168,15 +1177,6 @@ class ServingEngine:
             slot=victim, freed_pages=len(s.pages),
             emitted=delivered)
         return True
-
-    def _paged(self, name: str):
-        """The paged program ``name`` of this module (looked up at call
-        time), or for an MLA model its in-place twin: the ONE place
-        that decides donation, by attention kind until ROADMAP S4 has
-        raced it on the K/V cell."""
-        if self.cfg.attention_kind == "mla":
-            return _INPLACE[name]
-        return globals()[name]
 
     def _delivered(self, s: _Slot) -> int:
         """Tokens delivered across incarnations (an evicted request's
@@ -1276,7 +1276,6 @@ class ServingEngine:
                          SCRATCH_PAGE, np.int32)
         rows = [None] * (sv.max_batch * k)
         longest = 1
-        own_pages = 0
         for i in active:
             s = self.slots[i]
             feed[i, 0] = s.emitted[-1]
@@ -1285,7 +1284,6 @@ class ServingEngine:
             positions[i] = s.length
             tables[i, :len(s.pages)] = s.pages
             longest = max(longest, s.length + t_span)
-            own_pages += (s.length + t_span - 1) // sv.page_size + 1
             base = self._delivered(s)   # emitted already holds tok_0
             rows[i * k:(i + 1) * k] = [(s.req, base + t)
                                        for t in range(k)]
@@ -1293,7 +1291,6 @@ class ServingEngine:
                                  sv.ctx_bucket_pages,
                                  sv.max_pages_per_slot)
         self.stats["decode_buckets"].add(n_ctx)
-        self._note_ctx(n_ctx, own_pages, len(active))
         self._phase("serve.verify")
         if self._ep_fn is not None:
             if self._ep_verify is None:
@@ -1304,10 +1301,11 @@ class ServingEngine:
                 jnp.asarray(tables[:, :n_ctx]),
                 jnp.asarray(positions))
         else:
-            span_logits, self.cache = self._paged("_paged_verify_step")(
+            span_logits, self.cache = _INPLACE["_paged_verify_step"](
                 self.params, self.cfg, self.cache, jnp.asarray(feed),
                 jnp.asarray(tables[:, :n_ctx]),
                 jnp.asarray(positions))
+        self._note_ctx(n_ctx, positions[active], t_span)
         self._spec_steps += 1
 
         self._phase("serve.sample")
@@ -1493,13 +1491,33 @@ class ServingEngine:
             self._heartbeat(beat)
         return now
 
-    def _note_ctx(self, n_ctx: int, own_pages: int, slots: int) -> None:
-        """What this step's decode or verify program gathers: ``n_ctx``
-        pages a slot, of which the ``slots`` decoding slots' own
-        contexts fill ``own_pages`` in all (a span drafted up to the
-        context ceiling counts pages past it: never under 0 idle)."""
-        self._ctx_pages = (n_ctx, max(0.0, n_ctx - own_pages / slots),
-                           slots)
+    def _note_ctx(self, n_ctx: int, lengths, t_span: int) -> None:
+        """What this step's decode or verify program reads of the cache:
+        the arm its attention takes (``ops/attention.kv_attention_arm``:
+        the rule the traced program asked), the pages a slot it reads
+        (mean over the decoding slots, whose contexts are ``lengths``
+        before this span of ``t_span`` rows) and how many of them lie
+        past what the slots' own contexts fill.  The gather arm reads
+        the bucket ``n_ctx`` for every slot; the kernel reads each
+        slot's context in whole blocks and the page or two it writes the
+        span into (a span drafted up to the context ceiling counts pages
+        past it: never under 0 idle)."""
+        page = self.serve.page_size
+        lengths = np.asarray(lengths)
+        span_pages = (lengths + t_span - 1) // page - lengths // page + 1
+        own = lengths // page + span_pages
+        arm = "gather"
+        if self.cfg.attention_kind != "mla":
+            arm = attention.kv_attention_arm(
+                t_span, page, self.cfg.resolved_num_kv_heads,
+                self.cfg.resolved_head_dim, self.cfg.dtype)
+        read = n_ctx
+        if arm == "paged_kernel":
+            block = attention.paged_decode_block_pages(page, n_ctx)
+            read = round(float(np.mean(
+                -(-lengths // (block * page)) * block + span_pages)), 3)
+        self._ctx_pages = (read, max(0.0, read - float(own.mean())),
+                           len(lengths), arm)
 
     def _sample(self, logits, knobs, n_rows: int) -> np.ndarray:
         """The tokens :func:`_sample_dynamic` gives ``logits`` on
@@ -1530,7 +1548,7 @@ class ServingEngine:
         compiles0, compile_s0 = compile_totals()
         self._phase_ms = {}
         self._delivered_now = {}
-        self._ctx_pages = (0, 0.0, 0)
+        self._ctx_pages = (0, 0.0, 0, None)
         self._sampled = np.zeros((3,), np.int64)
         t0_s = self._phase("serve.admit")
         if self.tracer is not None:
@@ -1603,19 +1621,16 @@ class ServingEngine:
             tables = np.full((sv.max_batch, sv.max_pages_per_slot),
                              SCRATCH_PAGE, np.int32)
             longest = 1
-            own_pages = 0
             for i in active:
                 s = self.slots[i]
                 feed[i] = s.emitted[-1]
                 positions[i] = s.length
                 tables[i, :len(s.pages)] = s.pages
                 longest = max(longest, s.length + 1)
-                own_pages += s.length // sv.page_size + 1
             n_ctx = ctx_pages_bucket(longest, sv.page_size,
                                      sv.ctx_bucket_pages,
                                      sv.max_pages_per_slot)
             self.stats["decode_buckets"].add(n_ctx)
-            self._note_ctx(n_ctx, own_pages, len(active))
             self._phase("serve.decode")
             if self._ep_fn is not None:
                 logits, self.cache = self._ep_fn(
@@ -1623,10 +1638,12 @@ class ServingEngine:
                     jnp.asarray(tables[:, :n_ctx]),
                     jnp.asarray(positions))
             else:
-                logits, self.cache = self._paged("_paged_decode_step")(
+                logits, self.cache = _INPLACE["_paged_decode_step"](
                     self.params, self.cfg, self.cache, jnp.asarray(feed),
                     jnp.asarray(tables[:, :n_ctx]),
                     jnp.asarray(positions))
+            # the step's account of it, while the device runs it
+            self._note_ctx(n_ctx, positions[active], 1)
             self._logits = logits
             for i in active:
                 self.slots[i].length += 1
@@ -1672,12 +1689,14 @@ class ServingEngine:
         self.metrics.sketch("serve.phase.account_ms",
                             self._phase_ms["serve.account"])
         compiles1, compile_s1 = compile_totals()
-        ctx_pages, ctx_idle, n_decoding = self._ctx_pages
+        ctx_pages, ctx_idle, n_decoding, attn_arm = self._ctx_pages
         sample_rows, sample_drawn, sample_sorted = map(int, self._sampled)
         if sample_rows:
             self.metrics.count("serve.sample_steps")
             self.metrics.count("serve.sample_sort_steps",
                                float(sample_sorted > 0))
+        if attn_arm == "paged_kernel":
+            self.metrics.count("serve.decode_kernel_steps")
         rec = {
             "kind": "serve_step", "step": self.step_idx,
             "active": n_active, "queue_depth": qd,
@@ -1712,7 +1731,8 @@ class ServingEngine:
                 self.recorder.record(
                     kind="serve_decode", step=self.step_idx,
                     slots=n_decoding, ctx_pages=ctx_pages,
-                    ctx_pages_idle=rec["ctx_pages_idle"])
+                    ctx_pages_idle=rec["ctx_pages_idle"],
+                    attn_arm=attn_arm)
         if self.watchdog is not None:
             self.watchdog.observe_step(self.step_idx, step_ms)
         self.step_idx += 1

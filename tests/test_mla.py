@@ -491,20 +491,30 @@ def test_pool_shape_and_bytes_a_token():
     assert pair.k_pages.shape == (6, 2048, 16, 16, 128)
 
 
-def test_engine_serves_mla_in_place_and_mha_as_before(params):
-    """An MLA engine runs the donated twins (the pool it handed in is
-    gone after a step); the K/V programs keep their inputs."""
-    engine = ServingEngine(params, CFG, ServeConfig(**SERVE))
-    assert engine._paged("_paged_decode_step") is \
-        eng._INPLACE["_paged_decode_step"]
-    ds = PRESETS["deepseek-moe-16b"](
-        num_layers=2, hidden_size=64, intermediate_size=64, num_experts=8,
-        expert_top_k=2, vocab_size=256, num_heads=4, dtype=jnp.float32)
-    plain = ServingEngine(init_params(jax.random.PRNGKey(0), ds), ds,
-                          ServeConfig(**SERVE))
-    assert plain._paged("_paged_decode_step") is eng._paged_decode_step
+@pytest.mark.parametrize("kind", ["mla", "mha"])
+def test_engine_serves_either_cache_in_place(params, kind):
+    """The engine runs the donated programs for either cache kind since
+    ISSUE 30 (``engine._INPLACE``: the pool it handed in is gone after a
+    step); the undonated ones keep their inputs for tests and
+    ``lower()``."""
     assert set(eng._INPLACE) == {"_prefill_chunk", "_paged_decode_step",
                                  "_paged_verify_step"}
+    cfg, weights = CFG, params
+    if kind == "mha":
+        cfg = PRESETS["deepseek-moe-16b"](
+            num_layers=2, hidden_size=64, intermediate_size=64,
+            num_experts=8, expert_top_k=2, vocab_size=256, num_heads=4,
+            dtype=jnp.float32)
+        weights = init_params(jax.random.PRNGKey(0), cfg)
+    engine = ServingEngine(weights, cfg, ServeConfig(**SERVE))
+    engine.submit(Request(rid=0, prompt=tuple(int(t) for t in TOKENS[:9]),
+                          max_new_tokens=3))
+    engine.step()                   # admits and prefills, then decodes
+    handed_in = jax.tree.leaves(engine.cache)
+    engine.step()
+    assert all(pool.is_deleted() for pool in handed_in)
+    assert not any(pool.is_deleted()
+                   for pool in jax.tree.leaves(engine.cache))
 
 
 # -------------------------------------------------------------- refusals

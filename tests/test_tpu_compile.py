@@ -426,10 +426,13 @@ def _program_bytes(compiled):
 
 @pytest.fixture(scope="module")
 def backlog_programs(one_chip):
-    """The backlog cell's widest decode program and its largest
-    whole-prompt prefill (``dsmoe16b``: 6 layers in bf16, 32 slots, a
-    2048 x 16-token K/V pool, tables at their 160 pages, a 2048-token
-    pad), lowered as the engine runs them: nothing donated."""
+    """The backlog cell's widest decode and verify programs, its
+    1024-token chunk and its largest whole-prompt prefill (``dsmoe16b``:
+    6 layers in bf16, 32 slots, a 2048 x 16-token K/V pool, tables at
+    their 160 pages, a span of 5, a 2048-token pad), lowered as the engine
+    runs them on the chip: the pool donated, and traced as on a TPU (the
+    attention picks its arm from the backend, and nothing is attached
+    here)."""
     from flashmoe_tpu.models.presets import PRESETS
     from flashmoe_tpu.models.transformer import init_params
     from flashmoe_tpu.serving import engine as eng
@@ -444,28 +447,58 @@ def backlog_programs(one_chip):
         lambda: init_params(jax.random.PRNGKey(0), cfg)))
     cache = on(jax.eval_shape(lambda: init_paged_cache(cfg, 2048, 16)))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32, sharding=one_chip)
-    return {
-        "decode": eng._paged_decode_step.lower(
-            params, cfg, cache, i32(32), i32(32, 160), i32(32)),
-        "prefill": eng._prefill_padded.lower(
-            params, cfg, i32(1, 2048), i32())}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return {
+            "decode": eng._INPLACE["_paged_decode_step"].lower(
+                params, cfg, cache, i32(32), i32(32, 160), i32(32)),
+            "verify": eng._INPLACE["_paged_verify_step"].lower(
+                params, cfg, cache, i32(32, 5), i32(32, 160), i32(32)),
+            "chunk": eng._INPLACE["_prefill_chunk"].lower(
+                params, cfg, cache, i32(1, 1024), i32(160), i32(64), i32(),
+                i32()),
+            "prefill": eng._prefill_padded.lower(
+                params, cfg, i32(1, 2048), i32())}
 
 
+def _arrays_of(text, *dims):
+    """Shapes of the bf16 / f32 arrays of a compiled program that have
+    exactly ``dims``, in any order."""
+    want = sorted(dims)
+    return [s for s in set(re.findall(r"(?:bf16|f32)\[([0-9,]+)\]", text))
+            if sorted(int(n) for n in s.split(",")) == want]
+
+
+@pytest.mark.parametrize("program", ["decode", "verify", "chunk"])
 def test_backlog_decode_step_is_the_program_the_ledger_measured(
-        backlog_programs):
-    """What the K/V decode step compiles to since before ISSUE 29 merged
-    the seven layer bodies, pinned so that ROADMAP S2 / S4 move it on
-    purpose: 13.07 GB (7.90 of weights, the 1.61 GB pool TWICE, the
-    gathered contexts) and FOUR copies of a whole pool in gather
-    order."""
-    compiled = backlog_programs["decode"].compile()
-    assert abs(_program_bytes(compiled) / 13.0654e9 - 1) < 0.01
-    assert compiled.memory_analysis().alias_size_in_bytes == 0
+        backlog_programs, program):
+    """What the K/V programs that take a pool compile to since ISSUE 30
+    (it was 13.07 GB with the pool TWICE, the gathered contexts and FOUR
+    copies of a whole pool in gather order): the pool once, aliased to
+    the output, and NO copy of it.  The decode step (T = 1) and the
+    verify step (T = 5) read each slot's pages in place: Mosaic compiles
+    ``fm_paged_decode`` at the cell's shapes, a K and a V pool through
+    every layer's call, and no array has the gathered context's element
+    count; the 1024-token chunk keeps ``gather_ctx`` + ``kv_attend``
+    over its one slot."""
+    compiled = backlog_programs[program].compile()
     text = compiled.as_text()
-    pool_copies = re.findall(
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * 6 * 2048 * 16 * 16 * 128 * 2                  # 1.61 GB
+    assert 9.3e9 < _program_bytes(compiled) < 11e9   # 9.54 / 9.59 / 10.38
+    assert not re.findall(
         r"^.*= bf16\[6,2048,16,16,128\]\S* copy\(.*$", text, re.M)
-    assert len(pool_copies) == 4, pool_copies
     assert "moe.gate" in text and "moe.expert" in text
+    kernels = [n for n, _ in _custom_call_names(text)]
+    if program == "chunk":
+        assert kernels == []
+        assert _arrays_of(text, 16, 2560, 128)      # its gathered context
+        return
+    assert len(kernels) == 6, kernels
+    assert all(n.split(".")[0] == "fm_paged_decode" for n in kernels)
+    assert _arrays_of(text, 5120, 16, 16, 128) == []
+    assert _arrays_of(text, 32, 16, 2560, 128) == []
+    assert " scatter(" not in text                  # the kernel stores
 
 
 def test_backlog_whole_prompt_prefill_fits_beside_the_pool(
