@@ -8,7 +8,9 @@ own means.  It never runs on the CPU: the first thing it does with JAX is
 to assert the platform, and every kernel claim is checked in the compiled
 program (``tpu_custom_call``), so interpret mode cannot pass for the chip.
 
-    python chip_smoke.py              # one chip: layer, serve, train
+    python chip_smoke.py              # one chip: layer, serve,
+                                      # serve_hybrid, train
+    python chip_smoke.py --only serve_hybrid    # that phase alone
     python chip_smoke.py --chips 4    # one host, four chips: ep4_layer,
                                       # ep4_fused, ep4_serve (builder-run)
 
@@ -241,7 +243,7 @@ def run_engine(params, cfg, serve, reqs, arrivals, mesh=None):
     return outputs, seen, summary
 
 
-def check_streams(cfg, params, reqs, outputs, seen, tol):
+def check_streams(cfg, params, reqs, outputs, seen, tol, own_row=False):
     """Engine streams against ``generate()`` and its logits.
 
     Tokens: ``generate()`` one request at a time, greedy.  Logits: the
@@ -250,10 +252,14 @@ def check_streams(cfg, params, reqs, outputs, seen, tol):
     engine's stream gives the reference logits at every position.  Every
     engine token must be the reference's argmax or within ``tol`` of it
     (a near-tie: printed, no failure), and so must ``generate()``'s own
-    token where the two streams first part.  Returns the failures, the
-    near-ties, the token mismatches, and how far the engine's own
-    logits, where visible, are from the reference's at each position
-    (all as fractions of the largest reference logit)."""
+    token where the two streams first part.  With ``own_row`` a token
+    further off is no failure where the engine's own row is visible and
+    the token is that row's best: the sampler was right, the ROW differs
+    (by at least half the token's gap), and the rows have their own rule.
+    Returns the failures, the near-ties (such tokens among them, marked),
+    the token mismatches, and how far the engine's own logits, where
+    visible, are from the reference's at each position (all as fractions
+    of the largest reference logit)."""
     import jax
     import jax.numpy as jnp
 
@@ -289,7 +295,12 @@ def check_streams(cfg, params, reqs, outputs, seen, tol):
         for j in range(r.max_new_tokens):
             row = ref[t0 + j - 1]
             gap = float(row.max() - row[got[t0 + j]]) / scale
-            if gap > tol:
+            own = seen[r.rid].get(j)
+            if gap > tol and own_row and own is not None \
+                    and got[t0 + j] == int(np.argmax(own)):
+                near_ties.append({"rid": r.rid, "token": j, "gap": gap,
+                                  "best_of_own_row": True})
+            elif gap > tol:
                 bad.append(f"rid {r.rid} token {j}: {got[t0 + j]} is "
                            f"{gap:.4f} of scale below the reference argmax")
             elif gap > 0:
@@ -318,18 +329,25 @@ def _serve_case(params, cfg, serve, seed, cut, extra):
     fault of paging, positions or batching shows there.  ``bfloat16`` is
     the preset as published: the engine batches four slots and pads
     prompts where ``generate()`` runs one exact-length request, so the
-    two arms round differently, and with seeded (flat) router weights a
-    top-6 choice now and then falls the other way in one of them — one
-    expert in six differs and that position's logits move by more than
-    bf16 rounding (first seen on the chip: 2 of 38 positions, 0.054 and
-    0.170 of scale).  There every token is still held to BF16_TOL of the
-    reference argmax, three positions in four to BF16_TOL in their
-    logits, and every position to half the logits' scale (a fault of
-    paging or position is an error of the whole scale)."""
+    two arms round differently.  A configuration with NO router is held
+    to BF16_TOL at every token and every visible row all the same: what
+    is left between the arms is rounding.  Behind a router with seeded
+    (flat) weights a top-k choice now and then falls the other way in one
+    arm — one expert in k differs and that position's row moves by more
+    than bf16 rounding (first seen on the chip: top-6 of 64, 2 of 38
+    rows, 0.054 and 0.170 of scale; top-8 of 512 in groups, 5 of 38 rows
+    up to 0.23 and one token 0.035 below the reference's best, while the
+    same mixers over dense layers agreed everywhere: ``serve_hybrid``).
+    There three rows in four are held to BF16_TOL and every row to half
+    the logits' scale (a fault of paging or position is an error of the
+    whole scale); every token is held to BF16_TOL of the reference
+    argmax or, where its own row is visible, to being that row's best
+    (``check_streams``: the row's distance is then the rows' to judge)."""
     import jax
     import jax.numpy as jnp
 
     strict = cfg.dtype == jnp.float32
+    routed = bool(cfg.moe_layer_indices)
     tol = F32_TOL if strict else BF16_TOL
     reqs, arrivals = serve_requests(cfg.vocab_size, seed)
     with (jax.default_matmul_precision("highest") if strict
@@ -340,11 +358,12 @@ def _serve_case(params, cfg, serve, seed, cut, extra):
         run_s = time.perf_counter() - t0
         t0 = time.perf_counter()
         bad, near_ties, token_mismatch, errs = check_streams(
-            cfg, params, reqs, outputs, seen, tol)
+            cfg, params, reqs, outputs, seen, tol,
+            own_row=routed and not strict)
         check_s = time.perf_counter() - t0
     errs = np.asarray(errs)
     over = int((errs > tol).sum())
-    if strict:
+    if strict or not routed:
         logits_ok = over == 0
     else:
         logits_ok = over <= len(errs) // 4 and float(errs.max()) <= 0.5
@@ -353,6 +372,7 @@ def _serve_case(params, cfg, serve, seed, cut, extra):
           and summary["max_active"] > 1)
     emit({"phase": "serve", "dtype": jnp.dtype(cfg.dtype).name, "ok": ok,
           "cut": cut,
+          "routed_layers": len(cfg.moe_layer_indices),
           "widths": {"H": cfg.hidden_size, "I": cfg.intermediate_size,
                      "E": cfg.num_experts, "shared": cfg.num_shared_experts,
                      "k": cfg.expert_top_k, "heads": cfg.num_heads,
@@ -407,6 +427,52 @@ def phase_serve(seed):
     gc.collect()
     ok &= _serve_case(params, cfg, serve, seed, cut,
                       {"decode_step_compile_s": round(compile_s, 3)})
+    return ok
+
+
+def phase_serve_hybrid(seed):
+    """The engine over two kinds of state: Ling-3.0-flash's widths, three
+    layers (a dense 'kda' layer, a mixture 'kda' layer, a mixture 'mla'
+    layer), one chip's quarter of the experts and of the vocabulary;
+    per-slot delta-rule state beside a latent pool of ONE layer, whole
+    and chunked prefill (the prompt of 33 crosses a 16-token chunk), four
+    slots.  Against ``generate()`` and its logits, as :func:`phase_serve`."""
+    import jax
+    import jax.numpy as jnp
+
+    from flashmoe_tpu.models.presets import PRESETS
+    from flashmoe_tpu.models.transformer import init_params
+    from flashmoe_tpu.serving import engine as eng
+
+    cfg = PRESETS["ling-3.0-flash"](
+        num_layers=3, first_k_dense=1, layer_mixers=("kda", "kda", "mla"),
+        experts_held=128, vocab_size=39296)
+    params = init_params(jax.random.PRNGKey(seed), cfg)
+    serve = eng.ServeConfig(max_batch=4, page_size=16, num_pages=256,
+                            max_pages_per_slot=4, ctx_bucket_pages=4,
+                            prompt_bucket=16, prefill_chunk=16)
+    cut = ("num_layers 42 -> 3 (layers 0, 6 and 11: dense kda, mixture "
+           "kda, mixture mla), experts 512 -> 128 held (routed over 512 in "
+           "8 groups), vocab 157184 -> 39296: f32 weights "
+           f"{sum(a.nbytes for a in jax.tree.leaves(params)) / 2**30:.2f} "
+           "GiB")
+    ok = _serve_case(params, cfg.replace(dtype=jnp.float32), serve, seed,
+                     cut + "; dtype bf16 -> f32, matmul precision highest",
+                     {"phase_of": "serve_hybrid"})
+    gc.collect()
+    # bf16, the same three mixers over DENSE layers (no router): the
+    # per-slot state, the convolution's carried inputs across the chunk,
+    # the latent pool and the batching, held to BF16_TOL at every token
+    # and every row.  What the routed case below reads beyond that is the
+    # router's (8 of 512 experts inside 4 of 8 groups, sigmoid scores a
+    # few 1e-3 apart: the arms' roundings flip a choice)
+    dense = cfg.replace(first_k_dense=cfg.num_layers)
+    ok &= _serve_case(init_params(jax.random.PRNGKey(seed), dense), dense,
+                      serve, seed, cut + "; every layer dense (no router)",
+                      {"phase_of": "serve_hybrid"})
+    gc.collect()
+    ok &= _serve_case(params, cfg, serve, seed, cut,
+                      {"phase_of": "serve_hybrid"})
     return ok
 
 
@@ -704,7 +770,8 @@ def phase_ep4_serve(seed, shared):
 
 # ----------------------------------------------------------------------
 
-ONE_CHIP = {"layer": phase_layer, "serve": phase_serve, "train": phase_train}
+ONE_CHIP = {"layer": phase_layer, "serve": phase_serve,
+            "serve_hybrid": phase_serve_hybrid, "train": phase_train}
 FOUR_CHIPS = {"ep4_layer": phase_ep4_layer, "ep4_fused": phase_ep4_fused,
               "ep4_serve": phase_ep4_serve}
 
@@ -713,6 +780,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--only", default="",
+                    help="comma-separated phases to run, of the chips' own")
     args = ap.parse_args(argv)
 
     import jax
@@ -741,6 +810,8 @@ def main(argv=None) -> int:
     jax.monitoring.register_event_listener(count)
 
     phases = ONE_CHIP if args.chips == 1 else FOUR_CHIPS
+    if args.only:
+        phases = {name: phases[name] for name in args.only.split(",")}
     shared = None
     failed = []
     t_all = time.perf_counter()

@@ -135,6 +135,30 @@ class MoEConfig:
     # moe_frequency says
     first_k_dense: int = 0
     dense_intermediate_size: int = 0
+    # group-limited routing (``n_group`` > 1): the experts lie in n_group
+    # equal groups, a group scores the sum of its two best selection
+    # scores, the topk_group best groups are kept and the top-k is taken
+    # inside them.  One group is the plain rule, bit for bit.
+    n_group: int = 1
+    topk_group: int = 1
+    # the token mixer PER LAYER: "mha", "mla" or "kda" (Kimi delta
+    # attention: the gated delta rule with a per-channel decay over a
+    # [kda_head_dim, kda_head_dim] state a head, behind a causal depthwise
+    # convolution of kda_conv taps; no positions, nothing cached a
+    # token).  ``layer_mixers`` names every layer; empty, every layer is
+    # ``attention_kind``.
+    layer_mixers: tuple = ()
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_lower_bound: float = -5.0  # log of the smallest decay a step
+    # the share of a mixture layer's experts THIS chip holds, of a
+    # deployment that divides every layer over several: experts
+    # expert_first .. expert_first + experts_held - 1 (0 held: all).  The
+    # router keeps its num_experts outputs; the layer computes the rows
+    # routed to its own experts and leaves the others' part out.
+    expert_first: int = 0
+    experts_held: int = 0
 
     # --- numerics ---
     dtype: Any = jnp.bfloat16
@@ -348,11 +372,14 @@ class MoEConfig:
                      self.qk_nope_head_dim, self.qk_rope_head_dim,
                      self.v_head_dim)
         if self.attention_kind == "mla":
-            if min(mla_sizes) < 1 or self.qk_rope_head_dim % 2:
+            # q_lora_rank 0: the published null, queries projected direct
+            if (min(mla_sizes[1:]) < 1 or self.q_lora_rank < 0
+                    or self.qk_rope_head_dim % 2):
                 raise ValueError(
-                    "attention_kind='mla' needs q_lora_rank, kv_lora_rank, "
+                    "attention_kind='mla' needs kv_lora_rank, "
                     "qk_nope_head_dim, qk_rope_head_dim (even) and "
-                    f"v_head_dim >= 1, got {mla_sizes}")
+                    "v_head_dim >= 1 and q_lora_rank >= 0, got "
+                    f"{mla_sizes}")
             if self.num_kv_heads not in (0, self.num_heads) or self.head_dim:
                 raise ValueError(
                     "attention_kind='mla' has no kv-head grouping and no "
@@ -369,6 +396,47 @@ class MoEConfig:
         else:
             raise ValueError(f"attention_kind {self.attention_kind!r} not "
                              f"in ('mha', 'mla')")
+        object.__setattr__(self, "layer_mixers", tuple(self.layer_mixers))
+        if self.layer_mixers and len(self.layer_mixers) != self.num_layers:
+            raise ValueError(
+                f"layer_mixers names {len(self.layer_mixers)} layers of "
+                f"{self.num_layers}")
+        if set(self.mixers) - {"mha", "mla", "kda"}:
+            raise ValueError(f"layer_mixers {self.mixers} not of "
+                             f"('mha', 'mla', 'kda')")
+        if set(self.mixers) - {"kda", self.attention_kind}:
+            raise ValueError(
+                f"layer_mixers {self.mixers}: the layers that cache rows "
+                f"are all attention_kind={self.attention_kind!r}")
+        if "kda" in self.mixers:
+            # the chunkwise form takes exp(16 x |bound|) in float32
+            if (self.kda_heads < 1 or self.kda_head_dim < 1
+                    or self.kda_conv < 2
+                    or not -5.5 <= self.kda_lower_bound < 0):
+                raise ValueError(
+                    "a 'kda' layer needs kda_heads, kda_head_dim >= 1, "
+                    "kda_conv >= 2 and kda_lower_bound in [-5.5, 0), got "
+                    f"{(self.kda_heads, self.kda_head_dim, self.kda_conv, self.kda_lower_bound)}")
+        if self.n_group < 1 or self.num_experts % self.n_group or not (
+                1 <= self.topk_group <= self.n_group):
+            raise ValueError(
+                f"n_group={self.n_group} must divide num_experts="
+                f"{self.num_experts} and topk_group={self.topk_group} "
+                f"lie in [1, n_group]")
+        if self.n_group > 1 and (
+                self.expert_top_k
+                > self.topk_group * (self.num_experts // self.n_group)):
+            raise ValueError("expert_top_k exceeds the experts of "
+                             "topk_group groups")
+        if not (0 <= self.experts_held and 0 <= self.expert_first
+                and self.expert_first + self.experts_held
+                <= self.num_experts):
+            raise ValueError(
+                f"experts {self.expert_first}..+{self.experts_held} are "
+                f"not among num_experts={self.num_experts}")
+        if self.experts_held and self.ep > 1:
+            raise ValueError("experts_held is one chip's share of a "
+                             "layer: it does not compose with ep > 1")
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"router_score {self.router_score!r} not in "
                              f"('softmax', 'sigmoid')")
@@ -590,12 +658,30 @@ class MoEConfig:
             return self
         return self.replace(
             num_experts=1, expert_top_k=1, num_shared_experts=0,
+            n_group=1, topk_group=1, expert_first=0, experts_held=0,
             intermediate_size=(self.dense_intermediate_size
                                or self.intermediate_size))
 
     @property
+    def mixers(self) -> tuple:
+        """The token mixer of every layer."""
+        return self.layer_mixers or (self.attention_kind,) * self.num_layers
+
+    @property
+    def cache_layers(self) -> tuple:
+        """The layers that cache rows a token (every layer but 'kda'):
+        layer ``cache_layers[i]`` owns index i of the paged pools."""
+        return tuple(li for li, m in enumerate(self.mixers) if m != "kda")
+
+    @property
+    def state_layers(self) -> tuple:
+        """The 'kda' layers: layer ``state_layers[i]`` owns index i of
+        the per-slot recurrent state."""
+        return tuple(li for li, m in enumerate(self.mixers) if m == "kda")
+
+    @property
     def kv_token_elems(self) -> int:
-        """Elements ONE layer's cache holds for one token: K and V of
+        """Elements ONE caching layer holds for one token: K and V of
         every kv head, or MLA's latent beside its shared rotary key."""
         if self.attention_kind == "mla":
             return self.kv_lora_rank + self.qk_rope_head_dim
@@ -603,9 +689,19 @@ class MoEConfig:
 
     @property
     def kv_token_bytes(self) -> int:
-        """Bytes one cached token costs over all layers."""
-        return (self.num_layers * self.kv_token_elems
+        """Bytes one cached token costs over all the layers that cache."""
+        return (len(self.cache_layers) * self.kv_token_elems
                 * jnp.dtype(self.dtype).itemsize)
+
+    @property
+    def state_slot_bytes(self) -> int:
+        """Bytes of recurrent state one slot holds over the 'kda' layers,
+        whatever its context: the float32 [heads, d, d] state and the
+        convolution's last kda_conv - 1 inputs of q, k and v."""
+        n, d = self.kda_heads, self.kda_head_dim
+        return len(self.state_layers) * (
+            n * d * d * 4 + (self.kda_conv - 1) * 3 * n * d
+            * jnp.dtype(self.dtype).itemsize)
 
     @property
     def param_count(self) -> int:

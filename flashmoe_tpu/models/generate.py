@@ -57,17 +57,19 @@ class LatentCache(NamedTuple):
 
 
 def init_cache(cfg: MoEConfig, batch: int, max_len: int):
-    if cfg.attention_kind == "mla":
-        return LatentCache(jnp.zeros(
-            (cfg.num_layers, batch, max_len * cfg.kv_token_elems),
-            cfg.dtype))
-    nkv, dh = cfg.resolved_num_kv_heads, cfg.resolved_head_dim
-    shape = (cfg.num_layers, batch, nkv, max_len, dh)
-    return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
+    """The dense cache: the paged cache of ``serving/kvcache`` with one
+    ``max_len``-row page a batch row, one slot a batch row."""
+    from flashmoe_tpu.serving.kvcache import cache_arrays
+
+    arrays = cache_arrays(cfg, batch, max_len, batch)
+    if cfg.state_layers:
+        return arrays
+    return (LatentCache if cfg.attention_kind == "mla" else KVCache)(*arrays)
 
 
 def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
-                 block_tables, *, absorbed: bool, mixture=None):
+                 block_tables, *, absorbed: bool, mixture=None, valid=None,
+                 slots=None, fresh=None):
     """The model's layers over a span of T tokens a slot: the ONE layer
     loop of every cached path of either attention kind (the serving
     engine's prefill, chunked prefill, decode and verify programs, their
@@ -78,10 +80,16 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
     module), or None (a whole prompt at once: nothing cached yet); pos:
     [B, T] absolute positions; write / block_tables / absorbed: see
     :func:`~flashmoe_tpu.ops.attention.kv_paged_attention` and
-    ``mla_paged_attention``.  ``mixture(moe_params, rows, cfg)`` runs the
+    ``mla_paged_attention``; valid / slots / fresh: which positions are
+    real, which slot's recurrent state a row owns and whether it starts
+    from nothing (:func:`~flashmoe_tpu.ops.kda.kda_attention`: read by
+    'kda' layers alone).  ``mixture(moe_params, rows, cfg)`` runs the
     experts of the mixture layers in place of :func:`moe_layer` (the EP
     programs' exchange).  Returns (x pre-final-norm [B, T, H], the cache,
-    the span's rows, one array ``[L, B, ...]`` for each pool)."""
+    the span's rows, one array ``[L, B, ...]`` for each array of the
+    cache over the layers that own it, and what the layers counted: for
+    a config that holds a share of the experts ``held_rows``, the routed
+    rows that fell on them, mean over the mixture layers)."""
     b, t, _ = x.shape
     # the experts over the S x K routed rows for an MLA config (the
     # capacity arm's E x S rows cost 32 x the routed work at 256 experts
@@ -90,11 +98,12 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
     local = functools.partial(moe_layer, use_pallas=False,
                               routed_rows=cfg.attention_kind == "mla")
     pools = None if cache is None else tuple(cache)
-    rows = []
+    rows, held = [], []
     for li, layer in enumerate(params["layers"]):
         a, pools, span = paged_attention(
             layer, rms_norm(x, layer["attn_norm"]), cfg, pools, li, pos,
-            write, block_tables, absorbed=absorbed)
+            write, block_tables, absorbed=absorbed, valid=valid,
+            slots=slots, fresh=fresh)
         rows.append(span)
         x = x + a
         f_in = rms_norm(x, layer["ffn_norm"]).reshape(b * t, -1)
@@ -103,9 +112,16 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
                               and li in cfg.moe_layer_indices) else local
         o = experts(layer["moe"], f_in, layer_cfg)
         x = x + o.out.reshape(b, t, -1).astype(x.dtype)
+        if layer_cfg.experts_held:
+            held.append(jnp.sum(o.expert_counts[
+                cfg.expert_first:cfg.expert_first + cfg.experts_held]))
     if cache is not None:
         cache = type(cache)(*pools)
-    return x, cache, tuple(jnp.stack(r) for r in zip(*rows))
+    counted = ({"held_rows": jnp.mean(jnp.stack(held).astype(jnp.float32))}
+               if held else {})
+    return x, cache, tuple(
+        jnp.stack([r for r in of_pool if r is not None])
+        for of_pool in zip(*rows)), counted
 
 
 def _dense_span(params, cfg: MoEConfig, x, cache, pos, absorbed: bool):
@@ -116,7 +132,7 @@ def _dense_span(params, cfg: MoEConfig, x, cache, pos, absorbed: bool):
         pos + jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
     rows_b = jnp.broadcast_to(jnp.arange(b, dtype=jnp.int32)[:, None],
                               (b, t))
-    x, cache, _ = span_forward(
+    x, cache, _, _ = span_forward(
         params, cfg, x, cache, positions, (rows_b, positions),
         rows_b[:, :1], absorbed=absorbed)
     return x, cache

@@ -89,10 +89,44 @@ def joyai_llm_flash(**overrides) -> MoEConfig:
     return MoEConfig(**base)
 
 
+def ling3_flash(**overrides) -> MoEConfig:
+    """The language model of Ling-3.0-flash-VL (~125B-A5.5B;
+    huggingface.co/inclusionAI/Ling-3.0-flash-VL ``config.json``): 42
+    layers in groups of 6 (the source's ``layer_group_size``), five 'kda' layers
+    (delta-rule linear attention: 32 heads of a 128 x 128 state, a
+    4-tap convolution, decay bounded below at exp(-5)) to one of latent
+    attention with NO query rank (``q_lora_rank`` null), RoPE theta 6e6;
+    2 leading dense layers of width 6144, then 512 routed experts top-8
+    + 1 shared of width 768 behind a sigmoid router with a selection
+    bias, limited to the 4 best of 8 groups, normalised weights times
+    2.5.  Its vision tower and its multi-token-prediction module are not
+    part of the model this preset builds."""
+    base = dict(
+        num_experts=512, expert_top_k=8, num_shared_experts=1,
+        hidden_size=2560, intermediate_size=768, num_layers=42,
+        moe_frequency=1, first_k_dense=2, dense_intermediate_size=6144,
+        vocab_size=157184, num_heads=32, attention_kind="mla",
+        q_lora_rank=0, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, rope_theta=6e6,
+        kda_heads=32, kda_head_dim=128, kda_conv=4,
+        kda_lower_bound=-5.0, router_score="sigmoid", router_bias=True,
+        norm_topk_prob=True, routed_scaling_factor=2.5, n_group=8,
+        topk_group=4, sequence_len=4096, gated_ffn=True,
+        hidden_act=Activation.SILU, drop_tokens=False, dtype=jnp.bfloat16,
+    )
+    base.update(overrides)
+    # the last layer of every group of 6 is the latent-attention one
+    base.setdefault("layer_mixers", tuple(
+        "mla" if (li + 1) % 6 == 0 else "kda"
+        for li in range(base["num_layers"])))
+    return MoEConfig(**base)
+
+
 PRESETS = {
     "mixtral-8x7b": mixtral_8x7b,
     "deepseek-moe-16b": deepseek_moe_16b,
     "switch-base": switch_base,
     "flashmoe-reference": flashmoe_reference,
     "joyai-llm-flash": joyai_llm_flash,
+    "ling-3.0-flash": ling3_flash,
 }

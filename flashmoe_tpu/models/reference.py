@@ -35,10 +35,12 @@ def init_moe_params(key, cfg: MoEConfig) -> dict:
     ``gate_w [H, E]``, per-expert up/down projections (+ optional gate proj for
     SwiGLU), all stored stacked on a leading expert axis.
     """
-    h, i, e = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
+    h, i = cfg.hidden_size, cfg.intermediate_size
+    # the router over every expert, the weights of those held here
+    e_all, e = cfg.num_experts, cfg.experts_held or cfg.num_experts
     ks = jax.random.split(key, 7)
     p = {
-        "gate_w": jax.random.normal(ks[0], (h, e), cfg.param_dtype) / jnp.sqrt(h),
+        "gate_w": jax.random.normal(ks[0], (h, e_all), cfg.param_dtype) / jnp.sqrt(h),
         "w_up": jax.random.normal(ks[1], (e, h, i), cfg.param_dtype) / jnp.sqrt(h),
         "b_up": jnp.zeros((e, i), cfg.param_dtype),
         "w_down": jax.random.normal(ks[2], (e, i, h), cfg.param_dtype) / jnp.sqrt(i),
@@ -48,10 +50,10 @@ def init_moe_params(key, cfg: MoEConfig) -> dict:
         p["w_gate"] = (
             jax.random.normal(ks[3], (e, h, i), cfg.param_dtype) / jnp.sqrt(h)
         )
-    if cfg.router_bias and e > 1:
+    if cfg.router_bias and e_all > 1:
         # the selection bias is a buffer the balancing rule moves, not a
         # trained weight: float32, zero until a checkpoint sets it
-        p["gate_bias"] = jnp.zeros((e,), jnp.float32)
+        p["gate_bias"] = jnp.zeros((e_all,), jnp.float32)
     if cfg.num_shared_experts:
         si = i * cfg.num_shared_experts
         p["shared_w_up"] = (
@@ -90,6 +92,14 @@ def reference_gate(x, gate_w, cfg: MoEConfig, gate_bias=None):
     else:
         scores = probs = jax.nn.softmax(logits, axis=-1)
     select = scores if gate_bias is None else scores + gate_bias[None, :]
+    if cfg.n_group > 1:
+        # group-limited: a group scores the sum of its two best, the
+        # topk_group best groups keep their experts in the running
+        per = select.reshape(select.shape[0], cfg.n_group, -1)
+        score = jnp.sum(jnp.sort(per, axis=-1)[..., -2:], axis=-1)
+        floor = jnp.sort(score, axis=-1)[:, -cfg.topk_group][:, None]
+        select = jnp.where((score >= floor)[:, :, None], per,
+                           -jnp.inf).reshape(select.shape)
     _, top_idx = jax.lax.top_k(select, cfg.expert_top_k)
     top_p = jnp.take_along_axis(scores, top_idx, axis=-1)
     # mask to top-k, renormalize over the selected set

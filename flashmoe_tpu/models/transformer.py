@@ -64,15 +64,35 @@ def init_params(key, cfg: MoEConfig) -> dict:
             "attn_norm": jnp.ones((h,), cfg.param_dtype),
             "ffn_norm": jnp.ones((h,), cfg.param_dtype),
         }
-        if cfg.attention_kind == "mla":
+        if cfg.mixers[li] == "kda":
+            ak = jax.random.split(lk[0], 7)
+            n, d = cfg.kda_heads, cfg.kda_head_dim
+            layer.update(
+                kda_wqkv=dense(ak[0], (h, 3 * n * d), h),
+                kda_conv=dense(ak[1], (cfg.kda_conv, 3 * n * d),
+                               cfg.kda_conv),
+                kda_wa=dense(ak[2], (h, n * d), h),
+                # the decay's per-head rate and per-channel offset are
+                # not matrices: float32 whatever the weights' dtype
+                kda_A=jnp.zeros((n,), jnp.float32),
+                kda_b=jax.random.normal(ak[3], (n * d,), jnp.float32),
+                kda_wb=dense(ak[4], (h, n), h),
+                kda_wg=dense(ak[5], (h, n), h),
+                kda_norm=jnp.ones((d,), cfg.param_dtype),
+                wo=dense(ak[6], (n * d, h), n * d))
+        elif cfg.attention_kind == "mla":
             ak = jax.random.split(lk[0], 4)
             rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
             dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                           cfg.v_head_dim)
+            if rq:
+                layer.update(
+                    wq_a=dense(ak[0], (h, rq), h),
+                    q_a_norm=jnp.ones((rq,), cfg.param_dtype),
+                    wq_b=dense(ak[1], (rq, nh * (dn + dr)), rq))
+            else:
+                layer.update(wq=dense(ak[0], (h, nh * (dn + dr)), h))
             layer.update(
-                wq_a=dense(ak[0], (h, rq), h),
-                q_a_norm=jnp.ones((rq,), cfg.param_dtype),
-                wq_b=dense(ak[1], (rq, nh * (dn + dr)), rq),
                 wkv_a=dense(ak[2], (h, rkv + dr), h),
                 kv_a_norm=jnp.ones((rkv,), cfg.param_dtype),
                 wkv_b=dense(ak[3], (rkv, nh * (dn + dv)), rkv),
@@ -93,21 +113,30 @@ def init_params(key, cfg: MoEConfig) -> dict:
 # ----------------------------------------------------------------------
 
 def attention(layer, x, cfg: MoEConfig, positions=None, mesh=None,
-              use_pallas=None):
-    """Causal self-attention with RoPE and GQA. x: [B, T, H].
+              use_pallas=None, li: int = 0):
+    """Layer ``li``'s token mixer over a whole sequence (no cache): causal
+    self-attention with RoPE and GQA, latent attention, or the delta rule
+    in its chunkwise form, by ``cfg.mixers[li]``.  x: [B, T, H].
 
-    Backend selection: ring attention over the ``sp`` mesh axis for
-    sequence-parallel configs, the flash Pallas kernel on TPU, plain XLA
-    otherwise.
+    Backend selection of the K/V kind: ring attention over the ``sp``
+    mesh axis for sequence-parallel configs, the flash Pallas kernel on
+    TPU, plain XLA otherwise.
     """
     from flashmoe_tpu.ops.attention import (
         attention_xla, flash_attention, mla_paged_attention,
     )
+    from flashmoe_tpu.ops.kda import kda_attention
     from flashmoe_tpu.parallel.ringattn import ring_attention
 
     b, t, h = x.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
+    if cfg.mixers[li] == "kda":
+        if mesh is not None and cfg.sp > 1:
+            raise NotImplementedError(
+                "a 'kda' layer under sp > 1: the state would have to "
+                "pass from one sequence shard to the next")
+        return kda_attention(layer, x, cfg, None, None, 0)[0]
     if cfg.attention_kind == "mla":
         # plain XLA, the first (decompressing) form: flash_attention
         # assumes equal q/k/v head sizes, ring attention K/V shards
@@ -216,7 +245,7 @@ def block(layer, x, cfg: MoEConfig, li: int, mesh=None, use_pallas=None,
     state's jaxpr whenever two builds share an equal config (the chaos
     drills rebuild their step exactly to pick up new arming)."""
     a = attention(layer, rms_norm(x, layer["attn_norm"]), cfg, mesh=mesh,
-                  use_pallas=use_pallas)
+                  use_pallas=use_pallas, li=li)
     x = x + a
     f, moe_loss, moe_stats = _ffn(layer, rms_norm(x, layer["ffn_norm"]),
                                   cfg, li, mesh, use_pallas)

@@ -22,6 +22,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from flashmoe_tpu.config import LANE
+from flashmoe_tpu.ops.kda import kda_attention
 from flashmoe_tpu.utils.telemetry import trace_span
 
 NEG_INF = -1e30
@@ -97,8 +98,12 @@ def mla_project(layer, x, cfg, positions):
     b, t, _ = x.shape
     nh, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     dc = cfg.kv_lora_rank
-    c_q = rms_norm(x @ layer["wq_a"].astype(x.dtype), layer["q_a_norm"])
-    q = (c_q @ layer["wq_b"].astype(x.dtype)).reshape(b, t, nh, dn + dr)
+    if cfg.q_lora_rank:
+        c_q = rms_norm(x @ layer["wq_a"].astype(x.dtype), layer["q_a_norm"])
+        q = c_q @ layer["wq_b"].astype(x.dtype)
+    else:                       # the published null rank: queries direct
+        q = x @ layer["wq"].astype(x.dtype)
+    q = q.reshape(b, t, nh, dn + dr)
     q_rope = rope_adjacent(q[..., dn:], positions, cfg.rope_theta)
     kv = x @ layer["wkv_a"].astype(x.dtype)                   # [B, T, dc+dr]
     c_kv = rms_norm(kv[..., :dc], layer["kv_a_norm"])
@@ -362,19 +367,40 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
 
 
 def paged_attention(layer, x, cfg, pools, li, pos, write, block_tables, *,
-                    absorbed: bool):
-    """Layer ``li``'s attention over a cache, by ``cfg.attention_kind``:
-    :func:`kv_paged_attention` or :func:`mla_paged_attention` (which alone
-    reads ``absorbed``).  pools: the cache's arrays as a tuple, a K/V pair
-    or the one latent pool, or None.  Returns (attention output, the
-    pools, the span's rows: one array for each pool of the cache)."""
+                    absorbed: bool, valid=None, slots=None, fresh=None):
+    """Layer ``li``'s token mixer over the cache, by ``cfg.mixers[li]``:
+    :func:`kv_paged_attention`, :func:`mla_paged_attention` (which alone
+    reads ``absorbed``) or :func:`~flashmoe_tpu.ops.kda.kda_attention`
+    (which alone reads ``valid``, ``slots`` and ``fresh``, and neither
+    positions nor pages).  pools: the cache's arrays as a tuple, or None:
+    the paged pools first (a K/V pair or the one latent pool, holding the
+    layers of ``cfg.cache_layers``), then, where the config has 'kda'
+    layers, their per-slot state and convolution inputs
+    (``cfg.state_layers``).  Returns (the block's output, the pools, the
+    span's rows: one entry for each array of the cache, None for those
+    this layer does not own)."""
+    n_paged = 1 if cfg.attention_kind == "mla" else 2
+    n_state = 2 if cfg.state_layers else 0
+    if cfg.mixers[li] == "kda":
+        state, conv = (None, None) if pools is None else pools[n_paged:]
+        out, state, conv, final = kda_attention(
+            layer, x, cfg, state, conv, cfg.state_layers.index(li), valid,
+            slots, fresh)
+        return (out, None if pools is None
+                else pools[:n_paged] + (state, conv),
+                (None,) * n_paged + final)
+    ci = cfg.cache_layers.index(li)
+    paged = None if pools is None else pools[:n_paged]
     if cfg.attention_kind != "mla":
-        return kv_paged_attention(layer, x, cfg, pools, li, pos, write,
-                                  block_tables)
-    out, pool, latent = mla_paged_attention(
-        layer, x, cfg, None if pools is None else pools[0], li, pos, write,
-        block_tables, absorbed=absorbed)
-    return out, None if pools is None else (pool,), (latent,)
+        out, paged, span = kv_paged_attention(layer, x, cfg, paged, ci, pos,
+                                              write, block_tables)
+    else:
+        out, pool, latent = mla_paged_attention(
+            layer, x, cfg, None if pools is None else paged[0], ci, pos,
+            write, block_tables, absorbed=absorbed)
+        paged, span = None if pools is None else (pool,), (latent,)
+    return (out, None if pools is None else paged + pools[n_paged:],
+            span + (None,) * n_state)
 
 
 # ----------------------------------------------------------------------

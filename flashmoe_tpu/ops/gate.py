@@ -30,6 +30,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from flashmoe_tpu.config import BLOCK_M, LANE, MoEConfig
+from flashmoe_tpu.utils.telemetry import trace_span
 
 
 class RouterOutput(NamedTuple):
@@ -80,6 +81,24 @@ def _finish(cfg: MoEConfig, top_p, top_idx, probs_sum, counts, zsum, s_tokens):
 # XLA reference path
 # ----------------------------------------------------------------------
 
+def limit_to_groups(select, cfg: MoEConfig):
+    """Group-limited selection.  select: [S, E] selection scores, the
+    experts in ``n_group`` equal consecutive groups.  A group scores the
+    sum of its two largest; the ``topk_group`` best groups are kept and
+    every expert outside them gets -inf, so the top-k that follows is
+    taken inside them."""
+    with trace_span("moe.route_groups"):
+        s, g = select.shape[0], cfg.n_group
+        grouped = select.reshape(s, g, -1)
+        group_score = jnp.sum(
+            jax.lax.top_k(grouped, min(2, grouped.shape[-1]))[0], axis=-1)
+        _, kept = jax.lax.top_k(group_score, cfg.topk_group)     # [S, tg]
+        keep = jnp.any(kept[:, :, None] == jnp.arange(g)[None, None, :],
+                       axis=1)                                   # [S, g]
+        return jnp.where(keep[:, :, None], grouped,
+                         -jnp.inf).reshape(select.shape)
+
+
 def router_xla(x, gate_w, cfg: MoEConfig, gate_bias=None) -> RouterOutput:
     """Router in plain XLA ops. x: [S, H], gate_w: [H, E].
 
@@ -87,7 +106,9 @@ def router_xla(x, gate_w, cfg: MoEConfig, gate_bias=None) -> RouterOutput:
     top-k is taken over ``scores + gate_bias`` (``gate_bias`` [E]: the
     published ``e_score_correction_bias``, which steers the SELECTION and
     nothing else) and the combine weights are the chosen experts' own
-    scores, normalised and scaled in :func:`_finish`.  The load-balance
+    scores, normalised and scaled in :func:`_finish`.  ``cfg.n_group`` >
+    1 limits the choice to the best groups (:func:`limit_to_groups`); one
+    group traces what it always traced.  The load-balance
     statistics read the scores normalised over the experts."""
     s = x.shape[0]
     logits = jnp.dot(
@@ -99,11 +120,14 @@ def router_xla(x, gate_w, cfg: MoEConfig, gate_bias=None) -> RouterOutput:
 
     if chaos_inject.is_armed("skewed_routing"):  # trace-time check only
         logits = chaos_inject.poison_logits(logits)
-    if cfg.router_score == "sigmoid" or gate_bias is not None:
+    if (cfg.router_score == "sigmoid" or gate_bias is not None
+            or cfg.n_group > 1):
         scores = (jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid"
                   else jax.nn.softmax(logits, axis=-1))
         select = scores if gate_bias is None else (
             scores + gate_bias.astype(scores.dtype)[None, :])
+        if cfg.n_group > 1:
+            select = limit_to_groups(select, cfg)
         _, top_idx = jax.lax.top_k(select, cfg.expert_top_k)
         top_p = jnp.take_along_axis(scores, top_idx, axis=-1)
         probs = scores / jnp.maximum(
@@ -575,7 +599,8 @@ def router(x, gate_w, cfg: MoEConfig, use_pallas: bool = True,
             "this config routes with a selection bias (router_bias) and "
             "the caller passed no gate_bias: only ops/moe.py:moe_layer "
             "carries it (the expert-parallel layers do not yet)")
-    if cfg.router_score != "softmax" or gate_bias is not None:
+    if (cfg.router_score != "softmax" or gate_bias is not None
+            or cfg.n_group > 1):
         return apply_replicas(router_xla(x, gate_w, cfg, gate_bias), cfg)
     if chaos_inject.is_armed("skewed_routing") and use_pallas:
         # the skew fault biases router LOGITS (router_xla hook); the

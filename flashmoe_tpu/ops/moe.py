@@ -84,16 +84,29 @@ def routed_rows_ffn(params, x, r, cfg: MoEConfig):
     (on a TPU XLA's own grouped matmul, which reads an expert's weights
     only if rows reached it; elsewhere a masked dense product).  No row is
     padded, none is dropped.  x: [S, H]; r: the router's output.  Returns
-    [S, H] float32, the weighted sum of every token's K expert outputs."""
+    [S, H] float32, the weighted sum of every token's K expert outputs.
+
+    A config that holds a SHARE of the experts (``cfg.experts_held``: the
+    weights of experts ``expert_first`` .. + ``experts_held`` - 1, routed
+    over all ``num_experts``) computes the rows that fall on its own
+    experts: the others sort behind the last group, belong to no group of
+    the grouped product and count as zero in the sum.  What the absent
+    experts would have added is left out."""
     s, h = x.shape
     k = cfg.expert_top_k
     act = activation_fn(cfg.hidden_act)
     f32 = dict(preferred_element_type=cfg.accum_dtype)
     with trace_span("moe.dispatch"):
         flat_e = r.expert_idx.reshape(-1)              # row t*K + j
+        sizes = r.expert_counts                        # rows an expert
+        here = None
+        if cfg.experts_held:
+            first, held = cfg.expert_first, cfg.experts_held
+            here = (flat_e >= first) & (flat_e < first + held)
+            flat_e = jnp.where(here, flat_e - first, held)
+            sizes = sizes[first:first + held]
         order = jnp.argsort(flat_e, stable=True)
         sorted_e = flat_e[order]
-        sizes = r.expert_counts                        # rows an expert
         xs = x.astype(cfg.dtype)[order // k]           # [S*K, H]
     with trace_span("moe.expert"):
         up = jax.lax.ragged_dot(xs, params["w_up"].astype(xs.dtype), sizes,
@@ -112,6 +125,9 @@ def routed_rows_ffn(params, x, r, cfg: MoEConfig):
              ).astype(xs.dtype)
     with trace_span("moe.combine"):
         back = jnp.argsort(order)                      # row t*K + j again
+        if here is not None:
+            # a row of no group holds whatever the product left there
+            y = jnp.where(here[order][:, None], y, jnp.zeros((), y.dtype))
         return jnp.einsum(
             "skh,sk->sh", y[back].reshape(s, k, h).astype(jnp.float32),
             r.combine_weights.astype(jnp.float32),
@@ -142,6 +158,11 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
                    gate_bias=params["gate_bias"] if cfg.router_bias
                    else None)
     s, h = x.shape
+    if cfg.experts_held and not routed_rows:
+        raise NotImplementedError(
+            "a share of the experts (experts_held) is computed over the "
+            "routed rows only (routed_rows=True: ops/moe.routed_rows_ffn); "
+            "the capacity and Pallas arms index every expert's weights")
     if routed_rows and (use_pallas or cfg.drop_tokens
                         or capacity is not None
                         or cfg.degrade_unhealthy_experts):

@@ -61,7 +61,7 @@ from flashmoe_tpu.ops import attention
 from flashmoe_tpu.serving.kvcache import (
     SCRATCH_PAGE, PagedKVCache, PagePool, ShardedPagePool,
     ctx_pages_bucket, init_paged_cache, page_size_of, prompt_pad,
-    store_prefill,
+    slot_state_fields, store_prefill, store_state,
 )
 from flashmoe_tpu.serving.speculate import (
     DraftState, SpecConfig, spec_stats_fields,
@@ -257,14 +257,19 @@ def _prefill_padded(params, cfg: MoEConfig, prompt_padded, true_len):
     """Prefill one padded prompt: [1, T_pad] int32 -> (logits [V] at
     the true last position, then one dense run for each pool of the
     cache, as ``store_prefill`` takes it: k_seq/v_seq
-    [L, N_kv, T_pad, D], or an MLA config's latent rows [L, T_pad, C]).
+    [L, N_kv, T_pad, D], or an MLA config's latent rows [L, T_pad, C];
+    then, with 'kda' layers, their state after the TRUE last token,
+    [L_s, N, D, D] and [L_s, (K - 1) * 3 N D], as ``store_state`` takes
+    it).
     Pad positions compute garbage no causal query before them ever
-    sees; their rows land in pages the length mask never exposes."""
+    sees; their rows land in pages the length mask never exposes, and
+    they leave a recurrent state alone."""
     t_pad = prompt_padded.shape[1]
-    x, _, runs = span_forward(
+    positions = jnp.arange(t_pad, dtype=jnp.int32)[None, :]
+    x, _, runs, _ = span_forward(
         params, cfg, params["embed"].astype(cfg.dtype)[prompt_padded],
-        None, jnp.arange(t_pad, dtype=jnp.int32)[None, :], None, None,
-        absorbed=False)
+        None, positions, None, None, absorbed=False,
+        valid=positions < true_len if cfg.state_layers else None)
     h = jax.lax.dynamic_slice(
         x, (0, true_len - 1, 0), (1, 1, x.shape[-1]))
     return (lm_logits(params, cfg, h)[0], *(run[:, 0] for run in runs))
@@ -272,7 +277,8 @@ def _prefill_padded(params, cfg: MoEConfig, prompt_padded, true_len):
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _prefill_chunk(params, cfg: MoEConfig, pools, chunk_toks,
-                   block_table, chunk_page_ids, start_pos, rel_last):
+                   block_table, chunk_page_ids, start_pos, rel_last,
+                   slot=0):
     """Prefill ONE fixed-size chunk of a long prompt directly into the
     paged cache.
 
@@ -281,8 +287,11 @@ def _prefill_chunk(params, cfg: MoEConfig, pools, chunk_toks,
     (bucketed, scratch-padded); chunk_page_ids: [C / page] the pages
     THIS chunk writes; start_pos: absolute position of the chunk's
     first token; rel_last: in-chunk index of the prompt's true last
-    token (clipped — only the chunk containing it keeps the logits).
-    ``pools`` is the engine's cache (a K/V pair or one latent pool).
+    token (clipped — only the chunk containing it keeps the logits);
+    slot: the batch slot the prompt was admitted to, whose recurrent
+    state the 'kda' layers carry from chunk to chunk (the first chunk
+    starts from nothing; positions past ``rel_last`` leave it alone).
+    ``pools`` is the engine's cache (any class of ``serving/kvcache``).
     Returns (logits [V], pools).
 
     The chunk's rows land in their pages BEFORE the gather, so in-chunk
@@ -292,10 +301,14 @@ def _prefill_chunk(params, cfg: MoEConfig, pools, chunk_toks,
     invariant, per chunk."""
     c = chunk_toks.shape[1]
     positions = start_pos + jnp.arange(c, dtype=jnp.int32)   # [C]
-    x, pools, _ = span_forward(
+    state = dict(
+        valid=(jnp.arange(c, dtype=jnp.int32) <= rel_last)[None, :],
+        slots=jnp.asarray(slot, jnp.int32)[None],
+        fresh=start_pos == 0) if cfg.state_layers else {}
+    x, pools, _, _ = span_forward(
         params, cfg, params["embed"].astype(cfg.dtype)[chunk_toks], pools,
         positions[None, :], (chunk_page_ids[None, :], None),  # whole pages
-        block_table[None, :], absorbed=False)
+        block_table[None, :], absorbed=False, **state)
     h = jax.lax.dynamic_slice(x, (0, rel_last, 0), (1, 1, x.shape[-1]))
     return lm_logits(params, cfg, h)[0], pools
 
@@ -305,7 +318,11 @@ def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
     """A span of T tokens a slot through the layers, over the paged
     cache: the body of the decode (T = 1) and verify programs and of
     their EP-sharded twins (which pass ``mixture``).  toks: [B, T];
-    column t lands at ``positions + t``.  Returns (x [B, T, H], pools).
+    column t lands at ``positions + t``.  Returns (x [B, T, H], pools,
+    what the layers counted: ``span_forward``'s).  Row b is slot b: its
+    recurrent state is read and written in place, and a row whose table
+    is all scratch (idle, or between two chunks of its prompt) leaves its
+    state alone.
 
     Span positions past the gathered context (a slot drafted into its
     context ceiling) route their writes to the scratch page and produce
@@ -321,11 +338,14 @@ def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
             block_tables, jnp.clip(pos // page, 0, ntab - 1), axis=1),
         jnp.int32(SCRATCH_PAGE))
     rows = jnp.where(valid, pos % page, 0)
+    live = (jnp.broadcast_to(block_tables[:, :1] != SCRATCH_PAGE, pos.shape)
+            if cfg.state_layers else None)
     # a short span over a long context: MLA's absorbed form
-    x, pools, _ = span_forward(
+    x, pools, _, counted = span_forward(
         params, cfg, params["embed"].astype(cfg.dtype)[toks], pools, pos,
-        (page_ids, rows), block_tables, absorbed=True, mixture=mixture)
-    return x, pools
+        (page_ids, rows), block_tables, absorbed=True, mixture=mixture,
+        valid=live)
+    return x, pools, counted
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -336,10 +356,11 @@ def _paged_decode_step(params, cfg: MoEConfig, pools, toks,
     toks: [B] int32 tokens to feed; block_tables: [B, n] page ids
     (bucketed); positions: [B] write positions (= each slot's current
     length; inactive slots pass 0 with an all-scratch table).  Returns
-    (logits [B, V] f32, pools)."""
-    x, pools = _span_step(params, cfg, pools, toks[:, None], block_tables,
-                          positions)
-    return lm_logits(params, cfg, x), pools
+    (logits [B, V] f32, pools, what the layers counted: a dict of scalars,
+    empty for a config whose layers count nothing)."""
+    x, pools, counted = _span_step(params, cfg, pools, toks[:, None],
+                                   block_tables, positions)
+    return lm_logits(params, cfg, x), pools, counted
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -363,7 +384,8 @@ def _paged_verify_step(params, cfg: MoEConfig, pools, toks,
     block-table/length state, and the next step's span overwrites those
     exact rows before any causal mask exposes them (the prefill pad-row
     invariant)."""
-    x, pools = _span_step(params, cfg, pools, toks, block_tables, positions)
+    x, pools, _ = _span_step(params, cfg, pools, toks, block_tables,
+                             positions)
     return lm_logits_span(params, cfg, x), pools
 
 
@@ -387,6 +409,8 @@ _INPLACE = {
 #: backlog cell, a twentieth of the device's time once the decode step
 #: stopped copying it)
 _store_prefill = jax.jit(store_prefill, donate_argnums=(0,))
+#: the same for a slot's recurrent state
+_store_state = jax.jit(store_state, donate_argnums=(0,))
 
 
 # ----------------------------------------------------------------------
@@ -440,10 +464,10 @@ def _ep_decode_fn(mesh, cfg: MoEConfig, params, *, span: bool = False):
     head = lm_logits_span if span else lm_logits
 
     def body(params, pools, toks, block_tables, positions):
-        x, pools = _span_step(params, cfg, pools,
-                              toks if span else toks[:, None],
-                              block_tables, positions,
-                              mixture=ragged_ep.decode_moe_rows)
+        x, pools, _ = _span_step(params, cfg, pools,
+                                 toks if span else toks[:, None],
+                                 block_tables, positions,
+                                 mixture=ragged_ep.decode_moe_rows)
         return head(params, cfg, x), pools
 
     slab = PagedKVCache(P(None, "ep"), P(None, "ep"))
@@ -590,6 +614,25 @@ class ServingEngine:
         None (the default) makes zero calls — byte-identical."""
         mla = cfg.attention_kind == "mla"
         sv = serve if serve is not None else ServeConfig()
+        if cfg.state_layers:
+            # what cannot keep a slot's recurrent state correct
+            missing = {
+                "speculate": (sv.speculate is not None,
+                              "a rejected draft has already moved the "
+                              "state; a snapshot to roll back to"),
+                "ep_shards > 1": (sv.ep_shards > 1,
+                                  "_ep_decode_fn shards a K/V page pair; "
+                                  "a per-slot state arm"),
+                "a prefill_fn (the fabric's KV handoff)": (
+                    prefill_fn is not None,
+                    "fabric/handoff.py encodes K/V pages; a payload for "
+                    "a slot's state"),
+            }
+            for what, (asked, lack) in missing.items():
+                if asked:
+                    raise NotImplementedError(
+                        f"recurrent-state ('kda') layers with {what}: "
+                        f"{lack} is missing")
         if mla and sv.ep_shards > 1:
             raise NotImplementedError(
                 "attention_kind='mla' with ep_shards > 1: _ep_decode_fn "
@@ -729,7 +772,13 @@ class ServingEngine:
                 ngram=self._spec.ngram, source=self._spec.source)
 
         self.cache = init_paged_cache(cfg, self.serve.num_pages,
-                                      self.serve.page_size)
+                                      self.serve.page_size,
+                                      self.serve.max_batch)
+        # this step's traffic with the slots' recurrent state, and what
+        # the decode program of the step before counted (ready by now:
+        # reading this step's would wait for it)
+        self._state_bytes = 0
+        self._counted = self._counted_prev = None
         self.pool = (ShardedPagePool(self.serve.num_pages, d) if d > 1
                      else PagePool(self.serve.num_pages))
         if self.quant_info is not None:
@@ -1050,8 +1099,12 @@ class ServingEngine:
                     page_ids = jnp.asarray(
                         self._global_pages(slot, pages), jnp.int32)
                     self.cache = type(self.cache)(*(
-                        _store_prefill(pool, seq, page_ids)
-                        for pool, seq in zip(self.cache, seqs)))
+                        _store_state(pool, seq, slot) if by_slot
+                        else _store_prefill(pool, seq, page_ids)
+                        for pool, seq, by_slot in zip(
+                            self.cache, seqs,
+                            slot_state_fields(self.cache))))
+                    self._state_bytes += self.cfg.state_slot_bytes
                 self._logits = self._logits.at[slot].set(logits)
                 self.slots[slot] = _Slot(
                     req=req, orig=orig, pages=list(pages), length=t0,
@@ -1060,6 +1113,10 @@ class ServingEngine:
                     first_token_s=entry.first_token_s, **account)
                 self.stats["prefill_buckets"].add(t_pad)
             self._rates["admits"].add()
+            if self.cfg.state_layers:
+                # the slot's state starts from nothing: a whole prefill
+                # overwrites it, a first chunk ignores what it holds
+                self.metrics.count("serve.state_resets")
             self.metrics.decision(
                 "serve.admit", rid=orig.rid, step=self.step_idx,
                 slot=slot, prompt_tokens=t0, pages=n_pages,
@@ -1122,7 +1179,11 @@ class ServingEngine:
                     jnp.asarray(toks)[None, :],
                     jnp.asarray(table),
                     jnp.asarray(chunk_ids, jnp.int32),
-                    jnp.int32(pos), jnp.int32(rel_last))
+                    jnp.int32(pos), jnp.int32(rel_last), jnp.int32(i))
+            if self.cfg.state_layers:
+                self._state_bytes += 2 * self.cfg.state_slot_bytes
+                if pos:
+                    self.metrics.count("serve.chunk_carries")
             s.prefill_pos = pos + chunk
             if pos <= t0 - 1 < pos + chunk:
                 # prefill complete — arm the sampler, join decode
@@ -1550,6 +1611,9 @@ class ServingEngine:
         self._delivered_now = {}
         self._ctx_pages = (0, 0.0, 0, None)
         self._sampled = np.zeros((3,), np.int64)
+        self._state_bytes = 0
+        if self._counted is not None:
+            self._counted_prev, self._counted = self._counted, None
         t0_s = self._phase("serve.admit")
         if self.tracer is not None:
             # open the step window BEFORE admissions: everything in
@@ -1638,10 +1702,15 @@ class ServingEngine:
                     jnp.asarray(tables[:, :n_ctx]),
                     jnp.asarray(positions))
             else:
-                logits, self.cache = _INPLACE["_paged_decode_step"](
+                logits, self.cache, counted = _INPLACE[
+                    "_paged_decode_step"](
                     self.params, self.cfg, self.cache, jnp.asarray(feed),
                     jnp.asarray(tables[:, :n_ctx]),
                     jnp.asarray(positions))
+                self._counted = counted or None
+                # every slot's state goes through the step and back
+                self._state_bytes += (2 * sv.max_batch
+                                      * self.cfg.state_slot_bytes)
             # the step's account of it, while the device runs it
             self._note_ctx(n_ctx, positions[active], 1)
             self._logits = logits
@@ -1716,6 +1785,16 @@ class ServingEngine:
             "sample_rows": sample_rows, "sample_drawn": sample_drawn,
             "sample_sorted": sample_sorted,
         }
+        if self.cfg.state_layers:
+            rec["state_bytes"] = self._state_bytes
+        held_rows = None
+        # the latest decode program that has finished (the very first
+        # record waits for its own)
+        counted = (self._counted_prev if self._counted_prev is not None
+                   else self._counted)
+        if counted is not None and self.recorder is not None:
+            held_rows = round(float(counted["held_rows"]), 3)
+            rec["held_rows"] = held_rows
         if self.serve.speculate is not None:
             rec["spec_tokens"] = int(n_extra or 0)
             rec["spec_on"] = self._spec is not None
@@ -1728,11 +1807,17 @@ class ServingEngine:
             if ctx_pages:
                 # the decode program's shape, one record per step that
                 # ran it: a mean over these is a mean over decode steps
+                more = {}
+                if self.cfg.state_layers:
+                    more["state_bytes"] = (2 * sv.max_batch
+                                           * self.cfg.state_slot_bytes)
+                if held_rows is not None:
+                    more["held_rows"] = held_rows
                 self.recorder.record(
                     kind="serve_decode", step=self.step_idx,
                     slots=n_decoding, ctx_pages=ctx_pages,
                     ctx_pages_idle=rec["ctx_pages_idle"],
-                    attn_arm=attn_arm)
+                    attn_arm=attn_arm, **more)
         if self.watchdog is not None:
             self.watchdog.observe_step(self.step_idx, step_ms)
         self.step_idx += 1

@@ -81,29 +81,97 @@ class LatentPagedCache(NamedTuple):
         return self.pages.shape[1]
 
 
-def init_paged_cache(cfg: MoEConfig, num_pages: int, page_size: int):
-    """Allocate the pool the config's attention kind reads: a K/V pair
-    (:class:`PagedKVCache`) or one latent pool
-    (:class:`LatentPagedCache`).  ``num_pages`` includes the scratch
-    page."""
+class HybridCache(NamedTuple):
+    """The cache of a model whose layers differ in kind: two kinds of
+    state side by side.  ``pages``: the latent pool of the layers that
+    cache rows (``cfg.cache_layers``: ``[L_c, P, page * C]``,
+    :class:`LatentPagedCache`'s array, addressed by PAGE through the block
+    tables).  ``state`` / ``conv``: what the 'kda' layers
+    (``cfg.state_layers``) keep of a request whatever its length,
+    addressed by SLOT: the float32 delta-rule state ``[L_s, slots, N, D,
+    D]`` and the convolution's last inputs ``[L_s, slots, (K - 1) * 3 N
+    D]`` (side by side, as a page's rows: no 3-row axis to pad).
+
+    Who does what to a slot's state.  A whole-prompt prefill computes it
+    from nothing and :func:`store_state` puts it in the slot; a prompt's
+    first chunk starts from nothing whatever the slot holds and every
+    later chunk carries it on; every decode step reads and writes it in
+    place, and a row of the step that is not decoding (idle, or between
+    two chunks) leaves it to the bit.  Retiring and evicting touch
+    nothing: the next tenant's prefill overwrites it, and an evicted
+    request's re-prefill rebuilds it."""
+
+    pages: jax.Array
+    state: jax.Array
+    conv: jax.Array
+
+    @property
+    def num_pages(self) -> int:
+        return self.pages.shape[1]
+
+
+def cache_arrays(cfg: MoEConfig, num_pages: int, page_size: int,
+                 slots: int) -> tuple:
+    """The cache's arrays, zeroed: the paged pools of the layers that
+    cache rows (a K/V pair, or one latent pool) and, where the config has
+    'kda' layers, a :class:`HybridCache` with ``slots`` slots of state."""
+    n_cache = len(cfg.cache_layers)
+    if cfg.attention_kind == "mla":
+        paged = (jnp.zeros(
+            (n_cache, num_pages, page_size * cfg.kv_token_elems),
+            cfg.dtype),)
+    else:
+        nkv, dh = cfg.resolved_num_kv_heads, cfg.resolved_head_dim
+        shape = (n_cache, num_pages, nkv, page_size, dh)
+        paged = (jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
+    if not cfg.state_layers:
+        return paged
+    if cfg.attention_kind != "mla":
+        raise NotImplementedError(
+            "'kda' layers beside K/V layers: the hybrid cache "
+            "(HybridCache) pairs the per-slot state with a latent pool")
+    n, d, n_state = cfg.kda_heads, cfg.kda_head_dim, len(cfg.state_layers)
+    return HybridCache(
+        *paged, jnp.zeros((n_state, slots, n, d, d), jnp.float32),
+        jnp.zeros((n_state, slots, (cfg.kda_conv - 1) * 3 * n * d),
+                  cfg.dtype))
+
+
+def init_paged_cache(cfg: MoEConfig, num_pages: int, page_size: int,
+                     slots: int = 0):
+    """Allocate the cache the config's layers read: a K/V pair
+    (:class:`PagedKVCache`), one latent pool (:class:`LatentPagedCache`)
+    or, with 'kda' layers, a latent pool beside ``slots`` slots of
+    recurrent state (:class:`HybridCache`).  ``num_pages`` includes the
+    scratch page."""
     if num_pages < 2:
         raise ValueError(f"num_pages={num_pages} must be >= 2 (page 0 "
                          f"is the reserved scratch page)")
     if page_size < 1:
         raise ValueError(f"page_size={page_size} must be >= 1")
-    if cfg.attention_kind == "mla":
-        return LatentPagedCache(jnp.zeros(
-            (cfg.num_layers, num_pages, page_size * cfg.kv_token_elems),
-            cfg.dtype))
-    nkv, dh = cfg.resolved_num_kv_heads, cfg.resolved_head_dim
-    shape = (cfg.num_layers, num_pages, nkv, page_size, dh)
-    return PagedKVCache(jnp.zeros(shape, cfg.dtype),
-                        jnp.zeros(shape, cfg.dtype))
+    arrays = cache_arrays(cfg, num_pages, page_size, slots)
+    if cfg.state_layers:
+        return arrays
+    return (LatentPagedCache if cfg.attention_kind == "mla"
+            else PagedKVCache)(*arrays)
+
+
+def slot_state_fields(cache) -> tuple:
+    """For each array of ``cache``: whether it is addressed by slot (the
+    recurrent state) and not by page."""
+    return tuple(isinstance(cache, HybridCache) and name != "pages"
+                 for name in cache._fields)
+
+
+def store_state(state, final, slot):
+    """Put a prefilled prompt's state into its slot, all layers at once.
+    state: ``[L_s, slots, ...]``; final: ``[L_s, ...]``."""
+    return state.at[:, slot].set(final.astype(state.dtype))
 
 
 def page_size_of(pools, cfg: MoEConfig) -> int:
-    """Tokens a page of ``pools`` (either cache class) holds."""
-    if isinstance(pools, LatentPagedCache):
+    """Tokens a page of ``pools`` (any cache class) holds."""
+    if isinstance(pools, (LatentPagedCache, HybridCache)):
         return pools.pages.shape[2] // cfg.kv_token_elems
     return pools.page_size
 

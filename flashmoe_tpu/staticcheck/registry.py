@@ -364,6 +364,9 @@ STRUCTURAL_FIELDS = frozenset({
     "qk_rope_head_dim", "v_head_dim",
     "router_score", "router_bias", "norm_topk_prob",
     "routed_scaling_factor", "first_k_dense", "dense_intermediate_size",
+    "n_group", "topk_group", "layer_mixers",
+    "kda_heads", "kda_head_dim", "kda_conv", "kda_lower_bound",
+    "expert_first", "experts_held",
     "dtype", "param_dtype", "accum_dtype",
     "dp", "ep", "tp", "sp", "pp",
 })

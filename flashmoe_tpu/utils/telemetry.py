@@ -240,6 +240,16 @@ SPAN_NAMES: dict[str, str] = {
     "attn.mla_decode":
         "latent attention, absorbed form: scores and output taken over "
         "the latent rows themselves (decode and verify steps)",
+    "attn.kda_prefill":
+        "delta-rule linear attention, chunkwise form: matrix products "
+        "over 64-token chunks and a scan over the chunks (prefill, "
+        "chunked prefill, training forward)",
+    "attn.kda_decode":
+        "delta-rule linear attention, one recurrence step over the "
+        "slots' float32 state (decode)",
+    "moe.route_groups":
+        "group-limited routing: the groups' scores and the mask of the "
+        "experts outside the kept groups",
     "serve.prefill":
         "serving engine: single-pass prompt prefill into cache pages",
     "serve.prefill_chunk":

@@ -399,7 +399,7 @@ def test_verify_column_0_equals_the_decode_step(params):
     cache, tables = _filled_cache(params)
     toks = jnp.asarray([[7, 9, 11], [0, 0, 0]])
     pos = jnp.asarray([19, 0])
-    dec, c1 = eng._paged_decode_step(params, CFG, cache, toks[:, 0], tables,
+    dec, c1, _ = eng._paged_decode_step(params, CFG, cache, toks[:, 0], tables,
                                      pos)
     ver, c2 = eng._paged_verify_step(params, CFG, cache, toks, tables, pos)
     assert isinstance(c1, LatentPagedCache) and c1.pages.shape == \

@@ -1381,7 +1381,7 @@ def test_every_paged_program_is_the_parents_span_body(body_state, prompts,
     if program == "decode":
         pos = jnp.asarray([8, 8, 7, 15, 8, 6, 8, 0], jnp.int32)
         toks = prompts[:, 0]
-        got, got_pools = eng._paged_decode_step(params, cfg, pools, toks,
+        got, got_pools, _ = eng._paged_decode_step(params, cfg, pools, toks,
                                                 tables, pos)
         want, want_pools = _parent_span_step(params, cfg, pools,
                                              toks[:, None], tables, pos)

@@ -513,3 +513,65 @@ def test_backlog_whole_prompt_prefill_fits_beside_the_pool(
     assert logits.shape == (102400,) and logits.dtype == jnp.float32
     assert k_run.shape == v_run.shape == (6, 16, 2048, 128)
     assert "[6,2048,16,16,128]" not in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def hybrid_programs(one_chip):
+    """The widest decode program and the 1024-token chunk of the cell
+    ``ling3_flash.serve.longgen`` (the leading dense layer + one period,
+    6 'kda' layers and 1 'mla', 128 of 512 experts held, a quarter of the
+    vocabulary, bf16; 64 slots, a 40960 x 16-token latent pool of ONE
+    layer, tables at their 640 pages), lowered as the engine runs them:
+    the whole cache donated."""
+    from flashmoe_tpu.models.presets import PRESETS
+    from flashmoe_tpu.models.transformer import init_params
+    from flashmoe_tpu.serving import engine as eng
+    from flashmoe_tpu.serving.kvcache import init_paged_cache
+
+    cfg = PRESETS["ling-3.0-flash"](
+        num_layers=7, first_k_dense=1, layer_mixers=("kda",) * 6 + ("mla",),
+        experts_held=128, vocab_size=39296, param_dtype=jnp.bfloat16)
+    on = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = on(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on(jax.eval_shape(lambda: init_paged_cache(cfg, 40960, 16, 64)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32, sharding=one_chip)
+    return {
+        "decode": eng._INPLACE["_paged_decode_step"].lower(
+            params, cfg, cache, i32(64), i32(64, 640), i32(64)),
+        "chunk": eng._INPLACE["_prefill_chunk"].lower(
+            params, cfg, cache, i32(1, 1024), i32(640), i32(64), i32(),
+            i32(), i32())}
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_hybrid_programs_fit_the_chip_with_state_and_pool_in_place(
+        hybrid_programs, program):
+    """13.57 GB (decode) and 13.37 GB (chunk) as compiled, under the
+    cell's 15.0: 10.34 GB of weights, and the latent pool (0.755 GB), the
+    float32 state (0.805 GB) and the convolution's inputs once each,
+    aliased to the outputs; no copy of the state or of the pool; the
+    experts are XLA's grouped matmul over the routed rows against the 128
+    experts held; the decode program is one recurrence step a 'kda' layer
+    and hands back what it counted."""
+    compiled = hybrid_programs[program].compile()
+    text = compiled.as_text()
+    cache_bytes = (40960 * 16 * 576 * 2 + 6 * 64 * 32 * 128 * 128 * 4
+                   + 6 * 64 * 3 * 12288 * 2)
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+    assert 12.5e9 < _program_bytes(compiled) < 15.0e9
+    for shape in (r"f32\[6,64,32,128,128\]", r"bf16\[1,40960,9216\]",
+                  r"bf16\[6,64,36864\]"):
+        assert re.search(shape, text)
+        assert not re.findall(rf"^.*= {shape}\S* copy\(.*$", text, re.M)
+    assert text.count("ragged-dot-metadata = ") >= 1
+    assert "[128,2560,768]" in text and "[512,2560,768]" not in text
+    assert "moe.route_groups" in text        # and plain XLA throughout
+    assert not [n for n, _ in _custom_call_names(text) if "fm_" in n]
+    if program == "decode":
+        assert "attn.kda_decode" in text and "attn.mla_decode" in text
+        assert len(jax.tree.leaves(compiled.out_info)) == 1 + 3 + 1
+    else:
+        assert "attn.kda_prefill" in text and "attn.mla_prefill" in text
