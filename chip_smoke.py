@@ -224,8 +224,10 @@ def run_engine(params, cfg, serve, reqs, arrivals, mesh=None):
     (every token but a request's first, whose prefill logits are made
     and consumed inside one step)."""
     from flashmoe_tpu.serving.engine import ServingEngine
+    from flashmoe_tpu.utils.telemetry import Metrics
 
-    engine = ServingEngine(params, cfg, serve, mesh=mesh)
+    engine = ServingEngine(params, cfg, serve, mesh=mesh,
+                           metrics_obj=Metrics())   # this run's counters
     seen = {r.rid: {} for r in reqs}
 
     def watch():
@@ -238,9 +240,60 @@ def run_engine(params, cfg, serve, reqs, arrivals, mesh=None):
     try:
         outputs = engine.run(reqs, arrivals, until=watch)
         summary = engine.summary()
+        # the steps whose decode program read each slot's pages in place
+        summary["decode_kernel_steps"] = engine.metrics.counters.get(
+            "serve.decode_kernel_steps", 0)
     finally:
         engine.close()
     return outputs, seen, summary
+
+
+@contextlib.contextmanager
+def gather_arm():
+    """Programs traced inside take the plain form of the cached attention
+    (store, gather the context, attend in XLA) whatever the backend: what
+    the decode kernel is held against.  The engine's paged programs are
+    traced anew on either side."""
+    from flashmoe_tpu.ops import attention
+    from flashmoe_tpu.serving import engine as eng
+
+    def retrace():
+        for program in eng._INPLACE.values():
+            program.clear_cache()
+
+    rule = attention.kv_attention_arm
+    attention.kv_attention_arm = lambda *a, **k: "gather"
+    retrace()
+    try:
+        yield
+    finally:
+        attention.kv_attention_arm = rule
+        retrace()
+
+
+def compare_arms(reqs, kernel, gather, tol):
+    """The engine's streams on the kernel's arm against its streams on
+    the gather arm: (requests whose tokens differ, rows compared, the
+    largest distance of two rows over the row's scale, rows further than
+    ``tol``).  Rows are compared while the two streams agree: the same
+    tokens fed, so the same context."""
+    (k_out, k_seen), (g_out, g_seen) = kernel, gather
+    differ, errs = [], []
+    for r in reqs:
+        a, b = list(k_out[r.rid]), list(g_out[r.rid])
+        t0 = len(r.prompt)
+        same = next((j for j in range(min(len(a), len(b)) - t0)
+                     if a[t0 + j] != b[t0 + j]), None)
+        if same is not None or len(a) != len(b):
+            differ.append(r.rid)
+        for j in sorted(set(k_seen[r.rid]) & set(g_seen[r.rid])):
+            if same is None or j <= same:
+                row = g_seen[r.rid][j]
+                errs.append(float(np.max(np.abs(k_seen[r.rid][j] - row))
+                                  / np.max(np.abs(row))))
+    errs = np.asarray(errs)
+    return (differ, len(errs), float(errs.max()) if len(errs) else None,
+            int((errs > tol).sum()))
 
 
 def check_streams(cfg, params, reqs, outputs, seen, tol, own_row=False):
@@ -356,6 +409,14 @@ def _serve_case(params, cfg, serve, seed, cut, extra):
         outputs, seen, summary = run_engine(params, cfg, serve, reqs,
                                             arrivals)
         run_s = time.perf_counter() - t0
+        # the same requests with the decode step on the gather arm: the
+        # Pallas kernel (which tier-1 sees in interpret mode only)
+        # against the plain form, at this page shape, on the chip
+        with gather_arm():
+            g_outputs, g_seen, g_summary = run_engine(params, cfg, serve,
+                                                      reqs, arrivals)
+        differ, arm_rows, arm_err, arm_over = compare_arms(
+            reqs, (outputs, seen), (g_outputs, g_seen), tol)
         t0 = time.perf_counter()
         bad, near_ties, token_mismatch, errs = check_streams(
             cfg, params, reqs, outputs, seen, tol,
@@ -367,7 +428,16 @@ def _serve_case(params, cfg, serve, seed, cut, extra):
         logits_ok = over == 0
     else:
         logits_ok = over <= len(errs) // 4 and float(errs.max()) <= 0.5
-    ok = (not bad and logits_ok and len(errs) > 0
+    # the arms: the kernel ran on every decoding step and on none of the
+    # gather arm's; float32 serves the same tokens; the rows agree within
+    # the tolerance wherever the two fed the same tokens (behind a router
+    # a flipped choice moves a row, as against generate(): three in four)
+    arms_ok = (summary["decode_kernel_steps"] > 0
+               and g_summary["decode_kernel_steps"] == 0
+               and arm_rows > 0 and (not differ or not strict)
+               and (arm_over == 0 if strict or not routed
+                    else arm_over <= arm_rows // 4 and arm_err <= 0.5))
+    ok = (not bad and logits_ok and len(errs) > 0 and arms_ok
           and summary["completed"] == len(reqs)
           and summary["max_active"] > 1)
     emit({"phase": "serve", "dtype": jnp.dtype(cfg.dtype).name, "ok": ok,
@@ -389,7 +459,14 @@ def _serve_case(params, cfg, serve, seed, cut, extra):
           "logits_compared": len(errs), "logits_over_tolerance": over,
           "logits_rel_err_median": float(np.median(errs)),
           "logits_rel_err_max": float(errs.max()),
-          "failures": bad[:8], **extra})
+          "failures": bad[:8],
+          "arms": {"kernel_steps": summary["decode_kernel_steps"],
+                   "gather_arm_kernel_steps":
+                       g_summary["decode_kernel_steps"],
+                   "requests_whose_tokens_differ": differ,
+                   "rows_compared": arm_rows, "rows_rel_err_max": arm_err,
+                   "rows_over_tolerance": arm_over, "ok": arms_ok},
+          **extra})
     return ok
 
 

@@ -688,9 +688,36 @@ class MoEConfig:
         return 2 * self.resolved_num_kv_heads * self.resolved_head_dim
 
     @property
+    def kv_pool_rows(self) -> tuple[int, int, int]:
+        """(pools, heads a pool keeps, elements of a head's row) of the
+        paged cache as it is STORED: a K and a V pool of every kv head; or
+        an MLA model's ONE pool of one latent row a token, padded to whole
+        lanes (576 -> 640) so that a page ``[page, row]`` is whole tiles,
+        contiguous, and a kernel's DMA can take it
+        (``serving/kvcache.LatentPagedCache``)."""
+        if self.attention_kind == "mla":
+            return 1, 1, _round_up(self.kv_token_elems, LANE)
+        return 2, self.resolved_num_kv_heads, self.resolved_head_dim
+
+    @property
+    def kv_row_elems(self) -> int:
+        """Elements the pools store for one token of one caching layer:
+        :attr:`kv_token_elems` with an MLA row's padding."""
+        pools, heads, row = self.kv_pool_rows
+        return pools * heads * row
+
+    @property
     def kv_token_bytes(self) -> int:
-        """Bytes one cached token costs over all the layers that cache."""
+        """Bytes one cached token holds over all the layers that cache
+        (what the model defines; the pool's padding is not in it)."""
         return (len(self.cache_layers) * self.kv_token_elems
+                * jnp.dtype(self.dtype).itemsize)
+
+    @property
+    def kv_pool_token_bytes(self) -> int:
+        """Bytes of pool one cached token takes over all the layers that
+        cache: :attr:`kv_token_bytes` with the rows as stored."""
+        return (len(self.cache_layers) * self.kv_row_elems
                 * jnp.dtype(self.dtype).itemsize)
 
     @property

@@ -49,7 +49,7 @@ class KVCache(NamedTuple):
 
 class LatentCache(NamedTuple):
     """The dense cache of an MLA model: one latent row a token a layer,
-    ``[L, B, T_max * (kv_lora_rank + qk_rope_head_dim)]``: a latent pool
+    ``[L, B, T_max, R]``, the row padded to whole lanes: a latent pool
     (``serving/kvcache.LatentPagedCache``'s layout) of one ``T_max``-row
     page a batch row."""
 

@@ -111,6 +111,24 @@ def mla_project(layer, x, cfg, positions):
     return q[..., :dn], q_rope, jnp.concatenate([c_kv, k_rope], axis=-1)
 
 
+def _mla_up_weights(layer, cfg, dt):
+    """``wkv_b`` split per head: (W_uk [rank, N, d_nope], W_uv [rank, N,
+    d_v])."""
+    dn = cfg.qk_nope_head_dim
+    w_kvb = layer["wkv_b"].astype(dt).reshape(
+        cfg.kv_lora_rank, cfg.num_heads, dn + cfg.v_head_dim)
+    return w_kvb[..., :dn], w_kvb[..., dn:]
+
+
+def _absorbed_query(q_nope, q_rope, w_uk):
+    """The absorbed form's query: ``[W_uk q_nope | q_rope]``, one row of
+    rank + d_rope elements a head, scored against latent rows as they
+    are cached.  [B, T, N, rank + d_rope]."""
+    q_lat = jnp.einsum("btnd,cnd->btnc", q_nope, w_uk,
+                       preferred_element_type=jnp.float32)
+    return jnp.concatenate([q_lat.astype(q_nope.dtype), q_rope], axis=-1)
+
+
 def mla_attend(layer, q_nope, q_rope, latent_ctx, cfg, q_pos, *,
                absorbed: bool):
     """Causal MLA of T queries a row over a context of latent rows.
@@ -125,16 +143,14 @@ def mla_attend(layer, q_nope, q_rope, latent_ctx, cfg, q_pos, *,
     b, t, nh, dn = q_nope.shape
     dc, dv = cfg.kv_lora_rank, cfg.v_head_dim
     dt = q_nope.dtype
-    w_kvb = layer["wkv_b"].astype(dt).reshape(dc, nh, dn + dv)
-    w_uk, w_uv = w_kvb[..., :dn], w_kvb[..., dn:]
+    w_uk, w_uv = _mla_up_weights(layer, cfg, dt)
     scale = (dn + cfg.qk_rope_head_dim) ** -0.5
     mask = (jnp.arange(latent_ctx.shape[1])[None, None, None, :]
             <= q_pos[:, None, :, None])
     f32 = dict(preferred_element_type=jnp.float32)
     if absorbed:
         with trace_span("attn.mla_decode"):
-            q_lat = jnp.einsum("btnd,cnd->btnc", q_nope, w_uk, **f32)
-            q_cat = jnp.concatenate([q_lat.astype(dt), q_rope], axis=-1)
+            q_cat = _absorbed_query(q_nope, q_rope, w_uk)
             logits = jnp.einsum("btnc,bsc->bnts", q_cat, latent_ctx,
                                 **f32) * scale
             probs = jax.nn.softmax(jnp.where(mask, logits, NEG_INF),
@@ -164,28 +180,29 @@ def mla_attend(layer, q_nope, q_rope, latent_ctx, cfg, q_pos, *,
 
 def store_latent(pool, li: int, rows_kv, page_ids, rows):
     """Scatter a span's latent rows into layer ``li`` of the latent pool.
-    pool: [L, P, page * C] (a page's rows side by side: see
-    ``serving/kvcache.LatentPagedCache``); rows_kv: [B, T, C]; page_ids /
-    rows: [B, T].  One window of C elements a token, at column
-    ``row * C`` of its page.  ``rows`` None: the span fills WHOLE pages
-    (a prefill chunk); page_ids is then [B, T // page], one id a page,
-    and a page is one window (the chip walks a scatter window by window:
-    1024 token windows a layer were a fifth of a chunk's time).  The
-    layer is an index of the scatter, not a slice taken out and put back:
-    that copied the layer's 300 MB twice in every program."""
-    c = rows_kv.shape[-1]
+    pool: [L, P, page, R] (``serving/kvcache.LatentPagedCache``: a row is
+    the latent padded with zeros to whole lanes); rows_kv: [B, T, C];
+    page_ids / rows: [B, T].  One window of R elements a token.  ``rows``
+    None: the span fills WHOLE pages (a prefill chunk); page_ids is then
+    [B, T // page], one id a page, and a page is one window (the chip
+    walks a scatter window by window: 1024 token windows a layer were a
+    fifth of a chunk's time).  The layer is an index of the scatter, not
+    a slice taken out and put back: that copied the layer's 300 MB twice
+    in every program."""
+    page, r = pool.shape[2:]
+    upd = jnp.pad(rows_kv, ((0, 0), (0, 0), (0, r - rows_kv.shape[-1])))
     lcol = jnp.full(page_ids.shape, li, page_ids.dtype)
     if rows is None:
         idx = jnp.stack([lcol, page_ids], axis=-1).reshape(-1, 2)
-        upd = rows_kv.reshape(idx.shape[0], -1)
+        upd = upd.reshape(idx.shape[0], page, r)
         dnums = jax.lax.ScatterDimensionNumbers(
-            update_window_dims=(1,), inserted_window_dims=(0, 1),
+            update_window_dims=(1, 2), inserted_window_dims=(0, 1),
             scatter_dims_to_operand_dims=(0, 1))
     else:
-        idx = jnp.stack([lcol, page_ids, rows * c], axis=-1).reshape(-1, 3)
-        upd = rows_kv.reshape(-1, c)
+        idx = jnp.stack([lcol, page_ids, rows], axis=-1).reshape(-1, 3)
+        upd = upd.reshape(-1, r)
         dnums = jax.lax.ScatterDimensionNumbers(
-            update_window_dims=(1,), inserted_window_dims=(0, 1),
+            update_window_dims=(1,), inserted_window_dims=(0, 1, 2),
             scatter_dims_to_operand_dims=(0, 1, 2))
     return jax.lax.scatter(
         pool, idx, upd.astype(pool.dtype), dnums,
@@ -194,34 +211,63 @@ def store_latent(pool, li: int, rows_kv, page_ids, rows):
 
 def gather_latent(pool, li: int, block_tables, c: int):
     """Each slot's context window from layer ``li`` of the latent pool.
-    pool: [L, P, page * C]; block_tables: [B, n] -> [B, n * page, C]; rows
-    past a slot's length are scratch and are masked by the caller."""
-    return pool[li, block_tables].reshape(block_tables.shape[0], -1, c)
+    pool: [L, P, page, R]; block_tables: [B, n] -> [B, n * page, C], the
+    rows without their padding; rows past a slot's length are scratch and
+    are masked by the caller."""
+    ctx = pool[li, block_tables]                       # [B, n, page, R]
+    return ctx.reshape(ctx.shape[0], -1, ctx.shape[-1])[..., :c]
 
 
 def mla_paged_attention(layer, x, cfg, pool, li, pos, write, block_tables,
                         *, absorbed: bool):
     """THE multi-head latent attention of every cached path: project a
     span of T tokens a slot, write its latent rows to layer ``li``'s
-    pages, gather the context, attend.  Prefill (whole and chunked),
-    decode and verify all call this and nothing else.
+    pages, attend over the context.  Prefill (whole and chunked), decode
+    and verify all call this and nothing else.  Two arms, by
+    :func:`kv_attention_arm` (the rule the K/V layers ask): a short span
+    in the absorbed form over a paged pool on a TPU reads each slot's own
+    pages in place (:func:`paged_decode_attention` over ONE pool: a
+    latent row is the key of every head and its first ``kv_lora_rank``
+    columns are the value); everything else stores, gathers the context
+    and attends in plain XLA (:func:`store_latent`,
+    :func:`gather_latent`, :func:`mla_attend`: the form the kernel is
+    held against).
 
-    x: [B, T, H] normed; pool: the latent pool [L, P, page * C], or None
+    x: [B, T, H] normed; pool: the latent pool [L, P, page, R], or None
     for a whole prompt at once (nothing is cached yet, the context is the
-    span itself); pos: [B, T] absolute positions; write:
-    ``(page_ids, rows)``, each [B, T], where the span's rows go (or
+    span itself); pos: [B, T] absolute positions, consecutive along T;
+    write: ``(page_ids, rows)``, each [B, T], where the span's rows go (or
     ``(page_ids [B, T // page], None)`` for a span of whole pages);
-    block_tables: [B, n] pages to gather as context.  Returns (attention
+    block_tables: [B, n] the slots' pages.  Returns (attention
     output [B, T, H], the pool, the span's latent rows [B, T, C])."""
     q_nope, q_rope, latent = mla_project(layer, x, cfg, pos)
     if pool is None:
-        ctx = latent
-    else:
-        pool = store_latent(pool, li, latent, *write)
-        ctx = gather_latent(pool, li, block_tables, latent.shape[-1])
-    out = mla_attend(layer, q_nope, q_rope, ctx, cfg, pos,
-                     absorbed=absorbed)
-    return out, pool, latent
+        return (mla_attend(layer, q_nope, q_rope, latent, cfg, pos,
+                           absorbed=absorbed), pool, latent)
+    b, t, nh, _ = q_nope.shape
+    page, r = pool.shape[2:]
+    if (absorbed and write[1] is not None and kv_attention_arm(
+            t, page, 1, r, pool.dtype, pools=1) == "paged_kernel"):
+        dt, dc = q_nope.dtype, cfg.kv_lora_rank
+        with trace_span("attn.mla_decode"):
+            w_uk, w_uv = _mla_up_weights(layer, cfg, dt)
+            lanes = lambda a: jnp.pad(
+                a, [(0, 0)] * (a.ndim - 1) + [(0, r - a.shape[-1])])
+            # every head of a slot against the slot's ONE row a token
+            o_lat, (pool,) = paged_decode_attention(
+                lanes(_absorbed_query(q_nope, q_rope, w_uk)),
+                (lanes(latent)[:, :, None],), (pool[:, :, None],), li,
+                block_tables, pos[:, 0], write, v_width=dc,
+                scale=(q_nope.shape[-1] + cfg.qk_rope_head_dim) ** -0.5,
+                interpret=jax.default_backend() != "tpu")
+            ctx = jnp.einsum("btnc,cnd->btnd", o_lat.reshape(b, t, nh, dc),
+                             w_uv, preferred_element_type=jnp.float32)
+        ctx = ctx.reshape(b, t, -1).astype(dt)
+        return ctx @ layer["wo"].astype(dt), pool[:, :, 0], latent
+    pool = store_latent(pool, li, latent, *write)
+    ctx = gather_latent(pool, li, block_tables, latent.shape[-1])
+    return (mla_attend(layer, q_nope, q_rope, ctx, cfg, pos,
+                       absorbed=absorbed), pool, latent)
 
 
 # ----------------------------------------------------------------------
@@ -356,7 +402,7 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
     if (write[1] is not None and kv_attention_arm(
             q.shape[1], page, n_kv, d, pools[0].dtype) == "paged_kernel"):
         ctx, pools = paged_decode_attention(
-            q, k, v, pools, li, block_tables, pos[:, 0], write,
+            q, (k, v), pools, li, block_tables, pos[:, 0], write,
             interpret=jax.default_backend() != "tpu")
         return ctx @ layer["wo"].astype(q.dtype), pools, span
     pools = (store_kv(pools[0], li, k, *write),
@@ -410,78 +456,121 @@ def paged_attention(layer, x, cfg, pools, li, pos, write, block_tables, *,
 # ``gather_ctx`` + ``kv_attend`` materialise every slot's context at the
 # batch's bucket ([B, N_kv, n * page, D], K and V, a layer), and with a
 # query or five a head the chip's compiler turns the scores into an f32
-# copy of all of it.  This kernel reads the pool where it lies: slot b's
-# block table and position arrive in SMEM before the body runs, the pages
-# that hold positions [0, pos_b) come into VMEM in blocks of
-# ``_KV_BLOCK`` positions, one [N_kv, page, D] DMA a page (all heads of a
-# page are contiguous in ``[L, P, N_kv, page, D]``), double-buffered, and
-# an online softmax runs over them.  The span's own rows are operands:
-# they are attended to from VMEM and written into the slot's page by the
-# kernel itself (the page is read, its rows replaced, and written back:
-# the pools are aliased to the outputs and no XLA instruction touches
-# them, so they keep their layout and are never copied).
+# copy of all of it; ``gather_latent`` + the absorbed ``mla_attend`` do
+# the same to a latent pool ([B, n * page, C], and a relayout of it).
+# This kernel reads the pools where they lie: slot b's block table and
+# position arrive in SMEM before the body runs, the pages that hold
+# positions [0, pos_b) come into VMEM in blocks, one [N_kv, page, D] DMA
+# a page a pool (all heads of a page are contiguous in
+# ``[L, P, N_kv, page, D]``), double-buffered, and an online softmax runs
+# over them.  The span's own rows are operands: they are attended to from
+# VMEM and written into the slot's page by the kernel itself (the page is
+# read, its rows replaced, and written back: the pools are aliased to the
+# outputs and no XLA instruction touches them, so they keep their layout
+# and are never copied).
+#
+# ONE body, two kinds of pool.  A K/V model hands it a K pool and a V pool
+# (``fm_paged_decode``).  An MLA model hands it its ONE latent pool as
+# ``[L, P, 1, page, R]`` (``fm_latent_decode``): a token's row is the key
+# of every head (multi-query attention over R-wide keys, the queries in
+# the absorbed form) and its first ``v_width`` columns are the value, so
+# scores and weighted sums read the same block of VMEM.
 
-#: context positions a block of the paged decode kernel holds
+#: context positions a block of the kernel holds at least (the scores'
+#: lanes), and the bytes of context (all pools) it holds at most: 128
+#: positions of 16 K and V heads of 128, 512 latent rows of 640
 _KV_BLOCK = LANE
+_KV_BLOCK_BYTES = 1024 * 1024
 
-#: VMEM the kernel's two double-buffered context blocks (K and V) may take
+#: VMEM the kernel's double-buffered context blocks may take
 _KV_VMEM_BUDGET = 8 * 1024 * 1024
 
 
-def kv_attention_arm(t: int, page: int, n_kv: int, d: int, dtype) -> str:
-    """The arm :func:`kv_paged_attention` takes for a span of ``t`` rows a
-    slot over a pool of pages ``[n_kv, page, d]``: ``"paged_kernel"``
-    (:func:`paged_decode_attention`) for a span shorter than a page over
-    pages that tile a VMEM block (whole packed tiles of ``dtype``, a
-    divisor of the block, full lanes, four blocks within the budget) on a
-    TPU; ``"gather"`` (:func:`gather_ctx` + :func:`kv_attend`) for
-    everything else: a chunk, the dense cache's one ``T_max``-row page,
-    any other backend.  The engine's records ask the same function."""
-    itemsize = jnp.dtype(dtype).itemsize
-    rows = 32 // itemsize                       # of a packed (rows, 128) tile
-    fits = (t < page and _KV_BLOCK % page == 0 and page % rows == 0
-            and d % LANE == 0
-            and 4 * n_kv * _KV_BLOCK * d * itemsize <= _KV_VMEM_BUDGET)
+
+def _decode_block(n_kv: int, d: int, dtype, pools: int) -> tuple[int, int]:
+    """(context positions a block of the kernel holds, the bytes of one
+    position over all pools): the largest power of two of positions
+    within ``_KV_BLOCK_BYTES``, ``_KV_BLOCK`` at least."""
+    pos_bytes = pools * n_kv * d * jnp.dtype(dtype).itemsize
+    fit = max(_KV_BLOCK, _KV_BLOCK_BYTES // pos_bytes)
+    return 1 << (fit.bit_length() - 1), pos_bytes
+
+
+def kv_attention_arm(t: int, page: int, n_kv: int, d: int, dtype,
+                     pools: int = 2) -> str:
+    """The arm a cached attention layer takes for a span of ``t`` rows a
+    slot over ``pools`` pools of pages ``[n_kv, page, d]`` (a K and a V
+    pool; or ONE latent pool, whose page is ``[1, page, R]``):
+    ``"paged_kernel"`` (:func:`paged_decode_attention`) for a span shorter
+    than a page over pages that tile a VMEM block (whole packed tiles of
+    ``dtype``, a divisor of the block, full lanes, two blocks within the
+    budget) on a TPU; ``"gather"`` (store, gather the context, attend in
+    plain XLA) for everything else: a chunk, the dense cache's one
+    ``T_max``-row page, rows that are no whole lanes, any other backend.
+    :func:`kv_paged_attention`, :func:`mla_paged_attention` and the
+    engine's records ask this one function."""
+    rows = 32 // jnp.dtype(dtype).itemsize      # of a packed (rows, 128) tile
+    block, pos_bytes = _decode_block(n_kv, d, dtype, pools)
+    fits = (t < page and block % page == 0 and page % rows == 0
+            and d % LANE == 0 and 2 * block * pos_bytes <= _KV_VMEM_BUDGET)
     return ("paged_kernel" if fits and jax.default_backend() == "tpu"
             else "gather")
 
 
-def paged_decode_block_pages(page: int, n_tab: int) -> int:
+def paged_decode_block_pages(page: int, n_tab: int, n_kv: int, d: int,
+                             dtype, pools: int = 2) -> int:
     """Pages a block of the kernel reads, under tables ``n_tab`` pages
     wide (a slot's context is read rounded up to this)."""
-    return max(1, min(_KV_BLOCK // page, n_tab))
+    block, _ = _decode_block(n_kv, d, dtype, pools)
+    return max(1, min(block // page, n_tab))
 
 
 def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
-                         q_ref, ks_ref, vs_ref, k_hbm, v_hbm,
-                         o_ref, k_out, v_out,
-                         kbuf, vbuf, wk, wv, sems, wsems, *,
-                         t, rep, page, bp, n_tab, scale):
+                         q_ref, *refs, n_pools, t, rep, page, bp, n_tab,
+                         scale):
     """Grid: (B,), one slot a step.  li_ref: [1] the layer; tab_ref /
     pos_ref / wpage_ref / wrow_ref: the block tables, positions and write
-    targets, flat.  q_ref / o_ref: [1, N_kv, R, D], row
-    ``t * rep + g`` the query of span column t and head ``h * rep + g``;
-    ks_ref / vs_ref: [1, N_kv, Tp, D] the span's rows; k_hbm / v_hbm: the
-    pools in HBM, read through these and written through their aliases
-    k_out / v_out.  kbuf / vbuf:
-    [2, N_kv, bp * page, D]; wk / wv: [2, N_kv, page, D], the one or two
-    pages the span's rows fall into."""
+    targets, flat.  q_ref: [1, N_kv, R, D], row ``t * rep + g`` the query
+    of span column t and head ``h * rep + g``.  ``refs``, ``n_pools`` of
+    each: the span's rows [1, N_kv, Tp, D]; the pools in HBM, read
+    through these; then o_ref [1, N_kv, R, Dv]; the pools' aliases, which
+    the span is written through; the context blocks [2, N_kv, bp * page,
+    D]; the one or two pages the span's rows fall into [2, N_kv, page,
+    D]; and the blocks' and the pages' DMA semaphores.  Keys are the
+    first pool's rows and values the first Dv columns of the last
+    pool's: K and V, or one latent row as both."""
+    n = n_pools
+    spans, hbm = refs[:n], refs[n:2 * n]
+    o_ref, out_hbm = refs[2 * n], refs[2 * n + 1:3 * n + 1]
+    bufs, wbufs = refs[3 * n + 1:4 * n + 1], refs[4 * n + 1:5 * n + 1]
+    sems, wsems = refs[5 * n + 1:]
     b = pl.program_id(0)
     li, pos = li_ref[0], pos_ref[b]
     nkv, r_pad, d = q_ref.shape[1:]
+    dv = o_ref.shape[-1]
     s_blk = bp * page
     n_blocks = (pos + s_blk - 1) // s_blk
 
-    def block_dmas(blk, slot):
-        for j in range(bp):
+    def values(rows):
+        return rows if dv == d else rows[..., :dv]
+
+    def block_dmas(blk, slot, act):
+        """Start, or wait for, the copies of block ``blk``'s pages into
+        buffer ``slot``: a loop over the pages, one turn a page.  Written
+        out (32 copies of a latent block at three places) the kernel was a
+        quarter faster (0.317 against 0.430 ms a call at the longctx
+        cell's shapes) and every process paid for it in ``setup_s`` (+12
+        %: a decode program a context bucket, each traced and lowered)."""
+        def page_j(j, _):
             pid = tab_ref[b * n_tab + jnp.minimum(blk * bp + j, n_tab - 1)]
             rows = pl.ds(j * page, page)
-            yield pltpu.make_async_copy(
-                k_hbm.at[li, pid], kbuf.at[slot, :, rows, :],
-                sems.at[0, slot])
-            yield pltpu.make_async_copy(
-                v_hbm.at[li, pid], vbuf.at[slot, :, rows, :],
-                sems.at[1, slot])
+            for i in range(n):
+                dma = pltpu.make_async_copy(
+                    hbm[i].at[li, pid], bufs[i].at[slot, :, rows, :],
+                    sems.at[i, slot])
+                dma.start() if act == "start" else dma.wait()
+
+        jax.lax.fori_loop(0, bp, page_j, None)
 
     # the page the span starts in and, where the span crosses a page
     # edge, the next: rows the span does not write are kept
@@ -490,8 +579,8 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
     two = page_b != page_a
 
     def page_dmas(page_id, i, *, store):
-        for j, (hbm, buf) in enumerate(((k_out, wk), (v_out, wv))):
-            ends = (buf.at[i], hbm.at[li, page_id])
+        for j in range(n):
+            ends = (wbufs[j].at[i], out_hbm[j].at[li, page_id])
             yield pltpu.make_async_copy(*(ends if store else ends[::-1]),
                                         wsems.at[i, j])
 
@@ -505,8 +594,7 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
 
     @pl.when(n_blocks > 0)
     def _():
-        for dma in block_dmas(0, 0):
-            dma.start()
+        block_dmas(0, 0, "start")
 
     q = q_ref[0]                                            # [N_kv, R, D]
     # products of bf16 operands are exact in f32 as they are; Mosaic
@@ -517,7 +605,7 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
 
     def attend(carry, s, v_rows):
         """One online-softmax step: masked scores s [h, r, c] over the
-        values v_rows [h, c, D]."""
+        values v_rows [h, c, Dv]."""
         m_prev, l_prev, acc = carry
         m = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m)
@@ -531,29 +619,29 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
 
         @pl.when(blk + 1 < n_blocks)
         def _():
-            for dma in block_dmas(blk + 1, 1 - slot):
-                dma.start()
+            block_dmas(blk + 1, 1 - slot, "start")
 
-        for dma in block_dmas(blk, slot):
-            dma.wait()
-        s = jnp.einsum("hrd,hcd->hrc", q, kbuf[slot], **f32) * scale
+        block_dmas(blk, slot, "wait")
+        s = jnp.einsum("hrd,hcd->hrc", q, bufs[0][slot], **f32) * scale
         col = blk * s_blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        return attend(carry, jnp.where(col < pos, s, NEG_INF), vbuf[slot])
+        return attend(carry, jnp.where(col < pos, s, NEG_INF),
+                      values(bufs[-1][slot]))
 
     carry = jax.lax.fori_loop(
         0, n_blocks, body,
         (jnp.full((nkv, r_pad, 1), NEG_INF, jnp.float32),
          jnp.zeros((nkv, r_pad, 1), jnp.float32),
-         jnp.zeros((nkv, r_pad, d), jnp.float32)))
+         jnp.zeros((nkv, r_pad, dv), jnp.float32)))
 
     # the span's own rows, causal among themselves: query row r (column
     # r // rep of the span) sees span row c iff c * rep <= r
-    ks, vs = ks_ref[0], vs_ref[0]                           # [N_kv, Tp, D]
-    s = jnp.einsum("hrd,hcd->hrc", q, ks, **f32) * scale
+    new = [span[0] for span in spans]                       # [N_kv, Tp, D]
+    s = jnp.einsum("hrd,hcd->hrc", q, new[0], **f32) * scale
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
     _, l, acc = attend(
-        carry, jnp.where((col * rep <= row) & (col < t), s, NEG_INF), vs)
+        carry, jnp.where((col * rep <= row) & (col < t), s, NEG_INF),
+        values(new[-1]))
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
     # the span's rows into their pages
@@ -565,13 +653,13 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
         for dma in page_dmas(page_b, 1, store=False):
             dma.wait()
 
-    at = jax.lax.broadcasted_iota(jnp.int32, wk.shape[1:], 1)
+    at = jax.lax.broadcasted_iota(jnp.int32, wbufs[0].shape[1:], 1)
     for c in range(t):
         which = (wpage_ref[b * t + c] != page_a).astype(jnp.int32)
         here = at == wrow_ref[b * t + c]
-        for buf, new in ((wk, ks), (wv, vs)):
+        for buf, rows in zip(wbufs, new):
             buf[which] = jnp.where(
-                here, new[:, c:c + 1, :].astype(jnp.float32),
+                here, rows[:, c:c + 1, :].astype(jnp.float32),
                 buf[which].astype(jnp.float32)).astype(buf.dtype)
     for dma in page_dmas(page_a, 0, store=True):
         dma.start()
@@ -587,8 +675,11 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
         dma.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("block_pages", "interpret"))
-def paged_decode_attention(q, k, v, pools, li, block_tables, pos, write, *,
+@functools.partial(jax.jit, static_argnames=("v_width", "scale",
+                                             "block_pages", "interpret"))
+def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
+                           v_width: int | None = None,
+                           scale: float | None = None,
                            block_pages: int | None = None,
                            interpret: bool = False):
     """Causal attention of a short span a slot over the slot's own pages,
@@ -597,20 +688,29 @@ def paged_decode_attention(q, k, v, pools, li, block_tables, pos, write, *,
     traced and lowered kernel (lowered once a layer, the kernels were a
     second of every decode program's set-up, cache or no cache).
 
-    q: [B, T, N, D]; k / v: [B, T, N_kv, D] the span's rows (T < page);
-    pools: ``(k_pages, v_pages)``, each [L, P, N_kv, page, D]; block_tables:
-    [B, n]; pos: [B] the position of each slot's first span row, the pool
-    holding positions before it; write: ``(page_ids, rows)``, each [B, T],
-    where the span's rows go (consecutive rows: at most two pages a slot).
-    Returns (the heads' outputs [B, T, N * D], the pools).  What
-    :func:`store_kv`, :func:`gather_ctx` and the softmax of
-    :func:`kv_attend` give, with f32 scores, statistics and accumulator,
-    the probabilities rounded to the pool's dtype before the PV product."""
+    q: [B, T, N, D]; span: the span's rows, one [B, T, N_kv, D] a pool
+    (T < page); pools: a ``(k_pages, v_pages)`` pair, or ONE pool whose
+    rows are keys and, in their first ``v_width`` columns, values (an MLA
+    model's latent pool: N_kv = 1, ``q`` in the absorbed form), each
+    [L, P, N_kv, page, D]; block_tables: [B, n]; pos: [B] the position of
+    each slot's first span row, the pool holding positions before it;
+    write: ``(page_ids, rows)``, each [B, T], where the span's rows go
+    (consecutive rows: at most two pages a slot); scale: of the scores,
+    ``D ** -0.5`` unless given.  Returns (the heads' outputs
+    [B, T, N * Dv], the pools).  What :func:`store_kv`,
+    :func:`gather_ctx` and the softmax of :func:`kv_attend` give (or
+    :func:`store_latent`, :func:`gather_latent` and the absorbed
+    :func:`mla_attend` up to the latent sums), with f32 scores, statistics
+    and accumulator, the probabilities rounded to the pool's dtype before
+    the product with the values."""
     b, t, nh, d = q.shape
-    nkv, page = k.shape[2], pools[0].shape[3]
+    n_pools, dt = len(pools), pools[0].dtype
+    nkv, page = pools[0].shape[2:4]
+    dv = v_width or d
     rep = nh // nkv
     n_tab = block_tables.shape[1]
-    bp = block_pages or paged_decode_block_pages(page, n_tab)
+    bp = block_pages or paged_decode_block_pages(page, n_tab, nkv, d, dt,
+                                                 n_pools)
     tile = 32 // q.dtype.itemsize
     r_pad = -(-t * rep // tile) * tile
     t_pad = -(-t // tile) * tile
@@ -619,44 +719,38 @@ def paged_decode_attention(q, k, v, pools, li, block_tables, pos, write, *,
         b, nkv, t * rep, d)
     qh = jnp.pad(qh, ((0, 0), (0, 0), (0, r_pad - t * rep), (0, 0)))
     span = [jnp.pad(x.transpose(0, 2, 1, 3),
-                    ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
-            for x in (k, v)]
-    slot_block = lambda rows: pl.BlockSpec(
-        (1, nkv, rows, d), lambda i, *_: (i, 0, 0, 0),
+                    ((0, 0), (0, 0), (0, t_pad - t), (0, 0))).astype(dt)
+            for x in span]
+    slot_block = lambda rows, width: pl.BlockSpec(
+        (1, nkv, rows, width), lambda i, *_: (i, 0, 0, 0),
         memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    dt = pools[0].dtype
-    out, k_pages, v_pages = pl.pallas_call(
+    out, *pools = pl.pallas_call(
         functools.partial(
-            _paged_decode_kernel, t=t, rep=rep, page=page, bp=bp,
-            n_tab=n_tab, scale=d ** -0.5),
-        name="fm_paged_decode",
+            _paged_decode_kernel, n_pools=n_pools, t=t, rep=rep, page=page,
+            bp=bp, n_tab=n_tab, scale=scale or d ** -0.5),
+        name="fm_paged_decode" if n_pools == 2 else "fm_latent_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(b,),
-            in_specs=[slot_block(r_pad), slot_block(t_pad),
-                      slot_block(t_pad), hbm, hbm],
-            out_specs=[slot_block(r_pad), hbm, hbm],
-            scratch_shapes=[
-                pltpu.VMEM((2, nkv, bp * page, d), dt),
-                pltpu.VMEM((2, nkv, bp * page, d), dt),
-                pltpu.VMEM((2, nkv, page, d), dt),
-                pltpu.VMEM((2, nkv, page, d), dt),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.SemaphoreType.DMA((2, 2)),
-            ]),
-        out_shape=[jax.ShapeDtypeStruct(qh.shape, q.dtype),
-                   jax.ShapeDtypeStruct(pools[0].shape, dt),
-                   jax.ShapeDtypeStruct(pools[1].shape, dt)],
-        # operands: 5 scalar vectors, q, k, v, then the pools
-        input_output_aliases={8: 1, 9: 2},
+            in_specs=[slot_block(r_pad, d)]
+            + [slot_block(t_pad, d)] * n_pools + [hbm] * n_pools,
+            out_specs=[slot_block(r_pad, dv)] + [hbm] * n_pools,
+            scratch_shapes=[pltpu.VMEM((2, nkv, bp * page, d), dt)] * n_pools
+            + [pltpu.VMEM((2, nkv, page, d), dt)] * n_pools
+            + [pltpu.SemaphoreType.DMA((n_pools, 2)),
+               pltpu.SemaphoreType.DMA((2, n_pools))]),
+        out_shape=[jax.ShapeDtypeStruct((b, nkv, r_pad, dv), q.dtype)]
+        + [jax.ShapeDtypeStruct(pool.shape, dt) for pool in pools],
+        # operands: 5 scalar vectors, q, the span's rows, then the pools
+        input_output_aliases={6 + n_pools + i: 1 + i
+                              for i in range(n_pools)},
         interpret=interpret,
     )(jnp.asarray(li, jnp.int32).reshape(1), block_tables.reshape(-1), pos,
-      write[0].reshape(-1), write[1].reshape(-1), qh, span[0].astype(dt),
-      span[1].astype(dt), *pools)
-    out = out[:, :, :t * rep].reshape(b, nkv, t, rep, d).transpose(
-        0, 2, 1, 3, 4).reshape(b, t, nh * d)
-    return out, (k_pages, v_pages)
+      write[0].reshape(-1), write[1].reshape(-1), qh, *span, *pools)
+    out = out[:, :, :t * rep].reshape(b, nkv, t, rep, dv).transpose(
+        0, 2, 1, 3, 4).reshape(b, t, nh * dv)
+    return out, tuple(pools)
 
 
 # ----------------------------------------------------------------------

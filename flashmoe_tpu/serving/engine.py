@@ -60,7 +60,7 @@ from flashmoe_tpu.models.generate import (
 from flashmoe_tpu.ops import attention
 from flashmoe_tpu.serving.kvcache import (
     SCRATCH_PAGE, PagedKVCache, PagePool, ShardedPagePool,
-    ctx_pages_bucket, init_paged_cache, page_size_of, prompt_pad,
+    ctx_pages_bucket, init_paged_cache, prompt_pad,
     slot_state_fields, store_prefill, store_state,
 )
 from flashmoe_tpu.serving.speculate import (
@@ -328,7 +328,7 @@ def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
     context ceiling) route their writes to the scratch page and produce
     garbage columns the host never reads — the host truncates drafts to
     fit, this is the in-graph belt-and-suspenders."""
-    page = page_size_of(pools, cfg)
+    page = pools.page_size
     ntab = block_tables.shape[1]
     pos = (positions[:, None]
            + jnp.arange(toks.shape[1], dtype=jnp.int32)[None, :])  # [B, T]
@@ -798,7 +798,7 @@ class ServingEngine:
                                self.quant_info["freed_bytes"] / 2**20)
         # bytes one cached token costs over all layers (a gauge, and on
         # every serve_step record): what the pool's pages are made of
-        self.metrics.gauge("serve.kv_token_bytes", cfg.kv_token_bytes)
+        self.metrics.gauge("serve.kv_token_bytes", cfg.kv_pool_token_bytes)
         self.queue: deque = deque()       # (arrival_step, _Slot-seed)
         self.slots: list[_Slot | None] = [None] * self.serve.max_batch
         self._logits = jnp.zeros(
@@ -1567,14 +1567,12 @@ class ServingEngine:
         lengths = np.asarray(lengths)
         span_pages = (lengths + t_span - 1) // page - lengths // page + 1
         own = lengths // page + span_pages
-        arm = "gather"
-        if self.cfg.attention_kind != "mla":
-            arm = attention.kv_attention_arm(
-                t_span, page, self.cfg.resolved_num_kv_heads,
-                self.cfg.resolved_head_dim, self.cfg.dtype)
+        pools, heads, row = self.cfg.kv_pool_rows
+        pool = (heads, row, self.cfg.dtype, pools)
+        arm = attention.kv_attention_arm(t_span, page, *pool)
         read = n_ctx
         if arm == "paged_kernel":
-            block = attention.paged_decode_block_pages(page, n_ctx)
+            block = attention.paged_decode_block_pages(page, n_ctx, *pool)
             read = round(float(np.mean(
                 -(-lengths // (block * page)) * block + span_pages)), 3)
         self._ctx_pages = (read, max(0.0, read - float(own.mean())),
@@ -1781,7 +1779,7 @@ class ServingEngine:
             "compile_ms": round((compile_s1 - compile_s0) * 1e3, 3),
             "ctx_pages": ctx_pages,
             "ctx_pages_idle": round(ctx_idle, 3),
-            "kv_token_bytes": self.cfg.kv_token_bytes,
+            "kv_token_bytes": self.cfg.kv_pool_token_bytes,
             "sample_rows": sample_rows, "sample_drawn": sample_drawn,
             "sample_sorted": sample_sorted,
         }

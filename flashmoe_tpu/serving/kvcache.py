@@ -65,14 +65,21 @@ class PagedKVCache(NamedTuple):
 
 class LatentPagedCache(NamedTuple):
     """The page pool of an MLA model (``cfg.attention_kind == 'mla'``):
-    ONE array ``[L, P, page * C]``, C = kv_lora_rank + qk_rope_head_dim,
-    holding a token a layer the normed latent beside the roped shared
-    key, and nothing per head.  A page's ``page`` rows lie side by side
-    in the last axis (row r at columns r*C .. r*C+C-1): as
-    ``[L, P, page, C]`` the chip would keep the array pages-minor (C =
-    576 is no multiple of its 128 lanes) and every program would copy
-    the whole pool into gather order and back.  Pages, block tables, the
-    scratch page and the allocators below are :class:`PagedKVCache`'s."""
+    ONE array ``[L, P, page, R]`` holding a token a layer ONE row, the
+    normed latent beside the roped shared key (C = kv_lora_rank +
+    qk_rope_head_dim elements, nothing per head), padded with zeros to
+    R = ``cfg.kv_row_elems``: C rounded up to whole lanes (576 -> 640,
+    11 % more pool).  The padding is what makes a page a thing a DMA can
+    take: ``[page, R]`` is whole ``(8, 128)`` tiles, one contiguous block
+    of HBM, and the chip keeps the array row-major as declared, so the
+    decode kernel (``ops/attention.fm_latent_decode``) reads and writes a
+    slot's pages in place and no program copies the pool.  The two
+    layouts this replaces: ``[.., page, 576]`` (4.5 lanes: the chip kept
+    the array pages-minor and every program copied the whole pool into
+    gather order and back, PR 27) and ``[.., page * 576]`` (no copy, but
+    the page index second-minor: sixteen pages interleave in one tile and
+    no DMA can take one).  Pages, block tables, the scratch page and the
+    allocators below are :class:`PagedKVCache`'s."""
 
     pages: jax.Array
 
@@ -80,13 +87,18 @@ class LatentPagedCache(NamedTuple):
     def num_pages(self) -> int:
         return self.pages.shape[1]
 
+    @property
+    def page_size(self) -> int:
+        return self.pages.shape[2]
+
 
 class HybridCache(NamedTuple):
     """The cache of a model whose layers differ in kind: two kinds of
     state side by side.  ``pages``: the latent pool of the layers that
-    cache rows (``cfg.cache_layers``: ``[L_c, P, page * C]``,
-    :class:`LatentPagedCache`'s array, addressed by PAGE through the block
-    tables).  ``state`` / ``conv``: what the 'kda' layers
+    cache rows (``cfg.cache_layers``: ``[L_c, P, page, R]``,
+    :class:`LatentPagedCache`'s array in its layout and for its reasons,
+    addressed by PAGE through the block tables and read in place by the
+    same decode kernel).  ``state`` / ``conv``: what the 'kda' layers
     (``cfg.state_layers``) keep of a request whatever its length,
     addressed by SLOT: the float32 delta-rule state ``[L_s, slots, N, D,
     D]`` and the convolution's last inputs ``[L_s, slots, (K - 1) * 3 N
@@ -109,6 +121,10 @@ class HybridCache(NamedTuple):
     def num_pages(self) -> int:
         return self.pages.shape[1]
 
+    @property
+    def page_size(self) -> int:
+        return self.pages.shape[2]
+
 
 def cache_arrays(cfg: MoEConfig, num_pages: int, page_size: int,
                  slots: int) -> tuple:
@@ -118,7 +134,7 @@ def cache_arrays(cfg: MoEConfig, num_pages: int, page_size: int,
     n_cache = len(cfg.cache_layers)
     if cfg.attention_kind == "mla":
         paged = (jnp.zeros(
-            (n_cache, num_pages, page_size * cfg.kv_token_elems),
+            (n_cache, num_pages, page_size, cfg.kv_pool_rows[2]),
             cfg.dtype),)
     else:
         nkv, dh = cfg.resolved_num_kv_heads, cfg.resolved_head_dim
@@ -169,13 +185,6 @@ def store_state(state, final, slot):
     return state.at[:, slot].set(final.astype(state.dtype))
 
 
-def page_size_of(pools, cfg: MoEConfig) -> int:
-    """Tokens a page of ``pools`` (any cache class) holds."""
-    if isinstance(pools, (LatentPagedCache, HybridCache)):
-        return pools.pages.shape[2] // cfg.kv_token_elems
-    return pools.page_size
-
-
 # ----------------------------------------------------------------------
 # Whole-run page write (the engine's admission; the per-span store and the
 # gather of the jitted steps are ops/attention.py's store_kv / gather_ctx)
@@ -190,13 +199,17 @@ def store_prefill(pages, seq_kv, page_ids):
     Positions past the true prompt length write garbage rows the
     length mask never exposes."""
     n = page_ids.shape[0]
-    if pages.ndim == 3:
-        # a latent pool [L, P, page * C] takes rows [L, T_pad, C]
+    if pages.ndim == 4:
+        # a latent pool [L, P, page, R] takes rows [L, T_pad, C], padded
+        # to the pool's row
         l, t_pad, c = seq_kv.shape
-        if t_pad * c != n * pages.shape[2]:
+        page, r = pages.shape[2:]
+        if t_pad != n * page:
             raise ValueError(f"prefill run of {t_pad} rows does not fill "
-                             f"{n} pages of {pages.shape[2] // c}")
-        return pages.at[:, page_ids].set(seq_kv.reshape(l, n, -1))
+                             f"{n} pages of {page}")
+        rows = jnp.pad(seq_kv, ((0, 0), (0, 0), (0, r - c)))
+        return pages.at[:, page_ids].set(
+            rows.reshape(l, n, page, r).astype(pages.dtype))
     l, nkv, t_pad, d = seq_kv.shape
     page = pages.shape[3]
     if t_pad != n * page:

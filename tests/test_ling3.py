@@ -496,8 +496,10 @@ def test_records_and_names(params):
                            and 0 <= d["held_rows"] <= 3 * 3
                            for d in decodes)
     assert max(d["held_rows"] for d in decodes) > 0
-    # one latent row a token, in the ONE layer that caches
-    assert steps[0]["kv_token_bytes"] == CFG.kv_token_bytes == 26 * 4
+    # one latent row a token, in the ONE layer that caches, as the pool
+    # stores it (26 elements in 128 lanes)
+    assert CFG.kv_token_bytes == 26 * 4
+    assert steps[0]["kv_token_bytes"] == CFG.kv_pool_token_bytes == 128 * 4
     # the first step: a whole prefill writes a slot, a chunk reads and
     # writes one, the decode step all three
     assert steps[0]["state_bytes"] == slot + 2 * slot + 2 * 3 * slot

@@ -155,7 +155,7 @@ def test_absorbed_form_equals_the_prefill_form_on_one_cache(params, t_span):
     layer, x = params["layers"][2], _x(40, seed=2)
     pos = jnp.arange(40)[None, :]
     page, n_pages = 8, 6
-    pool = jnp.zeros((2, n_pages + 1, page * 26), jnp.float32)
+    pool = jnp.zeros((2, n_pages + 1, page, CFG.kv_row_elems), jnp.float32)
     table = jnp.asarray([[3, 1, 6, 2, 5, 4]])           # scattered pages
     ids = table[0][pos[0] // page][None, :]
     _, pool, _ = att.mla_paged_attention(
@@ -480,12 +480,15 @@ def test_pool_shape_and_bytes_a_token():
     cut = PRESETS["joyai-llm-flash"](num_layers=5)
     shape = jax.eval_shape(lambda: init_paged_cache(cut, 16384, 16))
     assert isinstance(shape, LatentPagedCache) and len(shape) == 1
-    assert shape.pages.shape == (5, 16384, 16 * 576)
+    # a row is stored padded to whole lanes: a page is whole tiles
+    assert shape.pages.shape == (5, 16384, 16, 640)
     assert shape.pages.dtype == jnp.bfloat16 and shape.num_pages == 16384
     assert cut.kv_token_elems == 576 and cut.kv_token_bytes == 5760
-    assert shape.pages.size * 2 == 16384 * 16 * 5760          # 1.51 GB
+    assert cut.kv_row_elems == 640 and cut.kv_pool_token_bytes == 6400
+    assert shape.pages.size * 2 == 16384 * 16 * 6400          # 1.68 GB
     ds = PRESETS["deepseek-moe-16b"](num_layers=6)
     assert ds.kv_token_elems == 2 * 16 * 128 and ds.kv_token_bytes == 49152
+    assert ds.kv_row_elems == 4096 and ds.kv_pool_token_bytes == 49152
     pair = jax.eval_shape(lambda: init_paged_cache(ds, 2048, 16))
     assert isinstance(pair, PagedKVCache)
     assert pair.k_pages.shape == (6, 2048, 16, 16, 128)
@@ -613,7 +616,9 @@ def test_kv_token_bytes_is_on_the_records_and_the_gauge(params):
                            metrics_obj=mx)
     engine.run([Request(rid=0, prompt=(5, 6, 7), max_new_tokens=3)])
     steps = [r for r in rec.records if r["kind"] == "serve_step"]
-    want = 3 * 26 * 4                       # layers x elements x float32
+    # layers x elements as the pool stores them (26 in 128 lanes) x float32
+    want = 3 * 128 * 4
+    assert CFG.kv_pool_token_bytes == want and CFG.kv_token_bytes == 3 * 26 * 4
     assert steps and all(r["kv_token_bytes"] == want for r in steps)
     assert [r for r in rec.records if r["kind"] == "serve_decode"]
     assert mx.gauges["serve.kv_token_bytes"] == want

@@ -1253,7 +1253,7 @@ def _parent_span_step(params, cfg, pools, toks, block_tables, positions,
     from flashmoe_tpu.models.generate import lm_logits, lm_logits_span
     from flashmoe_tpu.models.transformer import _rope, rms_norm
     from flashmoe_tpu.ops.moe import moe_layer
-    from flashmoe_tpu.serving.kvcache import PagedKVCache, page_size_of
+    from flashmoe_tpu.serving.kvcache import PagedKVCache
 
     def store_tokens(pages, span_kv, page_ids, rows):
         return pages.at[page_ids, :, rows, :].set(span_kv)
@@ -1265,7 +1265,7 @@ def _parent_span_step(params, cfg, pools, toks, block_tables, positions,
         return g.transpose(0, 2, 1, 3, 4).reshape(b, nkv, n * page, d)
 
     b, t_span = toks.shape
-    page = page_size_of(pools, cfg)
+    page = pools.page_size
     ntab = block_tables.shape[1]
     n_ctx = ntab * page
     x = params["embed"].astype(cfg.dtype)[toks]              # [B, T, H]

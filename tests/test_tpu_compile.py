@@ -342,11 +342,13 @@ def test_sampler_program_keeps_its_sort_behind_a_conditional(one_chip, v):
 
 @pytest.fixture(scope="module")
 def mla_programs(one_chip):
-    """The benchmark cell's largest decode and prefill-chunk programs
-    (``joyai_flash``: the leading dense layer + 4 mixture layers in bf16,
-    32 slots, a 16384 x 16-token latent pool, tables at their 448 pages,
-    a 1024-token chunk), lowered as the engine runs them: the pool
-    donated."""
+    """The benchmark cell's largest decode, verify and prefill-chunk
+    programs (``joyai_flash``: the leading dense layer + 4 mixture layers
+    in bf16, 32 slots, a 16384 x 16-token latent pool, tables at their 448
+    pages, a span of 5, a 1024-token chunk), lowered as the engine runs
+    them on the chip: the pool donated, and traced as on a TPU (the
+    attention picks its arm from the backend, and nothing is attached
+    here)."""
     from flashmoe_tpu.models.presets import PRESETS
     from flashmoe_tpu.models.transformer import init_params
     from flashmoe_tpu.serving import engine as eng
@@ -360,12 +362,16 @@ def mla_programs(one_chip):
         lambda: init_params(jax.random.PRNGKey(0), cfg)))
     cache = on(jax.eval_shape(lambda: init_paged_cache(cfg, 16384, 16)))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32, sharding=one_chip)
-    return {
-        "decode": eng._INPLACE["_paged_decode_step"].lower(
-            params, cfg, cache, i32(32), i32(32, 448), i32(32)),
-        "chunk": eng._INPLACE["_prefill_chunk"].lower(
-            params, cfg, cache, i32(1, 1024), i32(448), i32(64), i32(),
-            i32())}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return {
+            "decode": eng._INPLACE["_paged_decode_step"].lower(
+                params, cfg, cache, i32(32), i32(32, 448), i32(32)),
+            "verify": eng._INPLACE["_paged_verify_step"].lower(
+                params, cfg, cache, i32(32, 5), i32(32, 448), i32(32)),
+            "chunk": eng._INPLACE["_prefill_chunk"].lower(
+                params, cfg, cache, i32(1, 1024), i32(448), i32(64), i32(),
+                i32())}
 
 
 @pytest.fixture(scope="module")
@@ -374,11 +380,29 @@ def mla_decode_compiled(mla_programs):
     return compiled, compiled.as_text()
 
 
+#: a whole latent pool of either MLA cell, as the programs hold it (the
+#: kernel's operand has a unit axis of heads) and in any layout
+_LATENT_POOL = r"bf16\[(?:5,16384|1,40960),(?:1,)?16,640\]"
+
+
+def _fm_kernels(text):
+    """The repo's own kernels in a compiled program, by name (XLA's
+    grouped matmul is a custom call too)."""
+    return [n.split(".")[0] for n, _ in _custom_call_names(text)
+            if n.startswith("fm_")]
+
+
+def _latent_pool_copies(text):
+    return re.findall(rf"^.*= {_LATENT_POOL}\S* copy\(.*$", text, re.M)
+
+
 def test_mla_prefill_chunk_fits_and_computes_the_routed_rows(mla_programs):
     """A 1024-token chunk at the widest context: the experts are XLA's
     grouped matmul over the 8192 routed rows (three a mixture layer), no
-    [256, 1024, .] capacity buffer, and under 14.5 GB (13.63 as compiled;
-    the E x S arm took 16.16)."""
+    [256, 1024, .] capacity buffer, and under 14.5 GB (13.80 as compiled
+    on the pool of 640-wide rows, 13.63 on 576; the E x S arm took
+    16.16).  A chunk is no short span: it keeps the gather arm (whole
+    pages scattered, the slot's pages gathered) and copies no pool."""
     compiled = mla_programs["chunk"].compile()
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -387,6 +411,10 @@ def test_mla_prefill_chunk_fits_and_computes_the_routed_rows(mla_programs):
     text = compiled.as_text()
     assert text.count("ragged-dot-metadata = ") >= 1
     assert "[8192,768]" in text and "[256,1024," not in text
+    assert _fm_kernels(text) == [] and " scatter(" in text
+    assert "bf16[5,16384,16,640]{3,2,1,0" in text
+    assert _latent_pool_copies(text) == []
+    assert "attn.mla_prefill" in text and "attn.mla_decode" not in text
 
 
 def test_mla_decode_step_fits_the_chip_in_place(mla_decode_compiled):
@@ -394,27 +422,43 @@ def test_mla_decode_step_fits_the_chip_in_place(mla_decode_compiled):
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    # 11.12 GB of weights + the 1.51 GB pool, once (donated: aliased to
-    # the output), + under 1.5 GB of temporaries: 13.83 GB as compiled
-    assert m.alias_size_in_bytes >= 5 * 16384 * 16 * 576 * 2
-    assert 12.6e9 < total < 14.5e9
-    # the pool arrives and leaves in gather order: no whole-pool copy
-    assert "bf16[5,16384,9216]{2,1,0" in text
-    import re
-    assert not re.search(r"bf16\[5,16384,9216\]\S* copy\(", text)
+    # 11.12 GB of weights + the 1.68 GB pool, once (donated: aliased to
+    # the output), + 0.02 GB of temporaries: 12.83 GB as compiled (13.225
+    # with a gathered context a layer)
+    assert m.alias_size_in_bytes >= 5 * 16384 * 16 * 640 * 2
+    assert 12.6e9 < total < 13.0e9
+    # the pool arrives and leaves row-major as declared, a page one
+    # contiguous block of tiles: no whole-pool copy
+    assert "bf16[5,16384,16,640]{3,2,1,0" in text
+    assert _latent_pool_copies(text) == []
 
 
-def test_mla_decode_step_reads_latent_rows_only(mla_decode_compiled):
+@pytest.mark.parametrize("program", ["decode", "verify"])
+def test_mla_decode_step_reads_latent_rows_only(mla_programs,
+                                                mla_decode_compiled,
+                                                program):
     """No K or V of the whole context ([.., 32 heads, 7168, 128] in any
-    order) in the program the chip runs: the absorbed form."""
-    import re
-    _, text = mla_decode_compiled
+    order) in the program the chip runs: the absorbed form.  And no
+    context at all: the decode step (T = 1) and the verify step (T = 5)
+    read each slot's latent pages in place, Mosaic compiles
+    ``fm_latent_decode`` at the cell's shapes (a 57 kB table as scalars,
+    blocks of 32 pages), the ONE pool goes through every layer's call,
+    and no array has the gathered context's element count in either
+    row width."""
+    text = (mla_decode_compiled[1] if program == "decode"
+            else mla_programs[program].compile().as_text())
     shapes = set(re.findall(r"(?:bf16|f32)\[([0-9,]+)\]", text))
     big = [s for s in shapes
            if {"7168", "128"} <= set(s.split(","))
            and s.split(",").count("32") >= 2]
     assert big == []
-    assert "bf16[32,7168,576]" in text          # the gathered latent rows
+    assert _fm_kernels(text) == ["fm_latent_decode"] * 5
+    for width in (576, 640):
+        assert _arrays_of(text, 32, 7168, width) == []
+        assert _arrays_of(text, 32, 448, 16, width) == []
+        assert _arrays_of(text, 14336, 16 * width) == []
+    assert " scatter(" not in text                  # the kernel stores
+    assert _latent_pool_copies(text) == []
     assert "attn.mla_decode" in text and "moe.gate" in text
 
 
@@ -538,40 +582,54 @@ def hybrid_programs(one_chip):
         lambda: init_params(jax.random.PRNGKey(0), cfg)))
     cache = on(jax.eval_shape(lambda: init_paged_cache(cfg, 40960, 16, 64)))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32, sharding=one_chip)
-    return {
-        "decode": eng._INPLACE["_paged_decode_step"].lower(
-            params, cfg, cache, i32(64), i32(64, 640), i32(64)),
-        "chunk": eng._INPLACE["_prefill_chunk"].lower(
-            params, cfg, cache, i32(1, 1024), i32(640), i32(64), i32(),
-            i32(), i32())}
+    with pytest.MonkeyPatch.context() as mp:        # traced as on a TPU
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return {
+            "decode": eng._INPLACE["_paged_decode_step"].lower(
+                params, cfg, cache, i32(64), i32(64, 640), i32(64)),
+            "chunk": eng._INPLACE["_prefill_chunk"].lower(
+                params, cfg, cache, i32(1, 1024), i32(640), i32(64), i32(),
+                i32(), i32())}
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_hybrid_programs_fit_the_chip_with_state_and_pool_in_place(
         hybrid_programs, program):
-    """13.57 GB (decode) and 13.37 GB (chunk) as compiled, under the
-    cell's 15.0: 10.34 GB of weights, and the latent pool (0.755 GB), the
-    float32 state (0.805 GB) and the convolution's inputs once each,
+    """12.07 GB (decode; 13.57 with the one latent layer's gathered
+    context) and 13.45 GB (chunk) as compiled, under the cell's 15.0:
+    10.34 GB of weights, and the latent pool (0.84 GB of 640-wide rows),
+    the float32 state (0.805 GB) and the convolution's inputs once each,
     aliased to the outputs; no copy of the state or of the pool; the
     experts are XLA's grouped matmul over the routed rows against the 128
-    experts held; the decode program is one recurrence step a 'kda' layer
-    and hands back what it counted."""
+    experts held; the decode program is one recurrence step a 'kda'
+    layer, reads the latent layer's pages in place (ONE
+    ``fm_latent_decode``, a 164 kB table as scalars, no gathered context)
+    and hands back what it counted; the chunk keeps the gather arm."""
     compiled = hybrid_programs[program].compile()
     text = compiled.as_text()
-    cache_bytes = (40960 * 16 * 576 * 2 + 6 * 64 * 32 * 128 * 128 * 4
+    cache_bytes = (40960 * 16 * 640 * 2 + 6 * 64 * 32 * 128 * 128 * 4
                    + 6 * 64 * 3 * 12288 * 2)
     assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
-    assert 12.5e9 < _program_bytes(compiled) < 15.0e9
-    for shape in (r"f32\[6,64,32,128,128\]", r"bf16\[1,40960,9216\]",
+    lo, hi = (11.8e9, 12.4e9) if program == "decode" else (12.5e9, 15.0e9)
+    assert lo < _program_bytes(compiled) < hi
+    for shape in (r"f32\[6,64,32,128,128\]", r"bf16\[1,40960,16,640\]",
                   r"bf16\[6,64,36864\]"):
         assert re.search(shape, text)
         assert not re.findall(rf"^.*= {shape}\S* copy\(.*$", text, re.M)
+    assert _latent_pool_copies(text) == []
     assert text.count("ragged-dot-metadata = ") >= 1
     assert "[128,2560,768]" in text and "[512,2560,768]" not in text
     assert "moe.route_groups" in text        # and plain XLA throughout
-    assert not [n for n, _ in _custom_call_names(text) if "fm_" in n]
+    kernels = _fm_kernels(text)
     if program == "decode":
+        assert kernels == ["fm_latent_decode"]
+        for width in (576, 640):
+            assert _arrays_of(text, 64, 10240, width) == []
+            assert _arrays_of(text, 64, 640, 16, width) == []
+            assert _arrays_of(text, 40960, 16 * width) == []
+        assert " scatter(" not in text
         assert "attn.kda_decode" in text and "attn.mla_decode" in text
         assert len(jax.tree.leaves(compiled.out_info)) == 1 + 3 + 1
     else:
+        assert kernels == []
         assert "attn.kda_prefill" in text and "attn.mla_prefill" in text
