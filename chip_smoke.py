@@ -513,7 +513,9 @@ def phase_serve_hybrid(seed):
     layer), one chip's quarter of the experts and of the vocabulary;
     per-slot delta-rule state beside a latent pool of ONE layer, whole
     and chunked prefill (the prompt of 33 crosses a 16-token chunk), four
-    slots.  Against ``generate()`` and its logits, as :func:`phase_serve`."""
+    slots.  Against ``generate()`` and its logits, as :func:`phase_serve`.
+    Then the other pairing: LFM2-24B-A2B's widths, per-slot convolution
+    inputs beside a K/V pool of 64-wide heads."""
     import jax
     import jax.numpy as jnp
 
@@ -546,6 +548,27 @@ def phase_serve_hybrid(seed):
     dense = cfg.replace(first_k_dense=cfg.num_layers)
     ok &= _serve_case(init_params(jax.random.PRNGKey(seed), dense), dense,
                       serve, seed, cut + "; every layer dense (no router)",
+                      {"phase_of": "serve_hybrid"})
+    gc.collect()
+    ok &= _serve_case(params, cfg, serve, seed, cut,
+                      {"phase_of": "serve_hybrid"})
+    del params
+    gc.collect()
+    # the other pairing of the hybrid cache: LFM2-24B-A2B's widths, three
+    # layers (a dense 'conv' layer, a mixture attention layer with q/k
+    # norm over 8 K/V heads of 64, a mixture 'conv' layer), every expert:
+    # per-slot convolution inputs beside a K/V pool whose rows hold two
+    # heads; the decode kernel handed those rows against the gather arm
+    cfg = PRESETS["lfm2-24b-a2b"](
+        num_layers=3, first_k_dense=1, layer_mixers=("conv", "mha", "conv"))
+    params = init_params(jax.random.PRNGKey(seed), cfg)
+    cut = ("LFM2-24B-A2B, num_layers 40 -> 3 (layers 0, 2 and 3: dense "
+           "conv, mixture attention, mixture conv), 64 experts and the "
+           "whole vocabulary: f32 weights "
+           f"{sum(a.nbytes for a in jax.tree.leaves(params)) / 2**30:.2f} "
+           "GiB")
+    ok &= _serve_case(params, cfg.replace(dtype=jnp.float32), serve, seed,
+                      cut + "; dtype bf16 -> f32, matmul precision highest",
                       {"phase_of": "serve_hybrid"})
     gc.collect()
     ok &= _serve_case(params, cfg, serve, seed, cut,

@@ -33,6 +33,10 @@ BLOCK_M = 128
 BLOCK_N = 128
 LANE = 128
 
+#: the mixers that keep a constant-size state a SLOT and cache nothing a
+#: token (``MoEConfig.slot_state`` says what each keeps)
+STATE_MIXERS = ("kda", "conv")
+
 
 class Activation:
     """Activation selector, mirroring ``hidden_act`` (0=relu / 1=gelu) in
@@ -141,17 +145,26 @@ class MoEConfig:
     # inside them.  One group is the plain rule, bit for bit.
     n_group: int = 1
     topk_group: int = 1
-    # the token mixer PER LAYER: "mha", "mla" or "kda" (Kimi delta
+    # the token mixer PER LAYER: "mha", "mla", "kda" (Kimi delta
     # attention: the gated delta rule with a per-channel decay over a
     # [kda_head_dim, kda_head_dim] state a head, behind a causal depthwise
-    # convolution of kda_conv taps; no positions, nothing cached a
-    # token).  ``layer_mixers`` names every layer; empty, every layer is
+    # convolution of kda_conv taps) or "conv" (a gated short convolution:
+    # a causal depthwise convolution of conv_taps taps over a gated
+    # projection of the input, between two gates).  The last two keep a
+    # constant-size state a slot: no positions, nothing cached a token.
+    # ``layer_mixers`` names every layer; empty, every layer is
     # ``attention_kind``.
     layer_mixers: tuple = ()
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_lower_bound: float = -5.0  # log of the smallest decay a step
+    conv_taps: int = 3
+    # an "mha" layer norms every head of q and of k (RMSNorm over the
+    # head's width, weights ``q_norm`` / ``k_norm``) before RoPE
+    qk_norm: bool = False
+    # the eps of every RMSNorm of the model (block, final, q/k)
+    norm_eps: float = 1e-6
     # the share of a mixture layer's experts THIS chip holds, of a
     # deployment that divides every layer over several: experts
     # expert_first .. expert_first + experts_held - 1 (0 held: all).  The
@@ -401,13 +414,24 @@ class MoEConfig:
             raise ValueError(
                 f"layer_mixers names {len(self.layer_mixers)} layers of "
                 f"{self.num_layers}")
-        if set(self.mixers) - {"mha", "mla", "kda"}:
+        if set(self.mixers) - {"mha", "mla", *STATE_MIXERS}:
             raise ValueError(f"layer_mixers {self.mixers} not of "
-                             f"('mha', 'mla', 'kda')")
-        if set(self.mixers) - {"kda", self.attention_kind}:
+                             f"('mha', 'mla') + {STATE_MIXERS}")
+        if set(self.mixers) - {*STATE_MIXERS, self.attention_kind}:
             raise ValueError(
                 f"layer_mixers {self.mixers}: the layers that cache rows "
                 f"are all attention_kind={self.attention_kind!r}")
+        if len(set(self.mixers) & set(STATE_MIXERS)) > 1:
+            raise ValueError(
+                f"layer_mixers {self.mixers}: the layers that keep a "
+                f"state a slot are all of one kind of {STATE_MIXERS}")
+        if "conv" in self.mixers and self.conv_taps < 2:
+            raise ValueError(
+                f"a 'conv' layer needs conv_taps >= 2, got {self.conv_taps}")
+        if self.qk_norm and self.attention_kind != "mha":
+            raise ValueError(
+                "qk_norm norms the heads of an 'mha' layer's q and k: an "
+                "'mla' layer norms its latent")
         if "kda" in self.mixers:
             # the chunkwise form takes exp(16 x |bound|) in float32
             if (self.kda_heads < 1 or self.kda_head_dim < 1
@@ -669,15 +693,39 @@ class MoEConfig:
 
     @property
     def cache_layers(self) -> tuple:
-        """The layers that cache rows a token (every layer but 'kda'):
-        layer ``cache_layers[i]`` owns index i of the paged pools."""
-        return tuple(li for li, m in enumerate(self.mixers) if m != "kda")
+        """The layers that cache rows a token (every layer whose mixer is
+        not of ``STATE_MIXERS``): layer ``cache_layers[i]`` owns index i
+        of the paged pools."""
+        return tuple(li for li, m in enumerate(self.mixers)
+                     if m not in STATE_MIXERS)
 
     @property
     def state_layers(self) -> tuple:
-        """The 'kda' layers: layer ``state_layers[i]`` owns index i of
-        the per-slot recurrent state."""
-        return tuple(li for li, m in enumerate(self.mixers) if m == "kda")
+        """The layers that keep a constant-size state a slot ('kda',
+        'conv'): layer ``state_layers[i]`` owns index i of the per-slot
+        arrays (:attr:`slot_state`)."""
+        return tuple(li for li, m in enumerate(self.mixers)
+                     if m in STATE_MIXERS)
+
+    @property
+    def slot_state(self) -> tuple:
+        """What ONE state layer keeps of a slot, whatever its context:
+        ``((name, shape, dtype), ...)``, the arrays the cache holds by
+        slot in this order and the mixer takes and returns.  'kda': the
+        float32 delta-rule ``state`` [heads, d, d] and ``conv``, the
+        convolution's last kda_conv - 1 inputs of q, k and v side by
+        side; 'conv': ``conv``, the convolution's last conv_taps - 1
+        inputs (hidden_size each), in the activations' dtype."""
+        kinds = set(self.mixers) & set(STATE_MIXERS)
+        if kinds == {"kda"}:
+            n, d = self.kda_heads, self.kda_head_dim
+            return (("state", (n, d, d), jnp.float32),
+                    ("conv", ((self.kda_conv - 1) * 3 * n * d,),
+                     self.dtype))
+        if kinds == {"conv"}:
+            return (("conv", ((self.conv_taps - 1) * self.hidden_size,),
+                     self.dtype),)
+        return ()
 
     @property
     def kv_token_elems(self) -> int:
@@ -689,15 +737,25 @@ class MoEConfig:
 
     @property
     def kv_pool_rows(self) -> tuple[int, int, int]:
-        """(pools, heads a pool keeps, elements of a head's row) of the
+        """(pools, rows a pool keeps a token, elements of a row) of the
         paged cache as it is STORED: a K and a V pool of every kv head; or
         an MLA model's ONE pool of one latent row a token, padded to whole
         lanes (576 -> 640) so that a page ``[page, row]`` is whole tiles,
         contiguous, and a kernel's DMA can take it
-        (``serving/kvcache.LatentPagedCache``)."""
+        (``serving/kvcache.LatentPagedCache``).  K/V heads narrower than
+        a lane tile lie ``LANE // head_dim`` to a row where they divide
+        (8 heads of 64 are 4 rows of 128: heads 2j and 2j + 1 side by
+        side), for the same reason: the chip pads a 64-wide minor
+        dimension to 128 lanes, twice the pool and twice the bytes a
+        step reads, and the decode kernel takes whole lanes
+        (``ops/attention.pack_heads``)."""
         if self.attention_kind == "mla":
             return 1, 1, _round_up(self.kv_token_elems, LANE)
-        return 2, self.resolved_num_kv_heads, self.resolved_head_dim
+        nkv, dh = self.resolved_num_kv_heads, self.resolved_head_dim
+        pack = LANE // dh if dh < LANE and LANE % dh == 0 else 1
+        if nkv % pack:
+            pack = 1
+        return 2, nkv // pack, dh * pack
 
     @property
     def kv_row_elems(self) -> int:
@@ -722,13 +780,11 @@ class MoEConfig:
 
     @property
     def state_slot_bytes(self) -> int:
-        """Bytes of recurrent state one slot holds over the 'kda' layers,
-        whatever its context: the float32 [heads, d, d] state and the
-        convolution's last kda_conv - 1 inputs of q, k and v."""
-        n, d = self.kda_heads, self.kda_head_dim
-        return len(self.state_layers) * (
-            n * d * d * 4 + (self.kda_conv - 1) * 3 * n * d
-            * jnp.dtype(self.dtype).itemsize)
+        """Bytes of state one slot holds over the state layers, whatever
+        its context: the arrays of :attr:`slot_state`."""
+        return len(self.state_layers) * sum(
+            math.prod(shape) * jnp.dtype(dtype).itemsize
+            for _, shape, dtype in self.slot_state)
 
     @property
     def param_count(self) -> int:

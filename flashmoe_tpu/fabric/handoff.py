@@ -131,9 +131,9 @@ class KVHandoff:
                  transport=None):
         if cfg.state_layers:
             raise NotImplementedError(
-                "KV handoff of a model with recurrent-state ('kda') "
-                "layers: the payload is a K/V pair of page runs; a "
-                "snapshot of the slot's state is missing")
+                "KV handoff of a model with recurrent-state layers (a "
+                "state a slot): the payload is a K/V pair of page runs; "
+                "a snapshot of the slot's state is missing")
         if cfg.attention_kind == "mla":
             raise NotImplementedError(
                 "KV handoff of an attention_kind='mla' model: the payload "

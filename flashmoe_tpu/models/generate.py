@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from flashmoe_tpu.config import MoEConfig
 from flashmoe_tpu.models.transformer import rms_norm
 from flashmoe_tpu.ops.attention import paged_attention
-from flashmoe_tpu.ops.moe import moe_layer
+from flashmoe_tpu.ops.moe import expert_arm, moe_layer
 
 
 class KVCache(NamedTuple):
@@ -81,44 +81,47 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
     [B, T] absolute positions; write / block_tables / absorbed: see
     :func:`~flashmoe_tpu.ops.attention.kv_paged_attention` and
     ``mla_paged_attention``; valid / slots / fresh: which positions are
-    real, which slot's recurrent state a row owns and whether it starts
-    from nothing (:func:`~flashmoe_tpu.ops.kda.kda_attention`: read by
-    'kda' layers alone).  ``mixture(moe_params, rows, cfg)`` runs the
+    real, which slot's state a row owns and whether it starts from
+    nothing (read by the layers that keep a state a slot alone:
+    ``ops/attention.paged_attention``).  ``mixture(moe_params, rows, cfg)`` runs the
     experts of the mixture layers in place of :func:`moe_layer` (the EP
     programs' exchange).  Returns (x pre-final-norm [B, T, H], the cache,
     the span's rows, one array ``[L, B, ...]`` for each array of the
-    cache over the layers that own it, and what the layers counted: for
-    a config that holds a share of the experts ``held_rows``, the routed
-    rows that fell on them, mean over the mixture layers)."""
+    cache over the layers that own it, and what the mixture layers
+    counted, each a mean over them: ``experts_touched``, the experts that
+    at least one routed row of the span reached, and, for a config that
+    holds a share of the experts, ``held_rows``, the routed rows that
+    fell on them)."""
     b, t, _ = x.shape
-    # the experts over the S x K routed rows for an MLA config (the
-    # capacity arm's E x S rows cost 32 x the routed work at 256 experts
-    # top-8 and do not fit beside the weights past a 512-token span), over
-    # E x S rows for a K/V config: ROADMAP R7 races the two on the chip
-    local = functools.partial(moe_layer, use_pallas=False,
-                              routed_rows=cfg.attention_kind == "mla")
     pools = None if cache is None else tuple(cache)
-    rows, held = [], []
+    rows, held, touched = [], [], []
     for li, layer in enumerate(params["layers"]):
         a, pools, span = paged_attention(
-            layer, rms_norm(x, layer["attn_norm"]), cfg, pools, li, pos,
-            write, block_tables, absorbed=absorbed, valid=valid,
-            slots=slots, fresh=fresh)
+            layer, rms_norm(x, layer["attn_norm"], cfg.norm_eps), cfg,
+            pools, li, pos, write, block_tables, absorbed=absorbed,
+            valid=valid, slots=slots, fresh=fresh)
         rows.append(span)
         x = x + a
-        f_in = rms_norm(x, layer["ffn_norm"]).reshape(b * t, -1)
+        f_in = rms_norm(x, layer["ffn_norm"], cfg.norm_eps).reshape(
+            b * t, -1)
         layer_cfg = cfg.ffn_config(li)
-        experts = mixture if (mixture is not None
-                              and li in cfg.moe_layer_indices) else local
-        o = experts(layer["moe"], f_in, layer_cfg)
+        if mixture is not None and li in cfg.moe_layer_indices:
+            o = mixture(layer["moe"], f_in, layer_cfg)
+        else:
+            o = moe_layer(layer["moe"], f_in, layer_cfg, use_pallas=False,
+                          routed_rows=expert_arm(layer_cfg, b * t)
+                          == "routed_rows")
         x = x + o.out.reshape(b, t, -1).astype(x.dtype)
+        if layer_cfg.num_experts > 1:
+            touched.append(jnp.sum(o.expert_counts > 0))
         if layer_cfg.experts_held:
             held.append(jnp.sum(o.expert_counts[
                 cfg.expert_first:cfg.expert_first + cfg.experts_held]))
     if cache is not None:
         cache = type(cache)(*pools)
-    counted = ({"held_rows": jnp.mean(jnp.stack(held).astype(jnp.float32))}
-               if held else {})
+    counted = {name: jnp.mean(jnp.stack(per_layer).astype(jnp.float32))
+               for name, per_layer in (("experts_touched", touched),
+                                       ("held_rows", held)) if per_layer}
     return x, cache, tuple(
         jnp.stack([r for r in of_pool if r is not None])
         for of_pool in zip(*rows)), counted
@@ -162,7 +165,7 @@ def lm_logits(params, cfg: MoEConfig, h):
     """Final-norm + lm_head on [B, 1, H] hidden states -> [B, V] f32
     (the exact tail :func:`_decode_step` applies, shared so every
     consumer produces bit-identical logits from the same hidden)."""
-    h = rms_norm(h, params["final_norm"])
+    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     return jnp.dot(
         h.astype(cfg.dtype), params["lm_head"].astype(cfg.dtype),
         preferred_element_type=jnp.float32,
@@ -176,7 +179,7 @@ def lm_logits_span(params, cfg: MoEConfig, h):
     positions per slot in one forward and needs the lm head at every
     one of them; sharing the tail here keeps each column bit-identical
     to what :func:`lm_logits` produces from the same hidden row."""
-    h = rms_norm(h, params["final_norm"])
+    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     return jnp.dot(
         h.astype(cfg.dtype), params["lm_head"].astype(cfg.dtype),
         preferred_element_type=jnp.float32,

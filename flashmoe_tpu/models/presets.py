@@ -122,6 +122,33 @@ def ling3_flash(**overrides) -> MoEConfig:
     return MoEConfig(**base)
 
 
+def lfm2_24b_a2b(**overrides) -> MoEConfig:
+    """LFM2-24B-A2B (huggingface.co/LiquidAI/LFM2-24B-A2B ``config.json``,
+    ``model_type`` lfm2_moe): 40 layers of width 2048, three gated short
+    convolutions (3 taps) to one grouped-query attention layer (32 heads
+    over 8 K/V heads of width 64, RMSNorm on every head of q and k before
+    RoPE, theta 1e6): ``layer_types`` has full attention at layers 2, 6,
+    ..., 38.  2 leading dense layers of width 11776, then 64 experts top-4
+    of width 1536 behind a sigmoid router with a selection bias,
+    normalised weights, no shared expert; RMSNorm eps 1e-5."""
+    base = dict(
+        num_experts=64, expert_top_k=4, num_shared_experts=0,
+        hidden_size=2048, intermediate_size=1536, num_layers=40,
+        moe_frequency=1, first_k_dense=2, dense_intermediate_size=11776,
+        vocab_size=65536, num_heads=32, num_kv_heads=8, qk_norm=True,
+        conv_taps=3, norm_eps=1e-5, rope_theta=1e6,
+        router_score="sigmoid", router_bias=True, norm_topk_prob=True,
+        routed_scaling_factor=1.0, sequence_len=4096, gated_ffn=True,
+        hidden_act=Activation.SILU, drop_tokens=False, dtype=jnp.bfloat16,
+    )
+    base.update(overrides)
+    # the published layer_types: full attention at 2, 6, ..., 38
+    base.setdefault("layer_mixers", tuple(
+        "mha" if li % 4 == 2 else "conv"
+        for li in range(base["num_layers"])))
+    return MoEConfig(**base)
+
+
 PRESETS = {
     "mixtral-8x7b": mixtral_8x7b,
     "deepseek-moe-16b": deepseek_moe_16b,
@@ -129,4 +156,5 @@ PRESETS = {
     "flashmoe-reference": flashmoe_reference,
     "joyai-llm-flash": joyai_llm_flash,
     "ling-3.0-flash": ling3_flash,
+    "lfm2-24b-a2b": lfm2_24b_a2b,
 }

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from flashmoe_tpu.config import MoEConfig
 from flashmoe_tpu.models.reference import init_moe_params
 from flashmoe_tpu.ops.attention import rms_norm  # noqa: F401  (re-exported)
-from flashmoe_tpu.ops.attention import rope_halves as _rope
+from flashmoe_tpu.ops.attention import rope_halves as _rope  # noqa: F401
 from flashmoe_tpu.ops.moe import dense_ffn, moe_layer
 from flashmoe_tpu.parallel.ep import ep_moe_layer
 
@@ -80,6 +80,12 @@ def init_params(key, cfg: MoEConfig) -> dict:
                 kda_wg=dense(ak[5], (h, n), h),
                 kda_norm=jnp.ones((d,), cfg.param_dtype),
                 wo=dense(ak[6], (n * d, h), n * d))
+        elif cfg.mixers[li] == "conv":
+            ak = jax.random.split(lk[0], 3)
+            layer.update(
+                conv_win=dense(ak[0], (h, 3 * h), h),
+                conv_w=dense(ak[1], (cfg.conv_taps, h), cfg.conv_taps),
+                wo=dense(ak[2], (h, h), h))
         elif cfg.attention_kind == "mla":
             ak = jax.random.split(lk[0], 4)
             rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
@@ -103,6 +109,9 @@ def init_params(key, cfg: MoEConfig) -> dict:
                 wk=dense(lk[1], (h, nkv * dh), h),
                 wv=dense(lk[2], (h, nkv * dh), h),
                 wo=dense(lk[3], (nh * dh, h), nh * dh))
+            if cfg.qk_norm:
+                layer.update(q_norm=jnp.ones((dh,), cfg.param_dtype),
+                             k_norm=jnp.ones((dh,), cfg.param_dtype))
         layer["moe"] = init_moe_params(lk[4], cfg.ffn_config(li))
         params["layers"].append(layer)
     return params
@@ -115,28 +124,31 @@ def init_params(key, cfg: MoEConfig) -> dict:
 def attention(layer, x, cfg: MoEConfig, positions=None, mesh=None,
               use_pallas=None, li: int = 0):
     """Layer ``li``'s token mixer over a whole sequence (no cache): causal
-    self-attention with RoPE and GQA, latent attention, or the delta rule
-    in its chunkwise form, by ``cfg.mixers[li]``.  x: [B, T, H].
+    self-attention with RoPE and GQA, latent attention, the delta rule in
+    its chunkwise form or the gated short convolution, by
+    ``cfg.mixers[li]``.  x: [B, T, H].
 
     Backend selection of the K/V kind: ring attention over the ``sp``
     mesh axis for sequence-parallel configs, the flash Pallas kernel on
     TPU, plain XLA otherwise.
     """
+    from flashmoe_tpu.config import STATE_MIXERS
     from flashmoe_tpu.ops.attention import (
-        attention_xla, flash_attention, mla_paged_attention,
+        attention_xla, flash_attention, kv_project, mla_paged_attention,
+        paged_attention,
     )
-    from flashmoe_tpu.ops.kda import kda_attention
     from flashmoe_tpu.parallel.ringattn import ring_attention
 
     b, t, h = x.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
-    if cfg.mixers[li] == "kda":
+    if cfg.mixers[li] in STATE_MIXERS:
         if mesh is not None and cfg.sp > 1:
             raise NotImplementedError(
-                "a 'kda' layer under sp > 1: the state would have to "
-                "pass from one sequence shard to the next")
-        return kda_attention(layer, x, cfg, None, None, 0)[0]
+                f"a {cfg.mixers[li]!r} layer under sp > 1: the state would "
+                f"have to pass from one sequence shard to the next")
+        return paged_attention(layer, x, cfg, None, li, positions, None,
+                               None, absorbed=False)[0]
     if cfg.attention_kind == "mla":
         # plain XLA, the first (decompressing) form: flash_attention
         # assumes equal q/k/v head sizes, ring attention K/V shards
@@ -148,10 +160,7 @@ def attention(layer, x, cfg: MoEConfig, positions=None, mesh=None,
                                    None, None, absorbed=False)[0]
     nh, nkv, dh = cfg.num_heads, cfg.resolved_num_kv_heads, cfg.resolved_head_dim
 
-    q = (x @ layer["wq"].astype(x.dtype)).reshape(b, t, nh, dh)
-    k = (x @ layer["wk"].astype(x.dtype)).reshape(b, t, nkv, dh)
-    v = (x @ layer["wv"].astype(x.dtype)).reshape(b, t, nkv, dh)
-    q, k = _rope(q, k, positions, cfg.rope_theta)
+    q, k, v = kv_project(layer, x, cfg, positions)
 
     if nkv != nh:  # GQA: repeat kv heads
         rep = nh // nkv
@@ -244,11 +253,12 @@ def block(layer, x, cfg: MoEConfig, li: int, mesh=None, use_pallas=None,
     re-armed injection point silently reuses the previous arming
     state's jaxpr whenever two builds share an equal config (the chaos
     drills rebuild their step exactly to pick up new arming)."""
-    a = attention(layer, rms_norm(x, layer["attn_norm"]), cfg, mesh=mesh,
-                  use_pallas=use_pallas, li=li)
+    a = attention(layer, rms_norm(x, layer["attn_norm"], cfg.norm_eps), cfg,
+                  mesh=mesh, use_pallas=use_pallas, li=li)
     x = x + a
-    f, moe_loss, moe_stats = _ffn(layer, rms_norm(x, layer["ffn_norm"]),
-                                  cfg, li, mesh, use_pallas)
+    f, moe_loss, moe_stats = _ffn(
+        layer, rms_norm(x, layer["ffn_norm"], cfg.norm_eps), cfg, li, mesh,
+        use_pallas)
     return x + f, moe_loss, moe_stats
 
 
@@ -288,7 +298,7 @@ def forward(params, tokens, cfg: MoEConfig, mesh=None, use_pallas=None):
         total_aux = total_aux + moe_loss
         if moe_stats is not None:
             layer_stats.append(moe_stats)
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.dot(
         x.astype(cfg.dtype), params["lm_head"].astype(cfg.dtype),
         preferred_element_type=jnp.float32,

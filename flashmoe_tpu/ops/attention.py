@@ -21,7 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from flashmoe_tpu.config import LANE
+from flashmoe_tpu.config import LANE, STATE_MIXERS
+from flashmoe_tpu.ops.conv import conv_attention
 from flashmoe_tpu.ops.kda import kda_attention
 from flashmoe_tpu.utils.telemetry import trace_span
 
@@ -99,14 +100,15 @@ def mla_project(layer, x, cfg, positions):
     nh, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     dc = cfg.kv_lora_rank
     if cfg.q_lora_rank:
-        c_q = rms_norm(x @ layer["wq_a"].astype(x.dtype), layer["q_a_norm"])
+        c_q = rms_norm(x @ layer["wq_a"].astype(x.dtype), layer["q_a_norm"],
+                       cfg.norm_eps)
         q = c_q @ layer["wq_b"].astype(x.dtype)
     else:                       # the published null rank: queries direct
         q = x @ layer["wq"].astype(x.dtype)
     q = q.reshape(b, t, nh, dn + dr)
     q_rope = rope_adjacent(q[..., dn:], positions, cfg.rope_theta)
     kv = x @ layer["wkv_a"].astype(x.dtype)                   # [B, T, dc+dr]
-    c_kv = rms_norm(kv[..., :dc], layer["kv_a_norm"])
+    c_kv = rms_norm(kv[..., :dc], layer["kv_a_norm"], cfg.norm_eps)
     k_rope = rope_adjacent(kv[..., dc:], positions, cfg.rope_theta)
     return q[..., :dn], q_rope, jnp.concatenate([c_kv, k_rope], axis=-1)
 
@@ -304,13 +306,17 @@ def rope_halves(q, k, positions, theta):
 
 def kv_project(layer, x, cfg, positions):
     """x: [B, T, H] normed -> (q [B, T, N, D], k and v [B, T, N_kv, D]),
-    q and k roped at ``positions`` [B, T]."""
+    q and k roped at ``positions`` [B, T]; under ``cfg.qk_norm`` every
+    head of q and of k goes through an RMSNorm over its width first."""
     b, t, _ = x.shape
     nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
                    cfg.resolved_head_dim)
     q = (x @ layer["wq"].astype(x.dtype)).reshape(b, t, nh, dh)
     k = (x @ layer["wk"].astype(x.dtype)).reshape(b, t, nkv, dh)
     v = (x @ layer["wv"].astype(x.dtype)).reshape(b, t, nkv, dh)
+    if cfg.qk_norm:
+        q = rms_norm(q, layer["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, layer["k_norm"], cfg.norm_eps)
     q, k = rope_halves(q, k, positions, cfg.rope_theta)
     return q, k, v
 
@@ -349,7 +355,11 @@ def store_kv(pages, li: int, span_kv, page_ids, rows):
     scratch rows are never read back with non-zero weight; a rejected
     draft's rows are overwritten before any causal mask exposes them).
     ``rows`` None: the span fills WHOLE pages (a prefill chunk); page_ids
-    is then [B, T // page], one id a page."""
+    is then [B, T // page], one id a page.  A pool of packed heads
+    (``MoEConfig.kv_pool_rows``: [L, P, N_kv / p, page, p * D]) takes the
+    span's heads p to a row, side by side."""
+    span_kv = span_kv.reshape(*span_kv.shape[:2], pages.shape[2],
+                              pages.shape[4])
     if rows is None:
         _, _, nkv, d = span_kv.shape
         whole = span_kv.reshape(-1, pages.shape[3], nkv, d)
@@ -362,17 +372,23 @@ def store_kv(pages, li: int, span_kv, page_ids, rows):
         pages[li].at[page_ids, :, rows, :].set(span_kv))
 
 
-def gather_ctx(pages, block_tables):
+def gather_ctx(pages, block_tables, d: int | None = None):
     """Gather each slot's context window from its pages.
 
     pages: ``[P, N_kv, page, D]`` (one layer's pool); block_tables:
     ``[B, n]`` page ids (already sliced to the bucketed page count).
     Returns ``[B, N_kv, n * page, D]`` — rows past a request's length are
-    scratch/garbage and MUST be masked by the caller's length mask."""
+    scratch/garbage and MUST be masked by the caller's length mask.  ``d``:
+    the heads' width where the pool packs several to a row (pages
+    ``[P, N_kv / p, page, p * d]``); the heads come back apart."""
     b, n = block_tables.shape
     g = pages[block_tables]                    # [B, n, N_kv, page, D]
-    _, _, nkv, page, d = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, nkv, n * page, d)
+    _, _, rows, page, width = g.shape
+    ctx = g.transpose(0, 2, 1, 3, 4).reshape(b, rows, n * page, width)
+    if d in (None, width):
+        return ctx
+    return ctx.reshape(b, rows, n * page, width // d, d).transpose(
+        0, 1, 3, 2, 4).reshape(b, rows * (width // d), n * page, d)
 
 
 def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
@@ -398,42 +414,51 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
     span = (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
     if pools is None:
         return kv_attend(layer, q, *span, pos), pools, span
-    n_kv, page, d = pools[0].shape[2:]
+    rows, page, width = pools[0].shape[2:]       # the heads as stored
     if (write[1] is not None and kv_attention_arm(
-            q.shape[1], page, n_kv, d, pools[0].dtype) == "paged_kernel"):
+            q.shape[1], page, rows, width, pools[0].dtype)
+            == "paged_kernel"):
         ctx, pools = paged_decode_attention(
             q, (k, v), pools, li, block_tables, pos[:, 0], write,
             interpret=jax.default_backend() != "tpu")
         return ctx @ layer["wo"].astype(q.dtype), pools, span
     pools = (store_kv(pools[0], li, k, *write),
              store_kv(pools[1], li, v, *write))
-    k_ctx = gather_ctx(pools[0][li], block_tables)
-    v_ctx = gather_ctx(pools[1][li], block_tables)
+    k_ctx = gather_ctx(pools[0][li], block_tables, q.shape[-1])
+    v_ctx = gather_ctx(pools[1][li], block_tables, q.shape[-1])
     return kv_attend(layer, q, k_ctx, v_ctx, pos), pools, span
+
+
+#: the mixers of ``config.STATE_MIXERS``: each takes ``(layer, x, cfg,
+#: *its per-slot arrays or Nones, its index among the state layers, valid,
+#: slots, fresh)`` and returns ``(out, *the arrays, the rows' final state
+#: as a tuple)``
+_STATE_MIXERS = {"kda": kda_attention, "conv": conv_attention}
 
 
 def paged_attention(layer, x, cfg, pools, li, pos, write, block_tables, *,
                     absorbed: bool, valid=None, slots=None, fresh=None):
     """Layer ``li``'s token mixer over the cache, by ``cfg.mixers[li]``:
     :func:`kv_paged_attention`, :func:`mla_paged_attention` (which alone
-    reads ``absorbed``) or :func:`~flashmoe_tpu.ops.kda.kda_attention`
-    (which alone reads ``valid``, ``slots`` and ``fresh``, and neither
-    positions nor pages).  pools: the cache's arrays as a tuple, or None:
-    the paged pools first (a K/V pair or the one latent pool, holding the
-    layers of ``cfg.cache_layers``), then, where the config has 'kda'
-    layers, their per-slot state and convolution inputs
-    (``cfg.state_layers``).  Returns (the block's output, the pools, the
-    span's rows: one entry for each array of the cache, None for those
-    this layer does not own)."""
+    reads ``absorbed``) or a mixer that keeps its state by slot
+    (``_STATE_MIXERS``: they alone read ``valid``, ``slots`` and
+    ``fresh``, and neither positions nor pages).  pools: the cache's
+    arrays as a tuple, or None: the paged pools first (a K/V pair or the
+    one latent pool, holding the layers of ``cfg.cache_layers``), then,
+    where the config has state layers, what they keep by slot
+    (``cfg.slot_state``, holding the layers of ``cfg.state_layers``).
+    Returns (the block's output, the pools, the span's rows: one entry
+    for each array of the cache, None for those this layer does not
+    own)."""
     n_paged = 1 if cfg.attention_kind == "mla" else 2
-    n_state = 2 if cfg.state_layers else 0
-    if cfg.mixers[li] == "kda":
-        state, conv = (None, None) if pools is None else pools[n_paged:]
-        out, state, conv, final = kda_attention(
-            layer, x, cfg, state, conv, cfg.state_layers.index(li), valid,
-            slots, fresh)
+    n_state = len(cfg.slot_state)
+    if cfg.mixers[li] in STATE_MIXERS:
+        kept = (None,) * n_state if pools is None else pools[n_paged:]
+        out, *kept, final = _STATE_MIXERS[cfg.mixers[li]](
+            layer, x, cfg, *kept, cfg.state_layers.index(li), valid, slots,
+            fresh)
         return (out, None if pools is None
-                else pools[:n_paged] + (state, conv),
+                else pools[:n_paged] + tuple(kept),
                 (None,) * n_paged + final)
     ci = cfg.cache_layers.index(li)
     paged = None if pools is None else pools[:n_paged]
@@ -692,7 +717,9 @@ def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
     (T < page); pools: a ``(k_pages, v_pages)`` pair, or ONE pool whose
     rows are keys and, in their first ``v_width`` columns, values (an MLA
     model's latent pool: N_kv = 1, ``q`` in the absorbed form), each
-    [L, P, N_kv, page, D]; block_tables: [B, n]; pos: [B] the position of
+    [L, P, N_kv, page, D], or a pair that packs p heads narrower than a
+    lane tile to a row ([L, P, N_kv / p, page, p * D]:
+    ``MoEConfig.kv_pool_rows``); block_tables: [B, n]; pos: [B] the position of
     each slot's first span row, the pool holding positions before it;
     write: ``(page_ids, rows)``, each [B, T], where the span's rows go
     (consecutive rows: at most two pages a slot); scale: of the scores,
@@ -703,9 +730,31 @@ def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
     :func:`mla_attend` up to the latent sums), with f32 scores, statistics
     and accumulator, the probabilities rounded to the pool's dtype before
     the product with the values."""
-    b, t, nh, d = q.shape
     n_pools, dt = len(pools), pools[0].dtype
-    nkv, page = pools[0].shape[2:4]
+    nkv, page, width = pools[0].shape[2:]
+    pack = width // q.shape[-1]
+    if pack > 1:
+        # a pool of packed heads: the kernel is handed rows of whole
+        # lanes.  Row r holds heads r * pack .. + pack - 1 side by side,
+        # so a query of head r * pack + p stands in the p-th part of a
+        # row-wide query, zeros beside it (its scores are its own head's),
+        # and its output is the p-th part of the row-wide sum.  The
+        # products are pack x the narrow heads': nothing, beside the
+        # context's bytes.
+        b, t, nh, d = q.shape
+        apart = jnp.eye(pack, dtype=q.dtype)
+        wide = jnp.einsum(
+            "btrpgd,pq->btrpgqd", q.reshape(b, t, nkv, pack, -1, d), apart)
+        out, pools = paged_decode_attention(
+            wide.reshape(b, t, nh, width),
+            [x.reshape(b, t, nkv, width) for x in span], pools, li,
+            block_tables, pos, write, scale=scale or d ** -0.5,
+            block_pages=block_pages, interpret=interpret)
+        out = jnp.einsum(
+            "btrpgqd,pq->btrpgd",
+            out.reshape(b, t, nkv, pack, -1, pack, d), apart)
+        return out.reshape(b, t, nh * d), pools
+    b, t, nh, d = q.shape
     dv = v_width or d
     rep = nh // nkv
     n_tab = block_tables.shape[1]

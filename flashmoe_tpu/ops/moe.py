@@ -280,6 +280,43 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
     )
 
 
+#: what the serving span gives the capacity arm (:func:`expert_arm`): its
+#: largest ``[E, capacity, H]`` buffer, its most rows for one routed row
+_CAPACITY_BUFFER_BYTES, _CAPACITY_ROWS_A_ROUTED_ROW = 128 * 1024 * 1024, 24
+
+
+def expert_arm(cfg: MoEConfig, s: int) -> str:
+    """The arm a span of ``s`` rows takes through a layer's experts in
+    plain XLA (the serving path: ``models/generate.span_forward``), ONE
+    rule over what the shapes say each arm computes and holds.
+
+    ``"capacity"`` (``E x capacity(s)`` rows, dispatched into an
+    ``[E, capacity, H]`` buffer) while that buffer is at most 128 MiB and
+    its rows at most 24 times the ``s x K`` routed rows; ``"routed_rows"``
+    (:func:`routed_rows_ffn`, XLA's grouped matmul) beyond either, and
+    always for a config that holds a share of its experts (the capacity
+    arm indexes every expert's weights).  Why the buffer: on the chip the
+    grouped matmul costs a tile of up to 512 rows a GROUP whatever its
+    rows (64 experts of 2048 x 1536, top-4: 3.9-4.6 ms a layer from 128 to
+    1024 tokens), while the capacity arm's rows ride under the weights'
+    stream up to the chip's ridge and grow with ``E x s`` after it (1.9,
+    4.7, 9.8 ms at 128, 512, 1024): they cross near 512 tokens, 128 MiB.
+    Why the rows (E / K when nothing drops): at 10.7 and 16 the capacity
+    arm won every span under 512 tokens; at 32 (256 experts top-8) a
+    decode step was a tie alone and LOST in its program (PERF.md section
+    6, PR 33).  A dense layer and a rule that drops keep that arm."""
+    if (cfg.drop_tokens or cfg.degrade_unhealthy_experts
+            or cfg.num_experts == 1):
+        return "capacity"
+    if cfg.experts_held:
+        return "routed_rows"
+    rows = cfg.num_experts * cfg.capacity_for(s)
+    fits = (rows * cfg.hidden_size * jnp.dtype(cfg.dtype).itemsize
+            <= _CAPACITY_BUFFER_BYTES)
+    near = rows <= _CAPACITY_ROWS_A_ROUTED_ROW * s * cfg.expert_top_k
+    return "capacity" if fits and near else "routed_rows"
+
+
 def moe_layer(params, x, cfg: MoEConfig, *, use_pallas: bool | None = None,
               capacity: int | None = None, interpret: bool = False,
               routed_rows: bool = False) -> MoEOutput:
@@ -297,9 +334,9 @@ def moe_layer(params, x, cfg: MoEConfig, *, use_pallas: bool | None = None,
     ``routed_rows`` (with ``use_pallas=False``, a dropless config and no
     ``capacity``) computes the experts over exactly the ``S x K`` routed
     rows (:func:`routed_rows_ffn`) where the capacity arm computes
-    ``E x S``: the serving path of the latent-attention models asks for
-    it (``models/generate.span_forward``); every other caller keeps
-    the arm it had.
+    ``E x S``: the serving path asks for it by :func:`expert_arm`
+    (``models/generate.span_forward``); every other caller keeps the arm
+    it had.
     """
     if use_pallas is None:
         use_pallas = interpret or jax.default_backend() == "tpu"

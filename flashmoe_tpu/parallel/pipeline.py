@@ -81,9 +81,9 @@ def _block_in_stage(layer, x, cfg: MoEConfig, li: int, use_ep: bool,
     ep-sharded through the stage in_specs); ``use_pallas`` selects the
     fused Pallas gate/FFN kernels inside the stage (the production TPU
     path — round-2 verdict weak #3 flagged the hard-coded XLA body)."""
-    a = tfm.attention(layer, tfm.rms_norm(x, layer["attn_norm"]), cfg)
+    a = tfm.attention(layer, tfm.rms_norm(x, layer["attn_norm"], cfg.norm_eps), cfg)
     x = x + a
-    xf = tfm.rms_norm(x, layer["ffn_norm"])
+    xf = tfm.rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
     b, t, h = xf.shape
     flat = xf.reshape(b * t, h)
     layer_cfg = cfg if li in cfg.moe_layer_indices else cfg.replace(
@@ -220,7 +220,7 @@ def pipeline_loss(params, batch, cfg: MoEConfig, mesh: Mesh, *,
 
             def ce_branch(y_tg):
                 yb, tg = y_tg
-                hn = tfm.rms_norm(yb, io_params["final_norm"])
+                hn = tfm.rms_norm(yb, io_params["final_norm"], cfg.norm_eps)
                 logits = jnp.dot(
                     hn.astype(cfg.dtype),
                     io_params["lm_head"].astype(cfg.dtype),

@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from flashmoe_tpu.config import MoEConfig
+from flashmoe_tpu.config import STATE_MIXERS, MoEConfig
 from flashmoe_tpu.models.generate import (
     lm_logits, lm_logits_span, span_forward,
 )
@@ -258,9 +258,9 @@ def _prefill_padded(params, cfg: MoEConfig, prompt_padded, true_len):
     the true last position, then one dense run for each pool of the
     cache, as ``store_prefill`` takes it: k_seq/v_seq
     [L, N_kv, T_pad, D], or an MLA config's latent rows [L, T_pad, C];
-    then, with 'kda' layers, their state after the TRUE last token,
-    [L_s, N, D, D] and [L_s, (K - 1) * 3 N D], as ``store_state`` takes
-    it).
+    then, with state layers, what they keep after the TRUE last token,
+    one [L_s, ...] array for each of ``cfg.slot_state``, as
+    ``store_state`` takes it).
     Pad positions compute garbage no causal query before them ever
     sees; their rows land in pages the length mask never exposes, and
     they leave a recurrent state alone."""
@@ -289,7 +289,7 @@ def _prefill_chunk(params, cfg: MoEConfig, pools, chunk_toks,
     first token; rel_last: in-chunk index of the prompt's true last
     token (clipped — only the chunk containing it keeps the logits);
     slot: the batch slot the prompt was admitted to, whose recurrent
-    state the 'kda' layers carry from chunk to chunk (the first chunk
+    state the state layers carry from chunk to chunk (the first chunk
     starts from nothing; positions past ``rel_last`` leave it alone).
     ``pools`` is the engine's cache (any class of ``serving/kvcache``).
     Returns (logits [V], pools).
@@ -615,7 +615,8 @@ class ServingEngine:
         mla = cfg.attention_kind == "mla"
         sv = serve if serve is not None else ServeConfig()
         if cfg.state_layers:
-            # what cannot keep a slot's recurrent state correct
+            # what cannot keep a slot's state correct, whatever the
+            # mixer that keeps it
             missing = {
                 "speculate": (sv.speculate is not None,
                               "a rejected draft has already moved the "
@@ -631,8 +632,9 @@ class ServingEngine:
             for what, (asked, lack) in missing.items():
                 if asked:
                     raise NotImplementedError(
-                        f"recurrent-state ('kda') layers with {what}: "
-                        f"{lack} is missing")
+                        f"recurrent-state layers (a state a slot: "
+                        f"{sorted(set(cfg.mixers) & set(STATE_MIXERS))}) "
+                        f"with {what}: {lack} is missing")
         if mla and sv.ep_shards > 1:
             raise NotImplementedError(
                 "attention_kind='mla' with ep_shards > 1: _ep_decode_fn "
@@ -1785,14 +1787,14 @@ class ServingEngine:
         }
         if self.cfg.state_layers:
             rec["state_bytes"] = self._state_bytes
-        held_rows = None
         # the latest decode program that has finished (the very first
         # record waits for its own)
         counted = (self._counted_prev if self._counted_prev is not None
                    else self._counted)
         if counted is not None and self.recorder is not None:
-            held_rows = round(float(counted["held_rows"]), 3)
-            rec["held_rows"] = held_rows
+            counted = {k: round(float(v), 3)
+                       for k, v in jax.device_get(counted).items()}
+            rec.update(counted)
         if self.serve.speculate is not None:
             rec["spec_tokens"] = int(n_extra or 0)
             rec["spec_on"] = self._spec is not None
@@ -1809,8 +1811,8 @@ class ServingEngine:
                 if self.cfg.state_layers:
                     more["state_bytes"] = (2 * sv.max_batch
                                            * self.cfg.state_slot_bytes)
-                if held_rows is not None:
-                    more["held_rows"] = held_rows
+                if counted is not None:
+                    more.update(counted)
                 self.recorder.record(
                     kind="serve_decode", step=self.step_idx,
                     slots=n_decoding, ctx_pages=ctx_pages,

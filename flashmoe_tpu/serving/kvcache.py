@@ -32,6 +32,8 @@ read back with exactly-zero attention weight.
 
 from __future__ import annotations
 
+import collections
+import functools
 from typing import NamedTuple
 
 import jax
@@ -47,7 +49,9 @@ SCRATCH_PAGE = 0
 
 class PagedKVCache(NamedTuple):
     """The device-side page pool.  ``k_pages`` / ``v_pages``:
-    ``[L, P, N_kv, page, D]``.  Block tables and lengths live on the
+    ``[L, P, N_kv, page, D]``; heads narrower than a lane tile lie p to a
+    row, ``[L, P, N_kv / p, page, p * D]`` (``MoEConfig.kv_pool_rows``).
+    Block tables and lengths live on the
     host (the engine's slot state) and ride into each jitted step as
     ordinary array arguments — values change, shapes never do."""
 
@@ -92,17 +96,23 @@ class LatentPagedCache(NamedTuple):
         return self.pages.shape[2]
 
 
-class HybridCache(NamedTuple):
+class HybridCache:
     """The cache of a model whose layers differ in kind: two kinds of
-    state side by side.  ``pages``: the latent pool of the layers that
-    cache rows (``cfg.cache_layers``: ``[L_c, P, page, R]``,
-    :class:`LatentPagedCache`'s array in its layout and for its reasons,
+    state side by side, in one tuple of arrays.  First the paged pools of
+    the layers that cache rows (``cfg.cache_layers``): a K/V pair
+    (:class:`PagedKVCache`'s arrays) or one latent pool
+    (:class:`LatentPagedCache`'s), in their layouts and for their reasons,
     addressed by PAGE through the block tables and read in place by the
-    same decode kernel).  ``state`` / ``conv``: what the 'kda' layers
-    (``cfg.state_layers``) keep of a request whatever its length,
-    addressed by SLOT: the float32 delta-rule state ``[L_s, slots, N, D,
-    D]`` and the convolution's last inputs ``[L_s, slots, (K - 1) * 3 N
-    D]`` (side by side, as a page's rows: no 3-row axis to pad).
+    same decode kernel.  Then what the other layers (``cfg.state_layers``)
+    keep of a request whatever its length, addressed by SLOT
+    (``cfg.slot_state``: each array ``[L_s, slots, ...]``).  A 'kda'
+    layer keeps ``state``, the float32 delta-rule state ``[N, D, D]``, and
+    ``conv``, its convolution's last inputs ``[(K - 1) * 3 N D]`` (side
+    by side, as a page's rows: no 3-row axis to pad); a 'conv' layer keeps
+    ``conv`` alone, its last ``(K - 1) * H`` inputs.  The mixer says what
+    it keeps; nothing here names one.  The concrete classes are named
+    tuples made by :func:`hybrid_cache_class`, one a set of field names;
+    ``by_slot`` names the fields addressed by slot.
 
     Who does what to a slot's state.  A whole-prompt prefill computes it
     from nothing and :func:`store_state` puts it in the slot; a prompt's
@@ -113,52 +123,57 @@ class HybridCache(NamedTuple):
     nothing: the next tenant's prefill overwrites it, and an evicted
     request's re-prefill rebuilds it."""
 
-    pages: jax.Array
-    state: jax.Array
-    conv: jax.Array
+    __slots__ = ()
+    by_slot: tuple = ()
 
     @property
     def num_pages(self) -> int:
-        return self.pages.shape[1]
+        return self[0].shape[1]
 
     @property
     def page_size(self) -> int:
-        return self.pages.shape[2]
+        return self[0].shape[-2]
+
+
+@functools.lru_cache(maxsize=None)
+def hybrid_cache_class(paged: tuple, by_slot: tuple) -> type:
+    """The :class:`HybridCache` whose fields are the paged pools ``paged``
+    then the per-slot arrays ``by_slot`` (names)."""
+    return type("HybridCache", (HybridCache, collections.namedtuple(
+        "HybridCache", paged + by_slot)),
+        {"__slots__": (), "by_slot": by_slot})
 
 
 def cache_arrays(cfg: MoEConfig, num_pages: int, page_size: int,
                  slots: int) -> tuple:
     """The cache's arrays, zeroed: the paged pools of the layers that
     cache rows (a K/V pair, or one latent pool) and, where the config has
-    'kda' layers, a :class:`HybridCache` with ``slots`` slots of state."""
+    state layers, a :class:`HybridCache` with ``slots`` slots of what
+    they keep (``cfg.slot_state``)."""
     n_cache = len(cfg.cache_layers)
+    _, rows, width = cfg.kv_pool_rows
     if cfg.attention_kind == "mla":
-        paged = (jnp.zeros(
-            (n_cache, num_pages, page_size, cfg.kv_pool_rows[2]),
-            cfg.dtype),)
+        names = ("pages",)
+        paged = (jnp.zeros((n_cache, num_pages, page_size, width),
+                           cfg.dtype),)
     else:
-        nkv, dh = cfg.resolved_num_kv_heads, cfg.resolved_head_dim
-        shape = (n_cache, num_pages, nkv, page_size, dh)
+        names = PagedKVCache._fields
+        shape = (n_cache, num_pages, rows, page_size, width)
         paged = (jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
     if not cfg.state_layers:
         return paged
-    if cfg.attention_kind != "mla":
-        raise NotImplementedError(
-            "'kda' layers beside K/V layers: the hybrid cache "
-            "(HybridCache) pairs the per-slot state with a latent pool")
-    n, d, n_state = cfg.kda_heads, cfg.kda_head_dim, len(cfg.state_layers)
-    return HybridCache(
-        *paged, jnp.zeros((n_state, slots, n, d, d), jnp.float32),
-        jnp.zeros((n_state, slots, (cfg.kda_conv - 1) * 3 * n * d),
-                  cfg.dtype))
+    kept = cfg.slot_state
+    return hybrid_cache_class(names, tuple(name for name, _, _ in kept))(
+        *paged, *(jnp.zeros((len(cfg.state_layers), slots, *shape), dtype)
+                  for _, shape, dtype in kept))
 
 
 def init_paged_cache(cfg: MoEConfig, num_pages: int, page_size: int,
                      slots: int = 0):
     """Allocate the cache the config's layers read: a K/V pair
     (:class:`PagedKVCache`), one latent pool (:class:`LatentPagedCache`)
-    or, with 'kda' layers, a latent pool beside ``slots`` slots of
-    recurrent state (:class:`HybridCache`).  ``num_pages`` includes the
+    or, with state layers, such pools beside ``slots`` slots of what
+    those layers keep (:class:`HybridCache`).  ``num_pages`` includes the
     scratch page."""
     if num_pages < 2:
         raise ValueError(f"num_pages={num_pages} must be >= 2 (page 0 "
@@ -173,9 +188,9 @@ def init_paged_cache(cfg: MoEConfig, num_pages: int, page_size: int,
 
 
 def slot_state_fields(cache) -> tuple:
-    """For each array of ``cache``: whether it is addressed by slot (the
-    recurrent state) and not by page."""
-    return tuple(isinstance(cache, HybridCache) and name != "pages"
+    """For each array of ``cache``: whether it is addressed by slot (a
+    state layer's) and not by page."""
+    return tuple(name in getattr(cache, "by_slot", ())
                  for name in cache._fields)
 
 
@@ -211,12 +226,17 @@ def store_prefill(pages, seq_kv, page_ids):
         return pages.at[:, page_ids].set(
             rows.reshape(l, n, page, r).astype(pages.dtype))
     l, nkv, t_pad, d = seq_kv.shape
-    page = pages.shape[3]
+    rows, page, width = pages.shape[2:]
     if t_pad != n * page:
         raise ValueError(f"prefill run of {t_pad} rows does not fill "
                          f"{n} pages of {page}")
-    # [L, N_kv, n, page, D] -> [L, n, N_kv, page, D]
-    chunks = seq_kv.reshape(l, nkv, n, page, d).transpose(0, 2, 1, 3, 4)
+    if width != d:
+        # packed heads: [L, rows, p, T, D] -> [L, rows, T, p * D]
+        seq_kv = seq_kv.reshape(l, rows, width // d, t_pad, d).transpose(
+            0, 1, 3, 2, 4).reshape(l, rows, t_pad, width)
+    # [L, rows, n, page, width] -> [L, n, rows, page, width]
+    chunks = seq_kv.reshape(l, rows, n, page, width).transpose(
+        0, 2, 1, 3, 4)
     return pages.at[:, page_ids].set(chunks)
 
 

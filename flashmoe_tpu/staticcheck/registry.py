@@ -366,6 +366,7 @@ STRUCTURAL_FIELDS = frozenset({
     "routed_scaling_factor", "first_k_dense", "dense_intermediate_size",
     "n_group", "topk_group", "layer_mixers",
     "kda_heads", "kda_head_dim", "kda_conv", "kda_lower_bound",
+    "conv_taps", "qk_norm", "norm_eps",
     "expert_first", "experts_held",
     "dtype", "param_dtype", "accum_dtype",
     "dp", "ep", "tp", "sp", "pp",

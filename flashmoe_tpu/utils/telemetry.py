@@ -247,6 +247,13 @@ SPAN_NAMES: dict[str, str] = {
     "attn.kda_decode":
         "delta-rule linear attention, one recurrence step over the "
         "slots' float32 state (decode)",
+    "attn.conv_prefill":
+        "gated short convolution over a span: the taps' shifted products "
+        "from the carried inputs on (prefill, chunked prefill, training "
+        "forward)",
+    "attn.conv_decode":
+        "gated short convolution, one step over the slots' carried "
+        "inputs (decode)",
     "moe.route_groups":
         "group-limited routing: the groups' scores and the mask of the "
         "experts outside the kept groups",

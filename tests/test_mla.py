@@ -449,31 +449,45 @@ def _primitives(jaxpr):
             yield from _primitives(sub)
 
 
-def test_mla_programs_compute_routed_rows_and_kv_programs_as_before(params):
-    """The MLA serving programs hold three ragged products a mixture layer
-    and no [E, S, .] capacity buffer; a K/V model's decode program holds
-    no ragged product (its arithmetic is the capacity arm's, as before)."""
+def test_mla_programs_compute_routed_rows_and_kv_programs_as_before(
+        params, monkeypatch):
+    """On the routed rows (since ISSUE 33 ONE rule over what the arms hold
+    picks the arm, ``ops/moe.expert_arm``, whatever the attention kind; a
+    toy's spans all fit the capacity arm's buffer, so it is given none
+    here) the MLA serving programs hold three ragged products a mixture
+    layer and no [E, S, .] capacity buffer, and so does a K/V model's
+    decode program; with the buffer it has, a toy of either kind takes
+    the capacity arm: no ragged product."""
+    from flashmoe_tpu.ops import moe as moe_ops
+
     cache, tables = _filled_cache(params)
     toks, pos = jnp.asarray([7, 0]), jnp.asarray([19, 0])
-    jaxpr = jax.make_jaxpr(eng._paged_decode_step.__wrapped__,
-                           static_argnums=(1,))(
-        params, CFG, cache, toks, tables, pos)
-    prims = list(_primitives(jaxpr.jaxpr))
-    assert prims.count("ragged_dot_general") + prims.count("ragged_dot") \
-        == 3 * len(CFG.moe_layer_indices)
-    e, inter = CFG.num_experts, CFG.intermediate_size
-    assert not [v.aval.shape for v in _vars(jaxpr.jaxpr)
-                if hasattr(v.aval, "shape") and len(v.aval.shape) == 3
-                and v.aval.shape[0] == e and v.aval.shape[2] == inter
-                and v.aval.shape[1] == 2]            # [E, S=2 slots, I]
     ds, p = _tiny_softmax_layer()
     del p
     model = init_params(jax.random.PRNGKey(0), ds)
     kv = init_paged_cache(ds, 24, 8)
-    jaxpr = jax.make_jaxpr(eng._paged_decode_step.__wrapped__,
-                           static_argnums=(1,))(
-        model, ds, kv, toks, tables, pos)
-    assert not [n for n in _primitives(jaxpr.jaxpr) if "ragged" in n]
+
+    def decode_jaxpr(weights, cfg, pool):
+        # a function of its own a call: a traced one is kept by identity
+        step = lambda w, c, *a: eng._paged_decode_step.__wrapped__(w, c, *a)
+        return jax.make_jaxpr(step, static_argnums=(1,))(
+            weights, cfg, pool, toks, tables, pos).jaxpr
+
+    for cfg, weights, pool in ((CFG, params, cache), (ds, model, kv)):
+        assert not [n for n in _primitives(decode_jaxpr(weights, cfg, pool))
+                    if "ragged" in n]
+    monkeypatch.setattr(moe_ops, "_CAPACITY_BUFFER_BYTES", 0)
+    jaxpr = decode_jaxpr(params, CFG, cache)
+    prims = list(_primitives(jaxpr))
+    assert prims.count("ragged_dot_general") + prims.count("ragged_dot") \
+        == 3 * len(CFG.moe_layer_indices)
+    e, inter = CFG.num_experts, CFG.intermediate_size
+    assert not [v.aval.shape for v in _vars(jaxpr)
+                if hasattr(v.aval, "shape") and len(v.aval.shape) == 3
+                and v.aval.shape[0] == e and v.aval.shape[2] == inter
+                and v.aval.shape[1] == 2]            # [E, S=2 slots, I]
+    assert len([n for n in _primitives(decode_jaxpr(model, ds, kv))
+                if "ragged" in n]) == 3 * len(ds.moe_layer_indices)
 
 
 def test_pool_shape_and_bytes_a_token():

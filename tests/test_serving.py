@@ -1252,7 +1252,7 @@ def _parent_span_step(params, cfg, pools, toks, block_tables, positions,
     differently)."""
     from flashmoe_tpu.models.generate import lm_logits, lm_logits_span
     from flashmoe_tpu.models.transformer import _rope, rms_norm
-    from flashmoe_tpu.ops.moe import moe_layer
+    from flashmoe_tpu.ops.moe import expert_arm, moe_layer
     from flashmoe_tpu.serving.kvcache import PagedKVCache
 
     def store_tokens(pages, span_kv, page_ids, rows):
@@ -1318,8 +1318,14 @@ def _parent_span_step(params, cfg, pools, toks, block_tables, positions,
         f_in = rms_norm(x, layer["ffn_norm"])
         layer_cfg = cfg if li in cfg.moe_layer_indices else cfg.replace(
             num_experts=1, expert_top_k=1, num_shared_experts=0)
+        # the ONE line that is not the parent's: since ISSUE 33 every
+        # serving span takes its experts by ``ops/moe.expert_arm`` (at
+        # these sizes the capacity arm, the parent's); everything around
+        # it is held
         o = moe_layer(layer["moe"], f_in.reshape(b * t_span, -1),
-                      layer_cfg, use_pallas=False)
+                      layer_cfg, use_pallas=False,
+                      routed_rows=expert_arm(layer_cfg, b * t_span)
+                      == "routed_rows")
         x = x + o.out.reshape(b, t_span, -1).astype(x.dtype)
 
     if row is not None:

@@ -529,11 +529,18 @@ def test_backlog_decode_step_is_the_program_the_ledger_measured(
     text = compiled.as_text()
     assert compiled.memory_analysis().alias_size_in_bytes \
         >= 2 * 6 * 2048 * 16 * 16 * 128 * 2                  # 1.61 GB
-    assert 9.3e9 < _program_bytes(compiled) < 11e9   # 9.54 / 9.59 / 10.38
+    # 9.54 / 9.59 / 9.72 (the chunk 10.38 with E x S rows)
+    assert 9.3e9 < _program_bytes(compiled) < 11e9
     assert not re.findall(
         r"^.*= bf16\[6,2048,16,16,128\]\S* copy\(.*$", text, re.M)
     assert "moe.gate" in text and "moe.expert" in text
-    kernels = [n for n, _ in _custom_call_names(text)]
+    # since ISSUE 33 ONE rule picks the experts' arm
+    # (``ops/moe.expert_arm``): the capacity arm for the decode and verify
+    # steps (32 and 160 rows: what they compiled to before), XLA's
+    # grouped matmul over the 6144 routed rows for the 1024-token chunk
+    assert (text.count("ragged-dot-metadata = ") >= 1) == (program
+                                                           == "chunk")
+    kernels = _fm_kernels(text)
     if program == "chunk":
         assert kernels == []
         assert _arrays_of(text, 16, 2560, 128)      # its gathered context
@@ -547,12 +554,13 @@ def test_backlog_decode_step_is_the_program_the_ledger_measured(
 
 def test_backlog_whole_prompt_prefill_fits_beside_the_pool(
         backlog_programs):
-    """A 2048-token prompt at once: 9.76 GB as compiled (the weights,
-    f32 scores of 16 heads over 2048 x 2048, the experts over E x S
-    rows), which leaves the engine's pool its 1.61 GB; the program holds
-    no pool and hands back one K and one V run for ``store_prefill``."""
+    """A 2048-token prompt at once: 8.33 GB as compiled (the weights,
+    f32 scores of 16 heads over 2048 x 2048, the experts over the 12288
+    routed rows; 9.76 GB with E x S rows before ISSUE 33), which leaves
+    the engine's pool its 1.61 GB; the program holds no pool and hands
+    back one K and one V run for ``store_prefill``."""
     compiled = backlog_programs["prefill"].compile()
-    assert abs(_program_bytes(compiled) / 9.7633e9 - 1) < 0.01
+    assert abs(_program_bytes(compiled) / 8.3298e9 - 1) < 0.01
     logits, k_run, v_run = jax.tree.leaves(compiled.out_info)
     assert logits.shape == (102400,) and logits.dtype == jnp.float32
     assert k_run.shape == v_run.shape == (6, 16, 2048, 128)
@@ -629,7 +637,95 @@ def test_hybrid_programs_fit_the_chip_with_state_and_pool_in_place(
             assert _arrays_of(text, 40960, 16 * width) == []
         assert " scatter(" not in text
         assert "attn.kda_decode" in text and "attn.mla_decode" in text
-        assert len(jax.tree.leaves(compiled.out_info)) == 1 + 3 + 1
+        # logits, the cache's three arrays, experts_touched and held_rows
+        assert len(jax.tree.leaves(compiled.out_info)) == 1 + 3 + 2
     else:
         assert kernels == []
         assert "attn.kda_prefill" in text and "attn.mla_prefill" in text
+
+
+@pytest.fixture(scope="module")
+def lfm2_programs(one_chip):
+    """The widest decode program, the widest 1024-token chunk and the
+    largest padded prefill of the cell ``lfm2_24b.serve.shortchat``
+    (LFM2-24B-A2B: the leading dense layer + two periods, 7 'conv' layers
+    and 2 attention layers of 8 K/V heads of 64, 64 experts and the whole
+    vocabulary, bf16; 128 slots, a 20480 x 16-token K/V pool of TWO
+    layers whose rows hold two heads, tables at their 320 pages), lowered
+    as the engine runs them: the whole cache donated, traced as on a
+    TPU."""
+    from flashmoe_tpu.models.presets import PRESETS
+    from flashmoe_tpu.models.transformer import init_params
+    from flashmoe_tpu.serving import engine as eng
+    from flashmoe_tpu.serving.kvcache import init_paged_cache
+
+    cfg = PRESETS["lfm2-24b-a2b"](
+        num_layers=9, first_k_dense=1, param_dtype=jnp.bfloat16,
+        layer_mixers=("conv", "mha") + ("conv",) * 3 + ("mha",)
+        + ("conv",) * 3)
+    on = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = on(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on(jax.eval_shape(lambda: init_paged_cache(cfg, 20480, 16, 128)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32, sharding=one_chip)
+    with pytest.MonkeyPatch.context() as mp:        # traced as on a TPU
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return {
+            "decode": eng._INPLACE["_paged_decode_step"].lower(
+                params, cfg, cache, i32(128), i32(128, 320), i32(128)),
+            "chunk": eng._INPLACE["_prefill_chunk"].lower(
+                params, cfg, cache, i32(1, 1024), i32(320), i32(64), i32(),
+                i32(), i32()),
+            "prefill": eng._prefill_padded.lower(
+                params, cfg, i32(1, 1024), i32())}
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "prefill"])
+def test_lfm2_programs_fit_the_chip_with_inputs_and_pool_in_place(
+        lfm2_programs, program):
+    """12.03 GB (decode), 13.38 GB (chunk) and 10.83 GB (a 1024-token
+    prompt at once) as compiled, under the cell's 15.0: 10.63 GB of
+    weights (the tied head a second array), and the K/V pool (1.34 GB:
+    4 096 B a token, the 8 heads of 64 stored as 4 rows of 128 lanes, no
+    padding) and the convolutions' inputs (7 MB) once each, aliased to
+    the outputs; no copy of either; the experts by ``ops/moe.expert_arm``
+    (below); the decode program is
+    one step a 'conv' layer and reads the two attention layers' pages in
+    place: Mosaic takes ``fm_paged_decode`` handed the packed rows, TWO
+    calls, no gathered context; the chunk keeps the gather arm."""
+    compiled = lfm2_programs[program].compile()
+    text = compiled.as_text()
+    pool, inputs = r"bf16\[2,20480,4,16,128\]", r"bf16\[7,128,4096\]"
+    lo, hi = {"decode": (11.8e9, 12.3e9), "chunk": (13.0e9, 13.8e9),
+              "prefill": (10.6e9, 11.1e9)}[program]
+    assert lo < _program_bytes(compiled) < hi
+    # the experts by ``ops/moe.expert_arm``: the capacity arm for the
+    # decode step's 128 rows ([64, 128, .] buffers, 33 MB of dispatch),
+    # XLA's grouped matmul over the 4096 routed rows of 1024 tokens
+    assert (text.count("ragged-dot-metadata = ") >= 1) == (program
+                                                           != "decode")
+    assert "[64,2048,1536]" in text
+    kernels = _fm_kernels(text)
+    if program == "prefill":
+        assert kernels == [] and "attn.conv_prefill" in text
+        assert len(jax.tree.leaves(compiled.out_info)) == 1 + 3
+        return
+    cache_bytes = 2 * 2 * 20480 * 4 * 16 * 128 * 2 + 7 * 128 * 4096 * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+    for shape in (pool, inputs):
+        assert re.search(shape, text)
+        assert not re.findall(rf"^.*= {shape}\S* copy\(.*$", text, re.M)
+    # a pool of unpacked 64-wide heads would be padded to twice the bytes
+    assert "[2,20480,8,16,64]" not in text
+    if program == "decode":
+        assert kernels == ["fm_paged_decode"] * 2
+        assert _arrays_of(text, 128, 8, 5120, 64) == []   # no context
+        assert _arrays_of(text, 128, 4, 5120, 128) == []
+        assert " scatter(" not in text
+        assert "attn.conv_decode" in text
+        # logits, K and V pool, the inputs, experts_touched
+        assert len(jax.tree.leaves(compiled.out_info)) == 1 + 3 + 1
+    else:
+        assert kernels == [] and "attn.conv_prefill" in text
