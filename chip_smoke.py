@@ -243,6 +243,9 @@ def run_engine(params, cfg, serve, reqs, arrivals, mesh=None):
         # the steps whose decode program read each slot's pages in place
         summary["decode_kernel_steps"] = engine.metrics.counters.get(
             "serve.decode_kernel_steps", 0)
+        # ... and those dispatched ahead of the step's read-back
+        summary["decode_ahead_steps"] = engine.metrics.counters.get(
+            "serve.decode_ahead_steps", 0)
     finally:
         engine.close()
     return outputs, seen, summary
@@ -460,6 +463,7 @@ def _serve_case(params, cfg, serve, seed, cut, extra):
           "logits_rel_err_median": float(np.median(errs)),
           "logits_rel_err_max": float(errs.max()),
           "failures": bad[:8],
+          "decode_ahead_steps": summary["decode_ahead_steps"],
           "arms": {"kernel_steps": summary["decode_kernel_steps"],
                    "gather_arm_kernel_steps":
                        g_summary["decode_kernel_steps"],

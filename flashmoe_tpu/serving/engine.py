@@ -11,15 +11,22 @@ slowest member:
 * **decode** — one jitted step advances every active slot: sample from
   each slot's pending logits (greedy / temperature / top-k / top-p,
   per-request), feed the sampled tokens, paged attention over each
-  slot's block table, MoE FFN on the batch rows;
+  slot's block table, MoE FFN on the batch rows.  The sampled tokens
+  stay on the device as the decode program's feed, and the step reads
+  them AFTER it has dispatched that program (who decodes is known from
+  the counts alone), so the host's work runs under the device's;
 * **retirement** — a slot leaves when it emits a stop token or its
   ``max_new_tokens``-th token (``serve.retire`` with TTFT/TPOT); its
-  pages return to the pool and the next admission reuses them;
+  pages return to the pool and the next admission reuses them.  A stop
+  token is found out after the slot's next row was dispatched: that one
+  row is wasted (it lands in a page the slot still owned);
 * **eviction** — when decode needs a page and the pool is dry, the
   youngest active request is preempted back to the queue head
   (``serve.evict``): its pages free immediately, its already-delivered
   tokens stand, and it later re-prefills prompt+generated and
-  continues.
+  continues.  A step whose growth would have to evict reads its tokens
+  first (the victim's resumed prompt is built from them), as does every
+  step with speculation armed (the drafts are).
 
 Everything host-side is a pure function of the submitted requests and
 their arrival steps, and the page allocator is LIFO — so a seeded drill
@@ -47,7 +54,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from collections import deque
+from collections import Counter, deque
 
 import jax
 import jax.numpy as jnp
@@ -314,7 +321,7 @@ def _prefill_chunk(params, cfg: MoEConfig, pools, chunk_toks,
 
 
 def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
-               positions, mixture=None):
+               positions, mixture=None, pad_token=None):
     """A span of T tokens a slot through the layers, over the paged
     cache: the body of the decode (T = 1) and verify programs and of
     their EP-sharded twins (which pass ``mixture``).  toks: [B, T];
@@ -322,7 +329,10 @@ def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
     what the layers counted: ``span_forward``'s).  Row b is slot b: its
     recurrent state is read and written in place, and a row whose table
     is all scratch (idle, or between two chunks of its prompt) leaves its
-    state alone.
+    state alone.  With a ``pad_token`` such a row is fed it, whatever
+    ``toks`` holds there: the engine hands the decode step the sampler's
+    array as it lies on the device, and the sampler gives an idle row the
+    ``argmax`` of stale logits.
 
     Span positions past the gathered context (a slot drafted into its
     context ceiling) route their writes to the scratch page and produce
@@ -338,8 +348,10 @@ def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
             block_tables, jnp.clip(pos // page, 0, ntab - 1), axis=1),
         jnp.int32(SCRATCH_PAGE))
     rows = jnp.where(valid, pos % page, 0)
-    live = (jnp.broadcast_to(block_tables[:, :1] != SCRATCH_PAGE, pos.shape)
-            if cfg.state_layers else None)
+    owns = block_tables[:, :1] != SCRATCH_PAGE      # the row has a tenant
+    if pad_token is not None:
+        toks = jnp.where(owns, toks, jnp.int32(pad_token))
+    live = (jnp.broadcast_to(owns, pos.shape) if cfg.state_layers else None)
     # a short span over a long context: MLA's absorbed form
     x, pools, _, counted = span_forward(
         params, cfg, params["embed"].astype(cfg.dtype)[toks], pools, pos,
@@ -348,18 +360,21 @@ def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
     return x, pools, counted
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
+@functools.partial(jax.jit, static_argnames=("cfg", "pad_token"))
 def _paged_decode_step(params, cfg: MoEConfig, pools, toks,
-                       block_tables, positions):
+                       block_tables, positions, pad_token=None):
     """One decode step for the whole slot grid: the span path at T = 1.
 
     toks: [B] int32 tokens to feed; block_tables: [B, n] page ids
     (bucketed); positions: [B] write positions (= each slot's current
-    length; inactive slots pass 0 with an all-scratch table).  Returns
+    length; inactive slots pass 0 with an all-scratch table, and with a
+    ``pad_token`` are fed it whatever ``toks`` holds: see
+    :func:`_span_step`).  Returns
     (logits [B, V] f32, pools, what the layers counted: a dict of scalars,
     empty for a config whose layers count nothing)."""
     x, pools, counted = _span_step(params, cfg, pools, toks[:, None],
-                                   block_tables, positions)
+                                   block_tables, positions,
+                                   pad_token=pad_token)
     return lm_logits(params, cfg, x), pools, counted
 
 
@@ -399,9 +414,10 @@ def _paged_verify_step(params, cfg: MoEConfig, pools, toks,
 # undonated programs above are what keeps its inputs: tests and
 # ``lower()`` hold them against each other over one pool.
 _INPLACE = {
-    fn.__name__: jax.jit(fn.__wrapped__, static_argnames=("cfg",),
+    fn.__name__: jax.jit(fn.__wrapped__, static_argnames=("cfg", *static),
                          donate_argnames=("pools",))
-    for fn in (_prefill_chunk, _paged_decode_step, _paged_verify_step)}
+    for fn, *static in ((_prefill_chunk,), (_paged_decode_step, "pad_token"),
+                        (_paged_verify_step,))}
 
 #: ``store_prefill`` with the pool donated, as ONE program: an admission
 #: writes a prompt's pages into the pool where it lies (called eagerly, the
@@ -440,8 +456,10 @@ def _ep_param_specs(params, cfg: MoEConfig):
     return tree_map_with_path(spec, params)
 
 
-def _ep_decode_fn(mesh, cfg: MoEConfig, params, *, span: bool = False):
-    """Build (and cache per (mesh, cfg, param-structure, span)) the
+def _ep_decode_fn(mesh, cfg: MoEConfig, params, *, span: bool = False,
+                  pad_token=None):
+    """Build (and cache per (mesh, cfg, param-structure, span,
+    pad_token: what :func:`_span_step` feeds an idle row)) the
     EP-sharded twin of :func:`_paged_decode_step` or, with ``span``, of
     :func:`_paged_verify_step`: one jitted ``shard_map`` whose body is
     the same :func:`_span_step` on the LOCAL slot rows and the LOCAL
@@ -454,7 +472,7 @@ def _ep_decode_fn(mesh, cfg: MoEConfig, params, *, span: bool = False):
     import jax.tree_util as jtu
     from jax.sharding import PartitionSpec as P
 
-    key = (mesh, cfg, jtu.tree_structure(params), span)
+    key = (mesh, cfg, jtu.tree_structure(params), span, pad_token)
     cached = _EP_CACHE.get(key)
     if cached is not None:
         return cached
@@ -467,7 +485,8 @@ def _ep_decode_fn(mesh, cfg: MoEConfig, params, *, span: bool = False):
         x, pools, _ = _span_step(params, cfg, pools,
                                  toks if span else toks[:, None],
                                  block_tables, positions,
-                                 mixture=ragged_ep.decode_moe_rows)
+                                 mixture=ragged_ep.decode_moe_rows,
+                                 pad_token=pad_token)
         return head(params, cfg, x), pools
 
     slab = PagedKVCache(P(None, "ep"), P(None, "ep"))
@@ -757,7 +776,8 @@ class ServingEngine:
                 raise ValueError(
                     f"ep_shards={d} needs an 'ep' mesh axis of size "
                     f"{d}, got mesh axes {dict(self.mesh.shape)}")
-            self._ep_fn = _ep_decode_fn(self.mesh, cfg, params)
+            self._ep_fn = _ep_decode_fn(self.mesh, cfg, params,
+                                        pad_token=self.serve.pad_token)
 
         # ---- speculative decoding (serving/speculate.py) -------------
         # off (None) keeps the engine byte-identical: no draft tables,
@@ -1246,22 +1266,41 @@ class ServingEngine:
         resumed prompt carries its earlier output)."""
         return len(s.req.prompt) - len(s.orig.prompt) + len(s.emitted)
 
-    def _grow_pages(self, span: int = 0) -> None:
-        """Allocate the next page for every active slot whose write
-        position crosses its allocated frontier, evicting the youngest
-        request when the pool runs dry.  ``span`` extra positions (the
-        verify step's drafted span) are pre-covered; the target index
-        clamps to the slot's table width — the host truncates drafts to
-        fit the context ceiling, and the verify graph routes any
-        residual over-the-edge write to the scratch page."""
+    def _next_page(self, s: _Slot, span: int = 0) -> int:
+        """Index, in its table, of the page ``s`` writes its next row (and
+        ``span`` more) into, clamped to the table's width."""
+        return min((s.length + span) // self.serve.page_size,
+                   self.serve.max_pages_per_slot - 1)
+
+    def _growth_fits(self, rows) -> bool:
+        """Whether the pool, as it is, holds the next page of every slot
+        of ``rows`` that stands at a page edge: :meth:`_grow_pages` then
+        evicts nobody."""
+        need = Counter()            # by page shard
+        for i in rows:
+            s = self.slots[i]
+            need[self._shard_of(i)] += max(
+                0, self._next_page(s) + 1 - len(s.pages))
+        free = (self.pool.shard_free_pages if self.serve.ep_shards > 1
+                else lambda shard: self.pool.free_pages)
+        return all(n <= free(shard) for shard, n in need.items())
+
+    def _grow_pages(self, rows, span: int = 0) -> None:
+        """Allocate the next page for every slot of ``rows`` (decoding
+        slots) whose write position crosses its allocated frontier,
+        evicting the youngest request when the pool runs dry (the caller
+        has read the step's tokens then: :meth:`_growth_fits`).  ``span``
+        extra positions (the verify step's drafted span) are pre-covered;
+        the target index clamps to the slot's table width — the host
+        truncates drafts to fit the context ceiling, and the verify graph
+        routes any residual over-the-edge write to the scratch page."""
         shard = (self._shard_of if self.serve.ep_shards > 1
                  else lambda i: None)
-        for i in list(self._decoding()):
+        for i in rows:
             s = self.slots[i]
-            if s is None:
+            if s is None:                   # evicted for a row before it
                 continue
-            need_idx = min((s.length + span) // self.serve.page_size,
-                           self.serve.max_pages_per_slot - 1)
+            need_idx = self._next_page(s, span)
             while need_idx >= len(s.pages):
                 got = self._alloc_pages(i, 1)
                 if got is not None:
@@ -1324,7 +1363,7 @@ class ServingEngine:
 
         # pre-cover the span's write positions (may evict — re-fetch)
         self._phase("serve.grow")
-        self._grow_pages(span=k)
+        self._grow_pages(active, span=k)
         active = self._decoding()
         if not active:
             return 0
@@ -1374,10 +1413,10 @@ class ServingEngine:
         self._phase("serve.sample")
         # canonical samples for every drafted position: column t-1
         # logits, position-(base+t-1) key, the same sampler numerics
-        cand = self._sample(
+        cand = np.asarray(self._sample(
             span_logits[:, :k, :].reshape(sv.max_batch * k, -1),
             _sampler_rows(rows), len(active) * k
-        ).reshape(sv.max_batch, k)
+        )).reshape(sv.max_batch, k)
 
         # ---- accept the agreeing prefix; roll back the rest ----------
         self._phase("serve.deliver")
@@ -1580,23 +1619,59 @@ class ServingEngine:
         self._ctx_pages = (read, max(0.0, read - float(own.mean())),
                            len(lengths), arm)
 
-    def _sample(self, logits, knobs, n_rows: int) -> np.ndarray:
-        """The tokens :func:`_sample_dynamic` gives ``logits`` on
-        ``knobs`` (:func:`_sampler_rows`; ``n_rows`` of its rows are
-        not idle), read back.  What the rows ask of the program is
-        counted from the host's arrays by the program's own rule
-        (:func:`_rows_ask`): rows with a temperature are drawn, and
+    def _sample(self, logits, knobs, n_rows: int):
+        """Dispatch :func:`_sample_dynamic` on ``logits`` and ``knobs``
+        (:func:`_sampler_rows`; ``n_rows`` of its rows are not idle) and
+        return its tokens where they lie, on the device: the caller reads
+        them when it needs them on the host.  What the rows ask of the
+        program is counted from the host's arrays by the program's own
+        rule (:func:`_rows_ask`): rows with a temperature are drawn, and
         those of them that truncate make it sort (0 of them: it sorted
         nothing)."""
         drawn, use_k, use_p = _rows_ask(*knobs[2:], logits.shape[-1])
         self._sampled += np.array(
             [n_rows, drawn.sum(), (drawn & (use_k | use_p)).sum()])
-        return np.asarray(_sample_dynamic(logits, *knobs))
+        return _sample_dynamic(logits, *knobs)
+
+    def _deliver(self, rows, toks) -> int:
+        """Read the step's tokens (``toks``: the sampler's array, a row a
+        slot) and hand each slot of ``rows`` its own: appended, the clocks
+        stamped, the request retired on a stop token or on its last token
+        by count.  THE STEP'S ONE READ-BACK: it returns when the sampler
+        has finished, which waits for the decode program the step before
+        dispatched and for no program dispatched after the sampler.
+        Returns the tokens delivered."""
+        toks = np.asarray(toks)
+        now = self._phase("serve.deliver")
+        for i in rows:
+            s = self.slots[i]
+            tok = int(toks[i])
+            s.emitted.append(tok)
+            if s.first_token_s is None:
+                s.first_token_s = now
+                s.prefill_ms = (now - s.admit_s) * 1e3
+            elif s.last_token_s is not None:
+                gap_ms = (now - s.last_token_s) * 1e3
+                if gap_ms > s.gap_max_ms:
+                    s.gap_max_ms = gap_ms
+            s.last_token_s = now
+            if self.recorder is not None:
+                self._delivered_now[s.orig.rid] = 1
+            if (tok in s.req.stop_tokens
+                    or self._delivered(s) >= s.orig.max_new_tokens):
+                self._retire(i, s)
+        return len(rows)
 
     def step(self) -> dict:
-        """One engine iteration: admit -> sample/retire -> decode.
-        Returns the step's flight record (also appended to the
-        recorder when one is attached)."""
+        """One engine iteration: admit -> sample -> grow -> decode ->
+        read the tokens, deliver, retire (the decode program is dispatched
+        AHEAD of the step's one read-back); sample -> read, deliver,
+        retire -> grow -> decode on a step where the host needs the tokens
+        in between (speculation armed, growth that would evict).  Either
+        way every token sampled in the step is delivered and every
+        finished request retired when it returns.  Returns the step's
+        flight record (also appended to the recorder when one is
+        attached)."""
         with trace_span("serve.step"):
             try:
                 return self._step()
@@ -1628,66 +1703,61 @@ class ServingEngine:
         self._advance_prefill()
 
         # sample each decoding slot's next token from its pending
-        # logits (slots mid-chunked-prefill have none yet)
+        # logits (slots mid-chunked-prefill have none yet): dispatched,
+        # not read
         self._phase("serve.sample_keys", beat="prefill")
         emitted_now = 0
-        active = self._decoding()
-        if active:
+        sampled = self._decoding()
+        active, toks, ahead = [], None, False
+        if sampled:
             rows = [None] * sv.max_batch
-            for i in active:
+            for i in sampled:
                 s = self.slots[i]
                 rows[i] = (s.req, self._delivered(s))
             knobs = _sampler_rows(rows)
             self._phase("serve.sample")
-            # the step's one read-back: it waits for the decode program
-            # the step before dispatched, then for the sampler's
-            toks = self._sample(self._logits, knobs, len(active))
-            now = self._phase("serve.deliver")
-            for i in active:
-                s = self.slots[i]
-                tok = int(toks[i])
-                s.emitted.append(tok)
-                emitted_now += 1
-                if s.first_token_s is None:
-                    s.first_token_s = now
-                    s.prefill_ms = (now - s.admit_s) * 1e3
-                elif s.last_token_s is not None:
-                    gap_ms = (now - s.last_token_s) * 1e3
-                    if gap_ms > s.gap_max_ms:
-                        s.gap_max_ms = gap_ms
-                s.last_token_s = now
-                if self.recorder is not None:
-                    self._delivered_now[s.orig.rid] = 1
-                done = (tok in s.req.stop_tokens
-                        or self._delivered(s) >= s.orig.max_new_tokens)
-                if done:
-                    self._retire(i, s)
-        self.stats["tokens"] += emitted_now
+            toks = self._sample(self._logits, knobs, len(sampled))
+            # who decodes is known without the tokens, but for a stop
+            # token: a slot whose token of this step is its last by count
+            # is not fed.  The decode step is dispatched AHEAD of the
+            # read-back unless what stands between them needs the tokens
+            # on the host: the drafts of an armed speculation are built
+            # from them, and an eviction rebuilds its victim's prompt from
+            # them (growth the pool cannot cover)
+            active = [i for i in sampled
+                      if self._delivered(self.slots[i]) + 1
+                      < self.slots[i].orig.max_new_tokens]
+            ahead = bool(active and self._spec is None
+                         and self._growth_fits(active))
+            if not ahead:
+                emitted_now += self._deliver(sampled, toks)
+                active = self._decoding()
 
         # feed the survivors one decode step — speculative (draft +
         # span verify, possibly emitting extra tokens) when armed and
         # anything drafted, else the plain one-token step
         self._phase("serve.grow", beat="sample")
-        active = self._decoding()
         if active:
-            self._grow_pages()
-            active = self._decoding()
+            self._grow_pages(active)
+            active = [i for i in active if self.slots[i] is not None]
         n_extra = None
         if active and self._spec is not None:
             n_extra = self._spec_decode(active)
             if n_extra is not None:
                 emitted_now += n_extra
-                self.stats["tokens"] += n_extra
         if active and n_extra is None:
+            # positions and block tables from the host's state; the FEED
+            # is the sampler's array where it lies: the program feeds
+            # sv.pad_token to every row whose table is all scratch (idle,
+            # mid prefill, retired or evicted in this step, at its last
+            # token by count)
             self._phase("serve.decode_feed")
-            feed = np.full((sv.max_batch,), sv.pad_token, np.int32)
             positions = np.zeros((sv.max_batch,), np.int32)
             tables = np.full((sv.max_batch, sv.max_pages_per_slot),
                              SCRATCH_PAGE, np.int32)
             longest = 1
             for i in active:
                 s = self.slots[i]
-                feed[i] = s.emitted[-1]
                 positions[i] = s.length
                 tables[i, :len(s.pages)] = s.pages
                 longest = max(longest, s.length + 1)
@@ -1698,15 +1768,15 @@ class ServingEngine:
             self._phase("serve.decode")
             if self._ep_fn is not None:
                 logits, self.cache = self._ep_fn(
-                    self.params, self.cache, jnp.asarray(feed),
+                    self.params, self.cache, toks,
                     jnp.asarray(tables[:, :n_ctx]),
                     jnp.asarray(positions))
             else:
                 logits, self.cache, counted = _INPLACE[
                     "_paged_decode_step"](
-                    self.params, self.cfg, self.cache, jnp.asarray(feed),
+                    self.params, self.cfg, self.cache, toks,
                     jnp.asarray(tables[:, :n_ctx]),
-                    jnp.asarray(positions))
+                    jnp.asarray(positions), pad_token=sv.pad_token)
                 self._counted = counted or None
                 # every slot's state goes through the step and back
                 self._state_bytes += (2 * sv.max_batch
@@ -1716,6 +1786,13 @@ class ServingEngine:
             self._logits = logits
             for i in active:
                 self.slots[i].length += 1
+        if ahead:
+            # the wait for the sampler is the sampler's phase; the decode
+            # program runs under it and under all that follows
+            self._phase("serve.sample")
+            emitted_now += self._deliver(sampled, toks)
+            self.metrics.count("serve.decode_ahead_steps")
+        self.stats["tokens"] += emitted_now
 
         # telemetry
         self._phase("serve.account", beat="decode")
@@ -1785,6 +1862,9 @@ class ServingEngine:
             "sample_rows": sample_rows, "sample_drawn": sample_drawn,
             "sample_sorted": sample_sorted,
         }
+        if sampled:
+            rec["readback"] = ("after_dispatch" if ahead
+                               else "before_dispatch")
         if self.cfg.state_layers:
             rec["state_bytes"] = self._state_bytes
         # the latest decode program that has finished (the very first

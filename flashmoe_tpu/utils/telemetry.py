@@ -295,17 +295,23 @@ SPAN_NAMES: dict[str, str] = {
         "index filled into numpy arrays on the host (the sampler "
         "program derives the keys from them; nothing is read back)",
     "serve.sample":
-        "step phase: the sampler program through the tokens on the "
-        "host, the step's one read-back (it waits for the decode step "
-        "dispatched before, then for the sampler)",
+        "step phase: the sampler program dispatched and, when the step "
+        "reads them, the wait for its tokens on the host, the step's one "
+        "read-back (it waits for the decode step dispatched the step "
+        "before, then for the sampler).  On a step that decodes ahead "
+        "(``readback: after_dispatch``) the phase opens twice, the "
+        "dispatch before ``serve.grow`` and the wait after "
+        "``serve.decode``, and its two times add up",
     "serve.deliver":
-        "step phase: tokens appended, first-token stamps, retirements",
+        "step phase: tokens appended, first-token stamps, retirements "
+        "(after ``serve.decode`` on a step that decodes ahead)",
     "serve.grow":
         "step phase: next KV page for every slot at a page edge "
-        "(evictions happen here)",
+        "(evictions happen here, on a step that read its tokens first)",
     "serve.decode_feed":
-        "step phase: the decode or verify step's feed, positions and "
-        "block tables built on the host",
+        "step phase: the decode step's positions and block tables built "
+        "on the host (its feed is the sampler's array on the device); "
+        "the verify step's feed too",
     "serve.account":
         "step phase: gauges, sketches and the step's flight record",
     "train.data_pull": "host wait on the data iterator",
