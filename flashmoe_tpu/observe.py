@@ -22,6 +22,7 @@ Usage::
     python -m flashmoe_tpu.observe --json flight.jsonl
     python -m flashmoe_tpu.observe --ledger obs/ledger.jsonl
     python -m flashmoe_tpu.observe --serving obs/flight.jsonl obs/decisions.jsonl
+    python -m flashmoe_tpu.observe --gaps <profiler trace dir> obs/flight.jsonl
     python -m flashmoe_tpu.observe --postmortem /path/to/bundles
     python -m flashmoe_tpu.observe --trace 3 obs/trace.jsonl
     python -m flashmoe_tpu.observe --merge obs/telemetry.*.jsonl
@@ -32,7 +33,9 @@ Usage::
 decision dumps); ``--serving`` renders the serving-engine report
 (TTFT/TPOT percentiles through the shared bounded-memory quantile
 sketch, queue depth, cache occupancy, the prefill-vs-decode planner
-split — docs/SERVING.md); ``--postmortem`` renders a triage report of
+split — docs/SERVING.md); ``--gaps`` lists the device's idle gaps of a
+profiler trace beside the serving engine's records of those moments
+(docs/OBSERVABILITY.md "Why the device waited"); ``--postmortem`` renders a triage report of
 the crash bundle(s) written by
 :mod:`flashmoe_tpu.profiler.postmortem`; ``--trace <rid>`` renders one
 request's end-to-end timeline (eviction gaps included) from
@@ -1155,6 +1158,123 @@ def render_postmortem_text(rep: dict) -> str:
     return "\n".join(lines)
 
 
+def read_gaps_trace(trace_dir: str) -> dict | None:
+    """What :func:`gaps_report` needs of the newest ``.xplane.pb`` under
+    ``trace_dir``, times in ns on the profiler's clock (an event's
+    ``start_ns`` counts from the trace's ``profile_start_time``):
+    ``busy``, the first device's operations ``(start, duration)`` in
+    order; ``spans``, the host's ``serve.*`` / ``bench.*`` events
+    ``(start, duration, name, step)`` in order, ``step`` the stat of a
+    ``serve.step`` event.  ``None`` where there is no trace."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    found = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                             recursive=True))
+    if not found:
+        return None
+    planes = list(ProfileData.from_file(found[-1]).planes)
+    base = next((int(v) for p in planes for k, v in p.stats
+                 if k == "profile_start_time"), 0)
+    device = min((p.name for p in planes
+                  if p.name.startswith("/device:TPU:")), default=None)
+    busy, spans = [], []
+    for plane in planes:
+        lines = {ln.name: ln for ln in plane.lines}
+        if plane.name == device:
+            ops = lines.get("XLA Ops") or lines.get("XLA Modules")
+            busy = [(base + int(e.start_ns), int(e.duration_ns))
+                    for e in (ops.events if ops else ())]
+        elif plane.name.startswith("/host:"):
+            spans += [(base + int(e.start_ns), int(e.duration_ns), e.name,
+                       dict(e.stats).get("step")
+                       if e.name == "serve.step" else None)
+                      for ln in lines.values() for e in ln.events
+                      if e.name.startswith(("serve.", "bench."))]
+    return {"file": found[-1], "base": base, "busy": sorted(busy),
+            "spans": sorted(spans, key=lambda s: s[:2])}
+
+
+def gaps_report(trace: dict, records: list[dict],
+                min_ms: float = 0.1) -> dict:
+    """The device's idle gaps over ``min_ms`` in ``trace``
+    (:func:`read_gaps_trace`), each beside what the serving engine
+    recorded of that moment.  The engine's records carry ``t0_trace_ns``
+    on the profiler's clock, so a gap finds its ``serve_step`` record (the
+    step it began IN, or the step it came BEFORE: the caller's time is
+    that step's ``between_ms``), the spans open when it began, outermost
+    first, and the ``serve_prefill`` record whose feed it fell into.
+    ``clock_skew_ms``: the largest distance between a ``serve.step`` event
+    and its record's ``t0_trace_ns``."""
+    import bisect
+
+    steps = sorted((r for r in records if r.get("kind") == "serve_step"
+                    and "t0_trace_ns" in r), key=lambda r: r["t0_trace_ns"])
+    by_idx = {r["step"]: r for r in steps}
+    step_t0 = [r["t0_trace_ns"] for r in steps]
+    prefills = [r for r in records if r.get("kind") == "serve_prefill"]
+    spans = trace["spans"]
+    span_t0 = [s[0] for s in spans]
+    skew = max((abs(t - by_idx[step]["t0_trace_ns"])
+                for t, _, _, step in spans if step in by_idx), default=0)
+    rows, idle_ns, end = [], 0, None
+    for t, dur in trace["busy"]:
+        g0, glen = end, t - (end or t)
+        end = max(end or 0, t + dur)
+        idle_ns += max(glen, 0)
+        if glen < min_ms * 1e6:
+            continue
+        i = bisect.bisect_right(step_t0, g0) - 1
+        rec, where = (steps[i], "in") if i >= 0 else (None, None)
+        if rec is not None and g0 > rec["t0_trace_ns"] + rec["step_ms"] * 1e6:
+            rec, where = (steps[i + 1], "before") if i + 1 < len(steps) \
+                else (None, None)
+        hi = bisect.bisect_right(span_t0, g0)
+        stack = sorted(((d, n) for t0, d, n, _ in spans[max(0, hi - 128):hi]
+                        if t0 + d > g0), reverse=True)
+        fed = next((p for p in prefills if p["t0_trace_ns"] <= g0 + glen
+                    and g0 <= p["t0_trace_ns"] + p["host_ms"] * 1e6), None)
+        rows.append({
+            "gap_ms": glen / 1e6, "at_ms": (g0 - trace["base"]) / 1e6,
+            "step": rec and rec["step"], "where": where,
+            "spans": [n for _, n in stack],
+            "prefill": fed and {k: fed[k] for k in (
+                "rid", "form", "pos", "tokens", "rows", "host_ms")},
+            **{k: rec[k] for k in ("host_ms", "between_ms", "cpu_ms",
+                                   "gc_ms", "ctx_switches") if rec}})
+    named = sum(r["gap_ms"] for r in rows
+                if r["step"] is not None and r["spans"])
+    return {"trace": trace.get("file"), "gaps": rows,
+            "idle_ms": idle_ns / 1e6,
+            "gaps_ms": sum(r["gap_ms"] for r in rows), "named_ms": named,
+            "clock_skew_ms": skew / 1e6, "steps": len(steps)}
+
+
+def render_gaps_text(rep: dict) -> str:
+    out = [f"device gaps over the threshold: {len(rep['gaps'])}, "
+           f"{rep['gaps_ms']:.3f} ms of {rep['idle_ms']:.3f} ms idle; "
+           f"{rep['named_ms']:.3f} ms with a step and a span "
+           f"({100 * rep['named_ms'] / max(rep['idle_ms'], 1e-9):.1f} % of "
+           f"the idle time); {rep['steps']} serve_step records; largest "
+           f"|serve.step start - t0_trace_ns| {rep['clock_skew_ms']:.4f} ms",
+           "gap_ms  at_ms  step  host_ms between_ms cpu_ms gc_ms "
+           "ctx_switches  spans  [prefill]"]
+    for r in rep["gaps"]:
+        acct = " ".join(f"{r.get(k, float('nan')):9.3f}" for k in (
+            "host_ms", "between_ms", "cpu_ms", "gc_ms"))
+        p = r["prefill"]
+        fed = (f"  [{p['form']} rid {p['rid']} pos {p['pos']}: {p['tokens']}"
+               f" tokens in {p['rows']} rows, fed in {p['host_ms']} ms]"
+               if p else "")
+        out.append(f"{r['gap_ms']:7.3f} {r['at_ms']:10.3f} "
+                   f"{r['where'] or '-':>6} {r['step']} {acct} "
+                   f"{r.get('ctx_switches', '-')}  "
+                   f"{' > '.join(r['spans']) or '(no span)'}{fed}")
+    return "\n".join(out)
+
+
 def _bar(value: float, peak: float, width: int = 40) -> str:
     n = int(round(width * value / peak)) if peak > 0 else 0
     return "#" * max(n, 1 if value > 0 else 0)
@@ -1285,6 +1405,11 @@ def main(argv=None) -> int:
                     help="render the serving report (engine "
                          "flight/decision dumps: TTFT/TPOT, queue "
                          "depth, cache occupancy, planner split)")
+    ap.add_argument("--gaps", action="store_true",
+                    help="the device's idle gaps in a profiler trace "
+                         "(first file: the trace directory) beside the "
+                         "serving engine's serve_step / serve_prefill "
+                         "records (the other files) of those moments")
     ap.add_argument("--postmortem", metavar="DIR",
                     help="render a triage report of the crash postmortem "
                          "bundle(s) under DIR")
@@ -1314,6 +1439,7 @@ def main(argv=None) -> int:
 
     modes = [m for m, on in (("--ledger", args.ledger),
                              ("--serving", args.serving),
+                             ("--gaps", args.gaps),
                              ("--postmortem", bool(args.postmortem)),
                              ("--trace", args.trace is not None),
                              ("--merge", args.merge),
@@ -1372,6 +1498,18 @@ def main(argv=None) -> int:
         else:
             print(render_merge_text(rep))
         return 0 if rep["records"] else 2
+    if args.gaps:
+        trace = read_gaps_trace(args.files[0])
+        if trace is None:
+            print(f"no .xplane.pb under {args.files[0]!r}", file=sys.stderr)
+            return 2
+        rep = gaps_report(trace, load_jsonl(args.files[1:]))
+        if args.json:
+            json.dump(rep, sys.stdout)
+            print()
+        else:
+            print(render_gaps_text(rep))
+        return 0 if rep["gaps"] else 2
     records = load_jsonl(args.files)
     if not records:
         print("no parseable records found", file=sys.stderr)

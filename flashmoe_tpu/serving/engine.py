@@ -53,6 +53,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import json
+import logging
 import time
 from collections import Counter, deque
 
@@ -75,8 +77,32 @@ from flashmoe_tpu.serving.speculate import (
 )
 from flashmoe_tpu.utils.telemetry import metrics as _global_metrics
 from flashmoe_tpu.utils.telemetry import (
-    compile_totals, trace_span, watch_compiles,
+    compile_totals, gc_totals, trace_span, watch_compiles, watch_gc,
 )
+
+try:                            # the thread's own rusage: Linux
+    import resource
+    _RUSAGE_THREAD = resource.RUSAGE_THREAD
+except (ImportError, AttributeError):
+    _RUSAGE_THREAD = None
+
+#: a step is STALLED when its host time plus the caller's time before it
+#: (``host_ms + between_ms``) is over ``_STALL_FACTOR`` times the running
+#: median of that sum and over ``_STALL_FLOOR_MS``
+_STALL_FACTOR = 4.0
+_STALL_FLOOR_MS = 5.0
+
+_log = logging.getLogger("flashmoe_tpu.serving")
+
+
+def _thread_rusage() -> tuple[int, int]:
+    """(involuntary context switches, minor + major page faults) of the
+    calling thread so far; zeros where the platform has no
+    ``RUSAGE_THREAD``."""
+    if _RUSAGE_THREAD is None:
+        return 0, 0
+    ru = resource.getrusage(_RUSAGE_THREAD)
+    return ru.ru_nivcsw, ru.ru_minflt + ru.ru_majflt
 
 
 @dataclasses.dataclass(frozen=True)
@@ -699,7 +725,18 @@ class ServingEngine:
         self._ctx_pages = (0, 0.0, 0, None)
         # this step's sampler rows: not idle, drawn, truncating
         self._sampled = np.zeros((3,), np.int64)
+        # this step's host account (see _step): the time blocked on the
+        # device, the dispatches that found its queue empty and the phase
+        # of the first, the prefill programs with their tokens and rows
+        self._wait_ms = 0.0
+        self._starved, self._starved_at = 0, None
+        self._prefills = [0, 0, 0]
+        # where the last step ended: its t1_s, the thread's CPU seconds,
+        # context switches and page faults then
+        self._step_end = None
+        self._stall_logged_s = None
         watch_compiles()
+        watch_gc()
         # ---- live telemetry plane (default off = zero threads, no
         # behavior change; outputs are bit-identical either way) ------
         self.tracer = None
@@ -825,13 +862,19 @@ class ServingEngine:
         self.slots: list[_Slot | None] = [None] * self.serve.max_batch
         self._logits = jnp.zeros(
             (self.serve.max_batch, cfg.vocab_size), jnp.float32)
+        # an output, not donated, of the device program issued last: when
+        # it is ready the device's queue is empty (see _queue_empty)
+        self._last_out = self._logits
         self.step_idx = 0
         self.outputs: dict[int, list] = {}
         self.stats = {
-            "submitted": 0, "completed": 0, "evictions": 0, "adopted": 0,
+            "submitted": 0, "admitted": 0, "completed": 0, "evictions": 0,
+            "adopted": 0,
             "tokens": 0, "steps": 0, "max_queue_depth": 0,
             "max_active": 0, "decode_buckets": set(),
             "prefill_buckets": set(), "peak_occupancy": 0.0,
+            # the last stalled steps' serve_stall records, and the worst
+            "stalls": deque(maxlen=16), "worst_stall": None,
         }
         self._record_plan()
 
@@ -1104,11 +1147,18 @@ class ServingEngine:
                     prefill_pos=0, prefill_toks=toks, **account)
                 self.stats["prefill_buckets"].add(chunk)
             else:
-                prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-                if t_pad > t0:
-                    prompt = jnp.pad(
-                        prompt, ((0, 0), (0, t_pad - t0)),
-                        constant_values=sv.pad_token)
+                fed = self._clock(), time.time_ns()
+                with trace_span("serve.prefill_feed"):
+                    # eager uploads and a pad program, one by one
+                    prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+                    if t_pad > t0:
+                        prompt = jnp.pad(
+                            prompt, ((0, 0), (0, t_pad - t0)),
+                            constant_values=sv.pad_token)
+                    true_len = jnp.int32(t0)
+                    page_ids = jnp.asarray(
+                        self._global_pages(slot, pages), jnp.int32)
+                starved = self._queue_empty("serve.prefill")
                 with trace_span("serve.prefill"):
                     # (logits, one dense run per pool of the cache)
                     if self._prefill_fn is not None:
@@ -1116,10 +1166,7 @@ class ServingEngine:
                             prompt, t0, rid=orig.rid)
                     else:
                         logits, *seqs = _prefill_padded(
-                            self.params, self.cfg, prompt,
-                            jnp.int32(t0))
-                    page_ids = jnp.asarray(
-                        self._global_pages(slot, pages), jnp.int32)
+                            self.params, self.cfg, prompt, true_len)
                     self.cache = type(self.cache)(*(
                         _store_state(pool, seq, slot) if by_slot
                         else _store_prefill(pool, seq, page_ids)
@@ -1127,7 +1174,9 @@ class ServingEngine:
                             self.cache, seqs,
                             slot_state_fields(self.cache))))
                     self._state_bytes += self.cfg.state_slot_bytes
-                self._logits = self._logits.at[slot].set(logits)
+                self._put_logits(slot, logits)
+                self._note_prefill(orig.rid, slot, "whole", 0, t0, t_pad,
+                                   fed, starved)
                 self.slots[slot] = _Slot(
                     req=req, orig=orig, pages=list(pages), length=t0,
                     emitted=[], admit_step=self.step_idx,
@@ -1135,6 +1184,7 @@ class ServingEngine:
                     first_token_s=entry.first_token_s, **account)
                 self.stats["prefill_buckets"].add(t_pad)
             self._rates["admits"].add()
+            self.stats["admitted"] += 1
             if self.cfg.state_layers:
                 # the slot's state starts from nothing: a whole prefill
                 # overwrites it, a first chunk ignores what it holds
@@ -1178,30 +1228,34 @@ class ServingEngine:
                     break
             if self.slots[i] is None:
                 continue
-            n_ctx_pages = ctx_pages_bucket(
-                pos + chunk, sv.page_size, sv.ctx_bucket_pages,
-                sv.max_pages_per_slot)
-            # the chunk jit addresses the GLOBAL page slab (it runs
-            # outside the EP shard_map); scratch fill rows are masked,
-            # any valid page id serves
-            gpages = self._global_pages(i, s.pages)
-            table = np.full((n_ctx_pages,), SCRATCH_PAGE, np.int32)
-            table[:len(gpages)] = gpages
-            first_pg = pos // sv.page_size
-            chunk_ids = gpages[first_pg:need_pages]
-            rel_last = min(max(t0 - 1 - pos, 0), chunk - 1)
-            toks = s.prefill_toks[pos:pos + chunk]
+            fed = self._clock(), time.time_ns()
+            with trace_span("serve.chunk_feed"):
+                n_ctx_pages = ctx_pages_bucket(
+                    pos + chunk, sv.page_size, sv.ctx_bucket_pages,
+                    sv.max_pages_per_slot)
+                # the chunk jit addresses the GLOBAL page slab (it runs
+                # outside the EP shard_map); scratch fill rows are masked,
+                # any valid page id serves
+                gpages = self._global_pages(i, s.pages)
+                table = np.full((n_ctx_pages,), SCRATCH_PAGE, np.int32)
+                table[:len(gpages)] = gpages
+                first_pg = pos // sv.page_size
+                rel_last = min(max(t0 - 1 - pos, 0), chunk - 1)
+                # eager uploads, one by one
+                operands = (
+                    jnp.asarray(s.prefill_toks[pos:pos + chunk])[None, :],
+                    jnp.asarray(table),
+                    jnp.asarray(gpages[first_pg:need_pages], jnp.int32),
+                    jnp.int32(pos), jnp.int32(rel_last), jnp.int32(i))
             if self.tracer is not None:
                 # chunks interleave across slots: re-arm attribution so
                 # the span lands on THIS slot's request track
                 self.tracer.on_prefill_chunk(s.orig.rid)
+            starved = self._queue_empty("serve.prefill_chunk")
             with trace_span("serve.prefill_chunk"):
                 logits, self.cache = _INPLACE["_prefill_chunk"](
-                    self.params, self.cfg, self.cache,
-                    jnp.asarray(toks)[None, :],
-                    jnp.asarray(table),
-                    jnp.asarray(chunk_ids, jnp.int32),
-                    jnp.int32(pos), jnp.int32(rel_last), jnp.int32(i))
+                    self.params, self.cfg, self.cache, *operands)
+            self._last_out = logits
             if self.cfg.state_layers:
                 self._state_bytes += 2 * self.cfg.state_slot_bytes
                 if pos:
@@ -1209,10 +1263,50 @@ class ServingEngine:
             s.prefill_pos = pos + chunk
             if pos <= t0 - 1 < pos + chunk:
                 # prefill complete — arm the sampler, join decode
-                self._logits = self._logits.at[i].set(logits)
+                self._put_logits(i, logits)
                 s.prefill_pos = None
                 s.prefill_toks = None
                 s.length = t0
+            self._note_prefill(s.orig.rid, i, "chunk", pos,
+                               min(t0, pos + chunk) - pos, chunk, fed,
+                               starved)
+
+    def _put_logits(self, slot: int, logits) -> None:
+        """A finished prefill's logits into the slot's row of the pending
+        logits: an eager scatter, the last program of an admission."""
+        with trace_span("serve.logits_put"):
+            self._logits = self._logits.at[slot].set(logits)
+        self._last_out = self._logits
+
+    def _queue_empty(self, phase: str) -> bool:
+        """Asked before every dispatch of a prefill, chunk, sampler, decode
+        or verify program: whether the device has finished the program the
+        engine issued last, so that its queue is empty and it idles until
+        this one arrives.  Counts incidents, not time (``starved`` on the
+        step's record, ``phase`` of the first as ``starved_at``)."""
+        if not self._last_out.is_ready():
+            return False
+        if not self._starved:
+            self._starved_at = phase
+        self._starved += 1
+        return True
+
+    def _note_prefill(self, rid: int, slot: int, form: str, pos: int,
+                      tokens: int, rows: int, fed, starved: bool) -> None:
+        """One prefill program's account: ``tokens`` of a prompt in
+        ``rows`` computed rows, fed from ``fed`` (engine's clock,
+        profiler's clock) to now."""
+        done = self._prefills
+        done[0] += 1
+        done[1] += tokens
+        done[2] += rows
+        if self.recorder is not None:
+            self.recorder.record(
+                kind="serve_prefill", step=self.step_idx, rid=rid,
+                slot=slot, form=form, pos=pos, tokens=tokens, rows=rows,
+                pad_rows=rows - tokens,
+                host_ms=round((self._clock() - fed[0]) * 1e3, 3),
+                starved=starved, t0_trace_ns=fed[1])
 
     def _evict_youngest(self, shard: int | None = None) -> bool:
         """Preempt the most recently admitted request back to the
@@ -1394,6 +1488,7 @@ class ServingEngine:
                                  sv.max_pages_per_slot)
         self.stats["decode_buckets"].add(n_ctx)
         self._phase("serve.verify")
+        self._queue_empty("serve.verify")
         if self._ep_fn is not None:
             if self._ep_verify is None:
                 self._ep_verify = _ep_decode_fn(
@@ -1407,10 +1502,11 @@ class ServingEngine:
                 self.params, self.cfg, self.cache, jnp.asarray(feed),
                 jnp.asarray(tables[:, :n_ctx]),
                 jnp.asarray(positions))
+        self._last_out = span_logits
         self._note_ctx(n_ctx, positions[active], t_span)
         self._spec_steps += 1
 
-        self._phase("serve.sample")
+        since = self._phase("serve.sample")
         # canonical samples for every drafted position: column t-1
         # logits, position-(base+t-1) key, the same sampler numerics
         cand = np.asarray(self._sample(
@@ -1419,7 +1515,8 @@ class ServingEngine:
         )).reshape(sv.max_batch, k)
 
         # ---- accept the agreeing prefix; roll back the rest ----------
-        self._phase("serve.deliver")
+        # (the wait for the verify step: the candidates' dispatch with it)
+        self._wait_ms += (self._phase("serve.deliver") - since) * 1e3
         n_extra = 0
         accepted_cols = np.zeros((sv.max_batch,), np.int32)
         for i in active:
@@ -1459,11 +1556,12 @@ class ServingEngine:
                 del s.pages[keep:]
                 self._free_slot_pages(i, surplus)
             if done:
-                self._retire(i, s)
+                with trace_span("serve.retire"):
+                    self._retire(i, s)
         # pending logits = the column after each slot's last emitted
         # token — exactly what the plain decode step would have
         # returned after feeding that token
-        self._logits = span_logits[
+        self._logits = self._last_out = span_logits[
             jnp.arange(sv.max_batch), jnp.asarray(accepted_cols)]
         return n_extra
 
@@ -1593,6 +1691,50 @@ class ServingEngine:
             self._heartbeat(beat)
         return now
 
+    def _judge_stall(self, account: dict) -> dict | None:
+        """Whether the step that ``account`` describes STALLED: the host's
+        own time in it plus the caller's time before it over
+        ``_STALL_FACTOR`` times the running median of that sum (the two
+        sketches, as they stood before this step) and over
+        ``_STALL_FLOOR_MS``.  A stall is counted (``serve.stall_steps``;
+        ``serve.stall_ms``: what it took over the median), kept
+        (``stats["stalls"]``, the worst as ``stats["worst_stall"]``),
+        logged at most once a second, and returned as its ``serve_stall``
+        record; otherwise ``None``."""
+        sk_host = self.metrics.sketches.get("serve.host_ms")
+        sk_between = self.metrics.sketches.get("serve.between_ms")
+        if sk_between is None or sk_between.n < 8:
+            return None                 # no median to speak of yet
+        lag_ms = account["host_ms"] + account["between_ms"]
+        median_ms = sk_host.quantile(0.5) + sk_between.quantile(0.5)
+        if lag_ms <= max(_STALL_FACTOR * median_ms, _STALL_FLOOR_MS):
+            return None
+        # the wait lies inside serve.sample: the rest is the host's
+        host_phases = dict(self._phase_ms)
+        host_phases["serve.sample"] = (host_phases.get("serve.sample", 0.0)
+                                       - self._wait_ms)
+        stall = {
+            "kind": "serve_stall", "step": self.step_idx,
+            "median_ms": round(median_ms, 3),
+            "phase": max(host_phases, key=host_phases.get),
+            "phase_ms": {k: round(v, 3) for k, v in self._phase_ms.items()},
+            **account,
+        }
+        self.metrics.count("serve.stall_steps")
+        self.metrics.count("serve.stall_ms", lag_ms - median_ms)
+        self.stats["stalls"].append(stall)
+        worst = self.stats["worst_stall"]
+        if worst is None or lag_ms > worst["host_ms"] + worst["between_ms"]:
+            self.stats["worst_stall"] = stall
+        now = self._step_end[0]
+        if self._stall_logged_s is None or now - self._stall_logged_s >= 1.0:
+            self._stall_logged_s = now
+            # (the record and stats["stalls"] keep the whole phase_ms)
+            _log.warning("serve_stall %s", json.dumps(
+                {k: v for k, v in stall.items()
+                 if k not in ("kind", "phase_ms")}))
+        return stall
+
     def _note_ctx(self, n_ctx: int, lengths, t_span: int) -> None:
         """What this step's decode or verify program reads of the cache:
         the arm its attention takes (``ops/attention.kv_attention_arm``:
@@ -1631,18 +1773,23 @@ class ServingEngine:
         drawn, use_k, use_p = _rows_ask(*knobs[2:], logits.shape[-1])
         self._sampled += np.array(
             [n_rows, drawn.sum(), (drawn & (use_k | use_p)).sum()])
-        return _sample_dynamic(logits, *knobs)
+        self._queue_empty("serve.sample")
+        toks = self._last_out = _sample_dynamic(logits, *knobs)
+        return toks
 
-    def _deliver(self, rows, toks) -> int:
+    def _deliver(self, rows, toks, since: float) -> int:
         """Read the step's tokens (``toks``: the sampler's array, a row a
         slot) and hand each slot of ``rows`` its own: appended, the clocks
         stamped, the request retired on a stop token or on its last token
         by count.  THE STEP'S ONE READ-BACK: it returns when the sampler
         has finished, which waits for the decode program the step before
-        dispatched and for no program dispatched after the sampler.
-        Returns the tokens delivered."""
+        dispatched and for no program dispatched after the sampler; the
+        time from ``since`` (the engine's clock, just read) until it
+        returns is the step's ``wait_ms``.  Returns the tokens
+        delivered."""
         toks = np.asarray(toks)
         now = self._phase("serve.deliver")
+        self._wait_ms += (now - since) * 1e3
         for i in rows:
             s = self.slots[i]
             tok = int(toks[i])
@@ -1659,7 +1806,8 @@ class ServingEngine:
                 self._delivered_now[s.orig.rid] = 1
             if (tok in s.req.stop_tokens
                     or self._delivered(s) >= s.orig.max_new_tokens):
-                self._retire(i, s)
+                with trace_span("serve.retire"):
+                    self._retire(i, s)
         return len(rows)
 
     def step(self) -> dict:
@@ -1672,7 +1820,7 @@ class ServingEngine:
         finished request retired when it returns.  Returns the step's
         flight record (also appended to the recorder when one is
         attached)."""
-        with trace_span("serve.step"):
+        with trace_span("serve.step", step=self.step_idx):
             try:
                 return self._step()
             finally:
@@ -1682,13 +1830,19 @@ class ServingEngine:
     def _step(self) -> dict:
         sv = self.serve
         compiles0, compile_s0 = compile_totals()
+        gc_n0, gc_s0 = gc_totals()
         self._phase_ms = {}
         self._delivered_now = {}
         self._ctx_pages = (0, 0.0, 0, None)
         self._sampled = np.zeros((3,), np.int64)
         self._state_bytes = 0
+        self._wait_ms = 0.0
+        self._starved, self._starved_at = 0, None
+        self._prefills = [0, 0, 0]
         if self._counted is not None:
             self._counted_prev, self._counted = self._counted, None
+        # the same instant on the profiler's clock and on the engine's
+        t0_trace_ns, cpu0_s = time.time_ns(), time.thread_time()
         t0_s = self._phase("serve.admit")
         if self.tracer is not None:
             # open the step window BEFORE admissions: everything in
@@ -1698,6 +1852,8 @@ class ServingEngine:
                 self.step_idx,
                 [self.slots[i].orig.rid for i in self._active()])
         self._mark_arrivals()
+        admitted0 = self.stats["admitted"]
+        retired0 = self.stats["completed"]
         self._admit()
         self._phase("serve.prefill_advance", beat="admit")
         self._advance_prefill()
@@ -1730,7 +1886,7 @@ class ServingEngine:
             ahead = bool(active and self._spec is None
                          and self._growth_fits(active))
             if not ahead:
-                emitted_now += self._deliver(sampled, toks)
+                emitted_now += self._deliver(sampled, toks, self._clock())
                 active = self._decoding()
 
         # feed the survivors one decode step — speculative (draft +
@@ -1766,6 +1922,7 @@ class ServingEngine:
                                      sv.max_pages_per_slot)
             self.stats["decode_buckets"].add(n_ctx)
             self._phase("serve.decode")
+            self._queue_empty("serve.decode")
             if self._ep_fn is not None:
                 logits, self.cache = self._ep_fn(
                     self.params, self.cache, toks,
@@ -1783,14 +1940,14 @@ class ServingEngine:
                                       * self.cfg.state_slot_bytes)
             # the step's account of it, while the device runs it
             self._note_ctx(n_ctx, positions[active], 1)
-            self._logits = logits
+            self._logits = self._last_out = logits
             for i in active:
                 self.slots[i].length += 1
         if ahead:
             # the wait for the sampler is the sampler's phase; the decode
             # program runs under it and under all that follows
-            self._phase("serve.sample")
-            emitted_now += self._deliver(sampled, toks)
+            emitted_now += self._deliver(sampled, toks,
+                                         self._phase("serve.sample"))
             self.metrics.count("serve.decode_ahead_steps")
         self.stats["tokens"] += emitted_now
 
@@ -1835,6 +1992,50 @@ class ServingEngine:
         self.metrics.sketch("serve.phase.account_ms",
                             self._phase_ms["serve.account"])
         compiles1, compile_s1 = compile_totals()
+        # ---- the host's account of the step --------------------------
+        # wall: blocked on the device / the host's own / the caller's
+        # before the step; the thread's CPU over the step and over the
+        # caller's time; its context switches and page faults since the
+        # last step ended (one getrusage a step), collections that ended
+        # inside the step
+        cpu1_s = time.thread_time()
+        switches, faults = _thread_rusage()
+        gc_n1, gc_s1 = gc_totals()
+        first = self._step_end is None
+        last = (t0_s, cpu0_s, switches, faults) if first else self._step_end
+        self._step_end = (t1_s, cpu1_s, switches, faults)
+        host_ms = step_ms - self._wait_ms
+        between_ms = (t0_s - last[0]) * 1e3
+        n_prefills, prefill_tokens, prefill_rows = self._prefills
+        held = len(sampled) if self._starved else 0
+        account = {
+            "t0_trace_ns": t0_trace_ns,
+            "wait_ms": round(self._wait_ms, 3),
+            "host_ms": round(host_ms, 3),
+            "between_ms": round(between_ms, 3),
+            "cpu_ms": round((cpu1_s - cpu0_s) * 1e3, 3),
+            "between_cpu_ms": round((cpu0_s - last[1]) * 1e3, 3),
+            "ctx_switches": switches - last[2],
+            "page_faults": faults - last[3],
+            "gc_n": int(gc_n1 - gc_n0),
+            "gc_ms": round((gc_s1 - gc_s0) * 1e3, 3),
+            "compiles": int(compiles1 - compiles0),
+            "compile_ms": round((compile_s1 - compile_s0) * 1e3, 3),
+            "admitted": self.stats["admitted"] - admitted0,
+            "retired": self.stats["completed"] - retired0,
+            "prefill_programs": n_prefills,
+        }
+        if self._starved:
+            self.metrics.count("serve.starved_dispatches", self._starved)
+            self.metrics.count("serve.held_steps")
+        if n_prefills:
+            self.metrics.count("serve.prefill_programs", n_prefills)
+            self.metrics.count("serve.prefill_tokens", prefill_tokens)
+            self.metrics.count("serve.prefill_rows", prefill_rows)
+        stall = self._judge_stall(account)
+        self.metrics.sketch("serve.host_ms", host_ms)
+        if not first:
+            self.metrics.sketch("serve.between_ms", between_ms)
         ctx_pages, ctx_idle, n_decoding, attn_arm = self._ctx_pages
         sample_rows, sample_drawn, sample_sorted = map(int, self._sampled)
         if sample_rows:
@@ -1854,8 +2055,10 @@ class ServingEngine:
             "t0_s": t0_s, "t1_s": t1_s,
             "phase_ms": {k: round(v, 3)
                          for k, v in self._phase_ms.items()},
-            "compiles": int(compiles1 - compiles0),
-            "compile_ms": round((compile_s1 - compile_s0) * 1e3, 3),
+            **account,
+            "starved": self._starved, "starved_at": self._starved_at,
+            "held_slots": held,
+            "prefill_tokens": prefill_tokens, "prefill_rows": prefill_rows,
             "ctx_pages": ctx_pages,
             "ctx_pages_idle": round(ctx_idle, 3),
             "kv_token_bytes": self.cfg.kv_pool_token_bytes,
@@ -1884,6 +2087,16 @@ class ServingEngine:
             rec["delivered"] = [[rid, n] for rid, n
                                 in self._delivered_now.items()]
             self.recorder.record(**rec)
+            # the slot-steps the host held up, one record a step under a
+            # kind of its own: a reader that means over records of a kind
+            # INDEXES the field, and a program without it has none of
+            # this kind where it has ``serve_step`` records without
+            self.recorder.record(
+                kind="serve_held", step=self.step_idx,
+                decoding=len(sampled), starved=self._starved,
+                starved_at=self._starved_at, held_slots=held)
+            if stall is not None:
+                self.recorder.record(**stall)
             if ctx_pages:
                 # the decode program's shape, one record per step that
                 # ran it: a mean over these is a mean over decode steps
@@ -1930,6 +2143,7 @@ class ServingEngine:
 
     def summary(self) -> dict:
         s = dict(self.stats)
+        s["stalls"] = list(s["stalls"])
         s["decode_buckets"] = sorted(s["decode_buckets"])
         s["prefill_buckets"] = sorted(s["prefill_buckets"])
         # O(1)-memory: the retire-time sketches, not a decision scan

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import bisect
 import contextlib
+import gc
 import json
 import math
 import os
@@ -259,9 +260,23 @@ SPAN_NAMES: dict[str, str] = {
         "experts outside the kept groups",
     "serve.prefill":
         "serving engine: single-pass prompt prefill into cache pages",
+    "serve.prefill_feed":
+        "serving engine: a whole prompt's upload and its pad to the "
+        "bucket, eager, before ``serve.prefill`` (inside ``serve.admit``)",
     "serve.prefill_chunk":
         "serving engine: one fixed-budget chunk of an admitted "
         "prompt's incremental prefill (chunked admission)",
+    "serve.chunk_feed":
+        "serving engine: a chunk's tokens, block table, page ids and four "
+        "scalars uploaded, eager, before ``serve.prefill_chunk`` (inside "
+        "``serve.prefill_advance``)",
+    "serve.logits_put":
+        "serving engine: a finished prefill's logits written into the "
+        "slot's row of the pending logits (an eager scatter, inside "
+        "``serve.admit`` or ``serve.prefill_advance``)",
+    "serve.retire":
+        "serving engine: one request retired: pages freed, TTFT/TPOT, the "
+        "``serve.retire`` decision (inside ``serve.deliver``)",
     "serve.handoff":
         "fabric: a prefill KV run's page codec round-trip on its way "
         "to the decode replica",
@@ -281,7 +296,8 @@ SPAN_NAMES: dict[str, str] = {
         "request trace: the parent span of one request's whole "
         "lifecycle (trace_id minted at serve.admit)",
     "serve.step":
-        "one whole engine step, the parent of the step's phases; on a "
+        "one whole engine step, the parent of the step's phases (the "
+        "profiler's event carries the stat ``step``); on a "
         "request's trace, the step window the request rode (it opens "
         "where the request's last window closed, so the time between "
         "two steps is on the track too)",
@@ -382,15 +398,17 @@ def get_span_listener():
 
 
 @contextlib.contextmanager
-def trace_span(name: str):
+def trace_span(name: str, **stats):
     """Named scope visible in xprof traces and HLO metadata.  When a
     phase-profiler timeline is armed (:func:`set_span_listener`), the
     span's host enter/exit instants are additionally recorded — the
-    xprof-free phase timeline of :mod:`flashmoe_tpu.profiler`."""
+    xprof-free phase timeline of :mod:`flashmoe_tpu.profiler`.
+    ``stats`` ride the profiler's event as its stats (the event keeps
+    ``name``)."""
     lst = _SPAN_LISTENER[0]
     tok = lst.span_enter(name) if lst is not None else None
     try:
-        with jax.profiler.TraceAnnotation(name):
+        with jax.profiler.TraceAnnotation(name, **stats):
             with jax.named_scope(name):
                 yield
     finally:
@@ -427,6 +445,38 @@ def compile_totals() -> tuple[float, float]:
     """(compiles, seconds spent in them) since :func:`watch_compiles`."""
     c = metrics.counters
     return c.get("compile.count", 0.0), c.get("compile.seconds", 0.0)
+
+
+_gc_listening: list = [False]
+
+
+def watch_gc() -> None:
+    """Count every collection of CPython's collector into the global
+    :data:`metrics` (``gc.count``, ``gc.seconds``: those that ENDED, and
+    the time from their start).  Installs ONE ``gc.callbacks`` listener,
+    however often it is called; the serving engine calls it when it is
+    built and reports each step's share (:func:`gc_totals` before and
+    after) as ``gc_n`` / ``gc_ms`` in its step records."""
+    if _gc_listening[0]:
+        return
+    _gc_listening[0] = True
+    started = [None]
+
+    def on_gc(phase, info):
+        if phase == "start":
+            started[0] = time.perf_counter()
+        elif started[0] is not None:
+            metrics.counters["gc.count"] += 1
+            metrics.counters["gc.seconds"] += time.perf_counter() - started[0]
+            started[0] = None
+
+    gc.callbacks.append(on_gc)
+
+
+def gc_totals() -> tuple[float, float]:
+    """(collections, seconds spent in them) since :func:`watch_gc`."""
+    c = metrics.counters
+    return c.get("gc.count", 0.0), c.get("gc.seconds", 0.0)
 
 
 def start_trace(log_dir: str):

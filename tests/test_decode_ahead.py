@@ -147,7 +147,38 @@ def _mixed(cfg, params, serve, mixed=MIXED, arrivals=ARRIVALS):
     assert [r for r in engine.recorder.records
             if r["kind"] == "serve_step"] == [
         {k: v for k, v in rec.items() if k != "fits"} for rec in recs]
+    _account_holds(engine, recs, serve, [t0 for t0, _ in mixed])
     return engine, recs, mx
+
+
+def _account_holds(engine, recs, serve, prompt_lengths):
+    """The host's account (ISSUE 35) through the same joins, chunks and
+    evictions, on the wall clock: the wait and the host's time are the
+    step, the wait lies where the tokens are read, and the prefill
+    programs' records carry every prompt token of every admission."""
+    for rec in recs:
+        assert rec["host_ms"] + rec["wait_ms"] == pytest.approx(
+            rec["step_ms"], abs=2e-3)
+        assert rec["wait_ms"] <= rec["phase_ms"].get("serve.sample", 0.0) \
+            + 1e-3
+        assert (rec["wait_ms"] > 0.0) == ("readback" in rec)
+        assert rec["held_slots"] == (rec["sample_rows"]
+                                     if rec["starved"] else 0)
+        assert rec["cpu_ms"] >= 0.0 and rec["between_ms"] >= 0.0
+    pre = [r for r in engine.recorder.records if r["kind"] == "serve_prefill"]
+    assert sum(rec["prefill_programs"] for rec in recs) == len(pre)
+    # an evicted request is admitted again with what it was served on its
+    # prompt: the admissions' own count of prompt tokens is the yardstick
+    admitted = [d for d in engine.metrics.decisions
+                if d["decision"] == "serve.admit"]
+    assert sum(p["tokens"] for p in pre) == sum(
+        d["prompt_tokens"] for d in admitted) >= sum(prompt_lengths)
+    for p in pre:
+        assert p["rows"] == (serve.prefill_chunk if p["form"] == "chunk"
+                             else -(-p["tokens"] // serve.prompt_bucket)
+                             * serve.prompt_bucket)
+    if engine.stats["evictions"] == 0:
+        assert len(admitted) == len(prompt_lengths)
 
 
 @pytest.mark.parametrize("chunk", [None, 16], ids=["whole", "chunked"])

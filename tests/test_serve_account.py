@@ -2,12 +2,17 @@
 scopes of the one-chip layer — all on the ``clock=`` seam with a stepped
 fake clock: nothing here sleeps or reads the wall clock."""
 
+import gc
+import json
+import logging
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from flashmoe_tpu.config import MoEConfig
 from flashmoe_tpu.models.transformer import init_params
+from flashmoe_tpu.serving import engine as eng
 from flashmoe_tpu.serving.engine import (
     Request, ServeConfig, ServingEngine,
 )
@@ -21,6 +26,13 @@ CFG = tiny_config()
 SERVE = ServeConfig(max_batch=4, page_size=8, num_pages=32,
                     max_pages_per_slot=4, ctx_bucket_pages=1,
                     prompt_bucket=8)
+
+
+#: spans that are no phase: each opens inside the phase named
+CHILD_SPANS = {"serve.prefill_feed": "serve.admit",
+               "serve.prefill": "serve.admit",
+               "serve.logits_put": "serve.admit",
+               "serve.retire": "serve.deliver"}
 
 
 class Ticking:
@@ -121,10 +133,11 @@ def test_phases_are_registered_sum_to_step_and_nest(params):
     assert "flashmoe_serve_phase_sample_keys_ms" in mx.prometheus_text()
 
     # nesting: one serve.step around everything, the phases one after
-    # another inside it, serve.prefill inside serve.admit
+    # another inside it, an admission's spans inside serve.admit and a
+    # retirement's inside serve.deliver
     # (the layer's own scopes show up too, while a program is traced
     # for its first call: not the engine's, left out here)
-    open_names, steps = [], 0
+    open_names, steps, children = [], 0, []
     for kind, name in log.events:
         if not name.startswith("serve."):
             continue
@@ -132,14 +145,18 @@ def test_phases_are_registered_sum_to_step_and_nest(params):
             if name == "serve.step":
                 assert not open_names
                 steps += 1
-            elif name == "serve.prefill":
-                assert open_names == ["serve.step", "serve.admit"]
+            elif name in CHILD_SPANS:
+                assert open_names == ["serve.step", CHILD_SPANS[name]]
+                children.append(name)
             else:
                 assert open_names == ["serve.step"], (name, open_names)
             open_names.append(name)
         else:
             assert open_names.pop() == name
     assert not open_names and steps == len(recs)
+    # three admissions: feed, program, put, in that order; three retired
+    assert children == ["serve.prefill_feed", "serve.prefill",
+                        "serve.logits_put"] * 3 + ["serve.retire"] * 3
 
 
 def test_phase_names_under_speculation(params):
@@ -318,6 +335,334 @@ def test_token_streams_do_not_depend_on_the_recorder(params):
             [_req(rid, max_new=6, temperature=0.7 * (rid % 2), seed=rid)
              for rid in range(6)], [0, 0, 1, 1, 2, 5]))
     assert outs[0] == outs[1]
+
+
+#: what PR 35 put on EVERY ``serve_step`` record (a reader that means over
+#: records of a kind indexes the field)
+ACCOUNT = ("t0_trace_ns", "wait_ms", "host_ms", "between_ms", "cpu_ms",
+           "between_cpu_ms", "ctx_switches", "page_faults", "gc_n", "gc_ms",
+           "starved", "starved_at", "held_slots", "prefill_programs",
+           "prefill_tokens", "prefill_rows", "admitted", "retired",
+           "compiles", "compile_ms")
+
+
+@pytest.mark.parametrize("chunk", [None, 16], ids=["whole", "chunked"])
+def test_every_step_record_carries_the_host_account(params, chunk):
+    """Prompts of 5 to 40 tokens, whole (padded to buckets of 8) or in
+    chunks of 16: every record has every field, the wait and the host's
+    time add up to the step, the phases still do, and the prefill
+    programs' records add up to the prompts."""
+    serve = ServeConfig(max_batch=3, page_size=8, num_pages=40,
+                        max_pages_per_slot=8, ctx_bucket_pages=2,
+                        prompt_bucket=8, prefill_chunk=chunk)
+    mx, rec = Metrics(), FlightRecorder()
+    engine = ServingEngine(params, CFG, serve, metrics_obj=mx,
+                           clock=Ticking(), recorder=rec)
+    lengths = [5, 40, 21, 33, 8, 17]
+    for rid, n in enumerate(lengths):
+        engine.submit(_req(rid, n=n, max_new=3 + rid), rid // 2)
+    recs = _drive(engine)
+    steps = [r for r in rec.records if r["kind"] == "serve_step"]
+    assert steps == recs
+    t1_before = None
+    for r in recs:
+        assert all(k in r for k in ACCOUNT), set(ACCOUNT) - set(r)
+        assert r["host_ms"] + r["wait_ms"] == pytest.approx(
+            r["step_ms"], abs=2e-3)
+        assert 0.0 <= r["wait_ms"] <= r["phase_ms"].get("serve.sample", 0.0)
+        assert sum(r["phase_ms"].values()) == pytest.approx(
+            r["step_ms"], rel=0.01)
+        assert r["between_ms"] == pytest.approx(
+            0.0 if t1_before is None else (r["t0_s"] - t1_before) * 1e3,
+            abs=2e-3)
+        t1_before = r["t1_s"]
+        assert r["t0_trace_ns"] > 1_500_000_000 * 10**9    # a wall clock
+        assert (r["starved_at"] is None) == (r["starved"] == 0)
+        assert r["held_slots"] in (0, r["sample_rows"])
+    # one serve_held record a step, the reader's: the same numbers
+    held = [r for r in rec.records if r["kind"] == "serve_held"]
+    assert [(h["step"], h["held_slots"], h["starved"]) for h in held] == [
+        (r["step"], r["held_slots"], r["starved"]) for r in recs]
+    # one serve_prefill record a program
+    pre = [r for r in rec.records if r["kind"] == "serve_prefill"]
+    by_rid = {}
+    for p in pre:
+        by_rid.setdefault(p["rid"], []).append(p)
+        assert p["pad_rows"] == p["rows"] - p["tokens"] >= 0
+        assert p["host_ms"] > 0.0 and isinstance(p["starved"], bool)
+        if p["form"] == "chunk":
+            assert p["rows"] == 16 and p["pos"] % 16 == 0
+        else:
+            assert p["rows"] % 8 == 0 and p["pos"] == 0 \
+                and p["pad_rows"] < 8
+    assert {rid: sum(p["tokens"] for p in ps)
+            for rid, ps in by_rid.items()} == dict(enumerate(lengths))
+    forms = {p["form"] for p in pre}
+    assert forms == ({"whole", "chunk"} if chunk else {"whole"})
+    by_step = {r["step"]: r for r in recs}
+    for step, r in by_step.items():
+        mine = [p for p in pre if p["step"] == step]
+        assert (r["prefill_programs"], r["prefill_tokens"],
+                r["prefill_rows"]) == (
+            len(mine), sum(p["tokens"] for p in mine),
+            sum(p["rows"] for p in mine))
+    assert mx.counters["serve.prefill_programs"] == len(pre)
+    assert mx.counters["serve.prefill_tokens"] == sum(lengths)
+    assert mx.counters["serve.prefill_rows"] == sum(p["rows"] for p in pre)
+    assert mx.sketches["serve.host_ms"].n == len(recs)
+    assert mx.sketches["serve.between_ms"].n == len(recs) - 1
+    assert mx.counters.get("serve.held_steps", 0) == sum(
+        r["starved"] > 0 for r in recs)
+    assert mx.counters.get("serve.starved_dispatches", 0) == sum(
+        r["starved"] for r in recs)
+
+
+def _long_run(params, clock, mx, rec, new=40):
+    serve = ServeConfig(max_batch=1, page_size=8, num_pages=32,
+                        max_pages_per_slot=8, ctx_bucket_pages=8,
+                        prompt_bucket=8)
+    engine = ServingEngine(params, CFG, serve, metrics_obj=mx,
+                           recorder=rec, clock=clock)
+    engine.submit(_req(0, max_new=new))
+    return engine
+
+
+def test_a_step_forty_times_its_median_leaves_one_stall(params, caplog):
+    """The clock stands still inside a step and moves 1 s between steps;
+    before step 20 it moves 40 s: ONE ``serve_stall`` record, one count,
+    one entry of ``stats["stalls"]`` (the worst too), one WARNING, and
+    the record says where the time went: not in the step."""
+    clock, mx, rec = Stepped(), Metrics(), FlightRecorder()
+    engine = _long_run(params, clock, mx, rec)
+
+    def hold(step):
+        if step == 20:
+            clock.t += 39.0
+
+    with caplog.at_level(logging.WARNING, logger="flashmoe_tpu.serving"):
+        recs = _drive(engine, clock, before_step=hold)
+    assert len(recs) == 40
+    stalls = [r for r in rec.records if r["kind"] == "serve_stall"]
+    assert len(stalls) == 1
+    st = stalls[0]
+    assert st["step"] == 20 and st["between_ms"] == 40000.0
+    assert st["host_ms"] == 0.0 and st["median_ms"] == 1000.0
+    assert st["phase_ms"] == recs[20]["phase_ms"] and st["phase"] in st[
+        "phase_ms"]
+    for k in ("wait_ms", "cpu_ms", "between_cpu_ms", "ctx_switches",
+              "page_faults", "gc_n", "gc_ms", "compiles", "compile_ms",
+              "prefill_programs", "admitted", "retired", "t0_trace_ns"):
+        assert st[k] == recs[20][k], k
+    assert (st["admitted"], st["retired"]) == (0, 0)
+    assert [(r["admitted"], r["retired"]) for r in recs] == [
+        (1, 0)] + [(0, 0)] * 38 + [(0, 1)]
+    assert mx.counters["serve.stall_steps"] == 1
+    assert mx.counters["serve.stall_ms"] == 39000.0
+    assert list(engine.stats["stalls"]) == [st]
+    assert engine.stats["worst_stall"] == st
+    assert engine.summary()["stalls"] == [st]
+    lines = [r for r in caplog.records if r.name == "flashmoe_tpu.serving"]
+    assert len(lines) == 1 and lines[0].levelno == logging.WARNING
+    said = lines[0].getMessage()
+    assert said.startswith("serve_stall {")
+    assert json.loads(said[len("serve_stall "):]) == {
+        k: v for k, v in st.items() if k not in ("kind", "phase_ms")}
+
+
+def test_a_long_step_under_five_milliseconds_is_no_stall(params):
+    """0.1 ms between steps and 4.1 ms before step 20: forty times the
+    median, under the floor.  6 ms: a stall, and stalls a second apart on
+    the engine's clock are each logged, closer ones are not."""
+    clock, mx, rec = Stepped(), Metrics(), FlightRecorder()
+    engine = _long_run(params, clock, mx, rec)
+
+    def hold(step):
+        if step == 20:
+            clock.t += 0.004
+        elif step in (25, 26):
+            clock.t += 0.006
+
+    _drive(engine, clock, dt=0.0001, before_step=hold)
+    stalls = [r["step"] for r in rec.records if r["kind"] == "serve_stall"]
+    assert stalls == [25, 26]
+    assert mx.counters["serve.stall_steps"] == 2
+    assert engine.stats["worst_stall"]["step"] == 25
+    assert eng._STALL_FLOOR_MS == 5.0 and eng._STALL_FACTOR == 4.0
+
+
+def test_watch_gc_installs_one_listener_and_a_step_counts_a_collection(
+        params):
+    def listeners():
+        return sum("watch_gc" in getattr(cb, "__qualname__", "")
+                   for cb in gc.callbacks)
+
+    telemetry.watch_gc()
+    telemetry.watch_gc()
+    assert listeners() == 1
+    n0, s0 = telemetry.gc_totals()
+    gc.collect()
+    n1, s1 = telemetry.gc_totals()
+    assert n1 == n0 + 1 and s1 > s0
+    engine = ServingEngine(params, CFG, SERVE, metrics_obj=Metrics(),
+                           clock=Ticking())
+    assert listeners() == 1
+    engine.submit(_req(0, max_new=6))
+    engine.step()
+    forced = engine._deliver
+
+    def deliver(*a):
+        gc.collect()
+        return forced(*a)
+
+    engine._deliver = deliver
+    rec = engine.step()
+    assert rec["gc_n"] >= 1 and rec["gc_ms"] > 0.0
+    del engine._deliver
+    gc.disable()
+    try:
+        rec = engine.step()
+    finally:
+        gc.enable()
+    assert (rec["gc_n"], rec["gc_ms"]) == (0, 0.0)
+
+
+class _Output:
+    """Stands for the output of the program issued last."""
+
+    def __init__(self, ready):
+        self.ready = ready
+
+    def is_ready(self):
+        return self.ready
+
+
+@pytest.mark.parametrize("ready", [False, True], ids=["busy", "empty"])
+def test_held_slots_are_the_decoding_slots_of_a_step_that_starved(
+        params, ready):
+    """Every dispatch asks the output of the program issued last whether it
+    is ready.  A busy queue: nothing starved, no slot held.  An empty one:
+    every dispatch starved, the first of them the step's first program, and
+    the held slots are the slots the sampler served."""
+
+    class Engine(ServingEngine):
+        _last_out = property(lambda self: _Output(ready),
+                             lambda self, out: None)
+
+    mx = Metrics()
+    engine = Engine(params, CFG, SERVE, metrics_obj=mx, clock=Ticking())
+    for rid in range(3):
+        engine.submit(_req(rid, max_new=5), rid)
+    recs = _drive(engine)
+    assert engine.outputs == ServingEngine(
+        params, CFG, SERVE, metrics_obj=Metrics()).run(
+        [_req(rid, max_new=5) for rid in range(3)], [0, 1, 2])
+    if not ready:
+        assert all((r["starved"], r["starved_at"], r["held_slots"])
+                   == (0, None, 0) for r in recs)
+        assert "serve.held_steps" not in mx.counters
+        return
+    for r in recs:
+        # a prefill, the sampler, the decode step where the step ran them
+        want = r["prefill_programs"] + bool(r["sample_rows"]) \
+            + bool(r["ctx_pages"])
+        assert r["starved"] == want >= 1
+        assert r["starved_at"] == ("serve.prefill" if r["prefill_programs"]
+                                   else "serve.sample")
+        assert r["held_slots"] == r["sample_rows"]
+    assert mx.counters["serve.held_steps"] == len(recs)
+
+
+def test_device_gaps_find_their_step_span_and_prefill_by_hand():
+    """``observe.gaps_report`` on a hand-made trace, times in ms on one
+    clock: two steps (10-14, 15-19), device busy 10-11, 11.5-14.2, 14.5-15.2
+    and 18-19: gaps of 0.5 (inside step 0, the sampler's phase), 0.3
+    (after step 0 ended: BEFORE step 1, in the caller's span), 2.8 (in
+    step 1, under a chunk's feed, the prefill record beside it) and none
+    of the 0.05 ms one."""
+    from flashmoe_tpu import observe
+
+    ms = 1_000_000
+    base = 5 * ms
+    steps = [
+        {"kind": "serve_step", "step": 0, "t0_trace_ns": 10 * ms,
+         "step_ms": 4.0, "host_ms": 1.0, "between_ms": 0.0, "cpu_ms": 1.0,
+         "gc_ms": 0.0, "ctx_switches": 0},
+        {"kind": "serve_step", "step": 1, "t0_trace_ns": 15 * ms,
+         "step_ms": 4.0, "host_ms": 3.5, "between_ms": 1.0, "cpu_ms": 3.0,
+         "gc_ms": 0.25, "ctx_switches": 2},
+        {"kind": "serve_prefill", "step": 1, "rid": 7, "slot": 1,
+         "form": "chunk", "pos": 16, "tokens": 9, "rows": 16,
+         "host_ms": 2.5, "t0_trace_ns": int(15.1 * ms)},
+        {"kind": "serve_step", "step": 2},        # an older program's
+    ]
+    spans = sorted([
+        (10 * ms, 4 * ms, "serve.step", 0),
+        (10 * ms + 40_000, 2 * ms, "serve.sample", None),
+        (14 * ms, 1 * ms, "bench.observe", None),
+        (15 * ms - 30_000, 4 * ms, "serve.step", 1),
+        (15 * ms, 3 * ms, "serve.prefill_advance", None),
+        (int(15.1 * ms), 2 * ms, "serve.chunk_feed", None),
+    ])
+    trace = {"file": "by hand", "base": base, "spans": spans, "busy": [
+        (10 * ms, 1 * ms), (int(11.5 * ms), int(2.7 * ms)),
+        (int(14.5 * ms), int(0.7 * ms)), (18 * ms, int(0.95 * ms)),
+        (19 * ms, 1 * ms)]}
+    rep = observe.gaps_report(trace, steps)
+    rows = rep["gaps"]
+    assert [round(r["gap_ms"], 3) for r in rows] == [0.5, 0.3, 2.8]
+    assert [(r["step"], r["where"]) for r in rows] == [
+        (0, "in"), (1, "before"), (1, "in")]
+    assert [r["spans"] for r in rows] == [
+        ["serve.step", "serve.sample"], ["bench.observe"],
+        ["serve.step", "serve.prefill_advance", "serve.chunk_feed"]]
+    assert [r["prefill"] and r["prefill"]["rid"] for r in rows] == [
+        None, None, 7]
+    assert rows[2]["between_ms"] == 1.0 and rows[2]["ctx_switches"] == 2
+    assert rows[0]["at_ms"] == 6.0
+    assert rep["idle_ms"] == pytest.approx(3.65)
+    assert rep["gaps_ms"] == rep["named_ms"] == pytest.approx(3.6)
+    assert rep["clock_skew_ms"] == pytest.approx(0.03) and rep["steps"] == 2
+    text = observe.render_gaps_text(rep)
+    assert "98.6 % of the idle time" in text
+    assert "serve.step > serve.prefill_advance > serve.chunk_feed" in text
+    assert "[chunk rid 7 pos 16: 9 tokens in 16 rows" in text
+
+
+def test_a_profiled_run_puts_records_and_trace_on_one_clock(
+        params, tmp_path, capsys):
+    """The engine under ``jax.profiler`` on the CPU (no device plane: no
+    gaps to list): every ``serve.step`` event carries its step as a stat
+    under the name it had, and starts within a millisecond of its record's
+    ``t0_trace_ns``; the command line finds the trace and the records."""
+    from flashmoe_tpu import observe
+
+    rec = FlightRecorder()
+    engine = ServingEngine(params, CFG, SERVE, metrics_obj=Metrics(),
+                           recorder=rec)
+    engine.submit(_req(0, max_new=3))
+    engine.run()                         # compiled before the trace
+    for rid in (1, 2):
+        engine.submit(_req(rid, max_new=4))
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        recs = _drive(engine)
+    finally:
+        jax.profiler.stop_trace()
+    trace = observe.read_gaps_trace(str(tmp_path / "trace"))
+    stepped = [s for s in trace["spans"] if s[2] == "serve.step"]
+    assert [s[3] for s in stepped] == [r["step"] for r in recs]
+    names = {s[2] for s in trace["spans"]}
+    assert {"serve.prefill_feed", "serve.logits_put", "serve.retire",
+            "serve.sample"} <= names
+    rep = observe.gaps_report(trace, rec.records)
+    assert rep["steps"] == sum(r["kind"] == "serve_step"
+                               for r in rec.records) > len(recs)
+    assert 0.0 < rep["clock_skew_ms"] < 1.0
+    path = tmp_path / "flight.jsonl"
+    path.write_text("".join(json.dumps(r) + "\n" for r in rec.records))
+    assert observe.main(["--gaps", str(tmp_path / "trace"), str(path)]) == 2
+    assert "device gaps over the threshold: 0" in capsys.readouterr().out
+    assert observe.main(["--gaps", str(tmp_path / "none"), str(path)]) == 2
 
 
 def test_ctx_pages_by_hand_on_a_two_slot_batch(params):
