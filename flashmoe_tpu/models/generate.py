@@ -110,7 +110,7 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
         else:
             o = moe_layer(layer["moe"], f_in, layer_cfg, use_pallas=False,
                           routed_rows=expert_arm(layer_cfg, b * t)
-                          == "routed_rows")
+                          != "capacity")
         x = x + o.out.reshape(b, t, -1).astype(x.dtype)
         if layer_cfg.num_experts > 1:
             touched.append(jnp.sum(o.expert_counts > 0))

@@ -77,23 +77,37 @@ def _ffn_vmem(block_m: int, h: int, bi: int, gated: bool, x_isz: int,
 def _ffn_chunks(x, w_up, w_gate, block_m: int, block_i: int, gated: bool,
                 res_outs: int = 0):
     """How one grouped-FFN launch walks the intermediate axis: the chunk
-    ``bi`` (the largest divisor of I under ``block_i`` whose working set
-    fits VMEM), the up-projection weights as the kernel streams them —
-    for a gated FFN [E, H, 2*I] with each I-chunk laid out
-    [gate_chunk | up_chunk], so one block DMA brings both halves — and
-    the compiler params that ask for the VMEM the launch needs."""
+    ``bi`` (all of I when ``block_i`` allows it, else the largest divisor
+    of I under ``block_i``, shrunk until the working set fits VMEM) and
+    the compiler params that ask for the VMEM the launch needs.  A gated
+    FFN's gate weights are an operand of their own beside ``w_up``, under
+    the same index map: no launch builds an array of the weights' size."""
     if gated and w_gate is None:
         raise ValueError("gated_ffn requires w_gate")
-    e, h, i = w_up.shape
+    _, h, i = w_up.shape
     need = lambda b: _ffn_vmem(block_m, h, b, gated, x.dtype.itemsize,
                                w_up.dtype.itemsize, res_outs)
-    bi = _fit_chunk(i, _auto_block(i, block_i), need)
-    if gated:
-        nj = i // bi
-        w_up = jnp.concatenate(
-            [w_gate.reshape(e, h, nj, bi), w_up.reshape(e, h, nj, bi)],
-            axis=-1).reshape(e, h, nj * 2 * bi)
-    return bi, w_up, _vmem_params(need(bi))
+    bi = _fit_chunk(i, i if block_i >= i else _auto_block(i, block_i), need)
+    return bi, _vmem_params(need(bi))
+
+
+def _up_specs(h: int, bi: int, gated: bool):
+    """BlockSpecs of the up (and gate) weight chunk of a row tile's
+    expert: ``[E, H, I]`` blocks ``(1, H, bi)`` chosen by the
+    scalar-prefetched group id."""
+    spec = pl.BlockSpec((1, h, bi), lambda ti, j, gid, *_: (gid[ti], 0, j),
+                        memory_space=pltpu.VMEM)
+    return [spec, spec] if gated else [spec]
+
+
+def _up_products(x, wup_ref, wg_ref, bup_ref):
+    """One I-chunk's float32 up product with its bias, and the gate
+    product (None when the FFN is not gated)."""
+    up = jnp.dot(x, wup_ref[0], preferred_element_type=jnp.float32)
+    up = up + bup_ref[0, 0, :].astype(jnp.float32)
+    if wg_ref is None:
+        return up, None
+    return up, jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
 
 
 # ----------------------------------------------------------------------
@@ -131,47 +145,52 @@ def expert_ffn_dense(xs, params, cfg: MoEConfig):
 # Pallas grouped kernel
 # ----------------------------------------------------------------------
 
-def _ffn_kernel(gid_ref, x_ref, wup_ref, bup_ref, wdn_ref, bdn_ref, out_ref,
-                acc_ref, *, act_name, gated):
-    """One (row-tile, I-chunk) grid step.
-
-    When ``gated`` the up-weight block holds [w_gate; w_up] stacked on a
-    doubled chunk axis (see :func:`grouped_ffn`).
-    """
+def _ffn_kernel(gid_ref, *refs, act_name, gated, live):
+    """One (row-tile, I-chunk) grid step.  ``refs``: the count of live
+    tiles (when ``live``: a second scalar), the row tile, the up weights,
+    the gate weights (when ``gated``), the up bias, the down weights and
+    bias, the output tile and the float32 accumulator."""
+    live_ref, refs = (refs[0], refs[1:]) if live else (None, refs)
+    x_ref, wup_ref = refs[:2]
+    wg_ref = refs[2] if gated else None
+    bup_ref, wdn_ref, bdn_ref, out_ref, acc_ref = refs[-5:]
     j = pl.program_id(1)
     nj = pl.num_programs(1)
     act = activation_fn(act_name)
 
-    @pl.when(j == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def step():
+        @pl.when(j == 0)
+        def _():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[:]
-    if gated:
-        half = wup_ref.shape[2] // 2
-        g = jnp.dot(x, wup_ref[0, :, :half], preferred_element_type=jnp.float32)
-        up = jnp.dot(x, wup_ref[0, :, half:], preferred_element_type=jnp.float32)
-        up = up + bup_ref[0, 0, :].astype(jnp.float32)
-        hidden = act(g) * up
+        x = x_ref[:]
+        up, g = _up_products(x, wup_ref, wg_ref, bup_ref)
+        hidden = act(g) * up if gated else act(up)
+        acc_ref[:] += jnp.dot(
+            hidden.astype(x.dtype), wdn_ref[0],
+            preferred_element_type=jnp.float32
+        )
+
+        @pl.when(j == nj - 1)
+        def _():
+            out_ref[:] = (
+                acc_ref[:] + bdn_ref[0, 0, :].astype(jnp.float32)
+            ).astype(out_ref.dtype)
+
+    if live:
+        # a tile past the last populated one: nothing to compute, and its
+        # group id repeats the last live tile's, so nothing is fetched
+        pl.when(pl.program_id(0) < live_ref[0])(step)
     else:
-        up = jnp.dot(x, wup_ref[0], preferred_element_type=jnp.float32)
-        hidden = act(up + bup_ref[0, 0, :].astype(jnp.float32))
-    acc_ref[:] += jnp.dot(
-        hidden.astype(x.dtype), wdn_ref[0], preferred_element_type=jnp.float32
-    )
-
-    @pl.when(j == nj - 1)
-    def _():
-        out_ref[:] = (
-            acc_ref[:] + bdn_ref[0, 0, :].astype(jnp.float32)
-        ).astype(out_ref.dtype)
+        step()
 
 
 @functools.partial(
     jax.jit, static_argnames=("act_name", "gated", "block_m", "block_i",
                               "interpret"),
 )
-def grouped_ffn(x, tile_gid, w_up, b_up, w_down, b_down, w_gate=None, *,
+def grouped_ffn(x, tile_gid, w_up, b_up, w_down, b_down, w_gate=None,
+                live_tiles=None, *,
                 act_name: str, gated: bool = False, block_m: int = BLOCK_M,
                 block_i: int = DEFAULT_BLOCK_I, interpret: bool = False):
     """Grouped FFN over row-sorted tokens.
@@ -180,20 +199,25 @@ def grouped_ffn(x, tile_gid, w_up, b_up, w_down, b_down, w_gate=None, *,
     tile_gid: [T // block_m] int32 expert id owning each row tile.
     w_up:     [E, H, I]; b_up: [E, I]; w_down: [E, I, H]; b_down: [E, H];
     w_gate:   [E, H, I] for SwiGLU-style experts.
+    live_tiles: [1] int32, the populated row tiles (a ragged plan's
+              ``num_rows // block_m``); the tiles from there on are not
+              computed and their rows of the output hold nothing.  None:
+              every tile is computed.
 
     Returns [T, H].  The scalar-prefetched ``tile_gid`` drives the weight
     BlockSpec index maps, so each row tile DMAs only its own expert's weight
-    chunks (megablox-style block-sparse grouped GEMM).
+    chunks (megablox-style block-sparse grouped GEMM), and consecutive
+    tiles of one expert fetch them once.
     """
     t, h = x.shape
     e, _, i = w_up.shape
     if t % block_m:
         raise ValueError(f"rows {t} must be a multiple of block_m={block_m}")
-    bi, w_up_eff, vmem = _ffn_chunks(x, w_up, w_gate, block_m, block_i,
-                                     gated)
+    bi, vmem = _ffn_chunks(x, w_up, w_gate, block_m, block_i, gated)
     nt, nj = t // block_m, i // bi
-    up_block = (1, h, 2 * bi if gated else bi)
-    up_map = lambda ti, j, gid: (gid[ti], 0, j)
+    live = live_tiles is not None
+    scalars = (tile_gid, live_tiles) if live else (tile_gid,)
+    up_weights = (w_up, w_gate) if gated else (w_up,)
 
     # biases are lifted to [E, 1, dim] so their (1, dim) trailing block shape
     # satisfies the TPU (8, 128) tiling rule via the equal-dimension escape
@@ -201,39 +225,40 @@ def grouped_ffn(x, tile_gid, w_up, b_up, w_down, b_down, w_gate=None, *,
     b_down3 = b_down.reshape(e, 1, h)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(scalars),
         grid=(nt, nj),
         in_specs=[
-            pl.BlockSpec((block_m, h), lambda ti, j, gid: (ti, 0),
+            pl.BlockSpec((block_m, h), lambda ti, j, *_: (ti, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(up_block, up_map, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bi), lambda ti, j, gid: (gid[ti], 0, j),
+            *_up_specs(h, bi, gated),
+            pl.BlockSpec((1, 1, bi), lambda ti, j, gid, *_: (gid[ti], 0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bi, h), lambda ti, j, gid: (gid[ti], j, 0),
+            pl.BlockSpec((1, bi, h), lambda ti, j, gid, *_: (gid[ti], j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, h), lambda ti, j, gid: (gid[ti], 0, 0),
+            pl.BlockSpec((1, 1, h), lambda ti, j, gid, *_: (gid[ti], 0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((block_m, h), lambda ti, j, gid: (ti, 0),
+        out_specs=pl.BlockSpec((block_m, h), lambda ti, j, *_: (ti, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.VMEM((block_m, h), jnp.float32)],
     )
     flops = 2 * t * h * i * (3 if gated else 2)
     return pl.pallas_call(
-        functools.partial(_ffn_kernel, act_name=act_name, gated=gated),
+        functools.partial(_ffn_kernel, act_name=act_name, gated=gated,
+                          live=live),
         name="fm_ffn_fwd",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, h), x.dtype),
         cost_estimate=pl.CostEstimate(
             flops=flops,
             bytes_accessed=x.size * x.dtype.itemsize
-            + w_up_eff.size * w_up_eff.dtype.itemsize
+            + sum(w.size * w.dtype.itemsize for w in up_weights)
             + w_down.size * w_down.dtype.itemsize,
             transcendentals=t * i,
         ),
         compiler_params=vmem,
         interpret=interpret,
-    )(tile_gid, x, w_up_eff, b_up3, w_down, b_down3)
+    )(*scalars, x, *up_weights, b_up3, w_down, b_down3)
 
 
 # ----------------------------------------------------------------------
@@ -241,10 +266,11 @@ def grouped_ffn(x, tile_gid, w_up, b_up, w_down, b_down, w_gate=None, *,
 # fly, never materializing the [E, C, H] dispatch buffer in HBM
 # ----------------------------------------------------------------------
 
-def _ffn_gather_kernel(gid_ref, tok_ref, x_ref, wup_ref, bup_ref, wdn_ref,
-                       bdn_ref, out_ref, xtile, acc_ref, sems, *,
+def _ffn_gather_kernel(gid_ref, tok_ref, x_ref, wup_ref, *refs,
                        act_name, gated, block_m):
-    """One (row-tile, I-chunk) grid step with in-kernel token gather.
+    """One (row-tile, I-chunk) grid step with in-kernel token gather
+    (``refs`` as :func:`_ffn_kernel`'s from the gate weights on, then the
+    gathered row tiles and the DMA semaphores).
 
     At each tile's first I-chunk the kernel issues per-row DMAs that pull
     the NEXT tile's token rows from ``x`` (HBM) into the alternate VMEM
@@ -253,6 +279,8 @@ def _ffn_gather_kernel(gid_ref, tok_ref, x_ref, wup_ref, bup_ref, wdn_ref,
     stage building heap cells from ``tokenIds`` while processors compute
     (``packet.cuh:99-206``).
     """
+    wg_ref = refs[0] if gated else None
+    bup_ref, wdn_ref, bdn_ref, out_ref, xtile, acc_ref, sems = refs[-7:]
     ti = pl.program_id(0)
     j = pl.program_id(1)
     nt = pl.num_programs(0)
@@ -299,15 +327,8 @@ def _ffn_gather_kernel(gid_ref, tok_ref, x_ref, wup_ref, bup_ref, wdn_ref,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     x = xtile[slot]
-    if gated:
-        half = wup_ref.shape[2] // 2
-        g = jnp.dot(x, wup_ref[0, :, :half], preferred_element_type=jnp.float32)
-        up = jnp.dot(x, wup_ref[0, :, half:], preferred_element_type=jnp.float32)
-        up = up + bup_ref[0, 0, :].astype(jnp.float32)
-        hidden = act(g) * up
-    else:
-        up = jnp.dot(x, wup_ref[0], preferred_element_type=jnp.float32)
-        hidden = act(up + bup_ref[0, 0, :].astype(jnp.float32))
+    up, g = _up_products(x, wup_ref, wg_ref, bup_ref)
+    hidden = act(g) * up if gated else act(up)
     acc_ref[:] += jnp.dot(
         hidden.astype(x.dtype), wdn_ref[0], preferred_element_type=jnp.float32
     )
@@ -345,10 +366,9 @@ def grouped_ffn_tokens(x, src_tok, tile_gid, w_up, b_up, w_down, b_down,
     e, _, i = w_up.shape
     if t % block_m:
         raise ValueError(f"slab rows {t} must be a multiple of {block_m}")
-    bi, w_up_eff, vmem = _ffn_chunks(x, w_up, w_gate, block_m, block_i,
-                                     gated)
+    bi, vmem = _ffn_chunks(x, w_up, w_gate, block_m, block_i, gated)
     nt, nj = t // block_m, i // bi
-    up_block = (1, h, 2 * bi if gated else bi)
+    up_weights = (w_up, w_gate) if gated else (w_up,)
     b_up3 = b_up.reshape(e, 1, i)
     b_down3 = b_down.reshape(e, 1, h)
 
@@ -357,8 +377,7 @@ def grouped_ffn_tokens(x, src_tok, tile_gid, w_up, b_up, w_down, b_down,
         grid=(nt, nj),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),  # x: full [S, H] in HBM
-            pl.BlockSpec(up_block, lambda ti, j, gid, tok: (gid[ti], 0, j),
-                         memory_space=pltpu.VMEM),
+            *_up_specs(h, bi, gated),
             pl.BlockSpec((1, 1, bi), lambda ti, j, gid, tok: (gid[ti], 0, j),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, bi, h), lambda ti, j, gid, tok: (gid[ti], j, 0),
@@ -384,13 +403,13 @@ def grouped_ffn_tokens(x, src_tok, tile_gid, w_up, b_up, w_down, b_down,
         cost_estimate=pl.CostEstimate(
             flops=flops,
             bytes_accessed=t * h * x.dtype.itemsize * 2
-            + w_up_eff.size * w_up_eff.dtype.itemsize
+            + sum(w.size * w.dtype.itemsize for w in up_weights)
             + w_down.size * w_down.dtype.itemsize,
             transcendentals=t * i,
         ),
         compiler_params=vmem,
         interpret=interpret,
-    )(tile_gid, src_tok, x, w_up_eff, b_up3, w_down, b_down3)
+    )(tile_gid, src_tok, x, *up_weights, b_up3, w_down, b_down3)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11, 12))
@@ -717,12 +736,13 @@ def _segment_bias_grad(d, tile_gid, num_experts: int, block_m: int):
 # Residual-saving forward + custom VJP: the fused backward path
 # ----------------------------------------------------------------------
 
-def _ffn_res_kernel(gid_ref, x_ref, wup_ref, bup_ref, wdn_ref, bdn_ref,
-                    out_ref, u_out_ref, g_out_ref, acc_ref, *,
-                    act_name, gated):
+def _ffn_res_kernel(gid_ref, x_ref, wup_ref, *refs, act_name, gated):
     """Same as :func:`_ffn_kernel` but additionally writes the
     pre-activation up (and gate) chunks — the residuals the backward needs,
     saved on the way through instead of recomputed."""
+    wg_ref = refs[0] if gated else None
+    (bup_ref, wdn_ref, bdn_ref, out_ref, u_out_ref, g_out_ref,
+     acc_ref) = refs[-7:]
     j = pl.program_id(1)
     nj = pl.num_programs(1)
     act = activation_fn(act_name)
@@ -732,20 +752,12 @@ def _ffn_res_kernel(gid_ref, x_ref, wup_ref, bup_ref, wdn_ref, bdn_ref,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     x = x_ref[:]
+    up, g = _up_products(x, wup_ref, wg_ref, bup_ref)
+    u_out_ref[:] = up.astype(u_out_ref.dtype)
     if gated:
-        half = wup_ref.shape[2] // 2
-        g = jnp.dot(x, wup_ref[0, :, :half],
-                    preferred_element_type=jnp.float32)
-        up = jnp.dot(x, wup_ref[0, :, half:],
-                     preferred_element_type=jnp.float32)
-        up = up + bup_ref[0, 0, :].astype(jnp.float32)
         g_out_ref[:] = g.astype(g_out_ref.dtype)
-        u_out_ref[:] = up.astype(u_out_ref.dtype)
         hidden = act(g) * up
     else:
-        up = jnp.dot(x, wup_ref[0], preferred_element_type=jnp.float32)
-        up = up + bup_ref[0, 0, :].astype(jnp.float32)
-        u_out_ref[:] = up.astype(u_out_ref.dtype)
         hidden = act(up)
     acc_ref[:] += jnp.dot(
         hidden.astype(x.dtype), wdn_ref[0], preferred_element_type=jnp.float32
@@ -766,10 +778,10 @@ def _grouped_ffn_res(x, tile_gid, w_up, b_up, w_down, b_down, w_gate, *,
     e, _, i = w_up.shape
     if t % block_m:
         raise ValueError(f"rows {t} must be a multiple of block_m={block_m}")
-    bi, w_up_eff, vmem = _ffn_chunks(x, w_up, w_gate, block_m, block_i,
-                                     gated, res_outs=2)
+    bi, vmem = _ffn_chunks(x, w_up, w_gate, block_m, block_i, gated,
+                           res_outs=2)
     nt, nj = t // block_m, i // bi
-    up_block = (1, h, 2 * bi if gated else bi)
+    up_weights = (w_up, w_gate) if gated else (w_up,)
     b_up3 = b_up.reshape(e, 1, i)
     b_down3 = b_down.reshape(e, 1, h)
 
@@ -788,8 +800,7 @@ def _grouped_ffn_res(x, tile_gid, w_up, b_up, w_down, b_down, w_gate, *,
         in_specs=[
             pl.BlockSpec((block_m, h), lambda ti, j, gid: (ti, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(up_block, lambda ti, j, gid: (gid[ti], 0, j),
-                         memory_space=pltpu.VMEM),
+            *_up_specs(h, bi, gated),
             pl.BlockSpec((1, 1, bi), lambda ti, j, gid: (gid[ti], 0, j),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, bi, h), lambda ti, j, gid: (gid[ti], j, 0),
@@ -818,7 +829,7 @@ def _grouped_ffn_res(x, tile_gid, w_up, b_up, w_down, b_down, w_gate, *,
         ],
         compiler_params=vmem,
         interpret=interpret,
-    )(tile_gid, x, w_up_eff, b_up3, w_down, b_down3)
+    )(tile_gid, x, *up_weights, b_up3, w_down, b_down3)
     return y, u, (g if gated else None)
 
 

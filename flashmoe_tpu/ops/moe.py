@@ -18,6 +18,7 @@ all-to-all.
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Any, NamedTuple
 
@@ -74,28 +75,33 @@ def dense_ffn(params, x, cfg: MoEConfig):
 
 
 def routed_rows_ffn(params, x, r, cfg: MoEConfig):
-    """Dropless expert FFN over exactly the ROUTED rows, in plain XLA.
+    """Dropless expert FFN over exactly the ROUTED rows.
 
     The capacity arm computes ``E x capacity`` rows, and a dropless
     config's capacity is the token count: ``E x S`` rows where ``S x K``
     are routed (32 x too many at 256 experts top-8, and [E, S, .] buffers
     beside the weights).  Here the ``S x K`` (token, choice) rows are
-    sorted by expert and the three products are ``jax.lax.ragged_dot``
-    (on a TPU XLA's own grouped matmul, which reads an expert's weights
-    only if rows reached it; elsewhere a masked dense product).  No row is
-    padded, none is dropped.  x: [S, H]; r: the router's output.  Returns
-    [S, H] float32, the weighted sum of every token's K expert outputs.
+    sorted by expert, ONE stable sort, and computed in one of two forms
+    (:func:`routed_rows_form`): on a TPU whose widths tile, ONE launch of
+    the grouped Pallas kernel over rows padded to whole tiles an expert
+    (:func:`_rows_kernel_ffn`), which streams each touched expert's
+    weights once; everywhere else three ``jax.lax.ragged_dot`` over the
+    rows as sorted (a masked dense product off the TPU; on it XLA's
+    grouped matmul, a tile of up to 512 rows a GROUP whatever its rows),
+    the form the kernel is held against.  The mathematics is one: bf16
+    operands, float32 accumulation and biases, the hidden activations
+    rounded to the compute dtype before the down product.  No row is
+    dropped.  x: [S, H]; r: the router's output.  Returns [S, H] float32,
+    the weighted sum of every token's K expert outputs.
 
     A config that holds a SHARE of the experts (``cfg.experts_held``: the
     weights of experts ``expert_first`` .. + ``experts_held`` - 1, routed
     over all ``num_experts``) computes the rows that fall on its own
-    experts: the others sort behind the last group, belong to no group of
-    the grouped product and count as zero in the sum.  What the absent
+    experts: the others sort behind the last group, belong to no group
+    (and to no tile) and count as zero in the sum.  What the absent
     experts would have added is left out."""
     s, h = x.shape
     k = cfg.expert_top_k
-    act = activation_fn(cfg.hidden_act)
-    f32 = dict(preferred_element_type=cfg.accum_dtype)
     with trace_span("moe.dispatch"):
         flat_e = r.expert_idx.reshape(-1)              # row t*K + j
         sizes = r.expert_counts                        # rows an expert
@@ -105,33 +111,112 @@ def routed_rows_ffn(params, x, r, cfg: MoEConfig):
             here = (flat_e >= first) & (flat_e < first + held)
             flat_e = jnp.where(here, flat_e - first, held)
             sizes = sizes[first:first + held]
-        order = jnp.argsort(flat_e, stable=True)
-        sorted_e = flat_e[order]
-        xs = x.astype(cfg.dtype)[order // k]           # [S*K, H]
-    with trace_span("moe.expert"):
-        up = jax.lax.ragged_dot(xs, params["w_up"].astype(xs.dtype), sizes,
-                                **f32)
-        up = up + params["b_up"].astype(cfg.accum_dtype)[sorted_e]
-        if cfg.gated_ffn:
-            g = jax.lax.ragged_dot(
-                xs, params["w_gate"].astype(xs.dtype), sizes, **f32)
-            hidden = act(g) * up
-        else:
-            hidden = act(up)
-        y = jax.lax.ragged_dot(
-            hidden.astype(xs.dtype), params["w_down"].astype(xs.dtype),
-            sizes, **f32)
-        y = (y + params["b_down"].astype(cfg.accum_dtype)[sorted_e]
-             ).astype(xs.dtype)
+    ffn = (params["w_up"].astype(cfg.dtype), params["b_up"],
+           params["w_down"].astype(cfg.dtype), params["b_down"],
+           params["w_gate"].astype(cfg.dtype) if cfg.gated_ffn else None)
+    rows = (x.astype(cfg.dtype), flat_e, sizes, *ffn)
+    if routed_rows_form(cfg) == "routed_kernel":
+        y = _rows_kernel_ffn(*rows, k=k, act_name=cfg.hidden_act,
+                             block_m=rows_block_m(cfg, s),
+                             interpret=jax.default_backend() != "tpu")
+    else:
+        y = _rows_ragged_ffn(*rows, k=k, act_name=cfg.hidden_act)
     with trace_span("moe.combine"):
-        back = jnp.argsort(order)                      # row t*K + j again
         if here is not None:
             # a row of no group holds whatever the product left there
-            y = jnp.where(here[order][:, None], y, jnp.zeros((), y.dtype))
+            y = jnp.where(here[:, None], y, jnp.zeros((), y.dtype))
         return jnp.einsum(
-            "skh,sk->sh", y[back].reshape(s, k, h).astype(jnp.float32),
+            "skh,sk->sh", y.reshape(s, k, h).astype(jnp.float32),
             r.combine_weights.astype(jnp.float32),
             preferred_element_type=jnp.float32)
+
+
+def _rows_ragged_ffn(x, flat_e, sizes, w_up, b_up, w_down, b_down, w_gate, *,
+                     k, act_name):
+    """The routed rows through three ``jax.lax.ragged_dot``: x [S, H] at
+    the compute dtype; flat_e [S*K] the group of row ``t*K + j`` (a row of
+    no group: ``len(sizes)``); sizes [G].  Returns the rows' outputs
+    [S*K, H] in that order, at the compute dtype."""
+    act = activation_fn(act_name)
+    f32 = dict(preferred_element_type=jnp.float32)
+    with trace_span("moe.dispatch"):
+        order = jnp.argsort(flat_e, stable=True)
+        sorted_e = flat_e[order]
+        xs = x[order // k]                             # [S*K, H]
+    with trace_span("moe.expert"):
+        up = jax.lax.ragged_dot(xs, w_up, sizes, **f32)
+        up = up + b_up.astype(jnp.float32)[sorted_e]
+        if w_gate is not None:
+            hidden = act(jax.lax.ragged_dot(xs, w_gate, sizes, **f32)) * up
+        else:
+            hidden = act(up)
+        y = jax.lax.ragged_dot(hidden.astype(xs.dtype), w_down, sizes, **f32)
+        y = (y + b_down.astype(jnp.float32)[sorted_e]).astype(xs.dtype)
+    with trace_span("moe.combine"):
+        return y[jnp.argsort(order)]                   # row t*K + j again
+
+
+@functools.partial(jax.jit, static_argnames=("k", "act_name", "block_m",
+                                             "interpret"))
+def _rows_kernel_ffn(x, flat_e, sizes, w_up, b_up, w_down, b_down, w_gate, *,
+                     k, act_name, block_m, interpret):
+    """:func:`_rows_ragged_ffn`'s contract through ONE launch of the
+    grouped Pallas kernel (``ops/expert.grouped_ffn``: up, gate,
+    activation and down fused, the ``[rows, I]`` intermediate never in
+    HBM).  The sorted rows are padded to whole tiles of ``block_m`` an
+    expert (``ops/ragged.sorted_rows_plan``: from the one sort); a tile
+    DMAs its own expert's weights, consecutive tiles of an expert fetch
+    them once, an expert with no rows has no tile and is never read, and
+    the tiles past the last populated one skip their body.  The
+    intermediate axis is ONE chunk (an expert's three matrices, double
+    buffered, fit the kernel's VMEM ceiling at the widths served), so an
+    expert streams once however many tiles it has.  Jitted with the
+    layer's weights as operands: the mixture layers of a program share
+    one traced and lowered function."""
+    n = flat_e.shape[0]
+    with trace_span("moe.dispatch"):
+        order = jnp.argsort(flat_e, stable=True)
+        back = jnp.argsort(order)
+        n_tiles = rag.sorted_rows_tiles(n, sizes.shape[0], block_m)
+        src, tile_gid, live, starts, pad_starts = rag.sorted_rows_plan(
+            order, sizes, block_m, n_tiles)
+        xbuf = x[src // k]                             # [T_pad, H]
+    with trace_span("moe.expert"):
+        ybuf = exp.grouped_ffn(
+            xbuf, tile_gid, w_up, b_up, w_down, b_down, w_gate, live,
+            act_name=act_name, gated=w_gate is not None, block_m=block_m,
+            block_i=w_up.shape[2], interpret=interpret)
+    with trace_span("moe.combine"):
+        # a row of no group indexes past the groups: clamped, and masked
+        # by the caller
+        group = jnp.minimum(flat_e, sizes.shape[0] - 1)
+        at = pad_starts[group] + back - starts[group]
+        return ybuf[jnp.clip(at, 0, ybuf.shape[0] - 1)]
+
+
+def rows_block_m(cfg: MoEConfig, s: int) -> int:
+    """Rows of a tile of the routed-rows kernel for a span of ``s`` rows:
+    twice the rows an expert expects (``s x K / E``: for a config that
+    holds a share of the experts, the rows that fall here over the experts
+    held), as a power-of-two count of the packed tiles the compute dtype
+    allows (16 rows of bf16) and at most 256.  A decode step's 1-4 rows
+    an expert take the smallest legal tile; a 1024-token chunk's 32-64
+    take 64-128, so that nearly every expert is ONE tile."""
+    block = 32 // jnp.dtype(cfg.dtype).itemsize
+    while (block * cfg.num_experts < 2 * s * cfg.expert_top_k
+           and block < 256):
+        block *= 2
+    return block
+
+
+def routed_rows_form(cfg: MoEConfig) -> str:
+    """The form :func:`routed_rows_ffn` computes in: ``"routed_kernel"``
+    (the grouped Pallas kernel) on a TPU when the widths tile (H and I
+    whole lanes: a weight block is whole tiles), ``"routed_rows"`` (plain
+    ``ragged_dot``) everywhere else."""
+    lanes = cfg.hidden_size % 128 == 0 and cfg.intermediate_size % 128 == 0
+    return ("routed_kernel" if lanes and jax.default_backend() == "tpu"
+            else "routed_rows")
 
 
 def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
@@ -167,9 +252,9 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
                         or capacity is not None
                         or cfg.degrade_unhealthy_experts):
         raise ValueError(
-            "routed_rows is the dropless plain-XLA arm: it takes no "
-            "use_pallas, no drop_tokens config, no capacity and no "
-            "degrade_unhealthy_experts")
+            "routed_rows is the dropless serving arm, its form its own "
+            "(routed_rows_form): it takes no use_pallas, no drop_tokens "
+            "config, no capacity and no degrade_unhealthy_experts")
     dropless = routed_rows or (
         use_pallas and not cfg.drop_tokens and capacity is None)
     stats = None
@@ -280,41 +365,59 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
     )
 
 
-#: what the serving span gives the capacity arm (:func:`expert_arm`): its
-#: largest ``[E, capacity, H]`` buffer, its most rows for one routed row
+#: what the serving span gives the capacity arm against the ``ragged_dot``
+#: form of the routed rows (:func:`expert_arm`): its largest
+#: ``[E, capacity, H]`` buffer, its most rows for one routed row
 _CAPACITY_BUFFER_BYTES, _CAPACITY_ROWS_A_ROUTED_ROW = 128 * 1024 * 1024, 24
 
 
 def expert_arm(cfg: MoEConfig, s: int) -> str:
     """The arm a span of ``s`` rows takes through a layer's experts in
-    plain XLA (the serving path: ``models/generate.span_forward``), ONE
-    rule over what the shapes say each arm computes and holds.
+    the serving path (``models/generate.span_forward``), ONE rule over
+    the backend and what the shapes say each arm computes and holds.
 
-    ``"capacity"`` (``E x capacity(s)`` rows, dispatched into an
-    ``[E, capacity, H]`` buffer) while that buffer is at most 128 MiB and
-    its rows at most 24 times the ``s x K`` routed rows; ``"routed_rows"``
-    (:func:`routed_rows_ffn`, XLA's grouped matmul) beyond either, and
-    always for a config that holds a share of its experts (the capacity
-    arm indexes every expert's weights).  Why the buffer: on the chip the
-    grouped matmul costs a tile of up to 512 rows a GROUP whatever its
-    rows (64 experts of 2048 x 1536, top-4: 3.9-4.6 ms a layer from 128 to
-    1024 tokens), while the capacity arm's rows ride under the weights'
-    stream up to the chip's ridge and grow with ``E x s`` after it (1.9,
-    4.7, 9.8 ms at 128, 512, 1024): they cross near 512 tokens, 128 MiB.
+    ``"routed_kernel"`` (:func:`routed_rows_ffn` through the grouped
+    Pallas kernel) wherever :func:`routed_rows_form` says the kernel runs:
+    raced on the chip against both other arms, one mixture layer alone and
+    four in one program, it won at EVERY span (PERF.md section 6, PR 36;
+    ms a layer in the program, capacity | ``ragged_dot`` | kernel:
+    ``lfm2_24b`` 128 tokens 1.88 | 3.87 | 1.74, 512 4.66 | 4.16 | 1.91,
+    1024 9.77 | 4.60 | 2.19; ``dsmoe16b`` 32 tokens 1.59 | 3.39 | 1.49,
+    512 4.58 | 5.95 | 2.00, 2048 18.1 | 9.61 | 3.47; ``joyai_flash`` 32
+    tokens 3.44 | 4.12 | 2.25, 256 6.63 | 8.98 | 3.49, 1024 22.0 | 10.1 |
+    4.31: the launch alone at 85-90 % of the touched weights' stream),
+    and in the whole decode programs of the two cells whose steps the
+    capacity arm held (``decode_device_ms`` 11.82 -> 11.41 and 17.41 ->
+    16.28 ms), so no span is kept from it.
+
+    Where the kernel does not run (another backend, widths that are no
+    whole lanes) the choice is the one PR 33's race set, which this race
+    read again: ``"capacity"`` (``E x capacity(s)`` rows, dispatched into
+    an ``[E, capacity, H]`` buffer, in plain XLA) while that buffer is at
+    most 128 MiB and its rows at most 24 times the ``s x K`` routed rows,
+    ``"routed_rows"`` (the ``ragged_dot`` form) beyond either.  Why the
+    buffer: on the chip ``ragged_dot`` costs a tile of up to 512 rows a
+    GROUP whatever its rows (3.9-4.6 ms a layer from 128 to 1024 tokens at
+    64 experts of 2048 x 1536), while the capacity arm's rows ride under
+    the weights' stream up to the chip's ridge and grow with ``E x s``
+    after it (1.9, 4.7, 9.8 ms): they cross near 512 tokens, 128 MiB.
     Why the rows (E / K when nothing drops): at 10.7 and 16 the capacity
-    arm won every span under 512 tokens; at 32 (256 experts top-8) a
-    decode step was a tie alone and LOST in its program (PERF.md section
-    6, PR 33).  A dense layer and a rule that drops keep that arm."""
+    arm won every span under 512 tokens, at 32 (256 experts top-8) its
+    ``w_down`` product runs at half its stream.  A config that holds a
+    share of its experts takes the routed rows always (the capacity arm
+    indexes every expert's weights); a dense layer and a rule that drops
+    keep the capacity arm."""
     if (cfg.drop_tokens or cfg.degrade_unhealthy_experts
             or cfg.num_experts == 1):
         return "capacity"
-    if cfg.experts_held:
-        return "routed_rows"
+    form = routed_rows_form(cfg)
+    if form == "routed_kernel" or cfg.experts_held:
+        return form
     rows = cfg.num_experts * cfg.capacity_for(s)
     fits = (rows * cfg.hidden_size * jnp.dtype(cfg.dtype).itemsize
             <= _CAPACITY_BUFFER_BYTES)
     near = rows <= _CAPACITY_ROWS_A_ROUTED_ROW * s * cfg.expert_top_k
-    return "capacity" if fits and near else "routed_rows"
+    return "capacity" if fits and near else form
 
 
 def moe_layer(params, x, cfg: MoEConfig, *, use_pallas: bool | None = None,
@@ -333,7 +436,8 @@ def moe_layer(params, x, cfg: MoEConfig, *, use_pallas: bool | None = None,
 
     ``routed_rows`` (with ``use_pallas=False``, a dropless config and no
     ``capacity``) computes the experts over exactly the ``S x K`` routed
-    rows (:func:`routed_rows_ffn`) where the capacity arm computes
+    rows (:func:`routed_rows_ffn`, in the form :func:`routed_rows_form`
+    says) where the capacity arm computes
     ``E x S``: the serving path asks for it by :func:`expert_arm`
     (``models/generate.span_forward``); every other caller keeps the arm
     it had.

@@ -114,6 +114,46 @@ def make_ragged_plan(expert_idx, cfg: MoEConfig, block_m: int) -> RaggedPlan:
                       present)
 
 
+def sorted_rows_tiles(n_rows: int, groups: int, block_m: int) -> int:
+    """Row tiles that hold ``n_rows`` sorted rows of at most ``groups``
+    groups, each group padded to whole tiles, whatever the rows' split:
+    every row and up to ``block_m - 1`` pad rows a group that has rows."""
+    return max(1, (n_rows + min(groups, n_rows) * (block_m - 1)) // block_m)
+
+
+def sorted_rows_plan(order, sizes, block_m: int, n_tiles: int):
+    """The tile-padded layout of rows ALREADY sorted by group, from that
+    sort and the groups' sizes alone: no second sort, and the only
+    searches are ``n_tiles`` tile starts against ``len(sizes)`` group ends.
+
+    order: [N] the stable argsort of the rows' group ids (rows of no group
+    sort last: ``sum(sizes)`` may be under N); sizes: [G] rows a group.
+    Returns (``src`` [n_tiles * block_m] the row of ``order``'s domain
+    that feeds each padded row, 0 for a pad row; ``tile_gid`` [n_tiles],
+    the tiles past the last live one repeating its group; ``live`` [1]
+    the populated tiles; ``starts`` / ``pad_starts`` [G] where a group
+    begins among the sorted and among the padded rows: sorted row i of
+    group g lies at ``pad_starts[g] + i - starts[g]``)."""
+    sizes = sizes.astype(jnp.int32)
+    tiles = (sizes + block_m - 1) // block_m
+    tile_ends = jnp.cumsum(tiles)
+    starts = jnp.cumsum(sizes) - sizes
+    pad_starts = (tile_ends - tiles) * block_m
+    live = tile_ends[-1:]
+    t = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32),
+                    jnp.maximum(live - 1, 0))
+    tile_gid = jnp.minimum(
+        jnp.sum(tile_ends[None, :] <= t[:, None], axis=1, dtype=jnp.int32),
+        sizes.shape[0] - 1)
+    rank = (jnp.arange(n_tiles, dtype=jnp.int32) * block_m
+            - pad_starts[tile_gid])[:, None] + jnp.arange(
+                block_m, dtype=jnp.int32)[None, :]
+    populated = rank < sizes[tile_gid][:, None]
+    at = jnp.clip(starts[tile_gid][:, None] + rank, 0, order.shape[0] - 1)
+    src = jnp.where(populated, order[at].astype(jnp.int32), 0)
+    return src.reshape(-1), tile_gid, live, starts, pad_starts
+
+
 def ragged_dispatch(x, plan: RaggedPlan, cfg: MoEConfig, block_m: int):
     """Gather tokens into the expert-sorted padded buffer: [T_pad, H].
 

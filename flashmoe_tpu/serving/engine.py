@@ -67,6 +67,7 @@ from flashmoe_tpu.models.generate import (
     lm_logits, lm_logits_span, span_forward,
 )
 from flashmoe_tpu.ops import attention
+from flashmoe_tpu.ops.moe import expert_arm
 from flashmoe_tpu.serving.kvcache import (
     SCRATCH_PAGE, PagedKVCache, PagePool, ShardedPagePool,
     ctx_pages_bucket, init_paged_cache, prompt_pad,
@@ -721,8 +722,9 @@ class ServingEngine:
         self._phase_open = None     # (span, name, opened at)
         self._phase_ms: dict = {}   # this step's phases, by name
         self._delivered_now: dict = {}   # rid -> tokens this step
-        # this step's decode program: pages read, idle, slots, arm
-        self._ctx_pages = (0, 0.0, 0, None)
+        # this step's decode program: pages read, idle, slots, the
+        # attention's arm, the experts' arm
+        self._ctx_pages = (0, 0.0, 0, None, None)
         # this step's sampler rows: not idle, drawn, truncating
         self._sampled = np.zeros((3,), np.int64)
         # this step's host account (see _step): the time blocked on the
@@ -1300,11 +1302,12 @@ class ServingEngine:
         done[0] += 1
         done[1] += tokens
         done[2] += rows
+        arm = self._expert_arm(rows)
         if self.recorder is not None:
             self.recorder.record(
                 kind="serve_prefill", step=self.step_idx, rid=rid,
                 slot=slot, form=form, pos=pos, tokens=tokens, rows=rows,
-                pad_rows=rows - tokens,
+                pad_rows=rows - tokens, expert_arm=arm,
                 host_ms=round((self._clock() - fed[0]) * 1e3, 3),
                 starved=starved, t0_trace_ns=fed[1])
 
@@ -1759,7 +1762,22 @@ class ServingEngine:
             read = round(float(np.mean(
                 -(-lengths // (block * page)) * block + span_pages)), 3)
         self._ctx_pages = (read, max(0.0, read - float(own.mean())),
-                           len(lengths), arm)
+                           len(lengths), arm,
+                           self._expert_arm(self.serve.max_batch * t_span))
+
+    def _expert_arm(self, rows: int) -> str | None:
+        """The arm the mixture layers of a program of ``rows`` rows take
+        through their experts (``ops/moe.expert_arm``: the rule the traced
+        program asked), counted where it is the grouped kernel.  None for
+        a model with no mixture layer and for an EP-sharded step (its
+        experts are the exchange's)."""
+        mixture = self.cfg.moe_layer_indices
+        if not mixture or self._ep_fn is not None:
+            return None
+        arm = expert_arm(self.cfg.ffn_config(mixture[0]), rows)
+        if arm == "routed_kernel":
+            self.metrics.count("serve.expert_kernel_programs")
+        return arm
 
     def _sample(self, logits, knobs, n_rows: int):
         """Dispatch :func:`_sample_dynamic` on ``logits`` and ``knobs``
@@ -1833,7 +1851,7 @@ class ServingEngine:
         gc_n0, gc_s0 = gc_totals()
         self._phase_ms = {}
         self._delivered_now = {}
-        self._ctx_pages = (0, 0.0, 0, None)
+        self._ctx_pages = (0, 0.0, 0, None, None)
         self._sampled = np.zeros((3,), np.int64)
         self._state_bytes = 0
         self._wait_ms = 0.0
@@ -2036,7 +2054,7 @@ class ServingEngine:
         self.metrics.sketch("serve.host_ms", host_ms)
         if not first:
             self.metrics.sketch("serve.between_ms", between_ms)
-        ctx_pages, ctx_idle, n_decoding, attn_arm = self._ctx_pages
+        ctx_pages, ctx_idle, n_decoding, attn_arm, ffn_arm = self._ctx_pages
         sample_rows, sample_drawn, sample_sorted = map(int, self._sampled)
         if sample_rows:
             self.metrics.count("serve.sample_steps")
@@ -2110,7 +2128,7 @@ class ServingEngine:
                     kind="serve_decode", step=self.step_idx,
                     slots=n_decoding, ctx_pages=ctx_pages,
                     ctx_pages_idle=rec["ctx_pages_idle"],
-                    attn_arm=attn_arm, **more)
+                    attn_arm=attn_arm, expert_arm=ffn_arm, **more)
         if self.watchdog is not None:
             self.watchdog.observe_step(self.step_idx, step_ms)
         self.step_idx += 1

@@ -351,12 +351,17 @@ def test_grouped_ffn_vmem_request_matches_the_chips_count():
     params = ex._vmem_params(22 << 20)
     assert (22 << 20) < params.vmem_limit_bytes <= ex._VMEM_CEILING
     assert ex._vmem_params(1 << 20).vmem_limit_bytes == ex._VMEM_DEFAULT
-    # a gated launch streams [gate_chunk | up_chunk] at the compute dtype
+    # a gated launch streams the gate chunk as an operand of its own:
+    # the chunk and the VMEM request come back, no array of the weights
     x16 = jnp.zeros((16, 128), jnp.bfloat16)
     w16 = jnp.zeros((2, 128, 256), jnp.bfloat16)
-    bi, stacked, _ = ex._ffn_chunks(x16, w16, w16, 16, 128, True)
-    assert bi == 128 and stacked.shape == (2, 128, 512)
-    assert stacked.dtype == jnp.bfloat16
+    bi, params = ex._ffn_chunks(x16, w16, w16, 16, 128, True)
+    assert bi == 128 and params.vmem_limit_bytes == ex._VMEM_DEFAULT
+    # a chunk the whole intermediate axis fits under is that axis
+    assert ex._ffn_chunks(x16, w16, w16, 16, 256, True)[0] == 256
+    w768 = jnp.zeros((2, 128, 768), jnp.bfloat16)
+    assert ex._ffn_chunks(x16, w768, w768, 16, 768, True)[0] == 768
+    assert ex._ffn_chunks(x16, w768, w768, 16, 512, True)[0] == 384
     with pytest.raises(ValueError, match="requires w_gate"):
         ex._ffn_chunks(x16, w16, None, 16, 128, True)
 

@@ -281,33 +281,43 @@ def test_narrow_heads_lie_two_to_a_lane_row():
 
 # -------------------------------------------------------- (c) the expert arm
 
-def test_the_expert_arm_is_one_rule_over_what_the_arms_hold():
-    """The capacity arm while its [E, capacity, H] dispatch buffer is at
-    most 128 MiB (a decode step of 128 slots and a prompt of up to 512
-    tokens at 64 experts) and its rows are at most 24 times the routed
-    rows (E / K: 16 and 10.7 at 64 experts top-4 and top-6; 32 at 256
-    experts top-8, which keeps the routed rows for every span), the
-    routed rows beyond either and for a share of the experts; the
-    capacity arm for one expert and for a rule that drops.  No attention
-    kind, no name."""
-    arms = lambda cfg, sizes: [expert_arm(cfg, s) == "routed_rows"
-                               for s in sizes]
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_the_expert_arm_is_one_rule_over_what_the_arms_hold(monkeypatch,
+                                                            backend):
+    """Where the grouped kernel runs (a TPU, whole-lane widths) every
+    dropless span takes the routed rows through it: it won every span of
+    ISSUE 36's race.  Elsewhere the capacity arm while its
+    [E, capacity, H] dispatch buffer is at most 128 MiB (a decode step of
+    128 slots and a prompt of up to 512 tokens at 64 experts) and its rows
+    are at most 24 times the routed rows (E / K: 16 and 10.7 at 64 experts
+    top-4 and top-6; 32 at 256 experts top-8, which keeps the routed rows
+    for every span), the routed rows as ``ragged_dot`` beyond either and
+    for a share of the experts; on either backend the capacity arm for
+    one expert and for a rule that drops.  No attention kind, no name."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    routed = "routed_kernel" if backend == "tpu" else "routed_rows"
+    arms = lambda cfg, sizes: [expert_arm(cfg, s) for s in sizes]
+    short = "capacity" if backend == "cpu" else routed
     sizes = (32, 128, 512, 768, 1024, 2048)
     lfm = PRESETS["lfm2-24b-a2b"]()
     ds = PRESETS["deepseek-moe-16b"]()
-    assert arms(lfm, sizes) == arms(ds, sizes) == [
-        False, False, False, True, True, True]
+    assert arms(lfm, sizes) == arms(ds, sizes) == [short] * 3 + [routed] * 3
     joyai = PRESETS["joyai-llm-flash"]()
-    assert all(arms(joyai, (32, 128, 160, 256, 1024)))
+    assert arms(joyai, (32, 128, 160, 256, 1024)) == [routed] * 5
     assert arms(joyai.replace(expert_top_k=12), (32, 128, 160)) == [
-        False, False, True]              # 21 rows a routed row; 160 MiB
-    assert all(arms(PRESETS["ling-3.0-flash"](experts_held=128), sizes))
+        short, short, routed]            # 21 rows a routed row; 160 MiB
+    assert arms(PRESETS["ling-3.0-flash"](experts_held=128),
+                sizes) == [routed] * 6
     assert expert_arm(lfm.ffn_config(0), 4096) == "capacity"      # dense
     assert expert_arm(PRESETS["switch-base"](), 4096) == "capacity"  # drops
     assert expert_arm(ds.replace(degrade_unhealthy_experts=True),
                       4096) == "capacity"
     # float32 activations: the same bytes at half the rows
-    assert arms(lfm.replace(dtype=jnp.float32), (256, 384)) == [False, True]
+    assert arms(lfm.replace(dtype=jnp.float32), (256, 384)) == [short,
+                                                                routed]
+    # widths that are no whole lanes keep the plain forms on a TPU too
+    odd = ds.replace(intermediate_size=1472)
+    assert arms(odd, (32, 2048)) == ["capacity", "routed_rows"]
 
 
 def test_both_expert_arms_compute_the_references_layer(params):

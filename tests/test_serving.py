@@ -1325,7 +1325,7 @@ def _parent_span_step(params, cfg, pools, toks, block_tables, positions,
         o = moe_layer(layer["moe"], f_in.reshape(b * t_span, -1),
                       layer_cfg, use_pallas=False,
                       routed_rows=expert_arm(layer_cfg, b * t_span)
-                      == "routed_rows")
+                      != "capacity")
         x = x + o.out.reshape(b, t_span, -1).astype(x.dtype)
 
     if row is not None:

@@ -392,26 +392,38 @@ def _fm_kernels(text):
             if n.startswith("fm_")]
 
 
+def _no_stacked_gate_up(text, e, h, i):
+    """No array of a layer's gate + up weights side by side ([E, H, 2I]:
+    what the grouped kernel's gated form concatenated on every call before
+    ISSUE 36) in a compiled program."""
+    return _arrays_of(text, e, h, 2 * i) == []
+
+
 def _latent_pool_copies(text):
     return re.findall(rf"^.*= {_LATENT_POOL}\S* copy\(.*$", text, re.M)
 
 
 def test_mla_prefill_chunk_fits_and_computes_the_routed_rows(mla_programs):
-    """A 1024-token chunk at the widest context: the experts are XLA's
-    grouped matmul over the 8192 routed rows (three a mixture layer), no
-    [256, 1024, .] capacity buffer, and under 14.5 GB (13.80 as compiled
-    on the pool of 640-wide rows, 13.63 on 576; the E x S arm took
-    16.16).  A chunk is no short span: it keeps the gather arm (whole
-    pages scattered, the slot's pages gathered) and copies no pool."""
+    """A 1024-token chunk at the widest context: the experts are ONE
+    launch of the grouped Pallas kernel a mixture layer over the 8192
+    routed rows in 64-row tiles (``fm_ffn_fwd``: four in the program, no
+    ``ragged_dot``, no [8192, 768] intermediate in HBM, no
+    [256, 2048, 1536] gate | up array, no [256, 1024, .] capacity buffer),
+    and under 14.5 GB (13.78 as compiled; 13.80 with XLA's grouped matmul;
+    the E x S arm took 16.16).  A chunk is no short span: its attention
+    keeps the gather arm (whole pages scattered, the slot's pages
+    gathered) and copies no pool."""
     compiled = mla_programs["chunk"].compile()
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert 12.6e9 < total < 14.5e9
     text = compiled.as_text()
-    assert text.count("ragged-dot-metadata = ") >= 1
-    assert "[8192,768]" in text and "[256,1024," not in text
-    assert _fm_kernels(text) == [] and " scatter(" in text
+    assert "ragged-dot" not in text
+    assert "[8192,768]" not in text and "[256,1024," not in text
+    assert _no_stacked_gate_up(text, 256, 2048, 768)
+    assert _fm_kernels(text) == ["fm_ffn_fwd"] * 4 and " scatter(" in text
+    assert "moe.expert/" in text
     assert "bf16[5,16384,16,640]{3,2,1,0" in text
     assert _latent_pool_copies(text) == []
     assert "attn.mla_prefill" in text and "attn.mla_decode" not in text
@@ -452,7 +464,12 @@ def test_mla_decode_step_reads_latent_rows_only(mla_programs,
            if {"7168", "128"} <= set(s.split(","))
            and s.split(",").count("32") >= 2]
     assert big == []
-    assert _fm_kernels(text) == ["fm_latent_decode"] * 5
+    # a latent layer's attention, then (the mixture layers) its experts:
+    # ONE launch of the grouped FFN kernel where three ragged_dot stood
+    assert _fm_kernels(text) == ["fm_latent_decode"] + [
+        "fm_latent_decode", "fm_ffn_fwd"] * 4
+    assert "ragged-dot" not in text
+    assert _no_stacked_gate_up(text, 256, 2048, 768)
     for width in (576, 640):
         assert _arrays_of(text, 32, 7168, width) == []
         assert _arrays_of(text, 32, 448, 16, width) == []
@@ -529,18 +546,23 @@ def test_backlog_decode_step_is_the_program_the_ledger_measured(
     text = compiled.as_text()
     assert compiled.memory_analysis().alias_size_in_bytes \
         >= 2 * 6 * 2048 * 16 * 16 * 128 * 2                  # 1.61 GB
-    # 9.54 / 9.59 / 9.72 (the chunk 10.38 with E x S rows)
+    # 9.53 / 9.59 / 9.74 (the chunk 10.38 with E x S rows)
     assert 9.3e9 < _program_bytes(compiled) < 11e9
     assert not re.findall(
         r"^.*= bf16\[6,2048,16,16,128\]\S* copy\(.*$", text, re.M)
     assert "moe.gate" in text and "moe.expert" in text
-    # since ISSUE 33 ONE rule picks the experts' arm
-    # (``ops/moe.expert_arm``): the capacity arm for the decode and verify
-    # steps (32 and 160 rows: what they compiled to before), XLA's
-    # grouped matmul over the 6144 routed rows for the 1024-token chunk
-    assert (text.count("ragged-dot-metadata = ") >= 1) == (program
-                                                           == "chunk")
+    # ONE rule picks the experts' arm (``ops/moe.expert_arm``): since
+    # ISSUE 36 the routed rows through the grouped Pallas kernel at every
+    # span on a TPU (192, 960 and 6144 rows here): one ``fm_ffn_fwd`` a
+    # layer, no ``ragged_dot``, no [64, capacity, .] dispatch buffer, no
+    # [64, 2048, 2816] gate | up array
+    assert "ragged-dot" not in text
+    assert _no_stacked_gate_up(text, 64, 2048, 1408)
+    for rows in (32, 160, 1024):                    # capacity(s) = s
+        assert _arrays_of(text, 64, rows, 2048) == []
     kernels = _fm_kernels(text)
+    assert [n for n in kernels if n == "fm_ffn_fwd"] == ["fm_ffn_fwd"] * 6
+    kernels = [n for n in kernels if n != "fm_ffn_fwd"]
     if program == "chunk":
         assert kernels == []
         assert _arrays_of(text, 16, 2560, 128)      # its gathered context
@@ -556,7 +578,8 @@ def test_backlog_whole_prompt_prefill_fits_beside_the_pool(
         backlog_programs):
     """A 2048-token prompt at once: 8.33 GB as compiled (the weights,
     f32 scores of 16 heads over 2048 x 2048, the experts over the 12288
-    routed rows; 9.76 GB with E x S rows before ISSUE 33), which leaves
+    routed rows in 256-row tiles; 9.76 GB with E x S rows before ISSUE
+    33), which leaves
     the engine's pool its 1.61 GB; the program holds no pool and hands
     back one K and one V run for ``store_prefill``."""
     compiled = backlog_programs["prefill"].compile()
@@ -608,8 +631,8 @@ def test_hybrid_programs_fit_the_chip_with_state_and_pool_in_place(
     10.34 GB of weights, and the latent pool (0.84 GB of 640-wide rows),
     the float32 state (0.805 GB) and the convolution's inputs once each,
     aliased to the outputs; no copy of the state or of the pool; the
-    experts are XLA's grouped matmul over the routed rows against the 128
-    experts held; the decode program is one recurrence step a 'kda'
+    experts are the grouped Pallas kernel over the routed rows that fall
+    on the 128 experts held; the decode program is one recurrence step a 'kda'
     layer, reads the latent layer's pages in place (ONE
     ``fm_latent_decode``, a 164 kB table as scalars, no gathered context)
     and hands back what it counted; the chunk keeps the gather arm."""
@@ -625,10 +648,15 @@ def test_hybrid_programs_fit_the_chip_with_state_and_pool_in_place(
         assert re.search(shape, text)
         assert not re.findall(rf"^.*= {shape}\S* copy\(.*$", text, re.M)
     assert _latent_pool_copies(text) == []
-    assert text.count("ragged-dot-metadata = ") >= 1
+    assert "ragged-dot" not in text
     assert "[128,2560,768]" in text and "[512,2560,768]" not in text
-    assert "moe.route_groups" in text        # and plain XLA throughout
+    assert _no_stacked_gate_up(text, 128, 2560, 768)
+    assert "moe.route_groups" in text
     kernels = _fm_kernels(text)
+    # the six mixture layers' experts: ONE launch of the grouped FFN
+    # kernel each, over the rows that fall on the 128 experts held
+    assert [n for n in kernels if n == "fm_ffn_fwd"] == ["fm_ffn_fwd"] * 6
+    kernels = [n for n in kernels if n != "fm_ffn_fwd"]
     if program == "decode":
         assert kernels == ["fm_latent_decode"]
         for width in (576, 640):
@@ -701,13 +729,18 @@ def test_lfm2_programs_fit_the_chip_with_inputs_and_pool_in_place(
     lo, hi = {"decode": (11.8e9, 12.3e9), "chunk": (13.0e9, 13.8e9),
               "prefill": (10.6e9, 11.1e9)}[program]
     assert lo < _program_bytes(compiled) < hi
-    # the experts by ``ops/moe.expert_arm``: the capacity arm for the
-    # decode step's 128 rows ([64, 128, .] buffers, 33 MB of dispatch),
-    # XLA's grouped matmul over the 4096 routed rows of 1024 tokens
-    assert (text.count("ragged-dot-metadata = ") >= 1) == (program
-                                                           != "decode")
+    # the experts by ``ops/moe.expert_arm``: since ISSUE 36 the routed
+    # rows through the grouped Pallas kernel at every span on a TPU (512
+    # rows of a decode step in 16-row tiles, 4096 of 1024 tokens in
+    # 128-row tiles): one ``fm_ffn_fwd`` a mixture layer, no
+    # ``ragged_dot``, no [64, 128, .] dispatch buffer
+    assert "ragged-dot" not in text
     assert "[64,2048,1536]" in text
+    assert _no_stacked_gate_up(text, 64, 2048, 1536)
+    assert _arrays_of(text, 64, 128, 2048) == []
     kernels = _fm_kernels(text)
+    assert [n for n in kernels if n == "fm_ffn_fwd"] == ["fm_ffn_fwd"] * 8
+    kernels = [n for n in kernels if n != "fm_ffn_fwd"]
     if program == "prefill":
         assert kernels == [] and "attn.conv_prefill" in text
         assert len(jax.tree.leaves(compiled.out_info)) == 1 + 3
