@@ -519,7 +519,9 @@ def phase_serve_hybrid(seed):
     and chunked prefill (the prompt of 33 crosses a 16-token chunk), four
     slots.  Against ``generate()`` and its logits, as :func:`phase_serve`.
     Then the other pairing: LFM2-24B-A2B's widths, per-slot convolution
-    inputs beside a K/V pool of 64-wide heads."""
+    inputs beside a K/V pool of 64-wide heads; then NVIDIA-Nemotron-3-Nano-
+    30B-A3B's: a state-space state beside a two-head K/V pool, every
+    layer a mixer or a mixture alone."""
     import jax
     import jax.numpy as jnp
 
@@ -569,6 +571,30 @@ def phase_serve_hybrid(seed):
     cut = ("LFM2-24B-A2B, num_layers 40 -> 3 (layers 0, 2 and 3: dense "
            "conv, mixture attention, mixture conv), 64 experts and the "
            "whole vocabulary: f32 weights "
+           f"{sum(a.nbytes for a in jax.tree.leaves(params)) / 2**30:.2f} "
+           "GiB")
+    ok &= _serve_case(params, cfg.replace(dtype=jnp.float32), serve, seed,
+                      cut + "; dtype bf16 -> f32, matmul precision highest",
+                      {"phase_of": "serve_hybrid"})
+    gc.collect()
+    ok &= _serve_case(params, cfg, serve, seed, cut,
+                      {"phase_of": "serve_hybrid"})
+    del params
+    gc.collect()
+    # a third pairing, and layers that are ONE thing: NVIDIA-Nemotron-3-
+    # Nano-30B-A3B's widths, four layers ``MEM*`` (a state-space mixer, a
+    # mixture of ungated relu^2 experts of width 1856 with 64 of 128 held,
+    # a second mixer, attention of 32 query heads over 2 K/V heads with no
+    # rotary embedding), half the vocabulary: a float32 state [64, 64, 128]
+    # a slot beside a K/V pool of ONE layer, the state carried across the
+    # 16-token chunk, the experts through ``fm_ffn_fwd`` at a width of 14.5
+    # lanes
+    cfg = PRESETS["nemotron-3-nano-30b-a3b"](
+        pattern="MEM*", experts_held=64, vocab_size=65536)
+    params = init_params(jax.random.PRNGKey(seed), cfg)
+    cut = ("NVIDIA-Nemotron-3-Nano-30B-A3B, num_layers 52 -> 4 (MEM*), "
+           "experts 128 -> 64 held (routed over 128), vocab 131072 -> "
+           "65536: f32 weights "
            f"{sum(a.nbytes for a in jax.tree.leaves(params)) / 2**30:.2f} "
            "GiB")
     ok &= _serve_case(params, cfg.replace(dtype=jnp.float32), serve, seed,

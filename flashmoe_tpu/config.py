@@ -19,6 +19,7 @@ the derived-quantity formulas of ``types.cuh:497-499``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import math
 from typing import Any
@@ -35,7 +36,7 @@ LANE = 128
 
 #: the mixers that keep a constant-size state a SLOT and cache nothing a
 #: token (``MoEConfig.slot_state`` says what each keeps)
-STATE_MIXERS = ("kda", "conv")
+STATE_MIXERS = ("kda", "conv", "ssm")
 
 
 class Activation:
@@ -45,6 +46,7 @@ class Activation:
     RELU = "relu"
     GELU = "gelu"
     SILU = "silu"  # used by Mixtral/DeepSeek family (gated FFN)
+    RELU2 = "relu2"  # relu(x)^2: ungated experts of two matrices
 
 
 _DTYPE_MAP = {
@@ -152,17 +154,37 @@ class MoEConfig:
     # a causal depthwise convolution of conv_taps taps over a gated
     # projection of the input, between two gates).  The last two keep a
     # constant-size state a slot: no positions, nothing cached a token.
+    # "ssm" is a selective state-space mixer in its scalar-decay form
+    # (Mamba-2: ssm_heads heads of width ssm_head_dim over a float32
+    # state [ssm_head_dim, ssm_state] a head, the input and output maps B
+    # and C shared by the heads of one of ssm_groups groups, behind a
+    # causal depthwise convolution of ssm_conv taps with a bias; its span
+    # form works in chunks of ssm_chunk tokens), a state a slot as well.
     # ``layer_mixers`` names every layer; empty, every layer is
-    # ``attention_kind``.
+    # ``attention_kind``.  An entry None is a layer with NO mixer:
+    # ``x + ffn(norm(x))`` alone.
     layer_mixers: tuple = ()
+    # the feed-forward part PER LAYER: "moe", "dense" or None (a layer
+    # that is its mixer alone, ``x + mixer(norm(x))``, with ONE norm).
+    # Empty: what moe_frequency and first_k_dense say, every layer one.
+    layer_ffns: tuple = ()
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_lower_bound: float = -5.0  # log of the smallest decay a step
     conv_taps: int = 3
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
     # an "mha" layer norms every head of q and of k (RMSNorm over the
     # head's width, weights ``q_norm`` / ``k_norm``) before RoPE
     qk_norm: bool = False
+    # an "mha" layer rotates q and k by their positions (RoPE); False: it
+    # applies none, and positions reach it through the causal mask alone
+    use_rope: bool = True
     # the eps of every RMSNorm of the model (block, final, q/k)
     norm_eps: float = 1e-6
     # the share of a mixture layer's experts THIS chip holds, of a
@@ -172,6 +194,15 @@ class MoEConfig:
     # routed to its own experts and leaves the others' part out.
     expert_first: int = 0
     experts_held: int = 0
+    # columns of ZEROS the routed experts' matrices are STORED with beyond
+    # intermediate_size (w_up / w_gate / b_up columns, w_down rows; 0:
+    # none).  Every activation here maps 0 to 0, so the layer's result is
+    # the published one; what it buys is a stored width of whole lanes
+    # (1856 + 64 = 15 x 128): the chip keeps an array whose last dimension
+    # is no whole lanes in ANOTHER dimension order, and copies it into
+    # row-major order before every kernel that takes it (PERF.md section
+    # 6, PR 39).  Serving only: a gradient would fill the zeros.
+    intermediate_pad: int = 0
 
     # --- numerics ---
     dtype: Any = jnp.bfloat16
@@ -409,15 +440,25 @@ class MoEConfig:
         else:
             raise ValueError(f"attention_kind {self.attention_kind!r} not "
                              f"in ('mha', 'mla')")
-        object.__setattr__(self, "layer_mixers", tuple(self.layer_mixers))
-        if self.layer_mixers and len(self.layer_mixers) != self.num_layers:
+        for name in ("layer_mixers", "layer_ffns"):
+            named = tuple(getattr(self, name))
+            object.__setattr__(self, name, named)
+            if named and len(named) != self.num_layers:
+                raise ValueError(f"{name} names {len(named)} layers of "
+                                 f"{self.num_layers}")
+        if set(self.layer_ffns) - {"moe", "dense", None}:
+            raise ValueError(f"layer_ffns {self.layer_ffns} not of "
+                             f"('moe', 'dense', None)")
+        if "moe" in self.layer_ffns and self.num_experts < 2:
+            raise ValueError("a 'moe' layer needs num_experts >= 2")
+        if (None, None) in self.layers:
             raise ValueError(
-                f"layer_mixers names {len(self.layer_mixers)} layers of "
-                f"{self.num_layers}")
-        if set(self.mixers) - {"mha", "mla", *STATE_MIXERS}:
+                f"layer {self.layers.index((None, None))} has neither a "
+                f"mixer nor a feed-forward part")
+        if set(self.mixers) - {"mha", "mla", *STATE_MIXERS, None}:
             raise ValueError(f"layer_mixers {self.mixers} not of "
-                             f"('mha', 'mla') + {STATE_MIXERS}")
-        if set(self.mixers) - {*STATE_MIXERS, self.attention_kind}:
+                             f"('mha', 'mla', None) + {STATE_MIXERS}")
+        if set(self.mixers) - {*STATE_MIXERS, self.attention_kind, None}:
             raise ValueError(
                 f"layer_mixers {self.mixers}: the layers that cache rows "
                 f"are all attention_kind={self.attention_kind!r}")
@@ -432,6 +473,25 @@ class MoEConfig:
             raise ValueError(
                 "qk_norm norms the heads of an 'mha' layer's q and k: an "
                 "'mla' layer norms its latent")
+        if not self.use_rope and self.attention_kind != "mha":
+            raise ValueError(
+                "use_rope=False is an 'mha' layer's: an 'mla' layer's "
+                "shared key IS its rotary part")
+        if "ssm" in self.mixers:
+            sizes = (self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
+                     self.ssm_state, self.ssm_chunk)
+            if (min(sizes) < 1 or self.ssm_conv < 2
+                    or self.ssm_heads % self.ssm_groups):
+                raise ValueError(
+                    "an 'ssm' layer needs ssm_heads, ssm_head_dim, "
+                    "ssm_state, ssm_chunk >= 1, ssm_groups dividing "
+                    f"ssm_heads and ssm_conv >= 2, got {sizes} and "
+                    f"{self.ssm_conv}")
+            if self.is_training:
+                raise NotImplementedError(
+                    "training through an 'ssm' layer: the chunked form's "
+                    "gradient is not held against the recurrence's by any "
+                    "test; a serving config (is_training=False) runs it")
         if "kda" in self.mixers:
             # the chunkwise form takes exp(16 x |bound|) in float32
             if (self.kda_heads < 1 or self.kda_head_dim < 1
@@ -458,6 +518,14 @@ class MoEConfig:
             raise ValueError(
                 f"experts {self.expert_first}..+{self.experts_held} are "
                 f"not among num_experts={self.num_experts}")
+        if self.intermediate_pad < 0 or self.intermediate_pad % 64:
+            raise ValueError("intermediate_pad must be a multiple of 64 "
+                             ">= 0")
+        if self.intermediate_pad and (self.is_training or self.tp > 1):
+            raise ValueError(
+                "intermediate_pad stores zero columns that only a forward "
+                "pass keeps zero and a tensor-parallel split would count "
+                "as the model's: is_training=False and tp=1 only")
         if self.experts_held and self.ep > 1:
             raise ValueError("experts_held is one chip's share of a "
                              "layer: it does not compose with ep > 1")
@@ -665,31 +733,48 @@ class MoEConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    @functools.cached_property
+    def layers(self) -> tuple:
+        """THE description of every layer, ``(mixer, ffn)``: the token
+        mixer ("mha", "mla" or one of ``STATE_MIXERS``) and the
+        feed-forward part ("moe" or "dense"), either of which may be None:
+        a layer is ``x + mixer(norm(x))``, then ``x + ffn(norm(x))``, with
+        one norm for each part it has.  Everything that asks what a layer
+        is (the parameter tree, the layer loops, the cache's layout, the
+        counts) reads this."""
+        mixers = self.layer_mixers or (self.attention_kind,) * self.num_layers
+        ffns = self.layer_ffns
+        if not ffns:
+            f = max(1, self.moe_frequency)
+            ffns = tuple(
+                "moe" if (self.num_experts > 1 and li >= self.first_k_dense
+                          and (li + 1) % f == 0) else "dense"
+                for li in range(self.num_layers))
+        return tuple(zip(mixers, ffns))
+
     @property
     def moe_layer_indices(self) -> tuple[int, ...]:
-        """Which transformer layers carry an MoE FFN (vs dense)."""
-        if self.num_experts <= 1:
-            return ()
-        f = max(1, self.moe_frequency)
-        return tuple(i for i in range(self.first_k_dense, self.num_layers)
-                     if (i + 1) % f == 0)
+        """Which transformer layers carry an MoE FFN (vs dense or none)."""
+        return tuple(li for li, (_, ffn) in enumerate(self.layers)
+                     if ffn == "moe")
 
     def ffn_config(self, li: int) -> "MoEConfig":
         """The config layer ``li``'s feed-forward runs under: this one
         for a mixture layer, else one dense expert (no router, no shared
         experts) of the dense width."""
-        if li in self.moe_layer_indices:
+        if self.layers[li][1] == "moe":
             return self
         return self.replace(
             num_experts=1, expert_top_k=1, num_shared_experts=0,
             n_group=1, topk_group=1, expert_first=0, experts_held=0,
+            intermediate_pad=0,
             intermediate_size=(self.dense_intermediate_size
                                or self.intermediate_size))
 
     @property
     def mixers(self) -> tuple:
-        """The token mixer of every layer."""
-        return self.layer_mixers or (self.attention_kind,) * self.num_layers
+        """The token mixer of every layer (None: the layer has none)."""
+        return tuple(mixer for mixer, _ in self.layers)
 
     @property
     def cache_layers(self) -> tuple:
@@ -697,13 +782,13 @@ class MoEConfig:
         not of ``STATE_MIXERS``): layer ``cache_layers[i]`` owns index i
         of the paged pools."""
         return tuple(li for li, m in enumerate(self.mixers)
-                     if m not in STATE_MIXERS)
+                     if m is not None and m not in STATE_MIXERS)
 
     @property
     def state_layers(self) -> tuple:
-        """The layers that keep a constant-size state a slot ('kda',
-        'conv'): layer ``state_layers[i]`` owns index i of the per-slot
-        arrays (:attr:`slot_state`)."""
+        """The layers that keep a constant-size state a slot (a mixer of
+        ``STATE_MIXERS``): layer ``state_layers[i]`` owns index i of the
+        per-slot arrays (:attr:`slot_state`)."""
         return tuple(li for li, m in enumerate(self.mixers)
                      if m in STATE_MIXERS)
 
@@ -715,8 +800,16 @@ class MoEConfig:
         float32 delta-rule ``state`` [heads, d, d] and ``conv``, the
         convolution's last kda_conv - 1 inputs of q, k and v side by
         side; 'conv': ``conv``, the convolution's last conv_taps - 1
-        inputs (hidden_size each), in the activations' dtype."""
+        inputs (hidden_size each), in the activations' dtype; 'ssm': the
+        float32 ``state`` [heads, head_dim, ssm_state] and ``conv``, the
+        last ssm_conv - 1 inputs of its convolution (x, B and C side by
+        side: :attr:`ssm_conv_width` each)."""
         kinds = set(self.mixers) & set(STATE_MIXERS)
+        if kinds == {"ssm"}:
+            return (("state", (self.ssm_heads, self.ssm_head_dim,
+                               self.ssm_state), jnp.float32),
+                    ("conv", ((self.ssm_conv - 1) * self.ssm_conv_width,),
+                     self.dtype))
         if kinds == {"kda"}:
             n, d = self.kda_heads, self.kda_head_dim
             return (("state", (n, d, d), jnp.float32),
@@ -726,6 +819,17 @@ class MoEConfig:
             return (("conv", ((self.conv_taps - 1) * self.hidden_size,),
                      self.dtype),)
         return ()
+
+    @property
+    def ssm_inner(self) -> int:
+        """Channels an 'ssm' mixer works on: heads x head width."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels an 'ssm' mixer's convolution runs over: x beside the
+        groups' B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def kv_token_elems(self) -> int:
@@ -790,13 +894,10 @@ class MoEConfig:
     def param_count(self) -> int:
         """PC (types.cuh:491-492): Chinchilla-style dense parameter count used
         by the Decider's cost model for gradient-buffer sizing."""
-        h, i, v, l = (
-            self.hidden_size,
-            self.intermediate_size,
-            self.vocab_size,
-            self.num_layers,
-        )
-        return v * h + l * (4 * h * h + 2 * h * i) + h * v
+        h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
+        mixers = sum(m is not None for m, _ in self.layers)
+        ffns = sum(f is not None for _, f in self.layers)
+        return v * h + mixers * 4 * h * h + ffns * 2 * h * i + h * v
 
     # ------------------------------------------------------------------
     # IO

@@ -95,17 +95,22 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
     b, t, _ = x.shape
     pools = None if cache is None else tuple(cache)
     rows, held, touched = [], [], []
-    for li, layer in enumerate(params["layers"]):
-        a, pools, span = paged_attention(
-            layer, rms_norm(x, layer["attn_norm"], cfg.norm_eps), cfg,
-            pools, li, pos, write, block_tables, absorbed=absorbed,
-            valid=valid, slots=slots, fresh=fresh)
-        rows.append(span)
-        x = x + a
+    for li, (layer, (mixer, ffn)) in enumerate(zip(params["layers"],
+                                                   cfg.layers)):
+        # a layer is the parts ``cfg.layers`` names, each behind its norm
+        if mixer is not None:
+            a, pools, span = paged_attention(
+                layer, rms_norm(x, layer["attn_norm"], cfg.norm_eps), cfg,
+                pools, li, pos, write, block_tables, absorbed=absorbed,
+                valid=valid, slots=slots, fresh=fresh)
+            rows.append(span)
+            x = x + a
+        if ffn is None:
+            continue
         f_in = rms_norm(x, layer["ffn_norm"], cfg.norm_eps).reshape(
             b * t, -1)
         layer_cfg = cfg.ffn_config(li)
-        if mixture is not None and li in cfg.moe_layer_indices:
+        if mixture is not None and ffn == "moe":
             o = mixture(layer["moe"], f_in, layer_cfg)
         else:
             o = moe_layer(layer["moe"], f_in, layer_cfg, use_pallas=False,

@@ -149,6 +149,45 @@ def lfm2_24b_a2b(**overrides) -> MoEConfig:
     return MoEConfig(**base)
 
 
+def nemotron3_nano_30b_a3b(**overrides) -> MoEConfig:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B (31.6B-A3.2B; huggingface.co/nvidia/
+    NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 ``config.json``, ``model_type``
+    nemotron_h): 52 blocks of width 2688, each ONE thing behind one norm by
+    ``hybrid_override_pattern``: a state-space mixer (``M``, 23: Mamba-2,
+    64 heads of 64 over a float32 state of 128 a channel, 8 groups, a
+    4-tap convolution with a bias, chunks of 128), a mixture of experts
+    (``E``, 23: 128 ungated relu^2 experts of width 1856, STORED with 64
+    zero columns to 1920 = 15 x 128 lanes, top-6 + 1 shared
+    of width 3712 behind a sigmoid router with a selection bias,
+    normalised weights times 2.5) or attention (``*``, 6: 32 query heads
+    over 2 K/V heads of 128, NO rotary embedding); RMSNorm eps 1e-5,
+    untied head.  The shared expert is held as ``num_shared_experts`` 2 x
+    1856: for an ungated expert one of width 3712 IS two of 1856 over the
+    halves of the same two matrices.  ``pattern`` (an override) replaces
+    the published pattern, ``num_layers`` alone keeps its first letters."""
+    pattern = overrides.pop(
+        "pattern", "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+    )[:overrides.get("num_layers")]
+    base = dict(
+        num_experts=128, expert_top_k=6, num_shared_experts=2,
+        hidden_size=2688, intermediate_size=1856, vocab_size=131072,
+        num_heads=32, num_kv_heads=2, head_dim=128, use_rope=False,
+        ssm_heads=64, ssm_head_dim=64, ssm_groups=8, ssm_state=128,
+        ssm_conv=4, ssm_chunk=128, norm_eps=1e-5,
+        router_score="sigmoid", router_bias=True, norm_topk_prob=True,
+        routed_scaling_factor=2.5, sequence_len=4096, gated_ffn=False,
+        hidden_act=Activation.RELU2, drop_tokens=False, dtype=jnp.bfloat16,
+    )
+    base.update(overrides)
+    # the experts stored at whole lanes: 64 zero columns beside 1856
+    base.setdefault("intermediate_pad", -base["intermediate_size"] % 128)
+    kinds = {"M": ("ssm", None), "E": (None, "moe"), "*": ("mha", None)}
+    base.setdefault("num_layers", len(pattern))
+    base.setdefault("layer_mixers", tuple(kinds[c][0] for c in pattern))
+    base.setdefault("layer_ffns", tuple(kinds[c][1] for c in pattern))
+    return MoEConfig(**base)
+
+
 PRESETS = {
     "mixtral-8x7b": mixtral_8x7b,
     "deepseek-moe-16b": deepseek_moe_16b,
@@ -157,4 +196,5 @@ PRESETS = {
     "joyai-llm-flash": joyai_llm_flash,
     "ling-3.0-flash": ling3_flash,
     "lfm2-24b-a2b": lfm2_24b_a2b,
+    "nemotron-3-nano-30b-a3b": nemotron3_nano_30b_a3b,
 }

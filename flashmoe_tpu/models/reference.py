@@ -25,6 +25,7 @@ def activation_fn(name: str):
         Activation.RELU: jax.nn.relu,
         Activation.GELU: jax.nn.gelu,
         Activation.SILU: jax.nn.silu,
+        Activation.RELU2: lambda x: jnp.square(jax.nn.relu(x)),
     }[name]
 
 
@@ -50,6 +51,14 @@ def init_moe_params(key, cfg: MoEConfig) -> dict:
         p["w_gate"] = (
             jax.random.normal(ks[3], (e, h, i), cfg.param_dtype) / jnp.sqrt(h)
         )
+    if cfg.intermediate_pad:
+        # stored with zero columns beyond the width (cfg.intermediate_pad)
+        wide = [(0, 0), (0, 0), (0, cfg.intermediate_pad)]
+        for name in ("w_up", "w_gate"):
+            if name in p:
+                p[name] = jnp.pad(p[name], wide)
+        p["b_up"] = jnp.pad(p["b_up"], wide[1:])
+        p["w_down"] = jnp.pad(p["w_down"], [wide[0], wide[2], wide[1]])
     if cfg.router_bias and e_all > 1:
         # the selection bias is a buffer the balancing rule moves, not a
         # trained weight: float32, zero until a checkpoint sets it

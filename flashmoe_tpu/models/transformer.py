@@ -58,13 +58,15 @@ def init_params(key, cfg: MoEConfig) -> dict:
         "lm_head": dense(keys[1], (h, cfg.vocab_size), h),
         "layers": [],
     }
-    for li in range(cfg.num_layers):
+    for li, (mixer, ffn) in enumerate(cfg.layers):
         lk = jax.random.split(keys[2 + li], 6)
-        layer = {
-            "attn_norm": jnp.ones((h,), cfg.param_dtype),
-            "ffn_norm": jnp.ones((h,), cfg.param_dtype),
-        }
-        if cfg.mixers[li] == "kda":
+        # one norm for each part the layer has (``cfg.layers``)
+        layer = {name: jnp.ones((h,), cfg.param_dtype)
+                 for name, part in (("attn_norm", mixer), ("ffn_norm", ffn))
+                 if part is not None}
+        if mixer is None:
+            pass
+        elif mixer == "kda":
             ak = jax.random.split(lk[0], 7)
             n, d = cfg.kda_heads, cfg.kda_head_dim
             layer.update(
@@ -80,12 +82,31 @@ def init_params(key, cfg: MoEConfig) -> dict:
                 kda_wg=dense(ak[5], (h, n), h),
                 kda_norm=jnp.ones((d,), cfg.param_dtype),
                 wo=dense(ak[6], (n * d, h), n * d))
-        elif cfg.mixers[li] == "conv":
+        elif mixer == "conv":
             ak = jax.random.split(lk[0], 3)
             layer.update(
                 conv_win=dense(ak[0], (h, 3 * h), h),
                 conv_w=dense(ak[1], (cfg.conv_taps, h), cfg.conv_taps),
                 wo=dense(ak[2], (h, h), h))
+        elif mixer == "ssm":
+            ak = jax.random.split(lk[0], 5)
+            n, di, width = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_width
+            layer.update(
+                ssm_win=dense(ak[0], (h, di + width + n), h),
+                ssm_conv_w=dense(ak[1], (cfg.ssm_conv, width),
+                                 cfg.ssm_conv),
+                ssm_conv_b=jnp.zeros((width,), cfg.param_dtype),
+                # the step's bias, the decay's rate and the skip are not
+                # matrices: float32 whatever the weights' dtype (steps
+                # from softplus^-1 of 0.001 .. 0.1, rates A = -1 .. -16)
+                ssm_dt_bias=jnp.log(jnp.expm1(jnp.exp(jax.random.uniform(
+                    ak[2], (n,), jnp.float32, jnp.log(1e-3),
+                    jnp.log(1e-1))))),
+                ssm_A_log=jnp.log(jax.random.uniform(
+                    ak[3], (n,), jnp.float32, 1.0, 16.0)),
+                ssm_D=jnp.ones((n,), jnp.float32),
+                ssm_norm=jnp.ones((di,), cfg.param_dtype),
+                wo=dense(ak[4], (di, h), di))
         elif cfg.attention_kind == "mla":
             ak = jax.random.split(lk[0], 4)
             rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
@@ -112,7 +133,8 @@ def init_params(key, cfg: MoEConfig) -> dict:
             if cfg.qk_norm:
                 layer.update(q_norm=jnp.ones((dh,), cfg.param_dtype),
                              k_norm=jnp.ones((dh,), cfg.param_dtype))
-        layer["moe"] = init_moe_params(lk[4], cfg.ffn_config(li))
+        if ffn is not None:
+            layer["moe"] = init_moe_params(lk[4], cfg.ffn_config(li))
         params["layers"].append(layer)
     return params
 
@@ -125,8 +147,8 @@ def attention(layer, x, cfg: MoEConfig, positions=None, mesh=None,
               use_pallas=None, li: int = 0):
     """Layer ``li``'s token mixer over a whole sequence (no cache): causal
     self-attention with RoPE and GQA, latent attention, the delta rule in
-    its chunkwise form or the gated short convolution, by
-    ``cfg.mixers[li]``.  x: [B, T, H].
+    its chunkwise form, the gated short convolution or the state-space
+    mixer in its chunked form, by ``cfg.mixers[li]``.  x: [B, T, H].
 
     Backend selection of the K/V kind: ring attention over the ``sp``
     mesh axis for sequence-parallel configs, the flash Pallas kernel on
@@ -242,9 +264,11 @@ def _ffn(layer, x, cfg: MoEConfig, li: int, mesh, use_pallas):
 
 def block(layer, x, cfg: MoEConfig, li: int, mesh=None, use_pallas=None,
           chaos_sig=()):
-    """One pre-norm transformer block.  Returns (x, moe_losses,
-    moe_stats) — stats is the layer's MoEStats when ``cfg.collect_stats``
-    and this is an MoE layer, else None (an empty pytree leaf).
+    """One pre-norm transformer block: the parts ``cfg.layers[li]`` names
+    (a mixer, a feed-forward part, or both), each behind its own norm.
+    Returns (x, moe_losses, moe_stats) — stats is the layer's MoEStats
+    when ``cfg.collect_stats`` and this is an MoE layer, else None (an
+    empty pytree leaf).
 
     ``chaos_sig`` is the chaos-injection registry snapshot
     (:func:`flashmoe_tpu.chaos.inject.trace_signature`), unused in the
@@ -253,9 +277,13 @@ def block(layer, x, cfg: MoEConfig, li: int, mesh=None, use_pallas=None,
     re-armed injection point silently reuses the previous arming
     state's jaxpr whenever two builds share an equal config (the chaos
     drills rebuild their step exactly to pick up new arming)."""
-    a = attention(layer, rms_norm(x, layer["attn_norm"], cfg.norm_eps), cfg,
-                  mesh=mesh, use_pallas=use_pallas, li=li)
-    x = x + a
+    mixer, ffn = cfg.layers[li]
+    if mixer is not None:
+        x = x + attention(
+            layer, rms_norm(x, layer["attn_norm"], cfg.norm_eps), cfg,
+            mesh=mesh, use_pallas=use_pallas, li=li)
+    if ffn is None:
+        return x, jnp.zeros((), cfg.accum_dtype), None
     f, moe_loss, moe_stats = _ffn(
         layer, rms_norm(x, layer["ffn_norm"], cfg.norm_eps), cfg, li, mesh,
         use_pallas)

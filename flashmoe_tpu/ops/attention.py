@@ -24,6 +24,7 @@ from jax.experimental.pallas import tpu as pltpu
 from flashmoe_tpu.config import LANE, STATE_MIXERS
 from flashmoe_tpu.ops.conv import conv_attention
 from flashmoe_tpu.ops.kda import kda_attention
+from flashmoe_tpu.ops.ssm import ssm_attention
 from flashmoe_tpu.utils.telemetry import trace_span
 
 NEG_INF = -1e30
@@ -306,8 +307,9 @@ def rope_halves(q, k, positions, theta):
 
 def kv_project(layer, x, cfg, positions):
     """x: [B, T, H] normed -> (q [B, T, N, D], k and v [B, T, N_kv, D]),
-    q and k roped at ``positions`` [B, T]; under ``cfg.qk_norm`` every
-    head of q and of k goes through an RMSNorm over its width first."""
+    q and k roped at ``positions`` [B, T] (unless ``cfg.use_rope`` is
+    off); under ``cfg.qk_norm`` every head of q and of k goes through an
+    RMSNorm over its width first."""
     b, t, _ = x.shape
     nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
                    cfg.resolved_head_dim)
@@ -317,7 +319,8 @@ def kv_project(layer, x, cfg, positions):
     if cfg.qk_norm:
         q = rms_norm(q, layer["q_norm"], cfg.norm_eps)
         k = rms_norm(k, layer["k_norm"], cfg.norm_eps)
-    q, k = rope_halves(q, k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        q, k = rope_halves(q, k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -433,7 +436,8 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
 #: *its per-slot arrays or Nones, its index among the state layers, valid,
 #: slots, fresh)`` and returns ``(out, *the arrays, the rows' final state
 #: as a tuple)``
-_STATE_MIXERS = {"kda": kda_attention, "conv": conv_attention}
+_STATE_MIXERS = {"kda": kda_attention, "conv": conv_attention,
+                 "ssm": ssm_attention}
 
 
 def paged_attention(layer, x, cfg, pools, li, pos, write, block_tables, *,

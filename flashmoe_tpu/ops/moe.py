@@ -211,10 +211,18 @@ def rows_block_m(cfg: MoEConfig, s: int) -> int:
 
 def routed_rows_form(cfg: MoEConfig) -> str:
     """The form :func:`routed_rows_ffn` computes in: ``"routed_kernel"``
-    (the grouped Pallas kernel) on a TPU when the widths tile (H and I
-    whole lanes: a weight block is whole tiles), ``"routed_rows"`` (plain
-    ``ragged_dot``) everywhere else."""
-    lanes = cfg.hidden_size % 128 == 0 and cfg.intermediate_size % 128 == 0
+    (the grouped Pallas kernel) on a TPU when the widths tile (H and the
+    intermediate width AS STORED, ``intermediate_size +
+    intermediate_pad``, whole lanes: a weight block is whole tiles),
+    ``"routed_rows"`` (plain ``ragged_dot``) everywhere else.  Why the
+    stored width and not a block of the array's own last dimension, which
+    Mosaic compiles at 1856 = 14.5 lanes: the chip keeps an ``[E, H, I]``
+    array whose I is no whole lanes H-minor, and copied all of it into
+    row-major order before EVERY launch (0.64 GB a layer, PERF.md section
+    6, PR 39); a config with such a width stores zero columns beside it
+    (``MoEConfig.intermediate_pad``)."""
+    stored = cfg.intermediate_size + cfg.intermediate_pad
+    lanes = cfg.hidden_size % 128 == 0 and stored % 128 == 0
     return ("routed_kernel" if lanes and jax.default_backend() == "tpu"
             else "routed_rows")
 

@@ -111,22 +111,24 @@ def transformer_param_specs(cfg: MoEConfig) -> dict:
     the vocab; MoE experts shard over ep.
     """
     tp_ax = "tp" if cfg.tp > 1 else None
-    layer = {
+    mixer_specs = {
         "attn_norm": P(None),
-        "ffn_norm": P(None),
         "wq": P(None, tp_ax),
         "wk": P(None, tp_ax),
         "wv": P(None, tp_ax),
         "wo": P(tp_ax, None),
-        "moe": moe_param_specs(cfg),
     }
-    dense_moe = moe_param_specs(
-        cfg.replace(num_experts=1, expert_top_k=1, num_shared_experts=0, ep=1)
-    )
-    moe_set = set(cfg.moe_layer_indices)
+    ffn_specs = {
+        "moe": moe_param_specs(cfg),
+        "dense": moe_param_specs(cfg.replace(
+            num_experts=1, expert_top_k=1, num_shared_experts=0, ep=1)),
+    }
+    # a layer holds the parts ``cfg.layers`` names
     layers = [
-        {**layer, "moe": layer["moe"] if li in moe_set else dense_moe}
-        for li in range(cfg.num_layers)
+        {**(mixer_specs if mixer is not None else {}),
+         **({"ffn_norm": P(None), "moe": ffn_specs[ffn]}
+            if ffn is not None else {})}
+        for mixer, ffn in cfg.layers
     ]
     return {
         "embed": P(None, None),

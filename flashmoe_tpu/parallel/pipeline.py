@@ -52,11 +52,10 @@ def stack_stage_params(params, cfg: MoEConfig, pp: int, interleave: int = 1):
             f"num_layers {cfg.num_layers} not divisible by "
             f"pp*interleave={pp * v}")
     lpc = cfg.num_layers // (pp * v)
-    moe_set = set(cfg.moe_layer_indices)
-    uniform = all(i in moe_set for i in range(cfg.num_layers)) or not moe_set
-    if not uniform:
+    if len(set(cfg.layers)) > 1 or None in cfg.layers[0]:
         raise ValueError(
-            "pipeline stages need a uniform layer pattern "
+            "pipeline stages need a uniform layer pattern: every layer "
+            "the same mixer and the same feed-forward part "
             "(moe_frequency=1 or num_experts=1)"
         )
     layers = params["layers"]

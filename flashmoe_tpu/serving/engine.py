@@ -860,6 +860,9 @@ class ServingEngine:
         # bytes one cached token costs over all layers (a gauge, and on
         # every serve_step record): what the pool's pages are made of
         self.metrics.gauge("serve.kv_token_bytes", cfg.kv_pool_token_bytes)
+        # bytes a slot's state costs over the state layers, whatever its
+        # context (0: every layer caches rows a token)
+        self.metrics.gauge("serve.state_slot_bytes", cfg.state_slot_bytes)
         self.queue: deque = deque()       # (arrival_step, _Slot-seed)
         self.slots: list[_Slot | None] = [None] * self.serve.max_batch
         self._logits = jnp.zeros(
@@ -2120,6 +2123,10 @@ class ServingEngine:
                 # ran it: a mean over these is a mean over decode steps
                 more = {}
                 if self.cfg.state_layers:
+                    # the program streams EVERY row's state through the
+                    # step, live or not: beside ``slots``, the rows that
+                    # got a token
+                    more["state_rows"] = sv.max_batch
                     more["state_bytes"] = (2 * sv.max_batch
                                            * self.cfg.state_slot_bytes)
                 if counted is not None:

@@ -364,10 +364,12 @@ STRUCTURAL_FIELDS = frozenset({
     "qk_rope_head_dim", "v_head_dim",
     "router_score", "router_bias", "norm_topk_prob",
     "routed_scaling_factor", "first_k_dense", "dense_intermediate_size",
-    "n_group", "topk_group", "layer_mixers",
+    "n_group", "topk_group", "layer_mixers", "layer_ffns",
     "kda_heads", "kda_head_dim", "kda_conv", "kda_lower_bound",
-    "conv_taps", "qk_norm", "norm_eps",
-    "expert_first", "experts_held",
+    "conv_taps", "qk_norm", "use_rope", "norm_eps",
+    "ssm_heads", "ssm_head_dim", "ssm_groups", "ssm_state", "ssm_conv",
+    "ssm_chunk",
+    "expert_first", "experts_held", "intermediate_pad",
     "dtype", "param_dtype", "accum_dtype",
     "dp", "ep", "tp", "sp", "pp",
 })

@@ -255,6 +255,13 @@ SPAN_NAMES: dict[str, str] = {
     "attn.conv_decode":
         "gated short convolution, one step over the slots' carried "
         "inputs (decode)",
+    "attn.ssm_prefill":
+        "state-space mixer, chunked form: masked products with the "
+        "cumulative decay inside chunks of ``ssm_chunk`` tokens and a scan "
+        "that carries the state between them (prefill, chunked prefill)",
+    "attn.ssm_decode":
+        "state-space mixer, one recurrence step over every slot's float32 "
+        "state, read once and written once (decode)",
     "moe.route_groups":
         "group-limited routing: the groups' scores and the mask of the "
         "experts outside the kept groups",

@@ -315,9 +315,12 @@ def test_the_expert_arm_is_one_rule_over_what_the_arms_hold(monkeypatch,
     # float32 activations: the same bytes at half the rows
     assert arms(lfm.replace(dtype=jnp.float32), (256, 384)) == [short,
                                                                 routed]
-    # widths that are no whole lanes keep the plain forms on a TPU too
+    # widths that are no whole lanes keep the plain forms on a TPU too,
+    # unless the experts are STORED at whole lanes (ISSUE 39)
     odd = ds.replace(intermediate_size=1472)
     assert arms(odd, (32, 2048)) == ["capacity", "routed_rows"]
+    assert arms(odd.replace(intermediate_pad=64), (32, 2048)) == [short,
+                                                                  routed]
 
 
 def test_both_expert_arms_compute_the_references_layer(params):

@@ -164,8 +164,8 @@ def test_the_kernel_form_at_the_served_widths(monkeypatch, name, s):
 ])
 def test_the_form_follows_the_widths_and_the_backend(monkeypatch, backend, h,
                                                      i, form):
-    """The kernel on a TPU when H and I are whole lanes, ``ragged_dot``
-    everywhere else; ``expert_arm`` names the form where it takes the
+    """The kernel on a TPU when H and I (as stored) are whole lanes,
+    ``ragged_dot`` everywhere else; ``expert_arm`` names the form where it takes the
     routed rows and says ``capacity`` as before where it does not."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     cfg = _cfg(e=256, k=8, h=h, i=i)
@@ -174,6 +174,11 @@ def test_the_form_follows_the_widths_and_the_backend(monkeypatch, backend, h,
     assert moe.expert_arm(cfg.replace(experts_held=64), 8) == form
     assert moe.expert_arm(cfg.replace(num_experts=1, expert_top_k=1),
                           1024) == "capacity"
+    # stored with zero columns to whole lanes (ISSUE 39), the odd width
+    # takes the kernel too
+    if (backend, i) == ("tpu", 960):
+        assert moe.routed_rows_form(
+            cfg.replace(intermediate_pad=64)) == "routed_kernel"
 
 
 @pytest.mark.parametrize("s,e,k,dtype,block", [

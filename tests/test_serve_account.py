@@ -632,8 +632,9 @@ def test_a_profiled_run_puts_records_and_trace_on_one_clock(
         params, tmp_path, capsys):
     """The engine under ``jax.profiler`` on the CPU (no device plane: no
     gaps to list): every ``serve.step`` event carries its step as a stat
-    under the name it had, and starts within a millisecond of its record's
-    ``t0_trace_ns``; the command line finds the trace and the records."""
+    under the name it had, and starts where its record's ``t0_trace_ns``
+    says, on the same clock; the command line finds the trace and the
+    records."""
     from flashmoe_tpu import observe
 
     rec = FlightRecorder()
@@ -657,7 +658,17 @@ def test_a_profiled_run_puts_records_and_trace_on_one_clock(
     rep = observe.gaps_report(trace, rec.records)
     assert rep["steps"] == sum(r["kind"] == "serve_step"
                                for r in rec.records) > len(recs)
-    assert 0.0 < rep["clock_skew_ms"] < 1.0
+    # ONE clock, not a tight one: ``t0_trace_ns`` is ``time.time_ns()`` read
+    # a few Python statements before the ``serve.step`` annotation opens,
+    # 0.10-0.14 ms apart on the chip's host (PERF.md section 6, PR 35) and
+    # under a millisecond here when the worker has a core to itself.  The
+    # driver runs six workers beside the profiler's own threads on eight
+    # cores, and a worker descheduled between the two reads waits a
+    # scheduler quantum or several (it read 1-3 ms there and failed a 1 ms
+    # bound).  Two clocks that are NOT one (the epoch's against a monotonic
+    # one) lie some 1.7e12 ms apart, so 250 ms of room still proves the
+    # point the test makes
+    assert 0.0 < rep["clock_skew_ms"] < 250.0
     path = tmp_path / "flight.jsonl"
     path.write_text("".join(json.dumps(r) + "\n" for r in rec.records))
     assert observe.main(["--gaps", str(tmp_path / "trace"), str(path)]) == 2
