@@ -10,6 +10,7 @@ decoded one at a time through ``generate()``.
 import functools
 import json
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +26,8 @@ from flashmoe_tpu.serving.engine import (
 )
 from flashmoe_tpu.ops.attention import gather_ctx, store_kv
 from flashmoe_tpu.serving.kvcache import (
-    SCRATCH_PAGE, PagePool, ctx_pages_bucket, init_paged_cache,
-    prompt_pad, store_prefill,
+    SCRATCH_PAGE, PagePool, ShardedPagePool, ctx_pages_bucket,
+    init_paged_cache, prompt_pad, store_prefill,
 )
 from flashmoe_tpu.serving.loadgen import (
     build_requests, serve_load_sweep, tiny_config,
@@ -77,6 +78,161 @@ def test_page_pool_lifo_reuse_and_errors():
         pool.free(b + b)
     with pytest.raises(ValueError, match="out of range"):
         pool.free([SCRATCH_PAGE])
+
+
+class _ListPool:
+    """The allocator as it stood before the state-by-id array: the LIFO
+    list alone, the double-free check a scan of it.  The plain reference
+    the pools are held to, call for call."""
+
+    def __init__(self, num_pages):
+        self.num_pages = num_pages
+        self._free = list(range(num_pages - 1, 0, -1))
+
+    def alloc(self, n):
+        if n > len(self._free):
+            return None
+        return [self._free.pop() for _ in range(n)]
+
+    def free(self, pages):
+        for p in reversed(list(pages)):
+            if not 0 < p < self.num_pages:
+                raise ValueError(f"page id {p} out of range")
+            if p in self._free:
+                raise ValueError(f"double free of page {p}")
+            self._free.append(p)
+
+
+def _call(fn, *args):
+    """What a call gave: its value, or the error it raised."""
+    try:
+        return fn(*args)
+    except ValueError as e:
+        return f"ValueError: {e}"
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("shards", [None, 2, 4])
+def test_page_pools_equal_the_list_only_allocator(shards, seed):
+    """Seeded random alloc / free sequences (whole requests, the tails a
+    speculative roll-back frees, frees that fill the pool, every kind of
+    bad free): the pool and the list-only reference hand out the same
+    ids in the same order and raise the same errors on the same calls."""
+    per = 48
+    if shards is None:
+        pool, refs = PagePool(per), [_ListPool(per)]
+        on = lambda shard: ()
+    else:
+        pool = ShardedPagePool(per * shards, shards)
+        refs = [_ListPool(per) for _ in range(shards)]
+        on = lambda shard: (shard,)
+    rng = np.random.default_rng(seed)
+    held = []                                   # (shard, pages) in flight
+    gone = []                                   # freed once already
+    raised = set()
+
+    def both(op, shard, arg):
+        got = _call(getattr(pool, op), arg, *on(shard))
+        want = _call(getattr(refs[shard], op), arg)
+        assert got == want, (op, shard, arg)
+        if isinstance(got, str):
+            raised.add(got.split(" ")[1])       # "double" / "page"
+        assert pool.free_pages == sum(len(r._free) for r in refs)
+        assert pool.used_pages == len(refs) * (per - 1) - pool.free_pages
+        return got
+
+    for _ in range(600):
+        shard = int(rng.integers(len(refs)))
+        kind = rng.choice(["alloc", "alloc", "free", "tail", "drain",
+                           "bad"], p=[0.3, 0.2, 0.2, 0.15, 0.05, 0.1])
+        mine = [k for k, (sh, _) in enumerate(held) if sh == shard]
+        if kind == "alloc":
+            # now and then more than remain: None, and nothing changes
+            n = int(rng.integers(0, 14)) if rng.random() < 0.9 else per
+            short = n > len(refs[shard]._free)
+            pages = both("alloc", shard, n)
+            assert (pages is None) == short
+            if pages:
+                held.append((shard, pages))
+        elif kind == "free" and mine:
+            sh, pages = held.pop(mine[int(rng.integers(len(mine)))])
+            assert both("free", sh, pages) is None
+            gone.append((sh, pages))
+        elif kind == "tail" and mine:
+            sh, pages = held[mine[int(rng.integers(len(mine)))]]
+            keep = int(rng.integers(1, len(pages) + 1))
+            assert both("free", sh, pages[keep:]) is None
+            del pages[keep:]
+        elif kind == "drain":
+            for k in reversed(mine):
+                sh, pages = held.pop(k)
+                assert both("free", sh, pages) is None
+                gone.append((sh, pages))
+            assert pool.occupancy == pytest.approx(
+                sum(len(pg) for _, pg in held)
+                / (len(refs) * (per - 1)))
+        elif kind == "bad":
+            live = [held[k][1] for k in mine]
+            stale = [pg for sh, pg in gone if sh == shard
+                     and not set(pg) & {p for l in live for p in l}
+                     and set(pg) <= set(refs[shard]._free)]
+            case = int(rng.integers(4))
+            if case == 0 and stale:             # freed by an earlier call
+                both("free", shard, stale[-1])
+            elif case == 1 and live:            # an id twice in ONE call:
+                sh, pages = held.pop(mine[0])   # all of it goes back, then
+                both("free", shard, pages + pages[:1])      # the error
+                gone.append((sh, pages))
+            elif case == 2:                     # the scratch page
+                both("free", shard, (live[0] if live else []) + [0])
+            elif live:                          # one past the last id,
+                sh, pages = held.pop(mine[0])   # after the good ones
+                both("free", shard, [per] + pages)
+                gone.append((sh, pages))
+    assert raised == {"double", "page"}
+    # what is left comes out in the same order, to the last page
+    for shard, ref in enumerate(refs):
+        n = len(ref._free)
+        assert both("alloc", shard, n + 1) is None
+        assert len(both("alloc", shard, n)) == n
+
+
+class _CountingList(list):
+    """A free list that counts what would scan it."""
+    scans = 0
+
+    def _scan(name):
+        def method(self, *args):
+            type(self).scans += 1
+            return getattr(list, name)(self, *args)
+        return method
+
+    __contains__, index, count = (_scan("__contains__"), _scan("index"),
+                                  _scan("count"))
+    __iter__, remove = _scan("__iter__"), _scan("remove")
+
+
+def test_free_costs_the_requests_pages_not_the_pools():
+    """A retirement at the longgen cell's size (640 pages into a pool of
+    40 960 with 33 000 free): no scan of the free list, and far under
+    the 250 ms the list's own membership test took."""
+    pool = PagePool(40_960)
+    held = [pool.alloc(640) for _ in range(12)]
+    rest = pool.alloc(pool.free_pages - 33_000 + 640)
+    pool._free = _CountingList(pool._free)
+    _CountingList.scans = 0
+    best = float("inf")
+    for pages in held:
+        assert pool.free_pages == 33_000 - 640
+        t0 = time.perf_counter()
+        pool.free(pages)
+        best = min(best, time.perf_counter() - t0)
+        assert pool.alloc(640) == pages         # LIFO, as ever
+    assert _CountingList.scans == 0
+    assert best < 0.020
+    with pytest.raises(ValueError, match="double free"):
+        pool.free(rest[:1] + held[0][:1] + rest[:1])
+    assert _CountingList.scans == 0
 
 
 def test_ctx_bucketing():
@@ -1041,6 +1197,120 @@ def spec_prompts():
                                 CFG.vocab_size)
     return jnp.asarray([[int(motifs[i][j % 2]) for j in range(8)]
                         for i in range(8)])
+
+
+# what the commit before the state-by-id array served (d6ace1a)
+PARENT_DRILL = {
+    "plain": {
+        "out": {0: [173, 29, 29, 29, 29, 29, 29, 29, 29],
+                1: [25, 135, 26, 135, 5, 40, 135, 5, 61, 122, 122, 122, 216,
+                    26],
+                2: [135, 96, 61, 139],
+                3: [4, 182, 103, 72, 87, 72, 56, 177, 163, 177, 163],
+                4: [209, 63, 140, 135, 63, 209],
+                5: [187, 26, 27, 27, 139, 206, 139, 206, 26, 26, 26, 119,
+                    119],
+                6: [134, 96, 221, 221, 221],
+                7: [206, 110, 110, 110, 110, 110, 96, 110, 96, 110]},
+        "used": [8, 8, 8, 6, 9, 9, 9, 10, 6, 9, 10, 11, 11, 7, 3, 7, 7, 8,
+                 9, 7, 7, 8, 9, 9, 5, 5, 0],
+        "tables": [[[1, 2, 7], [3, 4], [5, 6, 8]],
+                   [[1, 2, 7], [3, 4], [5, 6, 8]],
+                   [[1, 2, 7], [3, 4], [5, 6, 8]],
+                   [[1, 2, 7], [3, 4, 9], None],
+                   [[1, 2, 7, 8], [3, 4, 9], [5, 6]],
+                   [[1, 2, 7, 8], [3, 4, 9], [5, 6]],
+                   [[1, 2, 7, 8], [3, 4, 9], [5, 6]],
+                   [[1, 2, 7, 8], [3, 4, 9, 10], [5, 6]],
+                   [None, [3, 4, 9, 10], [5, 6]],
+                   [[1, 2], [3, 4, 9, 10], [5, 6, 7]],
+                   [[1, 2, 8], [3, 4, 9, 10], [5, 6, 7]],
+                   [[1, 2, 8], [3, 4, 9, 10, 11], [5, 6, 7]],
+                   [[1, 2, 8], [3, 4, 9, 10, 11], [5, 6, 7]],
+                   [[1, 2, 8], None, [5, 6, 7, 12]],
+                   [None, [3, 4, 9], None], [[5, 6], [3, 4, 9], [7, 12]],
+                   [[5, 6], [3, 4, 9], [7, 12]],
+                   [[5, 6], [3, 4, 9], [7, 12, 1]],
+                   [[5, 6], [3, 4, 9, 2], [7, 12, 1]],
+                   [None, [3, 4, 9, 2], [7, 12, 1]],
+                   [None, [3, 4, 9, 2], [7, 12, 1]],
+                   [None, [3, 4, 9, 2], [7, 12, 1, 5]],
+                   [None, [3, 4, 9, 2, 6], [7, 12, 1, 5]],
+                   [None, [3, 4, 9, 2, 6], [7, 12, 1, 5]],
+                   [None, [3, 4, 9, 2, 6], None],
+                   [None, [3, 4, 9, 2, 6], None], [None, None, None]],
+    },
+    "speculate": {
+        "out": {0: [182, 253, 96, 182, 134, 96, 96, 182, 134],
+                1: [206, 206, 206, 206, 206, 108, 104, 206, 110, 110, 110,
+                    110, 110, 110],
+                2: [154, 154, 154, 154],
+                3: [177, 222, 212, 218, 212, 75, 75, 75, 203, 178, 203],
+                4: [16, 3, 3, 3, 3, 3],
+                5: [198, 198, 198, 198, 198, 132, 132, 132, 132, 132, 132,
+                    163, 109],
+                6: [89, 89, 89, 89, 89],
+                7: [16, 3, 16, 3, 3, 16, 3, 3, 3, 3]},
+        "used": [8, 8, 6, 7, 9, 9, 10, 6, 9, 11, 6, 9, 7, 5, 8, 8, 7, 8, 8,
+                 9, 0],
+        "tables": [[[1, 2, 7], [3, 4], [5, 6, 8]],
+                   [[1, 2, 7], [3, 4], [5, 6, 8]],
+                   [[1, 2, 7], [3, 4, 10], None],
+                   [[1, 2, 7], [3, 4, 10], [5]],
+                   [[1, 2, 7, 6], [3, 4, 10], [5, 11]],
+                   [[1, 2, 7, 6], [3, 4, 10], [5, 11]],
+                   [[1, 2, 7, 6], [3, 4, 10, 8], [5, 11]],
+                   [None, [3, 4, 10, 8], [5, 11]],
+                   [[13, 12], [3, 4, 10, 8], [5, 11, 1]],
+                   [[13, 12, 2], [3, 4, 10, 8, 7], [5, 11, 1]],
+                   [[13, 12, 2], None, [5, 11, 1]],
+                   [[13, 12, 2], [14, 3, 4], [5, 11, 1]],
+                   [None, [14, 3, 4], [5, 11, 1, 13]],
+                   [[12, 2], [14, 3, 4], None],
+                   [[12, 2], [14, 3, 4, 1], [5, 11]],
+                   [[12, 2], [14, 3, 4, 1], [5, 11]],
+                   [None, [14, 3, 4, 1], [5, 11, 12]],
+                   [None, [14, 3, 4, 1, 2], [5, 11, 12]],
+                   [None, [14, 3, 4, 1, 2], [5, 11, 12]],
+                   [None, [14, 3, 4, 1, 2], [5, 11, 12, 10]],
+                   [None, None, None]],
+    },
+}
+
+
+@pytest.mark.parametrize("arm", ["plain", "speculate"])
+def test_large_pool_drill_places_pages_as_the_parent_did(params, prompts,
+                                                         spec_prompts, arm):
+    """The allocator's order is observable and did not move: a seeded
+    drill over a 4096-page pool (slots reused, requests of several
+    pages, and with speculation armed a roll-back's tail freed every
+    verify step) serves the parent's tokens from the parent's pages:
+    every slot's block table after every step, and the ``pages_used``
+    of every ``serve_step`` record."""
+    want = PARENT_DRILL[arm]
+    lens = [(8, 9), (5, 14), (8, 4), (3, 11), (7, 6), (8, 13), (4, 5),
+            (6, 10)]
+    toks = (spec_prompts if arm == "speculate" else prompts).tolist()
+    recorder = FlightRecorder()
+    engine = ServingEngine(
+        params, CFG,
+        _spec_serve(3 if arm == "speculate" else None, max_batch=3,
+                    page_size=4, num_pages=4096, max_pages_per_slot=6),
+        recorder=recorder)
+    for r, ((t0, n), at) in enumerate(zip(lens, [0, 0, 0, 1, 1, 2, 4, 4])):
+        engine.submit(Request(rid=r, prompt=tuple(toks[r][:t0]),
+                              max_new_tokens=n), at)
+    tables = []
+    while engine.pending():
+        engine.step()
+        tables.append([None if s is None else list(s.pages)
+                       for s in engine.slots])
+    assert tables == want["tables"]
+    assert [r["pages_used"] for r in recorder.records
+            if r.get("kind") == "serve_step"] == want["used"]
+    assert {r: engine.outputs[r][-n:]
+            for r, (_, n) in enumerate(lens)} == want["out"]
+    assert engine.pool.free_pages == 4095
 
 
 def test_speculative_decode_bit_equal_greedy(params, spec_prompts):
