@@ -521,7 +521,8 @@ def phase_serve_hybrid(seed):
     Then the other pairing: LFM2-24B-A2B's widths, per-slot convolution
     inputs beside a K/V pool of 64-wide heads; then NVIDIA-Nemotron-3-Nano-
     30B-A3B's: a state-space state beside a two-head K/V pool, every
-    layer a mixer or a mixture alone."""
+    layer a mixer or a mixture alone; then LongCat-Flash-Omni's: a mixture
+    joined a sublayer after it is read, identity experts."""
     import jax
     import jax.numpy as jnp
 
@@ -595,6 +596,32 @@ def phase_serve_hybrid(seed):
     cut = ("NVIDIA-Nemotron-3-Nano-30B-A3B, num_layers 52 -> 4 (MEM*), "
            "experts 128 -> 64 held (routed over 128), vocab 131072 -> "
            "65536: f32 weights "
+           f"{sum(a.nbytes for a in jax.tree.leaves(params)) / 2**30:.2f} "
+           "GiB")
+    ok &= _serve_case(params, cfg.replace(dtype=jnp.float32), serve, seed,
+                      cut + "; dtype bf16 -> f32, matmul precision highest",
+                      {"phase_of": "serve_hybrid"})
+    gc.collect()
+    ok &= _serve_case(params, cfg, serve, seed, cut,
+                      {"phase_of": "serve_hybrid"})
+    del params
+    gc.collect()
+    # a changed residual path and experts that compute nothing:
+    # LongCat-Flash-Omni's widths, ONE published layer = two latent
+    # sublayers of 64 heads with both rank scales, two dense FFNs of 12288
+    # and one mixture read at the first and joined after the second, a
+    # 768-wide softmax router of which 256 outputs are identity experts,
+    # 16 of 512 FFN experts held, an eighth of the vocabulary: a latent
+    # pool of TWO layers, ``fm_latent_decode`` at a [64, 640] query a slot
+    # against the gather arm, the routed rows through ``fm_ffn_fwd`` in
+    # windows of the plan (``ops/moe.rows_plan``)
+    cfg = PRESETS["longcat-flash"](num_layers=2, experts_held=16,
+                                   vocab_size=16384)
+    params = init_params(jax.random.PRNGKey(seed), cfg)
+    cut = ("LongCat-Flash-Omni, num_layers 28 -> 1 published (2 "
+           "sublayers: 'dense+moe', 'dense+join'), FFN experts 512 -> 16 "
+           "held (routed over 768 outputs, 256 of them identity), vocab "
+           "131072 -> 16384: f32 weights "
            f"{sum(a.nbytes for a in jax.tree.leaves(params)) / 2**30:.2f} "
            "GiB")
     ok &= _serve_case(params, cfg.replace(dtype=jnp.float32), serve, seed,

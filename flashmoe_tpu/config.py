@@ -38,6 +38,15 @@ LANE = 128
 #: token (``MoEConfig.slot_state`` says what each keeps)
 STATE_MIXERS = ("kda", "conv", "ssm")
 
+#: what a layer's feed-forward name (``MoEConfig.layer_ffns``) says:
+#: ``(the part whose output joins the residual stream where it is
+#: computed, the mixture branch)``; the branch is "moe" (a mixture reads
+#: the part's normed input here and its output is carried along), "join"
+#: (the carried output joins after this part's) or None
+FFN_PARTS = {None: (None, None), "moe": ("moe", None),
+             "dense": ("dense", None), "dense+moe": ("dense", "moe"),
+             "dense+join": ("dense", "join")}
+
 
 class Activation:
     """Activation selector, mirroring ``hidden_act`` (0=relu / 1=gelu) in
@@ -167,6 +176,15 @@ class MoEConfig:
     # the feed-forward part PER LAYER: "moe", "dense" or None (a layer
     # that is its mixer alone, ``x + mixer(norm(x))``, with ONE norm).
     # Empty: what moe_frequency and first_k_dense say, every layer one.
+    # Two more spell a mixture whose output joins the residual stream
+    # LATER than it is read (a shortcut-connected mixture): "dense+moe" is
+    # a dense FFN whose output joins now AND a mixture over the same
+    # normed input whose output is carried along (the layer holds both:
+    # ``moe`` the dense part, ``branch`` the mixture); "dense+join" is a
+    # dense FFN after whose output the carried one joins:
+    # ``x + dense(norm(x)) + carried``.  The layers between the two do not
+    # see the mixture's output.  One branch is open at a time and every
+    # one joins (:data:`FFN_PARTS` splits the names).
     layer_ffns: tuple = ()
     kda_heads: int = 0
     kda_head_dim: int = 0
@@ -194,6 +212,19 @@ class MoEConfig:
     # routed to its own experts and leaves the others' part out.
     expert_first: int = 0
     experts_held: int = 0
+    # zero-compute experts: the router of a mixture layer is num_experts +
+    # zero_experts wide (``gate_w``, ``gate_bias`` and the counts too) and
+    # a chosen output e >= num_experts is the IDENTITY: it adds its weight
+    # times the layer's input and runs no FFN (the expert weights stay
+    # num_experts; expert_top_k may be up to the router's width).  Nobody
+    # holds them: a chip with a share of the experts (experts_held counts
+    # FFN experts only) computes them for every one of its own tokens.
+    zero_experts: int = 0
+    # an "mla" layer multiplies its normed query latent by
+    # sqrt(hidden_size / q_lora_rank) and its normed key/value latent
+    # (not the shared rotary key) by sqrt(hidden_size / kv_lora_rank):
+    # ranks that are a fraction of the width keep the scale of the width
+    mla_rank_scale: bool = False
     # columns of ZEROS the routed experts' matrices are STORED with beyond
     # intermediate_size (w_up / w_gate / b_up columns, w_down rows; 0:
     # none).  Every activation here maps 0 to 0, so the layer's result is
@@ -401,8 +432,11 @@ class MoEConfig:
     def __post_init__(self):
         if self.num_experts < 1:
             raise ValueError("num_experts must be >= 1")
-        if not (1 <= self.expert_top_k <= self.num_experts):
-            raise ValueError("expert_top_k must be in [1, num_experts]")
+        if self.zero_experts < 0:
+            raise ValueError("zero_experts must be >= 0")
+        if not (1 <= self.expert_top_k <= self.router_width):
+            raise ValueError("expert_top_k must be in [1, num_experts + "
+                             "zero_experts]")
         # schema.json:34-63: hidden/intermediate multipleOf 64, seq multipleOf 128.
         if self.hidden_size % 64:
             raise ValueError("hidden_size must be a multiple of 64")
@@ -446,11 +480,27 @@ class MoEConfig:
             if named and len(named) != self.num_layers:
                 raise ValueError(f"{name} names {len(named)} layers of "
                                  f"{self.num_layers}")
-        if set(self.layer_ffns) - {"moe", "dense", None}:
+        if set(self.layer_ffns) - set(FFN_PARTS):
             raise ValueError(f"layer_ffns {self.layer_ffns} not of "
-                             f"('moe', 'dense', None)")
-        if "moe" in self.layer_ffns and self.num_experts < 2:
+                             f"{tuple(FFN_PARTS)}")
+        if self.moe_layer_indices and self.num_experts < 2:
             raise ValueError("a 'moe' layer needs num_experts >= 2")
+        carried = None
+        for li, (_, ffn) in enumerate(self.layers):
+            _, branch = FFN_PARTS[ffn]
+            if branch == "moe" and carried is not None:
+                raise ValueError(
+                    f"layer {li} reads a mixture branch while layer "
+                    f"{carried}'s has not joined: one is open at a time")
+            if branch == "join" and carried is None:
+                raise ValueError(
+                    f"layer {li} joins a mixture branch and none is open "
+                    f"(a branch joins once, after the layer that read it)")
+            carried = {"moe": li, "join": None}.get(branch, carried)
+        if carried is not None:
+            raise ValueError(
+                f"the mixture branch layer {carried} reads never joins: a "
+                f"later layer's feed-forward part must be 'dense+join'")
         if (None, None) in self.layers:
             raise ValueError(
                 f"layer {self.layers.index((None, None))} has neither a "
@@ -529,6 +579,25 @@ class MoEConfig:
         if self.experts_held and self.ep > 1:
             raise ValueError("experts_held is one chip's share of a "
                              "layer: it does not compose with ep > 1")
+        if self.zero_experts:
+            if self.ep > 1:
+                raise NotImplementedError(
+                    "zero_experts with ep > 1: the expert-parallel layers "
+                    "(parallel/ep.py, ragged_ep.py, fused.py) route over "
+                    "num_experts outputs and exchange every routed row")
+            if self.is_training:
+                raise NotImplementedError(
+                    "training through zero-compute experts: no test holds "
+                    "the identity term's gradient; a serving config "
+                    "(is_training=False) runs it")
+            if (self.drop_tokens or self.n_group > 1 or self.expert_replicas
+                    or self.degrade_unhealthy_experts or self.collect_stats):
+                raise ValueError(
+                    "zero_experts are computed over the routed rows of a "
+                    "dropless config (drop_tokens=False) with one routing "
+                    "group: the capacity arm, group-limited routing, "
+                    "expert_replicas, degrade_unhealthy_experts and "
+                    "collect_stats index num_experts outputs")
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"router_score {self.router_score!r} not in "
                              f"('softmax', 'sigmoid')")
@@ -737,11 +806,13 @@ class MoEConfig:
     def layers(self) -> tuple:
         """THE description of every layer, ``(mixer, ffn)``: the token
         mixer ("mha", "mla" or one of ``STATE_MIXERS``) and the
-        feed-forward part ("moe" or "dense"), either of which may be None:
-        a layer is ``x + mixer(norm(x))``, then ``x + ffn(norm(x))``, with
-        one norm for each part it has.  Everything that asks what a layer
-        is (the parameter tree, the layer loops, the cache's layout, the
-        counts) reads this."""
+        feed-forward part (a name of :data:`FFN_PARTS`), either of which
+        may be None: a layer is ``x + mixer(norm(x))``, then
+        ``x + ffn(norm(x))``, with one norm for each part it has; a
+        feed-forward name may also say that a mixture reads the part's
+        normed input and joins after a LATER layer's part (``layer_ffns``).
+        Everything that asks what a layer is (the parameter tree, the
+        layer loops, the cache's layout, the counts) reads this."""
         mixers = self.layer_mixers or (self.attention_kind,) * self.num_layers
         ffns = self.layer_ffns
         if not ffns:
@@ -753,21 +824,35 @@ class MoEConfig:
         return tuple(zip(mixers, ffns))
 
     @property
-    def moe_layer_indices(self) -> tuple[int, ...]:
-        """Which transformer layers carry an MoE FFN (vs dense or none)."""
-        return tuple(li for li, (_, ffn) in enumerate(self.layers)
-                     if ffn == "moe")
+    def router_width(self) -> int:
+        """Outputs of a mixture layer's router: the FFN experts and the
+        zero-compute ones behind them."""
+        return self.num_experts + self.zero_experts
 
-    def ffn_config(self, li: int) -> "MoEConfig":
-        """The config layer ``li``'s feed-forward runs under: this one
-        for a mixture layer, else one dense expert (no router, no shared
-        experts) of the dense width."""
-        if self.layers[li][1] == "moe":
+    @property
+    def moe_layer_indices(self) -> tuple[int, ...]:
+        """Which transformer layers carry an MoE FFN (vs dense or none),
+        as their feed-forward part or as the branch read there."""
+        return tuple(li for li, (_, ffn) in enumerate(self.layers)
+                     if "moe" in FFN_PARTS[ffn])
+
+    def ffn_config(self, li: int, branch: bool = False) -> "MoEConfig":
+        """The config layer ``li``'s feed-forward part runs under (with
+        ``branch``: the mixture branch read there): this one for a
+        mixture, else one dense expert (no router, no shared experts) of
+        the dense width."""
+        if FFN_PARTS[self.layers[li][1]][branch] == "moe":
             return self
+        return self.dense_config
+
+    @functools.cached_property
+    def dense_config(self) -> "MoEConfig":
+        """The config a dense feed-forward part runs under: one expert of
+        the dense width, no router, nothing of the mixture's."""
         return self.replace(
             num_experts=1, expert_top_k=1, num_shared_experts=0,
             n_group=1, topk_group=1, expert_first=0, experts_held=0,
-            intermediate_pad=0,
+            zero_experts=0, intermediate_pad=0, layer_ffns=(),
             intermediate_size=(self.dense_intermediate_size
                                or self.intermediate_size))
 
@@ -896,7 +981,8 @@ class MoEConfig:
         by the Decider's cost model for gradient-buffer sizing."""
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         mixers = sum(m is not None for m, _ in self.layers)
-        ffns = sum(f is not None for _, f in self.layers)
+        ffns = sum((part is not None) + (branch == "moe")
+                   for part, branch in (FFN_PARTS[f] for _, f in self.layers))
         return v * h + mixers * 4 * h * h + ffns * 2 * h * i + h * v
 
     # ------------------------------------------------------------------

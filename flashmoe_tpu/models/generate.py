@@ -31,10 +31,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from flashmoe_tpu.config import MoEConfig
+from flashmoe_tpu.config import FFN_PARTS, MoEConfig
 from flashmoe_tpu.models.transformer import rms_norm
 from flashmoe_tpu.ops.attention import paged_attention
 from flashmoe_tpu.ops.moe import expert_arm, moe_layer
+from flashmoe_tpu.utils.telemetry import trace_span
 
 
 class KVCache(NamedTuple):
@@ -85,16 +86,45 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
     nothing (read by the layers that keep a state a slot alone:
     ``ops/attention.paged_attention``).  ``mixture(moe_params, rows, cfg)`` runs the
     experts of the mixture layers in place of :func:`moe_layer` (the EP
-    programs' exchange).  Returns (x pre-final-norm [B, T, H], the cache,
-    the span's rows, one array ``[L, B, ...]`` for each array of the
+    programs' exchange), called where a mixture is READ: a layer whose
+    feed-forward name opens a branch (``MoEConfig.layer_ffns``) computes
+    the mixture from the part's normed input there, and the output is
+    carried to the layer that joins it, whose mixer and feed-forward part
+    do not see it (nothing of the mixture depends on them either, so a
+    compiler may run it under them).  Returns (x pre-final-norm
+    [B, T, H], the cache, the span's rows, one array ``[L, B, ...]`` for each array of the
     cache over the layers that own it, and what the mixture layers
     counted, each a mean over them: ``experts_touched``, the experts that
     at least one routed row of the span reached, and, for a config that
     holds a share of the experts, ``held_rows``, the routed rows that
-    fell on them)."""
+    fell on them, and, for one that routes over zero-compute experts,
+    ``zero_rows``, those that fell on these)."""
     b, t, _ = x.shape
     pools = None if cache is None else tuple(cache)
-    rows, held, touched = [], [], []
+    rows, held, touched, zero = [], [], [], []
+
+    def feed_forward(onto, ffn_params, f_in, layer_cfg, routed: bool):
+        """``onto`` + the part's output over ``f_in`` (None: the output
+        alone), counting what its router chose."""
+        if mixture is not None and routed:
+            o = mixture(ffn_params, f_in, layer_cfg)
+        else:
+            o = moe_layer(ffn_params, f_in, layer_cfg, use_pallas=False,
+                          routed_rows=expert_arm(layer_cfg, b * t)
+                          != "capacity")
+        out = o.out.reshape(b, t, -1).astype(x.dtype)
+        if onto is not None:
+            out = onto + out
+        if layer_cfg.num_experts > 1:
+            touched.append(jnp.sum(o.expert_counts > 0))
+        if layer_cfg.experts_held:
+            held.append(jnp.sum(o.expert_counts[
+                cfg.expert_first:cfg.expert_first + cfg.experts_held]))
+        if layer_cfg.zero_experts:
+            zero.append(jnp.sum(o.expert_counts[cfg.num_experts:]))
+        return out
+
+    carried = None
     for li, (layer, (mixer, ffn)) in enumerate(zip(params["layers"],
                                                    cfg.layers)):
         # a layer is the parts ``cfg.layers`` names, each behind its norm
@@ -105,28 +135,25 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
                 valid=valid, slots=slots, fresh=fresh)
             rows.append(span)
             x = x + a
-        if ffn is None:
+        part, branch = FFN_PARTS[ffn]
+        if part is None:
             continue
         f_in = rms_norm(x, layer["ffn_norm"], cfg.norm_eps).reshape(
             b * t, -1)
-        layer_cfg = cfg.ffn_config(li)
-        if mixture is not None and ffn == "moe":
-            o = mixture(layer["moe"], f_in, layer_cfg)
-        else:
-            o = moe_layer(layer["moe"], f_in, layer_cfg, use_pallas=False,
-                          routed_rows=expert_arm(layer_cfg, b * t)
-                          != "capacity")
-        x = x + o.out.reshape(b, t, -1).astype(x.dtype)
-        if layer_cfg.num_experts > 1:
-            touched.append(jnp.sum(o.expert_counts > 0))
-        if layer_cfg.experts_held:
-            held.append(jnp.sum(o.expert_counts[
-                cfg.expert_first:cfg.expert_first + cfg.experts_held]))
+        x = feed_forward(x, layer["moe"], f_in, cfg.ffn_config(li),
+                         part == "moe")
+        if branch == "moe":
+            carried = feed_forward(None, layer["branch"], f_in,
+                                   cfg.ffn_config(li, branch=True), True)
+        elif branch == "join":
+            with trace_span("moe.shortcut_join"):
+                x = x + carried
     if cache is not None:
         cache = type(cache)(*pools)
     counted = {name: jnp.mean(jnp.stack(per_layer).astype(jnp.float32))
                for name, per_layer in (("experts_touched", touched),
-                                       ("held_rows", held)) if per_layer}
+                                       ("held_rows", held),
+                                       ("zero_rows", zero)) if per_layer}
     return x, cache, tuple(
         jnp.stack([r for r in of_pool if r is not None])
         for of_pool in zip(*rows)), counted

@@ -188,6 +188,38 @@ def nemotron3_nano_30b_a3b(**overrides) -> MoEConfig:
     return MoEConfig(**base)
 
 
+def longcat_flash(**overrides) -> MoEConfig:
+    """The language model of LongCat-Flash-Omni (560B-A27B;
+    huggingface.co/meituan-longcat/LongCat-Flash-Omni ``config.json``):
+    28 published layers of width 6144, each TWO latent-attention
+    sublayers (64 heads, ``q_lora_rank`` 1536 and ``kv_lora_rank`` 512
+    with both rank scales, nope 128 / rope 64 / v 128, theta 1e7) and TWO
+    dense SwiGLU FFNs of width 12288 around ONE shortcut-connected
+    mixture: it reads the first dense FFN's normed input and joins the
+    residual stream after the second's (``layer_ffns``: 'dense+moe' then
+    'dense+join', so ``num_layers`` counts SUBLAYERS, 56).  The router is
+    768 wide, a softmax with a selection bias: 512 FFN experts of width
+    2048 and 256 zero-compute identity experts, top-12 over all of them,
+    the chosen probabilities NOT normalised, times 6; no shared expert;
+    RMSNorm eps 1e-5, untied head.  The audio and vision towers and the
+    audio decoder are not part of the model this preset builds."""
+    base = dict(
+        num_experts=512, zero_experts=256, expert_top_k=12,
+        num_shared_experts=0, hidden_size=6144, intermediate_size=2048,
+        dense_intermediate_size=12288, num_layers=56, vocab_size=131072,
+        num_heads=64, attention_kind="mla", q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, mla_rank_scale=True, rope_theta=1e7, norm_eps=1e-5,
+        router_score="softmax", router_bias=True, norm_topk_prob=False,
+        routed_scaling_factor=6.0, sequence_len=4096, gated_ffn=True,
+        hidden_act=Activation.SILU, drop_tokens=False, dtype=jnp.bfloat16,
+    )
+    base.update(overrides)
+    base.setdefault("layer_ffns", ("dense+moe", "dense+join")
+                    * (base["num_layers"] // 2))
+    return MoEConfig(**base)
+
+
 PRESETS = {
     "mixtral-8x7b": mixtral_8x7b,
     "deepseek-moe-16b": deepseek_moe_16b,
@@ -197,4 +229,5 @@ PRESETS = {
     "ling-3.0-flash": ling3_flash,
     "lfm2-24b-a2b": lfm2_24b_a2b,
     "nemotron-3-nano-30b-a3b": nemotron3_nano_30b_a3b,
+    "longcat-flash": longcat_flash,
 }

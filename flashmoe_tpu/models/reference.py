@@ -37,8 +37,9 @@ def init_moe_params(key, cfg: MoEConfig) -> dict:
     SwiGLU), all stored stacked on a leading expert axis.
     """
     h, i = cfg.hidden_size, cfg.intermediate_size
-    # the router over every expert, the weights of those held here
-    e_all, e = cfg.num_experts, cfg.experts_held or cfg.num_experts
+    # the router over every output (the zero-compute experts behind the
+    # FFN experts), the weights of the FFN experts held here
+    e_all, e = cfg.router_width, cfg.experts_held or cfg.num_experts
     ks = jax.random.split(key, 7)
     p = {
         "gate_w": jax.random.normal(ks[0], (h, e_all), cfg.param_dtype) / jnp.sqrt(h),
@@ -115,7 +116,7 @@ def reference_gate(x, gate_w, cfg: MoEConfig, gate_bias=None):
     denom = jnp.sum(top_p, axis=-1, keepdims=True)
     norm_top = (top_p / jnp.maximum(denom, 1e-20) if cfg.norm_topk_prob
                 else top_p) * cfg.routed_scaling_factor
-    one_hot = jax.nn.one_hot(top_idx, cfg.num_experts, dtype=probs.dtype)
+    one_hot = jax.nn.one_hot(top_idx, cfg.router_width, dtype=probs.dtype)
     combine_weights = jnp.einsum("sk,ske->se", norm_top, one_hot)
 
     # Switch-style load-balancing aux loss (gate.cuh:273-299 accumulates
@@ -124,7 +125,7 @@ def reference_gate(x, gate_w, cfg: MoEConfig, gate_bias=None):
         jnp.sum(one_hot, axis=1), axis=0
     )  # fraction routed per expert
     mean_probs = jnp.mean(probs, axis=0)
-    aux_loss = cfg.num_experts * jnp.sum(density * mean_probs)
+    aux_loss = cfg.router_width * jnp.sum(density * mean_probs)
     return combine_weights, top_idx, probs, aux_loss
 
 
@@ -179,9 +180,15 @@ def reference_moe(params, x, cfg: MoEConfig):
         [expert_ffn(xs, params, cfg, e) for e in range(cfg.num_experts)], axis=0
     )  # [E, S, H]
     out = jnp.einsum(
-        "se,esh->sh", combine_weights.astype(cfg.accum_dtype),
+        "se,esh->sh",
+        combine_weights[:, :cfg.num_experts].astype(cfg.accum_dtype),
         all_out.astype(cfg.accum_dtype),
     )
+    if cfg.zero_experts:
+        # the outputs behind the FFN experts are the identity
+        out = out + jnp.sum(
+            combine_weights[:, cfg.num_experts:], axis=-1,
+            keepdims=True).astype(cfg.accum_dtype) * xs.astype(cfg.accum_dtype)
     if cfg.num_shared_experts:
         out = out + shared_expert_ffn(xs, params, cfg).astype(out.dtype)
     return out.astype(cfg.dtype), aux

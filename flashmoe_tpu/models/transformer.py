@@ -31,7 +31,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from flashmoe_tpu.config import MoEConfig
+from flashmoe_tpu.config import FFN_PARTS, MoEConfig
 from flashmoe_tpu.models.reference import init_moe_params
 from flashmoe_tpu.ops.attention import rms_norm  # noqa: F401  (re-exported)
 from flashmoe_tpu.ops.attention import rope_halves as _rope  # noqa: F401
@@ -135,6 +135,10 @@ def init_params(key, cfg: MoEConfig) -> dict:
                              k_norm=jnp.ones((dh,), cfg.param_dtype))
         if ffn is not None:
             layer["moe"] = init_moe_params(lk[4], cfg.ffn_config(li))
+        if FFN_PARTS[ffn][1] == "moe":
+            # the mixture read beside the dense part, joined later
+            layer["branch"] = init_moe_params(
+                lk[5], cfg.ffn_config(li, branch=True))
         params["layers"].append(layer)
     return params
 
@@ -218,11 +222,14 @@ def _resolved_plan(cfg: MoEConfig, mesh) -> tuple[str, int | None]:
     return resolve_moe_plan(cfg, mesh)
 
 
-def _ffn(layer, x, cfg: MoEConfig, li: int, mesh, use_pallas):
-    """FFN sub-block: MoE (possibly expert-parallel) or dense."""
+def _ffn(layer, x, cfg: MoEConfig, li: int, mesh, use_pallas,
+         branch: bool = False):
+    """FFN sub-block: MoE (possibly expert-parallel) or dense; with
+    ``branch`` the mixture branch read at this layer."""
     b, t, h = x.shape
     flat = x.reshape(b * t, h)
-    layer_cfg = cfg.ffn_config(li)
+    layer_cfg = cfg.ffn_config(li, branch)
+    ffn_params = layer["branch" if branch else "moe"]
     if mesh is not None and layer_cfg.num_experts > 1 and cfg.ep > 1:
         axes = ("dp", "ep") + (("sp",) if cfg.sp > 1 else ())
         backend, chunks = _resolved_plan(cfg, mesh)
@@ -239,7 +246,7 @@ def _ffn(layer, x, cfg: MoEConfig, li: int, mesh, use_pallas):
             # step needs its own barrier-semaphore identity
             # the fused layer IS a Pallas kernel — interpret it anywhere
             # but on real TPU, independent of the use_pallas preference
-            o = fused_ep_moe_layer(layer["moe"], flat, layer_cfg, mesh,
+            o = fused_ep_moe_layer(ffn_params, flat, layer_cfg, mesh,
                                    token_axes=axes,
                                    collective_id=7 + (li % 16),
                                    interpret=jax.default_backend() != "tpu")
@@ -247,28 +254,35 @@ def _ffn(layer, x, cfg: MoEConfig, li: int, mesh, use_pallas):
                 and not layer_cfg.num_shared_experts):
             from flashmoe_tpu.parallel.ragged_ep import ragged_ep_moe_layer
 
-            o = ragged_ep_moe_layer(layer["moe"], flat, layer_cfg, mesh,
+            o = ragged_ep_moe_layer(ffn_params, flat, layer_cfg, mesh,
                                     use_pallas=bool(use_pallas),
                                     interpret=bool(use_pallas)
                                     and jax.default_backend() != "tpu",
                                     token_axes=axes)
         else:
-            o = ep_moe_layer(layer["moe"], flat, layer_cfg, mesh,
+            o = ep_moe_layer(ffn_params, flat, layer_cfg, mesh,
                              use_pallas=bool(use_pallas),
                              token_axes=axes)
+    elif layer_cfg.zero_experts or layer_cfg.experts_held:
+        # what only the routed rows compute (ops/moe.routed_rows_ffn)
+        o = moe_layer(ffn_params, flat, layer_cfg, use_pallas=False,
+                      routed_rows=True)
     else:
-        o = moe_layer(layer["moe"], flat, layer_cfg, use_pallas=use_pallas)
+        o = moe_layer(ffn_params, flat, layer_cfg, use_pallas=use_pallas)
     return (o.out.reshape(b, t, h).astype(x.dtype),
             o.aux_loss + o.z_loss, o.stats)
 
 
 def block(layer, x, cfg: MoEConfig, li: int, mesh=None, use_pallas=None,
-          chaos_sig=()):
+          chaos_sig=(), carried=None):
     """One pre-norm transformer block: the parts ``cfg.layers[li]`` names
     (a mixer, a feed-forward part, or both), each behind its own norm.
-    Returns (x, moe_losses, moe_stats) — stats is the layer's MoEStats
-    when ``cfg.collect_stats`` and this is an MoE layer, else None (an
-    empty pytree leaf).
+    Returns (x, moe_losses, moe_stats, carried) — stats is the layer's
+    MoEStats when ``cfg.collect_stats`` and this is an MoE layer, else
+    None (an empty pytree leaf); ``carried`` is the output of a mixture
+    branch that is open across this layer (``MoEConfig.layer_ffns``: read
+    here or before, it joins after a later layer's feed-forward part),
+    None where none is.
 
     ``chaos_sig`` is the chaos-injection registry snapshot
     (:func:`flashmoe_tpu.chaos.inject.trace_signature`), unused in the
@@ -283,11 +297,18 @@ def block(layer, x, cfg: MoEConfig, li: int, mesh=None, use_pallas=None,
             layer, rms_norm(x, layer["attn_norm"], cfg.norm_eps), cfg,
             mesh=mesh, use_pallas=use_pallas, li=li)
     if ffn is None:
-        return x, jnp.zeros((), cfg.accum_dtype), None
-    f, moe_loss, moe_stats = _ffn(
-        layer, rms_norm(x, layer["ffn_norm"], cfg.norm_eps), cfg, li, mesh,
-        use_pallas)
-    return x + f, moe_loss, moe_stats
+        return x, jnp.zeros((), cfg.accum_dtype), None, carried
+    f_in = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
+    f, moe_loss, moe_stats = _ffn(layer, f_in, cfg, li, mesh, use_pallas)
+    x = x + f
+    branch = FFN_PARTS[ffn][1]
+    if branch == "moe":
+        carried, branch_loss, moe_stats = _ffn(
+            layer, f_in, cfg, li, mesh, use_pallas, branch=True)
+        moe_loss = moe_loss + branch_loss
+    elif branch == "join":
+        x, carried = x + carried, None
+    return x, moe_loss, moe_stats, carried
 
 
 # ----------------------------------------------------------------------
@@ -318,11 +339,12 @@ def forward(params, tokens, cfg: MoEConfig, mesh=None, use_pallas=None):
 
     chaos_sig = chaos_inject.trace_signature()
     moe_layers = set(cfg.moe_layer_indices)
+    carried = None
     for li, layer in enumerate(params["layers"]):
         fused_block = fused_active and li in moe_layers
         blk = blk_remat if (cfg.is_training and not fused_block) else block
-        x, moe_loss, moe_stats = blk(layer, x, cfg, li, mesh, use_pallas,
-                                     chaos_sig)
+        x, moe_loss, moe_stats, carried = blk(
+            layer, x, cfg, li, mesh, use_pallas, chaos_sig, carried)
         total_aux = total_aux + moe_loss
         if moe_stats is not None:
             layer_stats.append(moe_stats)

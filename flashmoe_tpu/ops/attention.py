@@ -63,6 +63,11 @@ def attention_xla(q, k, v, *, causal: bool = True, q_offset: int | jax.Array = 0
 #   for all heads);  [k_nope_h | v_h] = c_kv W_kvb
 #   score_h(t, s) = (q_nope_h(t).k_nope_h(s) + q_rope_h(t).k_rope(s))
 #                   / sqrt(d_nope + d_rope),  causal softmax,  o_h = sum p v_h
+# With ``cfg.mla_rank_scale`` the two normed latents carry a factor each:
+# c_q is sqrt(hidden / q_lora_rank) RMSNorm(.), so both parts of every
+# head's query; c_kv is sqrt(hidden / kv_lora_rank) RMSNorm(.), so k_nope
+# and v and NOT the shared rotary key.  The factor is part of the norm's
+# weight, so every form below (and the cached row) has it and none names it.
 # What a cache keeps of a token is the LATENT row [c_kv | k_rope]
 # (kv_lora_rank + qk_rope_head_dim elements, nothing per head).  The
 # ABSORBED form gives the same numbers in another order and never
@@ -96,12 +101,21 @@ def rope_adjacent(x, positions, theta):
 def mla_project(layer, x, cfg, positions):
     """x: [B, T, H] normed -> (q_nope [B, T, N, d_nope], q_rope
     [B, T, N, d_rope] roped, latent [B, T, rank + d_rope]: the normed
-    ``c_kv`` beside the roped shared key, the row a cache keeps)."""
+    ``c_kv`` beside the roped shared key, the row a cache keeps; with
+    ``cfg.mla_rank_scale`` the normed ``c_q`` and ``c_kv`` carry their
+    factors)."""
     b, t, _ = x.shape
     nh, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     dc = cfg.kv_lora_rank
+    q_norm, kv_norm = layer.get("q_a_norm"), layer["kv_a_norm"]
+    if cfg.mla_rank_scale:
+        h = cfg.hidden_size
+        kv_norm = kv_norm.astype(jnp.float32) * (h / dc) ** 0.5
+        if cfg.q_lora_rank:
+            q_norm = q_norm.astype(jnp.float32) * (
+                h / cfg.q_lora_rank) ** 0.5
     if cfg.q_lora_rank:
-        c_q = rms_norm(x @ layer["wq_a"].astype(x.dtype), layer["q_a_norm"],
+        c_q = rms_norm(x @ layer["wq_a"].astype(x.dtype), q_norm,
                        cfg.norm_eps)
         q = c_q @ layer["wq_b"].astype(x.dtype)
     else:                       # the published null rank: queries direct
@@ -109,7 +123,7 @@ def mla_project(layer, x, cfg, positions):
     q = q.reshape(b, t, nh, dn + dr)
     q_rope = rope_adjacent(q[..., dn:], positions, cfg.rope_theta)
     kv = x @ layer["wkv_a"].astype(x.dtype)                   # [B, T, dc+dr]
-    c_kv = rms_norm(kv[..., :dc], layer["kv_a_norm"], cfg.norm_eps)
+    c_kv = rms_norm(kv[..., :dc], kv_norm, cfg.norm_eps)
     k_rope = rope_adjacent(kv[..., dc:], positions, cfg.rope_theta)
     return q[..., :dn], q_rope, jnp.concatenate([c_kv, k_rope], axis=-1)
 

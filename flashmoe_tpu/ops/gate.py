@@ -65,7 +65,7 @@ def _finish(cfg: MoEConfig, top_p, top_idx, probs_sum, counts, zsum, s_tokens):
     probs_mean = probs_sum / s_tokens
     density = counts.astype(cfg.accum_dtype) / (s_tokens * cfg.expert_top_k)
     # Switch-transformer load-balance loss: E * sum(density * mean_prob).
-    aux = cfg.num_experts * jnp.sum(density * probs_mean) * cfg.expert_top_k
+    aux = cfg.router_width * jnp.sum(density * probs_mean) * cfg.expert_top_k
     z = (zsum / s_tokens) * cfg.router_z_loss_coef
     return RouterOutput(
         combine_weights=combine_weights,
@@ -100,7 +100,11 @@ def limit_to_groups(select, cfg: MoEConfig):
 
 
 def router_xla(x, gate_w, cfg: MoEConfig, gate_bias=None) -> RouterOutput:
-    """Router in plain XLA ops. x: [S, H], gate_w: [H, E].
+    """Router in plain XLA ops. x: [S, H], gate_w: [H, E] (E the router's
+    width: ``cfg.router_width``, the zero-compute experts of
+    ``cfg.zero_experts`` behind the FFN experts; scores, selection, counts
+    and statistics run over all of it, and a chosen index >=
+    ``num_experts`` is the caller's to read as the identity).
 
     ``cfg.router_score='sigmoid'``: the scores are sigmoid(logits); the
     top-k is taken over ``scores + gate_bias`` (``gate_bias`` [E]: the
@@ -136,8 +140,8 @@ def router_xla(x, gate_w, cfg: MoEConfig, gate_bias=None) -> RouterOutput:
         probs = jax.nn.softmax(logits, axis=-1)
         top_p, top_idx = jax.lax.top_k(probs, cfg.expert_top_k)
     counts = jnp.sum(
-        jax.nn.one_hot(top_idx, cfg.num_experts, dtype=jnp.int32), axis=(0, 1)
-    )
+        jax.nn.one_hot(top_idx, cfg.router_width, dtype=jnp.int32),
+        axis=(0, 1))
     zsum = jnp.sum(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
     return _finish(cfg, top_p, top_idx, jnp.sum(probs, axis=0), counts, zsum, s)
 
@@ -580,7 +584,8 @@ def apply_replicas(out: RouterOutput, cfg: MoEConfig) -> RouterOutput:
 
 
 def router(x, gate_w, cfg: MoEConfig, use_pallas: bool = True,
-           interpret: bool = False, gate_bias=None) -> RouterOutput:
+           interpret: bool = False, gate_bias=None,
+           zero_ok: bool = False) -> RouterOutput:
     """Dispatch to a fused kernel on TPU, XLA fallback elsewhere.
     Differentiable on all paths.  Large-E configs beyond the single-tile
     kernel's VMEM budget (:func:`gate_vmem_bytes`) use the two-pass
@@ -591,7 +596,11 @@ def router(x, gate_w, cfg: MoEConfig, use_pallas: bool = True,
     XLA arm (:func:`router_xla`) on every path, whatever ``use_pallas``
     says.  A ``router_bias`` config must be handed its ``gate_bias``: a
     caller that has none to pass (the mesh paths) is refused here rather
-    than routed without it."""
+    than routed without it.  A config with zero-compute experts
+    (``cfg.zero_experts``) is routed for a caller that says it adds the
+    identity term (``zero_ok``: the routed rows of ``ops/moe.py``) and
+    refused for every other: their dispatch indexes ``num_experts``
+    outputs."""
     from flashmoe_tpu.chaos import inject as chaos_inject
 
     if cfg.router_bias and gate_bias is None:
@@ -599,6 +608,16 @@ def router(x, gate_w, cfg: MoEConfig, use_pallas: bool = True,
             "this config routes with a selection bias (router_bias) and "
             "the caller passed no gate_bias: only ops/moe.py:moe_layer "
             "carries it (the expert-parallel layers do not yet)")
+    if cfg.zero_experts:
+        if not zero_ok or use_pallas:
+            raise NotImplementedError(
+                "this config routes over zero-compute experts "
+                "(zero_experts) and the caller cannot express them: only "
+                "the routed rows of ops/moe.py:moe_layer (routed_rows=True, "
+                "use_pallas=False) add the identity term; the capacity "
+                "arm, the Pallas routers (softmax kernels over num_experts) "
+                "and the expert-parallel layers do not")
+        return router_xla(x, gate_w, cfg, gate_bias)
     if (cfg.router_score != "softmax" or gate_bias is not None
             or cfg.n_group > 1):
         return apply_replicas(router_xla(x, gate_w, cfg, gate_bias), cfg)

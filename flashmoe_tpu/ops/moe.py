@@ -99,15 +99,23 @@ def routed_rows_ffn(params, x, r, cfg: MoEConfig):
     over all ``num_experts``) computes the rows that fall on its own
     experts: the others sort behind the last group, belong to no group
     (and to no tile) and count as zero in the sum.  What the absent
-    experts would have added is left out."""
+    experts would have added is left out.
+
+    A chosen ZERO-COMPUTE expert (``cfg.zero_experts``: an index >=
+    ``num_experts``) sorts behind the last group in the same way, so it
+    costs no row of the plan, no tile and no gather; it is the identity,
+    and its part of the sum is the token's input times the weights of
+    its identity choices, added here in float32 for EVERY token, held
+    share or not."""
     s, h = x.shape
     k = cfg.expert_top_k
     with trace_span("moe.dispatch"):
         flat_e = r.expert_idx.reshape(-1)              # row t*K + j
-        sizes = r.expert_counts                        # rows an expert
+        sizes = r.expert_counts                        # rows an output
         here = None
-        if cfg.experts_held:
-            first, held = cfg.expert_first, cfg.experts_held
+        if cfg.experts_held or cfg.zero_experts:
+            first = cfg.expert_first
+            held = cfg.experts_held or cfg.num_experts
             here = (flat_e >= first) & (flat_e < first + held)
             flat_e = jnp.where(here, flat_e - first, held)
             sizes = sizes[first:first + held]
@@ -115,20 +123,36 @@ def routed_rows_ffn(params, x, r, cfg: MoEConfig):
            params["w_down"].astype(cfg.dtype), params["b_down"],
            params["w_gate"].astype(cfg.dtype) if cfg.gated_ffn else None)
     rows = (x.astype(cfg.dtype), flat_e, sizes, *ffn)
-    if routed_rows_form(cfg) == "routed_kernel":
-        y = _rows_kernel_ffn(*rows, k=k, act_name=cfg.hidden_act,
-                             block_m=rows_block_m(cfg, s),
-                             interpret=jax.default_backend() != "tpu")
+    kernel = dict(k=k, act_name=cfg.hidden_act, block_m=rows_block_m(cfg, s),
+                  interpret=jax.default_backend() != "tpu")
+
+    def combine(y):
+        with trace_span("moe.combine"):
+            if here is not None:
+                # a row of no group holds whatever the product left there
+                y = jnp.where(here[:, None], y, jnp.zeros((), y.dtype))
+            return jnp.einsum(
+                "skh,sk->sh", y.reshape(s, k, h).astype(jnp.float32),
+                r.combine_weights.astype(jnp.float32),
+                preferred_element_type=jnp.float32)
+
+    plan = rows_plan(cfg, s)
+    if routed_rows_form(cfg) != "routed_kernel":
+        out = combine(_rows_ragged_ffn(*rows, k=k, act_name=cfg.hidden_act))
+    elif plan < s * k:
+        # few of the routed rows fall on the experts here: a plan of the
+        # rows they expect, walked as often as the rows there are need
+        out = _rows_kernel_waves(*rows, r.combine_weights, plan_rows=plan,
+                                 **kernel)
     else:
-        y = _rows_ragged_ffn(*rows, k=k, act_name=cfg.hidden_act)
-    with trace_span("moe.combine"):
-        if here is not None:
-            # a row of no group holds whatever the product left there
-            y = jnp.where(here[:, None], y, jnp.zeros((), y.dtype))
-        return jnp.einsum(
-            "skh,sk->sh", y.reshape(s, k, h).astype(jnp.float32),
-            r.combine_weights.astype(jnp.float32),
-            preferred_element_type=jnp.float32)
+        out = combine(_rows_kernel_ffn(*rows, **kernel))
+    if not cfg.zero_experts:
+        return out
+    with trace_span("moe.zero"):
+        w_zero = jnp.sum(
+            jnp.where(r.expert_idx >= cfg.num_experts,
+                      r.combine_weights.astype(jnp.float32), 0.0), axis=-1)
+        return out + w_zero[:, None] * x.astype(jnp.float32)
 
 
 def _rows_ragged_ffn(x, flat_e, sizes, w_up, b_up, w_down, b_down, w_gate, *,
@@ -194,16 +218,87 @@ def _rows_kernel_ffn(x, flat_e, sizes, w_up, b_up, w_down, b_down, w_gate, *,
         return ybuf[jnp.clip(at, 0, ybuf.shape[0] - 1)]
 
 
+@functools.partial(jax.jit, static_argnames=("k", "act_name", "block_m",
+                                             "plan_rows", "interpret"))
+def _rows_kernel_waves(x, flat_e, sizes, w_up, b_up, w_down, b_down, w_gate,
+                       weights, *, k, act_name, block_m, plan_rows,
+                       interpret):
+    """The routed rows' weighted sum [S, H] float32 through the grouped
+    kernel where FEW of the ``S x K`` rows belong to a group here (a share
+    of the experts held, zero-compute experts): the padded layout of
+    :func:`_rows_kernel_ffn` is walked in windows of the tiles that
+    ``plan_rows`` rows need (``ops/ragged.sorted_rows_plan`` with a first
+    tile), ONE launch a window, as many windows as the populated tiles
+    take: one when the groups hold what they expect, none when they hold
+    nothing, more when a step's routing is lopsided.  So the gather, the
+    kernel's grid and its buffers are sized by the rows the groups expect
+    and not by rows that sort behind the last group; the result is the
+    one layout's whatever the windows.  weights: [S, K] float32; a row of
+    no group (``flat_e == len(sizes)``) adds nothing."""
+    n, groups = flat_e.shape[0], sizes.shape[0]
+    s, h = x.shape
+    n_tiles = rag.sorted_rows_tiles(plan_rows, groups, block_m)
+    with trace_span("moe.dispatch"):
+        order = jnp.argsort(flat_e, stable=True)
+        back = jnp.argsort(order)
+        tiles = (sizes.astype(jnp.int32) + block_m - 1) // block_m
+        tile_ends = jnp.cumsum(tiles)
+        starts = jnp.cumsum(sizes.astype(jnp.int32)) - sizes
+        group = jnp.minimum(flat_e, groups - 1)
+        # where a row lies in the padded layout; behind it for no group
+        at = jnp.where(flat_e < groups,
+                       (tile_ends - tiles)[group] * block_m + back
+                       - starts[group], -1)
+        windows = (tile_ends[-1] + n_tiles - 1) // n_tiles
+
+    def window(i, acc):
+        with trace_span("moe.dispatch"):
+            src, tile_gid, live, _, _ = rag.sorted_rows_plan(
+                order, sizes, block_m, n_tiles, first_tile=i * n_tiles)
+            xbuf = x[src // k]                         # [n_tiles * bm, H]
+        with trace_span("moe.expert"):
+            ybuf = exp.grouped_ffn(
+                xbuf, tile_gid, w_up, b_up, w_down, b_down, w_gate, live,
+                act_name=act_name, gated=w_gate is not None,
+                block_m=block_m, block_i=w_up.shape[2], interpret=interpret)
+        with trace_span("moe.combine"):
+            local = at - i * n_tiles * block_m
+            mine = (local >= 0) & (local < ybuf.shape[0])
+            y = jnp.where(mine[:, None],
+                          ybuf[jnp.clip(local, 0, ybuf.shape[0] - 1)],
+                          jnp.zeros((), ybuf.dtype))
+            return acc + jnp.einsum(
+                "skh,sk->sh", y.reshape(s, k, h).astype(jnp.float32),
+                weights.astype(jnp.float32),
+                preferred_element_type=jnp.float32)
+
+    return jax.lax.fori_loop(0, windows, window,
+                             jnp.zeros((s, h), jnp.float32))
+
+
+def rows_plan(cfg: MoEConfig, s: int) -> int:
+    """Rows the routed-rows kernel's plan is laid out for, of a span of
+    ``s`` rows: the ``s x K`` routed rows; or, where the router's width
+    says that the experts here expect under a quarter of them (a share of
+    the experts held, zero-compute outputs beside them), four times what
+    they expect, walked in as many windows as the rows that do come need
+    (:func:`_rows_kernel_waves`)."""
+    n = s * cfg.expert_top_k
+    here = cfg.experts_held or cfg.num_experts
+    return min(n, 4 * -(-n * here // cfg.router_width))
+
+
 def rows_block_m(cfg: MoEConfig, s: int) -> int:
     """Rows of a tile of the routed-rows kernel for a span of ``s`` rows:
-    twice the rows an expert expects (``s x K / E``: for a config that
-    holds a share of the experts, the rows that fall here over the experts
-    held), as a power-of-two count of the packed tiles the compute dtype
+    twice the rows an expert expects (``s x K`` over the router's width,
+    zero-compute outputs included: for a config that holds a share of the
+    experts, the rows that fall here over the experts held), as a
+    power-of-two count of the packed tiles the compute dtype
     allows (16 rows of bf16) and at most 256.  A decode step's 1-4 rows
     an expert take the smallest legal tile; a 1024-token chunk's 32-64
     take 64-128, so that nearly every expert is ONE tile."""
     block = 32 // jnp.dtype(cfg.dtype).itemsize
-    while (block * cfg.num_experts < 2 * s * cfg.expert_top_k
+    while (block * cfg.router_width < 2 * s * cfg.expert_top_k
            and block < 256):
         block *= 2
     return block
@@ -249,7 +344,7 @@ def _moe_layer_impl(params, x, cfg: MoEConfig, use_pallas: bool,
         r = router(x, params["gate_w"], cfg, use_pallas=use_pallas,
                    interpret=interpret,
                    gate_bias=params["gate_bias"] if cfg.router_bias
-                   else None)
+                   else None, zero_ok=routed_rows)
     s, h = x.shape
     if cfg.experts_held and not routed_rows:
         raise NotImplementedError(
@@ -412,14 +507,15 @@ def expert_arm(cfg: MoEConfig, s: int) -> str:
     Why the rows (E / K when nothing drops): at 10.7 and 16 the capacity
     arm won every span under 512 tokens, at 32 (256 experts top-8) its
     ``w_down`` product runs at half its stream.  A config that holds a
-    share of its experts takes the routed rows always (the capacity arm
-    indexes every expert's weights); a dense layer and a rule that drops
+    share of its experts, or routes over zero-compute experts, takes the
+    routed rows always (the capacity arm indexes every expert's weights
+    and ``num_experts`` outputs); a dense layer and a rule that drops
     keep the capacity arm."""
     if (cfg.drop_tokens or cfg.degrade_unhealthy_experts
             or cfg.num_experts == 1):
         return "capacity"
     form = routed_rows_form(cfg)
-    if form == "routed_kernel" or cfg.experts_held:
+    if form == "routed_kernel" or cfg.experts_held or cfg.zero_experts:
         return form
     rows = cfg.num_experts * cfg.capacity_for(s)
     fits = (rows * cfg.hidden_size * jnp.dtype(cfg.dtype).itemsize

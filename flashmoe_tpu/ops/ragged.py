@@ -121,10 +121,14 @@ def sorted_rows_tiles(n_rows: int, groups: int, block_m: int) -> int:
     return max(1, (n_rows + min(groups, n_rows) * (block_m - 1)) // block_m)
 
 
-def sorted_rows_plan(order, sizes, block_m: int, n_tiles: int):
+def sorted_rows_plan(order, sizes, block_m: int, n_tiles: int,
+                     first_tile=None):
     """The tile-padded layout of rows ALREADY sorted by group, from that
     sort and the groups' sizes alone: no second sort, and the only
     searches are ``n_tiles`` tile starts against ``len(sizes)`` group ends.
+    With ``first_tile`` (a scalar) the plan is a WINDOW of the layout:
+    tiles ``first_tile`` .. + ``n_tiles`` - 1 of it, ``live`` the
+    populated tiles among them.
 
     order: [N] the stable argsort of the rows' group ids (rows of no group
     sort last: ``sum(sizes)`` may be under N); sizes: [G] rows a group.
@@ -140,14 +144,19 @@ def sorted_rows_plan(order, sizes, block_m: int, n_tiles: int):
     starts = jnp.cumsum(sizes) - sizes
     pad_starts = (tile_ends - tiles) * block_m
     live = tile_ends[-1:]
-    t = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32),
-                    jnp.maximum(live - 1, 0))
+    def tile():
+        at = jnp.arange(n_tiles, dtype=jnp.int32)
+        return at if first_tile is None else at + first_tile
+
+    t = jnp.minimum(tile(), jnp.maximum(live - 1, 0))
     tile_gid = jnp.minimum(
         jnp.sum(tile_ends[None, :] <= t[:, None], axis=1, dtype=jnp.int32),
         sizes.shape[0] - 1)
-    rank = (jnp.arange(n_tiles, dtype=jnp.int32) * block_m
+    rank = (tile() * block_m
             - pad_starts[tile_gid])[:, None] + jnp.arange(
                 block_m, dtype=jnp.int32)[None, :]
+    if first_tile is not None:
+        live = jnp.clip(live - first_tile, 0, n_tiles)
     populated = rank < sizes[tile_gid][:, None]
     at = jnp.clip(starts[tile_gid][:, None] + rank, 0, order.shape[0] - 1)
     src = jnp.where(populated, order[at].astype(jnp.int32), 0)

@@ -20,7 +20,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from flashmoe_tpu.config import MoEConfig
+from flashmoe_tpu.config import FFN_PARTS, MoEConfig
 
 # Canonical mesh axis order: slowest-varying (DCN-adjacent) first.  dp and pp
 # tolerate slow links; ep's all-to-all and tp's collectives want ICI
@@ -120,14 +120,16 @@ def transformer_param_specs(cfg: MoEConfig) -> dict:
     }
     ffn_specs = {
         "moe": moe_param_specs(cfg),
-        "dense": moe_param_specs(cfg.replace(
-            num_experts=1, expert_top_k=1, num_shared_experts=0, ep=1)),
+        "dense": moe_param_specs(cfg.dense_config.replace(ep=1)),
     }
-    # a layer holds the parts ``cfg.layers`` names
+    # a layer holds the parts ``cfg.layers`` names (a mixture branch
+    # read beside a dense part under ``branch``)
     layers = [
         {**(mixer_specs if mixer is not None else {}),
-         **({"ffn_norm": P(None), "moe": ffn_specs[ffn]}
-            if ffn is not None else {})}
+         **({"ffn_norm": P(None), "moe": ffn_specs[FFN_PARTS[ffn][0]]}
+            if ffn is not None else {}),
+         **({"branch": ffn_specs["moe"]}
+            if FFN_PARTS[ffn][1] == "moe" else {})}
         for mixer, ffn in cfg.layers
     ]
     return {

@@ -56,7 +56,8 @@ def stack_stage_params(params, cfg: MoEConfig, pp: int, interleave: int = 1):
         raise ValueError(
             "pipeline stages need a uniform layer pattern: every layer "
             "the same mixer and the same feed-forward part "
-            "(moe_frequency=1 or num_experts=1)"
+            "(moe_frequency=1 or num_experts=1; a mixture branch that "
+            "joins at a later layer is two kinds of layer)"
         )
     layers = params["layers"]
     ordered = [

@@ -474,7 +474,8 @@ def _ep_param_specs(params, cfg: MoEConfig):
 
     def spec(path, leaf):
         names = [p.key for p in path if isinstance(p, DictKey)]
-        if ("moe" in names and (not names or names[-1] != "gate_w")
+        if (("moe" in names or "branch" in names)
+                and (not names or names[-1] != "gate_w")
                 and getattr(leaf, "ndim", 0) >= 1
                 and leaf.shape[0] == cfg.num_experts):
             return P("ep")
@@ -1777,7 +1778,7 @@ class ServingEngine:
         mixture = self.cfg.moe_layer_indices
         if not mixture or self._ep_fn is not None:
             return None
-        arm = expert_arm(self.cfg.ffn_config(mixture[0]), rows)
+        arm = expert_arm(self.cfg, rows)    # a mixture's config is cfg
         if arm == "routed_kernel":
             self.metrics.count("serve.expert_kernel_programs")
         return arm
@@ -2131,6 +2132,9 @@ class ServingEngine:
                                            * self.cfg.state_slot_bytes)
                 if counted is not None:
                     more.update(counted)
+                    if "zero_rows" in counted:
+                        self.metrics.count("serve.zero_rows",
+                                           counted["zero_rows"])
                 self.recorder.record(
                     kind="serve_decode", step=self.step_idx,
                     slots=n_decoding, ctx_pages=ctx_pages,

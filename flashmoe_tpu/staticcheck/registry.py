@@ -370,6 +370,7 @@ STRUCTURAL_FIELDS = frozenset({
     "ssm_heads", "ssm_head_dim", "ssm_groups", "ssm_state", "ssm_conv",
     "ssm_chunk",
     "expert_first", "experts_held", "intermediate_pad",
+    "zero_experts", "mla_rank_scale",
     "dtype", "param_dtype", "accum_dtype",
     "dp", "ep", "tp", "sp", "pp",
 })

@@ -265,6 +265,12 @@ SPAN_NAMES: dict[str, str] = {
     "moe.route_groups":
         "group-limited routing: the groups' scores and the mask of the "
         "experts outside the kept groups",
+    "moe.zero":
+        "zero-compute (identity) experts: the layer's input times the "
+        "weights of a token's identity choices, added beside the combine",
+    "moe.shortcut_join":
+        "a mixture branch read at an earlier layer joins the residual "
+        "stream after this layer's feed-forward part",
     "serve.prefill":
         "serving engine: single-pass prompt prefill into cache pages",
     "serve.prefill_feed":

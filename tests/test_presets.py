@@ -7,7 +7,7 @@ import pytest
 
 from flashmoe_tpu.models.presets import PRESETS
 from flashmoe_tpu.models.reference import init_moe_params, reference_moe
-from flashmoe_tpu.ops.moe import moe_layer
+from flashmoe_tpu.ops.moe import expert_arm, moe_layer
 
 
 def test_all_presets_valid():
@@ -27,11 +27,16 @@ def test_preset_layer_runs_small(name):
     )
     if cfg.num_experts > 16:
         cfg = cfg.replace(num_experts=16,
-                          expert_top_k=min(cfg.expert_top_k, 16))
+                          expert_top_k=min(cfg.expert_top_k, 16),
+                          zero_experts=min(cfg.zero_experts, 8))
     params = init_moe_params(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (cfg.tokens, 128),
                           jnp.float32)
-    out = moe_layer(params, x, cfg, use_pallas=False)
+    # the arm the serving span would take: the capacity arm, or the routed
+    # rows where the capacity arm cannot express the layer (a router wider
+    # than its experts)
+    out = moe_layer(params, x, cfg, use_pallas=False,
+                    routed_rows=expert_arm(cfg, cfg.tokens) != "capacity")
     assert np.isfinite(np.asarray(out.out)).all()
     if not cfg.drop_tokens:
         want, _ = reference_moe(params, x, cfg)
