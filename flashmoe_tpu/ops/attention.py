@@ -23,6 +23,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from flashmoe_tpu.config import LANE, STATE_MIXERS
 from flashmoe_tpu.ops.conv import conv_attention
+from flashmoe_tpu.ops.expert import _VMEM_DEFAULT, _vmem_params
 from flashmoe_tpu.ops.kda import kda_attention
 from flashmoe_tpu.ops.ssm import ssm_attention
 from flashmoe_tpu.utils.telemetry import trace_span
@@ -823,11 +824,129 @@ def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
 # ----------------------------------------------------------------------
 # Flash attention kernel
 # ----------------------------------------------------------------------
+#
+# Three kernels over [B*N, T, D] arrays, blockwise, one algorithm:
+#   forward   s = q k^T * scale (masked);  m, l the running row max and sum
+#             of exp(s - m);  o = sum_j exp(s - m) v / l;  lse = m + log l
+#   backward  p = exp(s - lse);  delta = rowsum(dO * o);  dp = dO v^T;
+#             ds = p (dp - delta) * scale;  dv = p^T dO;  dk = ds^T q;
+#             dq = ds k
+# Every product runs on the operands' own dtype with a float32 result;
+# scale, mask, max, sum, exp, lse, delta and the accumulators are float32;
+# p and ds are cast to the other operand's dtype for their products.
+# ``fm_flash_fwd`` and ``fm_flash_bwd_dq`` hold a query block and walk the
+# K/V blocks, ``fm_flash_bwd_dkv`` holds a K/V block and walks the query
+# blocks; it computes s, p and ds TRANSPOSED ([block_k, block_q]), so that
+# the row statistics broadcast along sublanes as they are stored ([1, T]
+# rows) and p^T dO, ds^T q are plain products.  Under ``causal`` a block
+# wholly above the diagonal is skipped AND not fetched: the walked side's
+# index map clamps to the nearest block the holder needs, so a skipped
+# step names the block already resident (or the one the first live step
+# wants) and issues no DMA of its own.  Only blocks the diagonal crosses
+# pay for the mask.
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                  scale, causal, block_q, block_k):
+#: rows a side of the tile the rule starts from.  A grid step costs the
+#: same whatever it holds (its DMAs' issue, the online softmax's row
+#: statistics and the accumulator's rescale), so the tile is as large as
+#: :func:`flash_blocks` lets it be: raced on a v5e at [32, 4096, 128]
+#: bfloat16 (PERF.md §6, PR 43), a forward call takes 6.3 / 3.1 / 1.5 ms
+#: at 256 / 512 / 1024 a side
+_FLASH_TILE = 1024
+
+
+def _flash_vmem(bq: int, bk: int, d: int, isz: int) -> int:
+    """Bytes a grid step of the costliest of the three kernels keeps in
+    VMEM: double-buffered blocks of the query side (q, dO, o or dq) and of
+    the K/V side (k, v, dk, dv), the float32 scratch (two lane-wide row
+    statistics, the accumulators) and ONE float32 [bq, bk] tile: Mosaic
+    streams the chain from scores to the cast probabilities through
+    registers and keeps about one copy of the tile (at 1024 x 1024 x 128
+    bfloat16 the three kernels compile within 9 MiB and not within 8; at
+    512 x 512 within 3 and not 2)."""
+    blocks = 2 * (3 * bq + 4 * bk) * d * isz
+    scratch = (2 * bq * LANE + (bq + 2 * bk) * d) * 4
+    return blocks + scratch + bq * bk * 4
+
+
+def _flash_side(t: int, target: int) -> int:
+    """Largest block of whole lanes that divides ``t`` and is no larger
+    than ``target``; ``t`` itself where it has none (one block a side)."""
+    return next((b for b in range(min(t, target) // LANE * LANE, 0, -LANE)
+                 if t % b == 0), t)
+
+
+def _flash_params(bq: int, bk: int, d: int, dtype) -> pltpu.CompilerParams:
+    """The kernels' VMEM request at a tile: the experts' rule over what a
+    step counts (a quarter and 2 MiB on top, Mosaic's default at least)."""
+    return _vmem_params(_flash_vmem(bq, bk, d, jnp.dtype(dtype).itemsize))
+
+
+def flash_blocks(tq: int, tk: int, d: int, dtype) -> tuple[int, int]:
+    """The tile (block_q, block_k) the flash kernels take over ``tq``
+    query and ``tk`` K/V rows of width ``d``: :data:`_FLASH_TILE` a side
+    where it divides the side, the larger side halved while the kernels
+    would ask for more than Mosaic's default scope of 16 MiB.  A wider
+    scope is taken from the VMEM in which XLA keeps arrays of the program
+    AROUND the kernel: with 39.5 MiB requested the train step's output
+    head lost one and 2 ms a step (PERF.md §6, PR 43)."""
+    bq, bk = _flash_side(tq, _FLASH_TILE), _flash_side(tk, _FLASH_TILE)
+    while _flash_params(bq, bk, d, dtype).vmem_limit_bytes > _VMEM_DEFAULT:
+        if bk >= bq and bk > LANE:
+            bk = _flash_side(tk, bk // 2)
+        elif bq > LANE:
+            bq = _flash_side(tq, bq // 2)
+        else:
+            break
+    return bq, bk
+
+
+def _flash_step(step, q_start, k_start, block_q, block_k, causal):
+    """Run ``step(masked)`` for the tile whose first query row is
+    ``q_start`` and first key ``k_start``, if any of it lies on or under
+    the diagonal (it is live); the masked form only where some of it lies
+    above (the diagonal crosses it)."""
+    if not causal:
+        step(False)
+        return
+    live = k_start <= q_start + block_q - 1
+    crossed = k_start + block_k - 1 > q_start
+    pl.when(live & crossed)(functools.partial(step, True))
+    pl.when(live & jnp.logical_not(crossed))(functools.partial(step, False))
+
+
+def _nt(a, b):
+    """a b^T with a float32 result: [m, d] x [n, d] -> [m, n]."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    """a b with a float32 result: [m, k] x [k, n] -> [m, n]."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _causal_keep(q_start, k_start, shape, q_axis):
+    """Where a [.., ..] tile of scores keeps its value: query position >=
+    key position; ``q_axis`` is the tile's axis of queries."""
+    qpos = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis) + q_start
+    kpos = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis) + k_start
+    return qpos >= kpos
+
+
+def _rows_to_column(row):
+    """A [1, block] row of float32 statistics as a lane-wide [block, 128]
+    column (every lane the same value), the layout the [block, .] tiles
+    broadcast from."""
+    return jnp.transpose(jnp.broadcast_to(row, (LANE, row.shape[1])))
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+                  *, scale, causal, block_q, block_k):
     """Grid: (B*N, Tq/block_q, Tk/block_k) — kv innermost, accumulating the
-    online softmax in VMEM scratch."""
+    online softmax in VMEM scratch.  m/l scratch is lane-width (bq, 128)
+    holding broadcast copies to keep TPU layouts happy, like the upstream
+    flash kernels; the log-sum-exp leaves as a [1, bq] row."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -840,27 +959,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     q_start = qi * block_q
     k_start = ki * block_k
-    # skip fully-masked kv blocks (strictly above the diagonal); m/l scratch
-    # is lane-width (bq, 128) holding broadcast copies to keep TPU layouts
-    # happy, like the upstream flash kernels
-    run = (
-        k_start <= q_start + block_q - 1 if causal else jnp.bool_(True)
-    )
 
-    @pl.when(run)
-    def _():
-        q = q_ref[0].astype(jnp.float32)  # [bq, d]
-        k = k_ref[0].astype(jnp.float32)  # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [bq, bk]
-        if causal:
-            rows = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0) + q_start
-            cols = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1) + k_start
-            s = jnp.where(rows >= cols, s, NEG_INF)
+    def step(masked):
+        s = _nt(q_ref[0], k_ref[0]) * scale     # [bq, bk]
+        if masked:
+            s = jnp.where(_causal_keep(q_start, k_start, s.shape, 0),
+                          s, NEG_INF)
         m_prev = m_scr[:, :1]                   # [bq, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -868,29 +972,105 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         alpha = jnp.exp(m_prev - m_new)         # [bq, 1]
         l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_scr[:] = acc_scr[:] * alpha + _nn(p.astype(v_ref.dtype), v_ref[0])
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+
+    _flash_step(step, q_start, k_start, block_q, block_k, causal)
 
     @pl.when(ki == nk - 1)
     def _():
-        o_ref[0] = (
-            acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)
-        ).astype(o_ref.dtype)
+        l = jnp.maximum(l_scr[:], 1e-30)
+        o_ref[0] = (acc_scr[:] / l[:, :1]).astype(o_ref.dtype)
+        lse_ref[0] = jnp.transpose(m_scr[:] + jnp.log(l))[:1]
+
+
+def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                     lse_scr, delta_scr, dq_scr, *, scale, causal, block_q,
+                     block_k):
+    """Grid: (B*N, Tq/block_q, Tk/block_k) — kv innermost, as the forward:
+    dq of one query block accumulates in VMEM over the K/V blocks it
+    attends to."""
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+    nk = pl.num_programs(2)
+
+    @pl.when(ki == 0)
+    def _():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+        lse_scr[:] = _rows_to_column(lse_ref[0])
+        delta_scr[:] = _rows_to_column(delta_ref[0])
+
+    q_start = qi * block_q
+    k_start = ki * block_k
+
+    def step(masked):
+        k = k_ref[0]
+        s = _nt(q_ref[0], k) * scale            # [bq, bk]
+        if masked:
+            s = jnp.where(_causal_keep(q_start, k_start, s.shape, 0),
+                          s, NEG_INF)
+        p = jnp.exp(s - lse_scr[:, :1])
+        dp = _nt(do_ref[0], v_ref[0])
+        ds = p * (dp - delta_scr[:, :1]) * scale
+        dq_scr[:] = dq_scr[:] + _nn(ds.astype(k.dtype), k)
+
+    _flash_step(step, q_start, k_start, block_q, block_k, causal)
+
+    @pl.when(ki == nk - 1)
+    def _():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+
+def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal,
+                      block_q, block_k):
+    """Grid: (B*N, Tk/block_k, Tq/block_q) — q innermost: dk and dv of one
+    K/V block accumulate in VMEM over the query blocks that attend to it.
+    Scores, probabilities and ds are [bk, bq] here (see the section's
+    head)."""
+    ki = pl.program_id(1)
+    qi = pl.program_id(2)
+    nq = pl.num_programs(2)
+
+    @pl.when(qi == 0)
+    def _():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    q_start = qi * block_q
+    k_start = ki * block_k
+
+    def step(masked):
+        q, do = q_ref[0], do_ref[0]
+        s = _nt(k_ref[0], q) * scale            # [bk, bq]
+        if masked:
+            s = jnp.where(_causal_keep(q_start, k_start, s.shape, 1),
+                          s, NEG_INF)
+        p = jnp.exp(s - lse_ref[0])             # the [1, bq] row, by sublane
+        dv_scr[:] = dv_scr[:] + _nn(p.astype(do.dtype), do)
+        dp = _nt(v_ref[0], do)
+        ds = p * (dp - delta_ref[0]) * scale
+        dk_scr[:] = dk_scr[:] + _nn(ds.astype(q.dtype), q)
+
+    _flash_step(step, q_start, k_start, block_q, block_k, causal)
+
+    @pl.when(qi == nq - 1)
+    def _():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: int | None = None, block_k: int | None = None,
                     interpret: bool = False):
-    """Blockwise attention. q/k/v: [B, N, T, D] with T % block == 0.
+    """Blockwise attention. q/k/v: [B, N, T, D] with T % block == 0;
+    ``block_q`` / ``block_k`` None: the rule's (:func:`flash_blocks`).
 
-    Differentiable: the forward is the Pallas kernel; the backward
-    recomputes through :func:`attention_xla` (the kernel writes no
-    residuals, and ``pallas_call`` itself has no transpose rule — on the
-    chip ``jax.grad`` through the bare kernel dies in its JVP rule, which
-    is how the trainer first met it)."""
+    Differentiable: ``pallas_call`` has no transpose rule, so the forward
+    kernel also writes each row's log-sum-exp and the backward is two
+    kernels that recompute the probabilities of a tile from it
+    (``fm_flash_bwd_dkv``, ``fm_flash_bwd_dq``); no [T, T] array exists
+    in either direction."""
     return _flash_ad(q, k, v, causal, scale, block_q, block_k, interpret)
 
 
@@ -898,24 +1078,58 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
 def _flash_ad(q, k, v, causal, scale, block_q, block_k, interpret):
     return _flash_forward(q, k, v, causal=causal, scale=scale,
                           block_q=block_q, block_k=block_k,
-                          interpret=interpret)
+                          interpret=interpret)[0]
 
 
 def _flash_ad_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    out = _flash_forward(q, k, v, causal=causal, scale=scale,
-                         block_q=block_q, block_k=block_k,
-                         interpret=interpret)
-    return out, (q, k, v)
+    out, lse = _flash_forward(q, k, v, causal=causal, scale=scale,
+                              block_q=block_q, block_k=block_k,
+                              interpret=interpret)
+    return out, (q, k, v, out, lse)
 
 
 def _flash_ad_bwd(causal, scale, block_q, block_k, interpret, res, dy):
-    _, vjp = jax.vjp(
-        lambda q, k, v: attention_xla(q, k, v, causal=causal, scale=scale),
-        *res)
-    return vjp(dy)
+    return _flash_backward(*res, dy, causal=causal, scale=scale,
+                           block_q=block_q, block_k=block_k,
+                           interpret=interpret)
 
 
 _flash_ad.defvjp(_flash_ad_fwd, _flash_ad_bwd)
+
+
+def _flash_plan(q, k, scale, block_q, block_k):
+    """(scale, bq, bk, compiler params) of a launch over q [B, N, Tq, D]
+    and k [B, N, Tk, D]."""
+    tq, d = q.shape[2:]
+    tk = k.shape[2]
+    rule = flash_blocks(tq, tk, d, q.dtype)
+    bq = min(block_q or rule[0], tq)
+    bk = min(block_k or rule[1], tk)
+    if tq % bq or tk % bk:
+        raise ValueError(f"T ({tq},{tk}) must divide blocks ({bq},{bk})")
+    return (scale if scale is not None else d ** -0.5, bq, bk,
+            _flash_params(bq, bk, d, q.dtype))
+
+
+def _flash_specs(bq, bk, d, causal, nk, q_inner):
+    """Block specs (query side [1, bq, d], K/V side [1, bk, d], a row
+    statistic [1, 1, bq]) of a grid (h, holder, walker): the walker is the
+    K/V block when ``q_inner`` is false, the query block when true.  The
+    walker is clamped to the blocks the holder's tile is live for."""
+    if q_inner:
+        first = (lambda j: (j * bk) // bq) if causal else (lambda j: 0)
+        qb = lambda h, j, i: jnp.maximum(i, first(j))
+        kb = lambda h, j, i: j
+    else:
+        last = ((lambda i: ((i + 1) * bq - 1) // bk) if causal
+                else (lambda i: nk - 1))
+        qb = lambda h, i, j: i
+        kb = lambda h, i, j: jnp.minimum(j, last(i))
+    spec = lambda shape, imap: pl.BlockSpec(shape, imap,
+                                            memory_space=pltpu.VMEM)
+    return (spec((1, bq, d), lambda *g: (g[0], qb(*g), 0)),
+            spec((1, bk, d), lambda *g: (g[0], kb(*g), 0)),
+            spec((1, 1, bq), lambda *g: (g[0], 0, qb(*g))))
 
 
 @functools.partial(
@@ -923,42 +1137,86 @@ _flash_ad.defvjp(_flash_ad_fwd, _flash_ad_bwd)
     static_argnames=("causal", "block_q", "block_k", "interpret", "scale"),
 )
 def _flash_forward(q, k, v, *, causal: bool, scale: float | None,
-                   block_q: int, block_k: int, interpret: bool):
+                   block_q: int | None, block_k: int | None,
+                   interpret: bool):
+    """(o [B, N, Tq, D], lse [B*N, 1, Tq] float32)."""
     b, n, tq, d = q.shape
     tk = k.shape[2]
-    scale = scale if scale is not None else d ** -0.5
-    bq = min(block_q, tq)
-    bk = min(block_k, tk)
-    if tq % bq or tk % bk:
-        raise ValueError(f"T ({tq},{tk}) must divide blocks ({bq},{bk})")
-
-    qf = q.reshape(b * n, tq, d)
-    kf = k.reshape(b * n, tk, d)
-    vf = v.reshape(b * n, tk, d)
-    grid = (b * n, tq // bq, tk // bk)
-    out = pl.pallas_call(
+    scale, bq, bk, params = _flash_plan(q, k, scale, block_q, block_k)
+    q_spec, kv_spec, row_spec = _flash_specs(bq, bk, d, causal, tk // bk,
+                                             q_inner=False)
+    out, lse = pl.pallas_call(
         functools.partial(
             _flash_kernel, scale=scale, causal=causal,
             block_q=bq, block_k=bk,
         ),
         name="fm_flash_fwd",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda h, i, j: (h, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda h, i, j: (h, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b * n, tq, d), q.dtype),
+        grid=(b * n, tq // bq, tk // bk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct((b * n, tq, d), q.dtype),
+                   jax.ShapeDtypeStruct((b * n, 1, tq), jnp.float32)],
         scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
+            pltpu.VMEM((bq, LANE), jnp.float32),
+            pltpu.VMEM((bq, LANE), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
+        compiler_params=params,
         interpret=interpret,
-    )(qf, kf, vf)
-    return out.reshape(b, n, tq, d)
+    )(q.reshape(b * n, tq, d), k.reshape(b * n, tk, d),
+      v.reshape(b * n, tk, d))
+    return out.reshape(b, n, tq, d), lse
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("causal", "block_q", "block_k", "interpret", "scale"),
+)
+def _flash_backward(q, k, v, o, lse, do, *, causal: bool,
+                    scale: float | None, block_q: int | None,
+                    block_k: int | None, interpret: bool):
+    """(dq, dk, dv) from the forward's output and log-sum-exp."""
+    b, n, tq, d = q.shape
+    tk = k.shape[2]
+    scale, bq, bk, params = _flash_plan(q, k, scale, block_q, block_k)
+    nq, nk = tq // bq, tk // bk
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1).reshape(b * n, 1, tq)
+    operands = (q.reshape(b * n, tq, d), k.reshape(b * n, tk, d),
+                v.reshape(b * n, tk, d), do.reshape(b * n, tq, d), lse, delta)
+    kernel = dict(scale=scale, causal=causal, block_q=bq, block_k=bk)
+
+    q_spec, kv_spec, row_spec = _flash_specs(bq, bk, d, causal, nk,
+                                             q_inner=True)
+    dk, dv = pl.pallas_call(
+        functools.partial(_flash_dkv_kernel, **kernel),
+        name="fm_flash_bwd_dkv",
+        grid=(b * n, nk, nq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct((b * n, tk, d), k.dtype),
+                   jax.ShapeDtypeStruct((b * n, tk, d), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32)] * 2,
+        compiler_params=params,
+        interpret=interpret,
+    )(*operands)
+
+    q_spec, kv_spec, row_spec = _flash_specs(bq, bk, d, causal, nk,
+                                             q_inner=False)
+    dq = pl.pallas_call(
+        functools.partial(_flash_dq_kernel, **kernel),
+        name="fm_flash_bwd_dq",
+        grid=(b * n, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b * n, tq, d), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((bq, LANE), jnp.float32),
+            pltpu.VMEM((bq, LANE), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
+        ],
+        compiler_params=params,
+        interpret=interpret,
+    )(*operands)
+    return (dq.reshape(b, n, tq, d), dk.reshape(b, n, tk, d),
+            dv.reshape(b, n, tk, d))

@@ -381,6 +381,62 @@ def test_flash_attention_grad_matches_xla():
                                    rtol=2e-3, atol=2e-3)
 
 
+def _flash_grads(fn, q, k, v):
+    loss = lambda q, k, v: (fn(q, k, v).astype(jnp.float32) ** 2).sum()
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+
+
+@pytest.mark.parametrize(
+    "t,blocks,causal,dtype,tol",
+    [(512, (128, 128), True, jnp.float32, 2e-3),    # both kernels walk 4 tiles
+     (512, (128, 128), False, jnp.float32, 2e-3),
+     (512, (128, 256), True, jnp.float32, 2e-3),    # the clamp, unequal sides
+     (512, (256, 128), True, jnp.float32, 2e-3),
+     (2048, None, True, jnp.float32, 2e-3),         # the rule's 1024 x 1024
+     (384, None, True, jnp.bfloat16, 3e-2),         # one block a side
+     (512, (128, 128), True, jnp.bfloat16, 3e-2),
+     (512, (128, 128), False, jnp.bfloat16, 3e-2)],
+    ids=["causal", "full", "q_short", "k_short", "rule", "bf16_one_block",
+         "bf16_causal", "bf16_full"])
+def test_flash_attention_backward_kernels_match_xla(t, blocks, causal, dtype,
+                                                    tol):
+    """dq, dk, dv of ``fm_flash_bwd_dq`` / ``fm_flash_bwd_dkv`` against
+    ``jax.grad`` of the oracle on the same inputs.  bfloat16: both sides
+    round the probabilities and their cotangent to 8 bits, in another
+    order, so 3 % of a gradient's largest element is the tolerance."""
+    from flashmoe_tpu.ops.attention import attention_xla, flash_attention
+
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (1, 2, t, 64),
+                                 jnp.float32).astype(dtype) for i in range(3))
+    bq, bk = blocks or (None, None)
+    got = _flash_grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=bq, block_k=bk, interpret=True),
+        q, k, v)
+    want = _flash_grads(lambda q, k, v: attention_xla(
+        q, k, v, causal=causal), q, k, v)
+    for g, w in zip(got, want):
+        assert g.dtype == dtype
+        g, w = (np.asarray(a, np.float32) for a in (g, w))
+        np.testing.assert_allclose(g, w, rtol=tol, atol=tol * np.abs(w).max())
+
+
+def test_flash_attention_grad_under_the_trainers_checkpoint():
+    """``transformer.forward`` wraps a block in
+    ``jax.checkpoint(nothing_saveable)``: the forward kernel runs again in
+    the backward pass and hands the same residuals to the same kernels."""
+    from flashmoe_tpu.ops.attention import flash_attention
+
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (1, 2, 256, 64),
+                                 jnp.float32) for i in range(3))
+    fa = lambda q, k, v: flash_attention(q, k, v, block_q=128, block_k=128,
+                                         interpret=True)
+    plain = _flash_grads(fa, q, k, v)
+    remat = _flash_grads(jax.checkpoint(
+        fa, policy=jax.checkpoint_policies.nothing_saveable), q, k, v)
+    for g, w in zip(remat, plain):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_under_abstract_trace_on_this_jax():
     from flashmoe_tpu.utils.compat import concrete_leaf, under_abstract_trace
 

@@ -119,13 +119,20 @@ def test_flash_attention_compiles_forward_and_grad(one_chip):
     q = jax.ShapeDtypeStruct((1, 16, 4096, 128), jnp.bfloat16,
                              sharding=one_chip)
     _, text = _compile(lambda q, k, v: flash_attention(q, k, v), q, q, q)
-    assert "tpu_custom_call" in text
-    # the trainer differentiates through it; the backward recomputes in
-    # XLA, so all that is asked here is that the gradient compiles at all
-    # (before PR 22 pallas_call's JVP rule raised an AssertionError)
-    _compile(jax.grad(
+    assert [n.split(".")[0] for n, _ in _custom_call_names(text)] == [
+        "fm_flash_fwd"]
+    # the trainer differentiates through it: the forward kernel (it
+    # writes the log-sum-exp) and the two backward kernels, and no
+    # [T, T] array of scores or probabilities anywhere, f32[1,16,4096,4096]
+    # among them (before PR 43 the backward recomputed through
+    # attention_xla; before PR 22 pallas_call's JVP rule raised an
+    # AssertionError)
+    _, text = _compile(jax.grad(
         lambda q, k, v: flash_attention(q, k, v).astype(jnp.float32).sum(),
         argnums=(0, 1, 2)), q, q, q)
+    assert sorted(n.split(".")[0] for n, _ in _custom_call_names(text)) == [
+        "fm_flash_bwd_dkv", "fm_flash_bwd_dq", "fm_flash_fwd"]
+    assert not re.search(r"\[(\d+,)*4096,4096\]", text)
 
 
 @pytest.fixture(scope="module")
@@ -273,7 +280,8 @@ def test_train_step_names_its_kernels(train_step_compiled):
     assert not stray, stray
     families = {n.split(".")[0] for n, _ in calls}
     assert families == {"fm_ffn_fwd_res", "fm_gmm", "fm_tgmm",
-                        "fm_flash_fwd", "fm_router"}, families
+                        "fm_flash_fwd", "fm_flash_bwd_dkv",
+                        "fm_flash_bwd_dq", "fm_router"}, families
     for name, op in calls:
         assert "train.forward_backward" in op, (name, op)
         stage = ("moe.gate" if name.startswith("fm_router") else
