@@ -255,22 +255,23 @@ def run_engine(params, cfg, serve, reqs, arrivals, mesh=None):
 def gather_arm():
     """Programs traced inside take the plain form of the cached attention
     (store, gather the context, attend in XLA) whatever the backend: what
-    the decode kernel is held against.  The engine's paged programs are
-    traced anew on either side."""
+    the decode kernel and a long span's flash kernel are held against.
+    The engine's programs are traced anew on either side."""
     from flashmoe_tpu.ops import attention
     from flashmoe_tpu.serving import engine as eng
 
     def retrace():
-        for program in eng._INPLACE.values():
+        for program in (eng._prefill_padded, *eng._INPLACE.values()):
             program.clear_cache()
 
-    rule = attention.kv_attention_arm
+    rules = attention.kv_attention_arm, attention.span_attention_arm
     attention.kv_attention_arm = lambda *a, **k: "gather"
+    attention.span_attention_arm = lambda *a, **k: "xla"
     retrace()
     try:
         yield
     finally:
-        attention.kv_attention_arm = rule
+        attention.kv_attention_arm, attention.span_attention_arm = rules
         retrace()
 
 

@@ -8,8 +8,11 @@ accumulator is also the building block of the ring attention in
 :mod:`flashmoe_tpu.parallel.ringattn` (same math, kv blocks arriving over
 ICI instead of from HBM).
 
-Layouts: q/k/v are [B, N, T, D] (batch, heads, time, head_dim); GQA is
-handled by the caller repeating kv heads (cheap view under XLA).
+Layouts: q/k/v are [B, N, T, D] (batch, heads, time, head_dim); the
+training call (:func:`flash_attention`) has GQA handled by the caller
+repeating kv heads (cheap view under XLA), the serving call
+(:func:`flash_span_attention`: a span of queries that starts anywhere in
+its context) reads a K/V head's blocks from every query head of its group.
 """
 
 from __future__ import annotations
@@ -152,20 +155,35 @@ def mla_attend(layer, q_nope, q_rope, latent_ctx, cfg, q_pos, *,
     """Causal MLA of T queries a row over a context of latent rows.
 
     q_nope / q_rope: [B, T, N, .]; latent_ctx: [B, S, rank + d_rope], row
-    s the latent of position s; q_pos: [B, T] the queries' positions (a
-    query sees s <= its position: rows past it may hold anything).
-    ``absorbed`` picks the order of the products (see above): the plain
-    form decompresses K and V of all S rows, the absorbed form touches
-    the latent rows only.  Returns the block's attention output
-    [B, T, H]."""
+    s the latent of position s; q_pos: [B, T] the queries' positions,
+    consecutive along T (a query sees s <= its position: rows past it may
+    hold anything).  ``absorbed`` picks the order of the products (see
+    above): the plain form decompresses K and V of all S rows, the
+    absorbed form touches the latent rows only.  The plain form's scores
+    and softmax run blockwise through :func:`flash_span_attention` where
+    :func:`span_attention_arm` says so (a long span on a TPU), as float32
+    logits ``[B, N, T, S]`` in plain XLA elsewhere: the form the kernel
+    is held against.  Returns the block's attention output [B, T, H]."""
     b, t, nh, dn = q_nope.shape
     dc, dv = cfg.kv_lora_rank, cfg.v_head_dim
     dt = q_nope.dtype
     w_uk, w_uv = _mla_up_weights(layer, cfg, dt)
     scale = (dn + cfg.qk_rope_head_dim) ** -0.5
+    f32 = dict(preferred_element_type=jnp.float32)
+    if not absorbed and span_attention_arm(
+            t, latent_ctx.shape[1], nh, (dn, cfg.qk_rope_head_dim), dv,
+            dt) == "flash":
+        with trace_span("attn.mla_prefill"):
+            c_kv, k_rope = latent_ctx[..., :dc], latent_ctx[..., dc:]
+            k_nope = jnp.einsum("bsc,cnd->bnsd", c_kv, w_uk, **f32)
+            v = jnp.einsum("bsc,cnd->bnsd", c_kv, w_uv, **f32)
+            # the rotary key is ONE [S, d_rope] array for all heads
+            ctx = _flash_span_ctx(
+                (q_nope, q_rope), (k_nope.astype(dt), k_rope[:, None]),
+                v.astype(dt), q_pos, scale)
+        return ctx @ layer["wo"].astype(dt)
     mask = (jnp.arange(latent_ctx.shape[1])[None, None, None, :]
             <= q_pos[:, None, :, None])
-    f32 = dict(preferred_element_type=jnp.float32)
     if absorbed:
         with trace_span("attn.mla_decode"):
             q_cat = _absorbed_query(q_nope, q_rope, w_uk)
@@ -247,9 +265,10 @@ def mla_paged_attention(layer, x, cfg, pool, li, pos, write, block_tables,
     pages in place (:func:`paged_decode_attention` over ONE pool: a
     latent row is the key of every head and its first ``kv_lora_rank``
     columns are the value); everything else stores, gathers the context
-    and attends in plain XLA (:func:`store_latent`,
-    :func:`gather_latent`, :func:`mla_attend`: the form the kernel is
-    held against).
+    and attends through :func:`mla_attend` (:func:`store_latent`,
+    :func:`gather_latent`; in plain XLA, the form the kernel is held
+    against, or for a long span on a TPU blockwise:
+    :func:`span_attention_arm`).
 
     x: [B, T, H] normed; pool: the latent pool [L, P, page, R], or None
     for a whole prompt at once (nothing is cached yet, the context is the
@@ -298,7 +317,8 @@ def mla_paged_attention(layer, x, cfg, pool, li, pos, write, block_tables,
 # dense ``KVCache`` is the same layout with one ``T_max``-row page a batch
 # row).  The functions of this section are the plain form; a short span
 # over a paged pool on a TPU takes the kernel further down instead
-# (``kv_attention_arm``).
+# (``kv_attention_arm``), and a long span's scores run through the flash
+# kernel at the end of the file (``span_attention_arm``).
 
 def rope_halves(q, k, positions, theta):
     """Rotary position embeddings over the two HALVES (i, i + D/2) of the
@@ -343,11 +363,19 @@ def kv_attend(layer, q, k_ctx, v_ctx, q_pos):
     """Causal attention of T queries a row over a context of K/V rows.
 
     q: [B, T, N, D]; k_ctx / v_ctx: [B, N_kv, S, D], row s the K/V of
-    position s; q_pos: [B, T] the queries' positions (a query sees
-    s <= its position: rows past it may hold anything).  Returns the
-    block's attention output [B, T, H]."""
+    position s; q_pos: [B, T] the queries' positions, consecutive along
+    T (a query sees s <= its position: rows past it may hold anything).
+    Blockwise through :func:`flash_span_attention` where
+    :func:`span_attention_arm` says so (a long span on a TPU; a query
+    head reads its K/V head, nothing is repeated), as float32 logits
+    ``[B, N, T, S]`` in plain XLA elsewhere: the form the kernel is held
+    against.  Returns the block's attention output [B, T, H]."""
     b, t, nh, dh = q.shape
     dt = q.dtype
+    if span_attention_arm(t, k_ctx.shape[2], nh, (dh,), dh, dt) == "flash":
+        ctx = _flash_span_ctx((q,), (k_ctx.astype(dt),), v_ctx.astype(dt),
+                              q_pos, dh ** -0.5)
+        return ctx @ layer["wo"].astype(dt)
     if k_ctx.shape[1] != nh:  # GQA: repeat kv heads
         rep = nh // k_ctx.shape[1]
         k_ctx = jnp.repeat(k_ctx, rep, axis=1)
@@ -416,9 +444,10 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
     context.  Two arms by what the shapes and the backend say
     (:func:`kv_attention_arm`): a short span over a paged pool on a TPU
     reads each slot's own pages in place (:func:`paged_decode_attention`);
-    everything else stores, gathers the context and attends in plain XLA
-    (:func:`store_kv`, :func:`gather_ctx`, :func:`kv_attend`: the form
-    the kernel is held against).
+    everything else stores, gathers the context and attends through
+    :func:`kv_attend` (:func:`store_kv`, :func:`gather_ctx`; in plain
+    XLA, the form the kernel is held against, or for a long span on a
+    TPU blockwise: :func:`span_attention_arm`).
 
     x: [B, T, H] normed; pools: the ``(k_pages, v_pages)`` pair, each
     [L, P, N_kv, page, D], or None for a whole prompt at once (the
@@ -844,6 +873,19 @@ def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
 # step names the block already resident (or the one the first live step
 # wants) and issues no DMA of its own.  Only blocks the diagonal crosses
 # pay for the mask.
+#
+# The forward kernel has a second launch, ``fm_flash_span``
+# (:func:`flash_span_attention`): the serving engine's prefill programs,
+# whose queries are a chunk or a whole prompt over a gathered context.
+# The same body, tile rule and clamp, with what differs an operand or a
+# shape: the context position of the first query row is a scalar in SMEM
+# that the body's mask and the K/V index maps read (the diagonal shifts,
+# blocks wholly past a query block's last row are neither fetched nor
+# computed); the key may come in parts whose products sum (MLA's own
+# 128-wide part a head and ONE 64-wide rotary part for all heads); an
+# array with fewer heads than the queries is read by every head of its
+# group; no log-sum-exp is written.  :func:`span_attention_arm` says
+# where :func:`mla_attend` and :func:`kv_attend` take it.
 
 #: rows a side of the tile the rule starts from.  A grid step costs the
 #: same whatever it holds (its DMAs' issue, the online softmax's row
@@ -854,7 +896,8 @@ def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
 _FLASH_TILE = 1024
 
 
-def _flash_vmem(bq: int, bk: int, d: int, isz: int) -> int:
+def _flash_vmem(bq: int, bk: int, d: int, isz: int,
+                dv: int | None = None) -> int:
     """Bytes a grid step of the costliest of the three kernels keeps in
     VMEM: double-buffered blocks of the query side (q, dO, o or dq) and of
     the K/V side (k, v, dk, dv), the float32 scratch (two lane-wide row
@@ -862,7 +905,12 @@ def _flash_vmem(bq: int, bk: int, d: int, isz: int) -> int:
     streams the chain from scores to the cast probabilities through
     registers and keeps about one copy of the tile (at 1024 x 1024 x 128
     bfloat16 the three kernels compile within 9 MiB and not within 8; at
-    512 x 512 within 3 and not 2)."""
+    512 x 512 within 3 and not 2).  With ``dv`` the forward kernel ALONE
+    (the span form, which has no backward) over keys ``d`` and values
+    ``dv`` wide: q, k, v and o blocks and one accumulator."""
+    if dv is not None:
+        blocks = 2 * (bq + bk) * (d + dv) * isz
+        return blocks + (2 * bq * LANE + bq * dv) * 4 + bq * bk * 4
     blocks = 2 * (3 * bq + 4 * bk) * d * isz
     scratch = (2 * bq * LANE + (bq + 2 * bk) * d) * 4
     return blocks + scratch + bq * bk * 4
@@ -875,22 +923,27 @@ def _flash_side(t: int, target: int) -> int:
                  if t % b == 0), t)
 
 
-def _flash_params(bq: int, bk: int, d: int, dtype) -> pltpu.CompilerParams:
+def _flash_params(bq: int, bk: int, d: int, dtype,
+                  dv: int | None = None) -> pltpu.CompilerParams:
     """The kernels' VMEM request at a tile: the experts' rule over what a
     step counts (a quarter and 2 MiB on top, Mosaic's default at least)."""
-    return _vmem_params(_flash_vmem(bq, bk, d, jnp.dtype(dtype).itemsize))
+    return _vmem_params(_flash_vmem(bq, bk, d, jnp.dtype(dtype).itemsize,
+                                    dv))
 
 
-def flash_blocks(tq: int, tk: int, d: int, dtype) -> tuple[int, int]:
+def flash_blocks(tq: int, tk: int, d: int, dtype,
+                 dv: int | None = None) -> tuple[int, int]:
     """The tile (block_q, block_k) the flash kernels take over ``tq``
-    query and ``tk`` K/V rows of width ``d``: :data:`_FLASH_TILE` a side
-    where it divides the side, the larger side halved while the kernels
-    would ask for more than Mosaic's default scope of 16 MiB.  A wider
-    scope is taken from the VMEM in which XLA keeps arrays of the program
-    AROUND the kernel: with 39.5 MiB requested the train step's output
-    head lost one and 2 ms a step (PERF.md §6, PR 43)."""
+    query and ``tk`` K/V rows of width ``d`` (``dv``: the forward kernel
+    alone, values ``dv`` wide: :func:`_flash_vmem`): :data:`_FLASH_TILE` a
+    side where it divides the side, the larger side halved while the
+    kernels would ask for more than Mosaic's default scope of 16 MiB.  A
+    wider scope is taken from the VMEM in which XLA keeps arrays of the
+    program AROUND the kernel: with 39.5 MiB requested the train step's
+    output head lost one and 2 ms a step (PERF.md §6, PR 43)."""
     bq, bk = _flash_side(tq, _FLASH_TILE), _flash_side(tk, _FLASH_TILE)
-    while _flash_params(bq, bk, d, dtype).vmem_limit_bytes > _VMEM_DEFAULT:
+    while (_flash_params(bq, bk, d, dtype, dv).vmem_limit_bytes
+           > _VMEM_DEFAULT):
         if bk >= bq and bk > LANE:
             bk = _flash_side(tk, bk // 2)
         elif bq > LANE:
@@ -941,12 +994,25 @@ def _rows_to_column(row):
     return jnp.transpose(jnp.broadcast_to(row, (LANE, row.shape[1])))
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                  *, scale, causal, block_q, block_k):
+def _flash_kernel(*refs, scale, causal, block_q, block_k, parts=1,
+                  heads=None, lse=True):
     """Grid: (B*N, Tq/block_q, Tk/block_k) — kv innermost, accumulating the
     online softmax in VMEM scratch.  m/l scratch is lane-width (bq, 128)
     holding broadcast copies to keep TPU layouts happy, like the upstream
-    flash kernels; the log-sum-exp leaves as a [1, bq] row."""
+    flash kernels; the log-sum-exp leaves as a [1, bq] row.
+
+    ``refs``: with ``heads`` (the span form, :func:`flash_span_attention`)
+    first pos_ref, [B] in SMEM, the context position of each batch row's
+    first query (query row i sees context rows ``s <= pos + i``); then the
+    ``parts`` query blocks and the ``parts`` key blocks (the score is the
+    sum of the parts' products), v_ref, o_ref, lse_ref (where ``lse``) and
+    the scratch m, l, acc."""
+    if heads is not None:
+        pos_ref, *refs = refs
+    qs, ks = refs[:parts], refs[parts:2 * parts]
+    v_ref, o_ref = refs[2 * parts:2 * parts + 2]
+    lse_ref = refs[2 * parts + 2] if lse else None
+    m_scr, l_scr, acc_scr = refs[-3:]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -958,10 +1024,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     q_start = qi * block_q
+    if heads is not None:
+        q_start = q_start + pos_ref[pl.program_id(0) // heads]
     k_start = ki * block_k
 
     def step(masked):
-        s = _nt(q_ref[0], k_ref[0]) * scale     # [bq, bk]
+        s = _nt(qs[0][0], ks[0][0])             # [bq, bk]
+        for q_ref, k_ref in zip(qs[1:], ks[1:]):
+            s = s + _nt(q_ref[0], k_ref[0])
+        s = s * scale
         if masked:
             s = jnp.where(_causal_keep(q_start, k_start, s.shape, 0),
                           s, NEG_INF)
@@ -972,7 +1043,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         alpha = jnp.exp(m_prev - m_new)         # [bq, 1]
         l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-        acc_scr[:] = acc_scr[:] * alpha + _nn(p.astype(v_ref.dtype), v_ref[0])
+        acc = acc_scr[:] * alpha
+        p = p.astype(v_ref.dtype)
+        v = v_ref[0]
+        if masked and heads is not None:
+            # context rows past the last query's position are scratch
+            # (anything, a NaN too): their probabilities are 0, and
+            # 0 x NaN is NaN, so their values are zeroed as well
+            row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            v = jnp.where(row + k_start < q_start + block_q, v,
+                          jnp.zeros_like(v))
+        acc_scr[:] = acc + _nn(p, v)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
 
     _flash_step(step, q_start, k_start, block_q, block_k, causal)
@@ -981,7 +1062,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     def _():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / l[:, :1]).astype(o_ref.dtype)
-        lse_ref[0] = jnp.transpose(m_scr[:] + jnp.log(l))[:1]
+        if lse:
+            lse_ref[0] = jnp.transpose(m_scr[:] + jnp.log(l))[:1]
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -1111,6 +1193,13 @@ def _flash_plan(q, k, scale, block_q, block_k):
             _flash_params(bq, bk, d, q.dtype))
 
 
+def _last_live(i, bq: int, bk: int, q_pos0=None):
+    """The last K/V block that query block ``i`` is live for under the
+    causal mask, its first row at context position ``q_pos0`` (None: 0)."""
+    end = (i + 1) * bq - 1
+    return (end if q_pos0 is None else end + q_pos0) // bk
+
+
 def _flash_specs(bq, bk, d, causal, nk, q_inner):
     """Block specs (query side [1, bq, d], K/V side [1, bk, d], a row
     statistic [1, 1, bq]) of a grid (h, holder, walker): the walker is the
@@ -1121,7 +1210,7 @@ def _flash_specs(bq, bk, d, causal, nk, q_inner):
         qb = lambda h, j, i: jnp.maximum(i, first(j))
         kb = lambda h, j, i: j
     else:
-        last = ((lambda i: ((i + 1) * bq - 1) // bk) if causal
+        last = ((lambda i: _last_live(i, bq, bk)) if causal
                 else (lambda i: nk - 1))
         qb = lambda h, i, j: i
         kb = lambda h, i, j: jnp.minimum(j, last(i))
@@ -1166,6 +1255,126 @@ def _flash_forward(q, k, v, *, causal: bool, scale: float | None,
     )(q.reshape(b * n, tq, d), k.reshape(b * n, tk, d),
       v.reshape(b * n, tk, d))
     return out.reshape(b, n, tq, d), lse
+
+
+#: float32 scores ``[N, t, s]`` the flash arm starts from.  Under it
+#: XLA's fusions over the scores keep up with the kernel: raced on a v5e
+#: (PERF.md §6, PR 44; a layer's attention with its output product, ms,
+#: XLA | kernel), 32 MiB of them (32 heads x 512 x 512) 0.241 | 0.269
+#: with MLA's keys, 0.247 | 0.252 and 0.228 | 0.242 with 128- and 64-wide
+#: heads; 64 MiB 0.995 | 0.692 (64 x 512 x 512) and 0.339 | 0.223
+#: (16 x 1024 x 1024); from there the XLA form grows with the scores'
+#: bytes (4.90 | 1.75 at 512 MiB) and the kernel with the live tiles
+_SPAN_SCORE_BYTES = 64 << 20
+
+
+def span_attention_arm(t: int, s: int, heads: int,
+                       k_widths: tuple[int, ...], v_width: int,
+                       dtype) -> str:
+    """The arm the gather arm's attention takes for a span of ``t`` rows a
+    slot over a context of ``s`` rows at ``heads`` query heads, the keys
+    in parts ``k_widths`` wide and the values ``v_width``: ``"flash"``
+    (:func:`flash_span_attention`) on a TPU for a span and a context of
+    whole 128-row blocks whose float32 scores would take
+    :data:`_SPAN_SCORE_BYTES` or more, key parts and values of whole
+    lanes or half a lane tile (MLA's shared 64-wide rotary key; a
+    narrower block is no whole sublane tile of its transpose) and a dtype
+    the matrix unit takes; ``"xla"`` (the float32 logits ``[B, N, t, s]``
+    of :func:`mla_attend` / :func:`kv_attend`) for everything else: a
+    decode or verify span, a short prompt, one that is no whole blocks,
+    any other backend.  :func:`mla_attend`, :func:`kv_attend` and the
+    engine's records ask this one function."""
+    fits = (t % LANE == 0 and s % LANE == 0 and s >= t
+            and heads * t * s * 4 >= _SPAN_SCORE_BYTES
+            and all(w % (LANE // 2) == 0 for w in (*k_widths, v_width))
+            and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32))
+    return "flash" if fits and jax.default_backend() == "tpu" else "xla"
+
+
+def attention_widths(cfg) -> tuple[tuple[int, ...], int]:
+    """(the widths of the key's parts, the value's width) of ``cfg``'s
+    attention layers, as :func:`span_attention_arm` takes them: what
+    :func:`mla_attend`'s plain form and :func:`kv_attend` hand the
+    kernel."""
+    if cfg.attention_kind == "mla":
+        return ((cfg.qk_nope_head_dim, cfg.qk_rope_head_dim),
+                cfg.v_head_dim)
+    return (cfg.resolved_head_dim,), cfg.resolved_head_dim
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def flash_span_attention(q, k, v, q_pos0, *, scale: float,
+                         interpret: bool = False):
+    """Causal attention of a span of queries over a context that starts
+    before it: the forward flash kernel (``_flash_kernel``: the training
+    call's body, tile rule and clamped index maps) with the position of
+    the first query as an OPERAND.  Jitted: the layers of a program share
+    ONE traced and lowered kernel a shape.
+
+    q: the query's parts, each [B, N, Tq, d_i]; k: the key's parts, each
+    [B, N_i, Tk, d_i] with N_i a divisor of N (head h reads K/V head
+    ``h // (N / N_i)``: N for a head's own keys, fewer for grouped
+    queries, 1 for a part every head shares, MLA's rotary key); the
+    score is the sum of the parts' products times ``scale``; v:
+    [B, N_v, Tk, Dv]; q_pos0: [B] int32, query row i of batch row b sees
+    context rows ``s <= q_pos0[b] + i``; rows past that may hold anything
+    (K/V blocks wholly past a query block's last row are neither fetched
+    nor computed, only blocks the shifted diagonal crosses are masked).
+    Products on the operands' dtype with float32 results, float32 scale,
+    mask, statistics and accumulator, the probabilities cast to ``v``'s
+    dtype.  No [Tq, Tk] array exists.  Returns [B, N, Tq, Dv]."""
+    b, n, tq, _ = q[0].shape
+    tk, dv = v.shape[2:]
+    lanes = lambda w: -(-w // LANE) * LANE      # a block's width in VMEM
+    dk = sum(lanes(part.shape[-1]) for part in k)
+    bq, bk = flash_blocks(tq, tk, dk, v.dtype, lanes(dv))
+
+    def q_side(width):
+        return pl.BlockSpec((1, bq, width), lambda h, i, j, pos: (h, i, 0),
+                            memory_space=pltpu.VMEM)
+
+    def kv_side(x):
+        rep = n // x.shape[1]       # query heads a head of this array
+        return pl.BlockSpec(
+            (1, bk, x.shape[-1]),
+            lambda h, i, j, pos: (h // rep, jnp.minimum(
+                j, _last_live(i, bq, bk, pos[h // n])), 0),
+            memory_space=pltpu.VMEM)
+
+    flat = lambda x: x.reshape(-1, *x.shape[2:])
+    out = pl.pallas_call(
+        functools.partial(
+            _flash_kernel, scale=scale, causal=True, block_q=bq, block_k=bk,
+            parts=len(q), heads=n, lse=False),
+        name="fm_flash_span",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * n, tq // bq, tk // bk),
+            in_specs=[q_side(part.shape[-1]) for part in q]
+            + [kv_side(part) for part in k] + [kv_side(v)],
+            out_specs=q_side(dv),
+            scratch_shapes=[
+                pltpu.VMEM((bq, LANE), jnp.float32),
+                pltpu.VMEM((bq, LANE), jnp.float32),
+                pltpu.VMEM((bq, dv), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b * n, tq, dv), q[0].dtype),
+        compiler_params=_flash_params(bq, bk, dk, v.dtype, lanes(dv)),
+        interpret=interpret,
+    )(q_pos0.astype(jnp.int32), *map(flat, q), *map(flat, k), flat(v))
+    return out.reshape(b, n, tq, dv)
+
+
+def _flash_span_ctx(q, k, v, q_pos, scale: float):
+    """:func:`flash_span_attention` as the cached attention calls it: the
+    query's parts laid out [B, T, N, d_i], q_pos [B, T] consecutive along
+    T, interpreted off a TPU.  Returns the heads' outputs side by side,
+    [B, T, N * Dv]."""
+    b, t = q[0].shape[:2]
+    out = flash_span_attention(
+        tuple(part.transpose(0, 2, 1, 3) for part in q), k, v, q_pos[:, 0],
+        scale=scale, interpret=jax.default_backend() != "tpu")
+    return out.transpose(0, 2, 1, 3).reshape(b, t, -1)
 
 
 @functools.partial(

@@ -1182,7 +1182,7 @@ class ServingEngine:
                     self._state_bytes += self.cfg.state_slot_bytes
                 self._put_logits(slot, logits)
                 self._note_prefill(orig.rid, slot, "whole", 0, t0, t_pad,
-                                   fed, starved)
+                                   t_pad, fed, starved)
                 self.slots[slot] = _Slot(
                     req=req, orig=orig, pages=list(pages), length=t0,
                     emitted=[], admit_step=self.step_idx,
@@ -1274,8 +1274,8 @@ class ServingEngine:
                 s.prefill_toks = None
                 s.length = t0
             self._note_prefill(s.orig.rid, i, "chunk", pos,
-                               min(t0, pos + chunk) - pos, chunk, fed,
-                               starved)
+                               min(t0, pos + chunk) - pos, chunk,
+                               n_ctx_pages * sv.page_size, fed, starved)
 
     def _put_logits(self, slot: int, logits) -> None:
         """A finished prefill's logits into the slot's row of the pending
@@ -1298,20 +1298,31 @@ class ServingEngine:
         return True
 
     def _note_prefill(self, rid: int, slot: int, form: str, pos: int,
-                      tokens: int, rows: int, fed, starved: bool) -> None:
+                      tokens: int, rows: int, ctx_rows: int, fed,
+                      starved: bool) -> None:
         """One prefill program's account: ``tokens`` of a prompt in
-        ``rows`` computed rows, fed from ``fed`` (engine's clock,
-        profiler's clock) to now."""
+        ``rows`` computed rows over a context of ``ctx_rows``, fed from
+        ``fed`` (engine's clock, profiler's clock) to now."""
         done = self._prefills
         done[0] += 1
         done[1] += tokens
         done[2] += rows
         arm = self._expert_arm(rows)
+        # the arm the program's attention layers took over their context
+        # (``ops/attention.span_attention_arm``: the rule the traced
+        # program asked), counted where it is the flash kernel
+        attn_arm = None
+        if self.cfg.cache_layers:
+            attn_arm = attention.span_attention_arm(
+                rows, ctx_rows, self.cfg.num_heads,
+                *attention.attention_widths(self.cfg), self.cfg.dtype)
+            if attn_arm == "flash":
+                self.metrics.count("serve.prefill_flash_programs")
         if self.recorder is not None:
             self.recorder.record(
                 kind="serve_prefill", step=self.step_idx, rid=rid,
                 slot=slot, form=form, pos=pos, tokens=tokens, rows=rows,
-                pad_rows=rows - tokens, expert_arm=arm,
+                pad_rows=rows - tokens, expert_arm=arm, attn_arm=attn_arm,
                 host_ms=round((self._clock() - fed[0]) * 1e3, 3),
                 starved=starved, t0_trace_ns=fed[1])
 

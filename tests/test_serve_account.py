@@ -407,6 +407,9 @@ def test_every_step_record_carries_the_host_account(params, chunk):
             len(mine), sum(p["tokens"] for p in mine),
             sum(p["rows"] for p in mine))
     assert mx.counters["serve.prefill_programs"] == len(pre)
+    # the CPU keeps the plain XLA attention in every prefill program
+    assert {p["attn_arm"] for p in pre} == {"xla"}
+    assert mx.counters.get("serve.prefill_flash_programs", 0) == 0
     assert mx.counters["serve.prefill_tokens"] == sum(lengths)
     assert mx.counters["serve.prefill_rows"] == sum(p["rows"] for p in pre)
     assert mx.sketches["serve.host_ms"].n == len(recs)
@@ -415,6 +418,79 @@ def test_every_step_record_carries_the_host_account(params, chunk):
         r["starved"] > 0 for r in recs)
     assert mx.counters.get("serve.starved_dispatches", 0) == sum(
         r["starved"] for r in recs)
+
+
+@pytest.mark.parametrize("model", ["kv", "latent_beside_state"])
+def test_prefill_records_say_which_arm_the_attention_took(monkeypatch,
+                                                          model):
+    """``attn_arm`` on every ``serve_prefill`` record is what
+    ``ops/attention.span_attention_arm`` answers for the program's rows
+    and context (the rule the traced program asked), and the counter
+    ``serve.prefill_flash_programs`` counts once a program that took the
+    kernel: 0 on the CPU; with the arm forced (the kernel in
+    ``interpret``), whole prompts and chunks of a K/V toy and of one with
+    a latent layer beside recurrent state serve the plain arm's tokens,
+    and every prefill program is counted."""
+    from flashmoe_tpu.models.presets import PRESETS
+    from flashmoe_tpu.ops import attention
+
+    if model == "kv":
+        cfg = tiny_config(vocab=247)
+    else:
+        cfg = PRESETS["ling-3.0-flash"](
+            num_layers=3, layer_mixers=("kda", "kda", "mla"), first_k_dense=1,
+            num_experts=16, expert_top_k=3, n_group=4, topk_group=2,
+            kda_heads=3, kda_head_dim=16, hidden_size=64,
+            intermediate_size=64, dense_intermediate_size=128,
+            vocab_size=247, num_heads=3, kv_lora_rank=20,
+            qk_nope_head_dim=10, qk_rope_head_dim=6, v_head_dim=14,
+            dtype=jnp.float32, param_dtype=jnp.float32)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    reqs = [Request(rid=i, prompt=tuple(int(t) for t in jax.random.randint(
+        jax.random.PRNGKey(50 + i), (n,), 1, 247)), max_new_tokens=5)
+        for i, n in enumerate((5, 37, 9))]
+    serve = ServeConfig(max_batch=4, page_size=8, num_pages=40,
+                        max_pages_per_slot=6, ctx_bucket_pages=2,
+                        prompt_bucket=8, prefill_chunk=16)
+    programs = [eng._prefill_padded, *eng._INPLACE.values()]
+    asked = []
+
+    def run():
+        for program in programs:
+            program.clear_cache()       # trace with the arm of the moment
+        recorder, metrics = FlightRecorder(), Metrics()
+        engine = ServingEngine(params, cfg, serve, recorder=recorder,
+                               metrics_obj=metrics)
+        out = engine.run(reqs, arrivals=[0, 0, 1])
+        pre = [r for r in recorder.records if r["kind"] == "serve_prefill"]
+        return out, pre, metrics.counters.get(
+            "serve.prefill_flash_programs", 0)
+
+    want, pre, counted = run()
+    assert {p["attn_arm"] for p in pre} == {"xla"} and counted == 0
+
+    def forced(t, s, heads, k_widths, v_width, dtype):
+        assert heads == cfg.num_heads
+        asked.append((t, s, k_widths, v_width))
+        return "flash"
+
+    try:
+        monkeypatch.setattr(attention, "span_attention_arm", forced)
+        got, pre, counted = run()
+    finally:
+        monkeypatch.undo()
+        for program in programs:
+            program.clear_cache()
+    assert got == want
+    assert {p["attn_arm"] for p in pre} == {"flash"}
+    assert {p["form"] for p in pre} == {"whole", "chunk"}
+    assert counted == len(pre) >= 5             # a prompt in three chunks
+    # the records asked what the traced programs asked: a whole prompt
+    # over itself, a chunk over its bucket of pages, this model's widths
+    widths = attention.attention_widths(cfg)
+    assert widths == (((32,), 32) if model == "kv" else ((10, 6), 14))
+    assert {a[2:] for a in asked} == {widths}
+    assert {(8, 8), (16, 16), (16, 32), (16, 48)} <= {a[:2] for a in asked}
 
 
 def _long_run(params, clock, mx, rec, new=40):

@@ -135,6 +135,41 @@ def test_flash_attention_compiles_forward_and_grad(one_chip):
     assert not re.search(r"\[(\d+,)*4096,4096\]", text)
 
 
+@pytest.mark.parametrize("heads,kv_heads,widths,t,s", [
+    (64, 64, (128, 64), 1024, 7168),     # a shortcut chunk, widest table
+    (32, 32, (128, 64), 256, 256),       # a short latent prompt
+    (32, 2, (128,), 1024, 4608),         # 16 query heads a K/V head
+    (32, 8, (64,), 1024, 5120),          # heads half a lane tile wide
+    (16, 16, (128,), 2048, 2048),        # the backlog's longest prompt
+], ids=["mla64_chunk", "mla32_prompt", "gqa_2_of_32", "heads_of_64",
+        "mha16_prompt"])
+def test_flash_span_compiles_at_the_serving_shapes(one_chip, heads,
+                                                   kv_heads, widths, t, s):
+    """Mosaic takes ``fm_flash_span`` at the prefill programs' shapes with
+    the first query's position a scalar operand the index maps read: MLA's
+    keys in two parts, the 64-wide rotary one a single array for all
+    heads (a block as wide as the array, half a lane tile); K/V heads
+    fewer than query heads; 64-wide heads; within Mosaic's default scope
+    at the rule's tile."""
+    from flashmoe_tpu.ops.attention import flash_span_attention
+
+    arr = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                              sharding=one_chip)
+    mla = len(widths) == 2
+    q = tuple(arr(1, heads, t, w) for w in widths)
+    k = tuple(arr(1, 1 if mla and i else kv_heads, s, w)
+              for i, w in enumerate(widths))
+    v = arr(1, kv_heads, s, 128 if mla else widths[0])
+    pos = jax.ShapeDtypeStruct((1,), np.int32, sharding=one_chip)
+    compiled, text = _compile(
+        lambda q, k, v, pos: flash_span_attention(
+            q, k, v, pos, scale=sum(widths) ** -0.5), q, k, v, pos)
+    assert [n.split(".")[0] for n, _ in _custom_call_names(text)] == [
+        "fm_flash_span"]
+    assert '"size":"16777216"' in text           # the default 16 MiB scope
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 @pytest.fixture(scope="module")
 def ep4(topo):
     """reference config over a Mesh of the four described chips."""
@@ -400,6 +435,15 @@ def _fm_kernels(text):
             if n.startswith("fm_")]
 
 
+def _score_arrays(text, heads, span, ctx):
+    """Arrays of a compiled program shaped as the scores of a span over
+    its context, ``[heads, span, ctx]`` in either float type (what the
+    plain XLA attention of a long span wrote and read three times before
+    ISSUE 44; ``fm_flash_span`` keeps a tile of them in VMEM)."""
+    return (_arrays_of(text, heads, span, ctx)
+            + _arrays_of(text, 1, heads, span, ctx))
+
+
 def _no_stacked_gate_up(text, e, h, i):
     """No array of a layer's gate + up weights side by side ([E, H, 2I]:
     what the grouped kernel's gated form concatenated on every call before
@@ -418,19 +462,24 @@ def test_mla_prefill_chunk_fits_and_computes_the_routed_rows(mla_programs):
     ``ragged_dot``, no [8192, 768] intermediate in HBM, no
     [256, 2048, 1536] gate | up array, no [256, 1024, .] capacity buffer),
     and under 14.5 GB (13.78 as compiled; 13.80 with XLA's grouped matmul;
-    the E x S arm took 16.16).  A chunk is no short span: its attention
-    keeps the gather arm (whole pages scattered, the slot's pages
-    gathered) and copies no pool."""
+    the E x S arm took 16.16; 12.94 since ISSUE 44).  A chunk is no short
+    span: its attention keeps the gather arm (whole pages scattered, the
+    slot's pages gathered) and copies no pool; since ISSUE 44 the
+    gathered context is scored blockwise, one ``fm_flash_span`` call a
+    latent layer (FIVE, the decompressed keys and values its operands),
+    and no ``[32, 1024, 7168]`` array of scores exists."""
     compiled = mla_programs["chunk"].compile()
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    assert 12.6e9 < total < 14.5e9
+    assert 12.6e9 < total < 13.2e9
     text = compiled.as_text()
     assert "ragged-dot" not in text
     assert "[8192,768]" not in text and "[256,1024," not in text
     assert _no_stacked_gate_up(text, 256, 2048, 768)
-    assert _fm_kernels(text) == ["fm_ffn_fwd"] * 4 and " scatter(" in text
+    assert _fm_kernels(text) == ["fm_flash_span"] + [
+        "fm_flash_span", "fm_ffn_fwd"] * 4 and " scatter(" in text
+    assert _score_arrays(text, 32, 1024, 7168) == []
     assert "moe.expert/" in text
     assert "bf16[5,16384,16,640]{3,2,1,0" in text
     assert _latent_pool_copies(text) == []
@@ -549,7 +598,9 @@ def test_backlog_decode_step_is_the_program_the_ledger_measured(
     ``fm_paged_decode`` at the cell's shapes, a K and a V pool through
     every layer's call, and no array has the gathered context's element
     count; the 1024-token chunk keeps ``gather_ctx`` + ``kv_attend``
-    over its one slot."""
+    over its one slot, the gathered context scored blockwise since ISSUE
+    44 (``fm_flash_span``, one call a layer, no ``[16, 1024, 2560]``
+    scores)."""
     compiled = backlog_programs[program].compile()
     text = compiled.as_text()
     assert compiled.memory_analysis().alias_size_in_bytes \
@@ -572,8 +623,9 @@ def test_backlog_decode_step_is_the_program_the_ledger_measured(
     assert [n for n in kernels if n == "fm_ffn_fwd"] == ["fm_ffn_fwd"] * 6
     kernels = [n for n in kernels if n != "fm_ffn_fwd"]
     if program == "chunk":
-        assert kernels == []
+        assert kernels == ["fm_flash_span"] * 6
         assert _arrays_of(text, 16, 2560, 128)      # its gathered context
+        assert _score_arrays(text, 16, 1024, 2560) == []
         return
     assert len(kernels) == 6, kernels
     assert all(n.split(".")[0] == "fm_paged_decode" for n in kernels)
@@ -592,6 +644,10 @@ def test_backlog_whole_prompt_prefill_fits_beside_the_pool(
     back one K and one V run for ``store_prefill``."""
     compiled = backlog_programs["prefill"].compile()
     assert abs(_program_bytes(compiled) / 8.3298e9 - 1) < 0.01
+    text = compiled.as_text()
+    assert [n for n in _fm_kernels(text)
+            if n != "fm_ffn_fwd"] == ["fm_flash_span"] * 6
+    assert _score_arrays(text, 16, 2048, 2048) == []
     logits, k_run, v_run = jax.tree.leaves(compiled.out_info)
     assert logits.shape == (102400,) and logits.dtype == jnp.float32
     assert k_run.shape == v_run.shape == (6, 16, 2048, 128)
@@ -635,7 +691,8 @@ def hybrid_programs(one_chip):
 def test_hybrid_programs_fit_the_chip_with_state_and_pool_in_place(
         hybrid_programs, program):
     """12.07 GB (decode; 13.57 with the one latent layer's gathered
-    context) and 13.45 GB (chunk) as compiled, under the cell's 15.0:
+    context) and 12.24 GB (chunk; 13.45 with float32 scores over the
+    widest table) as compiled, under the cell's 15.0:
     10.34 GB of weights, and the latent pool (0.84 GB of 640-wide rows),
     the float32 state (0.805 GB) and the convolution's inputs once each,
     aliased to the outputs; no copy of the state or of the pool; the
@@ -643,13 +700,15 @@ def test_hybrid_programs_fit_the_chip_with_state_and_pool_in_place(
     on the 128 experts held; the decode program is one recurrence step a 'kda'
     layer, reads the latent layer's pages in place (ONE
     ``fm_latent_decode``, a 164 kB table as scalars, no gathered context)
-    and hands back what it counted; the chunk keeps the gather arm."""
+    and hands back what it counted; the chunk keeps the gather arm, its
+    one latent layer's context scored blockwise (ONE ``fm_flash_span``,
+    no ``[32, 1024, 10240]`` scores: ISSUE 44)."""
     compiled = hybrid_programs[program].compile()
     text = compiled.as_text()
     cache_bytes = (40960 * 16 * 640 * 2 + 6 * 64 * 32 * 128 * 128 * 4
                    + 6 * 64 * 3 * 12288 * 2)
     assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
-    lo, hi = (11.8e9, 12.4e9) if program == "decode" else (12.5e9, 15.0e9)
+    lo, hi = (11.8e9, 12.4e9) if program == "decode" else (12.0e9, 12.5e9)
     assert lo < _program_bytes(compiled) < hi
     for shape in (r"f32\[6,64,32,128,128\]", r"bf16\[1,40960,16,640\]",
                   r"bf16\[6,64,36864\]"):
@@ -676,7 +735,8 @@ def test_hybrid_programs_fit_the_chip_with_state_and_pool_in_place(
         # logits, the cache's three arrays, experts_touched and held_rows
         assert len(jax.tree.leaves(compiled.out_info)) == 1 + 3 + 2
     else:
-        assert kernels == []
+        assert kernels == ["fm_flash_span"]
+        assert _score_arrays(text, 32, 1024, 10240) == []
         assert "attn.kda_prefill" in text and "attn.mla_prefill" in text
 
 
@@ -721,8 +781,9 @@ def lfm2_programs(one_chip):
 @pytest.mark.parametrize("program", ["decode", "chunk", "prefill"])
 def test_lfm2_programs_fit_the_chip_with_inputs_and_pool_in_place(
         lfm2_programs, program):
-    """12.03 GB (decode), 13.38 GB (chunk) and 10.83 GB (a 1024-token
-    prompt at once) as compiled, under the cell's 15.0: 10.63 GB of
+    """12.03 GB (decode), 12.35 GB (chunk; 13.38 with float32 scores over
+    the widest table) and 10.83 GB (a 1024-token prompt at once) as
+    compiled, under the cell's 15.0: 10.63 GB of
     weights (the tied head a second array), and the K/V pool (1.34 GB:
     4 096 B a token, the 8 heads of 64 stored as 4 rows of 128 lanes, no
     padding) and the convolutions' inputs (7 MB) once each, aliased to
@@ -730,11 +791,14 @@ def test_lfm2_programs_fit_the_chip_with_inputs_and_pool_in_place(
     (below); the decode program is
     one step a 'conv' layer and reads the two attention layers' pages in
     place: Mosaic takes ``fm_paged_decode`` handed the packed rows, TWO
-    calls, no gathered context; the chunk keeps the gather arm."""
+    calls, no gathered context; the chunk keeps the gather arm, and it
+    and the whole prompt score their context blockwise since ISSUE 44
+    (``fm_flash_span`` over 64-wide heads, a query head reading its K/V
+    head of 8: TWO calls, no ``[32, 1024, .]`` scores)."""
     compiled = lfm2_programs[program].compile()
     text = compiled.as_text()
     pool, inputs = r"bf16\[2,20480,4,16,128\]", r"bf16\[7,128,4096\]"
-    lo, hi = {"decode": (11.8e9, 12.3e9), "chunk": (13.0e9, 13.8e9),
+    lo, hi = {"decode": (11.8e9, 12.3e9), "chunk": (12.1e9, 12.6e9),
               "prefill": (10.6e9, 11.1e9)}[program]
     assert lo < _program_bytes(compiled) < hi
     # the experts by ``ops/moe.expert_arm``: since ISSUE 36 the routed
@@ -750,7 +814,9 @@ def test_lfm2_programs_fit_the_chip_with_inputs_and_pool_in_place(
     assert [n for n in kernels if n == "fm_ffn_fwd"] == ["fm_ffn_fwd"] * 8
     kernels = [n for n in kernels if n != "fm_ffn_fwd"]
     if program == "prefill":
-        assert kernels == [] and "attn.conv_prefill" in text
+        assert kernels == ["fm_flash_span"] * 2
+        assert _score_arrays(text, 32, 1024, 1024) == []
+        assert "attn.conv_prefill" in text
         assert len(jax.tree.leaves(compiled.out_info)) == 1 + 3
         return
     cache_bytes = 2 * 2 * 20480 * 4 * 16 * 128 * 2 + 7 * 128 * 4096 * 2
@@ -769,7 +835,9 @@ def test_lfm2_programs_fit_the_chip_with_inputs_and_pool_in_place(
         # logits, K and V pool, the inputs, experts_touched
         assert len(jax.tree.leaves(compiled.out_info)) == 1 + 3 + 1
     else:
-        assert kernels == [] and "attn.conv_prefill" in text
+        assert kernels == ["fm_flash_span"] * 2
+        assert _score_arrays(text, 32, 1024, 5120) == []
+        assert "attn.conv_prefill" in text
 
 
 @pytest.fixture(scope="module")
@@ -812,8 +880,9 @@ def ssm_programs(one_chip):
 @pytest.mark.parametrize("program", ["decode", "chunk", "prefill"])
 def test_ssm_programs_fit_the_chip_with_state_and_pool_in_place(
         ssm_programs, program):
-    """12.55 GB (decode), 13.76 GB (chunk) and 8.36 GB (a 1024-token prompt
-    at once) as compiled, under the cell's 14.5: 8.08 GB of weights as
+    """12.55 GB (decode), 12.78 GB (chunk; 13.76 with float32 scores over
+    the widest table) and 8.36 GB (a 1024-token prompt at once) as
+    compiled, under the cell's 14.5: 8.08 GB of weights as
     stored, and the by-slot state (3.22 GB: 256 slots x 6 layers x 2.10 MB
     float32), the K/V pool (1.07 GB: 2048 B a token) and the convolutions'
     inputs (57 MB) once each, aliased to the outputs.  NO copy of the
@@ -830,13 +899,16 @@ def test_ssm_programs_fit_the_chip_with_state_and_pool_in_place(
     the convolutions' inputs into a slots-minor layout and back (2 copies
     of 57 MB: XLA lays the [256, 10304] projection out batch-minor at 256
     rows and carries that to the array it is sliced beside; 0.2 GB of a
-    step's 15 GB)."""
+    step's 15 GB).  The chunk and the whole prompt score the two
+    attention layers' context blockwise since ISSUE 44 (``fm_flash_span``,
+    TWO calls, 16 query heads reading one K/V head's blocks: nothing
+    repeated, no ``[32, 1024, .]`` scores)."""
     compiled = ssm_programs[program].compile()
     text = compiled.as_text()
     state, pool, inputs = (r"f32\[6,256,64,64,128\]",
                            r"bf16\[2,32768,2,16,128\]",
                            r"bf16\[6,256,18432\]")
-    lo, hi = {"decode": (12.3e9, 12.8e9), "chunk": (13.5e9, 14.0e9),
+    lo, hi = {"decode": (12.3e9, 12.8e9), "chunk": (12.5e9, 13.0e9),
               "prefill": (8.1e9, 8.6e9)}[program]
     assert lo < _program_bytes(compiled) < hi < 14.5e9
     assert "ragged-dot" not in text
@@ -850,7 +922,9 @@ def test_ssm_programs_fit_the_chip_with_state_and_pool_in_place(
     assert [n for n in kernels if n == "fm_ffn_fwd"] == ["fm_ffn_fwd"] * 5
     kernels = [n for n in kernels if n != "fm_ffn_fwd"]
     if program == "prefill":
-        assert kernels == [] and "attn.ssm_prefill" in text
+        assert kernels == ["fm_flash_span"] * 2
+        assert _score_arrays(text, 32, 1024, 1024) == []
+        assert "attn.ssm_prefill" in text
         # logits, K and V rows, the state and the inputs after the prompt
         assert len(jax.tree.leaves(compiled.out_info)) == 1 + 4
         return
@@ -873,7 +947,8 @@ def test_ssm_programs_fit_the_chip_with_state_and_pool_in_place(
         # logits, the cache's four arrays, experts_touched and held_rows
         assert len(jax.tree.leaves(compiled.out_info)) == 1 + 4 + 2
     else:
-        assert kernels == [] and copies(inputs) == []
+        assert kernels == ["fm_flash_span"] * 2 and copies(inputs) == []
+        assert _score_arrays(text, 32, 1024, 4608) == []
         assert "attn.ssm_prefill" in text
 
 
@@ -918,9 +993,10 @@ def shortcut_programs(one_chip):
 @pytest.mark.parametrize("program", ["decode", "chunk", "prefill"])
 def test_shortcut_programs_fit_the_chip_with_the_pool_in_place(
         shortcut_programs, program):
-    """12.40 GB (decode), 14.40 GB (chunk: 1.9 GB of it the float32 scores
-    of 64 heads x 1024 queries x 7168 gathered rows) and 10.96 GB (a
-    1024-token prompt at once) as compiled, under the cell's 14.5: 10.35
+    """12.40 GB (decode), 12.93 GB (chunk; 14.40 before ISSUE 44, 1.9 GB
+    of it the float32 scores of 64 heads x 1024 queries x 7168 gathered
+    rows) and 10.93 GB (a 1024-token prompt at once) as compiled, under
+    the cell's 14.5: 10.35
     GB of weights and the latent pool (2.01 GB: 8 sublayers x 1280 B a
     token) once, aliased to the output.  NO copy of the pool.  The decode
     program attends through ``fm_latent_decode`` at 64 heads, EIGHT calls
@@ -932,10 +1008,13 @@ def test_shortcut_programs_fit_the_chip_with_the_pool_in_place(
     routed rows, 1024 of a chunk's 12288: ``ops/moe.rows_plan``), so the
     kernel's row buffer is 304 / 1504 rows where the whole S x K would be
     1008 / 12768, and no tile is spent on a row of an identity expert or
-    of an expert held elsewhere."""
+    of an expert held elsewhere.  The chunk and the whole prompt score
+    their context blockwise, EIGHT ``fm_flash_span`` calls (the
+    decompressed 128-wide keys and values a head, the 64-wide rotary key
+    ONE array for all 64), and hold no ``[64, 1024, .]`` scores."""
     compiled = shortcut_programs[program].compile()
     text = compiled.as_text()
-    lo, hi = {"decode": (12.2e9, 12.6e9), "chunk": (14.1e9, 14.5e9),
+    lo, hi = {"decode": (12.2e9, 12.6e9), "chunk": (12.7e9, 13.0e9),
               "prefill": (10.7e9, 11.2e9)}[program]
     assert lo < _program_bytes(compiled) < hi <= 14.5e9
     assert "ragged-dot" not in text
@@ -948,7 +1027,8 @@ def test_shortcut_programs_fit_the_chip_with_the_pool_in_place(
     assert "moe.zero" in text and "moe.shortcut_join" in text
     kernels = [n for n in kernels if n != "fm_ffn_fwd"]
     if program == "prefill":
-        assert kernels == []
+        assert kernels == ["fm_flash_span"] * 8
+        assert _score_arrays(text, 64, 1024, 1024) == []
         # logits and the eight sublayers' latent rows
         assert len(jax.tree.leaves(compiled.out_info)) == 1 + 1
         return
@@ -964,4 +1044,6 @@ def test_shortcut_programs_fit_the_chip_with_the_pool_in_place(
         # logits, the pool, experts_touched, held_rows and zero_rows
         assert len(jax.tree.leaves(compiled.out_info)) == 1 + 1 + 3
     else:
-        assert kernels == [] and "attn.mla_prefill" in text
+        assert kernels == ["fm_flash_span"] * 8
+        assert _score_arrays(text, 64, 1024, 7168) == []
+        assert "attn.mla_prefill" in text
