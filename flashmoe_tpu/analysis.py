@@ -2,9 +2,9 @@
 analytical HBM-byte/FLOP model of every candidate execution path.
 
 Four rounds of this framework shipped kernels whose relative performance
-was argued from design notes ("dispatch/combine HBM traffic is the gap",
-BASELINE.md roofline note) while no chip could be reached.  This module
-converts those arguments into checked numbers two ways:
+was argued from design notes ("dispatch/combine HBM traffic is the gap")
+while no chip could be reached.  This module converts those arguments
+into checked numbers two ways:
 
   * :func:`xla_cost` measures a compiled XLA path's FLOPs / bytes with
     ``jit(...).lower().compile().cost_analysis()`` — real compiler
@@ -228,8 +228,7 @@ def path_costs(cfg: MoEConfig, path: str, d_world: int = 1,
     # stream — exact for the rowwin streamer (in-VMEM dequant) and the
     # XLA einsum arm; the grouped Pallas kernels currently materialize
     # the dequantized copy layer-side, so their realized saving is
-    # smaller than modeled until they grow an int8 arm — exactly the
-    # class of drift `bench.py --quant` monitors.  The fused
+    # smaller than modeled until they grow an int8 arm.  The fused
     # weights-once schedules boundary-dequantize and are priced at
     # compute width below.
     w_once = expert_weight_stream_bytes(cfg, nlx)
@@ -244,15 +243,15 @@ def path_costs(cfg: MoEConfig, path: str, d_world: int = 1,
     #     per row tile); the round-5 arrival-batched schedule processes
     #     the own slab at step 0 and every remote slab expert-major at
     #     the final step, streaming weights exactly TWICE.  The d_world
-    #     factor was this model's headline finding (BASELINE.md round-5
-    #     reading #2) and motivated the batched schedule.  The
-    #     row-windowed schedule (ISSUE 12) makes the same 2-pass
-    #     guarantee WITHOUT holding anything weights-once in VMEM:
+    #     factor was this model's headline finding and motivated the
+    #     batched schedule.  The row-windowed schedule (ISSUE 12)
+    #     makes the same 2-pass guarantee WITHOUT holding anything
+    #     weights-once in VMEM:
     #     window-major / row-minor order streams each K-window once per
     #     pass (own slab at step 0, batched remotes at the final step),
     #     so its weight column matches batched — the d x n_row_tiles
     #     collapse that rescues mixtral-width experts from the 40x
-    #     stream column (BASELINE.md's updated caveat).
+    #     stream column.
     fused_streams = {
         "batched": 2 if d_world > 1 else 1,
         "resident": d_world,
@@ -327,15 +326,14 @@ def path_costs(cfg: MoEConfig, path: str, d_world: int = 1,
             # HBM accumulator at each INTERIOR window boundary (the
             # first window starts from zero, the last folds straight
             # into y_stage) — 4 B read + 4 B write per element per
-            # boundary.  This is the term BASELINE.md's caveat demanded
-            # the model charge before believing the 2x weight column.
+            # boundary.  The model must charge this term before the
+            # 2x weight column can be believed.
             act_bytes += (g["n_i_chunks"] - 1) * slots * h * 8.0
         if path == "fused_combine":
             # sorted per-row returns carry only the rows actually routed
             # (dispatch.sorted_return_maps): rows*h out + rows*h in — the
             # slab path below returns full capacity-padded slabs, which
             # overstated this path's comm at capacity_factor > 1
-            # (ADVICE round 5)
             comm += 2 * rows * h * dt                 # y back out + in
         else:
             comm += 2 * slots * h * dt                # y back out + in
@@ -597,8 +595,7 @@ def chunked_pipeline_ms(chip_ms: float, dispatch_leg_ms: float,
 
 
 def candidate_table(cfg: MoEConfig, d_world: int = 1) -> str:
-    """Markdown table of every path's modeled bytes at ``cfg`` — the
-    BASELINE.md evidence table (VERDICT r4 next #2)."""
+    """Markdown table of every path's modeled bytes at ``cfg``."""
     paths = ["xla", "explicit", "gather", "ragged", "fused",
              "fused_combine"]
     lines = [

@@ -8,8 +8,7 @@ every drilled fault recovered — CI-able.
 ``--obs-dir`` exports the postmortem artifacts next to the report:
 ``decisions.jsonl`` (every structured decision the drills produced —
 planner fallbacks, checkpoint fallbacks, skipped updates) and
-``drill_results.jsonl`` (one result object per fault), the same
-artifact convention as ``bench.py --obs-dir``.
+``drill_results.jsonl`` (one result object per fault).
 """
 
 from __future__ import annotations

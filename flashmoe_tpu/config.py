@@ -425,8 +425,8 @@ class MoEConfig:
     # (ops/expert.py:grouped_ffn_tokens — no [E, C, H] HBM buffer).
     # None = auto: follow the FLASHMOE_GATHER_FUSED env var, else stay on
     # the explicit-dispatch path, which is hardware-validated.  The gather
-    # kernel is opt-in until a committed stage_bench row shows it winning
-    # on real TPU (round-2 advisor finding; VERDICT r2 "do this" #2).
+    # kernel is opt-in until a measurement on the chip shows it winning
+    # (round-2 advisor finding; VERDICT r2 "do this" #2).
     gather_fused: bool | None = None
 
     def __post_init__(self):
@@ -1042,7 +1042,7 @@ BENCH_CONFIGS = {
                           hidden_act=Activation.SILU, ep=8),
     # 5. 256-expert weak-scaling / payload-skew bench (BASELINE.json
     #    config #5, sized for v5p-256).  ep clamps to the devices actually
-    #    present at bench time (bench.py main), so the same name runs
+    #    present where it runs, so the same name runs
     #    single-chip for latency, on the virtual 8-device mesh for
     #    correctness (tests/test_presets.py), and at full scale when a
     #    v5p pod is reachable.  Per-rank tokens stay constant as ep grows

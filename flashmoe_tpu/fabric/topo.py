@@ -3,7 +3,7 @@
 The serving twin of the PR 12 ``FLASHMOE_MOCK_SLICES`` mock
 (:func:`flashmoe_tpu.parallel.topology._mock_slices`): partition the
 device world into ``k`` equal contiguous replica blocks so multi-replica
-fabric drills, the ``bench.py --fabric`` sweep and the router tests run
+fabric drills and the router tests run
 on the virtual CPU mesh without real multi-host serving.
 
 The parse is hardened the same way: a malformed mock (non-integer,
@@ -59,9 +59,7 @@ def fabric_world(n_devices: int | None = None) -> tuple[int, int]:
     """(replicas, devices_per_replica) for the current (or given)
     world: the ``FLASHMOE_MOCK_FABRIC`` blocking when set, else one
     replica owning every device.  The one resolution
-    :class:`~flashmoe_tpu.fabric.engine.ServingFabric` and
-    ``bench.py --fabric`` share, so a mis-typed mock fails both the
-    same way."""
+    :class:`~flashmoe_tpu.fabric.engine.ServingFabric` uses."""
     if n_devices is None:
         import jax
 

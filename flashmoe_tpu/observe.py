@@ -7,8 +7,6 @@ Input: any mix of JSONL files produced by this framework —
     records with ``step`` + optional per-layer ``moe`` stats),
   * telemetry decision logs (``Metrics.dump_decisions_jsonl`` — planner
     path selections and ``planner.drift`` comparisons),
-  * bench.py output lines (``metric``/``value`` records with
-    ``predicted_ms``/``prediction_error`` calibration fields),
   * metrics summaries (``Metrics.dump_jsonl`` — phase timers).
 
 Output: an expert-load imbalance report (per-expert histogram), the
@@ -26,7 +24,6 @@ Usage::
     python -m flashmoe_tpu.observe --postmortem /path/to/bundles
     python -m flashmoe_tpu.observe --trace 3 obs/trace.jsonl
     python -m flashmoe_tpu.observe --merge obs/telemetry.*.jsonl
-    python -m flashmoe_tpu.observe --regression --ci [obs/history.jsonl]
 
 ``--ledger`` renders the per-phase predicted-vs-measured cost ledger
 (:mod:`flashmoe_tpu.profiler.ledger` artifacts / ``planner.phase_drift``
@@ -40,9 +37,8 @@ the crash bundle(s) written by
 :mod:`flashmoe_tpu.profiler.postmortem`; ``--trace <rid>`` renders one
 request's end-to-end timeline (eviction gaps included) from
 ``serve_trace_span`` records; ``--merge`` folds per-host telemetry
-shards into one fleet view; ``--regression`` runs the perf sentry over
-``obs/history.jsonl`` (``--ci`` exits rc 2 on a tolerance breach) —
-docs/OBSERVABILITY.md "Live telemetry plane".
+shards into one fleet view — docs/OBSERVABILITY.md "Live telemetry
+plane".
 """
 
 from __future__ import annotations
@@ -314,13 +310,13 @@ def adaptation_report(records: list[dict]) -> dict:
 
 
 def phase_report(records: list[dict]) -> dict:
-    """Mean of every ``*_ms`` field across records (flight ``step_ms``,
-    bench leg timings) plus ``*_ms_p50`` phase timers from metrics
+    """Mean of every ``*_ms`` field across records (flight
+    ``step_ms``) plus ``*_ms_p50`` phase timers from metrics
     summaries — the comm/compute phase breakdown."""
     sums: dict[str, float] = {}
     counts: dict[str, int] = {}
     # prediction fields are drift inputs, not phases — keep them out
-    skip = {"predicted_ms", "xla_predicted_ms", "measured_ms"}
+    skip = {"predicted_ms", "measured_ms"}
     for rec in records:
         for k, v in rec.items():
             if not isinstance(v, (int, float)) or isinstance(v, bool):
@@ -409,8 +405,8 @@ def ledger_report(records: list[dict]) -> dict:
 
 def render_ledger_text(led: dict) -> str:
     if not led["n"] and not led["overlap"]:
-        return "no phase-ledger rows found (run `bench.py --profile` " \
-               "or profiler.ledger.run_ledger_matrix first)"
+        return "no phase-ledger rows found (run " \
+               "profiler.ledger.run_ledger_matrix first)"
     lines = []
     if led["n"]:
         lines += [f"cost ledger: {led['n']} phase comparisons over "
@@ -1428,13 +1424,6 @@ def main(argv=None) -> int:
                          "request's latency went (queue wait, router "
                          "spill, prefill, handoff DCN, decode, "
                          "eviction gaps) and the fleet rollup")
-    ap.add_argument("--regression", action="store_true",
-                    help="perf sentry: compare the newest run in the "
-                         "history file (default obs/history.jsonl) "
-                         "against the rolling baseline")
-    ap.add_argument("--ci", action="store_true",
-                    help="with --regression: exit rc 2 when any metric "
-                         "regressed (regress.detected decisions)")
     args = ap.parse_args(argv)
 
     modes = [m for m, on in (("--ledger", args.ledger),
@@ -1443,33 +1432,9 @@ def main(argv=None) -> int:
                              ("--postmortem", bool(args.postmortem)),
                              ("--trace", args.trace is not None),
                              ("--merge", args.merge),
-                             ("--attribution", args.attribution),
-                             ("--regression", args.regression)) if on]
+                             ("--attribution", args.attribution)) if on]
     if len(modes) > 1:
         ap.error(f"pick one mode: {' '.join(modes)}")
-    if args.ci and not args.regression:
-        ap.error("--ci only applies with --regression")
-
-    if args.regression:
-        from flashmoe_tpu.telemetry_plane import regression as reg
-
-        path = args.files[0] if args.files else reg.DEFAULT_HISTORY
-        runs = reg.load_history(path)
-        if not runs:
-            print(f"no run history at {path!r} (append one with "
-                  f"`bench.py --regression` or "
-                  f"regression.append_run)", file=sys.stderr)
-            return 2
-        report = reg.check_regression(runs)
-        report["history"] = path
-        if args.json:
-            json.dump(report, sys.stdout)
-            print()
-        else:
-            print(reg.render_text(report))
-        if args.ci and report["regressions"]:
-            return 2
-        return 0
 
     if args.postmortem:
         from flashmoe_tpu.profiler import postmortem as pm
@@ -1488,8 +1453,7 @@ def main(argv=None) -> int:
         return 0
 
     if not args.files:
-        ap.error("JSONL files required (or use --postmortem DIR / "
-                 "--regression)")
+        ap.error("JSONL files required (or use --postmortem DIR)")
     if args.merge:
         rep = merge_report(args.files)
         if args.json:

@@ -37,8 +37,8 @@ from flashmoe_tpu.utils.telemetry import trace_span
 def _gather_fused(cfg: MoEConfig) -> bool:
     """Whether inference routes through the gather-fused FFN kernel.
 
-    Opt-in (config field, or FLASHMOE_GATHER_FUSED=1) until the kernel has a
-    winning stage_bench row on real TPU; the explicit-dispatch path is the
+    Opt-in (config field, or FLASHMOE_GATHER_FUSED=1) until the kernel wins
+    a measurement on the chip; the explicit-dispatch path is the
     hardware-validated default (round-2 advisor finding)."""
     if cfg.gather_fused is not None:
         return cfg.gather_fused

@@ -285,7 +285,7 @@ def speculation_summary(records) -> dict:
     the engine folds per-slot counters into ``serve_request`` records and
     per-step ``spec_tokens``/``spec_on`` into step records; this reduces a
     recorder dump (or any iterable of such dicts) back into one summary the
-    report surfaces (``observe.py``, loadgen sweeps) can print without
+    report surface (``observe.py``) can print without
     re-deriving engine internals.
     """
     drafted = 0

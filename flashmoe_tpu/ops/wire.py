@@ -73,7 +73,7 @@ WIRE_NAMES = tuple(sorted(_ALIASES))
 
 def canonical_name(name: str | None) -> str:
     """Canonical wire name ('bf16' / 'e4m3' / 'e5m2'), or 'off' for
-    ``None`` — the spelling measurement keys and bench records use."""
+    ``None`` — the spelling measurement keys use."""
     if name is None:
         return "off"
     key = _ALIASES.get(str(name).lower())

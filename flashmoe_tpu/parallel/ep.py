@@ -528,7 +528,7 @@ def resolve_moe_backend(cfg: MoEConfig, mesh: Mesh | None = None) -> str:
     Pass-through for explicit configs; ``moe_backend='auto'`` consults
     the analytical planner (:mod:`flashmoe_tpu.planner.select`) — the
     predicted per-path latency winner, overridden by measured entries
-    when the tuning table or bench records cover this shape.  The
+    when the tuning table covers this shape.  The
     decision and its full breakdown land in telemetry
     (``metrics.decision('planner.path_select', ...)``)."""
     from flashmoe_tpu.planner.select import resolve_moe_backend as _resolve

@@ -31,7 +31,7 @@ over the ``ep`` mesh axis:
     remote slabs computed expert-major at the final step so each weight
     byte streams twice total instead of once per source (the round-5
     cost model showed the per-source schedules' d x weight re-streaming
-    dominates every other byte at multi-chip scale — see BASELINE.md) —
+    dominates every other byte at multi-chip scale) —
     and the row-windowed ``rowwin`` schedule for experts too wide for
     any weights-once residency (mixtral's i=14336): weights stream in
     VMEM-sized K-windows, window-major / row-minor, partial sums parked
@@ -608,10 +608,9 @@ def _fused_kernel(
             weight traffic at ~2 streams total (own-slab pass at step 0
             + the arrival-batched remote pass at the final step)
             regardless of d or the row-tile count.  This is exactly the
-            loop order BASELINE.md's round-5 caveat said naive
-            row-windowing misses: a ROW-major window loop re-streams
-            every window per row tile and degenerates to the stream
-            schedule's bytes.
+            loop order naive row-windowing misses: a ROW-major window
+            loop re-streams every window per row tile and degenerates
+            to the stream schedule's bytes.
 
             The price is per-window activation re-streaming: each row
             tile re-reads its x tile per window and round-trips its f32
@@ -1059,13 +1058,10 @@ def rowwin_tile_candidates(cap: int, h: int, i_dim: int, dt_size: int,
                            sc_bytes: float = 0.0
                            ) -> list[tuple[int, int]]:
     """Every VMEM-feasible (cm row tile, kw K-window) pair of the
-    rowwin schedule at this shape — THE candidate grid shared by the
-    IO-aware chooser (:func:`_rowwin_tiles`), ``bench.py --tiles`` and
-    ``tune_sweep.py --stage tiles`` (via
-    :func:`rowwin_sweep_candidates`), and the contract tests, so the
-    measured sweeps can never silently drift from the pairs the
-    chooser can actually pick.  ``w_dt``/``sc_bytes``: quantized-store
-    weight width + scale residency (:func:`_rowwin_budget_ok`)."""
+    rowwin schedule at this shape — the candidate grid of the
+    IO-aware chooser (:func:`_rowwin_tiles`).  ``w_dt``/``sc_bytes``:
+    quantized-store weight width + scale residency
+    (:func:`_rowwin_budget_ok`)."""
     return [
         (cm, kw)
         for cm in (256, 128, 64, 32, 16, 8) if cap % cm == 0
@@ -1074,28 +1070,6 @@ def rowwin_tile_candidates(cap: int, h: int, i_dim: int, dt_size: int,
                               fuse_combine, k, w_dt=w_dt,
                               sc_bytes=sc_bytes)
     ]
-
-
-def rowwin_sweep_candidates(cap: int, h: int, i_dim: int, dt_size: int,
-                            gated: bool, fuse_combine: bool,
-                            k: int, *,
-                            w_dt: int | None = None,
-                            sc_bytes: float = 0.0
-                            ) -> list[tuple[int, int]]:
-    """The measurement subset of :func:`rowwin_tile_candidates` the
-    tiles sweeps time: ONE candidate per feasible K-window, at its
-    widest feasible row tile.  cm moves no modeled HBM bytes (the
-    chooser always prefers the widest feasible cm for whatever kw it
-    picks), so per-kw best-cm covers every pair the analytic chooser
-    can select while keeping a hardware sweep to a handful of timed
-    points instead of the full grid."""
-    best_cm: dict[int, int] = {}
-    for cm, kw in rowwin_tile_candidates(cap, h, i_dim, dt_size, gated,
-                                         fuse_combine, k, w_dt=w_dt,
-                                         sc_bytes=sc_bytes):
-        best_cm[kw] = max(best_cm.get(kw, 0), cm)
-    return sorted(((cm, kw) for kw, cm in best_cm.items()),
-                  key=lambda t: -t[1])
 
 
 def _rowwin_tiles(cap: int, h: int, i_dim: int, dt_size: int,
@@ -1118,8 +1092,7 @@ def _rowwin_tiles(cap: int, h: int, i_dim: int, dt_size: int,
     tiles mean fewer DMA issues and better MXU occupancy).
 
     A measured ``fused_tiles`` tuning entry
-    (:mod:`flashmoe_tpu.tuning`; swept by ``scripts/tune_sweep.py
-    --stage tiles`` / ``bench.py --tiles``) overrides the analytic pick
+    (:mod:`flashmoe_tpu.tuning`) overrides the analytic pick
     when it still divides the shapes — the VMEM gate is never
     overridable.  Returns ``(cm, kw)``, or ``(None, None)`` when no
     pair fits the budget."""
@@ -1216,7 +1189,7 @@ def _fused_schedule(cap: int, h: int, i_dim: int, dt_size: int,
       batched    own slab at step 0, ALL remote slabs expert-major at the
                  final step with weights streamed once -> 2x weight HBM
                  traffic instead of the per-source d x (the round-5 cost
-                 model's headline finding; see BASELINE.md).  Default at
+                 model's headline finding).  Default at
                  d >= 3 when the (d-1)*cap-row hidden slab fits VMEM —
                  at d=2 the two schedules move identical weight bytes
                  and per-source keeps finer overlap.
@@ -1264,7 +1237,7 @@ def _fused_schedule(cap: int, h: int, i_dim: int, dt_size: int,
                     f"fused_schedule={forced!r} is VMEM-infeasible at "
                     f"this shape: the {hid_rows}-row hidden slab plus "
                     f"the double-buffered weight chunks exceed the "
-                    f"budget (see BASELINE.md; 'rowwin' or 'stream' "
+                    f"budget ('rowwin' or 'stream' "
                     f"stay feasible)")
             return forced, bh
         if forced == "rowwin":
@@ -1946,8 +1919,8 @@ def _fuse_combine_enabled(cfg: MoEConfig, s_loc: int, h: int, i_dim: int,
                           cap: int, d_world: int | None = None) -> bool:
     """Whether the weighted un-permute runs inside the RDMA kernel.
 
-    OPT-IN (``FLASHMOE_FUSED_COMBINE=1``) until a hardware stage_bench
-    row shows it beating the XLA combine: the sorted-return restructure
+    OPT-IN (``FLASHMOE_FUSED_COMBINE=1``) until a measurement on the
+    chip shows it beating the XLA combine: the sorted-return restructure
     (round 5) moved the cost from S*K sequential VPU row-adds to per-row
     return DMAs whose issue cost overlaps the FFN, but the DMA-engine
     behavior of thousands of [1, h] remote copies on real ICI is exactly

@@ -21,8 +21,7 @@ the virtual 8-device CPU mesh (where it validates the harness, not the
 hardware — XLA's CPU collectives are memcpys).
 
 Timing uses chained in-jit iterations (two chain lengths, differenced),
-which takes dispatch and readback out of the per-iteration time — see
-``bench.py``.
+which takes dispatch and readback out of the per-iteration time.
 """
 
 from __future__ import annotations
@@ -231,7 +230,7 @@ def overlap_bound(cfg: MoEConfig, d: int, gen: str = "v5e", *,
         schedule = _geom(cfg, d, fuse_combine=fuse_combine)["schedule"]
     # ValueError naming the supported generations for anything outside
     # {v4, v5e, v5p, v6e} — the planner calls this with arbitrary gen
-    # strings, so it must fail cleanly (ADVICE round 5)
+    # strings, so it must fail cleanly
     peak_tflops, _ = chip_spec(gen)
     bw_link = ici_spec(gen)[1] * 1e9             # B/s one way per link
     dt = jnp.dtype(cfg.dtype).itemsize
@@ -280,7 +279,7 @@ def chunked_overlap_bound(cfg: MoEConfig, d: int, gen: str = "v5e",
                           path: str = "collective") -> dict:
     """Analytical expected overlap efficiency of the chunked
     double-buffered XLA-transport pipeline (``MoEConfig.a2a_chunks``) —
-    the number a ``bench.py --overlap`` measurement of the chunked
+    the number a :func:`measure_overlap` reading of the chunked
     schedule is judged against, the way :func:`overlap_bound` anchors
     the fused kernel's measurement.
 

@@ -51,8 +51,7 @@ def chip_spec(gen: str) -> tuple[float, float]:
 
     Raises ``ValueError`` naming the supported set for anything else —
     the planner and overlap bound call this with arbitrary user strings,
-    and a bare ``KeyError`` carried no hint of what is accepted
-    (ADVICE round 5)."""
+    and a bare ``KeyError`` carried no hint of what is accepted."""
     if gen not in _PEAK_TFLOPS:
         raise ValueError(
             f"unknown TPU generation {gen!r}; supported: "
@@ -256,11 +255,11 @@ def slice_structure(devices=None) -> tuple[int, int] | None:
     ``FLASHMOE_MOCK_SLICES=k`` partitions the first ``n`` devices into
     ``k`` equal contiguous "slices" regardless of their real topology —
     the virtual-mesh hook (CPU devices all share process 0) used by the
-    multislice tests, the weak-scaling bench (``bench.py --scaling``)
-    and the chaos drills.  Malformed mock values (non-integer,
-    non-positive, non-divisor of ``n``) raise a ``ValueError`` naming
-    the world size (:func:`_mock_slices`) — a mis-typed mock must fail
-    the bootstrap, not silently run the flat transport.
+    multislice tests and the chaos drills.  Malformed mock values
+    (non-integer, non-positive, non-divisor of ``n``) raise a
+    ``ValueError`` naming the world size (:func:`_mock_slices`) — a
+    mis-typed mock must fail the bootstrap, not silently run the flat
+    transport.
     """
     devices = list(devices if devices is not None else jax.devices())
     return contiguous_blocking(device_slice_ids(devices))

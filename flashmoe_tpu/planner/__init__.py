@@ -8,7 +8,7 @@ and measured tuning entries (:mod:`flashmoe_tpu.tuning`) into a
 predicted end-to-end latency per execution path, and a selection policy
 (predicted winner, measured-winner override) that
 ``parallel/ep.py`` / ``models/transformer.py`` (``moe_backend='auto'``)
-and ``bench.py`` consult.
+and the serving engine consult.
 
 CLI::
 

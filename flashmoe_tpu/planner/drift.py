@@ -12,7 +12,6 @@ telemetry as a ``planner.drift`` decision (plus an error histogram), and
 errors past a threshold raise a visible warning that the cost model /
 golden tables need recalibration.
 
-Wired in: ``bench.py`` records drift for every executed path;
 ``python -m flashmoe_tpu.observe`` summarizes accumulated drift records
 offline (:func:`drift_report`).
 """
@@ -193,7 +192,7 @@ def record_phase_drift(cfg: MoEConfig, path: str, phase: str,
 @dataclasses.dataclass(frozen=True)
 class OverlapDriftRecord:
     """One predicted-vs-measured overlap-fraction comparison (the
-    chunked-pipeline validation loop, ``bench.py --overlap``)."""
+    chunked-pipeline validation loop)."""
 
     path: str
     gen: str
@@ -255,61 +254,16 @@ def record_overlap_drift(path: str, measured_fraction: float, *,
     return rec
 
 
-def _as_drift_fields(rec: dict) -> dict | None:
-    """Normalize a JSONL record to drift fields, or None.
-
-    Accepts ``planner.drift`` decision records and bench.py records
-    (which carry ``predicted_ms`` / ``value`` / ``path``)."""
-    if rec.get("decision") == "planner.drift":
-        return rec
-    if ("predicted_ms" in rec and "value" in rec
-            and isinstance(rec.get("value"), (int, float))):
-        pred = rec["predicted_ms"]
-        if not isinstance(pred, (int, float)) or pred <= 0:
-            return None
-        meas = float(rec["value"])
-        return {
-            "path": rec.get("predicted_path") or rec.get("path", "?"),
-            "gen": rec.get("planner_gen", "?"),
-            "d": rec.get("d", 1),
-            "predicted_ms": float(pred),
-            "measured_ms": meas,
-            "rel_error": rec.get("prediction_error",
-                                 meas / float(pred) - 1.0),
-            "exceeded": rec.get("drift_exceeded", False),
-        }
-    return None
-
-
 def drift_report(records: list[dict]) -> dict:
-    """Summarize drift across a pile of JSONL records (decision logs,
-    bench records, flight-recorder dumps — unrecognized records are
+    """Summarize the ``planner.drift`` decisions in a pile of JSONL
+    records (decision logs, flight-recorder dumps — other records are
     skipped).  Per (path, gen): count, mean/worst |relative error|, and
     how many comparisons exceeded their threshold."""
     by_key: dict[str, dict] = {}
-    seen: set = set()
     n = exceeded = 0
-    for raw in records:
-        d = _as_drift_fields(raw)
-        if d is None:
+    for d in records:
+        if d.get("decision") != "planner.drift":
             continue
-        # bench.py mirrors each measurement into a planner.drift decision
-        # (record_drift), so an obs-dir pair (bench_records.jsonl +
-        # decisions.jsonl) presents the SAME comparison twice — dedup on
-        # the (path, gen, d, predicted, measured) identity the mirror
-        # preserves exactly.  Records without both numbers (synthetic /
-        # partial) carry no such identity and always count.
-        pred = d.get("predicted_ms")
-        meas = d.get("measured_ms")
-        if isinstance(pred, (int, float)) and pred > 0 \
-                and isinstance(meas, (int, float)) and meas > 0:
-            # 3 decimals: the coarser of the two mirrors' precisions
-            # (bench rounds value to 3, record_drift measured_ms to 4)
-            ident = (d.get("path"), d.get("gen"), d.get("d"),
-                     round(float(pred), 3), round(float(meas), 3))
-            if ident in seen:
-                continue
-            seen.add(ident)
         n += 1
         exceeded += bool(d.get("exceeded"))
         key = f"{d.get('path', '?')}@{d.get('gen', '?')}"

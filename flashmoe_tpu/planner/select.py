@@ -8,9 +8,8 @@ The policy (VERDICT r3 #4 "measured-winner", applied framework-wide):
      **predicted winner**.
   2. If measured end-to-end latencies exist for this shape — committed
      ``path_latency`` tuning entries
-     (:func:`flashmoe_tpu.tuning.measured_path_latencies`), a bench
-     records file (``FLASHMOE_BENCH_RECORDS`` pointing at bench.py
-     JSONL output), or an explicit ``measured=`` dict — the fastest
+     (:func:`flashmoe_tpu.tuning.measured_path_latencies`) or an
+     explicit ``measured=`` dict — the fastest
      *measured* path overrides the prediction (**measured winner**).
      Measurements only override for paths the predictor considers
      runnable; a stale measurement of an infeasible path is ignored.
@@ -23,16 +22,13 @@ Measurements are keyed at path-family granularity ('fused', not
 measurement of the kernel observes — the kernel resolves its own
 schedule (``MoEConfig.fused_schedule`` pins it when a measurement must
 target one schedule; the per-TILE geometry inside the rowwin schedule
-is measured separately, as ``fused_tiles`` tuning entries swept by
-``bench.py --tiles`` / ``tune_sweep.py --stage tiles``).
+is measured separately, as ``fused_tiles`` tuning entries).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-import json
-import os
 
 import jax.numpy as jnp
 
@@ -130,79 +126,10 @@ def _shape_key(cfg: MoEConfig, d: int, spec: str = "off") -> dict:
 def spec_tag(verify_tokens: int | None) -> str:
     """The measurement-identity tag of a speculative verify span:
     ``"off"`` for the plain one-token step, ``"v<k>"`` for a
-    ``verify_tokens=k`` span (rides tuning/bench/select shape keys
+    ``verify_tokens=k`` span (rides tuning/select shape keys
     like ``wire`` / ``chunks``)."""
     k = int(verify_tokens or 0)
     return f"v{k}" if k else "off"
-
-
-def _bench_record_latencies(cfg: MoEConfig, d: int,
-                            spec: str = "off") -> dict:
-    """Measured path latencies mined from a bench.py JSONL records file
-    (``FLASHMOE_BENCH_RECORDS``).  A record matches when its metric
-    string carries this exact shape signature (dtype included) AND its
-    ``d`` field matches the queried rank count — a single-chip timing
-    must never override an 8-rank selection.  ``path``/``value`` (ms)
-    name the primary measurement; ``xla_path_ms`` contributes the xla
-    leg of the same record.  Unreadable files contribute nothing."""
-    from flashmoe_tpu.ops import wire as wr
-
-    path = os.environ.get("FLASHMOE_BENCH_RECORDS")
-    if not path or not os.path.exists(path):
-        return {}
-    from flashmoe_tpu.quant import core as qcore
-
-    sig = (f"E={cfg.num_experts},k={cfg.expert_top_k},"
-           f"H={cfg.hidden_size},I={cfg.intermediate_size},"
-           f"S={cfg.tokens},{jnp.dtype(cfg.dtype).name}")
-    wire_sig = (wr.canonical_name(cfg.wire_dtype),
-                wr.canonical_name(cfg.wire_dtype_combine),
-                wr.canonical_name(cfg.wire_dtype_dcn))
-    quant_sig = qcore.canonical_name(cfg.expert_quant)
-    out: dict[str, float] = {}
-
-    def keep(p, v):
-        if p and isinstance(v, (int, float)) and v > 0:
-            out[p] = min(float(v), out.get(p, float("inf")))
-
-    try:
-        with open(path) as f:
-            for line in f:
-                try:
-                    rec = json.loads(line)
-                except ValueError:
-                    continue
-                if sig not in str(rec.get("metric", "")):
-                    continue
-                if int(rec.get("d", 1)) != d:
-                    continue
-                # wire/chunk knobs are part of the measurement's
-                # identity: a compressed or chunk-pipelined timing
-                # never overrides a selection without it (records
-                # without the fields are legacy = off / serial)
-                if (str(rec.get("wire_dtype", "off")),
-                        str(rec.get("wire_dtype_combine", "off")),
-                        str(rec.get("wire_dtype_dcn",
-                                    "off"))) != wire_sig:
-                    continue
-                if int(rec.get("a2a_chunks", 1) or 1) != (
-                        cfg.a2a_chunks or 1):
-                    continue
-                # quantized-store identity: a timing of int8 weights
-                # never overrides a full-precision selection (records
-                # without the field are legacy = off)
-                if str(rec.get("expert_quant", "off")) != quant_sig:
-                    continue
-                # speculative-span identity: a verify-span timing
-                # (spec="v<k>") never overrides a plain one-token
-                # decode selection, and vice versa
-                if str(rec.get("spec", "off")) != spec:
-                    continue
-                keep(rec.get("path"), rec.get("value"))
-                keep("xla", rec.get("xla_path_ms"))
-    except OSError:
-        return {}
-    return out
 
 
 #: chunk counts the auto sweep considers (filtered per shape by
@@ -235,9 +162,9 @@ def select_path(cfg: MoEConfig, d: int = 1, gen: str | None = None, *,
     """Pick the execution path for (cfg, d ranks, gen).
 
     ``measured``: explicit {path_family: ms} overrides (highest
-    precedence); the tuning table and ``FLASHMOE_BENCH_RECORDS`` are
-    consulted automatically.  ``record=False`` suppresses the telemetry
-    decision record (pure queries, e.g. the CLI's golden writer).
+    precedence); the tuning table is consulted automatically.
+    ``record=False`` suppresses the telemetry decision record (pure
+    queries, e.g. the CLI's golden writer).
 
     ``sweep_chunks``: additionally sweep the chunked-pipeline depth
     (``MoEConfig.a2a_chunks``) over :data:`CHUNK_CANDIDATES` and pick
@@ -245,7 +172,7 @@ def select_path(cfg: MoEConfig, d: int = 1, gen: str | None = None, *,
     resolution uses this; an explicit ``cfg.a2a_chunks`` pins the
     sweep to that value.  Measurements keep their chunk identity: a
     timing recorded at chunks=4 only competes inside the chunks=4
-    candidate (tuning/bench ``chunks`` keys).
+    candidate (the tuning table's ``chunks`` key).
 
     ``mode``: the pricing regime (``planner.model.predict_paths``) —
     ``'decode'`` re-shapes the config to the per-step decode batch
@@ -301,7 +228,6 @@ def select_path(cfg: MoEConfig, d: int = 1, gen: str | None = None, *,
         meas: dict[str, float] = {}
         meas.update(tuning.measured_path_latencies(
             gen, **_shape_key(cfg_n, d, spec)))
-        meas.update(_bench_record_latencies(cfg_n, d, spec))
         if measured:
             meas.update(measured)
         runnable = {p.family for p in feasible}

@@ -4,9 +4,9 @@ Writes the JSON-object flavor of the Trace Event Format — the format
 ``ui.perfetto.dev`` and ``chrome://tracing`` open directly — so a CPU
 (or TPU) phase timeline becomes a zoomable trace with zero TPU tooling:
 
-* one *process* (pid) per timeline (``bench.py --profile`` merges the
-  whole ledger matrix into one file, one pid per matrix point, named
-  via ``process_name`` metadata);
+* one *process* (pid) per timeline (``ledger.run_ledger_matrix``
+  merges the whole ledger matrix into one file, one pid per matrix
+  point, named via ``process_name`` metadata);
 * ``tid 0``: MoE phase spans (``moe.gate`` .. ``moe.combine``, chunked
   sub-slices as their own ``moe.expert.k`` slices);
 * ``tid 1``: trainer host sections (``train.*``);

@@ -25,7 +25,7 @@ modeled to.
 
 ``run_ledger_matrix`` drives the acceptance matrix — flat /
 hierarchical / ragged x {serial, chunked} x {wire off, e4m3} — on the
-virtual CPU mesh (``bench.py --profile``), writing ``ledger.jsonl`` +
+virtual CPU mesh, writing ``ledger.jsonl`` +
 ``trace.json`` artifacts that ``python -m flashmoe_tpu.observe
 --ledger`` summarizes.
 """
@@ -256,7 +256,7 @@ def phase_ledger(tl: PhaseTimeline, cfg: MoEConfig, *, d: int, gen: str,
 
 
 # ----------------------------------------------------------------------
-# The acceptance matrix (bench.py --profile / tests)
+# The acceptance matrix
 # ----------------------------------------------------------------------
 
 #: (name, ep width, dcn_inner, profiler path, planner slices)
@@ -293,7 +293,7 @@ def run_ledger_matrix(obs_dir: str | None = None, *, quick: bool = False,
     ``ledger.jsonl`` (one line per joined phase + one ``overlap``
     line per point) and ``trace.json`` (all points merged, one
     Perfetto process per point).  Returns the per-point summary
-    records (also the ``bench.py --profile`` output lines)."""
+    records."""
     import json
 
     import jax
@@ -340,7 +340,7 @@ def run_ledger_matrix(obs_dir: str | None = None, *, quick: bool = False,
                 # rows carry BOTH names: "path" is the planner's path
                 # (the planner.phase_drift join key; "collective" IS
                 # the flat transport) and "point" is the matrix point
-                # the docs/bench records speak (flat/hierarchical/
+                # the docs speak (flat/hierarchical/
                 # ragged), so either vocabulary filters ledger.jsonl
                 rows = [dict(r, point=pname) for r in rows]
                 ledger_rows.extend(rows)

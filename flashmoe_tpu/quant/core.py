@@ -50,8 +50,7 @@ _INT8_QMAX = 127.0
 
 def canonical_name(name: str | None) -> str:
     """Canonical store name ('int8' / 'e4m3'), or 'off' for ``None`` —
-    the spelling measurement keys, bench records and golden tables
-    use."""
+    the spelling measurement keys and golden tables use."""
     if name is None:
         return "off"
     key = _ALIASES.get(str(name).lower())

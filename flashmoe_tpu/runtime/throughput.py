@@ -8,9 +8,9 @@ rate-proportional expert assignment.
 
 The TPU version times the same synthetic grouped FFN through the real
 kernel path.  Iterations are chained inside one jit and two chain lengths
-differenced, which takes dispatch and readback out of the reading — see
-``bench.py`` for the same technique (never yet run on real devices:
-ROADMAP S1 replaces it with a host clock around ``block_until_ready``).
+differenced, which takes dispatch and readback out of the reading
+(never yet run on real devices: ROADMAP S1 replaces it with a host
+clock around ``block_until_ready``).
 Results are
 cached per (device-kind, config shape) since homogeneous slices need one
 probe, not one per chip — except :func:`device_rates`' per-DEVICE probes,

@@ -23,8 +23,8 @@ subsystem on the INFERENCE half of the north star (ROADMAP item 1):
 
 CLI: ``python -m flashmoe_tpu.serving`` drives a seeded multi-request
 drill and prints a JSON summary; ``python -m flashmoe_tpu.observe
---serving`` renders the serving report from the artifacts; ``python
-bench.py --serve`` sweeps offered load.  See docs/SERVING.md.
+--serving`` renders the serving report from the artifacts.  See
+docs/SERVING.md.
 """
 
 from flashmoe_tpu.serving.engine import (  # noqa: F401

@@ -1,12 +1,9 @@
-"""Seeded load generation + the offered-load sweep behind
-``bench.py --serve``.
+"""Seeded request traces for the CPU-sized serving drills and tests.
 
-Offered load is expressed as the arrival gap of the seeded trace
-(requests arrive in pairs every ``arrival_every`` engine steps —
-smaller gap = higher load).  Each sweep point drives a fresh engine on
-a CPU-sized model and emits one bench record: throughput
-(tokens/sec), TTFT/TPOT percentiles, queue depth, cache occupancy,
-evictions — the latency/throughput curve a capacity plan reads off.
+The toy model (:func:`tiny_config`), the seeded trace with staggered
+arrivals (:func:`build_requests`; requests arrive in pairs every
+``arrival_every`` engine steps), its per-replica split and merge, and
+the nearest-rank percentile the serving reports share (:func:`pctl`).
 """
 
 from __future__ import annotations
@@ -36,8 +33,8 @@ def build_requests(n: int, *, vocab: int, prompt_len: int,
     staggered arrivals (one PAIR of arrivals every ``arrival_every``
     engine steps).  ``repetitive`` tiles each prompt from a per-request
     random bigram motif instead of i.i.d. tokens — the speculative
-    sweep's trace, where the n-gram drafter has suffix matches to
-    propose from (an i.i.d. prompt never drafts, which would bench the
+    drills' trace, where the n-gram drafter has suffix matches to
+    propose from (an i.i.d. prompt never drafts, which would drive the
     no-op path)."""
     import jax
 
@@ -62,236 +59,13 @@ def build_requests(n: int, *, vocab: int, prompt_len: int,
 
 def pctl(values, q: float):
     """Nearest-rank percentile (None on empty) — THE serving
-    percentile: `bench.py --serve` records and the `observe --serving`
-    report both use this one definition, so the two surfaces can never
-    disagree about what p99 means."""
+    percentile: the `observe --serving` report and the quantile
+    sketch's exact regime both use this one definition, so no two
+    surfaces can disagree about what p99 means."""
     if not values:
         return None
     v = sorted(values)
     return round(v[min(len(v) - 1, int(q * len(v)))], 3)
-
-
-def serve_load_sweep(loads, *, n_requests: int = 8, max_batch: int = 4,
-                     prompt_len: int = 8, max_new: int = 6,
-                     seed: int = 0, page_size: int = 8,
-                     num_pages: int = 64,
-                     telemetry_port: int | None = None,
-                     speculate: int | None = None) -> list[dict]:
-    """One bench record per offered-load point (``loads``: arrival
-    gaps in engine steps, descending = rising load).  ``vs_baseline``
-    is each point's throughput relative to the LIGHTEST load measured
-    — the saturation curve.  Deterministic token streams per seed;
-    latency numbers are wall-clock.
-
-    ``telemetry_port`` (``bench.py --serve --telemetry-port N``): one
-    scrape server spans the whole sweep, resolving to the CURRENT
-    point's metrics stream; each record then carries a mid-sweep
-    ``/metrics`` self-scrape (``telemetry_scrape``: exposition size,
-    whether the TTFT/TPOT summary quantiles were present and the text
-    parsed) — the live plane drilled by the same contract tests as the
-    rest of the bench surface.
-
-    ``speculate`` (``bench.py --serve --speculate``, ISSUE 20): arm
-    speculative decoding at ``draft_tokens=speculate`` over a
-    repetitive trace and run an EQUAL-SLO baseline per point — the
-    same requests at the same offered load with speculation off — so
-    each record carries its own TPOT comparison
-    (``baseline_tpot_ms_p50/p99``), the realized ``accept_rate`` /
-    ``spec_tokens_per_step``, and ``bit_equal_to_baseline`` (the
-    exactness guarantee, asserted per point, not trusted).  The metric
-    identity gains a ``spec=kN`` tag: a speculative run's numbers must
-    never baseline a plain run's in the sentry."""
-    import time
-
-    import jax
-
-    from flashmoe_tpu.models.transformer import init_params
-    from flashmoe_tpu.serving.engine import ServeConfig, ServingEngine
-    from flashmoe_tpu.utils.telemetry import Metrics
-
-    cfg = tiny_config()
-    params = init_params(jax.random.PRNGKey(seed), cfg)
-    serve = ServeConfig(
-        max_batch=max_batch, page_size=page_size, num_pages=num_pages,
-        max_pages_per_slot=max(
-            2, -(-(prompt_len + max_new) // page_size) + 1),
-        ctx_bucket_pages=1, prompt_bucket=page_size)
-    if speculate:
-        import dataclasses
-
-        from flashmoe_tpu.serving.speculate import SpecConfig
-
-        serve = dataclasses.replace(
-            serve, speculate=SpecConfig(draft_tokens=int(speculate)))
-    holder = [Metrics()]
-    server = None
-    if telemetry_port is not None:
-        from flashmoe_tpu.telemetry_plane.server import maybe_server
-
-        server = maybe_server(telemetry_port,
-                              metrics_fn=lambda: holder[0])
-    try:
-        records = _sweep_points(loads, params, cfg, serve, holder,
-                                server, n_requests=n_requests,
-                                max_batch=max_batch,
-                                prompt_len=prompt_len, max_new=max_new,
-                                seed=seed)
-    finally:
-        if server is not None:
-            server.stop()
-    return records
-
-
-def _scrape_metrics(server) -> dict:
-    """The mid-sweep self-scrape: fetch ``/metrics`` off the live
-    server and report whether it parsed and carried the serving
-    summary quantiles."""
-    from flashmoe_tpu.telemetry_plane.server import scrape
-
-    try:
-        body, ctype = scrape(f"{server.url}/metrics")
-    except Exception as e:  # noqa: BLE001 — the record survives
-        return {"ok": False, "error": f"{type(e).__name__}: "
-                                      f"{str(e)[:120]}"}
-    return {
-        "ok": True,
-        "bytes": len(body),
-        "content_type": ctype,
-        "has_ttft_quantiles":
-            'flashmoe_serve_ttft_ms{quantile="' in body,
-        "has_tpot_quantiles":
-            'flashmoe_serve_tpot_ms{quantile="' in body,
-    }
-
-
-def _sweep_points(loads, params, cfg, serve, holder, server, *,
-                  n_requests, max_batch, prompt_len, max_new, seed):
-    import time
-
-    from flashmoe_tpu.serving.engine import ServingEngine
-    from flashmoe_tpu.utils.telemetry import Metrics
-
-    import jax
-
-    records = []
-    base_tps = None
-    spec = serve.speculate
-    for every in loads:
-        if every < 1:
-            raise ValueError(f"offered-load gap {every} must be >= 1 "
-                             f"engine step")
-        reqs, arrivals = build_requests(
-            n_requests, vocab=cfg.vocab_size, prompt_len=prompt_len,
-            max_new=max_new, seed=seed, arrival_every=int(every),
-            repetitive=spec is not None)
-        spec_rec = None
-        if spec is not None:
-            # equal-SLO baseline: the SAME trace at the SAME offered
-            # load with speculation off — the comparison each record
-            # carries, and the oracle the exactness assert checks
-            # against
-            import dataclasses as _dc
-
-            bmx = Metrics()
-            b_eng = ServingEngine(
-                params, cfg, _dc.replace(serve, speculate=None),
-                metrics_obj=bmx)
-            b_eng.run(list(reqs), list(arrivals))
-            b_ret = [d for d in bmx.decisions
-                     if d.get("decision") == "serve.retire"]
-            spec_rec = {
-                "baseline_outputs": dict(b_eng.outputs),
-                "baseline_tpot_ms_p50": pctl(
-                    [d["tpot_ms"] for d in b_ret
-                     if d.get("tpot_ms") is not None], 0.5),
-                "baseline_tpot_ms_p99": pctl(
-                    [d["tpot_ms"] for d in b_ret
-                     if d.get("tpot_ms") is not None], 0.99),
-            }
-        mx = Metrics()   # private stream per point: clean retire stats
-        holder[0] = mx   # the live server scrapes THIS point now
-        engine = ServingEngine(params, cfg, serve, metrics_obj=mx)
-        t0 = time.monotonic()
-        scrape_rec = None
-        scrape_pause_s = 0.0
-        if server is not None:
-            # drive until the first retirement seeds the TTFT/TPOT
-            # sketches, scrape MID-DRILL (work still in flight), then
-            # run to completion — the live-plane acceptance: the
-            # scrape must carry the serving summary quantiles.  Both
-            # legs go through engine.run() (its max_steps wedge guard
-            # applies: a starved queue fails fast, never spins).  The
-            # scrape pause is EXCLUDED from the timed window so the
-            # throughput number stays comparable with a plain sweep —
-            # and the record's identity key is still tagged
-            # ``telemetry`` below, so the sentry never baselines an
-            # armed run against an unarmed one.
-            engine.run(reqs, arrivals,
-                       until=lambda: "serve.ttft_ms" in mx.sketches)
-            t_pause = time.monotonic()
-            scrape_rec = _scrape_metrics(server)
-            scrape_pause_s = time.monotonic() - t_pause
-            engine.run()
-        else:
-            engine.run(reqs, arrivals)
-        wall_s = max(time.monotonic() - t0 - scrape_pause_s, 1e-9)
-        s = engine.summary()
-        tps = s["tokens"] / wall_s
-        base_tps = base_tps if base_tps is not None else tps
-        retires = [d for d in mx.decisions
-                   if d.get("decision") == "serve.retire"]
-        ttfts = [d["ttft_ms"] for d in retires
-                 if d.get("ttft_ms") is not None]
-        tpots = [d["tpot_ms"] for d in retires
-                 if d.get("tpot_ms") is not None]
-        # telemetry arming rides the measurement identity: an armed
-        # run's numbers never baseline an unarmed run's in the sentry
-        tag = ",telemetry" if server is not None else ""
-        if spec is not None:
-            tag += f",spec=k{spec.draft_tokens}"
-        records.append({
-            "metric": f"serve_load[every={every},B={max_batch},"
-                      f"req={n_requests}{tag}]",
-            "value": round(tps, 1),
-            "unit": "tokens_per_sec",
-            "vs_baseline": round(tps / base_tps, 3) if base_tps
-            else None,
-            "offered_every_steps": int(every),
-            "completed": s["completed"],
-            "tokens": s["tokens"],
-            "steps": s["steps"],
-            "ttft_ms_p50": pctl(ttfts, 0.5),
-            "ttft_ms_p99": pctl(ttfts, 0.99),
-            "tpot_ms_p50": pctl(tpots, 0.5),
-            "tpot_ms_p99": pctl(tpots, 0.99),
-            "queue_depth_max": s["max_queue_depth"],
-            "cache_occupancy_peak": round(s["peak_occupancy"], 4),
-            "evictions": s["evictions"],
-            "decode_plan": s["decode_plan"],
-            "backend": jax.default_backend(),
-        })
-        if scrape_rec is not None:
-            records[-1]["telemetry_scrape"] = scrape_rec
-            records[-1]["telemetry_port"] = server.port
-        if spec_rec is not None:
-            snap = engine.spec_snapshot()
-            bit_equal = dict(engine.outputs) \
-                == spec_rec.pop("baseline_outputs")
-            records[-1].update(spec_rec)
-            records[-1].update({
-                "accept_rate": snap["accept_rate"],
-                "spec_tokens_per_step": snap["spec_tokens_per_step"],
-                "spec_drafted": snap["spec_drafted"],
-                "spec_accepted": snap["spec_accepted"],
-                "bit_equal_to_baseline": bit_equal,
-            })
-            if not bit_equal:
-                # exactness is the whole contract — a diverged stream
-                # is a broken run, not a data point
-                raise AssertionError(
-                    f"speculative decode diverged from baseline at "
-                    f"load point every={every}")
-    return records
 
 
 def split_requests(n: int, *, replicas: int, vocab: int,
@@ -335,378 +109,3 @@ def merge_traces(splits):
         merged.extend(zip(arrivals, reqs))
     merged.sort(key=lambda p: (p[0], p[1].rid))
     return [q for _, q in merged], [a for a, _ in merged]
-
-
-def fabric_load_sweep(loads, *, replica_counts=(1, 2, 4),
-                      n_requests: int = 8, max_batch: int = 4,
-                      prompt_len: int = 8, max_new: int = 6,
-                      seed: int = 0, page_size: int = 8,
-                      num_pages: int = 64,
-                      telemetry_port: int | None = None,
-                      vclock: bool = False,
-                      wire: str = "inproc") -> list[dict]:
-    """The ``bench.py --fabric`` sweep: one record per (replica count,
-    offered-load point), each driving a fresh
-    :class:`~flashmoe_tpu.fabric.engine.ServingFabric` on the mocked
-    ``FLASHMOE_MOCK_FABRIC`` blocking (set per point, restored on
-    exit) with the :func:`split_requests` trace for that width.  Each
-    record carries throughput, TTFT/TPOT percentiles, handoff count
-    and modeled DCN cost, and the router's placement histogram;
-    ``vs_baseline`` is relative to the same replica count's lightest
-    load (the per-width saturation curve) and ``vs_single`` to the
-    1-replica fabric at the same load (the scale-out curve).
-
-    ``telemetry_port`` arms one scrape server for the whole sweep and
-    self-scrapes ``/metrics`` mid-drill into each record — the fabric
-    acceptance's live-plane leg.
-
-    ``vclock`` (``bench.py --fabric --vclock``): each point steps on a
-    :class:`~flashmoe_tpu.fabric.vclock.VirtualClock` behind a
-    :class:`~flashmoe_tpu.fabric.frontdoor.FrontDoor` — requests come
-    from :func:`build_requests` directly (the front door owns the
-    trace namespace; no per-replica pre-split), the TTFT/TPOT
-    percentiles are MEASURED UNDER the modeled DCN delay, and each
-    record adds the measured-vs-priced handoff fields plus the
-    per-request attribution rollup.  The record identity gains a
-    ``vclock`` tag so the perf sentry never baselines virtual-time
-    latencies against wall-clock ones.
-
-    ``wire`` (``bench.py --fabric --wire tcp``): every KV handoff
-    crosses a REAL localhost socket through a CRC-verifying
-    :class:`~flashmoe_tpu.fabric.transport.HandoffTransport` instead
-    of the in-process wire.  Tokens stay bit-identical (the wire is a
-    byte codec); the record identity gains a ``wire=tcp`` tag so the
-    sentry baselines socket and in-process throughput separately."""
-    import os
-    import time
-
-    import jax
-
-    from flashmoe_tpu.fabric.engine import ServingFabric
-    from flashmoe_tpu.fabric.topo import ENV_MOCK_FABRIC
-    from flashmoe_tpu.fabric.transport import WIRE_MODES
-    from flashmoe_tpu.models.transformer import init_params
-    from flashmoe_tpu.serving.engine import ServeConfig
-    from flashmoe_tpu.utils.telemetry import Metrics
-
-    if wire not in WIRE_MODES:
-        raise ValueError(f"wire {wire!r} not in {WIRE_MODES}")
-    cfg = tiny_config()
-    params = init_params(jax.random.PRNGKey(seed), cfg)
-    serve = ServeConfig(
-        max_batch=max_batch, page_size=page_size, num_pages=num_pages,
-        max_pages_per_slot=max(
-            2, -(-(prompt_len + max_new) // page_size) + 1),
-        ctx_bucket_pages=1, prompt_bucket=page_size)
-    holder = [Metrics()]
-    server = None
-    if telemetry_port is not None:
-        from flashmoe_tpu.telemetry_plane.server import maybe_server
-
-        server = maybe_server(telemetry_port,
-                              metrics_fn=lambda: holder[0])
-    records = []
-    single_tps: dict = {}       # every -> 1-replica tokens/sec
-    saved = os.environ.get(ENV_MOCK_FABRIC)
-    try:
-        for k in replica_counts:
-            if k < 1:
-                raise ValueError(f"replica count {k} must be >= 1")
-            os.environ[ENV_MOCK_FABRIC] = str(int(k))
-            base_tps = None
-            for every in loads:
-                if every < 1:
-                    raise ValueError(f"offered-load gap {every} must "
-                                     f"be >= 1 engine step")
-                if vclock:
-                    # the front door owns the namespace: ONE global
-                    # trace, no per-replica pre-split of rids/seeds
-                    reqs, arrivals = build_requests(
-                        n_requests, vocab=cfg.vocab_size,
-                        prompt_len=prompt_len, max_new=max_new,
-                        seed=seed, arrival_every=int(every))
-                else:
-                    reqs, arrivals = merge_traces(split_requests(
-                        n_requests, replicas=int(k),
-                        vocab=cfg.vocab_size, prompt_len=prompt_len,
-                        max_new=max_new, seed=seed,
-                        arrival_every=int(every)))
-                mx = Metrics()
-                holder[0] = mx
-                vc = door = None
-                if vclock:
-                    from flashmoe_tpu.fabric.frontdoor import FrontDoor
-                    from flashmoe_tpu.fabric.vclock import VirtualClock
-
-                    vc = VirtualClock()
-                transport = None
-                if wire == "tcp":
-                    from flashmoe_tpu.fabric.transport import (
-                        HandoffTransport,
-                    )
-
-                    transport = HandoffTransport(metrics_obj=mx,
-                                                 wire="tcp")
-                fab = ServingFabric(params, cfg, serve, metrics_obj=mx,
-                                    vclock=vc, transport=transport)
-                driver = fab
-                if vclock:
-                    door = FrontDoor(fab)
-                    driver = door
-                t0 = time.monotonic()
-                scrape_rec = None
-                scrape_pause_s = 0.0
-                if server is not None:
-                    driver.run(reqs, arrivals,
-                               until=lambda: "serve.ttft_ms"
-                               in mx.sketches)
-                    t_pause = time.monotonic()
-                    scrape_rec = _scrape_metrics(server)
-                    scrape_pause_s = time.monotonic() - t_pause
-                    driver.run()
-                else:
-                    driver.run(reqs, arrivals)
-                wall_s = max(time.monotonic() - t0 - scrape_pause_s,
-                             1e-9)
-                s = fab.summary()
-                tokens = sum(e["tokens"] for e in s["engines"])
-                tps = tokens / wall_s
-                base_tps = base_tps if base_tps is not None else tps
-                if int(k) == 1:
-                    single_tps[int(every)] = tps
-                retires = [d for d in mx.decisions
-                           if d.get("decision") == "serve.retire"]
-                ttfts = [d["ttft_ms"] for d in retires
-                         if d.get("ttft_ms") is not None]
-                tpots = [d["tpot_ms"] for d in retires
-                         if d.get("tpot_ms") is not None]
-                tag = ",telemetry" if server is not None else ""
-                if vclock:
-                    tag += ",vclock"
-                if wire != "inproc":
-                    tag += f",wire={wire}"
-                rec = {
-                    "metric": f"fabric_load[replicas={int(k)},"
-                              f"every={int(every)},"
-                              f"req={n_requests}{tag}]",
-                    "value": round(tps, 1),
-                    "unit": "tokens_per_sec",
-                    "vs_baseline": (round(tps / base_tps, 3)
-                                    if base_tps else None),
-                    "vs_single": (round(
-                        tps / single_tps[int(every)], 3)
-                        if single_tps.get(int(every)) else None),
-                    "replicas": int(k),
-                    "offered_every_steps": int(every),
-                    "completed": sum(e["completed"]
-                                     for e in s["engines"]),
-                    "tokens": tokens,
-                    "steps": s["steps"],
-                    "handoffs": s["handoffs"],
-                    "handoff_kb": round(s["handoff_bytes"] / 1024, 3),
-                    "handoff_ms_modeled": round(
-                        fab.handoff.modeled_ms_total, 6),
-                    "routed": s["routed"],
-                    "evictions": sum(e["evictions"]
-                                     for e in s["engines"]),
-                    "ttft_ms_p50": pctl(ttfts, 0.5),
-                    "ttft_ms_p99": pctl(ttfts, 0.99),
-                    "tpot_ms_p50": pctl(tpots, 0.5),
-                    "tpot_ms_p99": pctl(tpots, 0.99),
-                    "pools_formed": fab.pool_plan is not None,
-                    "backend": jax.default_backend(),
-                }
-                if scrape_rec is not None:
-                    rec["telemetry_scrape"] = scrape_rec
-                    rec["telemetry_port"] = server.port
-                if door is not None:
-                    # the measured-latency leg: TTFT/TPOT above are
-                    # VIRTUAL-time numbers (under the priced DCN
-                    # delay); these fields reconcile them against the
-                    # planner's verdicts and the attribution gate
-                    att = door.attribution()
-                    errs = door.validate()
-                    rec["vclock"] = True
-                    rec["tick_ms"] = (round(vc.tick_ms, 6)
-                                      if vc.tick_ms is not None
-                                      else None)
-                    rec["handoff_ms_measured"] = round(
-                        fab.handoff.measured_ms_total, 6)
-                    rec["handoff_hidden_frac"] = (
-                        round(fab.handoff.hidden_ms_total
-                              / fab.handoff.measured_ms_total, 6)
-                        if fab.handoff.measured_ms_total > 0 else None)
-                    rec["handoff_verdicts_agree"] = \
-                        fab.handoff.drift_agree
-                    rec["handoff_verdicts_total"] = \
-                        fab.handoff.drift_total
-                    rec["attribution_sum_ok"] = bool(
-                        att and all(a["sum_ok"] for a in att.values()))
-                    rec["attribution_max_rel_err"] = (
-                        max(a["rel_err"] for a in att.values())
-                        if att else None)
-                    doms = [a["dominant"] for a in att.values()]
-                    rec["attribution_dominant"] = {
-                        d: doms.count(d) for d in sorted(set(doms))}
-                    rec["trace_errors"] = len(errs)
-                    door.close()
-                if transport is not None:
-                    # socket-wire provenance: real roundtrips + any
-                    # real connection resets the ladder absorbed
-                    rec["wire"] = wire
-                    rec["wire_transfers"] = transport.transfers
-                    rec["wire_resets"] = transport.reset_total
-                records.append(rec)
-                fab.close()
-                if transport is not None:
-                    transport.close()
-    finally:
-        if saved is None:
-            os.environ.pop(ENV_MOCK_FABRIC, None)
-        else:
-            os.environ[ENV_MOCK_FABRIC] = saved
-        if server is not None:
-            server.stop()
-    return records
-
-
-#: the serving fault-tolerance ladder drilled by ``--fabric --faults``
-#: (chaos.EXPECTED_TIER owns the fault -> recovery-tier mapping)
-SERVING_FAULTS = ("replica_crash", "handoff_corrupt",
-                  "handoff_timeout", "frontdoor_loss",
-                  "net_partition", "lease_split_brain",
-                  "replica_stall", "lease_torn_write")
-
-
-def fabric_fault_sweep(faults=None, *, seed: int = 0,
-                       include_brownout: bool = True) -> list[dict]:
-    """The ``bench.py --fabric --faults`` sweep: one record per
-    serving fault, each running that fault's chaos drill
-    (:func:`flashmoe_tpu.chaos.drill.run_drill`) against a mocked
-    2-replica fabric and reporting the recovery ledger — wall-clock
-    recovery latency as the headline value plus migrated-request
-    count, handoff retry/corrupt totals, front-door failovers, and
-    the trace-contiguity verdict.  A drill that does not recover
-    carries ``error`` so the perf sentry never baselines a broken
-    run's latency.
-
-    ``include_brownout`` appends one more record: a seeded flood
-    through a brownout-armed :class:`~flashmoe_tpu.fabric.frontdoor.
-    FrontDoor` on the virtual clock, whose headline value is the shed
-    fraction (``unit: frac`` — admissions rejected / offered)."""
-    import jax
-
-    from flashmoe_tpu.chaos.drill import run_drill
-
-    faults = tuple(faults) if faults is not None else SERVING_FAULTS
-    bad = [f for f in faults if f not in SERVING_FAULTS]
-    if bad:
-        raise ValueError(f"not serving faults: {bad} "
-                         f"(choose from {SERVING_FAULTS})")
-    records = []
-    for fault in faults:
-        r = run_drill(fault, seed=seed)
-        ev = r.evidence
-        rec = {
-            "metric": f"fabric_fault[{fault}]",
-            "value": round(r.wall_s * 1e3, 1),
-            "unit": "ms",
-            "fault": fault,
-            "tier": r.expected_tier,
-            "recovered": r.recovered,
-            "completed": ev.get("completed", 0),
-            "bit_equal": ev.get("bit_equal_to_baseline", False),
-            "migrated": ev.get("migrations", 0),
-            "retries": ev.get("retries", 0),
-            "corrupt": ev.get("corrupt", 0),
-            "failovers": ev.get("failovers", 0),
-            "partitions": ev.get("partitions", 0),
-            "fences": ev.get("fences", 0),
-            "lease_repairs": ev.get("lease_repairs", 0),
-            "shed_frac": 0.0,   # fault drills never shed; the brownout
-            "trace_errors": len(ev.get("trace_errors") or []),
-            "backend": jax.default_backend(),
-        }
-        # sub-step detection latency (virtual ms from the hang to the
-        # watchdog's verdict) — only the heartbeat drill prices one
-        stalls = [d for d in r.decisions
-                  if d.get("decision") == "fabric.heartbeat_stall"]
-        if stalls:
-            rec["heartbeat_detect_ms"] = round(
-                max(d.get("detect_ms", 0.0) for d in stalls), 6)
-        if not r.recovered:
-            rec["error"] = r.reason[:200]
-        records.append(rec)
-    if include_brownout:
-        records.append(_brownout_shed_record(seed=seed))
-    return records
-
-
-def _brownout_shed_record(*, seed: int = 0) -> dict:
-    """One deterministic brownout drill: a seeded flood against the
-    hysteretic admission controller on the virtual clock (shed
-    decisions depend only on queue depth and step index — bit-stable
-    across machines)."""
-    import os
-    import time
-
-    import jax
-
-    from flashmoe_tpu.fabric.engine import ServingFabric
-    from flashmoe_tpu.fabric.frontdoor import FrontDoor
-    from flashmoe_tpu.fabric.topo import ENV_MOCK_FABRIC
-    from flashmoe_tpu.fabric.vclock import VirtualClock
-    from flashmoe_tpu.models.transformer import init_params
-    from flashmoe_tpu.runtime.controller import BrownoutConfig
-    from flashmoe_tpu.serving.engine import ServeConfig
-    from flashmoe_tpu.utils.telemetry import Metrics
-
-    cfg = tiny_config()
-    params = init_params(jax.random.PRNGKey(seed), cfg)
-    serve = ServeConfig(
-        max_batch=2, page_size=8, num_pages=64, max_pages_per_slot=4,
-        ctx_bucket_pages=1, prompt_bucket=8)
-    flood, _ = build_requests(10, vocab=cfg.vocab_size, prompt_len=8,
-                              max_new=6, seed=seed + 1,
-                              arrival_every=1)
-    # front-loaded arrivals: the burst trips the threshold, the tail
-    # arrives while the brownout holds
-    arrivals = [0, 0, 0, 0, 2, 2, 3, 3, 4, 5]
-    bo = BrownoutConfig(queue_high=2.0, queue_low=0.5,
-                        debounce_steps=1, cooldown_steps=2,
-                        episode_budget=2)
-    mx = Metrics()
-    saved = os.environ.get(ENV_MOCK_FABRIC)
-    os.environ[ENV_MOCK_FABRIC] = "2"
-    fab = door = None
-    t0 = time.perf_counter()
-    try:
-        fab = ServingFabric(params, cfg, serve, metrics_obj=mx,
-                            vclock=VirtualClock())
-        door = FrontDoor(fab, brownout=bo)
-        out = door.run(flood, arrivals)
-        errs = door.validate()
-        snap = door.brownout_snapshot()
-    finally:
-        if door is not None:
-            door.close()
-        if fab is not None:
-            fab.close()
-        if saved is None:
-            os.environ.pop(ENV_MOCK_FABRIC, None)
-        else:
-            os.environ[ENV_MOCK_FABRIC] = saved
-    wall_ms = (time.perf_counter() - t0) * 1e3
-    return {
-        "metric": "fabric_shed[brownout]",
-        "value": round(snap["shed"] / len(flood), 4),
-        "unit": "frac",
-        "offered": len(flood),
-        "completed": len(out),
-        "shed": snap["shed"],
-        "degraded": snap["degraded"],
-        "episodes": snap["episodes"],
-        "trace_errors": len(errs),
-        "wall_ms": round(wall_ms, 1),
-        "backend": jax.default_backend(),
-    }

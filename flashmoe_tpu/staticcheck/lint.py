@@ -30,8 +30,9 @@ pytest-in-pytest:
   (which now thinly wraps this engine): tests that run chaos drills
   (any test file) or execute shard_map MoE layers (files listed in
   ``SHARD_MAP_EXEC_FILES``; ``jax.make_jaxpr`` tracing is exempt — it
-  is exactly what this package does) must carry ``@pytest.mark.slow``
-  so the tier-1 gate stays inside its 870s budget (ROADMAP.md).
+  is exactly what this package does) must carry ``@pytest.mark.slow``.
+  ``slow`` means multi-process tests, drills and any case over 40 s;
+  the gate itself executes the mesh paths in the other test files.
 """
 
 from __future__ import annotations
@@ -173,7 +174,7 @@ def check_slow_marks(test_files=None) -> list[Violation]:
                     "lint", "slow-mark", f"{name}::{fn.name}",
                     "runs a chaos drill (a full resilient training "
                     "job) without @pytest.mark.slow — drills belong "
-                    "outside the fast gate (ROADMAP.md tier-1 budget)"))
+                    "outside the tier-1 gate (ROADMAP.md)"))
             if strict and called & SHARD_MAP_CALLS \
                     and "make_jaxpr" not in called \
                     and not _is_slow_marked(fn):

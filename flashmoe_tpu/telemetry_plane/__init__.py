@@ -27,11 +27,6 @@ that story (docs/OBSERVABILITY.md "Live telemetry plane"):
   active knobs).  Default off everywhere = zero threads = byte-identical
   behavior; armed via ``--telemetry-port`` on the train and serving
   CLIs.  Per-host JSONL shard helpers feed ``observe --merge``.
-* :mod:`flashmoe_tpu.telemetry_plane.regression` — the perf-regression
-  sentry: per-run metric summaries persisted to ``obs/history.jsonl``
-  keyed by the bench/serving measurement-identity strings, compared
-  against a rolling baseline by ``python -m flashmoe_tpu.observe
-  --regression`` (``regress.detected`` decision, rc 2 under ``--ci``).
 
 Import the submodules directly — this ``__init__`` stays import-light
 (the sketch is pulled lazily by :class:`Metrics` on first use).

@@ -17,8 +17,8 @@ framework byte-identical):
   active config knobs (``vars_fn``), the "what is this job actually
   running" page.
 
-Arming: ``--telemetry-port N`` on ``python -m flashmoe_tpu.serving``,
-``python -m flashmoe_tpu.runtime.train_cli``, and ``bench.py --serve``;
+Arming: ``--telemetry-port N`` on ``python -m flashmoe_tpu.serving``
+and ``python -m flashmoe_tpu.runtime.train_cli``;
 programmatically via :class:`TelemetryServer` (context manager) or the
 ``telemetry_port=`` argument on ``ServingEngine`` / ``train`` /
 ``resilient_train`` / ``supervise``.  Port 0 binds an ephemeral port
@@ -57,8 +57,8 @@ def host_shard_path(obs_dir: str, host: str | None = None) -> str:
 
 class TelemetryServer:
     """Background scrape server.  ``metrics_fn`` resolves the
-    :class:`Metrics` registry per request (a zero-arg callable, so bench
-    sweeps can rotate per-point streams under one server); ``health_fn``
+    :class:`Metrics` registry per request (a zero-arg callable, so a
+    caller can rotate streams under one server); ``health_fn``
     / ``vars_fn`` return JSON-serializable dicts (both optional —
     ``/healthz`` always answers with at least ``{"ok": true}``)."""
 
@@ -160,12 +160,3 @@ def maybe_server(port: int | None, **kw) -> TelemetryServer | None:
     if port is None:
         return None
     return TelemetryServer(int(port), **kw).start()
-
-
-def scrape(url: str, timeout_s: float = 5.0) -> tuple[str, str]:
-    """GET one endpoint; returns (body, content_type).  Stdlib only —
-    the bench sweep and the tests share this one scraper."""
-    import urllib.request
-
-    with urllib.request.urlopen(url, timeout=timeout_s) as r:
-        return (r.read().decode(), r.headers.get("Content-Type", ""))

@@ -7,10 +7,11 @@ this table: measured winners for the Pallas kernels' block sizes keyed by
 (generation, kernel, shape), consulted at trace time, with the existing
 size-derived heuristics as the fallback when no measurement matches.
 
-The table is populated by ``scripts/tune_sweep.py`` running on real
-hardware (winners are committed to ``flashmoe_tpu/tuning_data/<gen>.json``
-so they ship with the package); entries are ignored with a warning if
-they stopped dividing the shapes they claim to match.
+No table has been measured yet: the heuristics apply.  A table of
+winners measured on real hardware is committed to
+``flashmoe_tpu/tuning_data/<gen>.json`` (:func:`save_entries`) so it ships
+with the package; entries are ignored with a warning if they stopped
+dividing the shapes they claim to match.
 
 Knobs per kernel family:
 
@@ -30,8 +31,7 @@ Knobs per kernel family:
                  (``parallel/fused.py:_rowwin_tiles``) — a measured
                  entry overrides the analytic minimum-HBM-traffic pick
                  when it still divides the shapes; the VMEM budget gate
-                 is never overridable.  Swept by ``tune_sweep.py
-                 --stage tiles`` / ``bench.py --tiles``.
+                 is never overridable.
 
 Committed tables must pass :func:`validate_entries` — a malformed table
 fails ``tests/test_tuning.py`` in CI instead of being silently ignored
@@ -136,8 +136,8 @@ def measured_path_latencies(gen: str | None = None, **shape) -> dict:
 
     The planner's measured-winner override
     (:mod:`flashmoe_tpu.planner.select`) consults this: a committed
-    bench/tune_sweep measurement beats any prediction for the paths it
-    covers.  Unknown shapes return {} and the roofline prediction stands.
+    measurement beats any prediction for the paths it covers.  Unknown
+    shapes return {} and the roofline prediction stands.
     """
     gen = gen or generation()
     best: dict[str, tuple[int, float]] = {}
@@ -284,7 +284,7 @@ def validate_table(path: str) -> list[str]:
 
 
 def save_entries(gen: str, entries: list, path: str | None = None) -> str:
-    """Write a measured table (used by scripts/tune_sweep.py).  Replaces
+    """Write a measured table.  Replaces
     existing entries for the same (kernel, match) keys, keeps others."""
     path = path or os.path.join(_DATA_DIR, f"{gen}.json")
     os.makedirs(os.path.dirname(path), exist_ok=True)

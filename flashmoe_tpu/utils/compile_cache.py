@@ -6,8 +6,8 @@ nothing is set here.  Unset, the cache goes to one fixed directory inside
 the checkout — the path is part of the cache's key, so a directory named
 after a pid, a time or a temp dir would never hit.
 
-Called by the programs (``chip_smoke.py``, ``bench.py``, the train and
-serve CLIs, the worker) before their first compile, never by a library
+Called by the programs (``chip_smoke.py``, ``benchmark/run.py``, the train
+and serve CLIs, the worker) before their first compile, never by a library
 module and never by ``tests/conftest.py``.
 """
 

@@ -162,9 +162,6 @@ DECISION_NAMES: dict[str, str] = {
         "graceful drain completed: final step, remaining grace",
     "preempt.notice":
         "a preemption notice arrived (signal source, grace budget)",
-    "regress.detected":
-        "the perf sentry found a metric past its tolerance vs the "
-        "rolling baseline in obs/history.jsonl",
     "planner.phase_drift":
         "one MoE phase's measured time compared against its prediction",
     "postmortem.saved":
@@ -896,7 +893,7 @@ class Metrics:
 
     def dump_decisions_jsonl(self, path: str, start: int = 0) -> int:
         """Append recorded decisions (full breakdowns) as JSONL from
-        index ``start`` on — callers that flush repeatedly (bench sweeps)
+        index ``start`` on — callers that flush repeatedly
         pass the previous return value so no decision is written twice.
         Returns the total decision count (the next call's ``start``)."""
         with open(path, "a") as f:

@@ -19,7 +19,6 @@ CFG = MoEConfig(num_experts=8, expert_top_k=2, hidden_size=128,
                 dtype=jnp.float32, param_dtype=jnp.float32)
 
 
-@pytest.mark.slow
 def test_save_restore_roundtrip(devices, tmp_path):
     mesh = make_mesh(CFG)
     opt = make_optimizer(CFG, total_steps=4)

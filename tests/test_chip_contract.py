@@ -6,7 +6,6 @@ the compile cache is placed from outside; one process owns a host's chips;
 the chip's compiler forced into the kernels (VMEM-fitted chunks, the flash
 attention VJP, the interpreter cure)."""
 
-import importlib.util
 import json
 import os
 import subprocess
@@ -18,86 +17,6 @@ import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-# ---- no TPU where one is needed ---------------------------------------
-
-@pytest.mark.parametrize("argv,env", [
-    ([], {}),
-    (["--tiles", "--config", "mixtral"], {}),
-    (["--quant", "--config", "mixtral"], {}),
-    (["--sweep", "tokens"], {}),
-    (["--scaling"], {"FLASHMOE_OVERLAP_TPU": "1"}),
-    (["--fabric"], {"FLASHMOE_OVERLAP_TPU": "1"}),
-], ids=["headline", "tiles", "quant", "sweep-tokens", "scaling-on-chips",
-        "fabric-on-chips"])
-def test_bench_without_a_tpu_fails_and_never_skips(argv, env, monkeypatch,
-                                                   capsys):
-    """The modes that time the chip, run where JAX finds only the CPU:
-    one error record, rc 2, no ``skipped`` key and no measurement."""
-    import bench
-
-    for k, v in env.items():
-        monkeypatch.setenv(k, v)
-    monkeypatch.setattr(sys, "argv", ["bench.py", *argv])
-    with pytest.raises(SystemExit) as e:
-        bench.main()
-    assert e.value.code == 2
-    out = capsys.readouterr().out.strip().splitlines()
-    assert len(out) == 1
-    rec = json.loads(out[0])
-    assert rec["value"] == -1 and "skipped" not in rec
-    assert "needs a TPU" in rec["error"] and "'cpu'" in rec["error"]
-
-
-def test_bench_has_no_probe_left():
-    import bench
-
-    assert not [n for n in dir(bench) if "probe" in n.lower()]
-    with open(os.path.join(ROOT, "bench.py")) as f:
-        src = f.read()
-    assert "--probe" not in src and '"skipped": True, "reason": info' \
-        not in src
-
-
-def test_bench_dead_backend_is_an_error_record():
-    """A platform that does not exist: rc 2 and an error record, at once."""
-    env = {**os.environ, "JAX_PLATFORMS": "definitely_not_a_platform"}
-    r = subprocess.run([sys.executable, "bench.py", "--deadline", "30"],
-                       capture_output=True, text=True, timeout=120,
-                       cwd=ROOT, env=env)
-    rec = json.loads(r.stdout.strip().splitlines()[-1])
-    assert r.returncode == 2
-    assert rec["value"] == -1 and "error" in rec and "skipped" not in rec
-
-
-def test_tune_sweep_without_a_tpu_fails(capsys):
-    spec = importlib.util.spec_from_file_location(
-        "tune_sweep", os.path.join(ROOT, "scripts", "tune_sweep.py"))
-    ts = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ts)
-    with pytest.raises(SystemExit) as e:
-        ts.main(["--stage", "tiles"])
-    assert e.value.code == 2
-    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rec["value"] == -1 and "skipped" not in rec
-    assert "needs a TPU" in rec["error"]
-
-
-def test_bench_says_when_it_folds_ep(capsys):
-    """A preset whose ep exceeds the devices present is timed at ep=1 and
-    the record says so (``ep_folded_from``)."""
-    import bench
-    from flashmoe_tpu.config import BENCH_CONFIGS
-
-    bench._PARTIAL.clear()
-    bench._emit(BENCH_CONFIGS["mixtral"].replace(ep=1), "mixtral/S=1024",
-                1e-3, 2e-3)
-    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rec["ep_folded_from"] == 8
-    bench._emit(BENCH_CONFIGS["reference"], "reference", 1e-3, 2e-3)
-    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert "ep_folded_from" not in rec
 
 
 # ---- the device is read, never assumed --------------------------------
@@ -134,15 +53,6 @@ def test_unknown_generation_is_never_priced_as_another(fn):
     for gen in ("default", "v9"):
         with pytest.raises(ValueError, match=gen):
             getattr(topology, fn)(gen)
-
-
-def test_bench_mxu_util_raises_on_an_unknown_tpu(monkeypatch):
-    import bench
-    from flashmoe_tpu.config import BENCH_CONFIGS
-
-    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("TPU v9 mega")])
-    with pytest.raises(ValueError, match="TPU v9 mega"):
-        bench._mxu_util(BENCH_CONFIGS["reference"], 1e-3)
 
 
 def test_tuning_generation_reads_the_device(monkeypatch):
@@ -193,7 +103,8 @@ def test_conftest_leaves_the_compile_cache_off():
 
 
 @pytest.mark.parametrize("path", [
-    "chip_smoke.py", "bench.py", "flashmoe_tpu/runtime/train_cli.py",
+    "chip_smoke.py", "benchmark/run.py",
+    "flashmoe_tpu/runtime/train_cli.py",
     "flashmoe_tpu/serving/__main__.py", "flashmoe_tpu/runtime/worker.py"])
 def test_programs_turn_the_cache_on(path):
     with open(os.path.join(ROOT, path)) as f:

@@ -27,7 +27,7 @@ def _hermetic(monkeypatch):
     from flashmoe_tpu.planner.select import _cached_backend
 
     for var in ("FLASHMOE_TUNING_FILE", "FLASHMOE_TPU_GEN",
-                "FLASHMOE_BENCH_RECORDS", "FLASHMOE_MOCK_SLICES"):
+                "FLASHMOE_MOCK_SLICES"):
         monkeypatch.delenv(var, raising=False)
     tuning._load.cache_clear()
     _cached_backend.cache_clear()
@@ -339,11 +339,9 @@ def test_auto_layer_threads_chunk_pick(monkeypatch, devices):
 
 def test_measured_override_keyed_by_chunks(tmp_path, monkeypatch):
     """A path latency measured at chunks=4 never overrides a serial
-    selection (and vice versa) — tuning table and bench records."""
+    selection (and vice versa)."""
     from flashmoe_tpu import tuning
-    from flashmoe_tpu.planner.select import (
-        _bench_record_latencies, _cached_backend, select_path,
-    )
+    from flashmoe_tpu.planner.select import _cached_backend, select_path
 
     shape = dict(h=REF.hidden_size, i=REF.intermediate_size, d=8)
     tbl = tmp_path / "table.json"
@@ -369,22 +367,6 @@ def test_measured_override_keyed_by_chunks(tmp_path, monkeypatch):
     sel = select_path(REF, 8, "v5e", record=False, sweep_chunks=True)
     assert (sel.mode, sel.winner) == ("measured", "ragged")
     assert sel.a2a_chunks == 4 and sel.measured_ms == 0.0001
-
-    # bench records: a2a_chunks field keys the same way
-    metric = (f"moe_layer_fwd_ms[x:E={REF.num_experts},"
-              f"k={REF.expert_top_k},H={REF.hidden_size},"
-              f"I={REF.intermediate_size},S={REF.tokens},bfloat16]")
-    p = tmp_path / "bench.jsonl"
-    p.write_text(json.dumps(
-        {"metric": metric, "path": "collective", "value": 0.5, "d": 8,
-         "a2a_chunks": 4}) + "\n" + json.dumps(
-        {"metric": metric, "path": "ragged", "value": 0.7, "d": 8}) + "\n")
-    monkeypatch.setenv("FLASHMOE_BENCH_RECORDS", str(p))
-    assert _bench_record_latencies(REF, 8) == {"ragged": 0.7}
-    assert _bench_record_latencies(
-        REF.replace(a2a_chunks=4), 8) == {"collective": 0.5}
-    assert _bench_record_latencies(
-        REF.replace(a2a_chunks=2), 8) == {}
 
 
 # ----------------------------------------------------------------------
@@ -438,7 +420,6 @@ def test_overlap_drift_record_and_warning():
                              predicted_fraction=0.0, gen="v5e", d=8)
 
 
-@pytest.mark.slow
 def test_measure_overlap_ragged_arm_and_chunk_passthrough(devices):
     """The ragged overlap arm runs end to end on the virtual mesh and
     the a2a_chunks passthrough reaches the overlapped leg; the fused

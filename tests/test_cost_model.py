@@ -205,14 +205,14 @@ def test_xla_dispatch_bytes_match_model():
 
 
 def test_schedule_resolution_decision_table(monkeypatch):
-    """The BASELINE decision table: which FFN schedule each bench config
+    """The decision table: which FFN schedule each bench config
     resolves to at d=8.  Since ISSUE 12 the mixtral row is the
     row-windowed schedule's reason to exist: its 14336-wide expert
     hidden slab exceeds VMEM for every weights-once schedule (batched /
     resident stay infeasible), but the window-major rowwin schedule
     bounds weight traffic at exactly 2x the collective path — the
     ACCEPTANCE CRITERION pin: <= 2.5x, vs the 40x the stream fallback
-    pays (the pre-rowwin verdict BASELINE.md's caveat reconciles)."""
+    pays."""
     from flashmoe_tpu.analysis import _geom
     from flashmoe_tpu.parallel.fused import schedule_table
 
@@ -244,9 +244,8 @@ def test_rowwin_prices_activation_restreaming(monkeypatch):
     """The rowwin schedule's byte trade must be charged, not hidden:
     weight bytes collapse to the 2-pass bound, while the activation
     column grows by the per-window x re-reads AND the f32 partial-sum
-    round-trips at every interior window boundary — the term
-    BASELINE.md's round-5 caveat demanded before believing any
-    row-windowed rescue."""
+    round-trips at every interior window boundary — the term that must
+    be charged before any row-windowed rescue is believed."""
     from flashmoe_tpu.analysis import _geom
 
     monkeypatch.delenv("FLASHMOE_FUSED_BATCHED", raising=False)

@@ -91,7 +91,6 @@ def test_ep_with_tensor_parallel_experts(devices):
 
 
 @pytest.mark.parametrize("inner", [2, 4])
-@pytest.mark.slow
 def test_hierarchical_dcn_a2a_matches_flat(inner, devices):
     """Two-stage (intra-slice, inter-slice) all-to-all must be
     bit-identical to the flat exchange."""
@@ -131,7 +130,6 @@ def test_ep_pallas_path_and_grad(devices):
         assert np.isfinite(np.asarray(leaf)).all()
 
 
-@pytest.mark.slow
 def test_ep_grad(devices):
     """EP layer must be differentiable end-to-end (training path)."""
     cfg = MoEConfig(num_experts=8, expert_top_k=2, hidden_size=64,

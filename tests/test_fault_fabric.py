@@ -632,64 +632,6 @@ def test_brownout_config_validates():
         BrownoutConfig(episode_budget=0)
 
 
-def test_reference_shed_frac_matches_committed_sentry_row():
-    import json
-
-    from flashmoe_tpu.telemetry_plane.regression import (
-        _reference_shed_frac,
-    )
-
-    frac = _reference_shed_frac(BrownoutConfig())
-    assert 0.0 < frac < 1.0
-    hist = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "obs", "history.jsonl")
-    with open(hist) as f:
-        entry = json.loads(f.readline())
-    row = entry["metrics"]["fabric_shed_frac[brownout,reference]"]
-    assert row["value"] == pytest.approx(round(frac, 4))
-    assert row["unit"] == "frac"
-
-
-def test_fabric_fault_sweep_record_contract(monkeypatch):
-    """The bench sweep's record shape, with the drills faked out —
-    the real drills run under the slow mark below."""
-    from flashmoe_tpu.chaos import drill as drill_mod
-    from flashmoe_tpu.serving import loadgen
-
-    def fake_drill(fault, *, seed=0, **kw):
-        return drill_mod.DrillResult(
-            fault=fault, expected_tier=EXPECTED_TIER[fault],
-            recovered=(fault != "handoff_timeout"), reason="boom",
-            final_step=6, steps_rerun=0, wall_s=0.123,
-            evidence={"completed": 6, "bit_equal_to_baseline": True,
-                      "migrations": 2, "retries": 1, "corrupt": 1,
-                      "failovers": 0, "trace_errors": []},
-            decisions=[])
-
-    monkeypatch.setattr(drill_mod, "run_drill", fake_drill)
-    monkeypatch.setattr(loadgen, "_brownout_shed_record",
-                        lambda *, seed=0: {"metric":
-                                           "fabric_shed[brownout]",
-                                           "value": 0.4,
-                                           "unit": "frac"})
-    recs = loadgen.fabric_fault_sweep(seed=0)
-    assert [r["metric"] for r in recs] == [
-        "fabric_fault[replica_crash]", "fabric_fault[handoff_corrupt]",
-        "fabric_fault[handoff_timeout]",
-        "fabric_fault[frontdoor_loss]", "fabric_fault[net_partition]",
-        "fabric_fault[lease_split_brain]",
-        "fabric_fault[replica_stall]",
-        "fabric_fault[lease_torn_write]", "fabric_shed[brownout]"]
-    crash = recs[0]
-    assert crash["unit"] == "ms" and crash["value"] == 123.0
-    assert crash["migrated"] == 2 and crash["retries"] == 1
-    assert crash["bit_equal"] is True and "error" not in crash
-    # an unrecovered drill carries error so the sentry skips it
-    assert recs[2]["error"] == "boom"
-    with pytest.raises(ValueError, match="not serving faults"):
-        loadgen.fabric_fault_sweep(["nan_grad"])
-
-
 # ----------------------------------------------------------------------
 # The chaos-matrix drills (slow lane)
 # ----------------------------------------------------------------------

@@ -35,7 +35,6 @@ def test_fused_matches_oracle(ep, devices):
     )
 
 
-@pytest.mark.slow
 def test_fused_matches_ep_layer_with_drops(devices):
     """Same drops/renormalization as the collective EP path."""
     cfg = MoEConfig(num_experts=8, expert_top_k=2, hidden_size=128,
@@ -146,8 +145,9 @@ def test_fused_non_tile_multiple_capacity(devices):
     )
 
 
-@pytest.mark.parametrize("mode", ["1", "0"], ids=["in_kernel", "xla"])
-@pytest.mark.slow
+@pytest.mark.parametrize(
+    "mode", [pytest.param("1", marks=pytest.mark.slow), "0"],
+    ids=["in_kernel", "xla"])
 def test_fused_combine_modes_match_oracle(mode, monkeypatch, devices):
     """FLASHMOE_FUSED_COMBINE forces each combine implementation; both
     must match the dense oracle (and hence each other) — incl. drops,
@@ -167,7 +167,6 @@ def test_fused_combine_modes_match_oracle(mode, monkeypatch, devices):
     )
 
 
-@pytest.mark.slow
 def test_fused_gated_with_shared_experts(devices):
     """SwiGLU experts stream through the kernel; shared experts add in."""
     cfg = MoEConfig(num_experts=8, expert_top_k=2, hidden_size=128,
@@ -184,7 +183,7 @@ def test_fused_gated_with_shared_experts(devices):
 
 
 def test_fuse_combine_gate_is_opt_in(monkeypatch):
-    """The in-kernel combine is opt-in until a hardware stage_bench row
+    """The in-kernel combine is opt-in until a measurement on chips
     justifies a default (advisor r3 #1/#2): env unset -> XLA combine;
     env=1 -> enabled only within the SMEM/VMEM budget, with a warning
     (not a Mosaic compile failure) when the combine maps are too large.
@@ -323,7 +322,6 @@ def _assert_fused_grads_match_collective(params, x, cfg, mesh):
         )
 
 
-@pytest.mark.slow
 def test_fused_batched_gradients(monkeypatch, devices):
     """Autodiff through the batched-schedule forward (the custom VJP's
     backward is schedule-independent, but the fwd kernel under
@@ -338,7 +336,6 @@ def test_fused_batched_gradients(monkeypatch, devices):
     _assert_fused_grads_match_collective(params, x, cfg, mesh)
 
 
-@pytest.mark.slow
 def test_fused_batched_forced_at_two_ranks(monkeypatch, tmp_path,
                                            devices):
     """ep=2 sits below the batched default (the schedules tie on weight
@@ -370,7 +367,6 @@ def test_fused_batched_forced_at_two_ranks(monkeypatch, tmp_path,
         tuning._load.cache_clear()
 
 
-@pytest.mark.slow
 def test_fused_combine_gradients_match_collective_path(monkeypatch,
                                                        devices):
     """Router + FFN + input gradients must flow correctly through the
@@ -394,7 +390,6 @@ def test_fused_combine_gradients_match_collective_path(monkeypatch,
     _assert_fused_grads_match_collective(params, x, cfg, mesh)
 
 
-@pytest.mark.slow
 def test_fused_custom_src_order_any_permutation(devices):
     """Correctness must never depend on the source-processing schedule:
     an adversarial src_order (own slab first, then reverse ring — the
@@ -420,8 +415,7 @@ def test_fused_custom_src_order_any_permutation(devices):
 
 def _force_tiles(monkeypatch, tmp_path, cm, kw, h=128):
     """Pin the rowwin (cm, kw) pair through a throwaway fused_tiles
-    table (the mechanism tune_sweep/bench --tiles force candidates
-    with)."""
+    table."""
     import json
 
     from flashmoe_tpu import tuning
@@ -476,7 +470,6 @@ def test_rowwin_matches_oracle(ep, monkeypatch, tmp_path, devices):
 
 @requires_interpret
 @pytest.mark.parametrize("other", ["stream", "batched", "collective"])
-@pytest.mark.slow
 def test_rowwin_identity_across_schedules(other, monkeypatch, tmp_path,
                                           devices):
     """ISSUE 12 acceptance: rowwin output vs every mutually-feasible

@@ -20,7 +20,6 @@ CFG = MoEConfig(num_experts=4, expert_top_k=2, hidden_size=64,
                 param_dtype=jnp.float32)
 
 
-@pytest.mark.slow
 def test_greedy_matches_full_forward():
     """Greedy decode must reproduce argmax of the full (non-cached)
     forward at every step."""

@@ -112,7 +112,6 @@ def test_gather_fused_inference_matches_oracle(gated, cf):
 
 
 @pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated"])
-@pytest.mark.slow
 def test_dropless_gather_fused_inference(gated):
     """Dropless inference routes through the gather-fused kernel (inverse
     map from the ragged plan); output and re-gather-VJP grads match XLA."""

@@ -265,10 +265,9 @@ def test_drift_report_over_mixed_records():
          "rel_error": 0.4, "exceeded": False},
         {"decision": "planner.drift", "path": "explicit", "gen": "v5e",
          "rel_error": -0.8, "exceeded": True},
-        # a bench record doubles as a calibration point
-        {"metric": "moe_layer_fwd_ms[x]", "value": 2.0, "path": "explicit",
-         "predicted_ms": 1.0, "prediction_error": 1.0,
-         "planner_gen": "v5e", "drift_exceeded": True},
+        {"decision": "planner.drift", "path": "explicit", "gen": "v5e",
+         "predicted_ms": 1.0, "measured_ms": 2.0, "rel_error": 1.0,
+         "exceeded": True},
         {"unrelated": True},
     ]
     rep = drift_report(records)
@@ -276,26 +275,6 @@ def test_drift_report_over_mixed_records():
     b = rep["by_path"]["explicit@v5e"]
     assert b["n"] == 3
     assert b["worst_rel_error"] == pytest.approx(1.0)
-
-
-def test_drift_report_dedups_mirrored_bench_pair():
-    """bench.py writes each measurement twice across the obs-dir pair
-    (bench record + mirrored planner.drift decision): one comparison."""
-    from flashmoe_tpu.planner.drift import drift_report
-
-    # measured value where bench's 3-decimal and the decision's
-    # 4-decimal rounding differ — the dedup must still match
-    bench_rec = {"metric": "moe_layer_fwd_ms[x]", "value": 1.235,
-                 "path": "explicit", "predicted_ms": 0.015,
-                 "prediction_error": 81.3, "planner_gen": "v5e",
-                 "d": 1, "drift_exceeded": True}
-    decision = {"decision": "planner.drift", "path": "explicit",
-                "gen": "v5e", "d": 1, "predicted_ms": 0.015,
-                "measured_ms": 1.2346, "rel_error": 81.3067,
-                "exceeded": True}
-    rep = drift_report([bench_rec, decision])
-    assert rep["n"] == 1 and rep["exceeded"] == 1
-    assert rep["by_path"]["explicit@v5e"]["n"] == 1
 
 
 # ----------------------------------------------------------------------
@@ -457,28 +436,6 @@ def test_trainer_flight_recorder_end_to_end(tmp_path, devices):
     assert doc["imbalance"]["total_assignments"] == pytest.approx(64.0)
     assert sum(doc["imbalance"]["expert_load"]) > 0
     assert doc["drops"]["mean_dropped_fraction"] is not None
-
-
-# ----------------------------------------------------------------------
-# bench.py wiring: drift decisions land in telemetry
-# ----------------------------------------------------------------------
-
-def test_bench_emit_records_drift(monkeypatch, capsys):
-    import bench
-
-    monkeypatch.setenv("FLASHMOE_TPU_GEN", "v5e")
-    cfg = MoEConfig(num_experts=8, expert_top_k=2, hidden_size=256,
-                    intermediate_size=512, sequence_len=256, **F32)
-    n0 = len(global_metrics.decisions)
-    bench._PARTIAL.clear()
-    bench._emit(cfg, "unit", t_fused=5e-3, t_xla=8e-3)
-    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rec["predicted_ms"] > 0
-    assert "drift_exceeded" in rec
-    drifts = [d for d in global_metrics.decisions[n0:]
-              if d["decision"] == "planner.drift"]
-    # executed path + the xla comparison leg
-    assert {d["path"] for d in drifts} == {rec["path"], "xla"}
 
 
 def test_adaptation_report_timeline_with_before_after():

@@ -24,7 +24,6 @@ def _batch(b=4, seed=1):
 
 
 @pytest.mark.parametrize("pp,dp,mb", [(4, 2, 2), (2, 4, 4), (2, 2, 1)])
-@pytest.mark.slow
 def test_pipeline_ce_matches_plain_forward(pp, dp, mb, devices):
     cfg = CFG.replace(pp=pp, dp=dp)
     params = init_params(jax.random.PRNGKey(0), cfg)
@@ -70,7 +69,6 @@ def test_interleave_validation(devices):
                       num_microbatches=3, interleave=2)
 
 
-@pytest.mark.slow
 def test_pipeline_grad(devices):
     params = init_params(jax.random.PRNGKey(0), CFG)
     mesh = make_mesh(CFG)

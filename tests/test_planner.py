@@ -30,8 +30,7 @@ REF = BENCH_CONFIGS["reference"]
 def _hermetic(monkeypatch):
     """The model consults env knobs and caches; pin both per test."""
     for var in ("FLASHMOE_FUSED_BATCHED", "FLASHMOE_TUNING_FILE",
-                "FLASHMOE_TPU_GEN", "FLASHMOE_BENCH_RECORDS",
-                "FLASHMOE_MOCK_SLICES"):
+                "FLASHMOE_TPU_GEN", "FLASHMOE_MOCK_SLICES"):
         monkeypatch.delenv(var, raising=False)
     from flashmoe_tpu import tuning
 
@@ -248,7 +247,7 @@ def test_divisibility_errors():
     with pytest.raises(ValueError, match="slices"):
         predict_paths(REF, 8, "v5e", slices=3)  # 8 % 3 != 0
     with pytest.raises(ValueError, match="inner"):
-        a2a_transport_cost(8, 3, 1e6)           # ADVICE r5: no silent //
+        a2a_transport_cost(8, 3, 1e6)           # no silent //
 
 
 def test_mock_slices_garbage_is_loud_but_never_blocks_trace(monkeypatch):
@@ -315,25 +314,6 @@ def test_measured_override_from_tuning_table(tmp_path, monkeypatch):
     assert sel.backend == "ragged"
 
 
-def test_measured_override_from_bench_records(tmp_path, monkeypatch):
-    metric = (f"moe_layer_fwd_ms[x:E={REF.num_experts},"
-              f"k={REF.expert_top_k},H={REF.hidden_size},"
-              f"I={REF.intermediate_size},S={REF.tokens},bfloat16]")
-    rec = {"metric": metric, "path": "collective", "value": 0.0007,
-           "d": 8, "xla_path_ms": 0.009}
-    p = tmp_path / "bench.jsonl"
-    p.write_text("not json\n" + json.dumps(rec) + "\n")
-    monkeypatch.setenv("FLASHMOE_BENCH_RECORDS", str(p))
-    sel = select_path(REF, 8, "v5e", record=False)
-    assert sel.mode == "measured" and sel.winner == "collective"
-    # a single-chip record (bench's headline, d=1) must never override
-    # an 8-rank selection — and vice versa (code-review finding)
-    rec1 = dict(rec, d=1, path="explicit", value=0.0001)
-    p.write_text(json.dumps(rec1) + "\n")
-    sel1 = select_path(REF, 8, "v5e", record=False)
-    assert sel1.mode == "predicted"
-
-
 def test_selection_decision_lands_in_telemetry():
     n0 = len(metrics.decisions)
     sel = select_path(REF, 8, "v5e")
@@ -385,7 +365,7 @@ def test_planner_bytes_agree_with_analysis():
 
 
 def test_fused_combine_return_bytes_not_overstated():
-    """ADVICE r5 satellite: at capacity_factor > 1 the sorted-return
+    """At capacity_factor > 1 the sorted-return
     combine sends only the routed rows back, so its comm must be
     strictly below the slab path's."""
     cfg = REF.replace(capacity_factor=2.0)
@@ -397,24 +377,13 @@ def test_fused_combine_return_bytes_not_overstated():
         path_costs(REF, "fused", d_world=8).comm_bytes
 
 
-def test_single_chip_paths_and_bench_fields(monkeypatch):
+def test_single_chip_paths():
     preds = predict_paths(REF, 1, "v5e")
     assert {p.path for p in preds} == {"xla", "explicit", "gather"}
     assert all(p.ici_ms == 0 and p.dcn_ms == 0 for p in preds)
     # training excludes the inference-only gather kernel
     tr = predict_paths(REF.replace(is_training=True), 1, "v5e")
     assert not next(p for p in tr if p.path == "gather").feasible
-
-    import bench
-
-    monkeypatch.setenv("FLASHMOE_TPU_GEN", "v5e")
-    bench._PARTIAL.clear()
-    fields = bench._planner_fields(REF, 1e-3, 2e-3)
-    assert fields["planner_gen"] == "v5e"
-    assert fields["predicted_path"] == "explicit"
-    assert "predicted_ms" in fields and "prediction_error" in fields
-    assert "xla_prediction_error" in fields
-    assert fields["predicted_winner"] in ("explicit", "gather", "xla")
 
 
 def test_hierarchical_beats_flat_on_dcn_messages():
@@ -557,20 +526,19 @@ def test_select_path_keys_measurements_on_dcn_wire(tmp_path,
     """A latency measured with the DCN-hop wire on never overrides a
     selection without it (and vice versa) — the wire_dcn key rides the
     measurement identity like wire/wire_combine/chunks."""
-    import json as _json
+    from flashmoe_tpu import tuning
 
-    rec = {"metric": f"moe_layer_fwd_ms[x:E={REF.num_experts},"
-                     f"k={REF.expert_top_k},H={REF.hidden_size},"
-                     f"I={REF.intermediate_size},S={REF.tokens},"
-                     f"bfloat16]",
-           "value": 0.001, "path": "collective", "d": 8,
-           "wire_dtype": "off", "wire_dtype_combine": "off",
-           "wire_dtype_dcn": "e4m3"}
-    p = tmp_path / "records.jsonl"
-    p.write_text(_json.dumps(rec) + "\n")
-    monkeypatch.setenv("FLASHMOE_BENCH_RECORDS", str(p))
+    tbl = tmp_path / "table.json"
+    tbl.write_text(json.dumps({"generation": "v5e", "entries": [{
+        "kernel": "path_latency",
+        "match": {"path": "collective", "h": REF.hidden_size,
+                  "i": REF.intermediate_size, "d": 8,
+                  "wire_dcn": "e4m3"},
+        "measured_ms": 0.001}]}))
+    monkeypatch.setenv("FLASHMOE_TUNING_FILE", str(tbl))
+    tuning._load.cache_clear()
     sel_off = select_path(REF, 8, "v5e", record=False)
-    assert sel_off.mode == "predicted"       # dcn-wire record ignored
+    assert sel_off.mode == "predicted"       # dcn-wire entry ignored
     sel_on = select_path(REF.replace(wire_dtype_dcn="e4m3"), 8, "v5e",
                          record=False)
     assert sel_on.mode == "measured"

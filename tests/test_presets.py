@@ -45,13 +45,12 @@ def test_preset_layer_runs_small(name):
         )
 
 
-@pytest.mark.slow
 def test_weak_scaling_256_bench_config(devices):
     """BASELINE config #5 (256-expert weak-scaling / payload-skew) must be
-    driver-invokable by name (bench.py --config weak_scaling_256) and
-    correct: the full 256-expert routing runs through the collective EP
-    layer on the virtual 8-device mesh at shrunken H/I/S, matching the
-    dense oracle."""
+    there by name (``BENCH_CONFIGS["weak_scaling_256"]``) and correct:
+    the full 256-expert routing runs through the collective EP layer on
+    the virtual 8-device mesh at shrunken H/I/S, matching the dense
+    oracle."""
     from flashmoe_tpu.config import BENCH_CONFIGS
     from flashmoe_tpu.parallel.ep import ep_moe_layer
     from flashmoe_tpu.parallel.mesh import make_mesh

@@ -132,7 +132,7 @@ def test_ledger_matrix_quick_point_end_to_end(devices, tmp_path):
     phases measured, the per-step phase sum bounded by the step wall
     time, artifacts written and schema-valid, `observe --ledger`
     renders them.  (The full flat/hierarchical/ragged x chunks x wire
-    matrix is the slow test below / `bench.py --profile`.)"""
+    matrix is the slow test below.)"""
     obs = tmp_path / "obs"
     records = run_ledger_matrix(str(obs), quick=True, steps=1,
                                 overlapped=False, warn=False)
@@ -156,7 +156,7 @@ def test_ledger_matrix_quick_point_end_to_end(devices, tmp_path):
     led = observe.ledger_report(rows)
     assert led["n"] == len(PHASES)
     # both vocabularies ride every row: the matrix point name the docs
-    # and bench records speak, and the planner path it joins against
+    # speak, and the planner path it joins against
     assert led["points"][0]["point"] == "flat"
     assert led["points"][0]["path"] == "collective"
     assert all(r["point"] == "flat" for r in rows if "phase" in r)

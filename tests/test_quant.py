@@ -428,13 +428,11 @@ def test_golden_quant_dimension_gates_rowwin_race():
 def test_measurement_identity_separates_quant():
     """A latency measured with int8 weights must never override a
     full-precision selection (and vice versa): tuning entries match the
-    quant key strictly, and bench records carry expert_quant."""
+    quant key strictly."""
     import os
 
     from flashmoe_tpu import tuning
-    from flashmoe_tpu.planner.select import (
-        _bench_record_latencies, _shape_key,
-    )
+    from flashmoe_tpu.planner.select import _shape_key
 
     cfg = _cfg(ep=8)
     cq = cfg.replace(expert_quant="int8")
@@ -468,34 +466,6 @@ def test_measurement_identity_separates_quant():
         os.environ.pop("FLASHMOE_TUNING_FILE", None)
         tuning._load.cache_clear()
         os.unlink(path)
-
-    # bench records: the expert_quant field is part of the identity
-    with tempfile.NamedTemporaryFile("w", suffix=".jsonl",
-                                     delete=False) as f:
-        sig = (f"E={cfg.num_experts},k={cfg.expert_top_k},"
-               f"H={cfg.hidden_size},I={cfg.intermediate_size},"
-               f"S={cfg.tokens},float32")
-        f.write(json.dumps({"metric": f"x[{sig}]", "path": "explicit",
-                            "value": 3.0, "d": 8,
-                            "expert_quant": "int8"}) + "\n")
-        f.write(json.dumps({"metric": f"x[{sig}]", "path": "explicit",
-                            "value": 4.0, "d": 8}) + "\n")
-        rpath = f.name
-    os.environ["FLASHMOE_BENCH_RECORDS"] = rpath
-    try:
-        assert _bench_record_latencies(cq, 8) == {"explicit": 3.0}
-        assert _bench_record_latencies(cfg, 8) == {"explicit": 4.0}
-    finally:
-        os.environ.pop("FLASHMOE_BENCH_RECORDS", None)
-        os.unlink(rpath)
-
-
-def test_sentry_reference_points_cover_quant():
-    from flashmoe_tpu.telemetry_plane.regression import reference_points
-
-    pts = reference_points("v5e")
-    assert "planner_predicted_ms[mixtral,d=8,v5e,quant=int8]" in pts
-    assert "quant_rowwin_weight_ms[mixtral,d=8,v5e,quant=int8]" in pts
 
 
 # ----------------------------------------------------------------------

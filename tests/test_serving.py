@@ -29,9 +29,7 @@ from flashmoe_tpu.serving.kvcache import (
     SCRATCH_PAGE, PagePool, ShardedPagePool, ctx_pages_bucket,
     init_paged_cache, prompt_pad, store_prefill,
 )
-from flashmoe_tpu.serving.loadgen import (
-    build_requests, serve_load_sweep, tiny_config,
-)
+from flashmoe_tpu.serving.loadgen import build_requests, tiny_config
 from flashmoe_tpu.utils.telemetry import FlightRecorder, Metrics
 
 CFG = tiny_config()
@@ -904,8 +902,7 @@ def test_resolve_moe_plan_decode_mode(monkeypatch):
     )
 
     monkeypatch.setenv("FLASHMOE_TPU_GEN", "v5e")
-    for var in ("FLASHMOE_TUNING_FILE", "FLASHMOE_BENCH_RECORDS",
-                "FLASHMOE_MOCK_SLICES"):
+    for var in ("FLASHMOE_TUNING_FILE", "FLASHMOE_MOCK_SLICES"):
         monkeypatch.delenv(var, raising=False)
     _cached_backend.cache_clear()
     cfg = BENCH_CONFIGS["reference"].replace(moe_backend="auto", ep=8)
@@ -1002,18 +999,6 @@ def test_serving_cli_summary_and_artifacts(tmp_path, capsys):
                for l in flight)
     decisions = (obs / "decisions.jsonl").read_text()
     assert "serve.retire" in decisions and "slo.breach" in decisions
-
-
-def test_serve_load_sweep_records():
-    recs = serve_load_sweep([2, 1], n_requests=2, max_batch=2,
-                            max_new=3, prompt_len=8)
-    assert len(recs) == 2
-    for rec in recs:
-        assert rec["metric"].startswith("serve_load[")
-        assert rec["unit"] == "tokens_per_sec" and rec["value"] > 0
-        assert "ttft_ms_p50" in rec and "tpot_ms_p50" in rec
-        assert rec["completed"] == 2
-    assert recs[0]["vs_baseline"] == 1.0
 
 
 def test_build_requests_deterministic():
@@ -1459,23 +1444,6 @@ def test_set_speculate_morphs_and_validates(params, spec_prompts):
     plain = ServingEngine(params, CFG, _spec_serve())
     with pytest.raises(ValueError, match="speculate"):
         plain.set_speculate(True)
-
-
-def test_serve_load_sweep_speculate_arm():
-    """bench --serve --speculate contract: spec=kN metric identity,
-    per-record acceptance stats, the equal-SLO baseline TPOT
-    comparison, and the asserted exactness bit."""
-    recs = serve_load_sweep([3], n_requests=4, max_batch=2, max_new=5,
-                            speculate=2)
-    assert len(recs) == 1
-    r = recs[0]
-    assert ",spec=k2]" in r["metric"]
-    assert r["bit_equal_to_baseline"] is True
-    assert r["spec_drafted"] >= r["spec_accepted"] >= 0
-    assert 0.0 <= r["accept_rate"] <= 1.0
-    assert r["spec_tokens_per_step"] >= 1.0
-    assert r["baseline_tpot_ms_p50"] is not None
-    assert "baseline_outputs" not in r   # payload stays JSON-sized
 
 
 def test_draft_state_ngram_index():

@@ -42,13 +42,7 @@ def _fuzz_cfg(seed: int) -> MoEConfig:
 _SEEDS = list(range(10))
 
 
-@pytest.mark.parametrize("seed", _SEEDS[:2])
-def test_fuzz_single_device_fast(seed):
-    _run_one(_fuzz_cfg(seed))
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("seed", _SEEDS[2:])
+@pytest.mark.parametrize("seed", _SEEDS)
 def test_fuzz_single_device(seed):
     _run_one(_fuzz_cfg(seed))
 

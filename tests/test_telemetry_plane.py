@@ -1,18 +1,15 @@
 """Live telemetry plane (flashmoe_tpu/telemetry_plane/): quantile
 sketch equivalence, exposition-spec compliance, scrape endpoints,
-request tracing, shard merge, and the perf-regression sentry.
+request tracing and shard merge.
 
 The CI-shaped acceptance lives here and in tests/test_serving.py
 (tracer drill + mid-drill scrape on the real engine); this file covers
-the plane's own mechanics plus the planted-regression subprocess gate
-(mirroring the staticcheck planted-violation pattern).
+the plane's own mechanics.
 """
 
 import json
 import os
 import re
-import subprocess
-import sys
 import urllib.request
 
 import pytest
@@ -23,8 +20,6 @@ from flashmoe_tpu.telemetry_plane.sketch import (
 from flashmoe_tpu.utils.telemetry import (
     Metrics, PROM_CONTENT_TYPE, escape_label_value,
 )
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ----------------------------------------------------------------------
@@ -350,158 +345,6 @@ def test_observe_trace_and_merge(tmp_path, capsys):
     # one mode at a time
     with pytest.raises(SystemExit):
         observe.main(["--merge", "--serving", str(shard)])
-
-
-# ----------------------------------------------------------------------
-# Perf-regression sentry
-# ----------------------------------------------------------------------
-
-def _run(points, run="r"):
-    return {"run": run, "meta": {},
-            "metrics": {k: {"value": v, "unit": u}
-                        for k, (v, u) in points.items()}}
-
-
-def test_collect_points_skips_non_measurements():
-    from flashmoe_tpu.telemetry_plane import regression as reg
-
-    pts = reg.collect_points([
-        {"metric": "a[ms]", "value": 2.0, "unit": "ms",
-         "ttft_ms_p50": 4.0},
-        {"metric": "skip", "value": None, "skipped": True},
-        {"metric": "part", "value": 1.0, "partial": "deadline"},
-        {"metric": "err", "value": -1, "error": "boom"},
-        {"no_metric": 1},
-    ])
-    assert set(pts) == {"a[ms]", "a[ms].ttft_ms_p50"}
-    assert pts["a[ms].ttft_ms_p50"]["unit"] == "ms"
-
-
-def test_check_regression_directions_and_decision():
-    from flashmoe_tpu.telemetry_plane import regression as reg
-
-    m = Metrics()
-    runs = [
-        _run({"lat": (10.0, "ms"), "tps": (100.0, "tokens_per_sec")},
-             "r1"),
-        _run({"lat": (10.0, "ms"), "tps": (100.0, "tokens_per_sec")},
-             "r2"),
-        # newest: latency +30% (bad), throughput +30% (good)
-        _run({"lat": (13.0, "ms"), "tps": (130.0, "tokens_per_sec"),
-              "fresh": (1.0, "ms")}, "r3"),
-    ]
-    rep = reg.check_regression(runs, metrics_obj=m)
-    assert [r["metric"] for r in rep["regressions"]] == ["lat"]
-    assert [r["metric"] for r in rep["improvements"]] == ["tps"]
-    assert rep["new_metrics"] == ["fresh"]
-    dec = m.last_decision("regress.detected")
-    assert dec["metric"] == "lat" and dec["run"] == "r3"
-    # throughput DROP is the regression direction for tokens/s
-    runs[-1]["metrics"]["tps"]["value"] = 60.0
-    runs[-1]["metrics"]["lat"]["value"] = 10.0
-    rep = reg.check_regression(runs, metrics_obj=m)
-    assert [r["metric"] for r in rep["regressions"]] == ["tps"]
-    # single run: nothing to compare, never a false alarm
-    assert reg.check_regression(runs[:1])["regressions"] == []
-
-
-def _observe_regression(path, *flags):
-    return subprocess.run(
-        [sys.executable, "-m", "flashmoe_tpu.observe", "--regression",
-         *flags, str(path)],
-        capture_output=True, text=True, timeout=120, cwd=REPO,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-
-
-def test_sentry_ci_gate_planted_vs_clean(tmp_path):
-    """The CI fixture (satellite): a planted-regression history exits
-    rc 2 with the offending metric named; a clean history exits rc 0 —
-    subprocess-tested like the staticcheck planted violations."""
-    from flashmoe_tpu.telemetry_plane import regression as reg
-
-    clean = tmp_path / "clean.jsonl"
-    for run in ("a", "b", "c"):
-        reg.append_run(str(clean), {"m[ms]": {"value": 5.0,
-                                              "unit": "ms"}}, run=run)
-    planted = tmp_path / "planted.jsonl"
-    planted.write_text(clean.read_text())
-    reg.append_run(str(planted),
-                   {"m[ms]": {"value": 9.0, "unit": "ms"}},
-                   run="regressed")
-
-    r = _observe_regression(planted, "--ci")
-    assert r.returncode == 2, r.stdout + r.stderr
-    assert "m[ms]" in r.stdout and "REGRESSED" in r.stdout
-    r = _observe_regression(clean, "--ci")
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "0 regression(s)" in r.stdout
-    # missing history is an error, not a silent pass
-    r = _observe_regression(tmp_path / "absent.jsonl", "--ci")
-    assert r.returncode == 2
-
-
-def test_committed_baseline_seed_passes_ci():
-    """The recorded obs/history.jsonl (the baseline seed: deterministic
-    golden-config model points) must load, compare, and pass."""
-    from flashmoe_tpu.telemetry_plane import regression as reg
-
-    path = os.path.join(REPO, "obs", "history.jsonl")
-    runs = reg.load_history(path)
-    assert len(runs) >= 2
-    assert any(k.startswith("planner_predicted_ms[reference")
-               for k in runs[-1]["metrics"])
-    rep = reg.check_regression(runs, metrics_obj=Metrics())
-    assert rep["compared"] >= 3
-    assert rep["regressions"] == []
-
-
-def test_reference_points_deterministic():
-    from flashmoe_tpu.telemetry_plane import regression as reg
-
-    a, b = reg.reference_points(), reg.reference_points()
-    assert a == b and len(a) >= 3
-    assert all(v["unit"] in ("ms", "hidden_frac", "frac",
-                             "accept_rate", "tokens_per_step")
-               and v["value"] > 0 for v in a.values())
-    # the measured-latency plane rides along (PR 17): a virtual-clock
-    # TTFT and a hidden-fraction point per golden config
-    assert any(k.startswith("fabric_ttft_vclock_ms[") and
-               v["unit"] == "ms" for k, v in a.items())
-    assert any(k.startswith("fabric_handoff_hidden_frac[") and
-               v["unit"] == "hidden_frac" and 0 < v["value"] <= 1.0
-               for k, v in a.items())
-    # PR 18: fault-recovery latency per golden config plus the analytic
-    # brownout shed fraction, gating the serving-side failure ladder
-    assert any(k.startswith("fabric_recovery_ms[") and
-               v["unit"] == "ms" for k, v in a.items())
-    shed = a["fabric_shed_frac[brownout,reference]"]
-    assert shed["unit"] == "frac" and 0 < shed["value"] < 1.0
-    # ISSUE 20: the speculative-decode plane — a modeled break-even
-    # acceptance and an expected-tokens-per-verify-step point per
-    # golden config
-    assert any(k.startswith("decode_accept_rate[") and
-               v["unit"] == "accept_rate" and 0 < v["value"] < 1.0
-               for k, v in a.items())
-    assert any(k.startswith("spec_tokens_per_step[") and
-               v["unit"] == "tokens_per_step" and v["value"] > 1.0
-               for k, v in a.items())
-
-
-def test_check_regression_zero_baseline_direction_aware():
-    """A recovery from a 0-baseline throughput run is an improvement,
-    not a regression (code-review finding: the directions used to
-    cancel), and the report stays JSON-serializable (no Infinity)."""
-    from flashmoe_tpu.telemetry_plane import regression as reg
-
-    runs = [_run({"tps": (0.0, "tokens_per_sec"),
-                  "lat": (0.0, "ms")}, "dead"),
-            _run({"tps": (120.0, "tokens_per_sec"),
-                  "lat": (5.0, "ms")}, "alive")]
-    rep = reg.check_regression(runs, metrics_obj=Metrics())
-    assert [r["metric"] for r in rep["improvements"]] == ["tps"]
-    # latency OFF a zero baseline is the bad direction
-    assert [r["metric"] for r in rep["regressions"]] == ["lat"]
-    json.dumps(rep)    # finite sentinel: valid JSON end to end
 
 
 def test_tracer_evictee_leaves_step_window():

@@ -78,7 +78,6 @@ def test_optax_trainer_with_shardings(devices):
 
 
 @pytest.mark.parametrize("backend", ["fused", "ragged"])
-@pytest.mark.slow
 def test_moe_backend_selection(backend, devices):
     """The flagship model can route its distributed MoE through the fused
     RDMA kernel or the dropless ragged layer and still match the default

@@ -170,7 +170,6 @@ def test_ep_wire_on_tracks_oracle(wd, wc, devices):
         assert 0.0 < float(on.stats.wire_rtq_error) < 0.1
 
 
-@pytest.mark.slow
 def test_hierarchical_a2a_wire_roundtrip_matches_flat(devices):
     """The two-stage (intra-slice, inter-slice) exchange must carry
     payload AND fp8 scales consistently through both hops: with the wire
@@ -229,7 +228,6 @@ def test_wire_stats_zero_when_off_and_in_host_dict():
     assert stats_to_host(off.stats)["wire_rtq_error"] == 0.0
 
 
-@pytest.mark.slow
 def test_ep_wire_grad_finite(devices):
     """Training through an fp8 wire: grads flow (the codec is plain
     cast/scale arithmetic) and stay finite."""
@@ -251,11 +249,9 @@ def test_ep_wire_grad_finite(devices):
 # 50-step CPU smoke train: bf16 wire tracks the f32 baseline
 # ----------------------------------------------------------------------
 
-@pytest.mark.slow
 def test_smoke_train_bf16_wire_tracks_f32_baseline(devices):
-    """Two full 50-step training jobs — slow-marked per the repo's
-    convention that full training jobs stay out of the fast gate
-    (tests/test_collection.py; ROADMAP tier-1 budget)."""
+    """Two full 50-step training jobs at toy widths: the bf16 wire's
+    loss tracks the float32 baseline's."""
     from flashmoe_tpu.runtime.trainer import (
         init_state, make_optimizer, make_train_step, state_shardings,
     )
@@ -379,7 +375,6 @@ def test_measured_latencies_keyed_by_wire(tmp_path, monkeypatch):
          "measured_ms": 0.0002},
     ]}))
     monkeypatch.setenv("FLASHMOE_TUNING_FILE", str(tbl))
-    monkeypatch.delenv("FLASHMOE_BENCH_RECORDS", raising=False)
     tuning._load.cache_clear()
     _cached_backend.cache_clear()
     try:
@@ -399,26 +394,3 @@ def test_measured_latencies_keyed_by_wire(tmp_path, monkeypatch):
     finally:
         tuning._load.cache_clear()
         _cached_backend.cache_clear()
-
-
-def test_bench_records_keyed_by_wire(tmp_path, monkeypatch):
-    import json
-
-    from flashmoe_tpu.config import BENCH_CONFIGS
-    from flashmoe_tpu.planner.select import _bench_record_latencies
-
-    ref = BENCH_CONFIGS["reference"]
-    metric = (f"moe_layer_fwd_ms[x:E={ref.num_experts},"
-              f"k={ref.expert_top_k},H={ref.hidden_size},"
-              f"I={ref.intermediate_size},S={ref.tokens},bfloat16]")
-    p = tmp_path / "bench.jsonl"
-    p.write_text(json.dumps(
-        {"metric": metric, "path": "collective", "value": 0.5, "d": 8,
-         "wire_dtype": "e4m3"}) + "\n" + json.dumps(
-        {"metric": metric, "path": "ragged", "value": 0.7, "d": 8}) + "\n")
-    monkeypatch.setenv("FLASHMOE_BENCH_RECORDS", str(p))
-    assert _bench_record_latencies(ref, 8) == {"ragged": 0.7}
-    assert _bench_record_latencies(
-        ref.replace(wire_dtype="e4m3"), 8) == {"collective": 0.5}
-    assert _bench_record_latencies(
-        ref.replace(wire_dtype="e5m2"), 8) == {}
