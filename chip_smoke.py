@@ -11,6 +11,7 @@ program (``tpu_custom_call``), so interpret mode cannot pass for the chip.
     python chip_smoke.py              # one chip: layer, serve,
                                       # serve_hybrid, train
     python chip_smoke.py --only serve_hybrid    # that phase alone
+    python chip_smoke.py --only serve_sdar      # the serve phase's sdar cases
     python chip_smoke.py --chips 4    # one host, four chips: ep4_layer,
                                       # ep4_fused, ep4_serve (builder-run)
 
@@ -509,6 +510,102 @@ def phase_serve(seed):
     gc.collect()
     ok &= _serve_case(params, cfg, serve, seed, cut,
                       {"decode_step_compile_s": round(compile_s, 3)})
+    del params
+    gc.collect()
+    return phase_serve_sdar(seed) and ok
+
+
+def phase_serve_sdar(seed):
+    """The serving phase's ``sdar`` cases alone (``--only serve_sdar``):
+    generation by diffusion over blocks, one published layer of
+    SDAR-30B-A3B-Chat at full widths, every expert, the whole vocabulary,
+    float32 and bfloat16."""
+    import jax
+    import jax.numpy as jnp
+
+    from flashmoe_tpu.models.presets import PRESETS
+    from flashmoe_tpu.models.transformer import init_params
+
+    sdar = PRESETS["sdar-30b-a3b-chat"](num_layers=1)
+    params = init_params(jax.random.PRNGKey(seed + 1), sdar)
+    ok = _sdar_case(params, sdar.replace(dtype=jnp.float32), seed)
+    gc.collect()
+    ok &= _sdar_case(params, sdar, seed)
+    return ok
+
+
+def _sdar_case(params, cfg, seed):
+    """The engine's block-diffusion path at SDAR-30B-A3B-Chat's widths, ONE
+    published layer: tokens and reveal steps against ``generate_blocks``
+    (a request at a time over the dense cache), and the kernel's arm
+    (``fm_paged_decode`` at a span of one block under the block mask)
+    against the gather arm.  ``float32`` under matmul precision "highest"
+    must agree token for token; ``bfloat16`` reports how many do (a
+    flipped choice compounds through the blocks that follow: the cell's
+    teacher-forced check, not this one, judges bfloat16)."""
+    import jax
+    import jax.numpy as jnp
+
+    from flashmoe_tpu.models.generate import generate_blocks
+    from flashmoe_tpu.serving import engine as eng
+    from flashmoe_tpu.utils.telemetry import Metrics
+
+    strict = cfg.dtype == jnp.float32
+    serve = eng.ServeConfig(max_batch=4, page_size=16, num_pages=SERVE_PAGES,
+                            max_pages_per_slot=4, ctx_bucket_pages=2,
+                            prompt_bucket=16, denoise_steps=2)
+    reqs, arrivals = serve_requests(cfg.vocab_size, seed)
+
+    def run():
+        engine = eng.ServingEngine(params, cfg, serve,
+                                   metrics_obj=Metrics())  # this run's
+        try:
+            out = engine.run(reqs, arrivals)
+            return (out, dict(engine.reveal_steps),
+                    engine.metrics.counters.get("serve.decode_kernel_steps",
+                                                0))
+        finally:
+            engine.close()
+
+    with (jax.default_matmul_precision("highest") if strict
+          else contextlib.nullcontext()):
+        t0 = time.perf_counter()
+        out, steps, kernel_steps = run()
+        run_s = time.perf_counter() - t0
+        with gather_arm():
+            g_out, g_steps, g_kernel_steps = run()
+        want = {}
+        for r in reqs:
+            toks, at = generate_blocks(
+                params, jnp.asarray([r.prompt], jnp.int32), cfg,
+                max_new_tokens=r.max_new_tokens, denoise_steps=2)
+            want[r.rid] = (np.asarray(toks)[0].tolist(),
+                           np.asarray(at)[0].tolist())
+    new = sum(r.max_new_tokens for r in reqs)
+    same = lambda a, b: sum(x == y for x, y in zip(a, b))
+    tok_eq = sum(same(out[r.rid][len(r.prompt):],
+                      want[r.rid][0][len(r.prompt):]) for r in reqs)
+    step_eq = sum(same(steps[r.rid], want[r.rid][1]) for r in reqs)
+    arm_eq = sum(same(out[r.rid], g_out[r.rid]) - len(r.prompt)
+                 for r in reqs)
+    ok = (kernel_steps > 0 and g_kernel_steps == 0
+          and all(len(out[r.rid]) == len(r.prompt) + r.max_new_tokens
+                  for r in reqs)
+          and (not strict or (tok_eq == step_eq == arm_eq == new)))
+    emit({"phase": "serve", "case": "sdar",
+          "dtype": jnp.dtype(cfg.dtype).name, "ok": ok,
+          "widths": {"H": cfg.hidden_size, "I": cfg.intermediate_size,
+                     "E": cfg.num_experts, "k": cfg.expert_top_k,
+                     "heads": cfg.num_heads,
+                     "kv_heads": cfg.resolved_num_kv_heads,
+                     "head_dim": cfg.resolved_head_dim,
+                     "vocab": cfg.vocab_size, "block": cfg.block_length},
+          "new_tokens": new, "tokens_equal_generate": tok_eq,
+          "reveal_steps_equal_generate": step_eq,
+          "tokens_equal_gather_arm": arm_eq,
+          "kernel_steps": kernel_steps,
+          "gather_arm_kernel_steps": g_kernel_steps,
+          "run_s_with_compile": round(run_s, 3)})
     return ok
 
 
@@ -930,6 +1027,9 @@ def phase_ep4_serve(seed, shared):
 
 ONE_CHIP = {"layer": phase_layer, "serve": phase_serve,
             "serve_hybrid": phase_serve_hybrid, "train": phase_train}
+#: parts of a phase that ``--only`` may name alone (a whole run has them
+#: inside their phase)
+PARTS = {"serve_sdar": phase_serve_sdar}
 FOUR_CHIPS = {"ep4_layer": phase_ep4_layer, "ep4_fused": phase_ep4_fused,
               "ep4_serve": phase_ep4_serve}
 
@@ -969,7 +1069,8 @@ def main(argv=None) -> int:
 
     phases = ONE_CHIP if args.chips == 1 else FOUR_CHIPS
     if args.only:
-        phases = {name: phases[name] for name in args.only.split(",")}
+        known = dict(phases, **(PARTS if args.chips == 1 else {}))
+        phases = {name: known[name] for name in args.only.split(",")}
     shared = None
     failed = []
     t_all = time.perf_counter()

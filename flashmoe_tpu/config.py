@@ -234,6 +234,17 @@ class MoEConfig:
     # row-major order before every kernel that takes it (PERF.md section
     # 6, PR 39).  Serving only: a gradient would fill the zeros.
     intermediate_pad: int = 0
+    # generation by diffusion over blocks: the model generates
+    # ``block_length`` positions together (0: a token at a time, as every
+    # other model here).  Attention is then BLOCK-causal in prefill and in
+    # generation alike (a position sees every earlier block and its OWN
+    # block in both directions: ``attn_block``), a block starts as the
+    # ``mask_token_id`` token's embedding wherever the prompt's tail does
+    # not open it, and is revealed over denoising forwards, then committed
+    # (``models/generate.generate_blocks``, the serving engine's
+    # ``_paged_denoise_step``).  A power of two; every layer a K/V layer.
+    block_length: int = 0
+    mask_token_id: int = 0
 
     # --- numerics ---
     dtype: Any = jnp.bfloat16
@@ -523,6 +534,25 @@ class MoEConfig:
             raise ValueError(
                 "qk_norm norms the heads of an 'mha' layer's q and k: an "
                 "'mla' layer norms its latent")
+        if self.block_length:
+            bl = self.block_length
+            if bl < 2 or bl & (bl - 1):
+                raise ValueError(
+                    f"block_length={bl} must be 0 (a token at a time) or a "
+                    f"power of two >= 2 (a query sees up to "
+                    f"``pos | (block_length - 1)``)")
+            other = sorted({m for m in self.mixers if m is not None}
+                           - {"mha"})
+            if other:
+                raise NotImplementedError(
+                    f"block_length={bl} with {other} layers: generation by "
+                    f"blocks rewrites a block's cached rows in place, "
+                    f"forward after forward; a state a slot that can be "
+                    f"taken back, or a block-causal latent arm, is missing")
+            if not 0 <= self.mask_token_id < self.vocab_size:
+                raise ValueError(
+                    f"mask_token_id={self.mask_token_id} lies outside the "
+                    f"vocabulary of {self.vocab_size}")
         if not self.use_rope and self.attention_kind != "mha":
             raise ValueError(
                 "use_rope=False is an 'mha' layer's: an 'mla' layer's "
@@ -801,6 +831,13 @@ class MoEConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def attn_block(self) -> int:
+        """Positions an attention layer's mask treats as ONE: a query at
+        ``pos`` sees keys up to ``pos | (attn_block - 1)``.  1 is causal
+        attention; ``block_length`` for a model that generates by blocks."""
+        return self.block_length or 1
 
     @functools.cached_property
     def layers(self) -> tuple:

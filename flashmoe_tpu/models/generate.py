@@ -21,6 +21,12 @@ continuous-batching engine (:mod:`flashmoe_tpu.serving.engine`) builds
 on.  That engine's batch mixes requests, so it has a sampler of its own
 with the knobs as vectors; tests/test_serving.py holds its rows to
 :func:`sample_tokens`' tokens.
+
+A model that generates by diffusion over blocks (``cfg.block_length``)
+has a loop of its own beside the token loop, :func:`generate_blocks`: a
+block of positions starts masked, ``denoise_steps`` forwards reveal it by
+one of :data:`REVEAL_RULES` (:func:`reveal_rows`, the engine's rule too)
+and one more forward of the clean block commits its K/V.
 """
 
 from __future__ import annotations
@@ -279,18 +285,157 @@ def sample_tokens(logits, key, *, temperature: float = 0.0,
     return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
 
 
+#: how a denoising step picks the masked rows of a block it reveals, n of
+#: them a step (``block_length / denoise_steps``): the n of highest
+#: confidence (ties to the lower index); the n leftmost; every row whose
+#: confidence is over a threshold, and the n of highest confidence where
+#: fewer are
+REVEAL_RULES = ("low_confidence_static", "sequential",
+                "low_confidence_dynamic")
+
+
+def reveal_rows(logits, masked, n, *, rule: str, threshold: float,
+                mask_token_id: int):
+    """One denoising step's choice over a block a row.  logits:
+    [B, L, V] float32, row i's logits position i's OWN token (no shift);
+    masked: [B, L] bool; n: [B] int32, the rows a batch row reveals at
+    least (0: none, a commit or an idle row).  Greedy: ``x0`` is the
+    ``argmax`` with the ``[MASK]`` id left out, its confidence
+    ``softmax(logits)[x0]``.  Returns (x0 [B, L] int32, reveal [B, L]
+    bool, a subset of ``masked``).  ONE rule for :func:`generate_blocks`
+    and the serving engine's denoise program."""
+    if rule not in REVEAL_RULES:
+        raise ValueError(f"reveal rule {rule!r} not in {REVEAL_RULES}")
+    b, l, v = logits.shape
+    logits = jnp.where(jnp.arange(v) == mask_token_id,
+                       jnp.float32(-1e30), logits)
+    top = jnp.max(logits, axis=-1)
+    x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    conf = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
+    idx = jnp.arange(l)
+    key = (jnp.broadcast_to(-idx.astype(jnp.float32), (b, l))
+           if rule == "sequential" else conf)
+    key = jnp.where(masked, key, -jnp.inf)
+    ahead = ((key[:, None, :] > key[:, :, None])
+             | ((key[:, None, :] == key[:, :, None])
+                & (idx[None, None, :] < idx[None, :, None])))
+    reveal = masked & (jnp.sum(ahead, axis=-1) < n[:, None])
+    if rule == "low_confidence_dynamic":
+        over = masked & (conf > threshold) & (n > 0)[:, None]
+        reveal = jnp.where((jnp.sum(over, axis=-1) >= n)[:, None], over,
+                           reveal)
+    return x0, reveal
+
+
+@functools.partial(
+    jax.jit, static_argnames=("cfg", "max_new_tokens", "denoise_steps",
+                              "reveal_rule", "reveal_threshold",
+                              "stop_tokens", "pad_token", "with_logits"))
+def generate_blocks(params, prompt, cfg: MoEConfig, *,
+                    max_new_tokens: int = 32,
+                    denoise_steps: int | None = None,
+                    reveal_rule: str = "low_confidence_static",
+                    reveal_threshold: float = 0.9,
+                    stop_tokens: tuple = (), pad_token: int = 0,
+                    with_logits: bool = False):
+    """Greedy generation by diffusion over blocks of ``cfg.block_length``
+    (L) positions, over the dense cache: the oracle of the serving
+    engine's denoise path.
+
+    The first ``T0 // L * L`` prompt tokens are prefilled under the
+    block-causal mask; the prompt's tail opens the first block already
+    revealed and every other position starts as ``cfg.mask_token_id``.
+    A block takes ``denoise_steps`` (S, a divisor of L; None: L) forwards
+    of its L rows as they stand, each revealing ``L / S`` masked rows by
+    ``reveal_rule`` (:func:`reveal_rows`; a step that finds none masked
+    reveals none), and ONE more forward of the clean block that writes
+    the K/V later blocks read (the commit).  A row's stop token ends it:
+    the stop token is emitted, later positions are ``pad_token``.
+
+    prompt: [B, T0] int32.  Returns (tokens [B, T0 + max_new_tokens],
+    steps [B, max_new_tokens]: the denoising step that revealed each
+    answer position; and with ``with_logits`` the float32 logits of every
+    denoising forward, [blocks, S, B, L, V])."""
+    bl = cfg.block_length
+    if not bl:
+        raise ValueError("generate_blocks needs a config with a "
+                         "block_length")
+    s_steps = denoise_steps or bl
+    if bl % s_steps:
+        raise ValueError(f"denoise_steps={s_steps} must divide "
+                         f"block_length={bl}")
+    b, t0 = prompt.shape
+    t_pre = t0 // bl * bl
+    tail = t0 - t_pre
+    nb = -(-(tail + max_new_tokens) // bl)
+    cache = init_cache(cfg, b, t_pre + nb * bl)
+    if t_pre:
+        _, cache = prefill_forward(params, cfg, prompt[:, :t_pre], cache)
+    open_toks = jnp.full((b, nb * bl), cfg.mask_token_id, jnp.int32)
+    open_toks = open_toks.at[:, :tail].set(prompt[:, t_pre:])
+    n_reveal = jnp.full((b,), bl // s_steps, jnp.int32)
+    embed = params["embed"].astype(cfg.dtype)
+
+    def forward(cache, toks, masked, pos):
+        feed = jnp.where(masked, jnp.int32(cfg.mask_token_id), toks)
+        x, cache = _dense_span(params, cfg, embed[feed], cache, pos,
+                               absorbed=True)
+        return lm_logits_span(params, cfg, x), cache
+
+    def block(cache, xs):
+        toks, first, pos = xs
+        masked = jnp.arange(bl)[None, :] >= first
+        steps = jnp.full((b, bl), -1, jnp.int32)
+        seen = []
+        for s in range(s_steps):
+            logits, cache = forward(cache, toks, masked, pos)
+            x0, reveal = reveal_rows(
+                logits, masked, n_reveal, rule=reveal_rule,
+                threshold=reveal_threshold,
+                mask_token_id=cfg.mask_token_id)
+            toks = jnp.where(reveal, x0, toks)
+            steps = jnp.where(reveal, s, steps)
+            masked = masked & ~reveal
+            seen.append(logits)
+        _, cache = forward(cache, toks, masked, pos)        # the commit
+        return cache, (toks, steps,
+                       jnp.stack(seen) if with_logits else None)
+
+    firsts = jnp.where(jnp.arange(nb) == 0, tail, 0).astype(jnp.int32)
+    _, (toks, steps, logits) = jax.lax.scan(
+        block, cache,
+        (open_toks.reshape(b, nb, bl).transpose(1, 0, 2),
+         jnp.broadcast_to(firsts[:, None, None], (nb, b, 1)),
+         t_pre + bl * jnp.arange(nb, dtype=jnp.int32)))
+    flat = lambda a: a.transpose(1, 0, 2).reshape(b, nb * bl)[
+        :, tail:tail + max_new_tokens]
+    new, steps = flat(toks), flat(steps)
+    if stop_tokens:
+        stopped = jnp.isin(new, jnp.asarray(stop_tokens, jnp.int32))
+        after = jnp.cumsum(stopped, axis=1) - stopped > 0
+        new = jnp.where(after, jnp.int32(pad_token), new)
+    out = jnp.concatenate([prompt, new], axis=1), steps
+    return (*out, logits) if with_logits else out
+
+
 @functools.partial(
     jax.jit, static_argnames=("cfg", "max_new_tokens", "temperature",
                               "top_k", "top_p", "stop_tokens",
-                              "pad_token", "prefill"),
+                              "pad_token", "prefill", "denoise_steps",
+                              "reveal_rule", "reveal_threshold"),
 )
 def generate(params, prompt, cfg: MoEConfig, *, max_new_tokens: int = 32,
              temperature: float = 0.0, top_k: int = 0, top_p: float = 1.0,
              stop_tokens: tuple = (), pad_token: int = 0, key=None,
-             prefill: str = "auto"):
+             prefill: str = "auto", denoise_steps: int | None = None,
+             reveal_rule: str = "low_confidence_static",
+             reveal_threshold: float = 0.9):
     """Greedy (temperature=0) or sampled decoding.
 
-    prompt: [B, T0] int32.  Returns [B, T0 + max_new_tokens].
+    prompt: [B, T0] int32.  Returns [B, T0 + max_new_tokens].  A config
+    with a ``block_length`` generates by blocks, greedy
+    (:func:`generate_blocks`, which ``denoise_steps``, ``reveal_rule`` and
+    ``reveal_threshold`` are for and which also returns the reveal steps).
 
     ``stop_tokens``: static tuple of token ids that retire a row — the
     stop token itself is emitted, every later position is
@@ -300,6 +445,16 @@ def generate(params, prompt, cfg: MoEConfig, *, max_new_tokens: int = 32,
     (batched for dropless configs, loop when ``drop_tokens`` — whose
     capacity competition is per-step by definition).
     """
+    if cfg.block_length:
+        if temperature != 0.0:
+            raise NotImplementedError(
+                "a block_length with a temperature: generation by blocks "
+                "is greedy here (a keyed draw a position is missing)")
+        return generate_blocks(
+            params, prompt, cfg, max_new_tokens=max_new_tokens,
+            denoise_steps=denoise_steps, reveal_rule=reveal_rule,
+            reveal_threshold=reveal_threshold, stop_tokens=stop_tokens,
+            pad_token=pad_token)[0]
     b, t0 = prompt.shape
     max_len = t0 + max_new_tokens
     cache = init_cache(cfg, b, max_len)

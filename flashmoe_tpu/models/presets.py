@@ -220,6 +220,37 @@ def longcat_flash(**overrides) -> MoEConfig:
     return MoEConfig(**base)
 
 
+def sdar_30b_a3b(**overrides) -> MoEConfig:
+    """SDAR-30B-A3B-Chat (huggingface.co/JetLM/SDAR-30B-A3B-Chat
+    ``config.json``, ``model_type`` sdar_moe): 48 layers alike of width
+    2048, grouped-query attention (32 heads over 4 K/V heads of width 128:
+    the heads' 4096 columns are twice the hidden size; RMSNorm on every
+    head of q and k before RoPE, theta 1e6), 128 experts top-8 of width 768
+    behind a softmax router with renormalised weights, no shared expert,
+    SwiGLU, an untied head over 151936 tokens, RMSNorm eps 1e-6.  The model
+    GENERATES BY DIFFUSION OVER BLOCKS: ``block_length`` 4 (the family's
+    released default for the -Chat checkpoints; the config states none)
+    under block-causal attention, ``mask_token_id`` 151669 (the family's
+    ``<|MASK|>``)."""
+    base = dict(
+        num_experts=128, expert_top_k=8, num_shared_experts=0,
+        hidden_size=2048, intermediate_size=768, num_layers=48,
+        moe_frequency=1, vocab_size=151936, num_heads=32, num_kv_heads=4,
+        head_dim=128, qk_norm=True, norm_eps=1e-6, rope_theta=1e6,
+        router_score="softmax", norm_topk_prob=True,
+        routed_scaling_factor=1.0, sequence_len=4096, gated_ffn=True,
+        hidden_act=Activation.SILU, drop_tokens=False, dtype=jnp.bfloat16,
+        block_length=4, mask_token_id=151669,
+    )
+    base.update(overrides)
+    if "mask_token_id" not in overrides:
+        # a cut vocabulary keeps a [MASK] id inside it (any id serves:
+        # the engine tracks masks by state and never reveals this one)
+        base["mask_token_id"] = min(base["mask_token_id"],
+                                    base["vocab_size"] - 1)
+    return MoEConfig(**base)
+
+
 PRESETS = {
     "mixtral-8x7b": mixtral_8x7b,
     "deepseek-moe-16b": deepseek_moe_16b,
@@ -230,4 +261,5 @@ PRESETS = {
     "lfm2-24b-a2b": lfm2_24b_a2b,
     "nemotron-3-nano-30b-a3b": nemotron3_nano_30b_a3b,
     "longcat-flash": longcat_flash,
+    "sdar-30b-a3b-chat": sdar_30b_a3b,
 }

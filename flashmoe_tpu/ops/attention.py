@@ -359,12 +359,16 @@ def kv_project(layer, x, cfg, positions):
     return q, k, v
 
 
-def kv_attend(layer, q, k_ctx, v_ctx, q_pos):
+def kv_attend(layer, q, k_ctx, v_ctx, q_pos, block: int = 1):
     """Causal attention of T queries a row over a context of K/V rows.
 
     q: [B, T, N, D]; k_ctx / v_ctx: [B, N_kv, S, D], row s the K/V of
     position s; q_pos: [B, T] the queries' positions, consecutive along
     T (a query sees s <= its position: rows past it may hold anything).
+    ``block`` (static, a power of two; ``MoEConfig.attn_block``): a query
+    sees up to the END of its block of that many positions,
+    ``s <= q_pos | (block - 1)``; 1 is the causal mask, letter for letter
+    (a span of a flash tile then starts at a whole block).
     Blockwise through :func:`flash_span_attention` where
     :func:`span_attention_arm` says so (a long span on a TPU; a query
     head reads its K/V head, nothing is repeated), as float32 logits
@@ -374,8 +378,10 @@ def kv_attend(layer, q, k_ctx, v_ctx, q_pos):
     dt = q.dtype
     if span_attention_arm(t, k_ctx.shape[2], nh, (dh,), dh, dt) == "flash":
         ctx = _flash_span_ctx((q,), (k_ctx.astype(dt),), v_ctx.astype(dt),
-                              q_pos, dh ** -0.5)
+                              q_pos, dh ** -0.5, block)
         return ctx @ layer["wo"].astype(dt)
+    if block > 1:
+        q_pos = q_pos | (block - 1)
     if k_ctx.shape[1] != nh:  # GQA: repeat kv heads
         rep = nh // k_ctx.shape[1]
         k_ctx = jnp.repeat(k_ctx, rep, axis=1)
@@ -447,7 +453,8 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
     everything else stores, gathers the context and attends through
     :func:`kv_attend` (:func:`store_kv`, :func:`gather_ctx`; in plain
     XLA, the form the kernel is held against, or for a long span on a
-    TPU blockwise: :func:`span_attention_arm`).
+    TPU blockwise: :func:`span_attention_arm`).  Under
+    ``cfg.attn_block`` > 1 every arm masks by blocks (:func:`kv_attend`).
 
     x: [B, T, H] normed; pools: the ``(k_pages, v_pages)`` pair, each
     [L, P, N_kv, page, D], or None for a whole prompt at once (the
@@ -459,21 +466,25 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
     [B, N_kv, T, D])."""
     q, k, v = kv_project(layer, x, cfg, pos)
     span = (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+    block = cfg.attn_block
     if pools is None:
-        return kv_attend(layer, q, *span, pos), pools, span
+        return kv_attend(layer, q, *span, pos, block), pools, span
     rows, page, width = pools[0].shape[2:]       # the heads as stored
-    if (write[1] is not None and kv_attention_arm(
-            q.shape[1], page, rows, width, pools[0].dtype)
-            == "paged_kernel"):
+    # (under a block mask the kernel knows a span that is ONE block: a
+    # longer span over a cache whose page it fits, generate()'s prefill
+    # over a short dense cache, keeps the gather arm)
+    if (write[1] is not None and block in (1, q.shape[1])
+            and kv_attention_arm(q.shape[1], page, rows, width,
+                                 pools[0].dtype) == "paged_kernel"):
         ctx, pools = paged_decode_attention(
             q, (k, v), pools, li, block_tables, pos[:, 0], write,
-            interpret=jax.default_backend() != "tpu")
+            block=block, interpret=jax.default_backend() != "tpu")
         return ctx @ layer["wo"].astype(q.dtype), pools, span
     pools = (store_kv(pools[0], li, k, *write),
              store_kv(pools[1], li, v, *write))
     k_ctx = gather_ctx(pools[0][li], block_tables, q.shape[-1])
     v_ctx = gather_ctx(pools[1][li], block_tables, q.shape[-1])
-    return kv_attend(layer, q, k_ctx, v_ctx, pos), pools, span
+    return kv_attend(layer, q, k_ctx, v_ctx, pos, block), pools, span
 
 
 #: the mixers of ``config.STATE_MIXERS``: each takes ``(layer, x, cfg,
@@ -600,7 +611,7 @@ def paged_decode_block_pages(page: int, n_tab: int, n_kv: int, d: int,
 
 def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
                          q_ref, *refs, n_pools, t, rep, page, bp, n_tab,
-                         scale):
+                         scale, block=1):
     """Grid: (B,), one slot a step.  li_ref: [1] the layer; tab_ref /
     pos_ref / wpage_ref / wrow_ref: the block tables, positions and write
     targets, flat.  q_ref: [1, N_kv, R, D], row ``t * rep + g`` the query
@@ -707,14 +718,15 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
          jnp.zeros((nkv, r_pad, dv), jnp.float32)))
 
     # the span's own rows, causal among themselves: query row r (column
-    # r // rep of the span) sees span row c iff c * rep <= r
+    # r // rep of the span) sees span row c iff c * rep <= r; a span that
+    # IS one block of a block-causal model (``block`` == t, its first row
+    # at a whole block) sees all of itself
     new = [span[0] for span in spans]                       # [N_kv, Tp, D]
     s = jnp.einsum("hrd,hcd->hrc", q, new[0], **f32) * scale
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    _, l, acc = attend(
-        carry, jnp.where((col * rep <= row) & (col < t), s, NEG_INF),
-        values(new[-1]))
+    seen = col < t if block > 1 else (col * rep <= row) & (col < t)
+    _, l, acc = attend(carry, jnp.where(seen, s, NEG_INF), values(new[-1]))
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
     # the span's rows into their pages
@@ -749,11 +761,13 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("v_width", "scale",
-                                             "block_pages", "interpret"))
+                                             "block_pages", "block",
+                                             "interpret"))
 def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
                            v_width: int | None = None,
                            scale: float | None = None,
                            block_pages: int | None = None,
+                           block: int = 1,
                            interpret: bool = False):
     """Causal attention of a short span a slot over the slot's own pages,
     read in place, and the span's rows written into them.  Jitted with
@@ -771,7 +785,9 @@ def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
     each slot's first span row, the pool holding positions before it;
     write: ``(page_ids, rows)``, each [B, T], where the span's rows go
     (consecutive rows: at most two pages a slot); scale: of the scores,
-    ``D ** -0.5`` unless given.  Returns (the heads' outputs
+    ``D ** -0.5`` unless given; block: 1, or the span IS one block of a
+    block-causal model (T == block, ``pos`` a whole block: every row of
+    the span sees all of it).  Returns (the heads' outputs
     [B, T, N * Dv], the pools).  What :func:`store_kv`,
     :func:`gather_ctx` and the softmax of :func:`kv_attend` give (or
     :func:`store_latent`, :func:`gather_latent` and the absorbed
@@ -780,6 +796,10 @@ def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
     the product with the values."""
     n_pools, dt = len(pools), pools[0].dtype
     nkv, page, width = pools[0].shape[2:]
+    if block > 1 and q.shape[1] != block:
+        raise NotImplementedError(
+            f"a span of {q.shape[1]} rows under a block mask of {block}: "
+            f"the kernel's in-span mask knows a span that is ONE block")
     pack = width // q.shape[-1]
     if pack > 1:
         # a pool of packed heads: the kernel is handed rows of whole
@@ -797,7 +817,7 @@ def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
             wide.reshape(b, t, nh, width),
             [x.reshape(b, t, nkv, width) for x in span], pools, li,
             block_tables, pos, write, scale=scale or d ** -0.5,
-            block_pages=block_pages, interpret=interpret)
+            block_pages=block_pages, block=block, interpret=interpret)
         out = jnp.einsum(
             "btrpgqd,pq->btrpgd",
             out.reshape(b, t, nkv, pack, -1, pack, d), apart)
@@ -825,7 +845,8 @@ def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
     out, *pools = pl.pallas_call(
         functools.partial(
             _paged_decode_kernel, n_pools=n_pools, t=t, rep=rep, page=page,
-            bp=bp, n_tab=n_tab, scale=scale or d ** -0.5),
+            bp=bp, n_tab=n_tab, scale=scale or d ** -0.5,
+            **({"block": block} if block > 1 else {})),
         name="fm_paged_decode" if n_pools == 2 else "fm_latent_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
@@ -979,11 +1000,16 @@ def _nn(a, b):
                                preferred_element_type=jnp.float32)
 
 
-def _causal_keep(q_start, k_start, shape, q_axis):
+def _causal_keep(q_start, k_start, shape, q_axis, block: int = 1):
     """Where a [.., ..] tile of scores keeps its value: query position >=
-    key position; ``q_axis`` is the tile's axis of queries."""
+    key position; ``q_axis`` is the tile's axis of queries.  ``block`` > 1:
+    the END of the query's block of that many positions >= the key's (a
+    tile starts at a whole block, so which tiles are live, crossed or
+    fetched does not move: :func:`_flash_step`, :func:`_last_live`)."""
     qpos = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis) + q_start
     kpos = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis) + k_start
+    if block > 1:
+        qpos = qpos | (block - 1)
     return qpos >= kpos
 
 
@@ -995,7 +1021,7 @@ def _rows_to_column(row):
 
 
 def _flash_kernel(*refs, scale, causal, block_q, block_k, parts=1,
-                  heads=None, lse=True):
+                  heads=None, lse=True, block=1):
     """Grid: (B*N, Tq/block_q, Tk/block_k) — kv innermost, accumulating the
     online softmax in VMEM scratch.  m/l scratch is lane-width (bq, 128)
     holding broadcast copies to keep TPU layouts happy, like the upstream
@@ -1006,7 +1032,7 @@ def _flash_kernel(*refs, scale, causal, block_q, block_k, parts=1,
     first query (query row i sees context rows ``s <= pos + i``); then the
     ``parts`` query blocks and the ``parts`` key blocks (the score is the
     sum of the parts' products), v_ref, o_ref, lse_ref (where ``lse``) and
-    the scratch m, l, acc."""
+    the scratch m, l, acc.  ``block``: the mask's (:func:`_causal_keep`)."""
     if heads is not None:
         pos_ref, *refs = refs
     qs, ks = refs[:parts], refs[parts:2 * parts]
@@ -1034,7 +1060,7 @@ def _flash_kernel(*refs, scale, causal, block_q, block_k, parts=1,
             s = s + _nt(q_ref[0], k_ref[0])
         s = s * scale
         if masked:
-            s = jnp.where(_causal_keep(q_start, k_start, s.shape, 0),
+            s = jnp.where(_causal_keep(q_start, k_start, s.shape, 0, block),
                           s, NEG_INF)
         m_prev = m_scr[:, :1]                   # [bq, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -1302,8 +1328,8 @@ def attention_widths(cfg) -> tuple[tuple[int, ...], int]:
     return (cfg.resolved_head_dim,), cfg.resolved_head_dim
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def flash_span_attention(q, k, v, q_pos0, *, scale: float,
+@functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
+def flash_span_attention(q, k, v, q_pos0, *, scale: float, block: int = 1,
                          interpret: bool = False):
     """Causal attention of a span of queries over a context that starts
     before it: the forward flash kernel (``_flash_kernel``: the training
@@ -1322,7 +1348,11 @@ def flash_span_attention(q, k, v, q_pos0, *, scale: float,
     nor computed, only blocks the shifted diagonal crosses are masked).
     Products on the operands' dtype with float32 results, float32 scale,
     mask, statistics and accumulator, the probabilities cast to ``v``'s
-    dtype.  No [Tq, Tk] array exists.  Returns [B, N, Tq, Dv]."""
+    dtype.  ``block`` > 1 (a power of two that divides the tile; q_pos0 a
+    whole block): a query sees up to the end of its block of that many
+    positions, ``s <= (q_pos0[b] + i) | (block - 1)``; only the tiles the
+    diagonal crosses mask differently.  No [Tq, Tk] array exists.  Returns
+    [B, N, Tq, Dv]."""
     b, n, tq, _ = q[0].shape
     tk, dv = v.shape[2:]
     lanes = lambda w: -(-w // LANE) * LANE      # a block's width in VMEM
@@ -1345,7 +1375,8 @@ def flash_span_attention(q, k, v, q_pos0, *, scale: float,
     out = pl.pallas_call(
         functools.partial(
             _flash_kernel, scale=scale, causal=True, block_q=bq, block_k=bk,
-            parts=len(q), heads=n, lse=False),
+            parts=len(q), heads=n, lse=False,
+            **({"block": block} if block > 1 else {})),
         name="fm_flash_span",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -1365,7 +1396,7 @@ def flash_span_attention(q, k, v, q_pos0, *, scale: float,
     return out.reshape(b, n, tq, dv)
 
 
-def _flash_span_ctx(q, k, v, q_pos, scale: float):
+def _flash_span_ctx(q, k, v, q_pos, scale: float, block: int = 1):
     """:func:`flash_span_attention` as the cached attention calls it: the
     query's parts laid out [B, T, N, d_i], q_pos [B, T] consecutive along
     T, interpreted off a TPU.  Returns the heads' outputs side by side,
@@ -1373,7 +1404,7 @@ def _flash_span_ctx(q, k, v, q_pos, scale: float):
     b, t = q[0].shape[:2]
     out = flash_span_attention(
         tuple(part.transpose(0, 2, 1, 3) for part in q), k, v, q_pos[:, 0],
-        scale=scale, interpret=jax.default_backend() != "tpu")
+        scale=scale, block=block, interpret=jax.default_backend() != "tpu")
     return out.transpose(0, 2, 1, 3).reshape(b, t, -1)
 
 

@@ -28,6 +28,12 @@ slowest member:
   first (the victim's resumed prompt is built from them), as does every
   step with speculation armed (the drafts are).
 
+A model that generates by diffusion over blocks (``cfg.block_length``)
+takes the same path with another step: a slot's step is a BLOCK of
+positions scored together (:func:`_paged_denoise_step`), revealed over
+``ServeConfig.denoise_steps`` forwards and then committed, and its tokens
+are delivered a block at a time (see :meth:`ServingEngine._denoise`).
+
 Everything host-side is a pure function of the submitted requests and
 their arrival steps, and the page allocator is LIFO — so a seeded drill
 replays bit-identically on CPU, which is what makes the engine
@@ -64,7 +70,7 @@ import numpy as np
 
 from flashmoe_tpu.config import STATE_MIXERS, MoEConfig
 from flashmoe_tpu.models.generate import (
-    lm_logits, lm_logits_span, span_forward,
+    REVEAL_RULES, lm_logits, lm_logits_span, reveal_rows, span_forward,
 )
 from flashmoe_tpu.ops import attention
 from flashmoe_tpu.ops.moe import expert_arm
@@ -121,6 +127,9 @@ class Request:
     top_p: float = 1.0
     stop_tokens: tuple = ()
     seed: int = 0
+    #: denoising forwards a block (a model that generates by blocks; None:
+    #: ``ServeConfig.denoise_steps``)
+    denoise_steps: int | None = None
 
     def __post_init__(self):
         if not self.prompt:
@@ -158,7 +167,14 @@ class ServeConfig:
     (only canonical samples are ever emitted), and because the config
     rides ``ServeConfig`` it reaches every fabric replica, so
     speculation survives pool handoff and replica migration for
-    free."""
+    free.
+
+    ``denoise_steps`` / ``reveal_rule`` / ``reveal_threshold`` are read by
+    a model that generates by blocks alone (``MoEConfig.block_length``):
+    the denoising forwards a block (a divisor of the block length; None:
+    the block length, one token a forward), the rule that picks the rows
+    a forward reveals (``models/generate.REVEAL_RULES``) and the
+    confidence ``low_confidence_dynamic`` reveals every row over."""
 
     max_batch: int = 8
     page_size: int = 8
@@ -171,8 +187,16 @@ class ServeConfig:
     prefill_chunk: int | None = None
     ep_shards: int = 1
     speculate: SpecConfig | None = None
+    denoise_steps: int | None = None
+    reveal_rule: str = "low_confidence_static"
+    reveal_threshold: float = 0.9
 
     def __post_init__(self):
+        if self.reveal_rule not in REVEAL_RULES:
+            raise ValueError(f"reveal_rule {self.reveal_rule!r} not in "
+                             f"{REVEAL_RULES}")
+        if self.denoise_steps is not None and self.denoise_steps < 1:
+            raise ValueError("denoise_steps must be >= 1")
         if self.speculate is not None \
                 and not isinstance(self.speculate, SpecConfig):
             raise ValueError(
@@ -277,6 +301,15 @@ class _Slot:
     prefill_ms: float | None = None  # admission to first token
     last_token_s: float | None = None
     gap_max_ms: float = 0.0         # widest gap between two tokens
+    # ---- a model that generates by blocks (see ServingEngine._denoise) --
+    tail: tuple = ()                # the prompt's tokens past its last
+                                    # whole block: they open the first
+                                    # block, already revealed
+    block_masked: int | None = None  # rows of the open block still
+                                     # masked, by count (None: no block
+                                     # is open; -1: ask the device)
+    block_step: int = 0             # denoising forwards it has had
+    block_first: int = 0            # its rows the prompt's tail filled
 
 
 # ----------------------------------------------------------------------
@@ -431,7 +464,51 @@ def _paged_verify_step(params, cfg: MoEConfig, pools, toks,
     return lm_logits_span(params, cfg, x), pools
 
 
-# The same three programs with the cache DONATED: the pool is updated in
+@functools.partial(jax.jit, static_argnames=("cfg", "pad_token", "rule",
+                                             "threshold"))
+def _paged_denoise_step(params, cfg: MoEConfig, pools, state, ctl,
+                        block_tables, pad_token=None,
+                        rule: str = "low_confidence_static",
+                        threshold: float = 0.9):
+    """One forward of every slot's OPEN BLOCK (a model that generates by
+    diffusion over blocks, L = ``cfg.block_length``): the span path at
+    T = L under the block mask, the head on every row, then on the device
+    the greedy choice, its confidence and the rows to reveal
+    (``generate.reveal_rows``).  Denoising and committing are this ONE
+    program: every forward writes the block's K/V rows in place, and the
+    commit is the forward whose rows are all revealed (it reveals none),
+    so slots at different steps of their blocks share a launch.
+
+    state: [B, 3, L] int32, what the last launch left of each slot's
+    block: its tokens, which rows are masked, the step that revealed each
+    row (-1: the prompt's tail, or masked still); the feed of this launch
+    where it lies.  ctl: [B, 4 + L] int32 from the host, a row a slot:
+    the block's first position, the rows to reveal (0: a commit, or a row
+    that is not fed), the denoising step's index, and ``first``: -1 keeps
+    the slot's state; >= 0 OPENS a block whose first ``first`` rows are
+    the tokens in columns 4.., the others masked.  block_tables: [B, n].
+    A row whose table is all scratch is fed ``pad_token`` and keeps its
+    state.  Returns (state, pools, what the layers counted)."""
+    bl = cfg.block_length
+    pos, n, step, first = (ctl[:, j] for j in range(4))
+    opened = (first >= 0)[:, None]
+    toks = jnp.where(opened, ctl[:, 4:], state[:, 0])
+    masked = jnp.where(opened, jnp.arange(bl)[None, :] >= first[:, None],
+                       state[:, 1] > 0)
+    steps = jnp.where(opened, -1, state[:, 2])
+    feed = jnp.where(masked, jnp.int32(cfg.mask_token_id), toks)
+    x, pools, counted = _span_step(params, cfg, pools, feed, block_tables,
+                                   pos, pad_token=pad_token)
+    x0, reveal = reveal_rows(
+        lm_logits_span(params, cfg, x), masked, n, rule=rule,
+        threshold=threshold, mask_token_id=cfg.mask_token_id)
+    state = jnp.stack([jnp.where(reveal, x0, toks),
+                       (masked & ~reveal).astype(jnp.int32),
+                       jnp.where(reveal, step[:, None], steps)], axis=1)
+    return state, pools, counted
+
+
+# The same programs with the cache DONATED: the pool is updated in
 # place and the caller's arrays die with the call.  These are the programs
 # the engine runs, for either cache kind: beside the weights the chip has
 # no room for the input pool, the output pool and the pool of a prefill
@@ -444,7 +521,9 @@ _INPLACE = {
     fn.__name__: jax.jit(fn.__wrapped__, static_argnames=("cfg", *static),
                          donate_argnames=("pools",))
     for fn, *static in ((_prefill_chunk,), (_paged_decode_step, "pad_token"),
-                        (_paged_verify_step,))}
+                        (_paged_verify_step,),
+                        (_paged_denoise_step, "pad_token", "rule",
+                         "threshold"))}
 
 #: ``store_prefill`` with the pool donated, as ONE program: an admission
 #: writes a prompt's pages into the pool where it lies (called eagerly, the
@@ -682,6 +761,36 @@ class ServingEngine:
                         f"recurrent-state layers (a state a slot: "
                         f"{sorted(set(cfg.mixers) & set(STATE_MIXERS))}) "
                         f"with {what}: {lack} is missing")
+        if cfg.block_length:
+            bl = cfg.block_length
+            missing = {
+                "speculate": (sv.speculate is not None,
+                              "a verify span that is no whole block under "
+                              "the block mask"),
+                "ep_shards > 1": (sv.ep_shards > 1,
+                                  "an EP-sharded twin of "
+                                  "_paged_denoise_step"),
+                "a prefill_fn (the fabric's KV handoff)": (
+                    prefill_fn is not None,
+                    "a handoff of a prompt's whole blocks with its tail"),
+            }
+            for what, (asked, lack) in missing.items():
+                if asked:
+                    raise NotImplementedError(
+                        f"generation by blocks (block_length={bl}) with "
+                        f"{what}: {lack} is missing")
+            for name, size in (("page_size", sv.page_size),
+                               ("prompt_bucket", sv.prompt_bucket),
+                               ("prefill_chunk", sv.prefill_chunk)):
+                if size is not None and size % bl:
+                    raise ValueError(
+                        f"{name}={size} must be whole blocks of "
+                        f"block_length={bl} (a block lies in one page, a "
+                        f"prefill span starts and ends at a block's edge)")
+            if bl % (sv.denoise_steps or bl):
+                raise ValueError(
+                    f"denoise_steps={sv.denoise_steps} must divide "
+                    f"block_length={bl}")
         if mla and sv.ep_shards > 1:
             raise NotImplementedError(
                 "attention_kind='mla' with ep_shards > 1: _ep_decode_fn "
@@ -864,6 +973,16 @@ class ServingEngine:
         # bytes a slot's state costs over the state layers, whatever its
         # context (0: every layer caches rows a token)
         self.metrics.gauge("serve.state_slot_bytes", cfg.state_slot_bytes)
+        # generation by blocks: every slot's open block as the last launch
+        # left it (the denoise program's feed, where it lies), what this
+        # step's launch did by the host's count (slots fed, rows revealed,
+        # commits, rows fed masked), and per request the denoising step
+        # that revealed each delivered token
+        self._block_state = (
+            jnp.zeros((self.serve.max_batch, 3, cfg.block_length),
+                      jnp.int32) if cfg.block_length else None)
+        self._block_counts = None
+        self.reveal_steps: dict[int, list] = {}
         self.queue: deque = deque()       # (arrival_step, _Slot-seed)
         self.slots: list[_Slot | None] = [None] * self.serve.max_batch
         self._logits = jnp.zeros(
@@ -969,6 +1088,16 @@ class ServingEngine:
     # ---- submission --------------------------------------------------
 
     def submit(self, req: Request, arrival_step: int = 0) -> None:
+        bl = self.cfg.block_length
+        if bl and req.temperature > 0.0:
+            raise NotImplementedError(
+                f"request {req.rid}: temperature={req.temperature} with "
+                f"generation by blocks: the reveal rules rank greedy "
+                f"choices; a keyed draw a position is missing")
+        if bl and bl % (req.denoise_steps or bl):
+            raise ValueError(
+                f"request {req.rid}: denoise_steps={req.denoise_steps} "
+                f"must divide block_length={bl}")
         # the BUCKETED full lifetime must fit the slot context, so an
         # evicted request's resumed (longer, re-bucketed) prompt plus
         # its remaining budget is covered by the same bound
@@ -1100,7 +1229,14 @@ class ServingEngine:
             entry = self.queue[0]
             req, orig = entry.req, entry.orig
             t0 = len(req.prompt)
-            t_pad = prompt_pad(t0, sv.prompt_bucket)
+            blocks = {}
+            if self.cfg.block_length:
+                # only the prompt's WHOLE blocks are prefilled (a pad row
+                # in the last token's block would be seen under the block
+                # mask); its tail opens the first block
+                t0 -= t0 % self.cfg.block_length
+                blocks = {"tail": tuple(req.prompt[t0:])}
+            t_pad = prompt_pad(t0, sv.prompt_bucket) if t0 else 0
             chunk = sv.prefill_chunk
             # a handed-off prefill is always whole: the fabric's
             # prefill pool absorbs the long prompt, so chunking (the
@@ -1144,19 +1280,25 @@ class ServingEngine:
                 # the chunk holding the prompt's last token)
                 t_pad_c = ((t_pad + chunk - 1) // chunk) * chunk
                 toks = np.full((t_pad_c,), sv.pad_token, np.int32)
-                toks[:t0] = req.prompt
+                toks[:t0] = req.prompt[:t0]
                 self.slots[slot] = _Slot(
                     req=req, orig=orig, pages=list(pages), length=0,
                     emitted=[], admit_step=self.step_idx,
                     arrival_s=entry.arrival_s,
                     first_token_s=entry.first_token_s,
-                    prefill_pos=0, prefill_toks=toks, **account)
+                    prefill_pos=0, prefill_toks=toks, **account, **blocks)
                 self.stats["prefill_buckets"].add(chunk)
+            elif not t0:
+                # a prompt shorter than a block: nothing to prefill
+                self.slots[slot] = _Slot(
+                    req=req, orig=orig, pages=[], length=0, emitted=[],
+                    admit_step=self.step_idx, arrival_s=entry.arrival_s,
+                    first_token_s=entry.first_token_s, **account, **blocks)
             else:
                 fed = self._clock(), time.time_ns()
                 with trace_span("serve.prefill_feed"):
                     # eager uploads and a pad program, one by one
-                    prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+                    prompt = jnp.asarray(req.prompt[:t0], jnp.int32)[None, :]
                     if t_pad > t0:
                         prompt = jnp.pad(
                             prompt, ((0, 0), (0, t_pad - t0)),
@@ -1180,14 +1322,17 @@ class ServingEngine:
                             self.cache, seqs,
                             slot_state_fields(self.cache))))
                     self._state_bytes += self.cfg.state_slot_bytes
-                self._put_logits(slot, logits)
+                if blocks:      # no next-token logits: nothing reads them
+                    self._last_out = logits
+                else:
+                    self._put_logits(slot, logits)
                 self._note_prefill(orig.rid, slot, "whole", 0, t0, t_pad,
                                    t_pad, fed, starved)
                 self.slots[slot] = _Slot(
                     req=req, orig=orig, pages=list(pages), length=t0,
                     emitted=[], admit_step=self.step_idx,
                     arrival_s=entry.arrival_s,
-                    first_token_s=entry.first_token_s, **account)
+                    first_token_s=entry.first_token_s, **account, **blocks)
                 self.stats["prefill_buckets"].add(t_pad)
             self._rates["admits"].add()
             self.stats["admitted"] += 1
@@ -1216,7 +1361,7 @@ class ServingEngine:
             if s is None or s.prefill_pos is None:
                 continue
             pos = s.prefill_pos
-            t0 = len(s.req.prompt)
+            t0 = len(s.req.prompt) - len(s.tail)
             # this chunk's pages (first chunk's were allocated at
             # admission); eviction fallback mirrors _grow_pages
             need_pages = (pos + chunk) // sv.page_size
@@ -1269,7 +1414,8 @@ class ServingEngine:
             s.prefill_pos = pos + chunk
             if pos <= t0 - 1 < pos + chunk:
                 # prefill complete — arm the sampler, join decode
-                self._put_logits(i, logits)
+                if not self.cfg.block_length:
+                    self._put_logits(i, logits)
                 s.prefill_pos = None
                 s.prefill_toks = None
                 s.length = t0
@@ -1727,10 +1873,13 @@ class ServingEngine:
         median_ms = sk_host.quantile(0.5) + sk_between.quantile(0.5)
         if lag_ms <= max(_STALL_FACTOR * median_ms, _STALL_FLOOR_MS):
             return None
-        # the wait lies inside serve.sample: the rest is the host's
+        # the wait lies inside serve.sample (serve.reveal for a model that
+        # generates by blocks): the rest is the host's
         host_phases = dict(self._phase_ms)
-        host_phases["serve.sample"] = (host_phases.get("serve.sample", 0.0)
-                                       - self._wait_ms)
+        waited_in = ("serve.reveal" if self.cfg.block_length
+                     else "serve.sample")
+        host_phases[waited_in] = (host_phases.get(waited_in, 0.0)
+                                  - self._wait_ms)
         stall = {
             "kind": "serve_stall", "step": self.step_idx,
             "median_ms": round(median_ms, 3),
@@ -1843,57 +1992,16 @@ class ServingEngine:
                     self._retire(i, s)
         return len(rows)
 
-    def step(self) -> dict:
-        """One engine iteration: admit -> sample -> grow -> decode ->
-        read the tokens, deliver, retire (the decode program is dispatched
-        AHEAD of the step's one read-back); sample -> read, deliver,
-        retire -> grow -> decode on a step where the host needs the tokens
-        in between (speculation armed, growth that would evict).  Either
-        way every token sampled in the step is delivered and every
-        finished request retired when it returns.  Returns the step's
-        flight record (also appended to the recorder when one is
-        attached)."""
-        with trace_span("serve.step", step=self.step_idx):
-            try:
-                return self._step()
-            finally:
-                if self._phase_open is not None:    # the step raised
-                    self._phase(None)
-
-    def _step(self) -> dict:
-        sv = self.serve
-        compiles0, compile_s0 = compile_totals()
-        gc_n0, gc_s0 = gc_totals()
-        self._phase_ms = {}
-        self._delivered_now = {}
-        self._ctx_pages = (0, 0.0, 0, None, None)
-        self._sampled = np.zeros((3,), np.int64)
-        self._state_bytes = 0
-        self._wait_ms = 0.0
-        self._starved, self._starved_at = 0, None
-        self._prefills = [0, 0, 0]
-        if self._counted is not None:
-            self._counted_prev, self._counted = self._counted, None
-        # the same instant on the profiler's clock and on the engine's
-        t0_trace_ns, cpu0_s = time.time_ns(), time.thread_time()
-        t0_s = self._phase("serve.admit")
-        if self.tracer is not None:
-            # open the step window BEFORE admissions: everything in
-            # this step (a neighbour's prefill compile included) rides
-            # a serve.step span on each active request's track
-            self.tracer.begin_step(
-                self.step_idx,
-                [self.slots[i].orig.rid for i in self._active()])
-        self._mark_arrivals()
-        admitted0 = self.stats["admitted"]
-        retired0 = self.stats["completed"]
-        self._admit()
-        self._phase("serve.prefill_advance", beat="admit")
-        self._advance_prefill()
-
+    def _decode_tokens(self):
+        """A step's middle for a model that generates a token at a time:
+        sample, grow, decode (or draft and verify), deliver.  Returns (the
+        slots sampled, whether the decode program was dispatched ahead of
+        the read-back, the tokens delivered, the speculation's extra
+        tokens or None)."""
         # sample each decoding slot's next token from its pending
         # logits (slots mid-chunked-prefill have none yet): dispatched,
         # not read
+        sv = self.serve
         self._phase("serve.sample_keys", beat="prefill")
         emitted_now = 0
         sampled = self._decoding()
@@ -1982,6 +2090,235 @@ class ServingEngine:
             emitted_now += self._deliver(sampled, toks,
                                          self._phase("serve.sample"))
             self.metrics.count("serve.decode_ahead_steps")
+        return sampled, ahead, emitted_now, n_extra
+
+    def _steps_a_block(self, s: _Slot) -> int:
+        return (s.req.denoise_steps or self.serve.denoise_steps
+                or self.cfg.block_length)
+
+    def _denoise(self):
+        """A step's middle for a model that generates by diffusion over
+        blocks (L = ``cfg.block_length``): ONE launch of
+        :func:`_paged_denoise_step` advances every decoding slot's open
+        block by a forward, and the blocks the LAST launch made whole are
+        read and delivered.
+
+        By slot: no block open -> this launch opens one at ``length`` (the
+        prompt's tail first, on the slot's first block) and is its
+        denoising step 0; rows still masked -> the next denoising step,
+        which reveals ``L / denoise_steps`` of them; none masked -> the
+        COMMIT, the forward of the clean block whose K/V later blocks
+        read, after which ``length`` advances by L.  The last block of a
+        request (by count) is not committed: nothing reads it.  Under
+        ``low_confidence_static`` and ``sequential`` the host knows every
+        count without the tokens, so the launch is dispatched AHEAD of
+        the read-back of the state the launch before left (that state is
+        this launch's feed where it lies, and is not donated);
+        ``low_confidence_dynamic`` reveals by a threshold, so the step
+        reads the masked counts first, as does a step whose growth the
+        pool cannot cover (the victim's prompt is rebuilt from what was
+        delivered).  An evicted slot's open block starts again.  Returns
+        (the slots decoding, whether the read-back followed the dispatch,
+        the tokens delivered)."""
+        sv, bl = self.serve, self.cfg.block_length
+        self._phase("serve.decode_feed", beat="prefill")
+        decoding = self._decoding()
+        if not decoding:
+            return decoding, False, 0
+        dynamic = sv.reveal_rule == "low_confidence_dynamic"
+        state, read = self._block_state, None
+        revealed = 0
+        if dynamic:
+            read = self._read_blocks(state)
+            for i in decoding:
+                s = self.slots[i]
+                if s.block_masked == -1:
+                    left = int(read[i, 1].sum())
+                    revealed += int((read[i, 2] == s.block_step - 1).sum())
+                    s.block_masked = left
+        # blocks the last launch made whole, and those of them that end
+        # their request by count (delivered, not committed)
+        whole = [i for i in decoding if self.slots[i].block_masked == 0]
+        closing = {i for i in whole
+                   if self._delivered(self.slots[i])
+                   + bl - self.slots[i].block_first
+                   >= self.slots[i].orig.max_new_tokens}
+        active = [i for i in decoding if i not in closing]
+        ahead = bool(active and not dynamic and self._growth_fits(active))
+        emitted_now = 0
+        if not ahead and whole:
+            emitted_now += self._deliver_blocks(whole, state, read)
+            active = [i for i in active if self.slots[i] is not None]
+        self._phase("serve.grow", beat="sample")
+        if active:
+            self._grow_pages(active, span=bl - 1)
+            active = [i for i in active if self.slots[i] is not None]
+        if active:
+            self._phase("serve.decode_feed")
+            ctl = np.zeros((sv.max_batch, 4 + bl), np.int32)
+            ctl[:, 3] = -1
+            tables = np.full((sv.max_batch, sv.max_pages_per_slot),
+                             SCRATCH_PAGE, np.int32)
+            longest, commits, fed_masked = 1, 0, 0
+            for i in active:
+                s = self.slots[i]
+                if s.block_masked is None:          # open a block
+                    first = len(s.tail)
+                    ctl[i, 3] = first
+                    ctl[i, 4:4 + first] = s.tail
+                    s.tail = ()
+                    s.block_first, s.block_step = first, 0
+                    s.block_masked = bl - first
+                ctl[i, 0] = s.length
+                tables[i, :len(s.pages)] = s.pages
+                longest = max(longest, s.length + bl)
+                if s.block_masked:
+                    n = bl // self._steps_a_block(s)
+                    ctl[i, 1], ctl[i, 2] = n, s.block_step
+                    fed_masked += s.block_masked
+                    s.block_step += 1
+                    if dynamic:
+                        s.block_masked = -1
+                    else:
+                        revealed += min(n, s.block_masked)
+                        s.block_masked = max(0, s.block_masked - n)
+                else:                               # the commit
+                    commits += 1
+                    s.length += bl
+                    s.block_masked = None
+            n_ctx = ctx_pages_bucket(longest, sv.page_size,
+                                     sv.ctx_bucket_pages,
+                                     sv.max_pages_per_slot)
+            self.stats["decode_buckets"].add(n_ctx)
+            self._phase("serve.denoise")
+            self._queue_empty("serve.denoise")
+            self._block_state, self.cache, counted = _INPLACE[
+                "_paged_denoise_step"](
+                self.params, self.cfg, self.cache, state, jnp.asarray(ctl),
+                jnp.asarray(tables[:, :n_ctx]), pad_token=sv.pad_token,
+                rule=sv.reveal_rule, threshold=sv.reveal_threshold)
+            self._counted = counted or None
+            self._last_out = self._block_state
+            # the step's account of it, while the device runs it
+            self._note_ctx(n_ctx, ctl[active, 0], bl)
+            self._block_counts = (len(active), revealed, commits,
+                                  fed_masked)
+            self.metrics.count("serve.denoise_steps")
+            self.metrics.count("serve.commit_rows", commits)
+            self.metrics.count("serve.revealed_tokens", revealed)
+        if ahead:
+            if whole:
+                emitted_now += self._deliver_blocks(whole, state, read)
+            self.metrics.count("serve.decode_ahead_steps")
+        return decoding, ahead, emitted_now
+
+    def _read_blocks(self, state):
+        """The slots' blocks on the host, [B, 3, L]: THE STEP'S READ-BACK.
+        It waits for the launch that left ``state`` and for none
+        dispatched after it; the wait is the step's ``wait_ms``."""
+        since = self._phase("serve.reveal")
+        read = np.asarray(state)
+        self._wait_ms += (self._clock() - since) * 1e3
+        return read
+
+    def _deliver_blocks(self, rows, state, read=None) -> int:
+        """Hand each slot of ``rows`` the tokens of its whole block, as
+        ``state`` (what the launch that revealed its last row left) has
+        them: the rows past the prompt's tail, cut at the request's last
+        token by count and after a stop token (tokens past either are
+        dropped); the clocks stamped, the request retired on either.
+        Beside the tokens, the denoising step that revealed each
+        (``reveal_steps``).  Returns the tokens delivered."""
+        if read is None:
+            read = self._read_blocks(state)
+        now = self._phase("serve.reveal")
+        n_tokens = 0
+        for i in rows:
+            s = self.slots[i]
+            toks = read[i, 0, s.block_first:].tolist()
+            steps = read[i, 2, s.block_first:].tolist()
+            left = s.orig.max_new_tokens - self._delivered(s)
+            stop = next((j for j, t in enumerate(toks[:left])
+                         if t in s.req.stop_tokens), None)
+            done = stop is not None or len(toks) >= left
+            toks = toks[:left if stop is None else stop + 1]
+            s.emitted.extend(toks)
+            self.reveal_steps.setdefault(s.orig.rid, []).extend(
+                steps[:len(toks)])
+            n_tokens += len(toks)
+            if s.first_token_s is None:
+                s.first_token_s = now
+                s.prefill_ms = (now - s.admit_s) * 1e3
+            elif s.last_token_s is not None:
+                gap_ms = (now - s.last_token_s) * 1e3
+                if gap_ms > s.gap_max_ms:
+                    s.gap_max_ms = gap_ms
+            s.last_token_s = now
+            s.block_first = 0
+            if self.recorder is not None:
+                self._delivered_now[s.orig.rid] = len(toks)
+            if done:
+                with trace_span("serve.retire"):
+                    self._retire(i, s)
+        self.metrics.count("serve.blocks_delivered", len(rows))
+        return n_tokens
+
+    def step(self) -> dict:
+        """One engine iteration: admit -> sample -> grow -> decode ->
+        read the tokens, deliver, retire (the decode program is dispatched
+        AHEAD of the step's one read-back); sample -> read, deliver,
+        retire -> grow -> decode on a step where the host needs the tokens
+        in between (speculation armed, growth that would evict).  Either
+        way every token sampled in the step is delivered and every
+        finished request retired when it returns.  A model that
+        generates by blocks runs :meth:`_denoise` in the middle instead:
+        a step may then deliver several tokens a slot or none.  Returns
+        the step's flight record (also appended to the recorder when one
+        is attached)."""
+        with trace_span("serve.step", step=self.step_idx):
+            try:
+                return self._step()
+            finally:
+                if self._phase_open is not None:    # the step raised
+                    self._phase(None)
+
+    def _step(self) -> dict:
+        sv = self.serve
+        compiles0, compile_s0 = compile_totals()
+        gc_n0, gc_s0 = gc_totals()
+        self._phase_ms = {}
+        self._delivered_now = {}
+        self._ctx_pages = (0, 0.0, 0, None, None)
+        self._sampled = np.zeros((3,), np.int64)
+        self._state_bytes = 0
+        self._wait_ms = 0.0
+        self._starved, self._starved_at = 0, None
+        self._prefills = [0, 0, 0]
+        self._block_counts = None
+        if self._counted is not None:
+            self._counted_prev, self._counted = self._counted, None
+        # the same instant on the profiler's clock and on the engine's
+        t0_trace_ns, cpu0_s = time.time_ns(), time.thread_time()
+        t0_s = self._phase("serve.admit")
+        if self.tracer is not None:
+            # open the step window BEFORE admissions: everything in
+            # this step (a neighbour's prefill compile included) rides
+            # a serve.step span on each active request's track
+            self.tracer.begin_step(
+                self.step_idx,
+                [self.slots[i].orig.rid for i in self._active()])
+        self._mark_arrivals()
+        admitted0 = self.stats["admitted"]
+        retired0 = self.stats["completed"]
+        self._admit()
+        self._phase("serve.prefill_advance", beat="admit")
+        self._advance_prefill()
+
+        if self.cfg.block_length:
+            sampled, ahead, emitted_now = self._denoise()
+            n_extra = None
+        else:
+            sampled, ahead, emitted_now, n_extra = self._decode_tokens()
         self.stats["tokens"] += emitted_now
 
         # telemetry
@@ -2146,6 +2483,15 @@ class ServingEngine:
                     if "zero_rows" in counted:
                         self.metrics.count("serve.zero_rows",
                                            counted["zero_rows"])
+                if self._block_counts is not None:
+                    # the denoise program's launch: L rows a slot fed,
+                    # the tokens it revealed, the slots whose forward was
+                    # a commit, the rows fed as [MASK]
+                    fed, revealed, commits, fed_masked = self._block_counts
+                    more.update(
+                        span_rows=fed * self.cfg.block_length,
+                        revealed=revealed, commit_rows=commits,
+                        masked_rows=fed_masked)
                 self.recorder.record(
                     kind="serve_decode", step=self.step_idx,
                     slots=n_decoding, ctx_pages=ctx_pages,

@@ -370,7 +370,7 @@ STRUCTURAL_FIELDS = frozenset({
     "ssm_heads", "ssm_head_dim", "ssm_groups", "ssm_state", "ssm_conv",
     "ssm_chunk",
     "expert_first", "experts_held", "intermediate_pad",
-    "zero_experts", "mla_rank_scale",
+    "zero_experts", "mla_rank_scale", "block_length", "mask_token_id",
     "dtype", "param_dtype", "accum_dtype",
     "dp", "ep", "tp", "sp", "pp",
 })

@@ -338,6 +338,15 @@ SPAN_NAMES: dict[str, str] = {
         "step phase: the decode step's positions and block tables built "
         "on the host (its feed is the sampler's array on the device); "
         "the verify step's feed too",
+    "serve.denoise":
+        "step phase: the denoise program of a model that generates by "
+        "blocks dispatched, one forward of every decoding slot's open "
+        "block (where ``serve.decode`` stands for the other models)",
+    "serve.reveal":
+        "step phase: the read-back of the slots' blocks as the launch "
+        "before left them (the step's one read-back: after "
+        "``serve.denoise`` on a step that dispatches ahead) and the "
+        "delivery of the blocks it made whole, retirements included",
     "serve.account":
         "step phase: gauges, sketches and the step's flight record",
     "train.data_pull": "host wait on the data iterator",

@@ -515,7 +515,7 @@ def test_engine_serves_either_cache_in_place(params, kind):
     step); the undonated ones keep their inputs for tests and
     ``lower()``."""
     assert set(eng._INPLACE) == {"_prefill_chunk", "_paged_decode_step",
-                                 "_paged_verify_step"}
+                                 "_paged_verify_step", "_paged_denoise_step"}
     cfg, weights = CFG, params
     if kind == "mha":
         cfg = PRESETS["deepseek-moe-16b"](

@@ -1047,3 +1047,75 @@ def test_shortcut_programs_fit_the_chip_with_the_pool_in_place(
         assert kernels == ["fm_flash_span"] * 8
         assert _score_arrays(text, 64, 1024, 7168) == []
         assert "attn.mla_prefill" in text
+
+
+@pytest.fixture(scope="module")
+def sdar_programs(one_chip):
+    """The widest denoise program and the widest 1024-token chunk of the
+    cell ``sdar_30b_a3b.serve.blockgen`` (its largest padded prefill,
+    10.24 GB with seven ``fm_flash_span``, was compiled once by PR 46's
+    builder and is left out of the gate for its time)
+    (SDAR-30B-A3B-Chat: 7 of 48 layers alike, 32 heads over 4 K/V heads
+    of 128, 128 experts top-8 of 768 and the whole vocabulary, bf16; 64
+    slots, an 8192 x 16-token K/V pool, tables at their 160 pages, blocks
+    of 4 positions), lowered as the engine runs them: the pool donated,
+    traced as on a TPU."""
+    from flashmoe_tpu.models.presets import PRESETS
+    from flashmoe_tpu.models.transformer import init_params
+    from flashmoe_tpu.serving import engine as eng
+    from flashmoe_tpu.serving.kvcache import init_paged_cache
+
+    cfg = PRESETS["sdar-30b-a3b-chat"](num_layers=7,
+                                       param_dtype=jnp.bfloat16)
+    on = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = on(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on(jax.eval_shape(lambda: init_paged_cache(cfg, 8192, 16, 64)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32, sharding=one_chip)
+    with pytest.MonkeyPatch.context() as mp:        # traced as on a TPU
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return {
+            "denoise": eng._INPLACE["_paged_denoise_step"].lower(
+                params, cfg, cache, i32(64, 3, 4), i32(64, 8), i32(64, 160),
+                pad_token=0),
+            "chunk": eng._INPLACE["_prefill_chunk"].lower(
+                params, cfg, cache, i32(1, 1024), i32(160), i32(64), i32(),
+                i32(), i32())}
+
+
+@pytest.mark.parametrize("program", ["denoise", "chunk"])
+def test_sdar_programs_fit_the_chip_with_the_pool_in_place(
+        sdar_programs, program):
+    """The cell's programs as the chip's compiler builds them, under its
+    14.5 GB: 9.97 GB of weights and the K/V pool (1.88 GB: 14 336 B a
+    token over 7 layers) once, aliased to the output; the denoise program
+    reads every slot's pages in place (``fm_paged_decode`` at T = 4 under
+    the block mask, a call a layer, no gathered context) and runs the
+    routed rows of its 256-row span through ``fm_ffn_fwd``, a launch a
+    layer; the chunk scores its context blockwise (``fm_flash_span`` with
+    the block-causal diagonal)."""
+    compiled = sdar_programs[program].compile()
+    text = compiled.as_text()
+    total = _program_bytes(compiled)
+    print(program, total / 1e9)
+    assert total < 14.5e9
+    assert "ragged-dot" not in text
+    kernels = _fm_kernels(text)
+    assert [n for n in kernels if n == "fm_ffn_fwd"] == ["fm_ffn_fwd"] * 7
+    kernels = [n for n in kernels if n != "fm_ffn_fwd"]
+    pool = r"bf16\[7,8192,4,16,128\]"
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * 7 * 8192 * 4 * 16 * 128 * 2
+    assert re.search(pool, text)
+    assert not re.findall(rf"^.*= {pool}\S* copy\(.*$", text, re.M)
+    if program == "denoise":
+        assert kernels == ["fm_paged_decode"] * 7
+        assert _arrays_of(text, 64, 4, 2560, 128) == []   # no context
+        assert " scatter(" not in text
+        # the blocks' state, K and V pool, experts_touched
+        assert len(jax.tree.leaves(compiled.out_info)) == 1 + 2 + 1
+    else:
+        assert kernels == ["fm_flash_span"] * 7
+        assert _score_arrays(text, 32, 1024, 2560) == []
