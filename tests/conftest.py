@@ -29,3 +29,20 @@ def devices():
     d = jax.devices()
     assert len(d) >= 8, f"expected >=8 virtual devices, got {len(d)}"
     return d
+
+
+@pytest.fixture(scope="session")
+def jitted():
+    """``jitted(layer, cfg, mesh, **kw)(params, x)``: ``layer``
+    (``ep_moe_layer``, ``ragged_ep_moe_layer``, ``fused_ep_moe_layer``)
+    the way every program of the repo runs it, ONE ``jax.jit`` program
+    with ``cfg``, ``mesh`` and the keyword arguments closed over
+    (``runtime/worker.py:51``).  A bare call of a ``shard_map`` dispatches
+    each primitive of its body as a program of its own and caches none
+    (about a thousand small compiles a call), so the gate keeps ONE bare
+    call per transport, marked "a bare call works" where it stands.  A
+    fixture and not an import: ``benchmark/tests`` has a ``conftest``
+    module too, and a run over both directories imports the wrong one."""
+    def wrap(layer, cfg, mesh, **kw):
+        return jax.jit(lambda params, x: layer(params, x, cfg, mesh, **kw))
+    return wrap

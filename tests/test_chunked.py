@@ -91,47 +91,45 @@ def test_chunked_serial_invariants_via_staticcheck(devices):
                           include_coverage=False) == []
 
 
-@pytest.mark.slow
-def test_ep_chunked_bit_identical_hierarchical_and_wire(devices):
+def test_ep_chunked_bit_identical_hierarchical_and_wire(devices, jitted):
     """Chunked + two-stage (intra/inter-slice) exchange + fp8 wire:
     every chunk carries payload AND scales through both hops — outputs
     bit-identical to the serial schedule at the same knobs."""
     cfg, params, x = _setup(ep=4)
     mesh = make_mesh(cfg, dp=1, devices=devices[:4])
-    hoff = ep_moe_layer(params, x, cfg, mesh, dcn_inner=2)
-    hon = ep_moe_layer(params, x, cfg.replace(a2a_chunks=2), mesh,
-                       dcn_inner=2)
+    hoff = jitted(ep_moe_layer, cfg, mesh, dcn_inner=2)(params, x)
+    hon = jitted(ep_moe_layer, cfg.replace(a2a_chunks=2), mesh,
+                 dcn_inner=2)(params, x)
     np.testing.assert_array_equal(np.asarray(hoff.out),
                                   np.asarray(hon.out))
     wired = cfg.replace(wire_dtype="e4m3", wire_dtype_combine="e5m2")
-    woff = ep_moe_layer(params, x, wired, mesh)
-    won = ep_moe_layer(params, x, wired.replace(a2a_chunks=2), mesh)
+    woff = jitted(ep_moe_layer, wired, mesh)(params, x)
+    won = jitted(ep_moe_layer, wired.replace(a2a_chunks=2), mesh)(params, x)
     np.testing.assert_array_equal(np.asarray(woff.out),
                                   np.asarray(won.out))
 
 
-@pytest.mark.slow
-def test_ragged_chunked_bit_identical(devices):
+def test_ragged_chunked_bit_identical(devices, jitted):
     """The ragged row exchanges mirror the pipeline: per-chunk
     offsets/sizes derived from the gathered count matrix move exactly
     the serial schedule's rows — with and without the fp8 wire."""
     cfg, params, x = _setup()
     mesh = make_mesh(cfg, dp=1, devices=devices[:2])
-    off = ragged_ep_moe_layer(params, x, cfg, mesh, exchange="dense")
+    off = jitted(ragged_ep_moe_layer, cfg, mesh, exchange="dense")(params, x)
     for n in (2, 4):
-        on = ragged_ep_moe_layer(params, x, cfg.replace(a2a_chunks=n),
-                                 mesh, exchange="dense")
+        on = jitted(ragged_ep_moe_layer, cfg.replace(a2a_chunks=n), mesh,
+                    exchange="dense")(params, x)
         np.testing.assert_array_equal(np.asarray(off.out),
                                       np.asarray(on.out))
     wired = cfg.replace(wire_dtype="e4m3")
-    woff = ragged_ep_moe_layer(params, x, wired, mesh, exchange="dense")
-    won = ragged_ep_moe_layer(params, x, wired.replace(a2a_chunks=2),
-                              mesh, exchange="dense")
+    woff = jitted(ragged_ep_moe_layer, wired, mesh,
+                  exchange="dense")(params, x)
+    won = jitted(ragged_ep_moe_layer, wired.replace(a2a_chunks=2), mesh,
+                 exchange="dense")(params, x)
     np.testing.assert_array_equal(np.asarray(woff.out),
                                   np.asarray(won.out))
 
 
-@pytest.mark.slow
 def test_ep_chunked_grad_finite(devices):
     """Training through the chunked pipeline: grads flow through the
     per-chunk param slices and stay finite."""
@@ -142,7 +140,7 @@ def test_ep_chunked_grad_finite(devices):
         o = ep_moe_layer(p, x, cfg, mesh)
         return jnp.sum(o.out.astype(jnp.float32) ** 2) + o.aux_loss
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     for leaf in jax.tree_util.tree_leaves(g):
         assert np.isfinite(np.asarray(leaf)).all()
 

@@ -1,11 +1,14 @@
 """Tier-1 budget guard: collection-time marker hygiene.
 
 ``slow`` means: multi-process tests, chaos and fabric drills, and any
-case that costs over 40 s in a run of its own file under the driver's
-six xdist workers (inside the whole gate a case costs about twice
-that; ROADMAP.md's tier-1 paragraph has the wall time).  The tier-1 gate (``pytest -m 'not slow'``) EXECUTES the
-mesh paths — the EP transports' forwards and gradients on the eight
-virtual devices — so only two classes are held out by rule: end-to-end
+case that costs over 40 s in a run of its own file (inside the whole
+gate a case costs about twice that; ROADMAP.md's tier-1 paragraph has
+the wall time and the two limits a FILE is held to).  The tier-1 gate
+(``pytest -m 'not slow'``) EXECUTES the mesh paths — the EP transports'
+forwards and gradients on the eight virtual devices, under ``jax.jit``
+as the programs run them, one bare call a transport (an eager
+``shard_map`` is a thousand small compiles a call: ISSUE 47) — so only
+two classes are held out by rule: end-to-end
 chaos drills (a full training job per fault), anywhere, and shard_map
 *executions* inside ``test_chaos.py`` (trace-only jaxpr inspection is
 its fast-lane form).  Both must carry ``@pytest.mark.slow`` so a new

@@ -7,8 +7,9 @@ host: speculation armed (the drafts are built from them) or a pool that
 cannot cover the step's growth (an eviction rebuilds its victim's prompt
 from them).
 
-Held here, on a K/V toy, on a short-convolution + K/V toy (LFM2's
-pattern) and on a delta-rule + latent toy (Ling's): the served tokens are
+Held on a K/V toy (this file), on a short-convolution + K/V toy (LFM2's
+pattern) and on a delta-rule + latent toy (Ling's; a file each, the same
+cases imported): the served tokens are
 ``generate()``'s one request at a time through joins, retirements, chunked
 prefill and an eviction cycle; which steps take which order, and that the
 record and the counter say so; a request that ends on a stop token AFTER
@@ -62,10 +63,19 @@ MIXED = [(9, 7), (40, 9), (21, 5), (33, 12), (8, 10), (17, 4)]
 ARRIVALS = [0, 0, 1, 2, 4, 5]
 
 
-@pytest.fixture(scope="module", params=sorted(TOYS))
-def toy(request):
-    cfg = TOYS[request.param]
+def _toy(name):
+    cfg = TOYS[name]
     return cfg, init_params(jax.random.PRNGKey(0), cfg)
+
+
+#: the K/V toy here; the cases that take ``toy`` run on the other two in
+#: ``test_decode_ahead_conv_kv.py`` and ``test_decode_ahead_kda_mla.py``,
+#: which import them (a toy's first case compiles its oracle's programs, a
+#: minute and a half under the gate's load: one file a toy keeps each
+#: under the two minutes a file of few cases may hold a worker)
+@pytest.fixture(scope="module", params=["kv"])
+def toy(request):
+    return _toy(request.param)
 
 
 def _prompt(r, t0):

@@ -22,13 +22,18 @@ def _setup(cfg, seed=0):
 
 
 @pytest.mark.parametrize("ep", [2, 4, 8])
-def test_ep_matches_oracle_nodrop(ep, devices):
+def test_ep_matches_oracle_nodrop(ep, devices, jitted):
     cfg = MoEConfig(num_experts=8, expert_top_k=2, hidden_size=64,
                     intermediate_size=128, sequence_len=256,
                     drop_tokens=False, ep=ep, **F32)
     params, x = _setup(cfg)
     mesh = make_mesh(cfg, dp=1, devices=devices[:ep])
-    out = ep_moe_layer(params, x, cfg, mesh)
+    if ep == 2:
+        # eager on purpose: a bare call works (the layer is a public
+        # function); every other execution of it here is under jax.jit
+        out = ep_moe_layer(params, x, cfg, mesh)
+    else:
+        out = jitted(ep_moe_layer, cfg, mesh)(params, x)
     want, _ = reference_moe(params, x, cfg)
     np.testing.assert_allclose(
         np.asarray(out.out), np.asarray(want), rtol=2e-4, atol=2e-4
@@ -36,21 +41,21 @@ def test_ep_matches_oracle_nodrop(ep, devices):
     assert int(jnp.sum(out.expert_counts)) == cfg.tokens * cfg.expert_top_k
 
 
-def test_ep_gated_shared(devices):
+def test_ep_gated_shared(devices, jitted):
     cfg = MoEConfig(num_experts=16, expert_top_k=2, hidden_size=64,
                     intermediate_size=128, sequence_len=256,
                     drop_tokens=False, ep=8, gated_ffn=True,
                     hidden_act=Activation.SILU, num_shared_experts=1, **F32)
     params, x = _setup(cfg)
     mesh = make_mesh(cfg, dp=1)
-    out = ep_moe_layer(params, x, cfg, mesh)
+    out = jitted(ep_moe_layer, cfg, mesh)(params, x)
     want, _ = reference_moe(params, x, cfg)
     np.testing.assert_allclose(
         np.asarray(out.out), np.asarray(want), rtol=2e-4, atol=2e-4
     )
 
 
-def test_ep_matches_single_device_with_drops(devices):
+def test_ep_matches_single_device_with_drops(devices, jitted):
     """With per-shard capacity limits, EP must equal the single-device layer
     run shard-by-shard (same drops, same renormalization)."""
     cfg = MoEConfig(num_experts=8, expert_top_k=2, hidden_size=64,
@@ -58,7 +63,7 @@ def test_ep_matches_single_device_with_drops(devices):
                     capacity_factor=1.0, drop_tokens=True, ep=8, **F32)
     params, x = _setup(cfg)
     mesh = make_mesh(cfg, dp=1)
-    out = ep_moe_layer(params, x, cfg, mesh)
+    out = jitted(ep_moe_layer, cfg, mesh)(params, x)
 
     d = 8
     s_loc = cfg.tokens // d
@@ -74,7 +79,7 @@ def test_ep_matches_single_device_with_drops(devices):
     )
 
 
-def test_ep_with_tensor_parallel_experts(devices):
+def test_ep_with_tensor_parallel_experts(devices, jitted):
     """EP x TP: experts over ep, each expert's intermediate dim Megatron-
     split over tp (one psum per FFN)."""
     cfg = MoEConfig(num_experts=8, expert_top_k=2, hidden_size=64,
@@ -83,7 +88,8 @@ def test_ep_with_tensor_parallel_experts(devices):
                     hidden_act=Activation.SILU, **F32)
     params, x = _setup(cfg)
     mesh = make_mesh(cfg)  # dp=2, ep=2, tp=2 on 8 devices
-    out = ep_moe_layer(params, x, cfg, mesh, token_axes=("dp", "ep"))
+    out = jitted(ep_moe_layer, cfg, mesh,
+                 token_axes=("dp", "ep"))(params, x)
     want, _ = reference_moe(params, x, cfg)
     np.testing.assert_allclose(
         np.asarray(out.out), np.asarray(want), rtol=2e-4, atol=2e-4
@@ -91,7 +97,7 @@ def test_ep_with_tensor_parallel_experts(devices):
 
 
 @pytest.mark.parametrize("inner", [2, 4])
-def test_hierarchical_dcn_a2a_matches_flat(inner, devices):
+def test_hierarchical_dcn_a2a_matches_flat(inner, devices, jitted):
     """Two-stage (intra-slice, inter-slice) all-to-all must be
     bit-identical to the flat exchange."""
     cfg = MoEConfig(num_experts=8, expert_top_k=2, hidden_size=64,
@@ -99,15 +105,14 @@ def test_hierarchical_dcn_a2a_matches_flat(inner, devices):
                     drop_tokens=False, ep=8, **F32)
     params, x = _setup(cfg)
     mesh = make_mesh(cfg, dp=1, devices=devices[:8])
-    flat = ep_moe_layer(params, x, cfg, mesh)
-    hier = ep_moe_layer(params, x, cfg, mesh, dcn_inner=inner)
+    flat = jitted(ep_moe_layer, cfg, mesh)(params, x)
+    hier = jitted(ep_moe_layer, cfg, mesh, dcn_inner=inner)(params, x)
     np.testing.assert_array_equal(
         np.asarray(flat.out), np.asarray(hier.out)
     )
 
 
-@pytest.mark.slow
-def test_ep_pallas_path_and_grad(devices):
+def test_ep_pallas_path_and_grad(devices, jitted):
     """EP with pallas experts (interpreter): forward matches oracle and
     the custom-VJP backward produces finite grads."""
     cfg = MoEConfig(num_experts=8, expert_top_k=2, hidden_size=64,
@@ -115,7 +120,8 @@ def test_ep_pallas_path_and_grad(devices):
                     drop_tokens=False, ep=4, is_training=True, **F32)
     params, x = _setup(cfg)
     mesh = make_mesh(cfg, dp=1, devices=devices[:4])
-    out = ep_moe_layer(params, x, cfg, mesh, use_pallas=True, interpret=True)
+    out = jitted(ep_moe_layer, cfg, mesh, use_pallas=True,
+                 interpret=True)(params, x)
     want, _ = reference_moe(params, x, cfg)
     np.testing.assert_allclose(
         np.asarray(out.out), np.asarray(want), rtol=2e-4, atol=2e-4
@@ -125,7 +131,7 @@ def test_ep_pallas_path_and_grad(devices):
         o = ep_moe_layer(p, x, cfg, mesh, use_pallas=True, interpret=True)
         return jnp.sum(o.out ** 2) + o.aux_loss
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     for leaf in jax.tree_util.tree_leaves(g):
         assert np.isfinite(np.asarray(leaf)).all()
 
@@ -142,6 +148,6 @@ def test_ep_grad(devices):
         o = ep_moe_layer(p, x, cfg, mesh)
         return jnp.sum(o.out ** 2) + o.aux_loss
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     for leaf in jax.tree_util.tree_leaves(g):
         assert np.isfinite(np.asarray(leaf)).all()

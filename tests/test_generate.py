@@ -29,9 +29,10 @@ def test_greedy_matches_full_forward():
     assert out.shape == (2, 12)
 
     # oracle: re-run the full forward on the growing sequence
+    fwd = jax.jit(lambda p, s: forward(p, s, CFG))  # one program a length
     seq = prompt
     for _ in range(4):
-        logits, _ = forward(params, seq, CFG)
+        logits, _ = fwd(params, seq)
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(seq))

@@ -124,10 +124,12 @@ def test_dropless_gather_fused_inference(gated):
     np.testing.assert_allclose(
         np.asarray(got.out), np.asarray(want), rtol=2e-4, atol=2e-4
     )
-    g = jax.grad(lambda xx: moe_layer(params, xx, cfg, use_pallas=True,
-                                      interpret=True).out.sum())(x)
-    gx = jax.grad(lambda xx: moe_layer(params, xx, cfg,
-                                       use_pallas=False).out.sum())(x)
+    # jitted, as a train step takes them (an eager grad through an
+    # interpret-mode kernel is dispatch, op by op)
+    g = jax.jit(jax.grad(lambda xx: moe_layer(
+        params, xx, cfg, use_pallas=True, interpret=True).out.sum()))(x)
+    gx = jax.jit(jax.grad(lambda xx: moe_layer(
+        params, xx, cfg, use_pallas=False).out.sum()))(x)
     np.testing.assert_allclose(np.asarray(g), np.asarray(gx),
                                rtol=5e-3, atol=5e-3)
 
@@ -143,8 +145,8 @@ def test_fused_path_grad_matches_xla_grad():
         o = moe_layer(p, x, cfg, use_pallas=use_pallas, interpret=interpret)
         return jnp.sum(o.out ** 2) + o.aux_loss
 
-    gp = jax.grad(lambda p: loss(p, True, True))(params)
-    gx = jax.grad(lambda p: loss(p, False, False))(params)
+    gp = jax.jit(jax.grad(lambda p: loss(p, True, True)))(params)
+    gx = jax.jit(jax.grad(lambda p: loss(p, False, False)))(params)
     for a, b in zip(jax.tree_util.tree_leaves(gp),
                     jax.tree_util.tree_leaves(gx)):
         np.testing.assert_allclose(

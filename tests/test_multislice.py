@@ -31,7 +31,7 @@ def _setup(cfg, seed=0):
     return params, x
 
 
-def test_hierarchical_a2a_matches_flat_and_oracle(devices):
+def test_hierarchical_a2a_matches_flat_and_oracle(devices, jitted):
     """The two-stage exchange is a pure re-decomposition: bit-identical
     routing to the flat all-to-all, oracle-correct output, both
     directions (dispatch and combine-return)."""
@@ -40,8 +40,8 @@ def test_hierarchical_a2a_matches_flat_and_oracle(devices):
                     drop_tokens=False, ep=8, **F32)
     params, x = _setup(cfg)
     mesh = make_mesh(cfg, dp=1, devices=devices[:8])
-    flat = ep_moe_layer(params, x, cfg, mesh, dcn_inner=0)
-    hier = ep_moe_layer(params, x, cfg, mesh, dcn_inner=4)
+    flat = jitted(ep_moe_layer, cfg, mesh, dcn_inner=0)(params, x)
+    hier = jitted(ep_moe_layer, cfg, mesh, dcn_inner=4)(params, x)
     np.testing.assert_allclose(np.asarray(hier.out), np.asarray(flat.out),
                                rtol=1e-6, atol=1e-6)
     want, _ = reference_moe(params, x, cfg)
@@ -50,14 +50,14 @@ def test_hierarchical_a2a_matches_flat_and_oracle(devices):
 
 
 @pytest.mark.parametrize("inner", [2, 4])
-def test_hierarchical_a2a_other_factorizations(inner, devices):
+def test_hierarchical_a2a_other_factorizations(inner, devices, jitted):
     cfg = MoEConfig(num_experts=8, expert_top_k=2, hidden_size=128,
                     intermediate_size=256, sequence_len=256,
                     capacity_factor=1.0, drop_tokens=True, ep=8, **F32)
     params, x = _setup(cfg)
     mesh = make_mesh(cfg, dp=1, devices=devices[:8])
-    flat = ep_moe_layer(params, x, cfg, mesh, dcn_inner=0)
-    hier = ep_moe_layer(params, x, cfg, mesh, dcn_inner=inner)
+    flat = jitted(ep_moe_layer, cfg, mesh, dcn_inner=0)(params, x)
+    hier = jitted(ep_moe_layer, cfg, mesh, dcn_inner=inner)(params, x)
     np.testing.assert_allclose(np.asarray(hier.out), np.asarray(flat.out),
                                rtol=1e-6, atol=1e-6)
 
@@ -81,7 +81,7 @@ def test_slice_structure_detection(monkeypatch, devices):
             slice_structure(devices[:8])
 
 
-def test_bootstrap_publishes_dcn_inner(monkeypatch, devices):
+def test_bootstrap_publishes_dcn_inner(monkeypatch, devices, jitted):
     """An initialized runtime on a mocked 2-slice job publishes
     ranks-per-slice, ep_moe_layer picks it up by default (same pattern
     as the arrival-order table), and the gated accessor refuses meshes
@@ -106,7 +106,7 @@ def test_bootstrap_publishes_dcn_inner(monkeypatch, devices):
         # end to end: the default path must produce oracle output while
         # riding the published two-stage exchange
         params, x = _setup(cfg)
-        out = ep_moe_layer(params, x, cfg, mesh)
+        out = jitted(ep_moe_layer, cfg, mesh)(params, x)
         want, _ = reference_moe(params, x, cfg)
         np.testing.assert_allclose(np.asarray(out.out), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
@@ -142,8 +142,7 @@ def test_transport_cost_model_prefers_aggregation():
 # Per-hop wire dtypes (MoEConfig.wire_dtype_dcn, ISSUE 13)
 # ----------------------------------------------------------------------
 
-@pytest.mark.slow
-def test_dcn_wire_inert_on_flat_and_off_identical(devices):
+def test_dcn_wire_inert_on_flat_and_off_identical(devices, jitted):
     """wire_dtype_dcn must be a pure DCN-hop knob: on the flat exchange
     it is inert (bit-identical output), and on the hierarchical
     exchange the default None traces/computes exactly the single-dtype
@@ -153,22 +152,19 @@ def test_dcn_wire_inert_on_flat_and_off_identical(devices):
                     drop_tokens=False, ep=8, **F32)
     params, x = _setup(cfg)
     mesh = make_mesh(cfg, dp=1, devices=devices[:8])
-    flat = ep_moe_layer(params, x, cfg, mesh, dcn_inner=0)
-    flat_knob = ep_moe_layer(params, x,
-                             cfg.replace(wire_dtype_dcn="e4m3"),
-                             mesh, dcn_inner=0)
+    flat = jitted(ep_moe_layer, cfg, mesh, dcn_inner=0)(params, x)
+    flat_knob = jitted(ep_moe_layer, cfg.replace(wire_dtype_dcn="e4m3"), mesh,
+                       dcn_inner=0)(params, x)
     np.testing.assert_array_equal(np.asarray(flat_knob.out),
                                   np.asarray(flat.out))
-    hier = ep_moe_layer(params, x, cfg, mesh, dcn_inner=4)
-    hier_none = ep_moe_layer(params, x,
-                             cfg.replace(wire_dtype_dcn=None),
-                             mesh, dcn_inner=4)
+    hier = jitted(ep_moe_layer, cfg, mesh, dcn_inner=4)(params, x)
+    hier_none = jitted(ep_moe_layer, cfg.replace(wire_dtype_dcn=None), mesh,
+                       dcn_inner=4)(params, x)
     np.testing.assert_array_equal(np.asarray(hier_none.out),
                                   np.asarray(hier.out))
 
 
-@pytest.mark.slow
-def test_dcn_wire_fp8_hop_close_to_oracle_with_per_hop_error(devices):
+def test_dcn_wire_fp8_hop_close_to_oracle_with_per_hop_error(devices, jitted):
     """An fp8 DCN hop under a raw ICI hop: output stays close to the
     oracle (one fp8 round trip per leg), and MoEStats reports the two
     hops' round-trip errors separately — ici proxy 0 (leg wire off),
@@ -179,7 +175,7 @@ def test_dcn_wire_fp8_hop_close_to_oracle_with_per_hop_error(devices):
                     wire_dtype_dcn="e4m3", **F32)
     params, x = _setup(cfg)
     mesh = make_mesh(cfg, dp=1, devices=devices[:8])
-    out = ep_moe_layer(params, x, cfg, mesh, dcn_inner=4)
+    out = jitted(ep_moe_layer, cfg, mesh, dcn_inner=4)(params, x)
     want, _ = reference_moe(params, x, cfg)
     np.testing.assert_allclose(np.asarray(out.out), np.asarray(want),
                                atol=0.25)
@@ -187,12 +183,12 @@ def test_dcn_wire_fp8_hop_close_to_oracle_with_per_hop_error(devices):
     assert 0.0 < float(out.stats.wire_rtq_error_dcn) < 0.1
     # both wires on: both proxies populated, independently
     both = cfg.replace(wire_dtype="bf16")
-    ob = ep_moe_layer(params, x, both, mesh, dcn_inner=4)
+    ob = jitted(ep_moe_layer, both, mesh, dcn_inner=4)(params, x)
     assert float(ob.stats.wire_rtq_error) > 0.0
     assert float(ob.stats.wire_rtq_error_dcn) > 0.0
 
 
-def test_dcn_wire_split_hops_through_chunked_pipeline(devices):
+def test_dcn_wire_split_hops_through_chunked_pipeline(devices, jitted):
     """The per-hop codec composes with the chunked double-buffered
     pipeline: every chunk re-encodes its DCN hop, output stays close
     to the serial split-wire result."""
@@ -202,9 +198,9 @@ def test_dcn_wire_split_hops_through_chunked_pipeline(devices):
                     wire_dtype_dcn="e4m3", **F32)
     params, x = _setup(cfg)
     mesh = make_mesh(cfg, dp=1, devices=devices[:8])
-    serial = ep_moe_layer(params, x, cfg, mesh, dcn_inner=4)
-    chunked = ep_moe_layer(params, x, cfg.replace(a2a_chunks=2),
-                           mesh, dcn_inner=4)
+    serial = jitted(ep_moe_layer, cfg, mesh, dcn_inner=4)(params, x)
+    chunked = jitted(ep_moe_layer, cfg.replace(a2a_chunks=2), mesh,
+                     dcn_inner=4)(params, x)
     np.testing.assert_allclose(np.asarray(chunked.out),
                                np.asarray(serial.out),
                                rtol=1e-6, atol=1e-6)

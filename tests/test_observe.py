@@ -121,8 +121,7 @@ COLLECTIVES = ("all_to_all", "psum", "pmean", "all_gather", "ppermute",
                "ragged_all_to_all")
 
 
-@pytest.mark.slow
-def test_ep_stats_off_bit_identical_no_extra_collectives(devices):
+def test_ep_stats_off_bit_identical_no_extra_collectives(devices, jitted):
     from flashmoe_tpu.parallel.ep import ep_moe_layer
     from flashmoe_tpu.parallel.mesh import make_mesh
 
@@ -146,8 +145,9 @@ def test_ep_stats_off_bit_identical_no_extra_collectives(devices):
     on = collectives(cfg.replace(collect_stats=True))
     assert on["all_to_all"] == 2  # stats never add an exchange
 
-    o_off = ep_moe_layer(params, x, cfg, mesh)
-    o_on = ep_moe_layer(params, x, cfg.replace(collect_stats=True), mesh)
+    o_off = jitted(ep_moe_layer, cfg, mesh)(params, x)
+    o_on = jitted(ep_moe_layer, cfg.replace(collect_stats=True),
+                  mesh)(params, x)
     assert o_off.stats is None
     np.testing.assert_array_equal(np.asarray(o_off.out),
                                   np.asarray(o_on.out))

@@ -45,7 +45,7 @@ def test_preset_layer_runs_small(name):
         )
 
 
-def test_weak_scaling_256_bench_config(devices):
+def test_weak_scaling_256_bench_config(devices, jitted):
     """BASELINE config #5 (256-expert weak-scaling / payload-skew) must be
     there by name (``BENCH_CONFIGS["weak_scaling_256"]``) and correct:
     the full 256-expert routing runs through the collective EP layer on
@@ -65,7 +65,7 @@ def test_weak_scaling_256_bench_config(devices):
     x = jax.random.normal(jax.random.PRNGKey(1),
                           (cfg.tokens, cfg.hidden_size), jnp.float32)
     mesh = make_mesh(cfg, dp=1, devices=devices[:8])
-    out = ep_moe_layer(params, x, cfg, mesh)
+    out = jitted(ep_moe_layer, cfg, mesh)(params, x)
     want, _ = reference_moe(params, x, cfg)
     np.testing.assert_allclose(
         np.asarray(out.out), np.asarray(want), rtol=3e-4, atol=3e-4

@@ -149,11 +149,22 @@ def test_the_kernel_form_at_the_served_widths(monkeypatch, name, s):
     params = init_moe_params(jax.random.PRNGKey(4), cfg)
     x = jax.random.normal(jax.random.PRNGKey(5), (s, cfg.hidden_size),
                           jnp.bfloat16)
-    layer = lambda x: moe.moe_layer(params, x, cfg, use_pallas=False,
-                                    routed_rows=True).out
-    want = np.asarray(jax.jit(layer)(x), np.float32)
-    monkeypatch.setattr(moe, "routed_rows_form", lambda c: "routed_kernel")
-    got = np.asarray(jax.jit(layer)(x), np.float32)
+
+    def layer():
+        # a function of its own each time: jax.jit of the SAME function
+        # hands back its first trace and never reads the patched form (so
+        # these cases compared the plain form with itself until ISSUE 47);
+        # and the weights as an argument: 300 MB of closed-over constants
+        # took eight of a case's nine seconds to compile
+        return jax.jit(lambda p, x: moe.moe_layer(
+            p, x, cfg, use_pallas=False, routed_rows=True).out)
+
+    want = np.asarray(layer()(params, x), np.float32)
+    asked = []
+    monkeypatch.setattr(moe, "routed_rows_form",
+                        lambda c: asked.append(c) or "routed_kernel")
+    got = np.asarray(layer()(params, x), np.float32)
+    assert asked, "the second trace never asked for its form"
     assert np.abs(got - want).max() <= 2e-2 * np.abs(want).max()
 
 

@@ -193,8 +193,7 @@ def test_heterogeneous_src_order_published():
                                     cfg.replace(ep=2), 4) is None
 
 
-@pytest.mark.slow
-def test_fused_layer_picks_up_runtime_src_order(monkeypatch, devices):
+def test_fused_layer_picks_up_runtime_src_order(monkeypatch, devices, jitted):
     """fused_ep_moe_layer adopts the bootstrapped table only when the
     mesh's device ordering matches its rank indexing.  Proof of
     consumption: a deliberately INVALID published table must surface as
@@ -228,6 +227,7 @@ def test_fused_layer_picks_up_runtime_src_order(monkeypatch, devices):
     FakeRT.src_order = np.array(
         [[1, 0, 2, 3]] * 4, np.int32)  # not own-first
     with _pytest.raises(ValueError, match="starting with"):
+        # bare: the refusal comes before any program is built
         fused_ep_moe_layer(params, x, cfg, mesh, interpret=True)
 
     # valid reverse-ring table -> consumed, numerics still match oracle
@@ -235,7 +235,7 @@ def test_fused_layer_picks_up_runtime_src_order(monkeypatch, devices):
         np.array([r] + [(r - s) % 4 for s in range(1, 4)], np.int32)
         for r in range(4)
     ])
-    out = fused_ep_moe_layer(params, x, cfg, mesh, interpret=True)
+    out = jitted(fused_ep_moe_layer, cfg, mesh, interpret=True)(params, x)
     np.testing.assert_allclose(np.asarray(out.out), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
 
@@ -245,5 +245,5 @@ def test_fused_layer_picks_up_runtime_src_order(monkeypatch, devices):
     perm = [devices[2], devices[0], devices[3], devices[1]]
     mesh_p = make_mesh(cfg, dp=1, devices=perm)
     FakeRT.src_order = np.array([[1, 0, 2, 3]] * 4, np.int32)  # invalid
-    out_p = fused_ep_moe_layer(params, x, cfg, mesh_p, interpret=True)
+    out_p = jitted(fused_ep_moe_layer, cfg, mesh_p, interpret=True)(params, x)
     assert bool(jnp.isfinite(out_p.out).all())

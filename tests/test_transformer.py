@@ -48,13 +48,14 @@ def test_dense_layers_interleave():
     assert params["layers"][1]["moe"]["w_up"].shape[0] == cfg.num_experts
 
 
-@pytest.mark.slow
 def test_train_step_decreases_loss(devices):
     mesh = make_mesh(CFG)
     params = init_params(jax.random.PRNGKey(0), CFG)
     batch = _batch(CFG)
-    p1, l1, m1 = sgd_train_step(params, batch, CFG, lr=1e-2, mesh=mesh)
-    p2, l2, m2 = sgd_train_step(p1, batch, CFG, lr=1e-2, mesh=mesh)
+    step = jax.jit(lambda p, b: sgd_train_step(p, b, CFG, lr=1e-2,
+                                               mesh=mesh))
+    p1, l1, m1 = step(params, batch)
+    p2, l2, m2 = step(p1, batch)
     assert float(l2) < float(l1)
     assert np.isfinite(float(m2["ce"]))
 
@@ -115,16 +116,17 @@ def test_moe_backend_selection(backend, devices):
         )
 
 
-@pytest.mark.slow
 def test_sequence_parallel_forward(devices):
     """sp=2: ring attention + EP MoE with tokens sharded over (ep, sp)."""
     cfg = CFG.replace(ep=2, sp=2, sequence_len=128)
     mesh = make_mesh(cfg)
     params = init_params(jax.random.PRNGKey(0), cfg)
     tokens = _batch(cfg)["tokens"][:, :-1]
-    logits, aux = forward(params, tokens, cfg, mesh)
+    logits, aux = jax.jit(
+        lambda p, t: forward(p, t, cfg, mesh))(params, tokens)
     # oracle: same params, no mesh (single-device dense path)
-    want, _ = forward(params, tokens, cfg.replace(ep=1, sp=1), None)
+    want, _ = jax.jit(lambda p, t: forward(
+        p, t, cfg.replace(ep=1, sp=1), None))(params, tokens)
     np.testing.assert_allclose(
         np.asarray(logits), np.asarray(want), rtol=2e-3, atol=2e-3
     )

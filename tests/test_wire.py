@@ -148,7 +148,7 @@ def test_wire_off_invariants_via_staticcheck(devices):
 
 
 @pytest.mark.parametrize("wd,wc", [("bf16", None), ("e4m3", "e5m2")])
-def test_ep_wire_on_tracks_oracle(wd, wc, devices):
+def test_ep_wire_on_tracks_oracle(wd, wc, devices, jitted):
     """Two points cover both codec families and both legs: bf16
     dispatch-only (plain cast), fp8 on both legs (scaled, sidecar) —
     the fp8 point also carries collect_stats so the wire_rtq_error
@@ -157,9 +157,9 @@ def test_ep_wire_on_tracks_oracle(wd, wc, devices):
     cfg, params, x = _ep_setup(collect_stats=stats)
     mesh = make_mesh(cfg, dp=1, devices=devices[:2])
     want, _ = reference_moe(params, x, cfg)
-    on = ep_moe_layer(
-        params, x, cfg.replace(wire_dtype=wd, wire_dtype_combine=wc),
-        mesh)
+    on = jitted(ep_moe_layer,
+                cfg.replace(wire_dtype=wd, wire_dtype_combine=wc),
+                mesh)(params, x)
     scale = float(jnp.max(jnp.abs(want)))
     err = float(jnp.max(jnp.abs(on.out - want))) / scale
     # fp8 keeps 2-3 mantissa bits per leg (e5m2 on the combine leg is
@@ -170,7 +170,7 @@ def test_ep_wire_on_tracks_oracle(wd, wc, devices):
         assert 0.0 < float(on.stats.wire_rtq_error) < 0.1
 
 
-def test_hierarchical_a2a_wire_roundtrip_matches_flat(devices):
+def test_hierarchical_a2a_wire_roundtrip_matches_flat(devices, jitted):
     """The two-stage (intra-slice, inter-slice) exchange must carry
     payload AND fp8 scales consistently through both hops: with the wire
     on, hierarchical and flat outputs are bit-identical (same codec,
@@ -178,13 +178,13 @@ def test_hierarchical_a2a_wire_roundtrip_matches_flat(devices):
     cfg, params, x = _ep_setup(ep=4)
     on = cfg.replace(wire_dtype="e4m3", wire_dtype_combine="bf16")
     mesh = make_mesh(cfg, dp=1, devices=devices[:4])
-    flat = ep_moe_layer(params, x, on, mesh)
-    hier = ep_moe_layer(params, x, on, mesh, dcn_inner=2)
+    flat = jitted(ep_moe_layer, on, mesh)(params, x)
+    hier = jitted(ep_moe_layer, on, mesh, dcn_inner=2)(params, x)
     np.testing.assert_array_equal(np.asarray(flat.out),
                                   np.asarray(hier.out))
 
 
-def test_ragged_wire_on_accurate(devices):
+def test_ragged_wire_on_accurate(devices, jitted):
     # Wire-off identity and the fp8-free ragged graph are the invariant
     # engine's job now (test_wire_off_invariants_via_staticcheck covers
     # the ragged backend in the same matrix).  The single expensive
@@ -195,9 +195,8 @@ def test_ragged_wire_on_accurate(devices):
     cfg, params, x = _ep_setup(sequence_len=64)
     mesh = make_mesh(cfg, dp=1, devices=devices[:2])
     want, _ = reference_moe(params, x, cfg)
-    on = ragged_ep_moe_layer(
-        params, x, cfg.replace(wire_dtype="e4m3"), mesh,
-        exchange="dense")
+    on = jitted(ragged_ep_moe_layer, cfg.replace(wire_dtype="e4m3"), mesh,
+                exchange="dense")(params, x)
     scale = float(jnp.max(jnp.abs(want)))
     assert float(jnp.max(jnp.abs(on.out - want))) / scale < 0.1
 
@@ -211,6 +210,7 @@ def test_fused_layer_rejects_wire(devices):
     cfg, params, x = _ep_setup(ep=2, sequence_len=64, wire_dtype="bf16")
     mesh = make_mesh(cfg, dp=1, devices=devices[:2])
     with pytest.raises(ValueError, match="raw slabs"):
+        # bare: the refusal comes before any program is built
         fused_ep_moe_layer(params, x, cfg, mesh, interpret=True)
 
 
@@ -240,7 +240,7 @@ def test_ep_wire_grad_finite(devices):
         o = ep_moe_layer(p, x, cfg, mesh)
         return jnp.sum(o.out.astype(jnp.float32) ** 2) + o.aux_loss
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     for leaf in jax.tree_util.tree_leaves(g):
         assert np.isfinite(np.asarray(leaf)).all()
 
