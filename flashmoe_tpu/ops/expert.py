@@ -91,12 +91,47 @@ def _ffn_chunks(x, w_up, w_gate, block_m: int, block_i: int, gated: bool,
     return bi, _vmem_params(need(bi))
 
 
-def _up_specs(h: int, bi: int, gated: bool):
+def _chunk_of_step(ti, j, *scalars):
+    """The intermediate chunk whose weight blocks grid step ``(ti, j)``
+    holds: ``j``, every kernel's walk."""
+    return j
+
+
+def _chunk_under_live(nj: int):
+    """:func:`_chunk_of_step` for a launch with ``live_tiles`` that walks
+    ``nj`` > 1 chunks, where the pipeline's rule (a block is fetched when
+    its index differs from the step before) would cost bytes no row needs.
+
+    A live tile walks the chunks forward when its number is even and
+    backward when it is odd, so two consecutive tiles meet at ONE chunk:
+    where they are one expert's, that chunk's blocks are fetched once for
+    the pair (with ``j`` under both, the index wraps and a group of t
+    tiles streams ``t x nj`` blocks; so it streams ``t x (nj - 1) + 1``).
+    The float32 sum over the chunks of an odd tile runs in the other
+    order.
+
+    A tile past the last live one holds the chunk the last live tile
+    ended at, at every ``j`` (its group id repeats that tile's too): from
+    the last live step to the end of the grid no weight block's index
+    changes and nothing is fetched.  With ``j`` there the dead tiles
+    streamed the last live expert's matrices once more EACH."""
+    def walk(ti, j):
+        return jnp.where(ti % 2 == 0, j, nj - 1 - j)
+
+    def chunk(ti, j, gid, live_ref):
+        last = live_ref[0] - 1
+        return jnp.where(ti <= last, walk(ti, j), walk(last, nj - 1))
+
+    return chunk
+
+
+def _up_specs(h: int, bi: int, gated: bool, chunk=_chunk_of_step):
     """BlockSpecs of the up (and gate) weight chunk of a row tile's
     expert: ``[E, H, I]`` blocks ``(1, H, bi)`` chosen by the
-    scalar-prefetched group id."""
-    spec = pl.BlockSpec((1, h, bi), lambda ti, j, gid, *_: (gid[ti], 0, j),
-                        memory_space=pltpu.VMEM)
+    scalar-prefetched group id, the chunk by ``chunk``."""
+    spec = pl.BlockSpec(
+        (1, h, bi), lambda ti, j, gid, *s: (gid[ti], 0, chunk(ti, j, gid, *s)),
+        memory_space=pltpu.VMEM)
     return [spec, spec] if gated else [spec]
 
 
@@ -178,8 +213,12 @@ def _ffn_kernel(gid_ref, *refs, act_name, gated, live):
             ).astype(out_ref.dtype)
 
     if live:
-        # a tile past the last populated one: nothing to compute, and its
-        # group id repeats the last live tile's, so nothing is fetched
+        # a tile past the last populated one: nothing to compute, and
+        # nothing to fetch, because no weight block's index moves there:
+        # its group id repeats the last live tile's and its chunk stays
+        # where that tile ended (``_chunk_under_live``; with one chunk
+        # there is one index).  Which chunk a live step holds is the
+        # index maps' business: the sum below takes them as they come
         pl.when(pl.program_id(0) < live_ref[0])(step)
     else:
         step()
@@ -201,13 +240,22 @@ def grouped_ffn(x, tile_gid, w_up, b_up, w_down, b_down, w_gate=None,
     w_gate:   [E, H, I] for SwiGLU-style experts.
     live_tiles: [1] int32, the populated row tiles (a ragged plan's
               ``num_rows // block_m``); the tiles from there on are not
-              computed and their rows of the output hold nothing.  None:
+              computed, their rows of the output hold nothing, and they
+              fetch no weights however many chunks the intermediate axis
+              is walked in, PROVIDED their ``tile_gid`` repeats the last
+              live tile's (``ops/ragged.sorted_rows_plan`` lays it out
+              so): their row tile and output tile still move.  None:
               every tile is computed.
 
     Returns [T, H].  The scalar-prefetched ``tile_gid`` drives the weight
     BlockSpec index maps, so each row tile DMAs only its own expert's weight
-    chunks (megablox-style block-sparse grouped GEMM), and consecutive
-    tiles of one expert fetch them once.
+    chunks (megablox-style block-sparse grouped GEMM).  Where the
+    intermediate axis is ONE chunk (an expert's matrices, double buffered,
+    within ``_VMEM_CEILING``), consecutive tiles of one expert fetch them
+    once; walked in ``nj`` > 1, every tile streams its expert's chunks
+    again, so an expert streams once a TILE (less one chunk of ``nj`` a
+    tile after its first where ``live_tiles`` is given:
+    :func:`_chunk_under_live`).
     """
     t, h = x.shape
     e, _, i = w_up.shape
@@ -217,6 +265,7 @@ def grouped_ffn(x, tile_gid, w_up, b_up, w_down, b_down, w_gate=None,
     nt, nj = t // block_m, i // bi
     live = live_tiles is not None
     scalars = (tile_gid, live_tiles) if live else (tile_gid,)
+    chunk = _chunk_under_live(nj) if live and nj > 1 else _chunk_of_step
     up_weights = (w_up, w_gate) if gated else (w_up,)
 
     # biases are lifted to [E, 1, dim] so their (1, dim) trailing block shape
@@ -230,11 +279,15 @@ def grouped_ffn(x, tile_gid, w_up, b_up, w_down, b_down, w_gate=None,
         in_specs=[
             pl.BlockSpec((block_m, h), lambda ti, j, *_: (ti, 0),
                          memory_space=pltpu.VMEM),
-            *_up_specs(h, bi, gated),
-            pl.BlockSpec((1, 1, bi), lambda ti, j, gid, *_: (gid[ti], 0, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bi, h), lambda ti, j, gid, *_: (gid[ti], j, 0),
-                         memory_space=pltpu.VMEM),
+            *_up_specs(h, bi, gated, chunk),
+            pl.BlockSpec(
+                (1, 1, bi),
+                lambda ti, j, gid, *s: (gid[ti], 0, chunk(ti, j, gid, *s)),
+                memory_space=pltpu.VMEM),
+            pl.BlockSpec(
+                (1, bi, h),
+                lambda ti, j, gid, *s: (gid[ti], chunk(ti, j, gid, *s), 0),
+                memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, h), lambda ti, j, gid, *_: (gid[ti], 0, 0),
                          memory_space=pltpu.VMEM),
         ],

@@ -191,10 +191,16 @@ def _rows_kernel_ffn(x, flat_e, sizes, w_up, b_up, w_down, b_down, w_gate, *,
     expert (``ops/ragged.sorted_rows_plan``: from the one sort); a tile
     DMAs its own expert's weights, consecutive tiles of an expert fetch
     them once, an expert with no rows has no tile and is never read, and
-    the tiles past the last populated one skip their body.  The
-    intermediate axis is ONE chunk (an expert's three matrices, double
-    buffered, fit the kernel's VMEM ceiling at the widths served), so an
-    expert streams once however many tiles it has.  Jitted with the
+    the tiles past the last populated one skip their body and fetch no
+    weights.  The intermediate axis is ONE chunk wherever an expert's
+    matrices, double buffered, fit the kernel's VMEM ceiling (every width
+    served but H 6144 x I 2048, LongCat's: four chunks,
+    :func:`expert_chunks`), and an expert then streams once however many
+    tiles it has; walked in more, it streams once a TILE it has, less a
+    chunk a tile after its first (``expert._chunk_under_live``): once an
+    expert in a decode step, whose 16-row tiles no expert fills twice,
+    1.3 times in a 1024-token chunk of LongCat's routing (PERF.md section
+    6, PR 48).  Jitted with the
     layer's weights as operands: the mixture layers of a program share
     one traced and lowered function."""
     n = flat_e.shape[0]
@@ -302,6 +308,22 @@ def rows_block_m(cfg: MoEConfig, s: int) -> int:
            and block < 256):
         block *= 2
     return block
+
+
+def expert_chunks(cfg: MoEConfig, s: int) -> int:
+    """Chunks of the intermediate axis that the routed-rows kernel's
+    launch of a span of ``s`` rows walks (``ops/expert._ffn_chunks`` on
+    the shapes :func:`_rows_kernel_ffn` hands it: a tile of
+    :func:`rows_block_m` rows, the experts' matrices at the compute dtype
+    and the stored width, all of it asked for): 1 where an expert's
+    matrices, double buffered, fit the kernel's VMEM ceiling."""
+    stored = cfg.intermediate_size + cfg.intermediate_pad
+    block_m = rows_block_m(cfg, s)
+    x = jax.ShapeDtypeStruct((block_m, cfg.hidden_size), cfg.dtype)
+    w = jax.ShapeDtypeStruct((1, cfg.hidden_size, stored), cfg.dtype)
+    bi, _ = exp._ffn_chunks(x, w, w if cfg.gated_ffn else None, block_m,
+                            stored, cfg.gated_ffn)
+    return stored // bi
 
 
 def routed_rows_form(cfg: MoEConfig) -> str:

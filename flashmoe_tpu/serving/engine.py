@@ -73,7 +73,7 @@ from flashmoe_tpu.models.generate import (
     REVEAL_RULES, lm_logits, lm_logits_span, reveal_rows, span_forward,
 )
 from flashmoe_tpu.ops import attention
-from flashmoe_tpu.ops.moe import expert_arm
+from flashmoe_tpu.ops.moe import expert_arm, expert_chunks
 from flashmoe_tpu.serving.kvcache import (
     SCRATCH_PAGE, PagedKVCache, PagePool, ShardedPagePool,
     ctx_pages_bucket, init_paged_cache, prompt_pad,
@@ -833,8 +833,8 @@ class ServingEngine:
         self._phase_ms: dict = {}   # this step's phases, by name
         self._delivered_now: dict = {}   # rid -> tokens this step
         # this step's decode program: pages read, idle, slots, the
-        # attention's arm, the experts' arm
-        self._ctx_pages = (0, 0.0, 0, None, None)
+        # attention's arm, the experts' arm and chunks
+        self._ctx_pages = (0, 0.0, 0, None, None, None)
         # this step's sampler rows: not idle, drawn, truncating
         self._sampled = np.zeros((3,), np.int64)
         # this step's host account (see _step): the time blocked on the
@@ -1453,7 +1453,7 @@ class ServingEngine:
         done[0] += 1
         done[1] += tokens
         done[2] += rows
-        arm = self._expert_arm(rows)
+        arm, chunks = self._expert_arm(rows)
         # the arm the program's attention layers took over their context
         # (``ops/attention.span_attention_arm``: the rule the traced
         # program asked), counted where it is the flash kernel
@@ -1468,7 +1468,8 @@ class ServingEngine:
             self.recorder.record(
                 kind="serve_prefill", step=self.step_idx, rid=rid,
                 slot=slot, form=form, pos=pos, tokens=tokens, rows=rows,
-                pad_rows=rows - tokens, expert_arm=arm, attn_arm=attn_arm,
+                pad_rows=rows - tokens, expert_arm=arm,
+                expert_chunks=chunks, attn_arm=attn_arm,
                 host_ms=round((self._clock() - fed[0]) * 1e3, 3),
                 starved=starved, t0_trace_ns=fed[1])
 
@@ -1927,21 +1928,28 @@ class ServingEngine:
                 -(-lengths // (block * page)) * block + span_pages)), 3)
         self._ctx_pages = (read, max(0.0, read - float(own.mean())),
                            len(lengths), arm,
-                           self._expert_arm(self.serve.max_batch * t_span))
+                           *self._expert_arm(self.serve.max_batch * t_span))
 
-    def _expert_arm(self, rows: int) -> str | None:
+    def _expert_arm(self, rows: int) -> tuple[str | None, int | None]:
         """The arm the mixture layers of a program of ``rows`` rows take
         through their experts (``ops/moe.expert_arm``: the rule the traced
-        program asked), counted where it is the grouped kernel.  None for
-        a model with no mixture layer and for an EP-sharded step (its
-        experts are the exchange's)."""
+        program asked), counted where it is the grouped kernel, and there
+        the chunks its launches walk the intermediate axis in
+        (``ops/moe.expert_chunks``; counted where more than one: a launch
+        then streams an expert once a tile of the plan, not once).  No arm
+        for a model with no mixture layer and for an EP-sharded step (its
+        experts are the exchange's), no chunks off the kernel."""
         mixture = self.cfg.moe_layer_indices
         if not mixture or self._ep_fn is not None:
-            return None
+            return None, None
         arm = expert_arm(self.cfg, rows)    # a mixture's config is cfg
-        if arm == "routed_kernel":
-            self.metrics.count("serve.expert_kernel_programs")
-        return arm
+        if arm != "routed_kernel":
+            return arm, None
+        self.metrics.count("serve.expert_kernel_programs")
+        chunks = expert_chunks(self.cfg, rows)
+        if chunks > 1:
+            self.metrics.count("serve.expert_chunked_programs")
+        return arm, chunks
 
     def _sample(self, logits, knobs, n_rows: int):
         """Dispatch :func:`_sample_dynamic` on ``logits`` and ``knobs``
@@ -2288,7 +2296,7 @@ class ServingEngine:
         gc_n0, gc_s0 = gc_totals()
         self._phase_ms = {}
         self._delivered_now = {}
-        self._ctx_pages = (0, 0.0, 0, None, None)
+        self._ctx_pages = (0, 0.0, 0, None, None, None)
         self._sampled = np.zeros((3,), np.int64)
         self._state_bytes = 0
         self._wait_ms = 0.0
@@ -2406,7 +2414,8 @@ class ServingEngine:
         self.metrics.sketch("serve.host_ms", host_ms)
         if not first:
             self.metrics.sketch("serve.between_ms", between_ms)
-        ctx_pages, ctx_idle, n_decoding, attn_arm, ffn_arm = self._ctx_pages
+        (ctx_pages, ctx_idle, n_decoding, attn_arm, ffn_arm,
+         ffn_chunks) = self._ctx_pages
         sample_rows, sample_drawn, sample_sorted = map(int, self._sampled)
         if sample_rows:
             self.metrics.count("serve.sample_steps")
@@ -2496,7 +2505,8 @@ class ServingEngine:
                     kind="serve_decode", step=self.step_idx,
                     slots=n_decoding, ctx_pages=ctx_pages,
                     ctx_pages_idle=rec["ctx_pages_idle"],
-                    attn_arm=attn_arm, expert_arm=ffn_arm, **more)
+                    attn_arm=attn_arm, expert_arm=ffn_arm,
+                    expert_chunks=ffn_chunks, **more)
         if self.watchdog is not None:
             self.watchdog.observe_step(self.step_idx, step_ms)
         self.step_idx += 1

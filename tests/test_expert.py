@@ -160,3 +160,135 @@ def test_grouped_ffn_respects_tile_gid():
             np.asarray(got[t * bm:(t + 1) * bm]), np.asarray(want),
             rtol=2e-4, atol=2e-4,
         )
+
+
+# ----------------------------------------------------------------------
+# What a launch FETCHES: the weight blocks' index maps, walked as the
+# pipeline walks them (ISSUE 48)
+# ----------------------------------------------------------------------
+
+def _weight_fetches(monkeypatch, tile_gid, live, nj, *, gated=True, bm=16):
+    """The grid steps of one ``grouped_ffn`` launch at which the pipeline
+    fetches each weight operand: the launch is traced with ``pl.pallas_call``
+    replaced by a recorder of the ``BlockSpec`` s it is handed, every block's
+    index map is evaluated at every step of the ``(nt, nj)`` grid in the
+    order the grid runs (``j`` fastest) with the prefetched scalars as the
+    arrays they are, and a step counts where the block's index differs from
+    the step before (the first step fetches).  Returns ``{operand: steps}``
+    for the up, gate, up-bias and down blocks, and the sequence of the up
+    block's indices."""
+    from flashmoe_tpu.ops import expert as exp
+
+    h, i, e = 128, 128 * nj, int(max(tile_gid)) + 1
+    nt = len(tile_gid)
+    # one chunk of 128 columns fits, two do not: the walk is ``nj`` chunks
+    need = lambda b: exp._ffn_vmem(bm, h, b, gated, 2, 2)
+    monkeypatch.setattr(exp, "_VMEM_CEILING", need(128))
+    seen = {}
+
+    def record(kernel, *, grid_spec, out_shape, **kw):
+        seen["spec"] = grid_spec
+        return lambda *operands: jnp.zeros(out_shape.shape, out_shape.dtype)
+
+    monkeypatch.setattr(exp.pl, "pallas_call", record)
+    bf = lambda *s: jnp.zeros(s, jnp.bfloat16)
+    f32 = lambda *s: jnp.zeros(s, jnp.float32)
+    gid = jnp.asarray(tile_gid, jnp.int32)
+    lv = None if live is None else jnp.asarray([live], jnp.int32)
+    # not the jitted function: a trace of it would outlive the patches
+    exp.grouped_ffn.__wrapped__(
+        bf(nt * bm, h), gid, bf(e, h, i), f32(e, i), bf(e, i, h), f32(e, h),
+        bf(e, h, i) if gated else None, lv, act_name="silu", gated=gated,
+        block_m=bm, block_i=i)
+    spec = seen["spec"]
+    assert spec.grid == (nt, nj)
+    names = (["x", "up"] + (["gate"] if gated else [])
+             + ["b_up", "down", "b_down"])
+    assert len(spec.in_specs) == len(names)
+    scalars = (np.asarray(tile_gid),) + (() if live is None
+                                         else (np.asarray([live]),))
+    fetches, last, walk = dict.fromkeys(names, 0), {}, []
+    for ti in range(nt):
+        for j in range(nj):
+            for name, bs in zip(names, spec.in_specs):
+                at = tuple(int(a) for a in bs.index_map(ti, j, *scalars))
+                fetches[name] += at != last.get(name)
+                last[name] = at
+                if name == "up":
+                    walk.append(at)
+    return fetches, walk
+
+
+#: the decode plan of ``longcat_flash_omni.serve.avturns``: 304 rows in 19
+#: tiles of 16, ten experts of sixteen touched with a tile each, four chunks
+DECODE_PLAN = dict(tile_gid=[0, 2, 3, 5, 6, 8, 9, 11, 12, 15] + [15] * 9,
+                   live=10, nj=4)
+#: its walk with the parent's maps ``(gid[ti], 0, j)`` / ``(gid[ti], j, 0)``
+PARENT_WALK = lambda gid, nj: [(g, 0, j) for g in gid for j in range(nj)]
+#: and with every other tile walked backward: consecutive tiles meet at a chunk
+SERPENTINE = lambda gid, nj: [
+    (g, 0, j if ti % 2 == 0 else nj - 1 - j)
+    for ti, g in enumerate(gid) for j in range(nj)]
+
+
+def test_dead_tiles_of_a_chunked_launch_fetch_no_weights(monkeypatch):
+    """19 tiles, 10 live, 4 chunks: the weight blocks' indices change at
+    ``live x nj`` = 40 steps (each touched expert's four chunks, once);
+    on the parent's maps ``j`` ran 0..3 under every dead tile too and
+    they changed at ``nt x nj`` = 76, the last live expert streamed nine
+    times more."""
+    fetches, walk = _weight_fetches(monkeypatch, **DECODE_PLAN)
+    assert [fetches[k] for k in ("up", "gate", "b_up", "down")] == [40] * 4
+    assert fetches["b_down"] == 10 and fetches["x"] == 19
+    parent = PARENT_WALK(DECODE_PLAN["tile_gid"], 4)
+    assert 1 + sum(a != b for a, b in zip(parent, parent[1:])) == 76
+    # the live tiles: each its own expert's four chunks, odd tiles backward
+    assert walk[:40] == SERPENTINE(DECODE_PLAN["tile_gid"][:10], 4)
+    assert sorted(walk[:40]) == sorted(parent[:40])
+    # the dead ones: where the last live tile (the tenth: odd) ended
+    assert set(walk[40:]) == {walk[39]} == {(15, 0, 0)}
+
+
+@pytest.mark.parametrize("plan,want", [
+    # a group of two tiles meets at one chunk: 2 x 4 - 1 blocks, where the
+    # parent's wrapping ``j`` fetched 8 (and a group of t: t x 3 + 1)
+    (dict(tile_gid=[1, 4, 4, 7, 7, 7, 7], live=4, nj=4), 4 + 7 + 4),
+    (dict(tile_gid=[1, 4, 4, 7, 7, 7, 7], live=4, nj=2), 2 + 3 + 2),
+    (dict(tile_gid=[2, 2, 2, 2, 5, 5], live=5, nj=4), 13 + 4),
+    (dict(tile_gid=[3, 3, 3, 3], live=1, nj=4), 4),
+    # no live tile (a window past the populated ones): one block, once
+    (dict(tile_gid=[0, 0, 0], live=0, nj=4), 1),
+    # every tile live: nothing to hold
+    (dict(tile_gid=[0, 1, 2], live=3, nj=4), 12),
+    (dict(tile_gid=[0, 1, 1, 1], live=2, nj=4, gated=False), 8),
+], ids=["pair", "pair_two_chunks", "four_tiles", "one_live", "none_live",
+        "all_live", "ungated"])
+def test_a_chunked_launch_fetches_a_group_once_and_a_bit(monkeypatch, plan,
+                                                         want):
+    """``live x nj`` blocks less one for every live tile that follows a
+    tile of its own expert; every live tile still walks each of its
+    expert's chunks exactly once; no step past the live ones fetches."""
+    fetches, walk = _weight_fetches(monkeypatch, **plan)
+    live, nj, gid = plan["live"], plan["nj"], plan["tile_gid"]
+    pairs = sum(a == b for a, b in zip(gid[:live], gid[1:live]))
+    assert want == max(live * nj - pairs, 1)
+    assert fetches["up"] == fetches["down"] == fetches["b_up"] == want
+    assert walk[:live * nj] == SERPENTINE(gid[:live], nj)
+    for ti in range(live):
+        assert sorted(walk[ti * nj:(ti + 1) * nj]) == [
+            (gid[ti], 0, j) for j in range(nj)]
+    assert len(set(walk[max(live * nj - 1, 0):])) == 1
+
+
+@pytest.mark.parametrize("live,nj", [(None, 4), (None, 1), (10, 1)],
+                         ids=["no_live_tiles", "no_live_one_chunk",
+                              "live_one_chunk"])
+def test_other_launches_keep_the_parents_index_maps(monkeypatch, live, nj):
+    """Without ``live_tiles`` (training's launches) or with ONE chunk
+    (every serving cell but LongCat's) the walk is the parent's, step for
+    step; with one chunk a dead tile never fetched (its index is the last
+    live tile's)."""
+    gid = DECODE_PLAN["tile_gid"]
+    fetches, walk = _weight_fetches(monkeypatch, gid, live, nj)
+    assert walk == PARENT_WALK(gid, nj)
+    assert fetches["up"] == (76 if nj == 4 else 10)

@@ -6,6 +6,9 @@ The kernel form of ``ops/moe.routed_rows_ffn`` is held against its plain
 ``jax.lax.ragged_dot`` form through the function's own contract: x and a
 router's output in, ``[S, H]`` float32 back.  Serving: no gradients."""
 
+import hashlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -123,6 +126,118 @@ def test_a_share_of_the_experts_gets_tiles_for_its_own_rows(monkeypatch, here,
         idx = np.asarray(r.expert_idx)
         out = ~((idx >= 4) & (idx < 8)).any(axis=1)
         assert out.any() and not got[out].any()
+
+
+def _retrace_launches():
+    """The three jitted functions between the layer and the kernel keep
+    their traces by shape: one made under another ``_VMEM_CEILING`` or
+    other index maps would be handed back."""
+    from flashmoe_tpu.ops import expert as exp
+
+    for fn in (moe._rows_kernel_ffn, moe._rows_kernel_waves,
+               exp.grouped_ffn):
+        fn.clear_cache()
+
+
+@pytest.fixture
+def four_chunks(monkeypatch):
+    """``_VMEM_CEILING`` at what ONE 128-column chunk of a gated H 128
+    expert needs under 16-row bf16 tiles: at I 512 the launch walks four
+    chunks, as LongCat's does at H 6144 x I 2048 under the real ceiling."""
+    from flashmoe_tpu.ops import expert as exp
+
+    monkeypatch.setattr(exp, "_VMEM_CEILING",
+                        exp._ffn_vmem(16, 128, 128, True, 2, 2))
+    _retrace_launches()
+    yield exp
+    monkeypatch.undo()
+    _retrace_launches()
+
+
+#: rows on the four experts held (4-7 of 32; S x K = 128 routed rows, a
+#: plan of 64 in windows of 7 tiles): dead tiles behind a lopsided group
+#: of three tiles; and 8 live tiles, a second window with one live of 7
+HELD_ROWS = {"one_window": [5, 0, 40, 3], "two_windows": [40, 40, 8, 1]}
+
+
+@pytest.mark.parametrize("rows", list(HELD_ROWS))
+def test_the_chunked_walk_through_the_layer(monkeypatch, four_chunks, rows):
+    """ISSUE 48: the intermediate axis in four chunks under a plan with
+    dead tiles (a share of the experts held: ``rows_plan`` < S x K) and a
+    group of several tiles.  The layer is the ``ragged_dot`` form's to
+    bf16's rounding, and the launch whose tiles walk the chunks as
+    ``expert._chunk_under_live`` says returns what the parent's maps,
+    ``j`` under every tile, return: a dead tile computes nothing under
+    either, an even live tile sums the same chunks in the same order (its
+    rows BIT FOR BIT), an odd one in the other order (float32 sums in
+    another order, rounded to bf16 once: an ulp of bf16 at most)."""
+    exp = four_chunks
+    cfg = _cfg(e=32, i=512, experts_held=4, expert_first=4)
+    here = HELD_ROWS[rows]
+    rest = 128 - 24 - sum(here)                    # on the last sixteen
+    r = _route([2] * 4 + here + [2] * 8
+               + [rest // 16 + (e < rest % 16) for e in range(16)], cfg)
+    s = r.expert_idx.shape[0]
+    assert s * 2 == 128 and moe.rows_plan(cfg, s) == 64 < s * 2
+    assert moe.rows_block_m(cfg, s) == 16 and moe.expert_chunks(cfg, s) == 4
+    params = init_moe_params(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(6), (s, 128), jnp.bfloat16)
+    got, want = _both_forms(monkeypatch, params, x, r, cfg)
+    _close(got, want)
+    # a token none of whose choices is held gets exactly nothing
+    idx = np.asarray(r.expert_idx)
+    out = ~((idx >= 4) & (idx < 8)).any(axis=1)
+    assert out.any() and not got[out].any()
+    held = []
+    monkeypatch.setattr(exp, "_chunk_under_live",
+                        lambda nj: held.append(nj) or exp._chunk_of_step)
+    _retrace_launches()
+    parents = jax.jit(lambda x: moe.routed_rows_ffn(params, x, r, cfg))(x)
+    assert held == [4], "the launch never asked for its tiles' chunks"
+    parents = np.asarray(parents)
+    assert (parents == got).mean() > 0.5           # the even tiles' tokens
+    _close(got, parents, tol=2 ** -7)
+
+
+#: the eight configurations the benchmark runs, by their presets: H, the
+#: intermediate width as stored, gated, compute dtype
+CONFIGURATIONS = {
+    "dsmoe16b": ("deepseek-moe-16b", (2048, 1408, True, "bfloat16")),
+    "fmref": ("flashmoe-reference", (2048, 2048, False, "bfloat16")),
+    "joyai_flash": ("joyai-llm-flash", (2048, 768, True, "bfloat16")),
+    "ling3_flash": ("ling-3.0-flash", (2560, 768, True, "bfloat16")),
+    "lfm2_24b": ("lfm2-24b-a2b", (2048, 1536, True, "bfloat16")),
+    "nemotron3_nano": ("nemotron-3-nano-30b-a3b",
+                       (2688, 1920, False, "bfloat16")),
+    "longcat_flash_omni": ("longcat-flash", (6144, 2048, True, "bfloat16")),
+    "sdar_30b_a3b": ("sdar-30b-a3b-chat", (2048, 768, True, "bfloat16")),
+}
+
+
+@pytest.mark.parametrize("name", list(CONFIGURATIONS))
+def test_only_longcats_launch_walks_more_than_one_chunk(name):
+    """``_ffn_chunks`` at each configuration's (H, I, gated, dtype), the
+    tile of a decode step and of a 1024-token chunk: an expert's matrices
+    double buffered are 18-41 MB against the kernel's 64 MiB of VMEM
+    everywhere but at H 6144 x I 2048 (151 MB: chunks of 512 columns).
+    So ``longcat_flash_omni`` alone launches the maps that read
+    ``live_tiles``, and ``expert_chunks`` says so to the engine's
+    records."""
+    from flashmoe_tpu.ops import expert as exp
+
+    preset, widths = CONFIGURATIONS[name]
+    cfg = PRESETS[preset]()
+    h, i, gated, dtype = widths
+    assert (cfg.hidden_size, cfg.intermediate_size + cfg.intermediate_pad,
+            cfg.gated_ffn, jnp.dtype(cfg.dtype).name) == widths
+    w = jax.ShapeDtypeStruct((1, h, i), cfg.dtype)
+    for s in (64, 1024):
+        bm = moe.rows_block_m(cfg, s)
+        bi, _ = exp._ffn_chunks(jax.ShapeDtypeStruct((bm, h), cfg.dtype), w,
+                                w if gated else None, bm, i, gated)
+        assert (bi < i) == (name == "longcat_flash_omni")
+        assert moe.expert_chunks(cfg, s) == i // bi == (
+            4 if name == "longcat_flash_omni" else 1)
 
 
 #: the four serving configurations' widths (H, I, K, activation, biases as
@@ -305,3 +420,184 @@ def test_engine_on_the_kernel_form_serves_the_plain_arms_tokens(monkeypatch,
         "routed_kernel"}
     assert len(arms["serve_prefill"]) >= 4       # a prompt in two chunks
     assert counted == len(arms["serve_decode"]) + len(arms["serve_prefill"])
+
+
+# ----------------------------------------------------------------------
+# The launches ISSUE 48 must not move are the PARENT's: their programs
+# with every kernel's index maps, pinned from a copy of the parent commit
+# ----------------------------------------------------------------------
+
+def _program_text(jaxpr) -> str:
+    """A jaxpr's text and, behind it, the index maps of every
+    ``pallas_call`` under it in the order met (the printed jaxpr leaves
+    them out: a changed map would change WHAT A LAUNCH FETCHES and no
+    letter of the text)."""
+    maps = []
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                maps.extend(str(bm.index_map_jaxpr) for bm in
+                            eqn.params["grid_mapping"].block_mappings)
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return "\n".join([str(jaxpr), *maps])
+
+
+def _digest(fn, *args):
+    text = _program_text(jax.make_jaxpr(fn)(*args))
+    text = re.sub(r" at [^\s]+\.py:\d+", "", text)
+    text = re.sub(r"0x[0-9a-f]+", "0x", text)
+    return hashlib.sha256(text.encode()).hexdigest(), text
+
+
+def _parents_maps(exp):
+    """``grouped_ffn`` with every tile's chunk ``j``, the parent's walk
+    (on the parent's tree: the function itself)."""
+    def launch(*a, **kw):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(exp, "_chunk_under_live",
+                       lambda nj: lambda ti, j, *scalars: j, raising=False)
+            return exp.grouped_ffn.__wrapped__(*a, **kw)
+    return launch
+
+
+def single_chunk_digests():
+    """Digests (and texts) of what every configuration but LongCat's
+    runs through ``ops/expert.py``, on this tree (run from a copy of the
+    parent commit to make the pins: ``PYTHONPATH=. python
+    <this file>``): the decode step and a prefill chunk of a K/V toy
+    whose mixtures take the routed-rows kernel (traced as on a TPU, H and
+    I whole lanes: ONE chunk, ``live_tiles`` given), and the launches
+    that share ``_up_specs`` and carry no ``live_tiles``: the training
+    forward and its residual-saving twin in two chunks, the gather-fused
+    kernel."""
+    from flashmoe_tpu.models.transformer import init_params
+    from flashmoe_tpu.ops import expert as exp
+    from flashmoe_tpu.serving import engine as eng
+    from flashmoe_tpu.serving.kvcache import init_paged_cache
+
+    cfg = MoEConfig(
+        num_experts=8, expert_top_k=2, hidden_size=128,
+        intermediate_size=256, num_layers=2, vocab_size=300, num_heads=2,
+        num_kv_heads=1, head_dim=128, gated_ffn=True, drop_tokens=False,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, sequence_len=128)
+    shape = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: init_paged_cache(cfg, 32, 16, 4))
+    i32 = lambda *s: shape(s, jnp.int32)
+    bf = lambda *s: shape(s, jnp.bfloat16)
+    f32 = lambda *s: shape(s, jnp.float32)
+    ffn = (bf(4, 128, 512), f32(4, 512), bf(4, 512, 128), f32(4, 128),
+           bf(4, 128, 512))
+    kw = dict(act_name="silu", gated=True, block_m=16, block_i=256)
+    with pytest.MonkeyPatch.context() as mp:        # traced as on a TPU
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        jax.clear_caches()
+        try:
+            assert moe.expert_arm(cfg, 4) == "routed_kernel"
+            return {
+                "_paged_decode_step": _digest(
+                    lambda p, c, *a: eng._paged_decode_step(
+                        p, cfg, c, *a, pad_token=0),
+                    params, cache, i32(4), i32(4, 8), i32(4)),
+                "_prefill_chunk": _digest(
+                    lambda p, c, *a: eng._prefill_chunk(p, cfg, c, *a),
+                    params, cache, i32(1, 128), i32(8), i32(8), i32(),
+                    i32(), i32()),
+                "grouped_ffn_two_chunks_no_live": _digest(
+                    lambda x, gid, *w: exp.grouped_ffn(x, gid, *w, **kw),
+                    bf(64, 128), i32(4), *ffn),
+                # the one launch that did change, held to the parent's
+                # maps: the digest reads the maps (the test below)
+                "grouped_ffn_two_chunks_live_parents_maps": _digest(
+                    lambda x, gid, lv, *w: _parents_maps(exp)(
+                        x, gid, *w, lv, **kw),
+                    bf(64, 128), i32(4), i32(1), *ffn),
+                "grouped_ffn_ad_grad": _digest(
+                    lambda x, gid, *w: jax.grad(
+                        lambda x, *w: exp.grouped_ffn_ad(
+                            x, gid, *w, "silu", True, 16, 256, False)
+                        .astype(jnp.float32).sum(), argnums=(0, 1, 3, 5))(
+                            x, *w),
+                    bf(64, 128), i32(4), *ffn),
+                "grouped_ffn_tokens": _digest(
+                    lambda x, tok, gid, *w: exp.grouped_ffn_tokens(
+                        x, tok, gid, *w, **kw),
+                    bf(40, 128), i32(64), i32(4), *ffn),
+            }
+        finally:
+            jax.clear_caches()
+
+
+#: as the PARENT commit (2f68ddd) traces them
+PARENT_PROGRAMS = {
+    "_paged_decode_step":
+        "83e3655c7751517b313252a72b709553e737d2f14130ddcc9d119fa3f176ba15",
+    "_prefill_chunk":
+        "975b303f8e9f3bc4b50d19e27da82bf86dc30795efb924cb87e00e859c0f79e7",
+    "grouped_ffn_two_chunks_no_live":
+        "c46c604854d6641c06ef676b4cede487a4e20d9247a0fb8c7be416b44d5e9e55",
+    "grouped_ffn_two_chunks_live_parents_maps":
+        "e76dd1b2ad0c8b3eb25189ca10b5082a784ed28d2935ecf3b97a25bd06c7583b",
+    "grouped_ffn_ad_grad":
+        "3ba35053daca0033b3f447a9e22f7bd183b5d9debc49b64955cf8c36b1dfc2e7",
+    "grouped_ffn_tokens":
+        "d629091804b0592b5eae321d2d96433bb732045b6b9791d3df174f1b6a42f8d1",
+}
+
+
+@pytest.fixture(scope="module")
+def programs():
+    return single_chunk_digests()
+
+
+@pytest.mark.parametrize("program", sorted(PARENT_PROGRAMS))
+def test_single_chunk_launches_are_the_parents(programs, program):
+    """ISSUE 48 changed what a launch with ``live_tiles`` AND more than
+    one chunk fetches, and nothing else: these programs, index maps
+    included, are the parent's letter for letter."""
+    digest, text = programs[program]
+    assert digest == PARENT_PROGRAMS[program]
+    # (a jitted function met twice is printed once)
+    kernels = set(re.findall(r"name=(fm_\w+)", text))
+    assert kernels == {
+        "_paged_decode_step": {"fm_paged_decode", "fm_ffn_fwd"},
+        "_prefill_chunk": {"fm_ffn_fwd"},
+        "grouped_ffn_two_chunks_no_live": {"fm_ffn_fwd"},
+        "grouped_ffn_two_chunks_live_parents_maps": {"fm_ffn_fwd"},
+        "grouped_ffn_ad_grad": {"fm_ffn_fwd_res", "fm_gmm", "fm_tgmm"},
+        "grouped_ffn_tokens": {"fm_ffn_fwd_gather"}}[program]
+
+
+def test_the_digest_reads_the_index_maps(programs):
+    """The launch with ``live_tiles`` in two chunks, as this tree traces
+    it, is NOT the parent's (whose digest the same launch gives with the
+    dead tiles' chunk put back to ``j``): its printed jaxpr is, letter
+    for letter, and its maps are not."""
+    from flashmoe_tpu.ops import expert as exp
+
+    shape = jax.ShapeDtypeStruct
+    args = (shape((64, 128), jnp.bfloat16), shape((4,), jnp.int32),
+            shape((1,), jnp.int32),
+            shape((4, 128, 512), jnp.bfloat16), shape((4, 512), jnp.float32),
+            shape((4, 512, 128), jnp.bfloat16), shape((4, 128), jnp.float32),
+            shape((4, 128, 512), jnp.bfloat16))
+    launch = lambda x, gid, lv, *w: exp.grouped_ffn.__wrapped__(
+        x, gid, *w, lv, act_name="silu", gated=True, block_m=16, block_i=256)
+    digest, text = _digest(launch, *args)
+    parents, parents_text = programs[
+        "grouped_ffn_two_chunks_live_parents_maps"]
+    assert digest != parents
+    jaxpr = lambda t: t.split("{ lambda ; a:i32[] b:i32[]")[0]
+    assert jaxpr(text) == jaxpr(parents_text) != text
+
+
+if __name__ == "__main__":
+    for name, (digest, _) in single_chunk_digests().items():
+        print(f'    "{name}":\n        "{digest}",')

@@ -493,6 +493,77 @@ def test_prefill_records_say_which_arm_the_attention_took(monkeypatch,
     assert {(8, 8), (16, 16), (16, 32), (16, 48)} <= {a[:2] for a in asked}
 
 
+@pytest.mark.parametrize("chunks", [1, 4], ids=["one_chunk", "four_chunks"])
+def test_records_say_in_how_many_chunks_the_experts_walk(monkeypatch, chunks):
+    """``expert_chunks`` on every ``serve_decode`` and ``serve_prefill``
+    record is what ``ops/moe.expert_chunks`` answers for the program's
+    rows (``ops/expert._ffn_chunks`` on the shapes the launch is handed),
+    and ``serve.expert_chunked_programs`` counts a program that walks more
+    than one: ``None`` and 0 on the plain arms (the CPU's); with the
+    grouped kernel forced (in ``interpret``) 1 under the kernel's own
+    VMEM ceiling and 4 under one that holds a quarter of this toy's
+    expert, where whole prompts, chunks and decode steps, dead tiles
+    behind the live ones in every launch, serve the plain arm's tokens
+    (ISSUE 48)."""
+    import os
+
+    from flashmoe_tpu.ops import expert as exp
+    from flashmoe_tpu.ops import moe
+
+    cfg = tiny_config(vocab=247)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    reqs = [Request(rid=i, prompt=tuple(int(t) for t in jax.random.randint(
+        jax.random.PRNGKey(60 + i), (n,), 1, 247)), max_new_tokens=5)
+        for i, n in enumerate((5, 21, 9))]
+    serve = ServeConfig(max_batch=4, page_size=8, num_pages=32,
+                        max_pages_per_slot=4, ctx_bucket_pages=4,
+                        prompt_bucket=8, prefill_chunk=16)
+    programs = [eng._prefill_padded, *eng._INPLACE.values(),
+                moe._rows_kernel_ffn, moe._rows_kernel_waves,
+                exp.grouped_ffn]
+
+    def run():
+        for program in programs:
+            program.clear_cache()       # trace under the patches of now
+        recorder, metrics = FlightRecorder(), Metrics()
+        engine = ServingEngine(params, cfg, serve, recorder=recorder,
+                               metrics_obj=metrics)
+        out = engine.run(reqs, arrivals=[0, 0, 1])
+        seen = {kind: [r["expert_chunks"] for r in recorder.records
+                       if r["kind"] == kind]
+                for kind in ("serve_decode", "serve_prefill")}
+        return out, seen, metrics.counters
+
+    want, seen, counters = run()
+    assert set(seen["serve_decode"]) == set(seen["serve_prefill"]) == {None}
+    assert "serve.expert_chunked_programs" not in counters
+    try:
+        monkeypatch.setattr(moe, "routed_rows_form",
+                            lambda c: "routed_kernel")
+        if chunks > 1:
+            # a 16-row float32 tile and a QUARTER of an ungated expert of
+            # 64 x 128: the walk is four chunks of 32 columns
+            monkeypatch.setattr(exp, "_VMEM_CEILING",
+                                exp._ffn_vmem(16, 64, 32, False, 4, 4))
+        got, seen, counters = run()
+    finally:
+        monkeypatch.undo()
+        for program in programs:
+            program.clear_cache()
+    assert got == want
+    assert set(seen["serve_decode"]) == set(seen["serve_prefill"]) == {chunks}
+    assert len(seen["serve_prefill"]) >= 4       # a prompt in two chunks
+    launched = len(seen["serve_decode"]) + len(seen["serve_prefill"])
+    assert counters["serve.expert_kernel_programs"] == launched
+    assert counters.get("serve.expert_chunked_programs", 0) == (
+        launched if chunks > 1 else 0)
+    docs = os.path.join(os.path.dirname(__file__), "..", "docs")
+    for doc in ("OBSERVABILITY.md", "SERVING.md"):
+        text = open(os.path.join(docs, doc)).read()
+        assert "`expert_chunks`" in text
+        assert "`serve.expert_chunked_programs`" in text
+
+
 def _long_run(params, clock, mx, rec, new=40):
     serve = ServeConfig(max_batch=1, page_size=8, num_pages=32,
                         max_pages_per_slot=8, ctx_bucket_pages=8,

@@ -69,7 +69,11 @@ def test_shortcut_programs_fit_the_chip_with_the_pool_in_place(
     (a [64, 640] query block a slot against 512-row blocks of the slot's
     own pages), and every program runs its FOUR mixtures through
     ``fm_ffn_fwd``, one launch each inside the loop over the plan's
-    windows, no ``ragged_dot``; the plan is laid out for the rows the 16
+    windows, no ``ragged_dot`` (each launch walks the intermediate axis in
+    FOUR chunks of 512 columns, ``ops/moe.expert_chunks``: an expert's
+    three matrices double buffered are 151 MB against the kernel's 64 MiB
+    of VMEM; since ISSUE 48 its tiles past the live ones, about 11 of 19 in
+    a decode step and 30 of 47 in a chunk, fetch no weights); the plan is laid out for the rows the 16
     experts held could expect four times over (64 of a decode step's 768
     routed rows, 1024 of a chunk's 12288: ``ops/moe.rows_plan``), so the
     kernel's row buffer is 304 / 1504 rows where the whole S x K would be
@@ -80,9 +84,10 @@ def test_shortcut_programs_fit_the_chip_with_the_pool_in_place(
     ONE array for all 64), and hold no ``[64, 1024, .]`` scores."""
     compiled = shortcut_programs[program].compile()
     text = compiled.as_text()
-    lo, hi = {"decode": (12.2e9, 12.6e9), "chunk": (12.7e9, 13.0e9),
-              "prefill": (10.7e9, 11.2e9)}[program]
-    assert lo < program_bytes(compiled) < hi <= 14.5e9
+    size = {"decode": 12.40e9, "chunk": 12.93e9, "prefill": 10.93e9}[program]
+    # ISSUE 48: the launches whose dead tiles hold their chunk compile to
+    # the sizes the parent's did, to 0.05 GB
+    assert abs(program_bytes(compiled) - size) < 0.05e9 and size < 14.5e9
     assert "ragged-dot" not in text
     kernels = fm_kernels(text)
     assert [n for n in kernels if n == "fm_ffn_fwd"] == ["fm_ffn_fwd"] * 4
