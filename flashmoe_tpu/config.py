@@ -245,6 +245,21 @@ class MoEConfig:
     # ``_paged_denoise_step``).  A power of two; every layer a K/V layer.
     block_length: int = 0
     mask_token_id: int = 0
+    # scalar factors a published config states (the granitemoehybrid
+    # family's): the embedding's rows times ``embedding_multiplier`` as
+    # they enter the stream; every part joins it as ``x + residual_multiplier
+    # * part(norm(x))``; an "mha" layer's scores are ``q . k *
+    # attention_multiplier`` (None: ``head_dim ** -0.5``); the logits are
+    # divided by ``logits_scaling``.  ``tie_embeddings``: the head
+    # contracts over the embedding's own ``[V, H]`` array and the parameter
+    # tree has no ``lm_head``.  Each default writes NOTHING into a program
+    # (a Python branch where the factor would be applied, not a multiply
+    # by one).  The serving paths of one chip apply them (:attr:`rescaled`).
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float | None = None
+    logits_scaling: float = 1.0
+    tie_embeddings: bool = False
 
     # --- numerics ---
     dtype: Any = jnp.bfloat16
@@ -581,6 +596,28 @@ class MoEConfig:
                     "a 'kda' layer needs kda_heads, kda_head_dim >= 1, "
                     "kda_conv >= 2 and kda_lower_bound in [-5.5, 0), got "
                     f"{(self.kda_heads, self.kda_head_dim, self.kda_conv, self.kda_lower_bound)}")
+        if min(self.embedding_multiplier, self.residual_multiplier,
+               self.logits_scaling) <= 0 or (
+                self.attention_multiplier is not None
+                and self.attention_multiplier <= 0):
+            raise ValueError(
+                "embedding_multiplier, residual_multiplier, logits_scaling "
+                "and attention_multiplier (where given) must be > 0")
+        if self.attention_multiplier is not None and (
+                self.attention_kind != "mha"):
+            raise ValueError(
+                "attention_multiplier scales an 'mha' layer's scores: an "
+                "'mla' layer's scale is its own head widths'")
+        if self.rescaled and (self.is_training or max(
+                self.dp, self.ep, self.tp, self.sp, self.pp) > 1):
+            raise NotImplementedError(
+                "embedding_multiplier / residual_multiplier / "
+                "attention_multiplier / logits_scaling / tie_embeddings "
+                "under is_training or a mesh axis > 1: the trainer, the "
+                "pipeline stages and the mesh's parameter specs "
+                "(runtime/trainer.py, parallel/pipeline.py, "
+                "parallel/mesh.py) name an ``lm_head`` leaf and apply no "
+                "factor; a serving config of one chip runs them")
         if self.n_group < 1 or self.num_experts % self.n_group or not (
                 1 <= self.topk_group <= self.n_group):
             raise ValueError(
@@ -831,6 +868,15 @@ class MoEConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def rescaled(self) -> bool:
+        """Whether a scalar factor or the tied head is set: what the
+        training and mesh paths do not apply (``__post_init__`` refuses
+        them by name)."""
+        return (self.tie_embeddings or self.attention_multiplier is not None
+                or (self.embedding_multiplier, self.residual_multiplier,
+                    self.logits_scaling) != (1.0, 1.0, 1.0))
 
     @property
     def attn_block(self) -> int:

@@ -38,7 +38,9 @@ import jax
 import jax.numpy as jnp
 
 from flashmoe_tpu.config import FFN_PARTS, MoEConfig
-from flashmoe_tpu.models.transformer import rms_norm
+from flashmoe_tpu.models.transformer import (
+    embed_tokens, head_logits, join_stream, rms_norm,
+)
 from flashmoe_tpu.ops.attention import paged_attention
 from flashmoe_tpu.ops.moe import expert_arm, moe_layer
 from flashmoe_tpu.utils.telemetry import trace_span
@@ -120,7 +122,7 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
                           != "capacity")
         out = o.out.reshape(b, t, -1).astype(x.dtype)
         if onto is not None:
-            out = onto + out
+            out = join_stream(cfg, onto, out)
         if layer_cfg.num_experts > 1:
             touched.append(jnp.sum(o.expert_counts > 0))
         if layer_cfg.experts_held:
@@ -140,7 +142,7 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
                 pools, li, pos, write, block_tables, absorbed=absorbed,
                 valid=valid, slots=slots, fresh=fresh)
             rows.append(span)
-            x = x + a
+            x = join_stream(cfg, x, a)
         part, branch = FFN_PARTS[ffn]
         if part is None:
             continue
@@ -153,7 +155,7 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
                                    cfg.ffn_config(li, branch=True), True)
         elif branch == "join":
             with trace_span("moe.shortcut_join"):
-                x = x + carried
+                x = join_stream(cfg, x, carried)
     if cache is not None:
         cache = type(cache)(*pools)
     counted = {name: jnp.mean(jnp.stack(per_layer).astype(jnp.float32))
@@ -195,7 +197,7 @@ def prefill_forward(params, cfg: MoEConfig, prompt, cache):
     stay logits-equal on dropless configs (capacity configs compete for
     slots per call, so their drop pattern is step-count-dependent — use
     the loop arm there)."""
-    x = params["embed"].astype(cfg.dtype)[prompt]  # [B, T0, H]
+    x = embed_tokens(params, cfg, prompt)  # [B, T0, H]
     return _dense_span(params, cfg, x, cache, jnp.int32(0), absorbed=False)
 
 
@@ -203,11 +205,7 @@ def lm_logits(params, cfg: MoEConfig, h):
     """Final-norm + lm_head on [B, 1, H] hidden states -> [B, V] f32
     (the exact tail :func:`_decode_step` applies, shared so every
     consumer produces bit-identical logits from the same hidden)."""
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    return jnp.dot(
-        h.astype(cfg.dtype), params["lm_head"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )[:, 0]  # [B, V]
+    return head_logits(params, cfg, h)[:, 0]  # [B, V]
 
 
 def lm_logits_span(params, cfg: MoEConfig, h):
@@ -217,11 +215,7 @@ def lm_logits_span(params, cfg: MoEConfig, h):
     positions per slot in one forward and needs the lm head at every
     one of them; sharing the tail here keeps each column bit-identical
     to what :func:`lm_logits` produces from the same hidden row."""
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    return jnp.dot(
-        h.astype(cfg.dtype), params["lm_head"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )  # [B, T, V]
+    return head_logits(params, cfg, h)  # [B, T, V]
 
 
 def prefill_batched(params, cfg: MoEConfig, prompt, cache: KVCache):
@@ -238,7 +232,7 @@ def prefill_loop(params, cfg: MoEConfig, prompt, cache: KVCache):
 
     def body(i, carry):
         cache, _ = carry
-        x = params["embed"].astype(cfg.dtype)[prompt[:, i]][:, None, :]
+        x = embed_tokens(params, cfg, prompt[:, i])[:, None, :]
         logits, cache = _decode_step(params, cfg, x, cache, i)
         return cache, logits
 
@@ -374,12 +368,11 @@ def generate_blocks(params, prompt, cfg: MoEConfig, *,
     open_toks = jnp.full((b, nb * bl), cfg.mask_token_id, jnp.int32)
     open_toks = open_toks.at[:, :tail].set(prompt[:, t_pre:])
     n_reveal = jnp.full((b,), bl // s_steps, jnp.int32)
-    embed = params["embed"].astype(cfg.dtype)
 
     def forward(cache, toks, masked, pos):
         feed = jnp.where(masked, jnp.int32(cfg.mask_token_id), toks)
-        x, cache = _dense_span(params, cfg, embed[feed], cache, pos,
-                               absorbed=True)
+        x, cache = _dense_span(params, cfg, embed_tokens(params, cfg, feed),
+                               cache, pos, absorbed=True)
         return lm_logits_span(params, cfg, x), cache
 
     def block(cache, xs):
@@ -483,7 +476,7 @@ def generate(params, prompt, cfg: MoEConfig, *, max_new_tokens: int = 32,
         if stops is not None:
             tok = jnp.where(done, jnp.int32(pad_token), tok)
             done = done | jnp.isin(tok, stops)
-        x = params["embed"].astype(cfg.dtype)[tok][:, None, :]
+        x = embed_tokens(params, cfg, tok)[:, None, :]
         logits, cache = _decode_step(params, cfg, x, cache, t0 + i)
         return (cache, logits, key, done), tok
 

@@ -251,6 +251,40 @@ def sdar_30b_a3b(**overrides) -> MoEConfig:
     return MoEConfig(**base)
 
 
+def granite4_h_micro(**overrides) -> MoEConfig:
+    """granite-4.0-h-micro (3.19B; huggingface.co/ibm-granite/
+    granite-4.0-h-micro ``config.json``, ``model_type`` granitemoehybrid):
+    40 layers of width 2048, each a mixer AND a dense SwiGLU part of width
+    8192 (``shared_intermediate_size``; ``num_local_experts`` 0: no
+    mixture anywhere), every part joining the stream times
+    ``residual_multiplier`` 0.22.  ``layer_types``: attention at 5, 15, 25
+    and 35 (32 query heads over 8 K/V heads of width 64, NO positional
+    embedding, scores times ``attention_multiplier`` 1/64), a state-space
+    mixer elsewhere (Mamba-2: 64 heads of 64 over a float32 state of 128 a
+    channel, ONE group, a 4-tap convolution with a bias, chunks of 256).
+    The embedding's rows enter times ``embedding_multiplier`` 12, the
+    logits leave divided by ``logits_scaling`` 8 through a head TIED to
+    the embedding; RMSNorm eps 1e-5, vocabulary 100352."""
+    base = dict(
+        num_experts=1, expert_top_k=1, hidden_size=2048,
+        intermediate_size=8192, num_layers=40, vocab_size=100352,
+        num_heads=32, num_kv_heads=8, head_dim=64, use_rope=False,
+        ssm_heads=64, ssm_head_dim=64, ssm_groups=1, ssm_state=128,
+        ssm_conv=4, ssm_chunk=256, norm_eps=1e-5,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=0.015625, logits_scaling=8.0,
+        tie_embeddings=True, sequence_len=4096, gated_ffn=True,
+        hidden_act=Activation.SILU, drop_tokens=False, dtype=jnp.bfloat16,
+    )
+    base.update(overrides)
+    # the published layer_types: attention at 5, 15, 25, 35
+    base.setdefault("layer_mixers", tuple(
+        "mha" if li % 10 == 5 else "ssm"
+        for li in range(base["num_layers"])))
+    base.setdefault("layer_ffns", ("dense",) * base["num_layers"])
+    return MoEConfig(**base)
+
+
 PRESETS = {
     "mixtral-8x7b": mixtral_8x7b,
     "deepseek-moe-16b": deepseek_moe_16b,
@@ -262,4 +296,5 @@ PRESETS = {
     "nemotron-3-nano-30b-a3b": nemotron3_nano_30b_a3b,
     "longcat-flash": longcat_flash,
     "sdar-30b-a3b-chat": sdar_30b_a3b,
+    "granite-4.0-h-micro": granite4_h_micro,
 }

@@ -37,6 +37,7 @@ from flashmoe_tpu.ops.attention import rms_norm  # noqa: F401  (re-exported)
 from flashmoe_tpu.ops.attention import rope_halves as _rope  # noqa: F401
 from flashmoe_tpu.ops.moe import dense_ffn, moe_layer
 from flashmoe_tpu.parallel.ep import ep_moe_layer
+from flashmoe_tpu.utils.telemetry import trace_span
 
 
 # ----------------------------------------------------------------------
@@ -55,9 +56,10 @@ def init_params(key, cfg: MoEConfig) -> dict:
     params: dict[str, Any] = {
         "embed": dense(keys[0], (cfg.vocab_size, h), 1.0) * 0.02 * jnp.sqrt(1.0),
         "final_norm": jnp.ones((h,), cfg.param_dtype),
-        "lm_head": dense(keys[1], (h, cfg.vocab_size), h),
         "layers": [],
     }
+    if not cfg.tie_embeddings:      # tied: the head IS ``embed``, one leaf
+        params["lm_head"] = dense(keys[1], (h, cfg.vocab_size), h)
     for li, (mixer, ffn) in enumerate(cfg.layers):
         lk = jax.random.split(keys[2 + li], 6)
         # one norm for each part the layer has (``cfg.layers``)
@@ -144,6 +146,47 @@ def init_params(key, cfg: MoEConfig) -> dict:
 
 
 # ----------------------------------------------------------------------
+# Where the stream starts, grows and ends: the ONE place of each of the
+# config's scalar factors (``MoEConfig.embedding_multiplier`` ...)
+# ----------------------------------------------------------------------
+
+def embed_tokens(params, cfg: MoEConfig, tokens):
+    """Rows of the embedding, scaled: tokens [...] int32 -> [..., H] in
+    the activations' dtype, times ``cfg.embedding_multiplier``."""
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def join_stream(cfg: MoEConfig, x, part):
+    """A part's output joins the residual stream:
+    ``x + cfg.residual_multiplier * part``."""
+    if cfg.residual_multiplier != 1.0:
+        part = part * cfg.residual_multiplier
+    return x + part
+
+
+def head_logits(params, cfg: MoEConfig, x):
+    """The final norm and the head over hidden states [..., H] ->
+    float32 logits [..., V], divided by ``cfg.logits_scaling``.  A tied
+    head (``cfg.tie_embeddings``) contracts over the embedding's own
+    ``[V, H]`` array: no transposed copy of it anywhere."""
+    with trace_span("lm.head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(cfg.dtype)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum(
+                "...h,vh->...v", x, params["embed"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.dot(x, params["lm_head"].astype(cfg.dtype),
+                             preferred_element_type=jnp.float32)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+    return logits
+
+
+# ----------------------------------------------------------------------
 # Blocks
 # ----------------------------------------------------------------------
 
@@ -197,12 +240,13 @@ def attention(layer, x, cfg: MoEConfig, positions=None, mesh=None,
     qh, kh, vh = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
+    scale = cfg.attention_multiplier            # None: head_dim ** -0.5
     if mesh is not None and cfg.sp > 1:
-        ctx = ring_attention(qh, kh, vh, mesh, causal=True)
+        ctx = ring_attention(qh, kh, vh, mesh, causal=True, scale=scale)
     elif use_pallas and t % 128 == 0:
-        ctx = flash_attention(qh, kh, vh, causal=True)
+        ctx = flash_attention(qh, kh, vh, causal=True, scale=scale)
     else:
-        ctx = attention_xla(qh, kh, vh, causal=True)
+        ctx = attention_xla(qh, kh, vh, causal=True, scale=scale)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(b, t, nh * dh).astype(x.dtype)
     return ctx @ layer["wo"].astype(x.dtype)
 
@@ -293,21 +337,21 @@ def block(layer, x, cfg: MoEConfig, li: int, mesh=None, use_pallas=None,
     drills rebuild their step exactly to pick up new arming)."""
     mixer, ffn = cfg.layers[li]
     if mixer is not None:
-        x = x + attention(
+        x = join_stream(cfg, x, attention(
             layer, rms_norm(x, layer["attn_norm"], cfg.norm_eps), cfg,
-            mesh=mesh, use_pallas=use_pallas, li=li)
+            mesh=mesh, use_pallas=use_pallas, li=li))
     if ffn is None:
         return x, jnp.zeros((), cfg.accum_dtype), None, carried
     f_in = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
     f, moe_loss, moe_stats = _ffn(layer, f_in, cfg, li, mesh, use_pallas)
-    x = x + f
+    x = join_stream(cfg, x, f)
     branch = FFN_PARTS[ffn][1]
     if branch == "moe":
         carried, branch_loss, moe_stats = _ffn(
             layer, f_in, cfg, li, mesh, use_pallas, branch=True)
         moe_loss = moe_loss + branch_loss
     elif branch == "join":
-        x, carried = x + carried, None
+        x, carried = join_stream(cfg, x, carried), None
     return x, moe_loss, moe_stats, carried
 
 
@@ -320,7 +364,7 @@ def forward(params, tokens, cfg: MoEConfig, mesh=None, use_pallas=None):
     aux losses.  With ``cfg.collect_stats`` a third element is returned:
     a tuple of per-MoE-layer :class:`flashmoe_tpu.ops.stats.MoEStats`
     (flag off keeps the two-tuple contract every existing caller uses)."""
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    x = embed_tokens(params, cfg, tokens)
     total_aux = jnp.zeros((), cfg.accum_dtype)
     layer_stats = []
     # per-block remat keeps HBM bounded; excluded exactly for the blocks
@@ -348,11 +392,7 @@ def forward(params, tokens, cfg: MoEConfig, mesh=None, use_pallas=None):
         total_aux = total_aux + moe_loss
         if moe_stats is not None:
             layer_stats.append(moe_stats)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.dot(
-        x.astype(cfg.dtype), params["lm_head"].astype(cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    logits = head_logits(params, cfg, x)
     if cfg.collect_stats:
         return logits, total_aux, tuple(layer_stats)
     return logits, total_aux

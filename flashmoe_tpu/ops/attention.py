@@ -359,7 +359,8 @@ def kv_project(layer, x, cfg, positions):
     return q, k, v
 
 
-def kv_attend(layer, q, k_ctx, v_ctx, q_pos, block: int = 1):
+def kv_attend(layer, q, k_ctx, v_ctx, q_pos, block: int = 1,
+              scale: float | None = None):
     """Causal attention of T queries a row over a context of K/V rows.
 
     q: [B, T, N, D]; k_ctx / v_ctx: [B, N_kv, S, D], row s the K/V of
@@ -368,7 +369,9 @@ def kv_attend(layer, q, k_ctx, v_ctx, q_pos, block: int = 1):
     ``block`` (static, a power of two; ``MoEConfig.attn_block``): a query
     sees up to the END of its block of that many positions,
     ``s <= q_pos | (block - 1)``; 1 is the causal mask, letter for letter
-    (a span of a flash tile then starts at a whole block).
+    (a span of a flash tile then starts at a whole block).  ``scale``: of
+    the scores, ``D ** -0.5`` unless given (``MoEConfig.
+    attention_multiplier``).
     Blockwise through :func:`flash_span_attention` where
     :func:`span_attention_arm` says so (a long span on a TPU; a query
     head reads its K/V head, nothing is repeated), as float32 logits
@@ -376,9 +379,11 @@ def kv_attend(layer, q, k_ctx, v_ctx, q_pos, block: int = 1):
     against.  Returns the block's attention output [B, T, H]."""
     b, t, nh, dh = q.shape
     dt = q.dtype
+    if scale is None:
+        scale = dh ** -0.5
     if span_attention_arm(t, k_ctx.shape[2], nh, (dh,), dh, dt) == "flash":
         ctx = _flash_span_ctx((q,), (k_ctx.astype(dt),), v_ctx.astype(dt),
-                              q_pos, dh ** -0.5, block)
+                              q_pos, scale, block)
         return ctx @ layer["wo"].astype(dt)
     if block > 1:
         q_pos = q_pos | (block - 1)
@@ -388,7 +393,7 @@ def kv_attend(layer, q, k_ctx, v_ctx, q_pos, block: int = 1):
         v_ctx = jnp.repeat(v_ctx, rep, axis=1)
     logits = jnp.einsum(
         "bntd,bnsd->bnts", q.transpose(0, 2, 1, 3), k_ctx,
-        preferred_element_type=jnp.float32) * (dh ** -0.5)
+        preferred_element_type=jnp.float32) * scale
     mask = (jnp.arange(k_ctx.shape[2])[None, None, None, :]
             <= q_pos[:, None, :, None])
     probs = jax.nn.softmax(jnp.where(mask, logits, NEG_INF),
@@ -467,8 +472,9 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
     q, k, v = kv_project(layer, x, cfg, pos)
     span = (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
     block = cfg.attn_block
+    scale = cfg.attention_multiplier            # None: head_dim ** -0.5
     if pools is None:
-        return kv_attend(layer, q, *span, pos, block), pools, span
+        return kv_attend(layer, q, *span, pos, block, scale), pools, span
     rows, page, width = pools[0].shape[2:]       # the heads as stored
     # (under a block mask the kernel knows a span that is ONE block: a
     # longer span over a cache whose page it fits, generate()'s prefill
@@ -478,13 +484,15 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
                                  pools[0].dtype) == "paged_kernel"):
         ctx, pools = paged_decode_attention(
             q, (k, v), pools, li, block_tables, pos[:, 0], write,
-            block=block, interpret=jax.default_backend() != "tpu")
+            scale=scale, block=block,
+            interpret=jax.default_backend() != "tpu")
         return ctx @ layer["wo"].astype(q.dtype), pools, span
     pools = (store_kv(pools[0], li, k, *write),
              store_kv(pools[1], li, v, *write))
     k_ctx = gather_ctx(pools[0][li], block_tables, q.shape[-1])
     v_ctx = gather_ctx(pools[1][li], block_tables, q.shape[-1])
-    return kv_attend(layer, q, k_ctx, v_ctx, pos, block), pools, span
+    return (kv_attend(layer, q, k_ctx, v_ctx, pos, block, scale), pools,
+            span)
 
 
 #: the mixers of ``config.STATE_MIXERS``: each takes ``(layer, x, cfg,

@@ -573,7 +573,8 @@ def moe_layer(params, x, cfg: MoEConfig, *, use_pallas: bool | None = None,
     s, h = x.shape
     zero = jnp.zeros((), cfg.accum_dtype)
     if cfg.num_experts == 1:
-        out = dense_ffn(params, x, cfg)
+        with trace_span("ffn.dense"):
+            out = dense_ffn(params, x, cfg)
         return MoEOutput(out, zero, zero, jnp.full((1,), s, jnp.int32))
     return _moe_layer_impl(params, x, cfg, use_pallas, capacity, interpret,
                            routed_rows)

@@ -72,6 +72,7 @@ from flashmoe_tpu.config import STATE_MIXERS, MoEConfig
 from flashmoe_tpu.models.generate import (
     REVEAL_RULES, lm_logits, lm_logits_span, reveal_rows, span_forward,
 )
+from flashmoe_tpu.models.transformer import embed_tokens
 from flashmoe_tpu.ops import attention
 from flashmoe_tpu.ops.moe import expert_arm, expert_chunks
 from flashmoe_tpu.serving.kvcache import (
@@ -334,7 +335,7 @@ def _prefill_padded(params, cfg: MoEConfig, prompt_padded, true_len):
     t_pad = prompt_padded.shape[1]
     positions = jnp.arange(t_pad, dtype=jnp.int32)[None, :]
     x, _, runs, _ = span_forward(
-        params, cfg, params["embed"].astype(cfg.dtype)[prompt_padded],
+        params, cfg, embed_tokens(params, cfg, prompt_padded),
         None, positions, None, None, absorbed=False,
         valid=positions < true_len if cfg.state_layers else None)
     h = jax.lax.dynamic_slice(
@@ -373,7 +374,7 @@ def _prefill_chunk(params, cfg: MoEConfig, pools, chunk_toks,
         slots=jnp.asarray(slot, jnp.int32)[None],
         fresh=start_pos == 0) if cfg.state_layers else {}
     x, pools, _, _ = span_forward(
-        params, cfg, params["embed"].astype(cfg.dtype)[chunk_toks], pools,
+        params, cfg, embed_tokens(params, cfg, chunk_toks), pools,
         positions[None, :], (chunk_page_ids[None, :], None),  # whole pages
         block_table[None, :], absorbed=False, **state)
     h = jax.lax.dynamic_slice(x, (0, rel_last, 0), (1, 1, x.shape[-1]))
@@ -414,7 +415,7 @@ def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
     live = (jnp.broadcast_to(owns, pos.shape) if cfg.state_layers else None)
     # a short span over a long context: MLA's absorbed form
     x, pools, _, counted = span_forward(
-        params, cfg, params["embed"].astype(cfg.dtype)[toks], pools, pos,
+        params, cfg, embed_tokens(params, cfg, toks), pools, pos,
         (page_ids, rows), block_tables, absorbed=True, mixture=mixture,
         valid=live)
     return x, pools, counted
@@ -740,6 +741,12 @@ class ServingEngine:
         None (the default) makes zero calls — byte-identical."""
         mla = cfg.attention_kind == "mla"
         sv = serve if serve is not None else ServeConfig()
+        if cfg.rescaled and sv.ep_shards > 1:
+            raise NotImplementedError(
+                "a config with scalar factors or a tied head "
+                "(MoEConfig.rescaled) with ep_shards > 1: no test holds "
+                "_ep_decode_fn's sharded twin to them; one chip's engine "
+                "runs them")
         if cfg.state_layers:
             # what cannot keep a slot's state correct, whatever the
             # mixer that keeps it
@@ -1448,11 +1455,19 @@ class ServingEngine:
                       starved: bool) -> None:
         """One prefill program's account: ``tokens`` of a prompt in
         ``rows`` computed rows over a context of ``ctx_rows``, fed from
-        ``fed`` (engine's clock, profiler's clock) to now."""
+        ``fed`` (engine's clock, profiler's clock) to now.  A chunk GATHERS
+        its context from the pool (``ctx_pages``: its block table,
+        bucketed); a whole prompt's context is the span itself (0)."""
         done = self._prefills
         done[0] += 1
         done[1] += tokens
         done[2] += rows
+        ctx_pages = (ctx_rows // self.serve.page_size if form == "chunk"
+                     else 0)
+        if ctx_pages:
+            # for a scrape of an engine that has no recorder (over
+            # ``serve.prefill_programs``: does ``ctx_bucket_pages`` fit?)
+            self.metrics.count("serve.prefill_ctx_pages", ctx_pages)
         arm, chunks = self._expert_arm(rows)
         # the arm the program's attention layers took over their context
         # (``ops/attention.span_attention_arm``: the rule the traced
@@ -1468,7 +1483,7 @@ class ServingEngine:
             self.recorder.record(
                 kind="serve_prefill", step=self.step_idx, rid=rid,
                 slot=slot, form=form, pos=pos, tokens=tokens, rows=rows,
-                pad_rows=rows - tokens, expert_arm=arm,
+                pad_rows=rows - tokens, ctx_pages=ctx_pages, expert_arm=arm,
                 expert_chunks=chunks, attn_arm=attn_arm,
                 host_ms=round((self._clock() - fed[0]) * 1e3, 3),
                 starved=starved, t0_trace_ns=fed[1])

@@ -371,6 +371,8 @@ STRUCTURAL_FIELDS = frozenset({
     "ssm_chunk",
     "expert_first", "experts_held", "intermediate_pad",
     "zero_experts", "mla_rank_scale", "block_length", "mask_token_id",
+    "embedding_multiplier", "residual_multiplier", "attention_multiplier",
+    "logits_scaling", "tie_embeddings",
     "dtype", "param_dtype", "accum_dtype",
     "dp", "ep", "tp", "sp", "pp",
 })

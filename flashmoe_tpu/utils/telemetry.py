@@ -259,6 +259,12 @@ SPAN_NAMES: dict[str, str] = {
     "attn.ssm_decode":
         "state-space mixer, one recurrence step over every slot's float32 "
         "state, read once and written once (decode)",
+    "ffn.dense":
+        "a dense feed-forward part (one expert of the dense width, no "
+        "router): a layer's own, or a mixture model's leading layers'",
+    "lm.head":
+        "the final norm and the head's product over the vocabulary (a "
+        "tied head over the embedding's own rows), the logits' divisor",
     "moe.route_groups":
         "group-limited routing: the groups' scores and the mask of the "
         "experts outside the kept groups",

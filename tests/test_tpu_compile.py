@@ -19,6 +19,8 @@ of few cases starts last in the gate's queue and must be cheap):
 - ``test_tpu_compile_nemotron3_nano.py``: ``nemotron3_nano`` (``-k ssm``)
 - ``test_tpu_compile_longcat_flash_omni.py``: ``longcat_flash_omni``
   (``-k shortcut``)
+- ``test_tpu_compile_granite4_h_micro.py``: ``granite4_h_micro``
+  (``-k granite``)
 - ``test_tpu_compile_sampler.py``: the sampler at both vocabularies
 - ``test_tpu_compile_layers.py``: kernels, layers, four chips, train step
 
