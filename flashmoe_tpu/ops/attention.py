@@ -429,17 +429,19 @@ def store_kv(pages, li: int, span_kv, page_ids, rows):
         pages[li].at[page_ids, :, rows, :].set(span_kv))
 
 
-def gather_ctx(pages, block_tables, d: int | None = None):
-    """Gather each slot's context window from its pages.
+def gather_ctx(pool, li: int, block_tables, d: int | None = None):
+    """Each slot's context window from layer ``li`` of a K (or V) pool.
 
-    pages: ``[P, N_kv, page, D]`` (one layer's pool); block_tables:
-    ``[B, n]`` page ids (already sliced to the bucketed page count).
+    pool: ``[L, P, N_kv, page, D]``; block_tables: ``[B, n]`` page ids
+    (already sliced to the bucketed page count).  Layer and pages are
+    indexed in ONE gather, as :func:`gather_latent` does: ``pool[li]`` as
+    a value of its own is a copy of the layer's pool on the chip.
     Returns ``[B, N_kv, n * page, D]`` — rows past a request's length are
     scratch/garbage and MUST be masked by the caller's length mask.  ``d``:
-    the heads' width where the pool packs several to a row (pages
-    ``[P, N_kv / p, page, p * d]``); the heads come back apart."""
+    the heads' width where the pool packs several to a row (pool
+    ``[L, P, N_kv / p, page, p * d]``); the heads come back apart."""
     b, n = block_tables.shape
-    g = pages[block_tables]                    # [B, n, N_kv, page, D]
+    g = pool[li, block_tables]                 # [B, n, N_kv, page, D]
     _, _, rows, page, width = g.shape
     ctx = g.transpose(0, 2, 1, 3, 4).reshape(b, rows, n * page, width)
     if d in (None, width):
@@ -455,7 +457,8 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
     context.  Two arms by what the shapes and the backend say
     (:func:`kv_attention_arm`): a short span over a paged pool on a TPU
     reads each slot's own pages in place (:func:`paged_decode_attention`);
-    everything else stores, gathers the context and attends through
+    everything else stores, gathers the context (layer and pages in one
+    gather of the whole pool) and attends through
     :func:`kv_attend` (:func:`store_kv`, :func:`gather_ctx`; in plain
     XLA, the form the kernel is held against, or for a long span on a
     TPU blockwise: :func:`span_attention_arm`).  Under
@@ -489,8 +492,8 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
         return ctx @ layer["wo"].astype(q.dtype), pools, span
     pools = (store_kv(pools[0], li, k, *write),
              store_kv(pools[1], li, v, *write))
-    k_ctx = gather_ctx(pools[0][li], block_tables, q.shape[-1])
-    v_ctx = gather_ctx(pools[1][li], block_tables, q.shape[-1])
+    k_ctx = gather_ctx(pools[0], li, block_tables, q.shape[-1])
+    v_ctx = gather_ctx(pools[1], li, block_tables, q.shape[-1])
     return (kv_attend(layer, q, k_ctx, v_ctx, pos, block, scale), pools,
             span)
 

@@ -202,7 +202,8 @@ def store_state(state, final, slot):
 
 # ----------------------------------------------------------------------
 # Whole-run page write (the engine's admission; the per-span store and the
-# gather of the jitted steps are ops/attention.py's store_kv / gather_ctx)
+# gather of the jitted steps are ops/attention.py's store_kv / gather_ctx,
+# which index the whole pool by layer AND pages: no layer of it as a value)
 # ----------------------------------------------------------------------
 
 def store_prefill(pages, seq_kv, page_ids):

@@ -48,6 +48,21 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+class Programs(dict):
+    """A configuration's lowered programs by name.  ``compiled(name)``
+    hands the chip's compiler a program ONCE, for every case that reads
+    it (``--dist loadfile`` keeps a file's cases on one worker)."""
+
+    def __init__(self, lowered):
+        super().__init__(lowered)
+        self._compiled = {}
+
+    def compiled(self, name):
+        if name not in self._compiled:
+            self._compiled[name] = self[name].compile()
+        return self._compiled[name]
+
+
 #: a whole latent pool of either MLA cell, as the programs hold it (the
 #: kernel's operand has a unit axis of heads) and in any layout
 LATENT_POOL = r"bf16\[(?:5,16384|1,40960),(?:1,)?16,640\]"
@@ -89,6 +104,24 @@ def no_stacked_gate_up(text, e, h, i):
 
 def latent_pool_copies(text):
     return re.findall(rf"^.*= {LATENT_POOL}\S* copy\(.*$", text, re.M)
+
+
+def layer_of_pool(compiled, layers, pages, rows, page, width):
+    """What a compiled K/V program holds of a pool ``[layers, pages, rows,
+    page, width]`` besides the pool: (the arrays shaped as ONE layer of
+    it, the copies of all of it, how many gathers take whole pages by
+    layer AND page id).  ``pool[li]`` as a value is such an array on the
+    chip, a ``slice_bitcast_fusion`` that writes a layer's pool out before
+    a gather reads a few pages of it (ISSUE 50); ``pool[li, tables]`` is
+    one gather of the aliased parameter."""
+    text = compiled.as_text()
+    pool = rf"bf16\[{layers},{pages},{rows},{page},{width}\]"
+    assert re.search(pool, text)
+    return (arrays_of(text, pages, rows, page, width),
+            re.findall(rf"^.*= {pool}\S* copy\(.*$", text, re.M),
+            len(re.findall(
+                r" gather\(.*collapsed_slice_dims=\{0,1\}.*slice_sizes="
+                rf"\{{1,1,{rows},{page},{width}\}}", text)))
 
 
 def program_bytes(compiled):

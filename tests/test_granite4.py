@@ -404,24 +404,30 @@ OLDER = {
 #: decode (a denoise step where it generates by blocks), chunk and
 #: whole-prompt programs and its ``forward``, traced as on the CPU and as
 #: on a TPU, as the PARENT commit (1144894) traces them: every new field's
-#: default writes nothing into them
+#: default writes nothing into them.  ISSUE 50 re-pinned the five K/V
+#: presets (the three MLA presets' pins are PR 49's): their chunk programs,
+#: and at these toy widths their gather-arm decode programs, read one
+#: ``gather`` of the whole pool by (layer, page) where they read a ``slice``
+#: + ``squeeze`` of the layer and a gather by page; the primitive counts of
+#: parent and change differ by that and nothing else, the whole-prompt
+#: programs and ``forward`` are the parent's letter for letter
 PARENT_JAXPRS = {
     "deepseek-moe-16b":
-        "5d6d6bc28f352c8fcbf423d50c9792dd01fbb97fda3c7b7320d2e7792fabdabb",
+        "d275ffd6ce11ab2405f6a32bf4ab47ab99c4b9327ba669eeff68e8bb197a6269",
     "flashmoe-reference":
-        "7bacf7f7ab746a7e3fa319aec8f7eb8df82844f6859990472d055e4c5f644eb3",
+        "be6547708caaa9d98e25915bf87a1ca10e7b84e97075d5f9c156da9c20df6570",
     "joyai-llm-flash":
         "01cd895da1c70c00d329d0c475fc24fe0864a65b85ed8814760cdcb5464ee845",
     "lfm2-24b-a2b":
-        "9b23a716c06020e5cf07ab9cc39bd8189ab224bacda79aed0fa8129a5bdf25f3",
+        "192c11b5a688fbc91c241f2804c9238a6fab8bad1848e83d9cf45b5994a313fa",
     "ling-3.0-flash":
         "1a86865cabe1fa61120497c340a552f1291119e663e222dd173026d16256933d",
     "longcat-flash":
         "e1196f6470ad4c464ca9285284574a031edbea9dfdf90e51a4f7d842d001813a",
     "nemotron-3-nano-30b-a3b":
-        "068881c664180a2d49a6842d047971f6379c17d56eb6e95316525e9bb6680171",
+        "503b68b5d6f979cb12c17e75b0d7c9ebce333dbd553d91f35f418164827b8e8e",
     "sdar-30b-a3b-chat":
-        "e40c0acf120a9dabfefa9900a367e917cb62e513459218c7bf1bd5d627777276",
+        "a1a2514041198806c20786dc90032a5c04eabac9658cc808f09abecc8b0c8b0b",
 }
 
 

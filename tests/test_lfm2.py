@@ -245,7 +245,7 @@ def test_narrow_heads_lie_two_to_a_lane_row():
     ids = jnp.asarray([[1, 1, 1, 2, 2], [3, 3, 3, 3, 3]])
     rows = jnp.asarray([[5, 6, 7, 0, 1], [0, 1, 2, 3, 4]])
     pages = attention.store_kv(cache.k_pages, 1, k, ids, rows)
-    ctx = attention.gather_ctx(pages[1], jnp.asarray([[1, 2], [3, 0]]), 64)
+    ctx = attention.gather_ctx(pages, 1, jnp.asarray([[1, 2], [3, 0]]), 64)
     assert ctx.shape == (2, 2, 16, 64)
     np.testing.assert_array_equal(np.asarray(ctx[0, :, 5:10]),
                                   np.asarray(k[0].transpose(1, 0, 2)))
@@ -254,7 +254,7 @@ def test_narrow_heads_lie_two_to_a_lane_row():
     from flashmoe_tpu.serving.kvcache import store_prefill
     run = jax.random.normal(jax.random.PRNGKey(2), (2, 2, 16, 64))
     pages = store_prefill(cache.k_pages, run, jnp.asarray([4, 2]))
-    ctx = attention.gather_ctx(pages[0], jnp.asarray([[4, 2]]), 64)
+    ctx = attention.gather_ctx(pages, 0, jnp.asarray([[4, 2]]), 64)
     np.testing.assert_array_equal(np.asarray(ctx[0]), np.asarray(run[0]))
     # the kernel's arm, handed rows of whole lanes
     key = jax.random.PRNGKey(4)
@@ -272,8 +272,8 @@ def test_narrow_heads_lie_two_to_a_lane_row():
                        for p, x in zip(pools, kv))
     layer = {"wo": jnp.eye(256)}
     want = attention.kv_attend(
-        layer, q, attention.gather_ctx(want_pools[0][1], tables, 64),
-        attention.gather_ctx(want_pools[1][1], tables, 64), pos[:, None])
+        layer, q, attention.gather_ctx(want_pools[0], 1, tables, 64),
+        attention.gather_ctx(want_pools[1], 1, tables, 64), pos[:, None])
     _close(out, want, 1e-5)
     for a, b in zip(new, want_pools):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

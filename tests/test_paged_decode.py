@@ -110,9 +110,38 @@ def _plain(q, span, pools, tables, write, span_pos):
     pools = tuple(attention.store_kv(pool, LI, rows, *write)
                   for pool, rows in zip(pools, span))
     out = attention.kv_attend(
-        layer, q, attention.gather_ctx(pools[0][LI], tables),
-        attention.gather_ctx(pools[1][LI], tables), span_pos)
+        layer, q, attention.gather_ctx(pools[0], LI, tables),
+        attention.gather_ctx(pools[1], LI, tables), span_pos)
     return out, pools
+
+
+@pytest.mark.parametrize("d", [None, 64], ids=["plain", "packed"])
+@pytest.mark.parametrize("li", [0, 1, 2])
+def test_gather_ctx_is_the_layers_pages_laid_out_by_hand(li, d):
+    """``gather_ctx(pool, li, tables, d)`` indexes layer and pages in ONE
+    gather of the whole pool (no layer of it as a value: on the chip that
+    is a copy of the layer's pool, ISSUE 50) and gives, bit for bit, what
+    ``pool[li][tables]`` laid out by hand gives: a slot's pages in its
+    table's order, row ``i * page + r`` of head h the row r of page
+    ``tables[b, i]``; a head packed two to a row (``d`` 64 in a width of
+    128) comes back as heads 2j and 2j + 1.  A page met twice and the
+    scratch page read like any other."""
+    rng = np.random.default_rng(50)
+    pool = jnp.asarray(rng.normal(size=(3, 7, 2, 4, 128)), jnp.bfloat16)
+    tables = np.array([[3, 5, 3], [6, SCRATCH_PAGE, 1]], np.int32)
+    got = attention.gather_ctx(pool, li, jnp.asarray(tables), d)
+    pages = np.asarray(pool.astype(jnp.float32))[li][tables]  # [B, n, 2, 4, W]
+    w = d or 128                                   # a head's width
+    p = 128 // w                                   # heads to a row
+    want = np.empty((2, 2 * p, 3 * 4, w), np.float32)
+    for b, i, j, r, h in np.ndindex(2, 3, 2, 4, p):
+        want[b, j * p + h, i * 4 + r] = pages[b, i, j, r, h * w:(h + 1) * w]
+    assert got.dtype == pool.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)), want)
+    # the program holds the pool and the gathered pages, no layer of it
+    text = str(jax.make_jaxpr(
+        lambda pool, t: attention.gather_ctx(pool, li, t, d))(pool, tables))
+    assert "[7,2,4,128]" not in text and text.count("gather") == 1
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],

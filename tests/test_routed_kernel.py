@@ -535,12 +535,14 @@ def single_chunk_digests():
             jax.clear_caches()
 
 
-#: as the PARENT commit (2f68ddd) traces them
+#: as the PARENT commit (2f68ddd) traces them (``_prefill_chunk`` re-pinned
+#: by ISSUE 50: its context gathers index the whole K/V pool by (layer,
+#: page); every launch and index map in it is the parent's)
 PARENT_PROGRAMS = {
     "_paged_decode_step":
         "83e3655c7751517b313252a72b709553e737d2f14130ddcc9d119fa3f176ba15",
     "_prefill_chunk":
-        "975b303f8e9f3bc4b50d19e27da82bf86dc30795efb924cb87e00e859c0f79e7",
+        "b57b2390baa5e100ab7b34345e67515df67a0de2175ecccb3253c402a60e6680",
     "grouped_ffn_two_chunks_no_live":
         "c46c604854d6641c06ef676b4cede487a4e20d9247a0fb8c7be416b44d5e9e55",
     "grouped_ffn_two_chunks_live_parents_maps":

@@ -231,7 +231,7 @@ def test_paged_decode_kernel_under_the_block_mask_is_the_gather_arm():
         q, (k, v), pools, 0, tables, pos, write, block=4, interpret=True)
     want_pools = tuple(attention.store_kv(p, 0, x, *write)
                        for p, x in zip(pools, (k, v)))
-    ctx = [attention.gather_ctx(p[0], tables) for p in want_pools]
+    ctx = [attention.gather_ctx(p, 0, tables) for p in want_pools]
     want = attention.kv_attend({"wo": jnp.eye(nh * d)}, q, *ctx, at, 4)
     assert np.abs(np.asarray(out) - np.asarray(want)).max() < 2e-5
     for got, exp in zip(new, want_pools):
@@ -402,13 +402,15 @@ def test_submit_refuses_a_temperature_and_steps_that_do_not_divide(params):
 #: K/V config WITHOUT a block length, traced as on a TPU (the kernels'
 #: arms), as the PARENT commit (87e5287) traces it: the block mask is
 #: carried as a static 1 that writes nothing into these programs
+#: (``_prefill_chunk`` re-pinned by ISSUE 50: its context gathers index
+#: the whole pool by (layer, page), no ``slice`` + ``squeeze`` of a layer)
 PARENT_JAXPRS = {
     "_paged_decode_step":
         "307bbcdc5e757d2f29efa30611c8d02aa718c914397933d1e2043320fe657bd1",
     "_paged_verify_step":
         "ba25ecb7fd89b8348099afdf440e761f54b0d26eb03e01eab90c0e84d56de712",
     "_prefill_chunk":
-        "346ed6d350628ed3b99795d36e14a8168ae329be7c8d56797451bc162875ab11",
+        "a9de06838d90a26b8bba7fdbffcfaaf0daa582421c90f30d6ab8ea5e1a74f138",
     "flash_attention":
         "a92144ac8726bb2a89d75cde589d7acadb613f928e404d4e124763867e1d790e",
     "flash_span":

@@ -259,7 +259,7 @@ def test_paged_store_gather_roundtrip():
     kp = store_kv(kp, 0, tok[:, None], jnp.asarray([[6]]),
                   jnp.asarray([[0]]))
     bt = jnp.asarray([[3, 5, 6]], jnp.int32)        # this slot's table
-    got = gather_ctx(kp[0], bt)                     # [1, nkv, 12, dh]
+    got = gather_ctx(kp, 0, bt)                     # [1, nkv, 12, dh]
     np.testing.assert_array_equal(np.asarray(got[0, :, :8]),
                                   np.asarray(seq[0]))
     np.testing.assert_array_equal(np.asarray(got[0, :, 8]),

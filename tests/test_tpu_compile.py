@@ -43,7 +43,8 @@ import numpy as np
 import pytest
 
 from _compiled import (  # noqa: F401
-    arrays_of, fm_kernels, one_chip, program_bytes, score_arrays, topo,
+    Programs, arrays_of, fm_kernels, layer_of_pool, one_chip, program_bytes,
+    score_arrays, topo,
 )
 
 
@@ -74,13 +75,13 @@ def sdar_programs(one_chip):
     i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32, sharding=one_chip)
     with pytest.MonkeyPatch.context() as mp:        # traced as on a TPU
         mp.setattr(jax, "default_backend", lambda: "tpu")
-        return {
+        return Programs({
             "denoise": eng._INPLACE["_paged_denoise_step"].lower(
                 params, cfg, cache, i32(64, 3, 4), i32(64, 8), i32(64, 160),
                 pad_token=0),
             "chunk": eng._INPLACE["_prefill_chunk"].lower(
                 params, cfg, cache, i32(1, 1024), i32(160), i32(64), i32(),
-                i32(), i32())}
+                i32(), i32())})
 
 
 @pytest.mark.parametrize("program", ["denoise", "chunk"])
@@ -94,7 +95,7 @@ def test_sdar_programs_fit_the_chip_with_the_pool_in_place(
     routed rows of its 256-row span through ``fm_ffn_fwd``, a launch a
     layer; the chunk scores its context blockwise (``fm_flash_span`` with
     the block-causal diagonal)."""
-    compiled = sdar_programs[program].compile()
+    compiled = sdar_programs.compiled(program)
     text = compiled.as_text()
     total = program_bytes(compiled)
     print(program, total / 1e9)
@@ -117,3 +118,14 @@ def test_sdar_programs_fit_the_chip_with_the_pool_in_place(
     else:
         assert kernels == ["fm_flash_span"] * 7
         assert score_arrays(text, 32, 1024, 2560) == []
+
+
+def test_sdar_chunk_gathers_its_context_from_the_pool_where_it_lies(
+        sdar_programs):
+    """ISSUE 50: the chunk's fourteen context gathers (K and V of seven
+    layers, 160 pages) index layer AND pages of the 5-D pool.  NO array
+    of one layer's pool (``bf16[8192,4,16,128]``, 134 MB) exists in the
+    program: with ``gather_ctx(pools[.][li], ...)`` there were fourteen,
+    a ``slice_bitcast_fusion`` each."""
+    compiled = sdar_programs.compiled("chunk")
+    assert layer_of_pool(compiled, 7, 8192, 4, 16, 128) == ([], [], 14)

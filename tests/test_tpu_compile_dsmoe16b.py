@@ -14,8 +14,8 @@ import numpy as np
 import pytest
 
 from _compiled import (  # noqa: F401
-    arrays_of, fm_kernels, no_stacked_gate_up, one_chip, program_bytes,
-    score_arrays, topo,
+    Programs, arrays_of, fm_kernels, layer_of_pool, no_stacked_gate_up,
+    one_chip, program_bytes, score_arrays, topo,
 )
 
 
@@ -44,7 +44,7 @@ def backlog_programs(one_chip):
     i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32, sharding=one_chip)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
-        return {
+        return Programs({
             "decode": eng._INPLACE["_paged_decode_step"].lower(
                 params, cfg, cache, i32(32), i32(32, 160), i32(32)),
             "verify": eng._INPLACE["_paged_verify_step"].lower(
@@ -53,7 +53,7 @@ def backlog_programs(one_chip):
                 params, cfg, cache, i32(1, 1024), i32(160), i32(64), i32(),
                 i32()),
             "prefill": eng._prefill_padded.lower(
-                params, cfg, i32(1, 2048), i32())}
+                params, cfg, i32(1, 2048), i32())})
 
 
 @pytest.mark.parametrize("program", ["decode", "verify", "chunk"])
@@ -70,7 +70,7 @@ def test_backlog_decode_step_is_the_program_the_ledger_measured(
     over its one slot, the gathered context scored blockwise since ISSUE
     44 (``fm_flash_span``, one call a layer, no ``[16, 1024, 2560]``
     scores)."""
-    compiled = backlog_programs[program].compile()
+    compiled = backlog_programs.compiled(program)
     text = compiled.as_text()
     assert compiled.memory_analysis().alias_size_in_bytes \
         >= 2 * 6 * 2048 * 16 * 16 * 128 * 2                  # 1.61 GB
@@ -111,7 +111,7 @@ def test_backlog_whole_prompt_prefill_fits_beside_the_pool(
     33), which leaves
     the engine's pool its 1.61 GB; the program holds no pool and hands
     back one K and one V run for ``store_prefill``."""
-    compiled = backlog_programs["prefill"].compile()
+    compiled = backlog_programs.compiled("prefill")
     assert abs(program_bytes(compiled) / 8.3298e9 - 1) < 0.01
     text = compiled.as_text()
     assert [n for n in fm_kernels(text)
@@ -121,3 +121,15 @@ def test_backlog_whole_prompt_prefill_fits_beside_the_pool(
     assert logits.shape == (102400,) and logits.dtype == jnp.float32
     assert k_run.shape == v_run.shape == (6, 16, 2048, 128)
     assert "[6,2048,16,16,128]" not in compiled.as_text()
+
+
+def test_backlog_chunk_gathers_its_context_from_the_pool_where_it_lies(
+        backlog_programs):
+    """ISSUE 50: the chunk's twelve context gathers (K and V of six
+    layers, 160 pages) index layer AND pages of the 5-D pool.  NO array
+    of one layer's pool (``bf16[2048,16,16,128]``, 134 MB) exists in the
+    program: with ``gather_ctx(pools[.][li], ...)`` there were twelve.
+    (The cell's engine sets no ``prefill_chunk``; a deployment that does
+    runs this program.)"""
+    compiled = backlog_programs.compiled("chunk")
+    assert layer_of_pool(compiled, 6, 2048, 16, 16, 128) == ([], [], 12)

@@ -15,8 +15,8 @@ import numpy as np
 import pytest
 
 from _compiled import (  # noqa: F401
-    arrays_of, fm_kernels, no_stacked_gate_up, one_chip, program_bytes,
-    score_arrays, topo,
+    Programs, arrays_of, fm_kernels, layer_of_pool, no_stacked_gate_up,
+    one_chip, program_bytes, score_arrays, topo,
 )
 
 
@@ -48,20 +48,21 @@ def lfm2_programs(one_chip):
     i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32, sharding=one_chip)
     with pytest.MonkeyPatch.context() as mp:        # traced as on a TPU
         mp.setattr(jax, "default_backend", lambda: "tpu")
-        return {
+        return Programs({
             "decode": eng._INPLACE["_paged_decode_step"].lower(
                 params, cfg, cache, i32(128), i32(128, 320), i32(128)),
             "chunk": eng._INPLACE["_prefill_chunk"].lower(
                 params, cfg, cache, i32(1, 1024), i32(320), i32(64), i32(),
                 i32(), i32()),
             "prefill": eng._prefill_padded.lower(
-                params, cfg, i32(1, 1024), i32())}
+                params, cfg, i32(1, 1024), i32())})
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk", "prefill"])
 def test_lfm2_programs_fit_the_chip_with_inputs_and_pool_in_place(
         lfm2_programs, program):
-    """12.03 GB (decode), 12.35 GB (chunk; 13.38 with float32 scores over
+    """12.03 GB (decode), 12.10 GB (chunk; 12.35 with a K and a V layer
+    of the pool copied out before ISSUE 50, 13.38 with float32 scores over
     the widest table) and 10.83 GB (a 1024-token prompt at once) as
     compiled, under the cell's 15.0: 10.63 GB of
     weights (the tied head a second array), and the K/V pool (1.34 GB:
@@ -75,10 +76,10 @@ def test_lfm2_programs_fit_the_chip_with_inputs_and_pool_in_place(
     and the whole prompt score their context blockwise since ISSUE 44
     (``fm_flash_span`` over 64-wide heads, a query head reading its K/V
     head of 8: TWO calls, no ``[32, 1024, .]`` scores)."""
-    compiled = lfm2_programs[program].compile()
+    compiled = lfm2_programs.compiled(program)
     text = compiled.as_text()
     pool, inputs = r"bf16\[2,20480,4,16,128\]", r"bf16\[7,128,4096\]"
-    lo, hi = {"decode": (11.8e9, 12.3e9), "chunk": (12.1e9, 12.6e9),
+    lo, hi = {"decode": (11.8e9, 12.3e9), "chunk": (11.85e9, 12.35e9),
               "prefill": (10.6e9, 11.1e9)}[program]
     assert lo < program_bytes(compiled) < hi
     # the experts by ``ops/moe.expert_arm``: since ISSUE 36 the routed
@@ -118,3 +119,14 @@ def test_lfm2_programs_fit_the_chip_with_inputs_and_pool_in_place(
         assert kernels == ["fm_flash_span"] * 2
         assert score_arrays(text, 32, 1024, 5120) == []
         assert "attn.conv_prefill" in text
+
+
+def test_lfm2_chunk_gathers_its_context_from_the_pool_where_it_lies(
+        lfm2_programs):
+    """ISSUE 50: the chunk's four context gathers (K and V of two layers,
+    320 pages) index layer AND pages of the 5-D pool.  NO array of one
+    layer's pool (``bf16[20480,4,16,128]``, 335.5 MB) exists in the
+    program: with ``gather_ctx(pools[.][li], ...)`` there were four, a
+    ``slice_bitcast_fusion`` each."""
+    compiled = lfm2_programs.compiled("chunk")
+    assert layer_of_pool(compiled, 2, 20480, 4, 16, 128) == ([], [], 4)
