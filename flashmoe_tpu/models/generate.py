@@ -41,7 +41,7 @@ from flashmoe_tpu.config import FFN_PARTS, MoEConfig
 from flashmoe_tpu.models.transformer import (
     embed_tokens, head_logits, join_stream, rms_norm,
 )
-from flashmoe_tpu.ops.attention import paged_attention
+from flashmoe_tpu.ops.attention import MIXER_SPANS, paged_attention
 from flashmoe_tpu.ops.moe import expert_arm, moe_layer
 from flashmoe_tpu.utils.telemetry import trace_span
 
@@ -137,22 +137,29 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
                                                    cfg.layers)):
         # a layer is the parts ``cfg.layers`` names, each behind its norm
         if mixer is not None:
-            a, pools, span = paged_attention(
-                layer, rms_norm(x, layer["attn_norm"], cfg.norm_eps), cfg,
-                pools, li, pos, write, block_tables, absorbed=absorbed,
-                valid=valid, slots=slots, fresh=fresh)
-            rows.append(span)
-            x = join_stream(cfg, x, a)
+            scope = MIXER_SPANS[mixer]
+            with trace_span(scope):  # staticcheck: ok a MIXER_SPANS name
+                a, pools, span = paged_attention(
+                    layer, rms_norm(x, layer["attn_norm"], cfg.norm_eps),
+                    cfg, pools, li, pos, write, block_tables,
+                    absorbed=absorbed, valid=valid, slots=slots,
+                    fresh=fresh)
+                rows.append(span)
+                x = join_stream(cfg, x, a)
         part, branch = FFN_PARTS[ffn]
         if part is None:
             continue
-        f_in = rms_norm(x, layer["ffn_norm"], cfg.norm_eps).reshape(
-            b * t, -1)
-        x = feed_forward(x, layer["moe"], f_in, cfg.ffn_config(li),
-                         part == "moe")
+        with trace_span("ffn.moe") if part == "moe" \
+                else trace_span("ffn.dense"):
+            f_in = rms_norm(x, layer["ffn_norm"], cfg.norm_eps).reshape(
+                b * t, -1)
+            x = feed_forward(x, layer["moe"], f_in, cfg.ffn_config(li),
+                             part == "moe")
         if branch == "moe":
-            carried = feed_forward(None, layer["branch"], f_in,
-                                   cfg.ffn_config(li, branch=True), True)
+            with trace_span("ffn.moe"):
+                carried = feed_forward(
+                    None, layer["branch"], f_in,
+                    cfg.ffn_config(li, branch=True), True)
         elif branch == "join":
             with trace_span("moe.shortcut_join"):
                 x = join_stream(cfg, x, carried)

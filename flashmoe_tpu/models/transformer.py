@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from flashmoe_tpu.config import FFN_PARTS, MoEConfig
 from flashmoe_tpu.models.reference import init_moe_params
+from flashmoe_tpu.ops.attention import MIXER_SPANS
 from flashmoe_tpu.ops.attention import rms_norm  # noqa: F401  (re-exported)
 from flashmoe_tpu.ops.attention import rope_halves as _rope  # noqa: F401
 from flashmoe_tpu.ops.moe import dense_ffn, moe_layer
@@ -153,9 +154,10 @@ def init_params(key, cfg: MoEConfig) -> dict:
 def embed_tokens(params, cfg: MoEConfig, tokens):
     """Rows of the embedding, scaled: tokens [...] int32 -> [..., H] in
     the activations' dtype, times ``cfg.embedding_multiplier``."""
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    if cfg.embedding_multiplier != 1.0:
-        x = x * cfg.embedding_multiplier
+    with trace_span("lm.embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
     return x
 
 
@@ -337,18 +339,23 @@ def block(layer, x, cfg: MoEConfig, li: int, mesh=None, use_pallas=None,
     drills rebuild their step exactly to pick up new arming)."""
     mixer, ffn = cfg.layers[li]
     if mixer is not None:
-        x = join_stream(cfg, x, attention(
-            layer, rms_norm(x, layer["attn_norm"], cfg.norm_eps), cfg,
-            mesh=mesh, use_pallas=use_pallas, li=li))
+        scope = MIXER_SPANS[mixer]
+        with trace_span(scope):  # staticcheck: ok a MIXER_SPANS name
+            x = join_stream(cfg, x, attention(
+                layer, rms_norm(x, layer["attn_norm"], cfg.norm_eps), cfg,
+                mesh=mesh, use_pallas=use_pallas, li=li))
     if ffn is None:
         return x, jnp.zeros((), cfg.accum_dtype), None, carried
-    f_in = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
-    f, moe_loss, moe_stats = _ffn(layer, f_in, cfg, li, mesh, use_pallas)
-    x = join_stream(cfg, x, f)
-    branch = FFN_PARTS[ffn][1]
+    part, branch = FFN_PARTS[ffn]
+    with trace_span("ffn.moe") if part == "moe" else trace_span("ffn.dense"):
+        f_in = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
+        f, moe_loss, moe_stats = _ffn(layer, f_in, cfg, li, mesh,
+                                      use_pallas)
+        x = join_stream(cfg, x, f)
     if branch == "moe":
-        carried, branch_loss, moe_stats = _ffn(
-            layer, f_in, cfg, li, mesh, use_pallas, branch=True)
+        with trace_span("ffn.moe"):
+            carried, branch_loss, moe_stats = _ffn(
+                layer, f_in, cfg, li, mesh, use_pallas, branch=True)
         moe_loss = moe_loss + branch_loss
     elif branch == "join":
         x, carried = join_stream(cfg, x, carried), None

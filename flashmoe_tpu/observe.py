@@ -21,6 +21,7 @@ Usage::
     python -m flashmoe_tpu.observe --ledger obs/ledger.jsonl
     python -m flashmoe_tpu.observe --serving obs/flight.jsonl obs/decisions.jsonl
     python -m flashmoe_tpu.observe --gaps <profiler trace dir> obs/flight.jsonl
+    python -m flashmoe_tpu.observe --device <profiler trace dir> [obs/flight.jsonl]
     python -m flashmoe_tpu.observe --postmortem /path/to/bundles
     python -m flashmoe_tpu.observe --trace 3 obs/trace.jsonl
     python -m flashmoe_tpu.observe --merge obs/telemetry.*.jsonl
@@ -32,7 +33,10 @@ decision dumps); ``--serving`` renders the serving-engine report
 sketch, queue depth, cache occupancy, the prefill-vs-decode planner
 split — docs/SERVING.md); ``--gaps`` lists the device's idle gaps of a
 profiler trace beside the serving engine's records of those moments
-(docs/OBSERVABILITY.md "Why the device waited"); ``--postmortem`` renders a triage report of
+(docs/OBSERVABILITY.md "Why the device waited"); ``--device`` splits the
+device's busy time of such a trace by program, stage scope and kernel
+from the programs' HLO the trace itself carries (docs/OBSERVABILITY.md
+"Device time under those names"); ``--postmortem`` renders a triage report of
 the crash bundle(s) written by
 :mod:`flashmoe_tpu.profiler.postmortem`; ``--trace <rid>`` renders one
 request's end-to-end timeline (eviction gaps included) from
@@ -1154,14 +1158,90 @@ def render_postmortem_text(rep: dict) -> str:
     return "\n".join(lines)
 
 
-def read_gaps_trace(trace_dir: str) -> dict | None:
-    """What :func:`gaps_report` needs of the newest ``.xplane.pb`` under
-    ``trace_dir``, times in ns on the profiler's clock (an event's
-    ``start_ns`` counts from the trace's ``profile_start_time``):
-    ``busy``, the first device's operations ``(start, duration)`` in
-    order; ``spans``, the host's ``serve.*`` / ``bench.*`` events
-    ``(start, duration, name, step)`` in order, ``step`` the stat of a
-    ``serve.step`` event.  ``None`` where there is no trace."""
+def _pb_fields(buf):
+    """(field number, value) of one protobuf message's bytes: a varint as
+    an int, a length-delimited field as a ``memoryview`` (nothing else is
+    read of an ``.xplane.pb``)."""
+    i, n = 0, len(buf)
+
+    def varint():
+        nonlocal i
+        val = shift = 0
+        while True:
+            byte = buf[i]
+            i += 1
+            val |= (byte & 0x7F) << shift
+            shift += 7
+            if not byte & 0x80:
+                return val
+
+    while i < n:
+        key = varint()
+        kind = key & 7
+        if kind == 0:
+            val = varint()
+        else:
+            width = varint() if kind == 2 else {1: 8, 5: 4}[kind]
+            val = buf[i:i + width]
+            i += width
+        yield key >> 3, val
+
+
+def trace_programs(path: str) -> dict:
+    """``{"jit_f(<fingerprint>)": optimized HLO text}`` of every program
+    the process held compiled under the profiler (those that ran are
+    among them), from the ``/host:metadata`` plane of an
+    ``.xplane.pb``: the profiler keeps each program's whole ``HloProto``
+    there as a stat of an event METADATA entry, which
+    ``jax.profiler.ProfileData`` does not show (it lists a plane's lines
+    and an event's own stats), under the name the ``XLA Modules`` line
+    gives the program's executions.  The ``op_name`` of every instruction
+    is in that text and nowhere in an event."""
+    from jax._src.lib import xla_client
+
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    out = {}
+    for field, plane in _pb_fields(space):
+        if field != 1:
+            continue
+        parts = list(_pb_fields(plane))
+        if not any(f == 2 and bytes(v) == b"/host:metadata"
+                   for f, v in parts):
+            continue
+        for f, entry in parts:
+            if f != 4:                       # map<int64, XEventMetadata>
+                continue
+            meta = dict(_pb_fields(entry)).get(2)
+            if meta is None:
+                continue
+            name = protos = None
+            for mf, mv in _pb_fields(meta):
+                if mf == 2:
+                    name = bytes(mv).decode()
+                elif mf == 5:                # XStat: bytes_value is 6
+                    protos = dict(_pb_fields(mv)).get(6, protos)
+            if name and protos is not None:
+                module = dict(_pb_fields(protos)).get(1)   # hlo_module
+                out[name] = xla_client._xla.HloModule \
+                    .from_serialized_hlo_module_proto(
+                        bytes(module)).to_string()
+    return out
+
+
+def read_gaps_trace(trace_dir: str, programs: bool = False) -> dict | None:
+    """What :func:`gaps_report` and :func:`device_report` need of the
+    newest ``.xplane.pb`` under ``trace_dir``, times in ns on the
+    profiler's clock (an event's ``start_ns`` counts from the trace's
+    ``profile_start_time``): ``ops``, the first device's operations
+    ``(start, duration, name)`` in order (``busy``: the same without the
+    names), ``name`` the instruction's text as the chip's trace gives it;
+    ``modules``, that device's program executions ``(start, duration,
+    "jit_f(<fingerprint>)")``; ``spans``, the host's ``serve.*`` /
+    ``bench.*`` events ``(start, duration, name, step)`` in order,
+    ``step`` the stat of a ``serve.step`` event; with ``programs``, each
+    program's instructions by scope (:func:`trace_programs` through
+    ``telemetry.program_scopes``).  ``None`` where there is no trace."""
     import glob
     import os
 
@@ -1176,21 +1256,30 @@ def read_gaps_trace(trace_dir: str) -> dict | None:
                  if k == "profile_start_time"), 0)
     device = min((p.name for p in planes
                   if p.name.startswith("/device:TPU:")), default=None)
-    busy, spans = [], []
+    ops, modules, spans = [], [], []
     for plane in planes:
         lines = {ln.name: ln for ln in plane.lines}
         if plane.name == device:
-            ops = lines.get("XLA Ops") or lines.get("XLA Modules")
-            busy = [(base + int(e.start_ns), int(e.duration_ns))
-                    for e in (ops.events if ops else ())]
+            timed = lambda ln: sorted(
+                (base + int(e.start_ns), int(e.duration_ns), e.name)
+                for e in (ln.events if ln else ()))
+            modules = timed(lines.get("XLA Modules"))
+            ops = timed(lines.get("XLA Ops")) or modules
         elif plane.name.startswith("/host:"):
             spans += [(base + int(e.start_ns), int(e.duration_ns), e.name,
                        dict(e.stats).get("step")
                        if e.name == "serve.step" else None)
                       for ln in lines.values() for e in ln.events
                       if e.name.startswith(("serve.", "bench."))]
-    return {"file": found[-1], "base": base, "busy": sorted(busy),
-            "spans": sorted(spans, key=lambda s: s[:2])}
+    out = {"file": found[-1], "base": base, "device": device, "ops": ops,
+           "busy": [op[:2] for op in ops], "modules": modules,
+           "spans": sorted(spans, key=lambda s: s[:2])}
+    if programs:
+        from flashmoe_tpu.utils.telemetry import program_scopes
+
+        out["programs"] = {name: program_scopes(text) for name, text
+                           in trace_programs(found[-1]).items()}
+    return out
 
 
 def gaps_report(trace: dict, records: list[dict],
@@ -1242,9 +1331,14 @@ def gaps_report(trace: dict, records: list[dict],
                                    "gc_ms", "ctx_switches") if rec}})
     named = sum(r["gap_ms"] for r in rows
                 if r["step"] is not None and r["spans"])
+    by_span: dict[str, float] = {}
+    for r in rows:
+        inner = r["spans"][-1] if r["spans"] else "(no span)"
+        by_span[inner] = by_span.get(inner, 0.0) + r["gap_ms"]
     return {"trace": trace.get("file"), "gaps": rows,
             "idle_ms": idle_ns / 1e6,
             "gaps_ms": sum(r["gap_ms"] for r in rows), "named_ms": named,
+            "by_span": dict(sorted(by_span.items(), key=lambda kv: -kv[1])),
             "clock_skew_ms": skew / 1e6, "steps": len(steps)}
 
 
@@ -1255,6 +1349,8 @@ def render_gaps_text(rep: dict) -> str:
            f"({100 * rep['named_ms'] / max(rep['idle_ms'], 1e-9):.1f} % of "
            f"the idle time); {rep['steps']} serve_step records; largest "
            f"|serve.step start - t0_trace_ns| {rep['clock_skew_ms']:.4f} ms",
+           "idle ms by the innermost span open when a gap began: "
+           + ", ".join(f"{k} {v:.3f}" for k, v in rep["by_span"].items()),
            "gap_ms  at_ms  step  host_ms between_ms cpu_ms gc_ms "
            "ctx_switches  spans  [prefill]"]
     for r in rep["gaps"]:
@@ -1268,6 +1364,208 @@ def render_gaps_text(rep: dict) -> str:
                    f"{r['where'] or '-':>6} {r['step']} {acct} "
                    f"{r.get('ctx_switches', '-')}  "
                    f"{' > '.join(r['spans']) or '(no span)'}{fed}")
+    return "\n".join(out)
+
+
+def device_report(trace: dict, records: list[dict] = (),
+                  top: int = 10) -> dict:
+    """The device's time in ``trace`` (:func:`read_gaps_trace` with
+    ``programs``) by program, and inside each program by the stage scope
+    and the kernel of every operation.
+
+    An operation belongs to the program execution (``modules``) that
+    encloses it in time, and its scope is what that program's optimized
+    HLO says of the instruction the event is named after
+    (``trace["programs"][<module name>][<instruction>]``: two shapes of
+    one function are two fingerprints, so two programs).  An operation
+    that encloses others (a ``while``, a ``conditional``, a ``call``)
+    counts its SELF time, so the parts of a program sum to its busy time.
+    A program's executions that one of the trace's two ends clips (the
+    first or last of the device's line, with fewer operations than the
+    program's fullest) are counted apart; ``scopes`` and ``kernels`` are
+    ms a WHOLE execution, each the median over the whole executions.
+    ``(unscoped)``: the program's HLO knows the instruction and its
+    ``op_name`` holds no registered span; ``(unmatched)``: no program of
+    the trace holds it; each with its ``top`` largest operations folded by
+    name without ``.N`` and result shape (ms and events a whole execution,
+    the mean).  ``idle``: :func:`gaps_report`
+    of the same trace and ``records``.  None where the trace has no
+    device plane."""
+    import bisect
+    import re
+    import statistics
+
+    from flashmoe_tpu.utils.telemetry import hlo_result
+
+    if not trace.get("device") or not trace["ops"]:
+        return None
+    ops = sorted(trace["ops"], key=lambda e: (e[0], -e[1]))
+    modules = sorted(trace["modules"]) or [
+        (ops[0][0], ops[-1][0] + ops[-1][1] - ops[0][0], "(no program)")]
+    programs = trace.get("programs") or {}
+    own = [dur for _, dur, _ in ops]
+    open_ = []                                  # (end, index), outermost first
+    for i, (t, dur, _) in enumerate(ops):
+        while open_ and open_[-1][0] <= t:
+            open_.pop()
+        if open_:
+            own[open_[-1][1]] -= min(dur, open_[-1][0] - t)
+        open_.append((t + dur, i))
+
+    parsed = {}
+
+    def instruction(name):
+        """(instruction, name without ``.N``, result shape) of an event."""
+        if name not in parsed:
+            head, _, text = name.partition(" = ")
+            instr = head.lstrip("%")
+            parsed[name] = (
+                instr, re.sub(r"(\.(\d+|remat\d*|clone))+$", "", instr),
+                hlo_result(text)[0] if text else "")
+        return parsed[name]
+
+    starts = [m[0] for m in modules]
+    runs = [{"n": 0, "scopes": {}, "kernels": {}, "loose": {}}
+            for _ in modules]
+    astray = 0
+    for (t, dur, name), mine in zip(ops, own):
+        i = bisect.bisect_right(starts, t) - 1
+        if i < 0 or t >= modules[i][0] + modules[i][1]:
+            astray += mine
+            continue
+        run, known = runs[i], programs.get(modules[i][2])
+        instr, folded, shape = instruction(name)
+        scope, way, kernel = (known or {}).get(
+            instr, ("(unmatched)", "", None))
+        scope = scope or "(unscoped)"
+        run["n"] += 1
+        key = (scope, way if scope[0] != "(" else "")
+        run["scopes"][key] = run["scopes"].get(key, 0) + mine
+        if kernel:
+            k = run["kernels"].setdefault(kernel, [0, 0])
+            k[0] += mine
+            k[1] += 1
+        if scope[0] == "(":
+            k = run["loose"].setdefault((scope, folded, shape), [0, 0])
+            k[0] += mine
+            k[1] += 1
+
+    busy = sum(own)
+    spent = lambda i, *scopes: sum(
+        t for (sc, _), t in runs[i]["scopes"].items()
+        if not scopes or sc in scopes)
+    by_program = {}
+    for i, m in enumerate(modules):
+        by_program.setdefault(m[2], []).append(i)
+    fullest = {name: max(runs[i]["n"] for i in idx)
+               for name, idx in by_program.items()}
+    edge = {0, len(modules) - 1}
+    med = lambda vals: statistics.median(vals) / 1e6
+    out_programs, matched, scoped, kernels = [], 0, 0, {}
+    for name, idx in by_program.items():
+        clipped = [i for i in idx
+                   if i in edge and runs[i]["n"] < fullest[name]]
+        whole = [i for i in idx if i not in clipped] or idx
+        keys = sorted({k for i in whole for k in runs[i]["scopes"]})
+        rows = [{"scope": sc, "pass": way,
+                 "ms": med([runs[i]["scopes"].get((sc, way), 0)
+                            for i in whole])} for sc, way in keys]
+        fams = sorted({k for i in whole for k in runs[i]["kernels"]})
+        loose = {}
+        for i in idx:
+            for key, (t, n) in runs[i]["loose"].items() if i in whole \
+                    else ():
+                k = loose.setdefault(key, [0, 0])
+                k[0] += t
+                k[1] += n
+            for fam, (t, n) in runs[i]["kernels"].items():
+                k = kernels.setdefault(fam, [0, 0])
+                k[0] += t
+                k[1] += n
+        total = sum(spent(i) for i in idx)
+        matched += total - sum(spent(i, "(unmatched)") for i in idx)
+        scoped += total - sum(spent(i, "(unmatched)", "(unscoped)")
+                              for i in idx)
+        times = sorted(modules[i][1] / 1e6 for i in whole)
+        out_programs.append({
+            "program": name, "executions": len(whole),
+            "clipped": len(clipped),
+            "clipped_ms": sum(spent(i) for i in clipped) / 1e6,
+            "ms": [times[0], statistics.median(times), times[-1]],
+            "busy_ms": total / 1e6, "share": total / max(busy, 1),
+            "ops": fullest[name],
+            "rows_ms": sum(r["ms"] for r in rows),
+            "scopes": sorted(rows, key=lambda r: -r["ms"]),
+            "kernels": [{"kernel": fam,
+                         "ms": med([runs[i]["kernels"].get(fam, (0, 0))[0]
+                                    for i in whole]),
+                         "calls": statistics.median(
+                             [runs[i]["kernels"].get(fam, (0, 0))[1]
+                              for i in whole])} for fam in fams],
+            **{key[1:-1]: [
+                {"op": op, "shape": shape, "ms": t / len(whole) / 1e6,
+                 "n": n / len(whole)}
+                for (sc, op, shape), (t, n) in sorted(
+                    loose.items(), key=lambda kv: -kv[1][0])
+                if sc == key][:top]
+               for key in ("(unscoped)", "(unmatched)")}})
+    out_programs.sort(key=lambda p: -p["busy_ms"])
+    window = max(t + d for t, d, _ in ops) - ops[0][0]
+    return {"trace": trace.get("file"), "busy_ms": busy / 1e6,
+            "window_ms": window / 1e6, "idle_ms": (window - busy) / 1e6,
+            "outside_programs_ms": astray / 1e6,
+            "matched_share": matched / max(busy, 1),
+            "scoped_share": scoped / max(busy, 1),
+            "programs": out_programs,
+            "kernels": {fam: {"ms": t / 1e6, "calls": n}
+                        for fam, (t, n) in sorted(kernels.items())},
+            "idle": {k: v for k, v in gaps_report(trace, records).items()
+                     if k != "gaps"}}
+
+
+def render_device_text(rep: dict, min_share: float = 0.002) -> str:
+    pct = lambda part, whole: 100 * part / max(whole, 1e-9)
+    idle = rep["idle"]
+    out = [f"device busy {rep['busy_ms']:.3f} ms of a window of "
+           f"{rep['window_ms']:.3f} (idle {rep['idle_ms']:.3f} ms, "
+           f"{pct(rep['idle_ms'], rep['window_ms']):.2f} %); "
+           f"{100 * rep['matched_share']:.2f} % of the busy time matched to "
+           f"an instruction of a program the trace holds, "
+           f"{100 * rep['scoped_share']:.2f} % under a registered scope",
+           f"idle by the innermost span open when a gap began "
+           f"({idle['named_ms']:.3f} of {idle['idle_ms']:.3f} ms with a "
+           f"step and a span): " + (", ".join(
+               f"{k} {v:.3f}" for k, v in idle["by_span"].items())
+               or "no gap over the threshold")]
+    for p in rep["programs"]:
+        if p["share"] < min_share:
+            continue
+        lo, mid, hi = p["ms"]
+        out.append(
+            f"{p['program']}: {p['executions']} whole executions "
+            f"({p['clipped']} clipped, {p['clipped_ms']:.3f} ms), "
+            f"{lo:.3f} / {mid:.3f} / {hi:.3f} ms min / median / max, "
+            f"{100 * p['share']:.2f} % of the busy time; {p['ops']} "
+            f"operations; rows sum to {p['rows_ms']:.3f} ms")
+        for r in p["scopes"]:
+            out.append(f"    {r['ms']:10.3f} ms {pct(r['ms'], mid):6.2f} %  "
+                       f"{r['scope']} {r['pass']}".rstrip())
+        for k in p["kernels"]:
+            out.append(f"    kernel {k['kernel']}: {k['ms']:.3f} ms in "
+                       f"{k['calls']:g} calls")
+        for key in ("unscoped", "unmatched"):
+            for r in p[key]:
+                out.append(f"      ({key}) {r['ms']:8.3f} ms in {r['n']:g}: "
+                           f"{r['op']} -> {r['shape'][:60]}")
+    small = [p for p in rep["programs"] if p["share"] < min_share]
+    if small:
+        out.append("programs under %.1f %% of the busy time: " % (
+            100 * min_share) + ", ".join(
+            f"{p['program']} x{p['executions']} {p['busy_ms']:.3f} ms"
+            for p in small))
+    out.append("kernels over the whole trace: " + ", ".join(
+        f"{fam} {k['ms']:.3f} ms in {k['calls']} calls"
+        for fam, k in rep["kernels"].items()))
     return "\n".join(out)
 
 
@@ -1406,6 +1704,12 @@ def main(argv=None) -> int:
                          "(first file: the trace directory) beside the "
                          "serving engine's serve_step / serve_prefill "
                          "records (the other files) of those moments")
+    ap.add_argument("--device", action="store_true",
+                    help="the device's time in a profiler trace (first "
+                         "file: the trace directory) by program, stage "
+                         "scope and kernel, from the programs' HLO the "
+                         "trace carries; the idle side beside the serving "
+                         "engine's records (the other files, if any)")
     ap.add_argument("--postmortem", metavar="DIR",
                     help="render a triage report of the crash postmortem "
                          "bundle(s) under DIR")
@@ -1429,6 +1733,7 @@ def main(argv=None) -> int:
     modes = [m for m, on in (("--ledger", args.ledger),
                              ("--serving", args.serving),
                              ("--gaps", args.gaps),
+                             ("--device", args.device),
                              ("--postmortem", bool(args.postmortem)),
                              ("--trace", args.trace is not None),
                              ("--merge", args.merge),
@@ -1474,6 +1779,19 @@ def main(argv=None) -> int:
         else:
             print(render_gaps_text(rep))
         return 0 if rep["gaps"] else 2
+    if args.device:
+        trace = read_gaps_trace(args.files[0], programs=True)
+        rep = trace and device_report(trace, load_jsonl(args.files[1:]))
+        if rep is None:
+            print(f"no .xplane.pb with a TPU's plane under "
+                  f"{args.files[0]!r}", file=sys.stderr)
+            return 2
+        if args.json:
+            json.dump(rep, sys.stdout)
+            print()
+        else:
+            print(render_device_text(rep))
+        return 0
     records = load_jsonl(args.files)
     if not records:
         print("no parseable records found", file=sys.stderr)

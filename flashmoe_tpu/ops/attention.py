@@ -382,25 +382,28 @@ def kv_attend(layer, q, k_ctx, v_ctx, q_pos, block: int = 1,
     if scale is None:
         scale = dh ** -0.5
     if span_attention_arm(t, k_ctx.shape[2], nh, (dh,), dh, dt) == "flash":
-        ctx = _flash_span_ctx((q,), (k_ctx.astype(dt),), v_ctx.astype(dt),
-                              q_pos, scale, block)
+        with trace_span("attn.kv_prefill"):
+            ctx = _flash_span_ctx((q,), (k_ctx.astype(dt),),
+                                  v_ctx.astype(dt), q_pos, scale, block)
         return ctx @ layer["wo"].astype(dt)
-    if block > 1:
-        q_pos = q_pos | (block - 1)
-    if k_ctx.shape[1] != nh:  # GQA: repeat kv heads
-        rep = nh // k_ctx.shape[1]
-        k_ctx = jnp.repeat(k_ctx, rep, axis=1)
-        v_ctx = jnp.repeat(v_ctx, rep, axis=1)
-    logits = jnp.einsum(
-        "bntd,bnsd->bnts", q.transpose(0, 2, 1, 3), k_ctx,
-        preferred_element_type=jnp.float32) * scale
-    mask = (jnp.arange(k_ctx.shape[2])[None, None, None, :]
-            <= q_pos[:, None, :, None])
-    probs = jax.nn.softmax(jnp.where(mask, logits, NEG_INF),
-                           axis=-1).astype(dt)
-    ctx = jnp.einsum(
-        "bnts,bnsd->bntd", probs, v_ctx, preferred_element_type=jnp.float32
-    ).transpose(0, 2, 1, 3).reshape(b, t, nh * dh).astype(dt)
+    with trace_span("attn.kv_prefill"):
+        if block > 1:
+            q_pos = q_pos | (block - 1)
+        if k_ctx.shape[1] != nh:  # GQA: repeat kv heads
+            rep = nh // k_ctx.shape[1]
+            k_ctx = jnp.repeat(k_ctx, rep, axis=1)
+            v_ctx = jnp.repeat(v_ctx, rep, axis=1)
+        logits = jnp.einsum(
+            "bntd,bnsd->bnts", q.transpose(0, 2, 1, 3), k_ctx,
+            preferred_element_type=jnp.float32) * scale
+        mask = (jnp.arange(k_ctx.shape[2])[None, None, None, :]
+                <= q_pos[:, None, :, None])
+        probs = jax.nn.softmax(jnp.where(mask, logits, NEG_INF),
+                               axis=-1).astype(dt)
+        ctx = jnp.einsum(
+            "bnts,bnsd->bntd", probs, v_ctx,
+            preferred_element_type=jnp.float32
+        ).transpose(0, 2, 1, 3).reshape(b, t, nh * dh).astype(dt)
     return ctx @ layer["wo"].astype(dt)
 
 
@@ -485,17 +488,28 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
     if (write[1] is not None and block in (1, q.shape[1])
             and kv_attention_arm(q.shape[1], page, rows, width,
                                  pools[0].dtype) == "paged_kernel"):
-        ctx, pools = paged_decode_attention(
-            q, (k, v), pools, li, block_tables, pos[:, 0], write,
-            scale=scale, block=block,
-            interpret=jax.default_backend() != "tpu")
+        with trace_span("attn.kv_decode"):
+            ctx, pools = paged_decode_attention(
+                q, (k, v), pools, li, block_tables, pos[:, 0], write,
+                scale=scale, block=block,
+                interpret=jax.default_backend() != "tpu")
         return ctx @ layer["wo"].astype(q.dtype), pools, span
-    pools = (store_kv(pools[0], li, k, *write),
-             store_kv(pools[1], li, v, *write))
-    k_ctx = gather_ctx(pools[0], li, block_tables, q.shape[-1])
-    v_ctx = gather_ctx(pools[1], li, block_tables, q.shape[-1])
+    with trace_span("attn.kv_prefill"):
+        pools = (store_kv(pools[0], li, k, *write),
+                 store_kv(pools[1], li, v, *write))
+        k_ctx = gather_ctx(pools[0], li, block_tables, q.shape[-1])
+        v_ctx = gather_ctx(pools[1], li, block_tables, q.shape[-1])
     return (kv_attend(layer, q, k_ctx, v_ctx, pos, block, scale), pools,
             span)
+
+
+#: the registered scope a layer's token mixer part runs under, by its
+#: kind: everything of the part (its norm, projections, the mixer itself,
+#: the output product, the residual join) is that kind's time in a trace
+#: (``observe --device``); the mixer's two forms (``attn.<kind>_prefill`` /
+#: ``_decode``) and the kernels open their own scopes inside it
+MIXER_SPANS = {"mha": "attn.kv", "mla": "attn.mla", "kda": "attn.kda",
+               "conv": "attn.conv", "ssm": "attn.ssm"}
 
 
 #: the mixers of ``config.STATE_MIXERS``: each takes ``(layer, x, cfg,

@@ -673,11 +673,12 @@ def _sample_dynamic(logits, seeds, index, temps, top_ks, top_ps):
     no sampled row it is an ``argmax``; the keys (:func:`_token_keys`,
     derived here) and :func:`_sample_with_keys` run only in the other
     arm of a ``lax.cond`` on ``temps``."""
-    return jax.lax.cond(
-        jnp.any(temps > 0.0),
-        lambda: _sample_with_keys(logits, _token_keys(seeds, index),
-                                  temps, top_ks, top_ps),
-        lambda: jnp.argmax(logits, axis=-1).astype(jnp.int32))
+    with trace_span("lm.sample"):
+        return jax.lax.cond(
+            jnp.any(temps > 0.0),
+            lambda: _sample_with_keys(logits, _token_keys(seeds, index),
+                                      temps, top_ks, top_ps),
+            lambda: jnp.argmax(logits, axis=-1).astype(jnp.int32))
 
 
 def _sampler_rows(rows):

@@ -10,9 +10,11 @@ reads inside kernels, and cudaEvent kernel timing.  TPU equivalents:
     prefixes in xprof; the ep and fused MoE layers wrap their gate /
     dispatch / a2a / expert / combine phases so traces read like the
     reference's NVTX domain;
-  * :func:`start_trace` / :func:`stop_trace` — whole-program profiler
-    capture for tensorboard/xprof (the SM-utilization analogue: MXU
-    utilization comes from the captured trace);
+  * :func:`program_scopes` — from a compiled program's HLO text to the
+    registered scope, pass and kernel of every instruction a device trace
+    can show: what ``python -m flashmoe_tpu.observe --device`` joins a
+    ``jax.profiler`` trace's events with (the SM-utilization analogue:
+    device time by stage comes from the captured trace);
   * :class:`Metrics` — lightweight host-side counters/gauges/timers/
     histograms with JSONL export and Prometheus text exposition (the
     reference's per-rank ``fmt::println`` timings, structured);
@@ -259,9 +261,49 @@ SPAN_NAMES: dict[str, str] = {
     "attn.ssm_decode":
         "state-space mixer, one recurrence step over every slot's float32 "
         "state, read once and written once (decode)",
+    "attn.kv_prefill":
+        "K/V attention, the gather arm: a span's rows stored, its context "
+        "gathered (or the span itself, a whole prompt) and scored, "
+        "blockwise (``fm_flash_span``) or in plain XLA (prefill, chunked "
+        "prefill; the CPU's decode steps)",
+    "attn.kv_decode":
+        "K/V attention, the kernel's arm: a short span over each slot's "
+        "own pages, read and written in place (``fm_paged_decode``: decode, "
+        "verify and denoise steps on a TPU)",
+    "attn.kv":
+        "a K/V attention layer's part (MHA / GQA): what of it stands under "
+        "neither arm: its norm, the q / k / v projections with their norms "
+        "and RoPE, the output product, the residual join; training's "
+        "attention whole",
+    "attn.mla":
+        "a latent-attention layer's part: what of it stands under neither "
+        "form: its norm, the projections down and up, the output product, "
+        "the pool's store and gather, the residual join",
+    "attn.kda":
+        "a delta-rule layer's part: what of it stands under neither form: "
+        "its norm, projections, short convolutions, gates, the output "
+        "product, the state's read and write, the residual join",
+    "attn.conv":
+        "a gated short convolution layer's part: what of it stands under "
+        "neither form: its norm, the in- and out-projections, the gates, "
+        "the residual join",
+    "attn.ssm":
+        "a state-space layer's part: what of it stands under neither form: "
+        "its norm, the in-projection, the convolution, the gated norm, the "
+        "output product, the state's read and write, the residual join",
     "ffn.dense":
         "a dense feed-forward part (one expert of the dense width, no "
-        "router): a layer's own, or a mixture model's leading layers'",
+        "router): a layer's own, or a mixture model's leading layers'; "
+        "its norm and its residual join with it",
+    "ffn.moe":
+        "a mixture feed-forward part: what of it stands under none of its "
+        "stages (``moe.*``, which open inside it): its norm, its residual "
+        "join, what the layer counts",
+    "lm.embed":
+        "the embedding's rows of a span's tokens (and their multiplier)",
+    "lm.sample":
+        "the serving engine's sampler program: the rows' keys, the arms "
+        "by what the knobs ask (an argmax, a draw, a sort), the tokens",
     "lm.head":
         "the final norm and the head's product over the vocabulary (a "
         "tied head over the embedding's own rows), the logits' divisor",
@@ -504,21 +546,143 @@ def gc_totals() -> tuple[float, float]:
     return c.get("gc.count", 0.0), c.get("gc.seconds", 0.0)
 
 
-def start_trace(log_dir: str):
-    jax.profiler.start_trace(log_dir)
+def span_of_path(path: str) -> str | None:
+    """The INNERMOST name of :data:`SPAN_NAMES` on an operation's
+    ``op_name`` path (``jit(f)/attn.ssm_prefill/while/body/mul``; jax
+    wraps a differentiated scope, ``transpose(jvp(moe.expert))``), a
+    chunk's suffix folded (``moe.expert.3``); None where it holds none."""
+    from flashmoe_tpu.profiler.spans import merged_phase
+
+    for word in reversed(re.findall(r"[A-Za-z_][\w.]*", path)):
+        if merged_phase(word) in SPAN_NAMES:
+            return merged_phase(word)
+    return None
 
 
-def stop_trace():
-    jax.profiler.stop_trace()
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=]+)\s+=\s+(.*)$")
+_HLO_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_HLO_CALLS = re.compile(
+    r"\b(calls|body|condition|to_apply|true_computation|false_computation"
+    r"|branch_computations)=(?:%?([\w.\-]+)|\{([^}]*)\})")
+#: the instructions whose computations the chip runs instruction by
+#: instruction (their events enclose their bodies' on the ``XLA Ops`` line)
+_HLO_CONTROL = ("while", "conditional", "call")
 
 
-@contextlib.contextmanager
-def capture_trace(log_dir: str):
-    start_trace(log_dir)
-    try:
-        yield
-    finally:
-        stop_trace()
+def _closing(text: str, start: int) -> int:
+    """Index of the bracket that closes the one at ``text[start]``."""
+    depth = 0
+    for i in range(start, len(text)):
+        depth += (text[i] == "(") - (text[i] == ")")
+        if not depth:
+            return i
+    return len(text) - 1
+
+
+def hlo_result(text: str) -> tuple[str, str, str]:
+    """(result shape, opcode, operands) of an instruction's text after its
+    ``=``: ``f32[8,128]{1,0} fusion(%a, %b), kind=...`` or a tuple
+    ``(s32[], f32[2]{0}) while(%t), ...``; the shape without layouts."""
+    if text.startswith("("):
+        end = _closing(text, 0) + 1
+        shape, rest = text[:end], text[end:].lstrip()
+    else:
+        shape, _, rest = text.partition(" ")
+    opcode, paren, _ = rest.partition("(")
+    operands = (rest[len(opcode) + 1:_closing(rest, len(opcode))]
+                if paren else "")
+    return re.sub(r"\{[^{}]*\}", "", shape), opcode, operands
+
+
+def program_scopes(hlo_text: str) -> dict:
+    """From a compiled program's optimized HLO text
+    (``compiled.as_text()``, or what a trace keeps of it:
+    ``observe.trace_programs``) to ``{instruction name: (scope, pass,
+    kernel)}`` for every instruction the chip's ``XLA Ops`` line can show:
+    those of the entry computation and of the computations its ``while``
+    / ``conditional`` / ``call`` instructions run, a fusion being ONE
+    instruction.  ``scope``: :func:`span_of_path` of the instruction's
+    ``op_name``, None where there is none; a fusion whose own names none
+    takes the one most of its fused instructions carry; an instruction the
+    compiler made, whose ``op_name`` is no path of the program (none at
+    all, or a parameter's name: the prefetch of a weight, a copy into
+    another layout), takes the one most of the instructions that READ it
+    carry.  ``pass``: ``"bwd"`` where the path holds ``transpose(`` (jax's
+    mark on a backward's operations), else ``"fwd"``.  ``kernel``: the
+    family of a ``tpu_custom_call`` (``fm_ffn_fwd.13`` -> ``fm_ffn_fwd``),
+    else None.  The chip's trace names an event by its instruction's text
+    and gives it no ``op_name``: this is the join."""
+    comps, entry, cur = {}, None, None
+    for line in hlo_text.splitlines():
+        if not line or line[0] in " }" or line.startswith("HloModule"):
+            m = cur is not None and _HLO_INSTRUCTION.match(line)
+            if m:
+                cur.append((m.group(1), m.group(2)))
+        elif line.rstrip().endswith("{"):
+            head = line.split("(", 1)[0].split()
+            cur = comps[head[-1].lstrip("%")] = []
+            if head[0] == "ENTRY":
+                entry = cur
+
+    def called(text):
+        for m in _HLO_CALLS.finditer(text):
+            for name in (m.group(2) or m.group(3)).split(","):
+                yield name.strip().lstrip("%")
+
+    def own(text):
+        """(the ``op_name`` path or None, its scope, its pass)."""
+        m = _HLO_OP_NAME.search(text)
+        path = m.group(1) if m else ""
+        return (path if "/" in path else None, span_of_path(path),
+                "bwd" if "transpose(" in path else "fwd")
+
+    most = lambda votes: max(votes.items(), key=lambda kv: kv[1])[0]
+    fused = {}
+
+    def tally(comp):
+        """(scope, pass) -> instructions under it, over a fused
+        computation and those it calls in turn."""
+        if comp not in fused:
+            fused[comp] = acc = defaultdict(int)
+            for _, text in comps.get(comp, ()):
+                _, scope, way = own(text)
+                if scope:
+                    acc[scope, way] += 1
+                for sub in called(text):
+                    for key, n in tally(sub).items():
+                        acc[key] += n
+        return fused[comp]
+
+    out, seen, todo = {}, set(), [entry]
+    while todo:
+        comp = todo.pop()
+        if comp is None or id(comp) in seen:
+            continue
+        seen.add(id(comp))
+        readers = defaultdict(lambda: defaultdict(int))
+        for name, text in reversed(comp):       # a reader before its operand
+            _, opcode, operands = hlo_result(text)
+            path, scope, way = own(text)
+            subs = list(called(text))
+            if opcode in _HLO_CONTROL:
+                todo += [comps.get(sub) for sub in subs]
+            elif scope is None and subs:
+                votes = defaultdict(int)
+                for sub in subs:
+                    for key, n in tally(sub).items():
+                        votes[key] += n
+                if votes:
+                    scope, way = most(votes)
+            if scope is None and path is None and readers[name]:
+                scope, way = most(readers[name])
+            if scope is not None:
+                for operand in re.findall(r"%([\w.\-]+)", operands):
+                    readers[operand][scope, way] += 1
+            kernel = None
+            if 'custom_call_target="tpu_custom_call"' in text:
+                kernel = re.sub(r"(\.\d+)+$", "", name)
+            out[name] = (scope, way, kernel)
+    return out
 
 
 class Histogram:

@@ -769,7 +769,16 @@ def test_device_gaps_find_their_step_span_and_prefill_by_hand():
     assert rep["idle_ms"] == pytest.approx(3.65)
     assert rep["gaps_ms"] == rep["named_ms"] == pytest.approx(3.6)
     assert rep["clock_skew_ms"] == pytest.approx(0.03) and rep["steps"] == 2
+    # the idle time under the INNERMOST span open when each gap began,
+    # largest first: every gap with a span has one, so it names at least
+    # what ``named_ms`` does
+    assert list(rep["by_span"].items()) == [
+        ("serve.chunk_feed", pytest.approx(2.8)),
+        ("serve.sample", pytest.approx(0.5)),
+        ("bench.observe", pytest.approx(0.3))]
+    assert sum(rep["by_span"].values()) == pytest.approx(rep["named_ms"])
     text = observe.render_gaps_text(rep)
+    assert "serve.chunk_feed 2.800, serve.sample 0.500" in text
     assert "98.6 % of the idle time" in text
     assert "serve.step > serve.prefill_advance > serve.chunk_feed" in text
     assert "[chunk rid 7 pos 16: 9 tokens in 16 rows" in text
@@ -821,6 +830,28 @@ def test_a_profiled_run_puts_records_and_trace_on_one_clock(
     assert observe.main(["--gaps", str(tmp_path / "trace"), str(path)]) == 2
     assert "device gaps over the threshold: 0" in capsys.readouterr().out
     assert observe.main(["--gaps", str(tmp_path / "none"), str(path)]) == 2
+    # no TPU's plane: nothing for ``--device`` to split, and it says so
+    assert trace["device"] is None and trace["modules"] == []
+    assert observe.device_report(trace, rec.records) is None
+    assert observe.main(["--device", str(tmp_path / "trace"), str(path)]) == 2
+    assert "no .xplane.pb with a TPU's plane" in capsys.readouterr().err
+    # but the trace holds the HLO of every program that ran under it, in
+    # the plane ``ProfileData`` does not list (``/host:metadata``), under
+    # the name the ``XLA Modules`` line would give its executions: the
+    # decode step's instructions find their scopes from THAT text
+    progs = observe.read_gaps_trace(str(tmp_path / "trace"),
+                                    programs=True)["programs"]
+    # (every program the process holds compiled when the trace stops:
+    # other tests' engines may have left decode steps of other shapes)
+    decode = [name for name in progs
+              if name.startswith("jit__paged_decode_step(")]
+    assert decode and all(name.endswith(")") for name in decode)
+    assert any({"attn.kv", "lm.head", "lm.embed"}
+               <= {scope for scope, _, _ in progs[name].values()}
+               for name in decode)
+    sampler = next(v for k, v in progs.items()
+                   if k.startswith("jit__sample_dynamic("))
+    assert {scope for scope, _, _ in sampler.values()} >= {"lm.sample"}
 
 
 def test_ctx_pages_by_hand_on_a_two_slot_batch(params):
@@ -857,6 +888,14 @@ def test_ctx_pages_by_hand_on_a_two_slot_batch(params):
 def test_one_chip_layer_carries_the_stage_scopes():
     """The lowered one-chip ``moe_layer`` (XLA path) names the paper's
     four stages, and the shared experts, in its operations' op_name."""
+    p, x, layer = _one_chip_layer()
+    text = jax.jit(layer).lower(p, x).compile().as_text()
+    for stage in ("moe.gate", "moe.dispatch", "moe.expert", "moe.combine",
+                  "moe.shared"):
+        assert f"/{stage}/" in text, stage
+
+
+def _one_chip_layer():
     from flashmoe_tpu.models.reference import init_moe_params
     from flashmoe_tpu.ops.moe import moe_layer
 
@@ -866,11 +905,273 @@ def test_one_chip_layer_carries_the_stage_scopes():
                     dtype=jnp.float32, param_dtype=jnp.float32)
     p = init_moe_params(jax.random.PRNGKey(0), cfg)
     x = jnp.ones((32, 64), jnp.float32)
-    text = jax.jit(lambda p, x: moe_layer(
-        p, x, cfg, use_pallas=False).out).lower(p, x).compile().as_text()
-    for stage in ("moe.gate", "moe.dispatch", "moe.expert", "moe.combine",
-                  "moe.shared"):
-        assert f"/{stage}/" in text, stage
+    return p, x, lambda p, x: moe_layer(p, x, cfg, use_pallas=False).out
+
+
+def test_program_scopes_of_the_one_chip_layer_forward_and_backward():
+    """``telemetry.program_scopes`` on what the compiler built of the
+    one-chip ``moe_layer``: every stage is some instruction's scope, all
+    ``fwd``; under ``jax.grad`` the stages come back as ``bwd`` too (jax
+    marks a backward's operations ``transpose(...)``)."""
+    p, x, layer = _one_chip_layer()
+    stages = {"moe.gate", "moe.dispatch", "moe.expert", "moe.combine",
+              "moe.shared"}
+    fwd = telemetry.program_scopes(
+        jax.jit(layer).lower(p, x).compile().as_text())
+    assert {scope for scope, _, _ in fwd.values()} >= stages
+    assert {way for _, way, _ in fwd.values()} == {"fwd"}
+    assert {kernel for _, _, kernel in fwd.values()} == {None}
+    grad = telemetry.program_scopes(jax.jit(jax.grad(
+        lambda p, x: jnp.sum(layer(p, x)))).lower(p, x).compile().as_text())
+    both = {(scope, way) for scope, way, _ in grad.values()}
+    assert ("moe.expert", "bwd") in both and ("moe.expert", "fwd") in both
+    assert ("moe.gate", "bwd") in both
+
+
+_HLO_BY_HAND = """HloModule jit_f, is_scheduled=true, entry_computation_layout={(f32[8,128]{1,0})->f32[8,128]{1,0}}
+
+%fused_computation.1 (p0: f32[8,128]) -> f32[8,128] {
+  %p0 = f32[8,128]{1,0} parameter(0)
+  %a.1 = f32[8,128]{1,0} add(%p0, %p0), metadata={op_name="jit(f)/moe.gate/add"}
+  %a.2 = f32[8,128]{1,0} multiply(%a.1, %a.1), metadata={op_name="jit(f)/moe.gate/mul"}
+  ROOT %a.3 = f32[8,128]{1,0} negate(%a.2), metadata={op_name="jit(f)/moe.expert/neg"}
+}
+
+%fused_computation.2 (p1: f32[8,128]) -> f32[8,128] {
+  %p1 = f32[8,128]{1,0} parameter(0)
+  ROOT %b.1 = f32[8,128]{1,0} exponential(%p1), metadata={op_name="jit(f)/exp"}
+}
+
+%body.5 (t: (s32[], f32[8,128])) -> (s32[], f32[8,128]) {
+  %t = (s32[], f32[8,128]{1,0}) parameter(0)
+  %g.1 = f32[8,128]{1,0} get-tuple-element(%t), index=1
+  %fm_ffn_fwd.13 = f32[8,128]{1,0} custom-call(%g.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/moe.expert.3/while/body/fm_ffn_fwd/pallas_call"}
+  %g.0 = s32[] get-tuple-element(%t), index=0
+  ROOT %tuple.7 = (s32[], f32[8,128]{1,0}) tuple(%g.0, %fm_ffn_fwd.13)
+}
+
+%cond.6 (t.1: (s32[], f32[8,128])) -> pred[] {
+  %t.1 = (s32[], f32[8,128]{1,0}) parameter(0)
+  %g.2 = s32[] get-tuple-element(%t.1), index=0
+  %c.9 = s32[] constant(4)
+  ROOT %lt.1 = pred[] compare(%g.2, %c.9), direction=LT, metadata={op_name="jit(f)/moe.expert.3/while/cond/lt"}
+}
+
+ENTRY %main.9 (x: f32[8,128]) -> f32[8,128] {
+  %x = f32[8,128]{1,0:T(8,128)} parameter(0), metadata={op_name="x"}
+  %copy-start.1 = (f32[8,128]{1,0:T(8,128)S(1)}, f32[8,128]{1,0:T(8,128)}, u32[]{:S(2)}) copy-start(%x)
+  %copy-done.1 = f32[8,128]{1,0:T(8,128)S(1)} copy-done(%copy-start.1)
+  %fusion.1 = f32[8,128]{1,0} fusion(%copy-done.1), kind=kLoop, calls=%fused_computation.1
+  %fusion.2 = f32[8,128]{1,0} fusion(%fusion.1), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(f)/exp"}
+  %c.0 = s32[] constant(0)
+  %tuple.1 = (s32[], f32[8,128]{1,0}) tuple(%c.0, %fusion.2)
+  %while.4 = (s32[], f32[8,128]{1,0}) while(%tuple.1), condition=%cond.6, body=%body.5, metadata={op_name="jit(f)/moe.expert.3/while"}
+  %g.9 = f32[8,128]{1,0} get-tuple-element(%while.4), index=1
+  %dot.3 = f32[8,128]{1,0} multiply(%g.9, %g.9), metadata={op_name="jit(f)/train.forward_backward/transpose(jvp(moe.combine))/mul"}
+  ROOT %add.8 = f32[8,128]{1,0} add(%dot.3, %x), metadata={op_name="jit(f)/jit(helper)/add"}
+}
+"""
+
+
+@pytest.mark.parametrize("instruction, want", [
+    # no metadata of its own: two of its three fused instructions
+    ("fusion.1", ("moe.gate", "fwd", None)),
+    # its own path names no registered span, nor do its fused ones
+    ("fusion.2", (None, "fwd", None)),
+    # the compiler's own (no ``op_name``: a weight's prefetch): what
+    # READS it says whose time it is, through the pair's other half
+    ("copy-done.1", ("moe.gate", "fwd", None)),
+    ("copy-start.1", ("moe.gate", "fwd", None)),
+    # a chunk's suffix folds onto the registered base
+    ("while.4", ("moe.expert", "fwd", None)),
+    # a ``while``'s body and condition are on the ``XLA Ops`` line
+    ("fm_ffn_fwd.13", ("moe.expert", "fwd", "fm_ffn_fwd")),
+    ("lt.1", ("moe.expert", "fwd", None)),
+    # the INNERMOST registered name, and jax's mark of a backward
+    ("dot.3", ("moe.combine", "bwd", None)),
+    # a path of the program with no registered span stays unscoped,
+    # whoever reads it
+    ("add.8", (None, "fwd", None)),
+    # a fused computation's instructions are NOT on the line
+    ("a.1", None),
+])
+def test_program_scopes_by_hand(instruction, want):
+    assert telemetry.program_scopes(_HLO_BY_HAND).get(instruction) == want
+
+
+def _device_trace_by_hand():
+    """Times in units of 10 us.  ``jit_f`` ran at two shapes (two
+    fingerprints): (11) three times, the first clipped by the trace's
+    start (one operation of its four), (22) once; ``jit_g(33)`` once, with
+    an instruction its program does not hold.  Both ``jit_f``s and
+    ``jit_g`` own a ``%fusion.1``.  A ``while`` of 600 us encloses a
+    kernel of 400: 200 its own."""
+    u = 10_000
+    f11 = {"fusion.1": ("moe.gate", "fwd", None),
+           "while.2": ("moe.expert", "fwd", None),
+           "fm_ffn_fwd.3": ("moe.expert", "fwd", "fm_ffn_fwd"),
+           "copy.4": (None, "fwd", None)}
+    f22 = {"fusion.1": ("moe.combine", "fwd", None),
+           "fm_ffn_fwd.3": ("moe.expert", "fwd", "fm_ffn_fwd")}
+    g33 = {"fusion.1": ("lm.head", "fwd", None)}
+    fusion = "%fusion.1 = f32[8,128]{1,0:T(8,128)} fusion(f32[8,128] %p)"
+    loop = ("%while.2 = (s32[]{:T(128)}, f32[8,128]{1,0}) while((s32[], "
+            "f32[8,128]) %tuple.1), condition=%c, body=%b")
+    kernel = "%fm_ffn_fwd.3 = f32[8,128]{1,0} custom-call(f32[8,128] %g)"
+    copy = "%copy.4 = f32[8]{0:T(128)} copy(f32[8]{0} %q)"
+    modules = [(0, 30, "jit_f(11)"), (100, 100, "jit_f(11)"),
+               (200, 120, "jit_f(11)"), (330, 50, "jit_f(22)"),
+               (400, 50, "jit_g(33)")]
+    ops = [(10, 20, copy),
+           (100, 30, fusion), (130, 60, loop), (140, 40, kernel),
+           (190, 10, copy),
+           (200, 40, fusion), (240, 60, loop), (250, 40, kernel),
+           (300, 20, copy),
+           (330, 20, fusion), (350, 30, kernel),
+           (400, 20, fusion),
+           (420, 30, "%mystery.9 = f32[4]{0} add(f32[4] %a, f32[4] %b)")]
+    spans = [(20 * u, 100 * u, "serve.step", 0),
+             (25 * u, 60 * u, "serve.chunk_feed", None),
+             (315 * u, 10 * u, "bench.observe", None)]
+    scale = lambda evs: sorted((t * u, d * u, n) for t, d, n in evs)
+    ops = scale(ops)
+    return {"file": "by hand", "base": 0, "device": "/device:TPU:0",
+            "ops": ops, "busy": [op[:2] for op in ops],
+            "modules": scale(modules), "spans": spans,
+            "programs": {"jit_f(11)": f11, "jit_f(22)": f22,
+                         "jit_g(33)": g33}}
+
+
+def test_device_report_by_hand():
+    """``observe.device_report`` on a hand-made trace, every number
+    worked by hand (ms): busy 0.2 + 1.0 + 1.2 + 0.5 + 0.5 = 3.4 of a
+    window of 4.4; the instruction no program holds 0.3 (matched 3.1 of
+    3.4); ``copy.4`` unscoped in three executions 0.2 + 0.1 + 0.2 (under a
+    scope 2.6 of 3.4).  ``jit_f(11)``: the clipped execution apart; of the
+    two whole ones ``moe.gate`` 0.3 / 0.4, ``moe.expert`` 0.2 of the
+    ``while`` itself + 0.4 of the kernel in it = 0.6 / 0.6, unscoped 0.1 /
+    0.2: medians 0.35 + 0.6 + 0.15 = 1.1 = the median execution."""
+    from flashmoe_tpu import observe
+
+    rep = observe.device_report(_device_trace_by_hand())
+    assert rep["busy_ms"] == pytest.approx(3.4)
+    assert rep["window_ms"] == pytest.approx(4.4)
+    assert rep["idle_ms"] == pytest.approx(1.0)
+    assert rep["matched_share"] == pytest.approx(3.1 / 3.4)
+    assert rep["scoped_share"] == pytest.approx(2.6 / 3.4)
+    assert rep["outside_programs_ms"] == 0.0
+    by = {p["program"]: p for p in rep["programs"]}
+    assert list(by) == ["jit_f(11)", "jit_f(22)", "jit_g(33)"]  # by time
+    f11 = by["jit_f(11)"]
+    assert (f11["executions"], f11["clipped"]) == (2, 1)
+    assert f11["clipped_ms"] == pytest.approx(0.2)
+    assert f11["ms"] == pytest.approx([1.0, 1.1, 1.2])
+    assert f11["busy_ms"] == pytest.approx(2.4)
+    assert f11["share"] == pytest.approx(2.4 / 3.4) and f11["ops"] == 4
+    assert [(r["scope"], r["pass"], pytest.approx(r["ms"]))
+            for r in f11["scopes"]] == [
+        ("moe.expert", "fwd", 0.6), ("moe.gate", "fwd", 0.35),
+        ("(unscoped)", "", 0.15)]
+    # the parts sum to the execution
+    assert f11["rows_ms"] == pytest.approx(f11["ms"][1])
+    assert f11["kernels"] == [{"kernel": "fm_ffn_fwd",
+                               "ms": pytest.approx(0.4), "calls": 1}]
+    assert f11["unscoped"] == [{"op": "copy", "shape": "f32[8]",
+                                "ms": pytest.approx(0.15), "n": 1.0}]
+    assert f11["unmatched"] == []
+    # the other shape of the same function is a program of its own, and
+    # its ``fusion.1`` is not the first's
+    f22 = by["jit_f(22)"]
+    assert [(r["scope"], pytest.approx(r["ms"])) for r in f22["scopes"]] \
+        == [("moe.expert", 0.3), ("moe.combine", 0.2)]
+    g33 = by["jit_g(33)"]
+    assert (g33["executions"], g33["clipped"]) == (1, 0)   # last, but whole
+    assert [(r["scope"], pytest.approx(r["ms"])) for r in g33["scopes"]] \
+        == [("(unmatched)", 0.3), ("lm.head", 0.2)]
+    assert g33["unmatched"] == [{"op": "mystery", "shape": "f32[4]",
+                                 "ms": pytest.approx(0.3), "n": 1.0}]
+    # every program's rows sum to the busy time, the clipped one apart
+    assert sum(p["rows_ms"] * p["executions"] for p in rep["programs"]) \
+        + f11["clipped_ms"] == pytest.approx(rep["busy_ms"])
+    assert rep["kernels"] == {"fm_ffn_fwd": {"ms": pytest.approx(1.1),
+                                             "calls": 3}}
+    # the idle side: gaps of 0.7 (under a chunk's feed), 0.1 (the caller's)
+    # and 0.2 ms (no span); no ``serve_step`` record: none has a step
+    idle = rep["idle"]
+    assert idle["idle_ms"] == pytest.approx(1.0) and "gaps" not in idle
+    assert list(idle["by_span"].items()) == [
+        ("serve.chunk_feed", pytest.approx(0.7)),
+        ("(no span)", pytest.approx(0.2)),
+        ("bench.observe", pytest.approx(0.1))]
+    assert idle["named_ms"] == 0.0
+    text = observe.render_device_text(rep)
+    assert "91.18 % of the busy time matched" in text
+    assert "76.47 % under a registered scope" in text
+    assert "jit_f(11): 2 whole executions (1 clipped, 0.200 ms)" in text
+    assert "rows sum to 1.100 ms" in text
+    assert "(unmatched)    0.300 ms in 1: mystery -> f32[4]" in text
+    assert "fm_ffn_fwd 1.100 ms in 3 calls" in text
+
+
+def test_device_report_without_a_program_line_or_a_program():
+    """A trace whose programs' HLO is not there (``programs`` not read)
+    still splits by program and says every operation is unmatched; one
+    with no ``XLA Modules`` line is one nameless program."""
+    from flashmoe_tpu import observe
+
+    trace = _device_trace_by_hand()
+    del trace["programs"]
+    rep = observe.device_report(trace)
+    assert rep["matched_share"] == 0.0 and rep["scoped_share"] == 0.0
+    assert rep["busy_ms"] == pytest.approx(3.4)
+    assert {r["scope"] for p in rep["programs"] for r in p["scopes"]} \
+        == {"(unmatched)"}
+    trace["modules"] = []
+    rep = observe.device_report(trace)
+    assert [p["program"] for p in rep["programs"]] == ["(no program)"]
+    assert rep["programs"][0]["busy_ms"] == pytest.approx(3.4)
+
+
+def test_every_mixer_has_a_registered_scope_and_its_two_forms():
+    from flashmoe_tpu.config import STATE_MIXERS
+    from flashmoe_tpu.ops.attention import MIXER_SPANS
+
+    assert set(MIXER_SPANS) == {"mha", "mla", *STATE_MIXERS}
+    for part in MIXER_SPANS.values():
+        assert {part, part + "_prefill", part + "_decode"} <= set(SPAN_NAMES)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "chunk"])
+def test_the_engines_programs_stand_under_their_parts_scopes(params,
+                                                             program):
+    """What ``observe --device`` will find in a trace of the toy engine's
+    programs (compiled here for the CPU, where every span takes the
+    gather arm): the mixer's part under ``attn.kv`` with the arm's own
+    scope inside it, the mixture's under ``ffn.moe`` and its stages, the
+    embedding and the head under theirs; the matrix products all under a
+    scope."""
+    from flashmoe_tpu.serving.kvcache import init_paged_cache
+
+    cache = init_paged_cache(CFG, SERVE.num_pages, SERVE.page_size,
+                             SERVE.max_batch)
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)
+    lowered = {
+        "decode": lambda: eng._paged_decode_step.lower(
+            params, CFG, cache, i32(4), i32(4, 2), i32(4)),
+        "prefill": lambda: eng._prefill_padded.lower(
+            params, CFG, i32(1, 16), jnp.int32(9)),
+        "chunk": lambda: eng._prefill_chunk.lower(
+            params, CFG, cache, i32(1, 8), i32(2), i32(1), jnp.int32(0),
+            jnp.int32(7), jnp.int32(0)),
+    }[program]()
+    text = lowered.compile().as_text()
+    found = telemetry.program_scopes(text)
+    scopes = {scope for scope, _, _ in found.values()}
+    assert {"attn.kv", "attn.kv_prefill", "lm.embed", "lm.head", "ffn.moe",
+            "moe.gate", "moe.expert"} <= scopes
+    assert "attn.kv_decode" not in scopes       # the kernel's arm: a TPU's
+    products = [name for name in found if name.startswith(("dot", "conv"))]
+    assert products and all(found[name][0] for name in products)
 
 
 def test_train_step_scopes_and_records_say_which_step_compiled():
