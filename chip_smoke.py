@@ -538,7 +538,8 @@ def _sdar_case(params, cfg, seed):
     """The engine's block-diffusion path at SDAR-30B-A3B-Chat's widths, ONE
     published layer: tokens and reveal steps against ``generate_blocks``
     (a request at a time over the dense cache), and the kernel's arm
-    (``fm_paged_decode`` at a span of one block under the block mask)
+    (``fm_paged_decode`` at a span of two blocks under the block mask:
+    a block's commit beside the next block's first step)
     against the gather arm.  ``float32`` under matmul precision "highest"
     must agree token for token; ``bfloat16`` reports how many do (a
     flipped choice compounds through the blocks that follow: the cell's
@@ -563,17 +564,18 @@ def _sdar_case(params, cfg, seed):
             out = engine.run(reqs, arrivals)
             return (out, dict(engine.reveal_steps),
                     engine.metrics.counters.get("serve.decode_kernel_steps",
-                                                0))
+                                                0),
+                    engine.metrics.counters.get("serve.fused_commits", 0))
         finally:
             engine.close()
 
     with (jax.default_matmul_precision("highest") if strict
           else contextlib.nullcontext()):
         t0 = time.perf_counter()
-        out, steps, kernel_steps = run()
+        out, steps, kernel_steps, fused = run()
         run_s = time.perf_counter() - t0
         with gather_arm():
-            g_out, g_steps, g_kernel_steps = run()
+            g_out, g_steps, g_kernel_steps, _ = run()
         want = {}
         for r in reqs:
             toks, at = generate_blocks(
@@ -588,7 +590,7 @@ def _sdar_case(params, cfg, seed):
     step_eq = sum(same(steps[r.rid], want[r.rid][1]) for r in reqs)
     arm_eq = sum(same(out[r.rid], g_out[r.rid]) - len(r.prompt)
                  for r in reqs)
-    ok = (kernel_steps > 0 and g_kernel_steps == 0
+    ok = (kernel_steps > 0 and g_kernel_steps == 0 and fused > 0
           and all(len(out[r.rid]) == len(r.prompt) + r.max_new_tokens
                   for r in reqs)
           and (not strict or (tok_eq == step_eq == arm_eq == new)))
@@ -603,7 +605,7 @@ def _sdar_case(params, cfg, seed):
           "new_tokens": new, "tokens_equal_generate": tok_eq,
           "reveal_steps_equal_generate": step_eq,
           "tokens_equal_gather_arm": arm_eq,
-          "kernel_steps": kernel_steps,
+          "kernel_steps": kernel_steps, "fused_commits": fused,
           "gather_arm_kernel_steps": g_kernel_steps,
           "run_s_with_compile": round(run_s, 3)})
     return ok

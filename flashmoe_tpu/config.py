@@ -241,8 +241,9 @@ class MoEConfig:
     # block in both directions: ``attn_block``), a block starts as the
     # ``mask_token_id`` token's embedding wherever the prompt's tail does
     # not open it, and is revealed over denoising forwards, then committed
-    # (``models/generate.generate_blocks``, the serving engine's
-    # ``_paged_denoise_step``).  A power of two; every layer a K/V layer.
+    # (``models/generate.generate_blocks``; the serving engine's
+    # ``_paged_denoise_step`` commits it beside the next block's first
+    # forward).  A power of two; every layer a K/V layer.
     block_length: int = 0
     mask_token_id: int = 0
     # scalar factors a published config states (the granitemoehybrid

@@ -482,10 +482,11 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
     if pools is None:
         return kv_attend(layer, q, *span, pos, block, scale), pools, span
     rows, page, width = pools[0].shape[2:]       # the heads as stored
-    # (under a block mask the kernel knows a span that is ONE block: a
+    # (under a block mask the kernel knows a span of ONE block or TWO: a
     # longer span over a cache whose page it fits, generate()'s prefill
     # over a short dense cache, keeps the gather arm)
-    if (write[1] is not None and block in (1, q.shape[1])
+    if (write[1] is not None
+            and (block == 1 or q.shape[1] in (block, 2 * block))
             and kv_attention_arm(q.shape[1], page, rows, width,
                                  pools[0].dtype) == "paged_kernel"):
         with trace_span("attn.kv_decode"):
@@ -743,14 +744,16 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
          jnp.zeros((nkv, r_pad, dv), jnp.float32)))
 
     # the span's own rows, causal among themselves: query row r (column
-    # r // rep of the span) sees span row c iff c * rep <= r; a span that
-    # IS one block of a block-causal model (``block`` == t, its first row
-    # at a whole block) sees all of itself
+    # r // rep of the span) sees span row c iff c * rep <= r; under a
+    # block-causal model (the span whole blocks, its first row at a whole
+    # block) iff c's block is not past its own: one block sees all of
+    # itself, of two the first never sees the second
     new = [span[0] for span in spans]                       # [N_kv, Tp, D]
     s = jnp.einsum("hrd,hcd->hrc", q, new[0], **f32) * scale
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    seen = col < t if block > 1 else (col * rep <= row) & (col < t)
+    seen = ((col // block <= row // (rep * block)) if block > 1
+            else (col * rep <= row)) & (col < t)
     _, l, acc = attend(carry, jnp.where(seen, s, NEG_INF), values(new[-1]))
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
@@ -810,9 +813,10 @@ def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
     each slot's first span row, the pool holding positions before it;
     write: ``(page_ids, rows)``, each [B, T], where the span's rows go
     (consecutive rows: at most two pages a slot); scale: of the scores,
-    ``D ** -0.5`` unless given; block: 1, or the span IS one block of a
-    block-causal model (T == block, ``pos`` a whole block: every row of
-    the span sees all of it).  Returns (the heads' outputs
+    ``D ** -0.5`` unless given; block: 1, or the span is one block or two
+    of a block-causal model (T == block or 2 * block, ``pos`` a whole
+    block: a row of the span sees its own block whole and the block
+    before it, never the block after).  Returns (the heads' outputs
     [B, T, N * Dv], the pools).  What :func:`store_kv`,
     :func:`gather_ctx` and the softmax of :func:`kv_attend` give (or
     :func:`store_latent`, :func:`gather_latent` and the absorbed
@@ -821,10 +825,10 @@ def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
     the product with the values."""
     n_pools, dt = len(pools), pools[0].dtype
     nkv, page, width = pools[0].shape[2:]
-    if block > 1 and q.shape[1] != block:
+    if block > 1 and q.shape[1] not in (block, 2 * block):
         raise NotImplementedError(
             f"a span of {q.shape[1]} rows under a block mask of {block}: "
-            f"the kernel's in-span mask knows a span that is ONE block")
+            f"the kernel's in-span mask knows a span of ONE block or TWO")
     pack = width // q.shape[-1]
     if pack > 1:
         # a pool of packed heads: the kernel is handed rows of whole

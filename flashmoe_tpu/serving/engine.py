@@ -31,8 +31,9 @@ slowest member:
 A model that generates by diffusion over blocks (``cfg.block_length``)
 takes the same path with another step: a slot's step is a BLOCK of
 positions scored together (:func:`_paged_denoise_step`), revealed over
-``ServeConfig.denoise_steps`` forwards and then committed, and its tokens
-are delivered a block at a time (see :meth:`ServingEngine._denoise`).
+``ServeConfig.denoise_steps`` forwards and committed in the forward that
+opens the next block, and its tokens are delivered a block at a time (see
+:meth:`ServingEngine._denoise`).
 
 Everything host-side is a pure function of the submitted requests and
 their arrival steps, and the page allocator is LIFO — so a seeded drill
@@ -382,7 +383,7 @@ def _prefill_chunk(params, cfg: MoEConfig, pools, chunk_toks,
 
 
 def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
-               positions, mixture=None, pad_token=None):
+               positions, mixture=None, pad_token=None, writes=None):
     """A span of T tokens a slot through the layers, over the paged
     cache: the body of the decode (T = 1) and verify programs and of
     their EP-sharded twins (which pass ``mixture``).  toks: [B, T];
@@ -398,12 +399,16 @@ def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
     Span positions past the gathered context (a slot drafted into its
     context ceiling) route their writes to the scratch page and produce
     garbage columns the host never reads — the host truncates drafts to
-    fit, this is the in-graph belt-and-suspenders."""
+    fit, this is the in-graph belt-and-suspenders.  So do the columns
+    outside ``writes`` ([B, T] bool, the denoise program's dead half;
+    None: every column writes): they leave a slot's pages alone."""
     page = pools.page_size
     ntab = block_tables.shape[1]
     pos = (positions[:, None]
            + jnp.arange(toks.shape[1], dtype=jnp.int32)[None, :])  # [B, T]
     valid = pos < ntab * page
+    if writes is not None:
+        valid &= writes
     page_ids = jnp.where(
         valid, jnp.take_along_axis(
             block_tables, jnp.clip(pos // page, 0, ntab - 1), axis=1),
@@ -466,40 +471,65 @@ def _paged_verify_step(params, cfg: MoEConfig, pools, toks,
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "pad_token", "rule",
-                                             "threshold"))
+                                             "threshold", "halves"))
 def _paged_denoise_step(params, cfg: MoEConfig, pools, state, ctl,
                         block_tables, pad_token=None,
                         rule: str = "low_confidence_static",
-                        threshold: float = 0.9):
+                        threshold: float = 0.9, halves: int = 2):
     """One forward of every slot's OPEN BLOCK (a model that generates by
-    diffusion over blocks, L = ``cfg.block_length``): the span path at
-    T = L under the block mask, the head on every row, then on the device
-    the greedy choice, its confidence and the rows to reveal
-    (``generate.reveal_rows``).  Denoising and committing are this ONE
-    program: every forward writes the block's K/V rows in place, and the
-    commit is the forward whose rows are all revealed (it reveals none),
-    so slots at different steps of their blocks share a launch.
+    diffusion over blocks, L = ``cfg.block_length``) and, in the same
+    span, of the clean block BEFORE it: the span path at T = 2 L under
+    the block mask, ``[half 0 | half 1]`` from the slot's length, the
+    head on L rows a slot, then on the device the greedy choice, its
+    confidence and the rows to reveal (``generate.reveal_rows``).
+    Denoising and committing are this ONE program: every forward writes
+    its live rows' K/V in place, and the commit is the forward of a block
+    whose rows are all revealed, so slots at different steps of their
+    blocks share a launch.  What the halves hold is the host's to say:
+
+    - the open block in half 0 (a step of it, or the commit ALONE: it
+      reveals none) and half 1 DEAD: fed ``pad_token``, written to the
+      scratch page, seen by no live row (under the block mask half 0
+      never sees half 1) and read by nobody;
+    - the COMMIT of the state's block, whole, in half 0 and the NEXT
+      block, all ``[MASK]``, at its step 0 in half 1 (``half`` 1): half
+      0's rows see the cache and the clean block, as a commit alone does,
+      half 1's the cache, the clean block's K/V beside them in the span,
+      and themselves, as the step after a commit does.
 
     state: [B, 3, L] int32, what the last launch left of each slot's
     block: its tokens, which rows are masked, the step that revealed each
     row (-1: the prompt's tail, or masked still); the feed of this launch
-    where it lies.  ctl: [B, 4 + L] int32 from the host, a row a slot:
-    the block's first position, the rows to reveal (0: a commit, or a row
-    that is not fed), the denoising step's index, and ``first``: -1 keeps
-    the slot's state; >= 0 OPENS a block whose first ``first`` rows are
-    the tokens in columns 4.., the others masked.  block_tables: [B, n].
-    A row whose table is all scratch is fed ``pad_token`` and keeps its
-    state.  Returns (state, pools, what the layers counted)."""
+    where it lies.  ctl: [B, 5 + L] int32 from the host, a row a slot:
+    the position of half 0's first row, the rows to reveal (0: a commit
+    alone, or a row that is not fed), the denoising step's index,
+    ``first``: -1 keeps the slot's state; >= 0 OPENS a block whose first
+    ``first`` rows are the tokens in columns 5.., the others masked, and
+    ``half``: the half that block stands in and the head reads (1: half
+    0 commits the state's block).  block_tables: [B, n].  A row whose
+    table is all scratch is fed ``pad_token`` and keeps its state.
+    ``halves`` 1 is the span of ONE block (``half`` is 0 throughout): the
+    engine's where two blocks would cost the kernel's arm.  Returns
+    (state: the block the head read, pools, what the layers counted)."""
     bl = cfg.block_length
-    pos, n, step, first = (ctl[:, j] for j in range(4))
+    pos, n, step, first, half = (ctl[:, j] for j in range(5))
     opened = (first >= 0)[:, None]
-    toks = jnp.where(opened, ctl[:, 4:], state[:, 0])
+    toks = jnp.where(opened, ctl[:, 5:], state[:, 0])
     masked = jnp.where(opened, jnp.arange(bl)[None, :] >= first[:, None],
                        state[:, 1] > 0)
     steps = jnp.where(opened, -1, state[:, 2])
     feed = jnp.where(masked, jnp.int32(cfg.mask_token_id), toks)
+    writes = None
+    if halves == 2:
+        fused = (half == 1)[:, None]
+        writes = (jnp.arange(2 * bl) < bl)[None, :] | fused
+        feed = jnp.concatenate(
+            [jnp.where(fused, state[:, 0], feed),
+             jnp.where(fused, feed, jnp.int32(pad_token or 0))], axis=1)
     x, pools, counted = _span_step(params, cfg, pools, feed, block_tables,
-                                   pos, pad_token=pad_token)
+                                   pos, pad_token=pad_token, writes=writes)
+    if halves == 2:
+        x = jnp.where(fused[:, :, None], x[:, bl:], x[:, :bl])
     x0, reveal = reveal_rows(
         lm_logits_span(params, cfg, x), masked, n, rule=rule,
         threshold=threshold, mask_token_id=cfg.mask_token_id)
@@ -524,7 +554,7 @@ _INPLACE = {
     for fn, *static in ((_prefill_chunk,), (_paged_decode_step, "pad_token"),
                         (_paged_verify_step,),
                         (_paged_denoise_step, "pad_token", "rule",
-                         "threshold"))}
+                         "threshold", "halves"))}
 
 #: ``store_prefill`` with the pool donated, as ONE program: an admission
 #: writes a prompt's pages into the pool where it lies (called eagerly, the
@@ -990,6 +1020,16 @@ class ServingEngine:
             jnp.zeros((self.serve.max_batch, 3, cfg.block_length),
                       jnp.int32) if cfg.block_length else None)
         self._block_counts = None
+        # blocks a slot's launch spans: TWO (a whole block's commit rides
+        # beside the next block's first step: _denoise) where the longer
+        # span keeps the attention's arm, ONE where it would cost the
+        # kernel's (two blocks not under a page, on a TPU)
+        self._halves = 1
+        if cfg.block_length:
+            pools, heads, row = cfg.kv_pool_rows
+            arm = lambda t: attention.kv_attention_arm(
+                t, self.serve.page_size, heads, row, cfg.dtype, pools)
+            self._halves += arm(2 * cfg.block_length) == arm(cfg.block_length)
         self.reveal_steps: dict[int, list] = {}
         self.queue: deque = deque()       # (arrival_step, _Slot-seed)
         self.slots: list[_Slot | None] = [None] * self.serve.max_batch
@@ -1541,34 +1581,39 @@ class ServingEngine:
         resumed prompt carries its earlier output)."""
         return len(s.req.prompt) - len(s.orig.prompt) + len(s.emitted)
 
-    def _next_page(self, s: _Slot, span: int = 0) -> int:
+    def _next_page(self, s: _Slot, span=0) -> int:
         """Index, in its table, of the page ``s`` writes its next row (and
-        ``span`` more) into, clamped to the table's width."""
+        ``span`` more: a number, or a function of the slot) into, clamped
+        to the table's width."""
+        if callable(span):
+            span = span(s)
         return min((s.length + span) // self.serve.page_size,
                    self.serve.max_pages_per_slot - 1)
 
-    def _growth_fits(self, rows) -> bool:
+    def _growth_fits(self, rows, span=0) -> bool:
         """Whether the pool, as it is, holds the next page of every slot
-        of ``rows`` that stands at a page edge: :meth:`_grow_pages` then
-        evicts nobody."""
+        of ``rows`` that stands at a page edge (or comes to one within
+        ``span`` more positions: :meth:`_next_page`'s): :meth:`_grow_pages`
+        then evicts nobody."""
         need = Counter()            # by page shard
         for i in rows:
             s = self.slots[i]
             need[self._shard_of(i)] += max(
-                0, self._next_page(s) + 1 - len(s.pages))
+                0, self._next_page(s, span) + 1 - len(s.pages))
         free = (self.pool.shard_free_pages if self.serve.ep_shards > 1
                 else lambda shard: self.pool.free_pages)
         return all(n <= free(shard) for shard, n in need.items())
 
-    def _grow_pages(self, rows, span: int = 0) -> None:
+    def _grow_pages(self, rows, span=0) -> None:
         """Allocate the next page for every slot of ``rows`` (decoding
         slots) whose write position crosses its allocated frontier,
         evicting the youngest request when the pool runs dry (the caller
         has read the step's tokens then: :meth:`_growth_fits`).  ``span``
-        extra positions (the verify step's drafted span) are pre-covered;
-        the target index clamps to the slot's table width — the host
-        truncates drafts to fit the context ceiling, and the verify graph
-        routes any residual over-the-edge write to the scratch page."""
+        extra positions (the verify step's drafted span, the rows of a
+        denoise launch: :meth:`_next_page`'s) are pre-covered; the target
+        index clamps to the slot's table width — the host truncates
+        drafts to fit the context ceiling, and the verify graph routes
+        any residual over-the-edge write to the scratch page."""
         shard = (self._shard_of if self.serve.ep_shards > 1
                  else lambda i: None)
         for i in rows:
@@ -2127,23 +2172,36 @@ class ServingEngine:
         block by a forward, and the blocks the LAST launch made whole are
         read and delivered.
 
-        By slot: no block open -> this launch opens one at ``length`` (the
-        prompt's tail first, on the slot's first block) and is its
-        denoising step 0; rows still masked -> the next denoising step,
-        which reveals ``L / denoise_steps`` of them; none masked -> the
-        COMMIT, the forward of the clean block whose K/V later blocks
-        read, after which ``length`` advances by L.  The last block of a
-        request (by count) is not committed: nothing reads it.  Under
-        ``low_confidence_static`` and ``sequential`` the host knows every
-        count without the tokens, so the launch is dispatched AHEAD of
-        the read-back of the state the launch before left (that state is
-        this launch's feed where it lies, and is not donated);
+        A slot's launch spans TWO blocks from ``length`` (``_halves``;
+        see :func:`_paged_denoise_step`).  By slot: no block open -> this
+        launch opens one at ``length`` (the prompt's tail first, on the
+        slot's first block) and is its denoising step 0; rows still
+        masked -> the next denoising step, which reveals
+        ``L / denoise_steps`` of them; none masked -> the block's COMMIT
+        (the forward of the clean block, whose K/V later blocks read) in
+        the span's first half and, beside it in the second, the NEXT
+        block opened, all ``[MASK]``, at its step 0: ``length`` advances
+        by L and the slot's block is the new one, so a block costs
+        ``denoise_steps`` launches and no launch reveals nothing.  The
+        last block of a request (by count) is not committed: nothing
+        reads it.  Where a span of two blocks would cost the attention
+        the kernel's arm (``_halves`` 1) a launch spans one, and the
+        commit is a launch of its own after which the next block opens.
+        Under ``low_confidence_static`` and ``sequential`` the host knows
+        every count without the tokens, so the launch is dispatched AHEAD
+        of the read-back of the state the launch before left (that state
+        is this launch's feed where it lies, the whole block its first
+        half commits included, and is not donated);
         ``low_confidence_dynamic`` reveals by a threshold, so the step
         reads the masked counts first, as does a step whose growth the
         pool cannot cover (the victim's prompt is rebuilt from what was
-        delivered).  An evicted slot's open block starts again.  Returns
-        (the slots decoding, whether the read-back followed the dispatch,
-        the tokens delivered)."""
+        delivered).  An evicted slot's open block starts again.  A block
+        is delivered in the step whose launch commits it, before that
+        launch when the growth may evict and straight after it otherwise,
+        so no eviction falls between the two: a victim resumes from a
+        prompt that holds every block it committed, and loses the one
+        forward its open block had.  Returns (the slots decoding, whether
+        the read-back followed the dispatch, the tokens delivered)."""
         sv, bl = self.serve, self.cfg.block_length
         self._phase("serve.decode_feed", beat="prefill")
         decoding = self._decoding()
@@ -2168,34 +2226,45 @@ class ServingEngine:
                    + bl - self.slots[i].block_first
                    >= self.slots[i].orig.max_new_tokens}
         active = [i for i in decoding if i not in closing]
-        ahead = bool(active and not dynamic and self._growth_fits(active))
+        fuses = lambda s: self._halves == 2 and s.block_masked == 0
+        reach = lambda s: (1 + fuses(s)) * bl - 1   # rows past ``length``
+        ahead = bool(active and not dynamic
+                     and self._growth_fits(active, reach))
         emitted_now = 0
         if not ahead and whole:
             emitted_now += self._deliver_blocks(whole, state, read)
             active = [i for i in active if self.slots[i] is not None]
         self._phase("serve.grow", beat="sample")
         if active:
-            self._grow_pages(active, span=bl - 1)
+            self._grow_pages(active, span=reach)
             active = [i for i in active if self.slots[i] is not None]
         if active:
             self._phase("serve.decode_feed")
-            ctl = np.zeros((sv.max_batch, 4 + bl), np.int32)
+            ctl = np.zeros((sv.max_batch, 5 + bl), np.int32)
             ctl[:, 3] = -1
             tables = np.full((sv.max_batch, sv.max_pages_per_slot),
                              SCRATCH_PAGE, np.int32)
-            longest, commits, fed_masked = 1, 0, 0
+            longest, commits, fused, fed_masked = 1, 0, 0, 0
             for i in active:
                 s = self.slots[i]
-                if s.block_masked is None:          # open a block
+                ctl[i, 0] = s.length
+                tables[i, :len(s.pages)] = s.pages
+                longest = max(longest, s.length + reach(s) + 1)
+                if fuses(s):
+                    # the whole block commits in the first half; the
+                    # next opens beside it (``block_first`` is the
+                    # committed block's until that is delivered)
+                    ctl[i, 3:5] = 0, 1
+                    fused += 1
+                    s.length += bl
+                    s.block_step, s.block_masked = 0, bl
+                elif s.block_masked is None:        # open a block
                     first = len(s.tail)
                     ctl[i, 3] = first
-                    ctl[i, 4:4 + first] = s.tail
+                    ctl[i, 5:5 + first] = s.tail
                     s.tail = ()
                     s.block_first, s.block_step = first, 0
                     s.block_masked = bl - first
-                ctl[i, 0] = s.length
-                tables[i, :len(s.pages)] = s.pages
-                longest = max(longest, s.length + bl)
                 if s.block_masked:
                     n = bl // self._steps_a_block(s)
                     ctl[i, 1], ctl[i, 2] = n, s.block_step
@@ -2206,7 +2275,7 @@ class ServingEngine:
                     else:
                         revealed += min(n, s.block_masked)
                         s.block_masked = max(0, s.block_masked - n)
-                else:                               # the commit
+                else:                               # the commit, alone
                     commits += 1
                     s.length += bl
                     s.block_masked = None
@@ -2220,15 +2289,17 @@ class ServingEngine:
                 "_paged_denoise_step"](
                 self.params, self.cfg, self.cache, state, jnp.asarray(ctl),
                 jnp.asarray(tables[:, :n_ctx]), pad_token=sv.pad_token,
-                rule=sv.reveal_rule, threshold=sv.reveal_threshold)
+                rule=sv.reveal_rule, threshold=sv.reveal_threshold,
+                halves=self._halves)
             self._counted = counted or None
             self._last_out = self._block_state
             # the step's account of it, while the device runs it
-            self._note_ctx(n_ctx, ctl[active, 0], bl)
-            self._block_counts = (len(active), revealed, commits,
-                                  fed_masked)
+            self._note_ctx(n_ctx, ctl[active, 0], self._halves * bl)
+            self._block_counts = (bl * (len(active) + fused), revealed,
+                                  commits, fed_masked, fused)
             self.metrics.count("serve.denoise_steps")
             self.metrics.count("serve.commit_rows", commits)
+            self.metrics.count("serve.fused_commits", fused)
             self.metrics.count("serve.revealed_tokens", revealed)
         if ahead:
             if whole:
@@ -2509,14 +2580,17 @@ class ServingEngine:
                         self.metrics.count("serve.zero_rows",
                                            counted["zero_rows"])
                 if self._block_counts is not None:
-                    # the denoise program's launch: L rows a slot fed,
-                    # the tokens it revealed, the slots whose forward was
-                    # a commit, the rows fed as [MASK]
-                    fed, revealed, commits, fed_masked = self._block_counts
+                    # the denoise program's launch: the live rows fed (L
+                    # a slot; 2 L where a commit rode beside the block it
+                    # opened), the tokens it revealed, the slots whose
+                    # forward revealed nothing (a commit alone), the rows
+                    # fed as [MASK], the slots that committed AND opened
+                    (span_rows, revealed, commits, fed_masked,
+                     fused) = self._block_counts
                     more.update(
-                        span_rows=fed * self.cfg.block_length,
-                        revealed=revealed, commit_rows=commits,
-                        masked_rows=fed_masked)
+                        span_rows=span_rows, revealed=revealed,
+                        commit_rows=commits, masked_rows=fed_masked,
+                        fused_rows=fused)
                 self.recorder.record(
                     kind="serve_decode", step=self.step_idx,
                     slots=n_decoding, ctx_pages=ctx_pages,

@@ -25,6 +25,7 @@ from flashmoe_tpu.models.transformer import init_params
 from flashmoe_tpu.ops import attention
 from flashmoe_tpu.serving import engine as eng
 from flashmoe_tpu.serving.engine import Request, ServeConfig, ServingEngine
+from flashmoe_tpu.utils.telemetry import Metrics
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -155,9 +156,12 @@ def test_engine_is_generate_blocks(params, steps, rule, chunk):
     assert engine.stats["tokens"] == NEW * len(PROMPTS)
     assert len(engine.stats["decode_buckets"]) >= 2   # two context buckets
     launches = [r for r in recs if r["kind"] == "serve_decode"]
-    # one launch held denoising AND committing slots
-    assert any(0 < r["commit_rows"] < r["slots"] for r in launches)
-    assert all(r["span_rows"] == 4 * r["slots"] for r in launches)
+    # one launch held a slot denoising alone AND one whose commit rode
+    # beside the block it opened; no forward revealed nothing
+    assert any(0 < r["fused_rows"] < r["slots"] for r in launches)
+    assert all(r["commit_rows"] == 0 for r in launches)
+    assert all(r["span_rows"] == 4 * (r["slots"] + r["fused_rows"])
+               for r in launches)
     assert all(r["masked_rows"] <= r["span_rows"] for r in launches)
     counters = engine.metrics.counters
     assert counters["serve.denoise_steps"] >= len(launches)
@@ -197,6 +201,64 @@ def test_a_stop_token_in_a_delivered_block_retires_the_request(params):
     out = engine.run([Request(rid=0, prompt=p, max_new_tokens=NEW,
                               stop_tokens=(stop,))])
     assert out[0] == toks[0][:18 + first + 1]
+
+
+# ---- (b2) the schedule: a commit rides in the next block's first step ------
+
+@pytest.mark.parametrize("rule", ["low_confidence_static",
+                                  "low_confidence_dynamic"])
+@pytest.mark.parametrize("steps", [1, 2, 4])
+def test_a_block_costs_its_denoising_steps_and_no_commit_of_its_own(
+        params, steps, rule):
+    """A prompt of whole blocks and an answer of three: ``steps`` launches
+    a block (with a launch a commit, ISSUE 46's schedule, 3 * (steps + 1)
+    - 1), the first two blocks' commits each in the launch that opens the
+    next, the last block not committed."""
+    thr, n, prompt = 0.0155, 3, _prompt(8, 8)
+    m = Metrics()
+    engine = ServingEngine(params, CFG, _serve(
+        denoise_steps=steps, reveal_rule=rule, reveal_threshold=thr),
+        metrics_obj=m)
+    out = engine.run([Request(rid=0, prompt=prompt, max_new_tokens=4 * n)])
+    toks, at = _blocks(params, prompt, 4 * n, denoise_steps=steps,
+                       reveal_rule=rule, reveal_threshold=thr)
+    assert out[0] == list(toks)
+    assert engine.reveal_steps[0] == list(at)
+    c = m.counters
+    assert c["serve.fused_commits"] + c["serve.commit_rows"] == n - 1
+    if rule == "low_confidence_static":
+        assert c["serve.denoise_steps"] == n * steps
+        assert c["serve.revealed_tokens"] == 4 * n
+        assert c["serve.commit_rows"] == 0
+    else:       # a block is whole when its confidences say so
+        assert n <= c["serve.denoise_steps"] <= n * steps
+
+
+def test_the_span_is_two_blocks_where_that_keeps_the_arm(params,
+                                                         monkeypatch):
+    """The engine asks the arm rule at a span of one block and of two,
+    ONCE: where two blocks would leave the kernel's arm (not under a
+    page, on a TPU: the rule patched as
+    ``test_engine_tokens_on_the_kernels_arm`` patches it) a launch spans
+    one block and a commit is a launch of its own, ISSUE 46's schedule."""
+    two = lambda **kw: ServingEngine(params, CFG, _serve(**kw))._halves
+    assert two() == two(page_size=16, prompt_bucket=16) == 2    # the CPU
+    m = Metrics()
+    with monkeypatch.context() as mp:
+        mp.setattr(
+            attention, "kv_attention_arm",
+            lambda t, page, *a, **k: "paged_kernel" if t < page
+            else "gather")
+        assert two(page_size=16, prompt_bucket=16) == 2
+        engine = ServingEngine(params, CFG, _serve(denoise_steps=2),
+                               metrics_obj=m)       # a page of 8 rows
+    assert engine._halves == 1
+    prompt = _prompt(8, 8)
+    out = engine.run([Request(rid=0, prompt=prompt, max_new_tokens=12)])
+    assert out[0] == list(_blocks(params, prompt, 12, denoise_steps=2)[0])
+    assert m.counters["serve.denoise_steps"] == 3 * (2 + 1) - 1
+    assert m.counters["serve.commit_rows"] == 2
+    assert m.counters["serve.fused_commits"] == 0
 
 
 # ---- (c) across an eviction ---------------------------------------------
@@ -246,6 +308,47 @@ def test_paged_decode_kernel_under_the_block_mask_is_the_gather_arm():
             (write[0][:, :3], write[1][:, :3]), block=4, interpret=True)
 
 
+@pytest.mark.parametrize("live", [False, True], ids=["dead", "live"])
+def test_paged_decode_kernel_under_a_span_of_two_blocks_is_the_gather_arm(
+        live):
+    """A span of TWO blocks of 4 that starts at rows 0, 4, 8 and 12 of a
+    16-row page (the last crosses into the next page; the first has no
+    context), its second half live (the next block, beside the commit of
+    the first) or dead (written to the scratch page, as
+    ``engine._span_step`` routes it: the slot's rows there stay)."""
+    rng = np.random.default_rng(7)
+    b, t, nh, nkv, d, page, n_pages = 4, 8, 4, 2, 128, 16, 13
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    pools = (f(1, n_pages, nkv, page, d), f(1, n_pages, nkv, page, d))
+    q, k, v = f(b, t, nh, d), f(b, t, nkv, d), f(b, t, nkv, d)
+    tables = jnp.arange(1, n_pages, dtype=jnp.int32).reshape(b, 3)
+    pos = jnp.asarray([0, 20, 40, 28], jnp.int32)
+    at = pos[:, None] + jnp.arange(t)[None, :]
+    own = (jnp.take_along_axis(tables, at // page, axis=1), at % page)
+    writes = (jnp.arange(t) < 4)[None, :] | live
+    write = (jnp.where(writes, own[0], 0), jnp.where(writes, own[1], 0))
+    out, new = attention.paged_decode_attention(
+        q, (k, v), pools, 0, tables, pos, write, block=4, interpret=True)
+    want_pools = tuple(attention.store_kv(p, 0, x, *write)
+                       for p, x in zip(pools, (k, v)))
+    ctx = [attention.gather_ctx(p, 0, tables) for p in want_pools]
+    want = attention.kv_attend({"wo": jnp.eye(nh * d)}, q, *ctx, at, 4)
+    rows = t if live else 4         # a dead half's outputs nobody reads
+    assert np.abs(np.asarray(out)[:, :rows]
+                  - np.asarray(want)[:, :rows]).max() < 2e-5
+    half_1 = lambda pool: np.asarray(pool)[
+        0, np.asarray(own[0])[:, 4:], :, np.asarray(own[1])[:, 4:]]
+    for got, exp, old in zip(new, want_pools, pools):
+        assert np.array_equal(np.asarray(got)[0, 1:], np.asarray(exp)[0, 1:])
+        assert np.array_equal(half_1(got), half_1(old)) != live
+    # the first half never sees the second: its rows are the rows of a
+    # span of ONE block
+    one, _ = attention.paged_decode_attention(
+        q[:, :4], (k[:, :4], v[:, :4]), pools, 0, tables, pos,
+        (write[0][:, :4], write[1][:, :4]), block=4, interpret=True)
+    assert np.abs(np.asarray(out)[:, :4] - np.asarray(one)).max() < 2e-5
+
+
 @pytest.mark.parametrize("pos0", [0, 128])
 def test_flash_span_with_the_block_diagonal_is_the_xla_arm(pos0):
     rng = np.random.default_rng(6)
@@ -263,18 +366,19 @@ def test_flash_span_with_the_block_diagonal_is_the_xla_arm(pos0):
 
 
 def test_a_span_of_several_blocks_keeps_the_gather_arm(params, monkeypatch):
-    """Where the rule would hand the kernel a span that is no ONE block
-    (``generate_blocks``' prefill over a short dense cache on a TPU), the
-    attention keeps the gather arm: found by ``chip_smoke.py``."""
+    """Where the rule would hand the kernel a span of more blocks than
+    the two its mask knows (``generate_blocks``' prefill over a short
+    dense cache on a TPU), the attention keeps the gather arm: found by
+    ``chip_smoke.py``."""
     cfg = CFG.replace(head_dim=128, num_heads=2, num_kv_heads=1)
     layer = ref.make_params(2**31 + 47, dict(DIMS, head_dim=128, heads=2,
                                              kv_heads=1))["layers"][0]
-    x = jnp.asarray(np.random.default_rng(1).standard_normal((1, 8, 64)),
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((1, 12, 64)),
                     jnp.float32)
-    pools = tuple(jnp.zeros((1, 4, 1, 8, 128), jnp.float32)
+    pools = tuple(jnp.zeros((1, 4, 1, 16, 128), jnp.float32)
                   for _ in range(2))
-    pos = jnp.arange(8, dtype=jnp.int32)[None, :]
-    write = (jnp.ones((1, 8), jnp.int32), pos % 8)
+    pos = jnp.arange(12, dtype=jnp.int32)[None, :]
+    write = (jnp.ones((1, 12), jnp.int32), pos % 16)
     table = jnp.asarray([[1]], jnp.int32)
     want = attention.kv_paged_attention(layer, x, cfg, pools, 0, pos, write,
                                         table)[0]
@@ -287,23 +391,30 @@ def test_a_span_of_several_blocks_keeps_the_gather_arm(params, monkeypatch):
 
 def test_engine_tokens_on_the_kernels_arm(params, monkeypatch):
     """The toy engine with the paged kernel forced (interpret mode): the
-    tokens of the gather arm."""
+    tokens of the gather arm.  A page of 16 rows, as the cell's: a span
+    of two blocks is under it, and every launch takes the kernel."""
     cfg = CFG.replace(head_dim=128, num_heads=2, num_kv_heads=1)
     wide = ref.make_params(2**31 + 47, dict(DIMS, head_dim=128, heads=2,
                                             kv_heads=1))
     reqs = [Request(rid=i, prompt=PROMPTS[i], max_new_tokens=6)
             for i in range(2)]
-    want = ServingEngine(wide, cfg, _serve(denoise_steps=2)).run(reqs)
+    serve = _serve(denoise_steps=2, page_size=16, prompt_bucket=16)
+    want = ServingEngine(wide, cfg, serve).run(reqs)
     monkeypatch.setattr(
         attention, "kv_attention_arm",
         lambda t, page, *a, **k: "paged_kernel" if t < page else "gather")
     jax.clear_caches()
+    m = Metrics()
     try:
-        got = ServingEngine(wide, cfg, _serve(denoise_steps=2)).run(reqs)
+        engine = ServingEngine(wide, cfg, serve, metrics_obj=m)
+        got = engine.run(reqs)
     finally:
         monkeypatch.undo()
         jax.clear_caches()
     assert got == want
+    assert engine._halves == 2 and m.counters["serve.fused_commits"] == 2
+    assert (m.counters["serve.decode_kernel_steps"]
+            == m.counters["serve.denoise_steps"] > 0)
 
 
 # ---- (e) the two wrong programs the cell's controls name ------------------

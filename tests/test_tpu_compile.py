@@ -77,7 +77,7 @@ def sdar_programs(one_chip):
         mp.setattr(jax, "default_backend", lambda: "tpu")
         return Programs({
             "denoise": eng._INPLACE["_paged_denoise_step"].lower(
-                params, cfg, cache, i32(64, 3, 4), i32(64, 8), i32(64, 160),
+                params, cfg, cache, i32(64, 3, 4), i32(64, 9), i32(64, 160),
                 pad_token=0),
             "chunk": eng._INPLACE["_prefill_chunk"].lower(
                 params, cfg, cache, i32(1, 1024), i32(160), i32(64), i32(),
@@ -90,11 +90,12 @@ def test_sdar_programs_fit_the_chip_with_the_pool_in_place(
     """The cell's programs as the chip's compiler builds them, under its
     14.5 GB: 9.97 GB of weights and the K/V pool (1.88 GB: 14 336 B a
     token over 7 layers) once, aliased to the output; the denoise program
-    reads every slot's pages in place (``fm_paged_decode`` at T = 4 under
-    the block mask, a call a layer, no gathered context) and runs the
-    routed rows of its 256-row span through ``fm_ffn_fwd``, a launch a
-    layer; the chunk scores its context blockwise (``fm_flash_span`` with
-    the block-causal diagonal)."""
+    reads every slot's pages in place (``fm_paged_decode`` at T = 8, a
+    span of two blocks under the block mask (ISSUE 52), a call a layer,
+    no gathered context), runs the routed rows of its 512-row span through
+    ``fm_ffn_fwd``, a launch a layer, and scores 4 rows a slot, never 8;
+    the chunk scores its context blockwise (``fm_flash_span`` with the
+    block-causal diagonal)."""
     compiled = sdar_programs.compiled(program)
     text = compiled.as_text()
     total = program_bytes(compiled)
@@ -113,6 +114,9 @@ def test_sdar_programs_fit_the_chip_with_the_pool_in_place(
         assert kernels == ["fm_paged_decode"] * 7
         assert arrays_of(text, 64, 4, 2560, 128) == []   # no context
         assert " scatter(" not in text
+        assert arrays_of(text, 64, 4, 151936)            # the head: L rows
+        assert arrays_of(text, 64, 8, 151936) == []
+        assert arrays_of(text, 512, 151936) == []
         # the blocks' state, K and V pool, experts_touched
         assert len(jax.tree.leaves(compiled.out_info)) == 1 + 2 + 1
     else:
