@@ -12,6 +12,7 @@ program (``tpu_custom_call``), so interpret mode cannot pass for the chip.
                                       # serve_hybrid, train
     python chip_smoke.py --only serve_hybrid    # that phase alone
     python chip_smoke.py --only serve_sdar      # the serve phase's sdar cases
+    python chip_smoke.py --only serve_trinity   # window + full layers, two pools
     python chip_smoke.py --chips 4    # one host, four chips: ep4_layer,
                                       # ep4_fused, ep4_serve (builder-run)
 
@@ -611,6 +612,148 @@ def _sdar_case(params, cfg, seed):
     return ok
 
 
+def phase_serve_trinity(seed):
+    """Window layers beside a full layer at Trinity-Large-Preview's widths
+    (``--only serve_trinity``): ONE window layer and ONE full layer, both
+    mixture layers with 32 of 256 experts held, an eighth of the
+    vocabulary; float32 (weights and activations, matmul precision
+    "highest") and bfloat16; the weights and the plain reference are
+    ``benchmark/lib/reference_trinity.py``'s."""
+    import importlib.util
+
+    import jax.numpy as jnp
+
+    from flashmoe_tpu.models.presets import PRESETS
+    from flashmoe_tpu.ops import moe
+
+    spec = importlib.util.spec_from_file_location(
+        "benchlib_reference_trinity", os.path.join(
+            os.path.dirname(os.path.abspath(__file__)), "benchmark", "lib",
+            "reference_trinity.py"))
+    ref = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = ref
+    spec.loader.exec_module(ref)
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "configs",
+                           "trinity_large.json")) as f:
+        conf = json.load(f)
+    ok = True
+    for dtype in ("float32", "bfloat16"):
+        file = dict(conf, num_hidden_layers=2, num_dense_layers=0,
+                    layer_kinds=["sliding_attention", "full_attention"],
+                    served={"param_dtype": dtype})
+        dims = ref.model_dims(file)
+        cfg = PRESETS["trinity-large-preview"](
+            num_layers=2, first_k_dense=0, layer_mixers=("swa", "mha"),
+            experts_held=32, vocab_size=dims["vocab"],
+            dtype=jnp.dtype(dtype).type, param_dtype=jnp.dtype(dtype).type)
+        params = ref.make_params(seed + 54, dims)
+        # float32 expert matrices of 3072 x 3072 under "highest" ask
+        # Mosaic for 124 MB of VMEM in ``fm_ffn_fwd`` (the described
+        # chip's compiler refuses the chunk program), so the float32 case
+        # computes its routed rows through ``ragged_dot`` and holds the
+        # attention and the two pools to the reference; bfloat16, the
+        # served form, runs the kernel
+        form = moe.routed_rows_form
+        if dtype == "float32":
+            moe.routed_rows_form = lambda cfg: "routed_rows"
+        try:
+            ok &= _trinity_case(params, cfg, ref, dims, seed)
+        finally:
+            moe.routed_rows_form = form
+        del params
+        gc.collect()
+    return ok
+
+
+def _trinity_case(params, cfg, ref, dims, seed):
+    """The engine over TWO page pools at published widths: a prompt of
+    4500 tokens in five chunks (the window of 4096 is crossed inside the
+    prompt, the pages behind it go back), one of 3900 (crossed while it
+    decodes 300 tokens) and one of 700 (a whole prompt, never crossed);
+    every visible row of logits against the plain reference's forward over
+    the engine's own stream, and the kernels' arm (``fm_paged_decode`` and
+    ``fm_flash_span`` under a window) against the gather arm.  ``float32``
+    is held to F32_TOL at every row; ``bfloat16`` three rows in four to
+    BF16_TOL and every row to half the logits' scale, every token to
+    BF16_TOL of the reference's best (``_serve_case``'s rule behind a
+    router)."""
+    import jax
+    import jax.numpy as jnp
+
+    from flashmoe_tpu.serving.engine import Request, ServeConfig
+
+    strict = cfg.dtype == jnp.float32
+    tol = F32_TOL if strict else BF16_TOL
+    rng = np.random.default_rng(seed)
+    lens, news = (4500, 3900, 700), (60, 300, 40)
+    reqs = [Request(rid=i, prompt=tuple(int(t) for t in
+                                        rng.integers(1, cfg.vocab_size, n)),
+                    max_new_tokens=m)
+            for i, (n, m) in enumerate(zip(lens, news))]
+    serve = ServeConfig(max_batch=4, page_size=16, num_pages=1024,
+                        max_pages_per_slot=320, ctx_bucket_pages=64,
+                        prompt_bucket=256, prefill_chunk=1024)
+    with (jax.default_matmul_precision("highest") if strict
+          else contextlib.nullcontext()):
+        t0 = time.perf_counter()
+        outputs, seen, summary = run_engine(params, cfg, serve, reqs,
+                                            [0, 0, 3])
+        run_s = time.perf_counter() - t0
+        with gather_arm():
+            g_out, g_seen, g_summary = run_engine(params, cfg, serve, reqs,
+                                                  [0, 0, 3])
+    differ, n_rows, arm_err, arm_far = compare_arms(
+        reqs, (outputs, seen), (g_out, g_seen), tol)
+    row_errs, gaps = [], []
+    for r in reqs:
+        got, t0_ = list(outputs[r.rid]), len(r.prompt)
+        n = len(got) - t0_
+        t_run = -(-len(got) // 256) * 256
+        toks = np.zeros((t_run,), np.int32)
+        toks[:len(got)] = got
+        want = np.asarray(ref.forward_logits(
+            params, dims, jnp.asarray(toks),
+            jnp.arange(t0_ - 1, t0_ + n - 1)))
+        scale = float(np.abs(want).max())
+        gaps += [float(want[j].max() - want[j, got[t0_ + j]]) / scale
+                 for j in range(n)]
+        row_errs += [float(np.abs(seen[r.rid][j] - want[j]).max()) / scale
+                     for j in sorted(seen[r.rid]) if j < n]
+    row_errs, gaps = np.asarray(row_errs), np.asarray(gaps)
+    complete = all(len(outputs[r.rid]) == len(r.prompt) + r.max_new_tokens
+                   for r in reqs)
+    if strict:
+        ok = bool(complete and not differ and row_errs.max() <= tol
+                  and gaps.max() <= tol and (arm_err or 0) <= tol)
+    else:
+        ok = bool(complete and np.quantile(row_errs, 0.75) <= tol
+                  and row_errs.max() <= 0.5 and gaps.max() <= tol
+                  and arm_far <= n_rows // 4)
+    ok &= summary["decode_kernel_steps"] > 0
+    ok &= g_summary["decode_kernel_steps"] == 0
+    emit({"phase": "serve", "case": "trinity",
+          "dtype": jnp.dtype(cfg.dtype).name, "ok": ok,
+          "widths": {"H": cfg.hidden_size, "I": cfg.intermediate_size,
+                     "E": cfg.num_experts, "held": cfg.experts_held,
+                     "k": cfg.expert_top_k, "heads": cfg.num_heads,
+                     "kv_heads": cfg.resolved_num_kv_heads,
+                     "head_dim": cfg.resolved_head_dim,
+                     "window": cfg.attn_window, "vocab": cfg.vocab_size},
+          "cut": {"num_layers": "2 of 60: a window and a full layer"},
+          "prompts": lens, "new_tokens": news,
+          "rows_vs_reference": {"n": len(row_errs),
+                                "max": float(row_errs.max()),
+                                "p75": float(np.quantile(row_errs, 0.75))},
+          "served_gap_max": float(gaps.max()),
+          "arms": {"requests_differ": differ, "rows": n_rows,
+                   "max": arm_err, "far": arm_far},
+          "kernel_steps": summary["decode_kernel_steps"],
+          "evictions": summary["evictions"],
+          "run_s_with_compile": round(run_s, 3)})
+    return ok
+
+
 def phase_serve_hybrid(seed):
     """The engine over two kinds of state: Ling-3.0-flash's widths, three
     layers (a dense 'kda' layer, a mixture 'kda' layer, a mixture 'mla'
@@ -1031,7 +1174,8 @@ ONE_CHIP = {"layer": phase_layer, "serve": phase_serve,
             "serve_hybrid": phase_serve_hybrid, "train": phase_train}
 #: parts of a phase that ``--only`` may name alone (a whole run has them
 #: inside their phase)
-PARTS = {"serve_sdar": phase_serve_sdar}
+PARTS = {"serve_sdar": phase_serve_sdar,
+         "serve_trinity": phase_serve_trinity}
 FOUR_CHIPS = {"ep4_layer": phase_ep4_layer, "ep4_fused": phase_ep4_fused,
               "ep4_serve": phase_ep4_serve}
 

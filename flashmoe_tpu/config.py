@@ -38,6 +38,12 @@ LANE = 128
 #: token (``MoEConfig.slot_state`` says what each keeps)
 STATE_MIXERS = ("kda", "conv", "ssm")
 
+#: the "mha" kind with a WINDOW: a layer of it is an "mha" layer whose
+#: queries see the last ``MoEConfig.attn_window`` keys and whose K/V rows
+#: live in a page pool of their own, from which the pages behind the window
+#: go back as a slot advances (``MoEConfig.window_layers``)
+WINDOW_MIXER = "swa"
+
 #: what a layer's feed-forward name (``MoEConfig.layer_ffns``) says:
 #: ``(the part whose output joins the residual stream where it is
 #: computed, the mixture branch)``; the branch is "moe" (a mixture reads
@@ -169,10 +175,16 @@ class MoEConfig:
     # and C shared by the heads of one of ssm_groups groups, behind a
     # causal depthwise convolution of ssm_conv taps with a bias; its span
     # form works in chunks of ssm_chunk tokens), a state a slot as well.
+    # "swa" is an "mha" layer with a WINDOW: a query at position p sees
+    # the keys ``p - attn_window < j <= p`` (``attn_window`` keys, itself
+    # among them).  In a model that has such layers THEY rotate q and k
+    # (``use_rope``) and its "mha" layers, which see every key, do not
+    # (``ops/attention.kv_project``: the one place of the rule).
     # ``layer_mixers`` names every layer; empty, every layer is
     # ``attention_kind``.  An entry None is a layer with NO mixer:
     # ``x + ffn(norm(x))`` alone.
     layer_mixers: tuple = ()
+    attn_window: int = 0
     # the feed-forward part PER LAYER: "moe", "dense" or None (a layer
     # that is its mixer alone, ``x + mixer(norm(x))``, with ONE norm).
     # Empty: what moe_frequency and first_k_dense say, every layer one.
@@ -203,6 +215,14 @@ class MoEConfig:
     # an "mha" layer rotates q and k by their positions (RoPE); False: it
     # applies none, and positions reach it through the causal mask alone
     use_rope: bool = True
+    # an "mha" / "swa" layer multiplies its heads' outputs, before the
+    # output product, by ``sigmoid(u Wg)`` elementwise (``wg``: H ->
+    # heads x head_dim, over the part's normed input u)
+    attn_gate: bool = False
+    # every part norms its OUTPUT before it joins the stream, with weights
+    # of its own (``attn_out_norm`` / ``ffn_out_norm``): four norms a layer,
+    # ``x + norm(part(norm(x)))``
+    part_out_norm: bool = False
     # the eps of every RMSNorm of the model (block, final, q/k)
     norm_eps: float = 1e-6
     # the share of a mixture layer's experts THIS chip holds, of a
@@ -532,13 +552,46 @@ class MoEConfig:
             raise ValueError(
                 f"layer {self.layers.index((None, None))} has neither a "
                 f"mixer nor a feed-forward part")
-        if set(self.mixers) - {"mha", "mla", *STATE_MIXERS, None}:
+        if set(self.mixers) - {"mha", "mla", WINDOW_MIXER, *STATE_MIXERS,
+                               None}:
             raise ValueError(f"layer_mixers {self.mixers} not of "
-                             f"('mha', 'mla', None) + {STATE_MIXERS}")
-        if set(self.mixers) - {*STATE_MIXERS, self.attention_kind, None}:
+                             f"('mha', 'mla', '{WINDOW_MIXER}', None) + "
+                             f"{STATE_MIXERS}")
+        if set(self.mixers) - {*STATE_MIXERS, self.attention_kind, None,
+                               *((WINDOW_MIXER,)
+                                 if self.attention_kind == "mha" else ())}:
             raise ValueError(
                 f"layer_mixers {self.mixers}: the layers that cache rows "
-                f"are all attention_kind={self.attention_kind!r}")
+                f"are all attention_kind={self.attention_kind!r} (an 'mha' "
+                f"model's may be '{WINDOW_MIXER}')")
+        if bool(self.window_layers) != (self.attn_window > 0):
+            raise ValueError(
+                f"attn_window={self.attn_window} with "
+                f"{len(self.window_layers)} '{WINDOW_MIXER}' layers: the "
+                f"window is theirs, and they need one >= 1")
+        if (self.attn_gate or self.part_out_norm) and (
+                self.attention_kind != "mha"):
+            raise ValueError(
+                "attn_gate gates an 'mha' layer's heads, and part_out_norm "
+                "is held to the reference through such a model alone: an "
+                "'mla' model has neither")
+        if self.part_out_norm and any(
+                FFN_PARTS[ffn][1] for _, ffn in self.layers):
+            raise NotImplementedError(
+                "part_out_norm with a mixture branch that joins a layer "
+                "later: which norm the carried output takes is not stated "
+                "by any configuration here")
+        if self.windowed and (self.is_training or self.block_length or max(
+                self.dp, self.ep, self.tp, self.sp, self.pp) > 1):
+            raise NotImplementedError(
+                f"'{WINDOW_MIXER}' layers / attn_gate / part_out_norm under "
+                f"is_training, block_length > 0 or a mesh axis > 1: "
+                f"training's attention (ops/attention.flash_attention and "
+                f"its backward kernels, parallel/ringattn.py) has no "
+                f"window, the mesh's parameter specs (parallel/mesh.py, "
+                f"parallel/pipeline.py) name neither ``wg`` nor the output "
+                f"norms, and the block mask's kernels know no window; a "
+                f"serving config of one chip runs them")
         if len(set(self.mixers) & set(STATE_MIXERS)) > 1:
             raise ValueError(
                 f"layer_mixers {self.mixers}: the layers that keep a "
@@ -880,6 +933,15 @@ class MoEConfig:
                     self.logits_scaling) != (1.0, 1.0, 1.0))
 
     @property
+    def windowed(self) -> bool:
+        """Whether the model has window layers, an output gate on its
+        attention or norms on its parts' outputs: what the training and
+        mesh paths do not build (``__post_init__`` refuses them by
+        name)."""
+        return bool(self.window_layers or self.attn_gate
+                    or self.part_out_norm)
+
+    @property
     def attn_block(self) -> int:
         """Positions an attention layer's mask treats as ONE: a query at
         ``pos`` sees keys up to ``pos | (attn_block - 1)``.  1 is causal
@@ -947,11 +1009,21 @@ class MoEConfig:
 
     @property
     def cache_layers(self) -> tuple:
-        """The layers that cache rows a token (every layer whose mixer is
-        not of ``STATE_MIXERS``): layer ``cache_layers[i]`` owns index i
-        of the paged pools."""
+        """The layers that cache a row for EVERY token of a context (every
+        layer whose mixer is neither of ``STATE_MIXERS`` nor the window
+        kind): layer ``cache_layers[i]`` owns index i of the paged
+        pools."""
         return tuple(li for li, m in enumerate(self.mixers)
-                     if m is not None and m not in STATE_MIXERS)
+                     if m is not None and m != WINDOW_MIXER
+                     and m not in STATE_MIXERS)
+
+    @property
+    def window_layers(self) -> tuple:
+        """The layers that cache the rows of the last ``attn_window``
+        tokens alone (mixer "swa"): layer ``window_layers[i]`` owns index
+        i of the WINDOW pools, whose page ids are a space of their own."""
+        return tuple(li for li, m in enumerate(self.mixers)
+                     if m == WINDOW_MIXER)
 
     @property
     def state_layers(self) -> tuple:
@@ -1040,16 +1112,18 @@ class MoEConfig:
     @property
     def kv_token_bytes(self) -> int:
         """Bytes one cached token holds over all the layers that cache
-        (what the model defines; the pool's padding is not in it)."""
-        return (len(self.cache_layers) * self.kv_token_elems
-                * jnp.dtype(self.dtype).itemsize)
+        (what the model defines; the pool's padding is not in it), window
+        layers included: what a token costs while it is inside the
+        window."""
+        return ((len(self.cache_layers) + len(self.window_layers))
+                * self.kv_token_elems * jnp.dtype(self.dtype).itemsize)
 
     @property
     def kv_pool_token_bytes(self) -> int:
         """Bytes of pool one cached token takes over all the layers that
         cache: :attr:`kv_token_bytes` with the rows as stored."""
-        return (len(self.cache_layers) * self.kv_row_elems
-                * jnp.dtype(self.dtype).itemsize)
+        return ((len(self.cache_layers) + len(self.window_layers))
+                * self.kv_row_elems * jnp.dtype(self.dtype).itemsize)
 
     @property
     def state_slot_bytes(self) -> int:

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 
 from flashmoe_tpu.config import FFN_PARTS, MoEConfig
 from flashmoe_tpu.models.transformer import (
-    embed_tokens, head_logits, join_stream, rms_norm,
+    embed_tokens, head_logits, join_stream, part_out, rms_norm,
 )
 from flashmoe_tpu.ops.attention import MIXER_SPANS, paged_attention
 from flashmoe_tpu.ops.moe import expert_arm, moe_layer
@@ -71,7 +71,7 @@ def init_cache(cfg: MoEConfig, batch: int, max_len: int):
     from flashmoe_tpu.serving.kvcache import cache_arrays
 
     arrays = cache_arrays(cfg, batch, max_len, batch)
-    if cfg.state_layers:
+    if cfg.state_layers or cfg.window_layers:
         return arrays
     return (LatentCache if cfg.attention_kind == "mla" else KVCache)(*arrays)
 
@@ -111,9 +111,11 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
     pools = None if cache is None else tuple(cache)
     rows, held, touched, zero = [], [], [], []
 
-    def feed_forward(onto, ffn_params, f_in, layer_cfg, routed: bool):
+    def feed_forward(onto, ffn_params, f_in, layer_cfg, routed: bool,
+                     layer=None):
         """``onto`` + the part's output over ``f_in`` (None: the output
-        alone), counting what its router chose."""
+        alone), counting what its router chose.  ``layer``: the layer
+        whose part it is (its output norm, ``cfg.part_out_norm``)."""
         if mixture is not None and routed:
             o = mixture(ffn_params, f_in, layer_cfg)
         else:
@@ -122,7 +124,8 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
                           != "capacity")
         out = o.out.reshape(b, t, -1).astype(x.dtype)
         if onto is not None:
-            out = join_stream(cfg, onto, out)
+            out = join_stream(cfg, onto, part_out(cfg, layer,
+                                                  "ffn_out_norm", out))
         if layer_cfg.num_experts > 1:
             touched.append(jnp.sum(o.expert_counts > 0))
         if layer_cfg.experts_held:
@@ -145,7 +148,8 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
                     absorbed=absorbed, valid=valid, slots=slots,
                     fresh=fresh)
                 rows.append(span)
-                x = join_stream(cfg, x, a)
+                x = join_stream(cfg, x, part_out(cfg, layer,
+                                                 "attn_out_norm", a))
         part, branch = FFN_PARTS[ffn]
         if part is None:
             continue
@@ -154,7 +158,7 @@ def span_forward(params, cfg: MoEConfig, x, cache, pos, write,
             f_in = rms_norm(x, layer["ffn_norm"], cfg.norm_eps).reshape(
                 b * t, -1)
             x = feed_forward(x, layer["moe"], f_in, cfg.ffn_config(li),
-                             part == "moe")
+                             part == "moe", layer)
         if branch == "moe":
             with trace_span("ffn.moe"):
                 carried = feed_forward(

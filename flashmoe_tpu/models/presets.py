@@ -285,6 +285,41 @@ def granite4_h_micro(**overrides) -> MoEConfig:
     return MoEConfig(**base)
 
 
+def trinity_large_preview(**overrides) -> MoEConfig:
+    """Trinity-Large-Preview (400B-A13B; huggingface.co/arcee-ai/
+    Trinity-Large-Preview ``config.json``, ``model_type`` afmoe): 60 layers
+    of width 3072, 48 query heads over 8 K/V heads of 128 with an RMSNorm on
+    every head of q and k and a sigmoid gate ``u Wg`` on the heads' outputs;
+    ``layer_types``: three WINDOW layers (``sliding_window`` 4096 keys,
+    RoPE theta 1e4) to one full layer with NO rotation
+    (``global_attn_every_n_layers`` 4: full where ``(i + 1) % 4 == 0``);
+    four norms a layer (each part's input AND output); 6 leading dense
+    SwiGLU layers of width 12288, then 256 experts top-4 of width 3072 + 1
+    shared behind a sigmoid router with a selection bias, normalised
+    weights (``route_norm``) times ``route_scale`` 2.448, one group; the
+    embedding's rows enter times sqrt(3072) (``mup_enabled``); RMSNorm eps
+    1e-5, an untied head over 200192 tokens."""
+    base = dict(
+        num_experts=256, expert_top_k=4, num_shared_experts=1,
+        hidden_size=3072, intermediate_size=3072, num_layers=60,
+        moe_frequency=1, first_k_dense=6, dense_intermediate_size=12288,
+        vocab_size=200192, num_heads=48, num_kv_heads=8, head_dim=128,
+        qk_norm=True, attn_window=4096, attn_gate=True, part_out_norm=True,
+        norm_eps=1e-5, rope_theta=1e4, embedding_multiplier=3072 ** 0.5,
+        router_score="sigmoid", router_bias=True, norm_topk_prob=True,
+        routed_scaling_factor=2.448, sequence_len=4096, gated_ffn=True,
+        hidden_act=Activation.SILU, drop_tokens=False, dtype=jnp.bfloat16,
+    )
+    base.update(overrides)
+    if "first_k_dense" not in overrides:    # a cut under six layers
+        base["first_k_dense"] = min(6, base["num_layers"])
+    # the published layer_types: full attention at 3, 7, ..., 59
+    base.setdefault("layer_mixers", tuple(
+        "mha" if (li + 1) % 4 == 0 else "swa"
+        for li in range(base["num_layers"])))
+    return MoEConfig(**base)
+
+
 PRESETS = {
     "mixtral-8x7b": mixtral_8x7b,
     "deepseek-moe-16b": deepseek_moe_16b,
@@ -297,4 +332,5 @@ PRESETS = {
     "longcat-flash": longcat_flash,
     "sdar-30b-a3b-chat": sdar_30b_a3b,
     "granite-4.0-h-micro": granite4_h_micro,
+    "trinity-large-preview": trinity_large_preview,
 }

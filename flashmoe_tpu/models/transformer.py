@@ -136,6 +136,15 @@ def init_params(key, cfg: MoEConfig) -> dict:
             if cfg.qk_norm:
                 layer.update(q_norm=jnp.ones((dh,), cfg.param_dtype),
                              k_norm=jnp.ones((dh,), cfg.param_dtype))
+            if cfg.attn_gate:
+                layer.update(wg=dense(lk[5], (h, nh * dh), h))
+        if cfg.part_out_norm:
+            # a norm on each part's OUTPUT, beside the one on its input
+            layer.update({
+                name: jnp.ones((h,), cfg.param_dtype)
+                for name, part in (("attn_out_norm", mixer),
+                                   ("ffn_out_norm", ffn))
+                if part is not None})
         if ffn is not None:
             layer["moe"] = init_moe_params(lk[4], cfg.ffn_config(li))
         if FFN_PARTS[ffn][1] == "moe":
@@ -163,10 +172,20 @@ def embed_tokens(params, cfg: MoEConfig, tokens):
 
 def join_stream(cfg: MoEConfig, x, part):
     """A part's output joins the residual stream:
-    ``x + cfg.residual_multiplier * part``."""
+    ``x + cfg.residual_multiplier * part``.  (A part's OUTPUT norm,
+    ``cfg.part_out_norm``, is its callers': :func:`part_out`.)"""
     if cfg.residual_multiplier != 1.0:
         part = part * cfg.residual_multiplier
     return x + part
+
+
+def part_out(cfg: MoEConfig, layer, name: str, part):
+    """A part's output as it joins: through the layer's norm ``name``
+    (``attn_out_norm`` / ``ffn_out_norm``) under ``cfg.part_out_norm``, as
+    it is otherwise."""
+    if cfg.part_out_norm:
+        part = rms_norm(part, layer[name], cfg.norm_eps)
+    return part
 
 
 def head_logits(params, cfg: MoEConfig, x):
@@ -205,8 +224,8 @@ def attention(layer, x, cfg: MoEConfig, positions=None, mesh=None,
     """
     from flashmoe_tpu.config import STATE_MIXERS
     from flashmoe_tpu.ops.attention import (
-        attention_xla, flash_attention, kv_project, mla_paged_attention,
-        paged_attention,
+        attention_xla, flash_attention, kv_paged_attention, kv_project,
+        mla_paged_attention, paged_attention,
     )
     from flashmoe_tpu.parallel.ringattn import ring_attention
 
@@ -230,6 +249,11 @@ def attention(layer, x, cfg: MoEConfig, positions=None, mesh=None,
         return mla_paged_attention(layer, x, cfg, None, 0, positions,
                                    None, None, absorbed=False)[0]
     nh, nkv, dh = cfg.num_heads, cfg.resolved_num_kv_heads, cfg.resolved_head_dim
+    if cfg.windowed:
+        # a window, a rotation by layer kind, an output gate: the cached
+        # paths' form over the sequence as its own context (serving only)
+        return kv_paged_attention(layer, x, cfg, None, 0, positions, None,
+                                  None, cfg.mixers[li])[0]
 
     q, k, v = kv_project(layer, x, cfg, positions)
 
@@ -341,9 +365,10 @@ def block(layer, x, cfg: MoEConfig, li: int, mesh=None, use_pallas=None,
     if mixer is not None:
         scope = MIXER_SPANS[mixer]
         with trace_span(scope):  # staticcheck: ok a MIXER_SPANS name
-            x = join_stream(cfg, x, attention(
+            a = attention(
                 layer, rms_norm(x, layer["attn_norm"], cfg.norm_eps), cfg,
-                mesh=mesh, use_pallas=use_pallas, li=li))
+                mesh=mesh, use_pallas=use_pallas, li=li)
+            x = join_stream(cfg, x, part_out(cfg, layer, "attn_out_norm", a))
     if ffn is None:
         return x, jnp.zeros((), cfg.accum_dtype), None, carried
     part, branch = FFN_PARTS[ffn]
@@ -351,7 +376,7 @@ def block(layer, x, cfg: MoEConfig, li: int, mesh=None, use_pallas=None,
         f_in = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
         f, moe_loss, moe_stats = _ffn(layer, f_in, cfg, li, mesh,
                                       use_pallas)
-        x = join_stream(cfg, x, f)
+        x = join_stream(cfg, x, part_out(cfg, layer, "ffn_out_norm", f))
     if branch == "moe":
         with trace_span("ffn.moe"):
             carried, branch_loss, moe_stats = _ffn(

@@ -18,13 +18,14 @@ its context) reads a K/V head's blocks from every query head of its group.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from flashmoe_tpu.config import LANE, STATE_MIXERS
+from flashmoe_tpu.config import LANE, STATE_MIXERS, WINDOW_MIXER
 from flashmoe_tpu.ops.conv import conv_attention
 from flashmoe_tpu.ops.expert import _VMEM_DEFAULT, _vmem_params
 from flashmoe_tpu.ops.kda import kda_attention
@@ -340,11 +341,14 @@ def rope_halves(q, k, positions, theta):
     return rot(q), rot(k)
 
 
-def kv_project(layer, x, cfg, positions):
+def kv_project(layer, x, cfg, positions, mixer: str = "mha"):
     """x: [B, T, H] normed -> (q [B, T, N, D], k and v [B, T, N_kv, D]),
     q and k roped at ``positions`` [B, T] (unless ``cfg.use_rope`` is
     off); under ``cfg.qk_norm`` every head of q and of k goes through an
-    RMSNorm over its width first."""
+    RMSNorm over its width first.  ``mixer``: the layer's kind.  THE rule
+    of which layers rotate: in a model with window layers ("swa") they do
+    and its "mha" layers, which see every key, do not; a model without
+    rotates every layer."""
     b, t, _ = x.shape
     nh, nkv, dh = (cfg.num_heads, cfg.resolved_num_kv_heads,
                    cfg.resolved_head_dim)
@@ -354,13 +358,24 @@ def kv_project(layer, x, cfg, positions):
     if cfg.qk_norm:
         q = rms_norm(q, layer["q_norm"], cfg.norm_eps)
         k = rms_norm(k, layer["k_norm"], cfg.norm_eps)
-    if cfg.use_rope:
+    if cfg.use_rope and (mixer == WINDOW_MIXER or not cfg.window_layers):
         q, k = rope_halves(q, k, positions, cfg.rope_theta)
     return q, k, v
 
 
+def heads_out(layer, ctx, gate=None):
+    """The heads' outputs ctx [B, T, N * D] through the output product;
+    ``gate`` (``cfg.attn_gate``: ``u Wg``, [B, T, N * D]) multiplies them
+    by its sigmoid first, elementwise, in float32."""
+    if gate is not None:
+        with trace_span("attn.gate"):
+            ctx = (ctx.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(ctx.dtype)
+    return ctx @ layer["wo"].astype(ctx.dtype)
+
+
 def kv_attend(layer, q, k_ctx, v_ctx, q_pos, block: int = 1,
-              scale: float | None = None):
+              scale: float | None = None, window: int = 0, gate=None):
     """Causal attention of T queries a row over a context of K/V rows.
 
     q: [B, T, N, D]; k_ctx / v_ctx: [B, N_kv, S, D], row s the K/V of
@@ -371,7 +386,11 @@ def kv_attend(layer, q, k_ctx, v_ctx, q_pos, block: int = 1,
     ``s <= q_pos | (block - 1)``; 1 is the causal mask, letter for letter
     (a span of a flash tile then starts at a whole block).  ``scale``: of
     the scores, ``D ** -0.5`` unless given (``MoEConfig.
-    attention_multiplier``).
+    attention_multiplier``).  ``window`` (static; 0: none): a query sees
+    the last ``window`` keys alone, ``q_pos - window < s <= q_pos``; the
+    context's row 0 may then be any position, with ``q_pos`` counted from
+    it (a window is the same from wherever it is counted).  ``gate``:
+    :func:`heads_out`'s.
     Blockwise through :func:`flash_span_attention` where
     :func:`span_attention_arm` says so (a long span on a TPU; a query
     head reads its K/V head, nothing is repeated), as float32 logits
@@ -384,8 +403,9 @@ def kv_attend(layer, q, k_ctx, v_ctx, q_pos, block: int = 1,
     if span_attention_arm(t, k_ctx.shape[2], nh, (dh,), dh, dt) == "flash":
         with trace_span("attn.kv_prefill"):
             ctx = _flash_span_ctx((q,), (k_ctx.astype(dt),),
-                                  v_ctx.astype(dt), q_pos, scale, block)
-        return ctx @ layer["wo"].astype(dt)
+                                  v_ctx.astype(dt), q_pos, scale, block,
+                                  **({"window": window} if window else {}))
+        return heads_out(layer, ctx, gate)
     with trace_span("attn.kv_prefill"):
         if block > 1:
             q_pos = q_pos | (block - 1)
@@ -396,15 +416,17 @@ def kv_attend(layer, q, k_ctx, v_ctx, q_pos, block: int = 1,
         logits = jnp.einsum(
             "bntd,bnsd->bnts", q.transpose(0, 2, 1, 3), k_ctx,
             preferred_element_type=jnp.float32) * scale
-        mask = (jnp.arange(k_ctx.shape[2])[None, None, None, :]
-                <= q_pos[:, None, :, None])
+        s_pos = jnp.arange(k_ctx.shape[2])[None, None, None, :]
+        mask = s_pos <= q_pos[:, None, :, None]
+        if window:
+            mask &= s_pos > q_pos[:, None, :, None] - window
         probs = jax.nn.softmax(jnp.where(mask, logits, NEG_INF),
                                axis=-1).astype(dt)
         ctx = jnp.einsum(
             "bnts,bnsd->bntd", probs, v_ctx,
             preferred_element_type=jnp.float32
         ).transpose(0, 2, 1, 3).reshape(b, t, nh * dh).astype(dt)
-    return ctx @ layer["wo"].astype(dt)
+    return heads_out(layer, ctx, gate)
 
 
 def store_kv(pages, li: int, span_kv, page_ids, rows):
@@ -453,7 +475,8 @@ def gather_ctx(pool, li: int, block_tables, d: int | None = None):
         0, 1, 3, 2, 4).reshape(b, rows * (width // d), n * page, d)
 
 
-def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
+def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables,
+                       mixer: str = "mha"):
     """THE K/V attention of every cached path, with
     :func:`mla_paged_attention`'s contract: project a span of T tokens a
     slot, write its K and V rows to layer ``li``'s pages, attend over the
@@ -472,15 +495,28 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
     context is the span itself); pos: [B, T] absolute positions,
     consecutive along T; write: ``(page_ids, rows)``, each [B, T], or
     ``(page_ids [B, T // page], None)`` for a span of whole pages;
-    block_tables: [B, n].  Returns (attention output [B, T, H], the
+    block_tables: [B, n].  ``mixer`` "swa": a WINDOW layer
+    (``cfg.attn_window``), whose ``pools`` are the window pools and whose
+    ``block_tables`` may be ``(tables [B, n], base [B])``: the pages a
+    window reaches alone, column 0 the page that holds position
+    ``base`` (a whole page; a plain array counts from 0).  Every arm then
+    masks the keys behind the window and reads no page wholly behind it.
+    Under ``cfg.attn_gate`` the heads' outputs pass :func:`heads_out`'s
+    gate.  Returns (attention output [B, T, H], the
     pools, the span's ``(k, v)`` rows laid out as a context, each
     [B, N_kv, T, D])."""
-    q, k, v = kv_project(layer, x, cfg, pos)
+    q, k, v = kv_project(layer, x, cfg, pos, mixer)
     span = (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
     block = cfg.attn_block
     scale = cfg.attention_multiplier            # None: head_dim ** -0.5
+    window = {"window": cfg.attn_window} if mixer == WINDOW_MIXER else {}
+    if window and isinstance(block_tables, tuple):
+        block_tables, base = block_tables
+        pos = pos - base[:, None]               # counted from the table's
+    gate = x @ layer["wg"].astype(x.dtype) if cfg.attn_gate else None
     if pools is None:
-        return kv_attend(layer, q, *span, pos, block, scale), pools, span
+        return (kv_attend(layer, q, *span, pos, block, scale, gate=gate,
+                          **window), pools, span)
     rows, page, width = pools[0].shape[2:]       # the heads as stored
     # (under a block mask the kernel knows a span of ONE block or TWO: a
     # longer span over a cache whose page it fits, generate()'s prefill
@@ -493,15 +529,15 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
             ctx, pools = paged_decode_attention(
                 q, (k, v), pools, li, block_tables, pos[:, 0], write,
                 scale=scale, block=block,
-                interpret=jax.default_backend() != "tpu")
-        return ctx @ layer["wo"].astype(q.dtype), pools, span
+                interpret=jax.default_backend() != "tpu", **window)
+        return heads_out(layer, ctx, gate), pools, span
     with trace_span("attn.kv_prefill"):
         pools = (store_kv(pools[0], li, k, *write),
                  store_kv(pools[1], li, v, *write))
         k_ctx = gather_ctx(pools[0], li, block_tables, q.shape[-1])
         v_ctx = gather_ctx(pools[1], li, block_tables, q.shape[-1])
-    return (kv_attend(layer, q, k_ctx, v_ctx, pos, block, scale), pools,
-            span)
+    return (kv_attend(layer, q, k_ctx, v_ctx, pos, block, scale, gate=gate,
+                      **window), pools, span)
 
 
 #: the registered scope a layer's token mixer part runs under, by its
@@ -509,8 +545,17 @@ def kv_paged_attention(layer, x, cfg, pools, li, pos, write, block_tables):
 #: the output product, the residual join) is that kind's time in a trace
 #: (``observe --device``); the mixer's two forms (``attn.<kind>_prefill`` /
 #: ``_decode``) and the kernels open their own scopes inside it
-MIXER_SPANS = {"mha": "attn.kv", "mla": "attn.mla", "kda": "attn.kda",
-               "conv": "attn.conv", "ssm": "attn.ssm"}
+MIXER_SPANS = {"mha": "attn.kv", WINDOW_MIXER: "attn.kv", "mla": "attn.mla",
+               "kda": "attn.kda", "conv": "attn.conv", "ssm": "attn.ssm"}
+
+
+class ByKind(NamedTuple):
+    """What a model with window layers hands a layer where a model without
+    hands ONE value (the write targets, the block tables): the full
+    layers' and the window layers', each in its pool's own page ids."""
+
+    full: object
+    window: object
 
 
 #: the mixers of ``config.STATE_MIXERS``: each takes ``(layer, x, cfg,
@@ -529,34 +574,49 @@ def paged_attention(layer, x, cfg, pools, li, pos, write, block_tables, *,
     (``_STATE_MIXERS``: they alone read ``valid``, ``slots`` and
     ``fresh``, and neither positions nor pages).  pools: the cache's
     arrays as a tuple, or None: the paged pools first (a K/V pair or the
-    one latent pool, holding the layers of ``cfg.cache_layers``), then,
+    one latent pool, holding the layers of ``cfg.cache_layers``; then,
+    where the config has window layers, THEIR K/V pair, holding the
+    layers of ``cfg.window_layers``), then,
     where the config has state layers, what they keep by slot
     (``cfg.slot_state``, holding the layers of ``cfg.state_layers``).
+    ``write`` / ``block_tables`` may each be a :class:`ByKind`: a window
+    layer takes its ``window``, any other its ``full`` (a plain value,
+    the dense cache of ``generate()``, serves both).
     Returns (the block's output, the pools, the span's rows: one entry
     for each array of the cache, None for those this layer does not
     own)."""
-    n_paged = 1 if cfg.attention_kind == "mla" else 2
+    mixer = cfg.mixers[li]
+    n_own = 1 if cfg.attention_kind == "mla" else 2   # a kind's paged pools
+    n_paged = n_own * (1 + bool(cfg.window_layers))
     n_state = len(cfg.slot_state)
-    if cfg.mixers[li] in STATE_MIXERS:
+    if mixer in STATE_MIXERS:
         kept = (None,) * n_state if pools is None else pools[n_paged:]
-        out, *kept, final = _STATE_MIXERS[cfg.mixers[li]](
+        out, *kept, final = _STATE_MIXERS[mixer](
             layer, x, cfg, *kept, cfg.state_layers.index(li), valid, slots,
             fresh)
         return (out, None if pools is None
                 else pools[:n_paged] + tuple(kept),
                 (None,) * n_paged + final)
-    ci = cfg.cache_layers.index(li)
-    paged = None if pools is None else pools[:n_paged]
+    # a window layer owns an index of the SECOND pair of pools and takes
+    # its own kind's write targets and tables
+    windowed = mixer == WINDOW_MIXER
+    at = n_own * windowed
+    ci = (cfg.window_layers if windowed else cfg.cache_layers).index(li)
+    kind = lambda v: v[windowed] if isinstance(v, ByKind) else v
+    write, block_tables = kind(write), kind(block_tables)
+    paged = None if pools is None else pools[at:at + n_own]
     if cfg.attention_kind != "mla":
         out, paged, span = kv_paged_attention(layer, x, cfg, paged, ci, pos,
-                                              write, block_tables)
+                                              write, block_tables, mixer)
     else:
         out, pool, latent = mla_paged_attention(
             layer, x, cfg, None if pools is None else paged[0], ci, pos,
             write, block_tables, absorbed=absorbed)
         paged, span = None if pools is None else (pool,), (latent,)
-    return (out, None if pools is None else paged + pools[n_paged:],
-            span + (None,) * n_state)
+    rows = (None,) * at + span + (None,) * (n_paged - at - n_own)
+    return (out, None if pools is None
+            else pools[:at] + paged + pools[at + n_own:],
+            rows + (None,) * n_state)
 
 
 # ----------------------------------------------------------------------
@@ -637,7 +697,7 @@ def paged_decode_block_pages(page: int, n_tab: int, n_kv: int, d: int,
 
 def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
                          q_ref, *refs, n_pools, t, rep, page, bp, n_tab,
-                         scale, block=1):
+                         scale, block=1, window=0):
     """Grid: (B,), one slot a step.  li_ref: [1] the layer; tab_ref /
     pos_ref / wpage_ref / wrow_ref: the block tables, positions and write
     targets, flat.  q_ref: [1, N_kv, R, D], row ``t * rep + g`` the query
@@ -648,7 +708,10 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
     D]; the one or two pages the span's rows fall into [2, N_kv, page,
     D]; and the blocks' and the pages' DMA semaphores.  Keys are the
     first pool's rows and values the first Dv columns of the last
-    pool's: K and V, or one latent row as both."""
+    pool's: K and V, or one latent row as both.  ``window`` > 0: span
+    column c (position ``pos + c``) sees the keys ``j > pos + c - window``
+    alone: the walk starts at the block that holds the first of them and
+    fetches none before it."""
     n = n_pools
     spans, hbm = refs[:n], refs[n:2 * n]
     o_ref, out_hbm = refs[2 * n], refs[2 * n + 1:3 * n + 1]
@@ -660,6 +723,8 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
     dv = o_ref.shape[-1]
     s_blk = bp * page
     n_blocks = (pos + s_blk - 1) // s_blk
+    # the first block the window touches (0 without one)
+    blk0 = (jnp.maximum(pos - window + 1, 0) // s_blk) if window else 0
 
     def values(rows):
         return rows if dv == d else rows[..., :dv]
@@ -702,9 +767,9 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
         for dma in page_dmas(page_b, 1, store=False):
             dma.start()
 
-    @pl.when(n_blocks > 0)
+    @pl.when(n_blocks > blk0)
     def _():
-        block_dmas(0, 0, "start")
+        block_dmas(blk0, jax.lax.rem(blk0, 2) if window else 0, "start")
 
     q = q_ref[0]                                            # [N_kv, R, D]
     # products of bf16 operands are exact in f32 as they are; Mosaic
@@ -734,11 +799,15 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
         block_dmas(blk, slot, "wait")
         s = jnp.einsum("hrd,hcd->hrc", q, bufs[0][slot], **f32) * scale
         col = blk * s_blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        return attend(carry, jnp.where(col < pos, s, NEG_INF),
+        seen = col < pos
+        if window:
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            seen &= col > pos + row // rep - window
+        return attend(carry, jnp.where(seen, s, NEG_INF),
                       values(bufs[-1][slot]))
 
     carry = jax.lax.fori_loop(
-        0, n_blocks, body,
+        blk0, n_blocks, body,
         (jnp.full((nkv, r_pad, 1), NEG_INF, jnp.float32),
          jnp.zeros((nkv, r_pad, 1), jnp.float32),
          jnp.zeros((nkv, r_pad, dv), jnp.float32)))
@@ -754,6 +823,8 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
     seen = ((col // block <= row // (rep * block)) if block > 1
             else (col * rep <= row)) & (col < t)
+    if window:
+        seen &= col > row // rep - window
     _, l, acc = attend(carry, jnp.where(seen, s, NEG_INF), values(new[-1]))
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
@@ -790,12 +861,12 @@ def _paged_decode_kernel(li_ref, tab_ref, pos_ref, wpage_ref, wrow_ref,
 
 @functools.partial(jax.jit, static_argnames=("v_width", "scale",
                                              "block_pages", "block",
-                                             "interpret"))
+                                             "window", "interpret"))
 def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
                            v_width: int | None = None,
                            scale: float | None = None,
                            block_pages: int | None = None,
-                           block: int = 1,
+                           block: int = 1, window: int = 0,
                            interpret: bool = False):
     """Causal attention of a short span a slot over the slot's own pages,
     read in place, and the span's rows written into them.  Jitted with
@@ -846,7 +917,8 @@ def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
             wide.reshape(b, t, nh, width),
             [x.reshape(b, t, nkv, width) for x in span], pools, li,
             block_tables, pos, write, scale=scale or d ** -0.5,
-            block_pages=block_pages, block=block, interpret=interpret)
+            block_pages=block_pages, block=block, window=window,
+            interpret=interpret)
         out = jnp.einsum(
             "btrpgqd,pq->btrpgd",
             out.reshape(b, t, nkv, pack, -1, pack, d), apart)
@@ -875,7 +947,8 @@ def paged_decode_attention(q, span, pools, li, block_tables, pos, write, *,
         functools.partial(
             _paged_decode_kernel, n_pools=n_pools, t=t, rep=rep, page=page,
             bp=bp, n_tab=n_tab, scale=scale or d ** -0.5,
-            **({"block": block} if block > 1 else {})),
+            **({"block": block} if block > 1 else {}),
+            **({"window": window} if window else {})),
         name="fm_paged_decode" if n_pools == 2 else "fm_latent_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
@@ -1003,16 +1076,29 @@ def flash_blocks(tq: int, tk: int, d: int, dtype,
     return bq, bk
 
 
-def _flash_step(step, q_start, k_start, block_q, block_k, causal):
+def _flash_step(step, q_start, k_start, block_q, block_k, causal,
+                window: int = 0):
     """Run ``step(masked)`` for the tile whose first query row is
     ``q_start`` and first key ``k_start``, if any of it lies on or under
     the diagonal (it is live); the masked form only where some of it lies
-    above (the diagonal crosses it)."""
+    above (the diagonal crosses it).  ``window`` > 0 (a query sees the
+    keys ``j > q - window``): a tile wholly behind the first row's window
+    is not live either, and ``step(masked, edged)`` pays the window's mask
+    only where some of the tile lies behind the LAST row's window (the
+    window's edge crosses it)."""
     if not causal:
         step(False)
         return
     live = k_start <= q_start + block_q - 1
     crossed = k_start + block_k - 1 > q_start
+    if window:
+        live &= k_start + block_k - 1 > q_start - window
+        edged = k_start <= q_start + block_q - 1 - window
+        for m in (False, True):
+            for e in (False, True):
+                pl.when(live & (crossed == m) & (edged == e))(
+                    functools.partial(step, m, e))
+        return
     pl.when(live & crossed)(functools.partial(step, True))
     pl.when(live & jnp.logical_not(crossed))(functools.partial(step, False))
 
@@ -1042,6 +1128,14 @@ def _causal_keep(q_start, k_start, shape, q_axis, block: int = 1):
     return qpos >= kpos
 
 
+def _window_keep(q_start, k_start, shape, window: int):
+    """Where a [block_q, block_k] tile of scores keeps its value under a
+    window: key position > query position - ``window``."""
+    qpos = jax.lax.broadcasted_iota(jnp.int32, shape, 0) + q_start
+    kpos = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + k_start
+    return kpos > qpos - window
+
+
 def _rows_to_column(row):
     """A [1, block] row of float32 statistics as a lane-wide [block, 128]
     column (every lane the same value), the layout the [block, .] tiles
@@ -1050,7 +1144,7 @@ def _rows_to_column(row):
 
 
 def _flash_kernel(*refs, scale, causal, block_q, block_k, parts=1,
-                  heads=None, lse=True, block=1):
+                  heads=None, lse=True, block=1, window=0):
     """Grid: (B*N, Tq/block_q, Tk/block_k) — kv innermost, accumulating the
     online softmax in VMEM scratch.  m/l scratch is lane-width (bq, 128)
     holding broadcast copies to keep TPU layouts happy, like the upstream
@@ -1061,7 +1155,10 @@ def _flash_kernel(*refs, scale, causal, block_q, block_k, parts=1,
     first query (query row i sees context rows ``s <= pos + i``); then the
     ``parts`` query blocks and the ``parts`` key blocks (the score is the
     sum of the parts' products), v_ref, o_ref, lse_ref (where ``lse``) and
-    the scratch m, l, acc.  ``block``: the mask's (:func:`_causal_keep`)."""
+    the scratch m, l, acc.  ``block``: the mask's (:func:`_causal_keep`);
+    ``window``: :func:`_flash_step`'s (a row whose window lies wholly
+    past a live tile scores it all NEG_INF: its statistics are wiped by
+    the rescale once a key it sees arrives, and its own key always does)."""
     if heads is not None:
         pos_ref, *refs = refs
     qs, ks = refs[:parts], refs[parts:2 * parts]
@@ -1083,13 +1180,16 @@ def _flash_kernel(*refs, scale, causal, block_q, block_k, parts=1,
         q_start = q_start + pos_ref[pl.program_id(0) // heads]
     k_start = ki * block_k
 
-    def step(masked):
+    def step(masked, edged=False):
         s = _nt(qs[0][0], ks[0][0])             # [bq, bk]
         for q_ref, k_ref in zip(qs[1:], ks[1:]):
             s = s + _nt(q_ref[0], k_ref[0])
         s = s * scale
         if masked:
             s = jnp.where(_causal_keep(q_start, k_start, s.shape, 0, block),
+                          s, NEG_INF)
+        if edged:
+            s = jnp.where(_window_keep(q_start, k_start, s.shape, window),
                           s, NEG_INF)
         m_prev = m_scr[:, :1]                   # [bq, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -1111,7 +1211,8 @@ def _flash_kernel(*refs, scale, causal, block_q, block_k, parts=1,
         acc_scr[:] = acc + _nn(p, v)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
 
-    _flash_step(step, q_start, k_start, block_q, block_k, causal)
+    _flash_step(step, q_start, k_start, block_q, block_k, causal,
+                **({"window": window} if window else {}))
 
     @pl.when(ki == nk - 1)
     def _():
@@ -1357,9 +1458,10 @@ def attention_widths(cfg) -> tuple[tuple[int, ...], int]:
     return (cfg.resolved_head_dim,), cfg.resolved_head_dim
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "block", "window",
+                                             "interpret"))
 def flash_span_attention(q, k, v, q_pos0, *, scale: float, block: int = 1,
-                         interpret: bool = False):
+                         window: int = 0, interpret: bool = False):
     """Causal attention of a span of queries over a context that starts
     before it: the forward flash kernel (``_flash_kernel``: the training
     call's body, tile rule and clamped index maps) with the position of
@@ -1380,7 +1482,12 @@ def flash_span_attention(q, k, v, q_pos0, *, scale: float, block: int = 1,
     dtype.  ``block`` > 1 (a power of two that divides the tile; q_pos0 a
     whole block): a query sees up to the end of its block of that many
     positions, ``s <= (q_pos0[b] + i) | (block - 1)``; only the tiles the
-    diagonal crosses mask differently.  No [Tq, Tk] array exists.  Returns
+    diagonal crosses mask differently.  ``window`` > 0: a query sees the
+    last ``window`` context rows alone, ``s > q_pos0[b] + i - window``: K/V
+    blocks wholly behind a query block's FIRST row's window are neither
+    fetched nor computed (the index maps clamp from below as they clamp
+    from above), and only the blocks the window's edge crosses pay its
+    mask.  No [Tq, Tk] array exists.  Returns
     [B, N, Tq, Dv]."""
     b, n, tq, _ = q[0].shape
     tk, dv = v.shape[2:]
@@ -1394,6 +1501,15 @@ def flash_span_attention(q, k, v, q_pos0, *, scale: float, block: int = 1,
 
     def kv_side(x):
         rep = n // x.shape[1]       # query heads a head of this array
+        if window:
+            # clamped from below too: the first block query block i's
+            # first row still sees
+            return pl.BlockSpec(
+                (1, bk, x.shape[-1]),
+                lambda h, i, j, pos: (h // rep, jnp.clip(
+                    j, jnp.maximum(pos[h // n] + i * bq - window + 1, 0)
+                    // bk, _last_live(i, bq, bk, pos[h // n])), 0),
+                memory_space=pltpu.VMEM)
         return pl.BlockSpec(
             (1, bk, x.shape[-1]),
             lambda h, i, j, pos: (h // rep, jnp.minimum(
@@ -1405,7 +1521,8 @@ def flash_span_attention(q, k, v, q_pos0, *, scale: float, block: int = 1,
         functools.partial(
             _flash_kernel, scale=scale, causal=True, block_q=bq, block_k=bk,
             parts=len(q), heads=n, lse=False,
-            **({"block": block} if block > 1 else {})),
+            **({"block": block} if block > 1 else {}),
+            **({"window": window} if window else {})),
         name="fm_flash_span",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -1425,7 +1542,8 @@ def flash_span_attention(q, k, v, q_pos0, *, scale: float, block: int = 1,
     return out.reshape(b, n, tq, dv)
 
 
-def _flash_span_ctx(q, k, v, q_pos, scale: float, block: int = 1):
+def _flash_span_ctx(q, k, v, q_pos, scale: float, block: int = 1,
+                    window: int = 0):
     """:func:`flash_span_attention` as the cached attention calls it: the
     query's parts laid out [B, T, N, d_i], q_pos [B, T] consecutive along
     T, interpreted off a TPU.  Returns the heads' outputs side by side,
@@ -1433,7 +1551,8 @@ def _flash_span_ctx(q, k, v, q_pos, scale: float, block: int = 1):
     b, t = q[0].shape[:2]
     out = flash_span_attention(
         tuple(part.transpose(0, 2, 1, 3) for part in q), k, v, q_pos[:, 0],
-        scale=scale, block=block, interpret=jax.default_backend() != "tpu")
+        scale=scale, block=block, interpret=jax.default_backend() != "tpu",
+        **({"window": window} if window else {}))
     return out.transpose(0, 2, 1, 3).reshape(b, t, -1)
 
 

@@ -75,9 +75,10 @@ from flashmoe_tpu.models.generate import (
 )
 from flashmoe_tpu.models.transformer import embed_tokens
 from flashmoe_tpu.ops import attention
+from flashmoe_tpu.ops.attention import ByKind
 from flashmoe_tpu.ops.moe import expert_arm, expert_chunks
 from flashmoe_tpu.serving.kvcache import (
-    SCRATCH_PAGE, PagedKVCache, PagePool, ShardedPagePool,
+    SCRATCH_PAGE, WINDOW_FIELDS, PagedKVCache, PagePool, ShardedPagePool,
     ctx_pages_bucket, init_paged_cache, prompt_pad,
     slot_state_fields, store_prefill, store_state,
 )
@@ -176,11 +177,18 @@ class ServeConfig:
     the denoising forwards a block (a divisor of the block length; None:
     the block length, one token a forward), the rule that picks the rows
     a forward reveals (``models/generate.REVEAL_RULES``) and the
-    confidence ``low_confidence_dynamic`` reveals every row over."""
+    confidence ``low_confidence_dynamic`` reveals every row over.
+
+    ``window_pages`` is read by a model with window layers alone
+    (``MoEConfig.window_layers``): the pages of THEIR pool, its scratch
+    page included, as ``num_pages`` is the full layers' (0: what every
+    slot can hold at once, ``ServingEngine.window_slot_pages`` a slot; a
+    smaller pool is covered by eviction, as a small ``num_pages`` is)."""
 
     max_batch: int = 8
     page_size: int = 8
     num_pages: int = 64
+    window_pages: int = 0
     max_pages_per_slot: int = 8
     ctx_bucket_pages: int = 2
     prompt_bucket: int = 8
@@ -208,9 +216,10 @@ class ServeConfig:
             raise ValueError("max_batch must be >= 1")
         if self.page_size < 1:
             raise ValueError("page_size must be >= 1")
-        if self.num_pages < 2:
-            raise ValueError("num_pages must be >= 2 (page 0 is the "
-                             "scratch page)")
+        if self.num_pages < 2 or self.window_pages == 1 \
+                or self.window_pages < 0:
+            raise ValueError("num_pages (and window_pages, where given) "
+                             "must be >= 2 (page 0 is the scratch page)")
         if not 1 <= self.ctx_bucket_pages <= self.max_pages_per_slot:
             raise ValueError("ctx_bucket_pages must be in "
                              "[1, max_pages_per_slot]")
@@ -289,6 +298,12 @@ class _Slot:
     prefill_pos: int | None = None  # next chunk start (chunked prefill
                                     # in flight); None = decoding
     prefill_toks: object = None     # padded np prompt for the chunks
+    # a model with window layers: the slot's pages of the WINDOW pool,
+    # indexed as ``pages`` is (entry j holds positions j * page ..), the
+    # entries wholly behind the window given back and pointing at the
+    # scratch page; ``wlive`` is the first that is not
+    wpages: list = dataclasses.field(default_factory=list)
+    wlive: int = 0
     draft: object = None            # DraftState (speculative decode):
                                     # the slot's suffix-match table,
                                     # rebuilt from prompt+emitted so it
@@ -347,7 +362,7 @@ def _prefill_padded(params, cfg: MoEConfig, prompt_padded, true_len):
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _prefill_chunk(params, cfg: MoEConfig, pools, chunk_toks,
                    block_table, chunk_page_ids, start_pos, rel_last,
-                   slot=0):
+                   slot=0, window=None):
     """Prefill ONE fixed-size chunk of a long prompt directly into the
     paged cache.
 
@@ -361,7 +376,10 @@ def _prefill_chunk(params, cfg: MoEConfig, pools, chunk_toks,
     state the state layers carry from chunk to chunk (the first chunk
     starts from nothing; positions past ``rel_last`` leave it alone).
     ``pools`` is the engine's cache (any class of ``serving/kvcache``).
-    Returns (logits [V], pools).
+    ``window`` (a model with window layers): ``(table [n_w], page_ids
+    [C / page], base)``, the window pool's ids of the pages the chunk's
+    windows reach, column 0 the page of position ``base``, and of the
+    pages the chunk writes.  Returns (logits [V], pools).
 
     The chunk's rows land in their pages BEFORE the gather, so in-chunk
     causal attention sees them through the same paged read decode uses.
@@ -374,16 +392,22 @@ def _prefill_chunk(params, cfg: MoEConfig, pools, chunk_toks,
         valid=(jnp.arange(c, dtype=jnp.int32) <= rel_last)[None, :],
         slots=jnp.asarray(slot, jnp.int32)[None],
         fresh=start_pos == 0) if cfg.state_layers else {}
-    x, pools, _, _ = span_forward(
-        params, cfg, embed_tokens(params, cfg, chunk_toks), pools,
-        positions[None, :], (chunk_page_ids[None, :], None),  # whole pages
-        block_table[None, :], absorbed=False, **state)
+    span = (embed_tokens(params, cfg, chunk_toks), pools, positions[None, :],
+            (chunk_page_ids[None, :], None),                # whole pages
+            block_table[None, :])
+    if window is not None:
+        wtable, wpage_ids, wbase = window
+        span = (*span[:3], ByKind(span[3], (wpage_ids[None, :], None)),
+                ByKind(span[4], (wtable[None, :], wbase[None])))
+    x, pools, _, _ = span_forward(params, cfg, *span, absorbed=False,
+                                  **state)
     h = jax.lax.dynamic_slice(x, (0, rel_last, 0), (1, 1, x.shape[-1]))
     return lm_logits(params, cfg, h)[0], pools
 
 
 def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
-               positions, mixture=None, pad_token=None, writes=None):
+               positions, mixture=None, pad_token=None, writes=None,
+               window=None):
     """A span of T tokens a slot through the layers, over the paged
     cache: the body of the decode (T = 1) and verify programs and of
     their EP-sharded twins (which pass ``mixture``).  toks: [B, T];
@@ -401,7 +425,10 @@ def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
     garbage columns the host never reads — the host truncates drafts to
     fit, this is the in-graph belt-and-suspenders.  So do the columns
     outside ``writes`` ([B, T] bool, the denoise program's dead half;
-    None: every column writes): they leave a slot's pages alone."""
+    None: every column writes): they leave a slot's pages alone.
+    ``window`` (a model with window layers): ``(tables [B, n_w], base
+    [B])``, each slot's pages of the WINDOW pool from the first its window
+    reaches, column 0 the page of position ``base`` (a whole page)."""
     page = pools.page_size
     ntab = block_tables.shape[1]
     pos = (positions[:, None]
@@ -414,6 +441,17 @@ def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
             block_tables, jnp.clip(pos // page, 0, ntab - 1), axis=1),
         jnp.int32(SCRATCH_PAGE))
     rows = jnp.where(valid, pos % page, 0)
+    write, tables = (page_ids, rows), block_tables
+    if window is not None:
+        wtables, wbase = window
+        at = (pos - wbase[:, None]) // page     # the page's column there
+        inside = valid & (at >= 0) & (at < wtables.shape[1])
+        wpage_ids = jnp.where(
+            inside, jnp.take_along_axis(
+                wtables, jnp.clip(at, 0, wtables.shape[1] - 1), axis=1),
+            jnp.int32(SCRATCH_PAGE))
+        write = ByKind(write, (wpage_ids, jnp.where(inside, rows, 0)))
+        tables = ByKind(tables, window)
     owns = block_tables[:, :1] != SCRATCH_PAGE      # the row has a tenant
     if pad_token is not None:
         toks = jnp.where(owns, toks, jnp.int32(pad_token))
@@ -421,26 +459,26 @@ def _span_step(params, cfg: MoEConfig, pools, toks, block_tables,
     # a short span over a long context: MLA's absorbed form
     x, pools, _, counted = span_forward(
         params, cfg, embed_tokens(params, cfg, toks), pools, pos,
-        (page_ids, rows), block_tables, absorbed=True, mixture=mixture,
-        valid=live)
+        write, tables, absorbed=True, mixture=mixture, valid=live)
     return x, pools, counted
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "pad_token"))
 def _paged_decode_step(params, cfg: MoEConfig, pools, toks,
-                       block_tables, positions, pad_token=None):
+                       block_tables, positions, pad_token=None,
+                       window=None):
     """One decode step for the whole slot grid: the span path at T = 1.
 
     toks: [B] int32 tokens to feed; block_tables: [B, n] page ids
     (bucketed); positions: [B] write positions (= each slot's current
     length; inactive slots pass 0 with an all-scratch table, and with a
     ``pad_token`` are fed it whatever ``toks`` holds: see
-    :func:`_span_step`).  Returns
+    :func:`_span_step`; ``window``: its).  Returns
     (logits [B, V] f32, pools, what the layers counted: a dict of scalars,
     empty for a config whose layers count nothing)."""
     x, pools, counted = _span_step(params, cfg, pools, toks[:, None],
                                    block_tables, positions,
-                                   pad_token=pad_token)
+                                   pad_token=pad_token, window=window)
     return lm_logits(params, cfg, x), pools, counted
 
 
@@ -799,6 +837,23 @@ class ServingEngine:
                         f"recurrent-state layers (a state a slot: "
                         f"{sorted(set(cfg.mixers) & set(STATE_MIXERS))}) "
                         f"with {what}: {lack} is missing")
+        if cfg.window_layers:
+            missing = {
+                "speculate": (sv.speculate is not None,
+                              "a verify span's rejected rows in a window "
+                              "pool whose pages behind them are gone"),
+                "ep_shards > 1": (sv.ep_shards > 1,
+                                  "a window slab and tables in "
+                                  "_ep_decode_fn"),
+                "a prefill_fn (the fabric's KV handoff)": (
+                    prefill_fn is not None,
+                    "a payload for the window layers' pages"),
+            }
+            for what, (asked, lack) in missing.items():
+                if asked:
+                    raise NotImplementedError(
+                        f"window layers (attn_window={cfg.attn_window}) "
+                        f"with {what}: {lack} is missing")
         if cfg.block_length:
             bl = cfg.block_length
             missing = {
@@ -980,9 +1035,32 @@ class ServingEngine:
                 draft_tokens=self._spec.draft_tokens,
                 ngram=self._spec.ngram, source=self._spec.source)
 
-        self.cache = init_paged_cache(cfg, self.serve.num_pages,
-                                      self.serve.page_size,
-                                      self.serve.max_batch)
+        # a model with window layers: the pages ONE slot holds of their
+        # pool at most (a chunk's, or a whole prompt's padded end, and the
+        # window before it), the pool, and an allocator of its own
+        self.window_slot_pages = 0
+        self.wpool = None
+        if cfg.window_layers:
+            page = self.serve.page_size
+            span = self.serve.prefill_chunk or self.serve.prompt_bucket
+            self.window_slot_pages = (
+                -(-(cfg.attn_window - 1 + span) // page) + 1)
+            n_window = (self.serve.window_pages or
+                        self.serve.max_batch * self.window_slot_pages + 1)
+            if n_window - 1 < self.window_slot_pages:
+                raise ValueError(
+                    f"window_pages={n_window} cannot hold one slot's "
+                    f"{self.window_slot_pages} pages (attn_window="
+                    f"{cfg.attn_window} and a span of {span} tokens)")
+            self.wpool = PagePool(n_window)
+        self.cache = init_paged_cache(
+            cfg, self.serve.num_pages, self.serve.page_size,
+            self.serve.max_batch,
+            self.wpool.num_pages if self.wpool is not None else 0)
+        # this step's account of the window pool: pages given back, the
+        # pages a slot its programs read in a window layer
+        self._window_freed = 0
+        self._window_ctx = 0.0
         # this step's traffic with the slots' recurrent state, and what
         # the decode program of the step before counted (ready by now:
         # reading this step's would wait for it)
@@ -1250,6 +1328,69 @@ class ServingEngine:
             return self.pool.to_global(pages, self._shard_of(slot))
         return pages
 
+    # ---- the window pool (a model with window layers: a page-id space
+    # and an allocator of its own; every method is a no-op without) -----
+
+    def _window_first(self, pos: int) -> int:
+        """Index, in a slot's table, of the first page a query at
+        position ``pos`` still sees in a window layer."""
+        return (max(0, pos - self.cfg.attn_window + 1)
+                // self.serve.page_size)
+
+    def _window_reach(self, span: int, whole_pages: bool = False) -> int:
+        """Pages the windows of a span of ``span`` rows reach at most, the
+        span's own among them: of a span that starts anywhere (a decode
+        step's) or at a whole page (a chunk's): the width of the table a
+        window layer is handed."""
+        page, back = self.serve.page_size, self.cfg.attn_window - 1
+        if whole_pages:
+            return span // page - (-back // page)
+        return 1 - (-(back + span - 1) // page)
+
+    def _trim_window(self, s: _Slot, pos: int) -> None:
+        """Give back the window pages of ``s`` that lie wholly behind the
+        window of a query at ``pos`` (its NEXT query: every later one sees
+        less of them); their table entries point at the scratch page."""
+        if self.wpool is None:
+            return
+        first = min(self._window_first(pos), len(s.wpages))
+        if first > s.wlive:
+            self.wpool.free(s.wpages[s.wlive:first])
+            s.wpages[s.wlive:first] = [SCRATCH_PAGE] * (first - s.wlive)
+            self._window_freed += first - s.wlive
+            s.wlive = first
+
+    def _free_window(self, s: _Slot) -> None:
+        """All of the slot's window pages back (it retires or is evicted)."""
+        if self.wpool is not None:
+            self.wpool.free(s.wpages[s.wlive:])
+            s.wpages, s.wlive = [], 0
+
+    def _cover_window(self, i: int, need: int) -> bool:
+        """Extend slot ``i``'s window pages to ``need`` entries, evicting
+        the youngest request while the window pool runs dry (as the full
+        pool's growth does); False: slot ``i`` itself was evicted."""
+        s = self.slots[i]
+        while self.wpool is not None and len(s.wpages) < need:
+            got = self.wpool.alloc(need - len(s.wpages))
+            if got is not None:
+                s.wpages.extend(got)
+            elif not self._evict_youngest():
+                raise RuntimeError("window page pool exhausted with no "
+                                   "evictable request")
+            elif self.slots[i] is None:         # we evicted ourselves
+                return False
+        return True
+
+    def _window_table(self, s: _Slot, pos: int, width: int):
+        """(``width`` ids: the slot's window pages from the first a query
+        at ``pos`` sees, the scratch page past its own; the position that
+        page starts at)."""
+        first = self._window_first(pos)
+        ids = s.wpages[first:first + width]
+        return (ids + [SCRATCH_PAGE] * (width - len(ids)),
+                first * self.serve.page_size)
+
     def _arrived_head(self) -> bool:
         return bool(self.queue) \
             and self.queue[0].arrival_step <= self.step_idx
@@ -1292,18 +1433,29 @@ class ServingEngine:
             chunked = (chunk is not None and t_pad > chunk
                        and self._prefill_fn is None)
             n_pages = (chunk if chunked else t_pad) // sv.page_size
+            # the window pool holds a first chunk whole; of a whole prompt
+            # the pages its NEXT query's window reaches (the others' rows
+            # go to the scratch page)
+            w_skip = 0 if chunked or self.wpool is None \
+                else min(self._window_first(t0), n_pages)
+            n_wpages = 0 if self.wpool is None else n_pages - w_skip
             # first free slot whose shard can hold the pages (LIFO
             # alloc never partially succeeds, so free_pages >= n is
             # exactly alloc-would-succeed — the unsharded order is the
             # pre-fabric alloc-then-first-free-slot order)
             slot = None
             for i, s in enumerate(self.slots):
-                if s is None and self._shard_free_pages(i) >= n_pages:
+                if s is None and self._shard_free_pages(i) >= n_pages and (
+                        not n_wpages or self.wpool.free_pages >= n_wpages):
                     slot = i
                     break
             if slot is None:
                 break                      # head-of-line: deterministic
             pages = self._alloc_pages(slot, n_pages)
+            window = {}
+            if self.wpool is not None:
+                window = dict(wpages=[SCRATCH_PAGE] * w_skip
+                              + self.wpool.alloc(n_wpages), wlive=w_skip)
             self.queue.popleft()
             # the request's account: this wait ends here (a later one in
             # the same step also waited through its neighbour's prefill)
@@ -1334,7 +1486,8 @@ class ServingEngine:
                     emitted=[], admit_step=self.step_idx,
                     arrival_s=entry.arrival_s,
                     first_token_s=entry.first_token_s,
-                    prefill_pos=0, prefill_toks=toks, **account, **blocks)
+                    prefill_pos=0, prefill_toks=toks, **account, **blocks,
+                    **window)
                 self.stats["prefill_buckets"].add(chunk)
             elif not t0:
                 # a prompt shorter than a block: nothing to prefill
@@ -1354,6 +1507,8 @@ class ServingEngine:
                     true_len = jnp.int32(t0)
                     page_ids = jnp.asarray(
                         self._global_pages(slot, pages), jnp.int32)
+                    wpage_ids = (jnp.asarray(window["wpages"], jnp.int32)
+                                 if window else None)
                 starved = self._queue_empty("serve.prefill")
                 with trace_span("serve.prefill"):
                     # (logits, one dense run per pool of the cache)
@@ -1365,10 +1520,13 @@ class ServingEngine:
                             self.params, self.cfg, prompt, true_len)
                     self.cache = type(self.cache)(*(
                         _store_state(pool, seq, slot) if by_slot
-                        else _store_prefill(pool, seq, page_ids)
-                        for pool, seq, by_slot in zip(
+                        else _store_prefill(
+                            pool, seq,
+                            wpage_ids if name in WINDOW_FIELDS else page_ids)
+                        for pool, seq, by_slot, name in zip(
                             self.cache, seqs,
-                            slot_state_fields(self.cache))))
+                            slot_state_fields(self.cache),
+                            self.cache._fields)))
                     self._state_bytes += self.cfg.state_slot_bytes
                 if blocks:      # no next-token logits: nothing reads them
                     self._last_out = logits
@@ -1380,7 +1538,8 @@ class ServingEngine:
                     req=req, orig=orig, pages=list(pages), length=t0,
                     emitted=[], admit_step=self.step_idx,
                     arrival_s=entry.arrival_s,
-                    first_token_s=entry.first_token_s, **account, **blocks)
+                    first_token_s=entry.first_token_s, **account, **blocks,
+                    **window)
                 self.stats["prefill_buckets"].add(t_pad)
             self._rates["admits"].add()
             self.stats["admitted"] += 1
@@ -1425,7 +1584,8 @@ class ServingEngine:
                                        "evictable request")
                 if self.slots[i] is None:   # we evicted ourselves
                     break
-            if self.slots[i] is None:
+            if self.slots[i] is None or not self._cover_window(
+                    i, need_pages):
                 continue
             fed = self._clock(), time.time_ns()
             with trace_span("serve.chunk_feed"):
@@ -1446,6 +1606,18 @@ class ServingEngine:
                     jnp.asarray(table),
                     jnp.asarray(gpages[first_pg:need_pages], jnp.int32),
                     jnp.int32(pos), jnp.int32(rel_last), jnp.int32(i))
+                n_wctx = 0
+                if self.wpool is not None:
+                    # the pages the chunk's windows reach: its own and
+                    # those of the window before its first row
+                    n_wctx = min(n_ctx_pages,
+                                 self._window_reach(chunk, whole_pages=True))
+                    wtable, wbase = self._window_table(s, pos, n_wctx)
+                    operands += ((
+                        jnp.asarray(wtable, jnp.int32),
+                        jnp.asarray(s.wpages[first_pg:need_pages],
+                                    jnp.int32),
+                        jnp.int32(wbase)),)
             if self.tracer is not None:
                 # chunks interleave across slots: re-arm attribution so
                 # the span lands on THIS slot's request track
@@ -1460,6 +1632,9 @@ class ServingEngine:
                 if pos:
                     self.metrics.count("serve.chunk_carries")
             s.prefill_pos = pos + chunk
+            # the next query: the next chunk's first row, or the first
+            # token decoded after the prompt's true end
+            self._trim_window(s, min(pos + chunk, t0))
             if pos <= t0 - 1 < pos + chunk:
                 # prefill complete — arm the sampler, join decode
                 if not self.cfg.block_length:
@@ -1469,7 +1644,8 @@ class ServingEngine:
                 s.length = t0
             self._note_prefill(s.orig.rid, i, "chunk", pos,
                                min(t0, pos + chunk) - pos, chunk,
-                               n_ctx_pages * sv.page_size, fed, starved)
+                               n_ctx_pages * sv.page_size, fed, starved,
+                               n_wctx)
 
     def _put_logits(self, slot: int, logits) -> None:
         """A finished prefill's logits into the slot's row of the pending
@@ -1493,12 +1669,14 @@ class ServingEngine:
 
     def _note_prefill(self, rid: int, slot: int, form: str, pos: int,
                       tokens: int, rows: int, ctx_rows: int, fed,
-                      starved: bool) -> None:
+                      starved: bool, window_pages: int = 0) -> None:
         """One prefill program's account: ``tokens`` of a prompt in
         ``rows`` computed rows over a context of ``ctx_rows``, fed from
         ``fed`` (engine's clock, profiler's clock) to now.  A chunk GATHERS
         its context from the pool (``ctx_pages``: its block table,
-        bucketed); a whole prompt's context is the span itself (0)."""
+        bucketed); a whole prompt's context is the span itself (0).
+        ``window_pages``: the pages it gathers for a WINDOW layer (a model
+        with such layers; its record alone carries ``window_ctx_pages``)."""
         done = self._prefills
         done[0] += 1
         done[1] += tokens
@@ -1514,6 +1692,10 @@ class ServingEngine:
         # (``ops/attention.span_attention_arm``: the rule the traced
         # program asked), counted where it is the flash kernel
         attn_arm = None
+        more = {}
+        if self.wpool is not None:
+            more["window_ctx_pages"] = window_pages
+            self.metrics.count("serve.window_programs")
         if self.cfg.cache_layers:
             attn_arm = attention.span_attention_arm(
                 rows, ctx_rows, self.cfg.num_heads,
@@ -1527,7 +1709,7 @@ class ServingEngine:
                 pad_rows=rows - tokens, ctx_pages=ctx_pages, expert_arm=arm,
                 expert_chunks=chunks, attn_arm=attn_arm,
                 host_ms=round((self._clock() - fed[0]) * 1e3, 3),
-                starved=starved, t0_trace_ns=fed[1])
+                starved=starved, t0_trace_ns=fed[1], **more)
 
     def _evict_youngest(self, shard: int | None = None) -> bool:
         """Preempt the most recently admitted request back to the
@@ -1547,6 +1729,7 @@ class ServingEngine:
                                             self.slots[i].req.rid))
         s = self.slots[victim]
         self._free_slot_pages(victim, s.pages)
+        self._free_window(s)
         delivered = self._delivered(s)
         remaining = s.orig.max_new_tokens - delivered
         # the resumed prompt carries EVERY delivered token (across any
@@ -1596,13 +1779,16 @@ class ServingEngine:
         ``span`` more positions: :meth:`_next_page`'s): :meth:`_grow_pages`
         then evicts nobody."""
         need = Counter()            # by page shard
+        need_window = 0
         for i in rows:
             s = self.slots[i]
-            need[self._shard_of(i)] += max(
-                0, self._next_page(s, span) + 1 - len(s.pages))
+            next_page = self._next_page(s, span)
+            need[self._shard_of(i)] += max(0, next_page + 1 - len(s.pages))
+            need_window += max(0, next_page + 1 - len(s.wpages))
         free = (self.pool.shard_free_pages if self.serve.ep_shards > 1
                 else lambda shard: self.pool.free_pages)
-        return all(n <= free(shard) for shard, n in need.items())
+        return all(n <= free(shard) for shard, n in need.items()) and (
+            self.wpool is None or need_window <= self.wpool.free_pages)
 
     def _grow_pages(self, rows, span=0) -> None:
         """Allocate the next page for every slot of ``rows`` (decoding
@@ -1631,6 +1817,8 @@ class ServingEngine:
                                        "evictable request")
                 if self.slots[i] is None:   # we evicted ourselves
                     break
+            if self.slots[i] is not None:
+                self._cover_window(i, need_idx + 1)
 
     def _spec_decode(self, active) -> int | None:
         """Speculative decode step: draft, verify the span in one
@@ -1820,6 +2008,7 @@ class ServingEngine:
     def _retire(self, slot: int, s: _Slot) -> None:
         now = self._clock()
         self._free_slot_pages(slot, s.pages)
+        self._free_window(s)
         self.slots[slot] = None
         out = (list(s.orig.prompt)
                + list(s.req.prompt[len(s.orig.prompt):])
@@ -1987,6 +2176,22 @@ class ServingEngine:
             block = attention.paged_decode_block_pages(page, n_ctx, *pool)
             read = round(float(np.mean(
                 -(-lengths // (block * page)) * block + span_pages)), 3)
+        if self.wpool is not None:
+            # a window layer's read: the table it is handed on the gather
+            # arm; on the kernel's the whole blocks from the one that holds
+            # the window's first key (counted from the table's first page)
+            # and the page or two the span is written into
+            window = self.cfg.attn_window
+            n_w = min(n_ctx, self._window_reach(t_span))
+            self._window_ctx = float(n_w)
+            if arm == "paged_kernel":
+                rel = lengths - np.maximum(lengths - window + 1, 0) \
+                    // page * page
+                block = attention.paged_decode_block_pages(page, n_w, *pool)
+                walked = (-(-rel // (block * page))
+                          - np.maximum(rel - window + 1, 0) // (block * page))
+                self._window_ctx = round(float(np.mean(
+                    walked * block + span_pages)), 3)
         self._ctx_pages = (read, max(0.0, read - float(own.mean())),
                            len(lengths), arm,
                            *self._expert_arm(self.serve.max_batch * t_span))
@@ -2131,6 +2336,21 @@ class ServingEngine:
                                      sv.ctx_bucket_pages,
                                      sv.max_pages_per_slot)
             self.stats["decode_buckets"].add(n_ctx)
+            window = {}
+            if self.wpool is not None:
+                # the pages a token's window reaches, and the one it
+                # writes: each slot's from the first its window sees
+                n_w = min(n_ctx, self._window_reach(1))
+                wtables = np.full((sv.max_batch, n_w), SCRATCH_PAGE,
+                                  np.int32)
+                wbase = np.zeros((sv.max_batch,), np.int32)
+                for i in active:
+                    s = self.slots[i]
+                    wtables[i], wbase[i] = self._window_table(
+                        s, s.length, n_w)
+                window = {"window": (jnp.asarray(wtables),
+                                     jnp.asarray(wbase))}
+                self.metrics.count("serve.window_programs")
             self._phase("serve.decode")
             self._queue_empty("serve.decode")
             if self._ep_fn is not None:
@@ -2143,7 +2363,8 @@ class ServingEngine:
                     "_paged_decode_step"](
                     self.params, self.cfg, self.cache, toks,
                     jnp.asarray(tables[:, :n_ctx]),
-                    jnp.asarray(positions), pad_token=sv.pad_token)
+                    jnp.asarray(positions), pad_token=sv.pad_token,
+                    **window)
                 self._counted = counted or None
                 # every slot's state goes through the step and back
                 self._state_bytes += (2 * sv.max_batch
@@ -2152,7 +2373,9 @@ class ServingEngine:
             self._note_ctx(n_ctx, positions[active], 1)
             self._logits = self._last_out = logits
             for i in active:
-                self.slots[i].length += 1
+                s = self.slots[i]
+                s.length += 1
+                self._trim_window(s, s.length)
         if ahead:
             # the wait for the sampler is the sampler's phase; the decode
             # program runs under it and under all that follows
@@ -2390,6 +2613,7 @@ class ServingEngine:
         self._starved, self._starved_at = 0, None
         self._prefills = [0, 0, 0]
         self._block_counts = None
+        self._window_freed, self._window_ctx = 0, 0.0
         if self._counted is not None:
             self._counted_prev, self._counted = self._counted, None
         # the same instant on the profiler's clock and on the engine's
@@ -2536,6 +2760,20 @@ class ServingEngine:
                                else "before_dispatch")
         if self.cfg.state_layers:
             rec["state_bytes"] = self._state_bytes
+        windowed = {}
+        if self.wpool is not None:
+            # the window pool beside ``pages_used``: its pages in use, the
+            # pages this step gave back to it, the pages a slot the decode
+            # program read in a window layer (beside ``ctx_pages``)
+            windowed = {"window_pages_used": self.wpool.used_pages,
+                        "window_pages_freed": self._window_freed,
+                        "window_ctx_pages": self._window_ctx}
+            rec.update(windowed)
+            self.metrics.gauge("serve.window_pool_pages",
+                               self.wpool.used_pages)
+            if self._window_freed:
+                self.metrics.count("serve.window_pages_freed",
+                                   self._window_freed)
         # the latest decode program that has finished (the very first
         # record waits for its own)
         counted = (self._counted_prev if self._counted_prev is not None
@@ -2566,7 +2804,7 @@ class ServingEngine:
             if ctx_pages:
                 # the decode program's shape, one record per step that
                 # ran it: a mean over these is a mean over decode steps
-                more = {}
+                more = dict(windowed)
                 if self.cfg.state_layers:
                     # the program streams EVERY row's state through the
                     # step, live or not: beside ``slots``, the rows that

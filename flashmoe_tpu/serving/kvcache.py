@@ -121,14 +121,17 @@ class HybridCache:
     place, and a row of the step that is not decoding (idle, or between
     two chunks) leaves it to the bit.  Retiring and evicting touch
     nothing: the next tenant's prefill overwrites it, and an evicted
-    request's re-prefill rebuilds it."""
+    request's re-prefill rebuilds it.
+
+    A model with WINDOW layers (``cfg.window_layers``) keeps a second K/V
+    pair between the two, ``wk_pages`` / ``wv_pages``: the window layers'
+    rows, in a pool with a page count and a page-id space of its own (a
+    slot's pages behind the window go back to it while the slot lives).
+    So a cache has no ONE page count, and no ``num_pages``: a pool's is its
+    array's (``cache.wk_pages.shape[1]``); the page SIZE is every pool's."""
 
     __slots__ = ()
     by_slot: tuple = ()
-
-    @property
-    def num_pages(self) -> int:
-        return self[0].shape[1]
 
     @property
     def page_size(self) -> int:
@@ -144,12 +147,19 @@ def hybrid_cache_class(paged: tuple, by_slot: tuple) -> type:
         {"__slots__": (), "by_slot": by_slot})
 
 
+#: the window layers' K/V pair in a cache's tuple of arrays, behind the
+#: full layers' (``PagedKVCache._fields``)
+WINDOW_FIELDS = ("wk_pages", "wv_pages")
+
+
 def cache_arrays(cfg: MoEConfig, num_pages: int, page_size: int,
-                 slots: int) -> tuple:
+                 slots: int, window_pages: int = 0) -> tuple:
     """The cache's arrays, zeroed: the paged pools of the layers that
-    cache rows (a K/V pair, or one latent pool) and, where the config has
-    state layers, a :class:`HybridCache` with ``slots`` slots of what
-    they keep (``cfg.slot_state``)."""
+    cache rows (a K/V pair, or one latent pool), where the config has
+    window layers THEIR K/V pair of ``window_pages`` pages (0: as many as
+    the full pools', the dense cache of ``generate()``), and, where it has
+    state layers, ``slots`` slots of what they keep (``cfg.slot_state``);
+    with either of the last two a :class:`HybridCache`."""
     n_cache = len(cfg.cache_layers)
     _, rows, width = cfg.kv_pool_rows
     if cfg.attention_kind == "mla":
@@ -160,7 +170,12 @@ def cache_arrays(cfg: MoEConfig, num_pages: int, page_size: int,
         names = PagedKVCache._fields
         shape = (n_cache, num_pages, rows, page_size, width)
         paged = (jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
-    if not cfg.state_layers:
+    if cfg.window_layers:
+        names += WINDOW_FIELDS
+        shape = (len(cfg.window_layers), window_pages or num_pages, rows,
+                 page_size, width)
+        paged += (jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
+    if not cfg.state_layers and not cfg.window_layers:
         return paged
     kept = cfg.slot_state
     return hybrid_cache_class(names, tuple(name for name, _, _ in kept))(
@@ -169,19 +184,21 @@ def cache_arrays(cfg: MoEConfig, num_pages: int, page_size: int,
 
 
 def init_paged_cache(cfg: MoEConfig, num_pages: int, page_size: int,
-                     slots: int = 0):
+                     slots: int = 0, window_pages: int = 0):
     """Allocate the cache the config's layers read: a K/V pair
     (:class:`PagedKVCache`), one latent pool (:class:`LatentPagedCache`)
-    or, with state layers, such pools beside ``slots`` slots of what
-    those layers keep (:class:`HybridCache`).  ``num_pages`` includes the
-    scratch page."""
-    if num_pages < 2:
-        raise ValueError(f"num_pages={num_pages} must be >= 2 (page 0 "
-                         f"is the reserved scratch page)")
+    or, with window or state layers, such pools beside the window layers'
+    pair of ``window_pages`` pages and ``slots`` slots of what
+    the state layers keep (:class:`HybridCache`).  ``num_pages`` and
+    ``window_pages`` include their pool's scratch page."""
+    if num_pages < 2 or (cfg.window_layers and window_pages < 2):
+        raise ValueError(f"num_pages={num_pages} (and, with window "
+                         f"layers, window_pages={window_pages}) must be "
+                         f">= 2 (page 0 is the reserved scratch page)")
     if page_size < 1:
         raise ValueError(f"page_size={page_size} must be >= 1")
-    arrays = cache_arrays(cfg, num_pages, page_size, slots)
-    if cfg.state_layers:
+    arrays = cache_arrays(cfg, num_pages, page_size, slots, window_pages)
+    if cfg.state_layers or cfg.window_layers:
         return arrays
     return (LatentPagedCache if cfg.attention_kind == "mla"
             else PagedKVCache)(*arrays)

@@ -373,6 +373,7 @@ STRUCTURAL_FIELDS = frozenset({
     "zero_experts", "mla_rank_scale", "block_length", "mask_token_id",
     "embedding_multiplier", "residual_multiplier", "attention_multiplier",
     "logits_scaling", "tie_embeddings",
+    "attn_window", "attn_gate", "part_out_norm",
     "dtype", "param_dtype", "accum_dtype",
     "dp", "ep", "tp", "sp", "pp",
 })

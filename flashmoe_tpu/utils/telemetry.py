@@ -270,8 +270,14 @@ SPAN_NAMES: dict[str, str] = {
         "K/V attention, the kernel's arm: a short span over each slot's "
         "own pages, read and written in place (``fm_paged_decode``: decode, "
         "verify and denoise steps on a TPU)",
+    "attn.gate":
+        "the output gate of a K/V attention layer (``MoEConfig.attn_gate``): "
+        "the heads' outputs times the sigmoid of ``u Wg``, before the "
+        "output product (every program of a model that gates; inside "
+        "``attn.kv``)",
     "attn.kv":
-        "a K/V attention layer's part (MHA / GQA): what of it stands under "
+        "a K/V attention layer's part (MHA / GQA, with or without a "
+        "window): what of it stands under "
         "neither arm: its norm, the q / k / v projections with their norms "
         "and RoPE, the output product, the residual join; training's "
         "attention whole",

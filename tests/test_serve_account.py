@@ -1133,10 +1133,11 @@ def test_device_report_without_a_program_line_or_a_program():
 
 
 def test_every_mixer_has_a_registered_scope_and_its_two_forms():
-    from flashmoe_tpu.config import STATE_MIXERS
+    from flashmoe_tpu.config import STATE_MIXERS, WINDOW_MIXER
     from flashmoe_tpu.ops.attention import MIXER_SPANS
 
-    assert set(MIXER_SPANS) == {"mha", "mla", *STATE_MIXERS}
+    assert set(MIXER_SPANS) == {"mha", WINDOW_MIXER, "mla", *STATE_MIXERS}
+    assert MIXER_SPANS[WINDOW_MIXER] == MIXER_SPANS["mha"]
     for part in MIXER_SPANS.values():
         assert {part, part + "_prefill", part + "_decode"} <= set(SPAN_NAMES)
 

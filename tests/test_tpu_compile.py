@@ -21,6 +21,8 @@ of few cases starts last in the gate's queue and must be cheap):
   (``-k shortcut``)
 - ``test_tpu_compile_granite4_h_micro.py``: ``granite4_h_micro``
   (``-k granite``)
+- ``test_tpu_compile_trinity_large.py``: ``trinity_large``
+  (``-k trinity``)
 - ``test_tpu_compile_sampler.py``: the sampler at both vocabularies
 - ``test_tpu_compile_layers.py``: kernels, layers, four chips, train step
 
